@@ -14,22 +14,6 @@ __version__ = "0.1.0"
 __git_hash__ = None
 __git_branch__ = None
 
-import os as _os
-
-import jax as _jax
-
-# Sharding-invariant RNG: without this, jax<0.5's non-partitionable threefry
-# lets the SPMD partitioner produce layout-DEPENDENT random values, so the
-# same seed inits different weights under different ZeRO/MiCS topologies
-# (and costs an all-gather of the bits on TPU).  This DOES change
-# jax.random streams for the same seed; the only opt-out is the env var
-# JAX_THREEFRY_PARTITIONABLE (=0 to keep legacy streams) — an explicit
-# pre-import config update to False is indistinguishable from the default
-# and gets flipped.
-if "JAX_THREEFRY_PARTITIONABLE" not in _os.environ and \
-        not _jax.config.jax_threefry_partitionable:
-    _jax.config.update("jax_threefry_partitionable", True)
-
 from deepspeed_tpu.accelerator import get_accelerator, set_accelerator  # noqa: F401
 from deepspeed_tpu import comm  # noqa: F401
 from deepspeed_tpu.comm import init_distributed  # noqa: F401

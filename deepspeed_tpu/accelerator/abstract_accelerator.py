@@ -129,9 +129,10 @@ class Accelerator(ABC):
         flops profiler's budget, the autotuner's cost model, the
         serving memory sampler, bench watermarks): ``{device, platform,
         bytes_in_use, peak_bytes_in_use, bytes_limit, limit_source}``.
-        The base implementation normalizes :meth:`memory_stats`;
-        ``TPU_Accelerator`` refines ``bytes_limit`` with the datasheet
-        capacity when the backend reports none."""
+        The base implementation normalizes :meth:`memory_stats`
+        (``limit_source`` ``"runtime"``, or ``"unknown"`` with limit 0
+        where the backend reports none — the CPU test backend);
+        ``TPU_Accelerator`` raises when a TPU reports no limit."""
         stats = self.memory_stats(device_index)
         limit = int(stats.get("bytes_limit") or 0)
         return {

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from deepspeed_tpu.comm.backend import XlaBackend
-from deepspeed_tpu.utils.jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from deepspeed_tpu.utils.comms_logging import CommsLogger, get_msg_size_from_args
 from deepspeed_tpu.utils.logging import logger
 from deepspeed_tpu.parallel import topology as topo
@@ -220,8 +220,8 @@ def all_reduce(tensor, op=ReduceOp.SUM, group=None, async_op=False, log_name=Non
         if op == ReduceOp.PRODUCT:
             return jnp.exp(lax.psum(jnp.log(tensor), axes))
         raise ValueError(f"unsupported op {op}")
-    from deepspeed_tpu.utils.jax_compat import process_allgather_stacked
-    gathered = process_allgather_stacked(jnp.asarray(tensor))
+    from jax.experimental import multihost_utils
+    gathered = multihost_utils.process_allgather(jnp.asarray(tensor))
     reducers = {ReduceOp.SUM: jnp.sum, ReduceOp.AVG: jnp.mean,
                 ReduceOp.MAX: jnp.max, ReduceOp.MIN: jnp.min,
                 ReduceOp.PRODUCT: jnp.prod}
@@ -410,8 +410,7 @@ _pending_send = []      # [(opaque_trace_state, tensor, dst, axes, tag)]
 
 
 def _current_trace_state():
-    from deepspeed_tpu.utils.jax_compat import get_opaque_trace_state
-    return get_opaque_trace_state()
+    return jax.core.get_opaque_trace_state()
 
 
 _warned_missing_trace_ref = False

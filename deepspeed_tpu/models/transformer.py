@@ -28,6 +28,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
+from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.utils.logging import logger
 
@@ -365,9 +366,7 @@ def _attention(q, k, v, config, mask=None, bias=None, window=None):
             if k.shape[2] != q.shape[2]:  # GQA: expand for the sp kernels
                 k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
                 v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)
-            batch_axes = tuple(a for a in topo.get_data_parallel_axes()
-                               if topo.mesh.shape[a] > 1) or None
-            head_axes = "tp" if topo.mesh.shape.get("tp", 1) > 1 else None
+            batch_axes, head_axes = _batch_head_axes(topo)
             fn = shard_map_attention(topo.mesh,
                                      impl=config.sequence_parallel_impl,
                                      axis="sp", causal=True,
@@ -376,11 +375,45 @@ def _attention(q, k, v, config, mask=None, bias=None, window=None):
             return fn(q, k, v)
     if config.use_flash_attention and q.shape[1] > 1 and mask is None \
             and bias is None:
-        from deepspeed_tpu.ops.transformer.flash_attention import (
-            flash_attention, pallas_supported)
+        from deepspeed_tpu.ops.transformer.flash_attention import \
+            pallas_supported
         if pallas_supported():
-            return flash_attention(q, k, v, causal=True)
+            return _flash_on_mesh(q, k, v)
     return reference_attention(q, k, v, causal=True, mask=mask, bias=bias)
+
+
+def _flash_on_mesh(q, k, v):
+    """Causal flash attention on [B, S, H, D].  On a multi-device mesh the
+    kernel runs per shard under ``shard_map`` — batch over the data-parallel
+    axes, heads over ``tp`` — because GSPMD cannot partition a Mosaic kernel
+    (the TPU compiler refuses: "wrap the call in a shard_map"; the CPU
+    interpreter lowers the kernel to plain HLO and never sees this).
+    Shapes the mesh does not divide, and pipeline stage bodies (already
+    inside a ``pp`` shard_map), keep the bare call."""
+    from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+    from deepspeed_tpu.parallel.topology import get_topology
+    attend = partial(flash_attention, causal=True)
+    topo = get_topology()
+    if topo is None or topo.mesh.size == 1 or topo.mesh.shape["pp"] > 1:
+        return attend(q, k, v)
+    mesh = topo.mesh
+    batch_axes, head_axes = _batch_head_axes(topo)
+    n_batch = int(np.prod([mesh.shape[a] for a in batch_axes or ()]))
+    tp = mesh.shape["tp"]
+    if q.shape[0] % n_batch or q.shape[2] % tp or k.shape[2] % tp:
+        return attend(q, k, v)
+    spec = P(batch_axes, None, head_axes, None)
+    return jax.shard_map(attend, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+def _batch_head_axes(topo):
+    """The mesh axes a [B, S, H, D] activation's batch and head dims are
+    sharded over: the data-parallel axes of size > 1, and ``tp``."""
+    mesh = topo.mesh
+    batch_axes = tuple(a for a in topo.get_data_parallel_axes()
+                       if mesh.shape[a] > 1) or None
+    return batch_axes, "tp" if mesh.shape["tp"] > 1 else None
 
 
 _CACHE_DATA_KEYS = ("k", "v", "k_scale", "v_scale")

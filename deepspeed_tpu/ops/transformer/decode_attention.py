@@ -47,7 +47,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.transformer.flash_attention import (LSE_LANES, NEG_INF,
                                                            _interpret)
-from deepspeed_tpu.utils.jax_compat import CompilerParams as _CompilerParams
 
 DEFAULT_BLOCK_K_DECODE = int(_os.environ.get("DSTPU_DECODE_BLOCK_K", "512"))
 
@@ -461,7 +460,7 @@ def chunk_prefill_attention(q, k_cache, v_cache, starts, scale=None,
                 pltpu.VMEM((C, H * D), jnp.float32),     # per-head acc
             ]),
         out_shape=jax.ShapeDtypeStruct((B, C, H * D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=max(
                 64 * 1024 * 1024,
@@ -648,7 +647,7 @@ def decode_attention(q, k_cache, v_cache, lengths,
                  if mxu_int8 else [])),
         out_shape=out_shape if fused_write else out_shape[0],
         input_output_aliases=io_aliases,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             # the [block_k, KVH*D] K/V slabs double-buffer; the default
             # 16 MB scoped-vmem budget is a hair short at the default

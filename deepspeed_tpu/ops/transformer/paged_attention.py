@@ -54,7 +54,6 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.transformer.decode_attention import (
     _chunk_prefill_kernel, _decode_kernel)
 from deepspeed_tpu.ops.transformer.flash_attention import LSE_LANES, _interpret
-from deepspeed_tpu.utils.jax_compat import CompilerParams as _CompilerParams
 
 
 def _paged_decode_body(len_ref, layer_ref, pages_ref, *args, **kw):
@@ -223,7 +222,7 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
                  if mxu_int8 else [])),
         out_shape=out_shape if fused_write else out_shape[0],
         input_output_aliases=io_aliases,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             # pages are small (<= a monolithic block_k) — the monolithic
             # slab-sized floor is comfortably enough headroom
@@ -306,7 +305,7 @@ def paged_chunk_prefill_attention(q, k_pool, v_pool, starts, pages, *,
                 pltpu.VMEM((C, H * D), jnp.float32),     # per-head acc
             ]),
         out_shape=jax.ShapeDtypeStruct((B, C, H * D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=max(
                 64 * 1024 * 1024,

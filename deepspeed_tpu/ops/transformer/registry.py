@@ -312,7 +312,7 @@ def _fallback_reason(cfg, bias, window, cache):
     if window is not None:
         return "sliding-window layer"
     if not pallas_supported():
-        return "no Pallas support on this backend"
+        return "DSTPU_DISABLE_FLASH=1"
     return "unsupported configuration"
 
 

@@ -47,7 +47,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.parallel.topology import PP_AXIS
-from deepspeed_tpu.utils.jax_compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 
 def spmd_pipeline(stage_fn, stacked_params, x0, num_micro, mesh,
@@ -119,7 +119,7 @@ def spmd_pipeline(stage_fn, stacked_params, x0, num_micro, mesh,
             outs = lax.psum(outs, pp_axis)
         return outs
 
-    in_specs = (jax.tree.map(lambda _: P(pp_axis), stacked_params), P())  # tpu-lint: disable=TL010 -- every stage needs the full microbatch stream: the region slices its own microbatch per tick in-program; batch sharding over edp runs manually inside (jax_compat axis_names fallback)
+    in_specs = (jax.tree.map(lambda _: P(pp_axis), stacked_params), P())  # tpu-lint: disable=TL010 -- every stage needs the full microbatch stream: the region slices its own microbatch per tick in-program; batch sharding over edp runs manually inside
     out = _shard_map(
         region, mesh=mesh, in_specs=in_specs, out_specs=P(),
         axis_names=frozenset({pp_axis}), check_vma=False,

@@ -255,6 +255,21 @@ def pipeline_1f1b(world=8):
                           "appear with the tp axis at mesh 4; the "
                           "per-chip trajectory is flat from there "
                           "(4 -> 8 unchanged)",
+            # the three below appeared with the jaxlib 0.9.0 SPMD
+            # partitioner (the 0.4.37 lock had a flat trajectory); the
+            # parent tree schedules the same ops under the installed JAX
+            "all-reduce": "Megatron tp=2 activation reductions appear "
+                          "with the tp axis at mesh 4 (jaxlib 0.9.0 "
+                          "partitioner; flat 4 -> 8)",
+            "all-to-all": "one tp-axis reshard the jaxlib 0.9.0 "
+                          "partitioner emits at mesh 4 (2KB/chip, flat "
+                          "4 -> 8)",
+            "collective-permute": "UNEXPLAINED: with the dp axis at mesh "
+                                  "8 the jaxlib 0.9.0 partitioner adds "
+                                  "reshard permutes (8KB -> 144KB/chip) "
+                                  "the 0.4.37 one did not; CPU toy plan, "
+                                  "never run on chips — PERF.md open "
+                                  "question",
         })
 
 

@@ -158,7 +158,7 @@ def shard_map_attention(mesh, impl="ulysses", axis="sp", causal=True,
     dp-sharded batch onto every device — each device computes only its own
     batch/head shard, with collectives riding the sp axis alone."""
     from jax.sharding import PartitionSpec as P
-    from deepspeed_tpu.utils.jax_compat import shard_map as _shard_map
+    from jax import shard_map as _shard_map
 
     def smap(f, **kw):
         return _shard_map(f, mesh=kw["mesh"], in_specs=kw["in_specs"],

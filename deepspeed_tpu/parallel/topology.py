@@ -23,6 +23,8 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deepspeed_tpu.utils.logging import logger
+
 # Canonical axis names.
 PP_AXIS = "pp"      # pipeline stages
 MDP_AXIS = "mdp"    # MiCS replica groups (ZeRO shards live WITHIN a group,
@@ -73,13 +75,22 @@ class ParallelTopology:
                 f"topology dp={self.dp} tp={self.tp} pp={self.pp} sp={self.sp} "
                 f"needs {need} devices, have {len(devices)}")
         devices = devices[:need]
+        # which of the two below laid the devices out ("caller" = a mesh
+        # was handed in) — a plain reshape ignores the physical torus
+        self.mesh_built_by = "caller"
         if self.mesh is None:
             shape = (self.pp, self.mdp, self.edp, self.ep, self.sp, self.tp)
             try:
                 from jax.experimental import mesh_utils
                 dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-            except Exception:
+                self.mesh_built_by = "mesh_utils.create_device_mesh"
+            except Exception as e:
+                logger.warning(
+                    f"mesh_utils.create_device_mesh refused shape {shape} "
+                    f"({type(e).__name__}: {e}); laying the devices out by "
+                    f"plain reshape")
                 dev_array = np.asarray(devices).reshape(shape)
+                self.mesh_built_by = "reshape"
             self.mesh = Mesh(dev_array, AXIS_ORDER)
 
     # ------------------------------------------------------------------ #

@@ -51,29 +51,31 @@ PEAK_HBM_GBPS = {
 }
 
 
-def _device_peak(table, default):
+def _device_peak(table):
     d = jax.devices()[0]
-    kind = getattr(d, "device_kind", "cpu").lower()
+    kind = d.device_kind.lower()
     for key, val in table.items():
         if kind.startswith(key):
             return val
-    return table.get(d.platform, default)
+    raise KeyError(
+        f"no published peak for device kind {d.device_kind!r} — add it "
+        f"to the tables in {__name__} with its source; a device that is "
+        f"not in the table is an error, not a default")
 
 
 def device_peak_tflops():
-    return _device_peak(PEAK_TFLOPS, 100.0)
+    return _device_peak(PEAK_TFLOPS)
 
 
 def device_peak_hbm_gbps():
-    return _device_peak(PEAK_HBM_GBPS, 819.0)
+    return _device_peak(PEAK_HBM_GBPS)
 
 
 def device_hbm_bytes():
     """Device memory budget in bytes, via the accelerator's canonical
-    ``memory_snapshot`` reader: the backend's reported ``bytes_limit``
-    when available, else the datasheet capacity for the device kind
-    (``accelerator.tpu_accelerator.DATASHEET_HBM_BYTES``; 0 =
-    unknown/unbounded, callers should skip budget checks)."""
+    ``memory_snapshot`` reader: the runtime's reported ``bytes_limit``
+    (0 only on the CPU test backend = unbounded, callers skip budget
+    checks; a TPU that reports none raises)."""
     from deepspeed_tpu.accelerator.real_accelerator import get_accelerator
     return int(get_accelerator().memory_snapshot()["bytes_limit"])
 
@@ -347,11 +349,10 @@ def model_profile_tree(module, rngs, *args, measure_latency=True,
         # (jit dispatch would compile a second executable)
         compiled = fn.lower(variables, *args).compile()
         scopes = _hlo_op_scopes(compiled.as_text())
-        from deepspeed_tpu.utils.sync import dependent_sync_scalar
-        dependent_sync_scalar(compiled(variables, *args))   # warmup
+        jax.block_until_ready(compiled(variables, *args))   # warmup
 
         def run():
-            dependent_sync_scalar(compiled(variables, *args))
+            jax.block_until_ready(compiled(variables, *args))
 
         stats = _trace_op_stats(run)
         for op, (ps, flops) in stats.items():

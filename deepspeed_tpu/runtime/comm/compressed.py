@@ -135,7 +135,7 @@ class CompressedBackend:
         return self.worker_errors[name], self.server_errors[name]
 
     def allreduce(self, name, x):
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         n = int(np.prod(x.shape))
         we, se = self._buffers(name, n)

@@ -40,15 +40,15 @@ import time
 from typing import Any, Dict, Optional
 
 from deepspeed_tpu.runtime.config_utils import DeepSpeedConfigModel
-from deepspeed_tpu.utils.logging import logger, log_dist
+from deepspeed_tpu.utils.logging import logger, log_dist, warning_once
 
 
 class CompileCacheConfig(DeepSpeedConfigModel):
     """``compile_cache`` config block (shared by the training and inference
     engines; see ``docs/compile_cache.md``)."""
     enabled: bool = False
-    # framework-owned cache root; None → $DSTPU_COMPILE_CACHE_DIR or
-    # ~/.cache/deepspeed_tpu/compile_cache
+    # cache root.  $JAX_COMPILATION_CACHE_DIR, when set, wins over this key;
+    # None → :func:`default_cache_dir`
     cache_dir: Optional[str] = None
     # below this, XLA-cache writes are skipped (tiny programs recompile
     # faster than they deserialize); jax default is 1s
@@ -59,9 +59,23 @@ class CompileCacheConfig(DeepSpeedConfigModel):
     executable_dir: Optional[str] = None
 
 
+# the one path code ever chooses on its own: fixed (the path is part of
+# JAX's cache key, so a directory that moves never hits), inside the
+# checkout, git-ignored
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+def env_cache_dir():
+    """``$JAX_COMPILATION_CACHE_DIR`` or None.  Where it is set, JAX's own
+    handling of it is the only placement: nothing here re-points the
+    cache, and the executable store lives under it too."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+
+
 def default_cache_dir():
-    return os.environ.get("DSTPU_COMPILE_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "deepspeed_tpu", "compile_cache")
+    return env_cache_dir() or _CHECKOUT_CACHE_DIR
 
 
 # --------------------------------------------------------------------- #
@@ -80,6 +94,7 @@ class CacheStats:
         self.executable_mismatches = 0   # fingerprint said "not this build"
         self.executable_saves = 0
         self.executable_errors = 0
+        self.aot_fallbacks = 0           # AOT compiles that raised (→ plain jit)
         self.compile_seconds: Dict[str, float] = {}  # tag -> last compile time
 
     def snapshot(self):
@@ -110,12 +125,9 @@ def _register_jax_listener():
     global _listener_registered
     if _listener_registered:
         return
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_listener(_on_jax_event)
-        _listener_registered = True
-    except Exception as e:      # private API — accounting is best-effort
-        logger.debug(f"compile-cache hit accounting unavailable: {e}")
+    from jax import monitoring
+    monitoring.register_event_listener(_on_jax_event)
+    _listener_registered = True
 
 
 # --------------------------------------------------------------------- #
@@ -129,20 +141,28 @@ def configure_persistent_cache(cache_dir=None, min_compile_time_secs=None):
     directory (idempotent; process-wide).  Returns the directory."""
     global _configured_dir
     import jax
-    cache_dir = cache_dir or default_cache_dir()
+    placed = env_cache_dir()
+    if placed is not None:
+        if cache_dir not in (None, placed):
+            warning_once(
+                f"compile_cache: JAX_COMPILATION_CACHE_DIR={placed} is set "
+                f"and places the cache; cache_dir={cache_dir} is ignored")
+        cache_dir = placed
+    else:
+        cache_dir = cache_dir or _CHECKOUT_CACHE_DIR
+        # the XLA cache dir is PROCESS-GLOBAL: re-pointing it (a second
+        # engine with a different cache_dir) is last-wins and fragments
+        # the cache — allowed, but never silent
+        current = jax.config.jax_compilation_cache_dir
+        if current not in (None, cache_dir):
+            logger.warning(
+                f"compile_cache: re-pointing the process-global XLA "
+                f"compilation cache from {current} to {cache_dir} (the dir "
+                f"is one-per-process; every engine and jit in this process "
+                f"now writes there — use one cache_dir per process to "
+                f"avoid fragmenting the cache)")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
-    # the XLA cache dir is PROCESS-GLOBAL: re-pointing it (a second engine
-    # with a different cache_dir, or a user-set jax_compilation_cache_dir)
-    # is last-wins and fragments the cache — allowed, but never silent
-    current = jax.config.jax_compilation_cache_dir
-    if current not in (None, cache_dir):
-        logger.warning(
-            f"compile_cache: re-pointing the process-global XLA "
-            f"compilation cache from {current} to {cache_dir} (the dir is "
-            f"one-per-process; every engine and jit in this process now "
-            f"writes there — use one cache_dir per process to avoid "
-            f"fragmenting the cache)")
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     if min_compile_time_secs is not None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(min_compile_time_secs))
@@ -155,19 +175,12 @@ def configure_persistent_cache(cache_dir=None, min_compile_time_secs=None):
 
 def _reset_jax_cache_state():
     """Drop jax's initialized-once compilation-cache module state so the
-    next compile re-reads the live config.  jax 0.4.x caches the decision
-    AND the cache object in module globals (``_cache_checked`` /
-    ``_cache``), so flipping ``jax_compilation_cache_dir`` alone does
-    NOT detach an already-used cache."""
-    try:
-        from jax._src import compilation_cache as jcc
-        jcc.reset_cache()
-        return True
-    except Exception as e:                       # API drift: fail open
-        logger.warning(f"compile_cache: could not reset jax's "
-                       f"compilation-cache state ({e}) — persistent-cache "
-                       f"suspension is best-effort only")
-        return False
+    next compile re-reads the live config: jax memoizes the use-the-cache
+    decision AND the cache object at the first compile, so flipping
+    ``jax_compilation_cache_dir`` alone does NOT detach a cache already
+    in use."""
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+    jcc.reset_cache()
 
 
 @contextlib.contextmanager
@@ -295,9 +308,15 @@ class ExecutableStore:
                 return None
             with open(bin_path, "rb") as f:
                 payload, in_tree, out_tree = pickle.loads(f.read())
+            import jax
             from jax.experimental import serialize_executable
+            # load onto the devices the program was compiled for — the
+            # default is EVERY local device, which turns a one-device
+            # program into an n-shard one
+            by_id = {d.id: d for d in jax.devices()}
             exe = serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in meta["device_ids"]])
         except Exception as e:
             _STATS.executable_errors += 1
             _STATS.executable_misses += 1
@@ -312,6 +331,8 @@ class ExecutableStore:
         try:
             from jax.experimental import serialize_executable
             blob = pickle.dumps(serialize_executable.serialize(compiled))
+            device_ids = [d.id for d in compiled._executable
+                          ._unloaded_executable.device_list]
             tmp = bin_path + f".tmp.{os.getpid()}"
             with open(tmp, "wb") as f:
                 f.write(blob)
@@ -319,6 +340,7 @@ class ExecutableStore:
             tmp = meta_path + f".tmp.{os.getpid()}"
             with open(tmp, "w") as f:
                 json.dump({"fingerprint": self._fp, "key": key,
+                           "device_ids": device_ids,
                            "bytes": len(blob), "created": time.time()}, f)
             os.replace(tmp, meta_path)
         except Exception as e:
@@ -392,6 +414,7 @@ def aot_compile_with_store(program_cache, tag, key_parts, fn, args):
                 tag, key_parts, lambda: fn.lower(*args).compile())
         return fn.lower(*args).compile(), time.perf_counter() - t0, False
     except Exception as e:
+        _STATS.aot_fallbacks += 1
         logger.warning(f"AOT compile of {tag} failed ({e}); falling back "
                        f"to the plain jit call")
         return None, 0.0, False

@@ -55,6 +55,9 @@ _GROUPS_EXPLICIT_RE = re.compile(
     r"replica_groups=\{(\{[0-9, ]*\}(?:,\s*\{[0-9, ]*\})*)\}")
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=\[")
 _PAIRS_RE = re.compile(r"source_target_pairs=\{((?:\{\d+,\s*\d+\},?\s*)+)\}")
+# ``%name = <result shape(s)> opcode(`` — an instruction's definition
+_DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?(%[\w.\-]+)\s*=\s*(.*?)\s[a-z][\w\-]*\(")
+_NAME_RE = re.compile(r"%[\w.\-]+")
 
 # StableHLO mnemonics in an un-optimized lowering — a cheap "does this
 # program communicate at all?" probe that costs no compile
@@ -80,9 +83,20 @@ def parse_hlo_comm(hlo_text, world):
     groups, tuple-shaped variadic operands, async ``-start`` forms (the
     ``-done`` halves are skipped so nothing double-counts), and
     ``collective-permute``'s pair list.  An instruction with no
-    ``replica_groups`` spans the whole ``world``."""
+    ``replica_groups`` spans the whole ``world``.  Operand bytes come
+    from the operands' typed shapes where the text prints them
+    (``all-gather(f32[8,64] %x)``) and from the operands' own
+    definitions where it prints names only (``all-gather(%x)`` — what
+    ``compiled.as_text()`` gives since jaxlib 0.5)."""
     out = {}
-    for line in hlo_text.splitlines():
+    lines = hlo_text.splitlines()
+    defs = {}
+    for line in lines:
+        d = _DEF_RE.match(line)
+        if d:
+            defs[d.group(1)] = sum(shape_bytes(t, s) for t, s in
+                                   _SHAPE_RE.findall(d.group(2)))
+    for line in lines:
         m = _OP_RE.search(line)
         if m is None or "-done(" in line:
             continue
@@ -98,8 +112,9 @@ def parse_hlo_comm(hlo_text, world):
                 depth -= 1
             i += 1
         operands, tail = line[start:i - 1], line[i:]
-        op_bytes = sum(shape_bytes(d, s)
-                       for d, s in _SHAPE_RE.findall(operands))
+        typed = _SHAPE_RE.findall(operands)
+        op_bytes = sum(shape_bytes(d, s) for d, s in typed) if typed \
+            else sum(defs.get(n, 0) for n in _NAME_RE.findall(operands))
         gi = _GROUPS_IOTA_RE.search(tail)
         ge = _GROUPS_EXPLICIT_RE.search(tail)
         if gi:

@@ -76,17 +76,12 @@ def ensure_harness_env():
     devices) — a no-op when the backend is already initialized that way;
     raises when it is initialized differently (contracts extracted on
     another topology would never match the lockfile)."""
-    os.environ.setdefault("DSTPU_ACCELERATOR", "cpu")
     os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = \
             flags + " --xla_force_host_platform_device_count=8"
     import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
     if jax.default_backend() != "cpu" or jax.device_count() < 8:
         raise RuntimeError(
             f"contract extraction needs the tier-1 harness (CPU backend, "

@@ -10,7 +10,7 @@ an all-gather storm" regression the comm-cost contracts exist to catch.
 This rule catches it at the SOURCE level, before a byte moves:
 
 * a ``shard_map`` application (direct call, ``functools.partial``
-  decorator, or the ``jax_compat`` alias) carrying a ``mesh=`` but missing
+  decorator) carrying a ``mesh=`` but missing
   ``in_specs``/``out_specs`` — every operand silently replicates;
 * a ``jax.jit`` call inside a ``with <mesh>:`` block with no
   ``in_shardings``/``out_shardings`` at all — same default, harder to see;

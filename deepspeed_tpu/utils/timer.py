@@ -108,8 +108,7 @@ class ThroughputTimer:
         self.started = True
         if self.global_step_count >= self.start_step:
             # no device synchronize here: a per-step sync serializes the
-            # dispatch pipeline (and through a remote tunnel costs a full
-            # round-trip).  Async dispatch self-throttles over a window, so
+            # dispatch pipeline.  Async dispatch self-throttles over a window, so
             # windowed wall-clock throughput stays accurate without syncs.
             self.start_time = time.perf_counter()
 

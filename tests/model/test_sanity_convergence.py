@@ -169,8 +169,7 @@ def test_lean_optimizer_states_convergence_parity():
         f"sys.path.insert(0, {here!r});"
         "os.environ['XLA_FLAGS'] = "
         "'--xla_force_host_platform_device_count=8';"
-        "os.environ['DSTPU_ACCELERATOR'] = 'cpu';"
-        "import jax; jax.config.update('jax_platforms', 'cpu');"
+        "os.environ['JAX_PLATFORMS'] = 'cpu';"
         "import test_sanity_convergence as m; m._lean_parity_main()")
     result = subprocess.run(
         [sys.executable, "-c", code],

@@ -22,25 +22,19 @@ flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = \
         flags + " --xla_force_host_platform_device_count=8"
-os.environ.setdefault("DSTPU_ACCELERATOR", "cpu")
 sys.path.insert(0, os.environ["DSTPU_REPO_ROOT"])
 sys.path.insert(0, os.path.join(os.environ["DSTPU_REPO_ROOT"], "tests",
                                 "unit"))
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-# Per-harness compile cache so relaunches skip XLA compilation.  NEVER
-# point this at the suite's tests/.jax_compile_cache: this process is
-# killed with os._exit at arbitrary seams, and a truncated cache write
-# makes every LATER process that loads the entry abort natively deep in
-# XLA (observed: deterministic SIGABRT in engine.step until the poisoned
+# The launching test hands this process its own compile cache through
+# $JAX_COMPILATION_CACHE_DIR, NEVER the suite's: this process is killed
+# with os._exit at arbitrary seams, and a truncated cache write makes
+# every LATER process that loads the entry abort natively deep in XLA
+# (observed: deterministic SIGABRT in engine.step until the poisoned
 # entry was pruned).  Isolation bounds the blast radius to this test's
 # own tmp dir.
-_cache = os.environ.get("DSTPU_DRIVER_CACHE")
-if _cache:
-    jax.config.update("jax_compilation_cache_dir", _cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 import deepspeed_tpu  # noqa: E402
 from deepspeed_tpu.runtime.fault.supervisor import run_resilient  # noqa: E402

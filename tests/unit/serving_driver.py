@@ -24,19 +24,14 @@ import os
 import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.setdefault("DSTPU_ACCELERATOR", "cpu")
 sys.path.insert(0, os.environ["DSTPU_REPO_ROOT"])
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-# per-harness compile cache, NEVER the suite's (see fault_driver.py: an
+# the launching test hands this process its own compile cache through
+# $JAX_COMPILATION_CACHE_DIR, NEVER the suite's (see fault_driver.py: an
 # os._exit mid-cache-write once poisoned the shared cache for every
 # later process)
-_cache = os.environ.get("DSTPU_DRIVER_CACHE")
-if _cache:
-    jax.config.update("jax_compilation_cache_dir", _cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 import numpy as np  # noqa: E402
 
@@ -102,8 +97,8 @@ def main():
                     **({"speculative": True, "spec_k": 2,
                         "spec_draft_model": "self"} if args.spec else {})},
     }
-    if _cache:
-        config["compile_cache"] = {"enabled": True, "cache_dir": _cache,
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        config["compile_cache"] = {"enabled": True,
                                    "min_compile_time_secs": 0.0}
     eng = deepspeed_tpu.init_inference(model, config=config)
     eng.set_params(params)

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 _SM = lambda f, mesh, i, o: shard_map(f, mesh=mesh, in_specs=i,
                                       out_specs=o, check_vma=False)

@@ -7,7 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 import deepspeed_tpu.comm as dist
 from deepspeed_tpu.parallel.topology import (initialize_topology, DP_AXES,
@@ -273,8 +273,7 @@ def test_opaque_trace_state_has_trace_ref():
     canary makes that regression LOUD: if it fails, update
     ``comm._prune_dead_sends`` for the new OpaqueTraceState internals
     (comm.py emits a one-time runtime warning for the same condition)."""
-    from deepspeed_tpu.utils.jax_compat import get_opaque_trace_state
-    state = get_opaque_trace_state()
+    state = jax.core.get_opaque_trace_state()
     assert hasattr(state, "_trace_ref"), (
         "OpaqueTraceState._trace_ref is gone on this JAX version — "
         "_prune_dead_sends now treats every queued send as live; port it "

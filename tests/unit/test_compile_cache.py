@@ -42,6 +42,41 @@ def _delta(after, before, key):
 
 
 # --------------------------------------------------------------------- #
+# Placement: $JAX_COMPILATION_CACHE_DIR, else one fixed path in the checkout
+# --------------------------------------------------------------------- #
+def test_env_variable_is_the_only_placement(cache_dir, monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set, JAX's own handling of it
+    places the cache: the config key is ignored and NOTHING gives
+    ``jax_compilation_cache_dir`` a path (the executable store lives
+    under the variable's directory too)."""
+    placed = os.path.join(cache_dir, "from_env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(jax.config, "update", lambda k, v: (
+        updates.append((k, v)), real_update(k, v)))
+    got = cc.configure_persistent_cache(os.path.join(cache_dir, "from_key"))
+    assert got == placed and os.path.isdir(placed)
+    assert not os.path.exists(os.path.join(cache_dir, "from_key"))
+    assert "jax_compilation_cache_dir" not in [k for k, _ in updates]
+    pc = cc.ProgramCache(cc.CompileCacheConfig(
+        enabled=True, cache_dir=os.path.join(cache_dir, "from_key")))
+    assert pc.store.directory == os.path.join(placed, "executables")
+
+
+def test_unset_variable_places_the_cache_in_the_checkout(cache_dir,
+                                                         monkeypatch):
+    """Unset, the one path code ever chooses is ``.jax_cache`` at the
+    root of the checkout — never ``~``, never a temp name."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    assert cc.default_cache_dir() == os.path.join(repo, ".jax_cache")
+    assert cc.configure_persistent_cache() == os.path.join(repo, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == cc.default_cache_dir()
+
+
+# --------------------------------------------------------------------- #
 # ExecutableStore unit behavior
 # --------------------------------------------------------------------- #
 def test_executable_store_roundtrip_and_accounting(cache_dir):

@@ -583,10 +583,11 @@ def _run_driver(ckpt_dir, losses_path, inject_spec=None, max_steps=6,
     env["DSTPU_REPO_ROOT"] = REPO
     # drivers get their own compile cache (shared across the launches of
     # one scenario, isolated from the suite's): an os._exit mid-cache-
-    # write would otherwise poison tests/.jax_compile_cache for every
-    # later process (native abort loading the truncated executable)
-    env["DSTPU_DRIVER_CACHE"] = os.path.join(
+    # write would otherwise poison the suite's cache for every later
+    # process (native abort loading the truncated executable)
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
         os.path.dirname(str(ckpt_dir)), ".jax_driver_cache")
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0.5"
     env.pop("DSTPU_FAULT_INJECT", None)
     env.pop("BENCH_MODEL", None)
     if inject_spec:

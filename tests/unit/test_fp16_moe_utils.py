@@ -67,7 +67,7 @@ def test_fp16_optimizer_overflow_skips_and_rescales():
 # ------------------------------------------------------------------ #
 def test_moe_gather_drop_tokens_roundtrip(eight_devices):
     from jax.sharding import Mesh, PartitionSpec as P
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from deepspeed_tpu.moe.mappings import drop_tokens, gather_tokens
     mesh = Mesh(np.asarray(eight_devices).reshape(8), ("tp",))
     x = jnp.arange(32.0).reshape(8, 4)  # [tokens, dim] split over tp

@@ -139,7 +139,7 @@ def test_compressed_allreduce_unbiased_over_workers(eight_devices):
     """With different per-worker tensors (sharded batch axis), the decoded
     mean must correlate strongly with the true mean."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     import functools
     mesh = Mesh(np.array(eight_devices), ("dp",))
     rng = np.random.default_rng(1)

@@ -387,6 +387,26 @@ def test_hlo_comm_parser_formats():
                                           "bytes_per_step": 1024}
 
 
+def test_hlo_comm_parser_untyped_operands():
+    """``compiled.as_text()`` of the installed jaxlib prints operands by
+    NAME only (``all-gather(%c)``): bytes then come from the operands' own
+    definitions — same totals as the typed form above, never a silent 0
+    (the first 0.9.0 regeneration locked every schedule at 0 bytes)."""
+    txt = """
+ENTRY %main {
+  %c = f32[2,8]{1,0} parameter(0)
+  %p.1 = f32[2,8]{1,0} bitcast(%c)
+  %ag = f32[4,8]{1,0} all-gather(%c), replica_groups={{0,1},{2,3},{4,5},{6,7}}, dimensions={0}
+  %a2a = (f32[2,8]{1,0}, f32[2,8]{1,0}) all-to-all(%c, %p.1), replica_groups={{0,1},{2,3}}
+  ROOT %ar = f32[2,8]{1,0} all-reduce(%p.1), replica_groups=[4,2]<=[8], to_apply=%region
+}
+"""
+    comm = comm_contract.parse_hlo_comm(txt, 8)
+    assert comm["all-gather"] == {"count": 1, "bytes_per_step": 512}
+    assert comm["all-to-all"] == {"count": 1, "bytes_per_step": 512}
+    assert comm["all-reduce"] == {"count": 1, "bytes_per_step": 512}
+
+
 # ------------------------------------------------------------------ #
 # Memory/FLOP contracts (PROGRAMS.lock format 3, tools/lint/
 # mem_contract.py) — artifact invariants + the synthetic-break proof

@@ -490,7 +490,8 @@ def _run_serving_driver(ckpt_dir, results_path, cache_dir,
                         speculative=False):
     env = dict(os.environ)
     env["DSTPU_REPO_ROOT"] = REPO
-    env["DSTPU_DRIVER_CACHE"] = str(cache_dir)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0.5"
     env.pop("DSTPU_FAULT_INJECT", None)
     env.pop("BENCH_MODEL", None)
     if inject_spec:

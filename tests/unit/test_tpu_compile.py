@@ -1,0 +1,133 @@
+"""The main path's Pallas kernels, compiled to Mosaic for a DESCRIBED v5e
+(no chip attached) at OPT-1.3B widths — H=32, D=64, L=24, bf16.
+
+The TPU compiler is installed in the sandbox; ``get_topology_desc`` hands
+it a chip that exists only as a description, and it refuses exactly what
+the real chip's compiler would refuse (a slice not aligned to the tiling,
+too much VMEM, a kernel that cannot be partitioned) — things interpret
+mode never sees.  A compile that passes is not a chip run: nothing here
+says anything about results or time.
+
+All of these live in ONE file and describe the topology inside a
+module-scoped fixture: only one process at a time may load libtpu, so
+under xdist exactly one worker — the one handed this file — may do it,
+and only after collection.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops.sparse_attention import block_sparse
+from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
+                                           flash_attention as flash_mod,
+                                           paged_attention as paged_mod)
+
+H, D, L = 32, 64, 24            # OPT-1.3B (models/opt.py)
+HD = H * D
+BF16, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A described-chip executable can be written to the persistent cache
+    but not read back without a chip — keep it out of these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    jcc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    jcc.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch, no_persistent_cache):
+    """``_interpret()`` asks ``jax.default_backend()``, which is the CPU
+    here: steer it in the test, not through an option of the program."""
+    for mod in (flash_mod, decode_mod, paged_mod, block_sparse):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def _flash_fwd_bwd():
+    def loss(q, k, v):
+        out = flash_mod.flash_attention(q, k, v, causal=True)
+        return out.astype(F32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2)), [((2, 2048, H, D), BF16)] * 3
+
+
+def _paged_decode(quant, page=64, slots=8, cache_len=512):
+    pages_per_slot = cache_len // page
+    n_pages = slots * pages_per_slot + 1
+    pool = ((L, n_pages, page, HD), I8 if quant else BF16)
+    args = [((slots, H, D), BF16), pool, pool, ((slots,), I32),
+            ((slots, pages_per_slot), I32), ((slots, H, D), BF16),
+            ((slots, H, D), BF16)]
+    if quant:
+        args += [((L, n_pages, page, H), F32)] * 2
+
+    def fn(q, k_pool, v_pool, lengths, pages, new_k, new_v, *scales):
+        kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
+        return paged_mod.paged_decode_attention(
+            q, k_pool, v_pool, lengths, pages, layer=3, new_k=new_k,
+            new_v=new_v, **kw)
+    return fn, args
+
+
+def _paged_chunk_prefill(page=64, chunk=128, cache_len=512):
+    pages_per_slot = cache_len // page
+    pool = ((L, 8 * pages_per_slot + 1, page, HD), BF16)
+
+    def fn(q, k_pool, v_pool, starts, pages):
+        return paged_mod.paged_chunk_prefill_attention(
+            q, k_pool, v_pool, starts, pages, layer=3)
+    return fn, [((1, chunk, H, D), BF16), pool, pool, ((1,), I32),
+                ((1, pages_per_slot), I32)]
+
+
+def _mono_decode(batch=16, cache_len=1024):
+    cache = ((L, batch, cache_len, HD), BF16)
+
+    def fn(q, k_cache, v_cache, lengths, new_k, new_v):
+        return decode_mod.decode_attention(
+            q, k_cache, v_cache, lengths, layer=3, new_k=new_k, new_v=new_v)
+    return fn, [((batch, H, D), BF16), cache, cache, ((batch,), I32),
+                ((batch, H, D), BF16), ((batch, H, D), BF16)]
+
+
+CASES = {
+    "flash_fwd_bwd_s2048": _flash_fwd_bwd,
+    "paged_decode_bf16_fused_write_p64": lambda: _paged_decode(False),
+    "paged_decode_int8kv_fused_write_p64": lambda: _paged_decode(True),
+    "paged_chunk_prefill_c128_p64": _paged_chunk_prefill,
+    "mono_decode_bf16_fused_write": _mono_decode,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        f"{case}: no Mosaic kernel in the compiled program"
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 16e9, f"{case}: {total / 1e9:.1f} GB does not fit a v5e"

@@ -159,7 +159,7 @@ def test_sparse_tensor_roundtrip():
 
 def test_sparse_allreduce(eight_devices):
     import functools
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from deepspeed_tpu.runtime.sparse_tensor import SparseTensor, sparse_allreduce
     mesh = Mesh(np.array(eight_devices), ("dp",))

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.parallel.plans import PlanProgram
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 MESH_POINTS = (1, 2, 4)
 

@@ -187,15 +187,12 @@ class Accelerator(ABC):
         ...
 
     # Profiler range annotations (reference: range_push/range_pop
-    # abstract_accelerator.py:165-170 → jax.profiler traces on TPU).
+    # abstract_accelerator.py:165-170) — the one span helper's stack
+    # (utils/nvtx.py → monitor/trace.py::span).
     def range_push(self, msg):
-        import jax
-        ctx = jax.profiler.TraceAnnotation(msg)
-        ctx.__enter__()
-        self._range_stack = getattr(self, "_range_stack", [])
-        self._range_stack.append(ctx)
+        from deepspeed_tpu.utils import nvtx
+        nvtx.range_push(msg)
 
     def range_pop(self):
-        stack = getattr(self, "_range_stack", [])
-        if stack:
-            stack.pop().__exit__(None, None, None)
+        from deepspeed_tpu.utils import nvtx
+        nvtx.range_pop()

@@ -46,8 +46,9 @@ Deliberately NOT in the registry (each with its reason):
 
 import os
 import threading
-import time
 from collections import deque
+
+from deepspeed_tpu.monitor.trace import span
 
 # ---------------------------------------------------------------------- #
 # The registry — pure literals (tpu-lint parses this file statically)
@@ -211,11 +212,20 @@ class InstrumentedRLock:
     ``dstpu_serving_lock_wait_seconds`` in ``/metrics``, and the
     ``lock_wait_*`` percentiles in the ``serving_http`` bench phase).
 
+    Every non-re-entrant acquire is one ``dstpu.engine.lock_wait`` span
+    (``monitor/trace.py``: a profiler annotation always, a ring span on
+    the waiting thread's track under tracing); the span's timing is the
+    wait that is accounted.  ``last_wait_s`` is the calling thread's
+    newest wait (thread-local) — how the front end books a submit's own
+    lock wait.
+
     Accounting is mutated only AFTER a successful acquire — i.e. while
     holding the lock — so the totals need no extra synchronization.
     ``_owner_ref`` is set by the engine to a zero-arg callable returning
-    the current scheduler-owner thread (read lock-held, so it is safe
-    under ``DSTPU_CONCURRENCY_CHECKS`` too).  Delegates ``_is_owned`` /
+    the current scheduler-owner thread.  The thread class is read once,
+    BEFORE the acquire (the span carries it): a single-attribute read
+    that bypasses the ``DSTPU_CONCURRENCY_CHECKS`` hooks, racing only
+    with the owner's own bind/release.  Delegates ``_is_owned`` /
     ``_release_save`` / ``_acquire_restore`` so ``threading.Condition``
     (the engine's blocked-submit condvar) composes; a condvar re-acquire
     after ``wait()`` counts as lock wait — that IS time the thread spent
@@ -237,11 +247,24 @@ class InstrumentedRLock:
         # non-raising; exceptions are swallowed so a broken observer
         # can never poison the lock.
         self.on_wait = None
+        self._local = threading.local()
 
-    def _account(self, dt):
+    @property
+    def last_wait_s(self):
+        """The calling thread's newest non-re-entrant acquire wait."""
+        return getattr(self._local, "wait_s", 0.0)
+
+    def _wait_span(self):
         cls = ("scheduler"
                if threading.current_thread() is self._owner_ref()
                else "handler")
+        return span("dstpu.engine.lock_wait", cat="lock",
+                    track="scheduler" if cls == "scheduler" else None,
+                    thread_class=cls)
+
+    def _account(self, sp):
+        cls, dt = sp.args["thread_class"], sp.dur_s
+        self._local.wait_s = dt
         self.wait_s[cls] += dt
         self.acquires[cls] += 1
         self.samples[cls].append(dt)
@@ -259,10 +282,10 @@ class InstrumentedRLock:
             # contention, not the locked monitoring properties
             # re-entering from an already-locked caller
             return self._inner.acquire(blocking, timeout)
-        t0 = time.perf_counter()
-        ok = self._inner.acquire(blocking, timeout)
+        with self._wait_span() as sp:
+            ok = self._inner.acquire(blocking, timeout)
         if ok:
-            self._account(time.perf_counter() - t0)
+            self._account(sp)
         return ok
 
     def release(self):
@@ -284,9 +307,9 @@ class InstrumentedRLock:
         return self._inner._release_save()
 
     def _acquire_restore(self, state):
-        t0 = time.perf_counter()
-        self._inner._acquire_restore(state)
-        self._account(time.perf_counter() - t0)
+        with self._wait_span() as sp:
+            self._inner._acquire_restore(state)
+        self._account(sp)
 
 
 __all__ = ["GUARDED_FIELDS", "LOCK_ALIASES", "LOCKED_METHODS",

@@ -125,7 +125,8 @@ from deepspeed_tpu.inference.serving.concurrency import (
     InstrumentedRLock, checks_enabled, install_concurrency_checks)
 from deepspeed_tpu.inference.serving.config import ServingConfig
 from deepspeed_tpu.inference.serving.flightrec import FlightRecorder
-from deepspeed_tpu.monitor.trace import ServingHistograms, SpanTracer
+from deepspeed_tpu.monitor import trace as span_trace
+from deepspeed_tpu.monitor.trace import ServingHistograms, span
 from deepspeed_tpu.inference.serving.paging import (PagePool,
                                                     PagedPoolWorkspace,
                                                     PrefixIndex,
@@ -602,7 +603,10 @@ class ServingEngine:
         # zero-new-executables proof covers the tracing-on path too).
         self.tracing = bool(cfg.tracing)
         if self.tracing:
-            self._tracer = SpanTracer(int(cfg.trace_max_spans))  # guarded-by: _lock
+            # the process's ONE tracer (monitor/trace.py): every layer's
+            # span() mirrors into its ring, and it stays readable through
+            # monitor.trace.tracer() after this engine is closed
+            self._tracer = span_trace.enable(int(cfg.trace_max_spans))  # guarded-by: _lock
             # histograms carry their own per-bucket locks (the /metrics
             # scrape renders them WITHOUT the engine lock); the
             # InstrumentedRLock observer feeds per-acquire lock waits
@@ -729,34 +733,32 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
     @contextmanager
     def _observe_dispatch(self, program, **args):  # lock-held: _lock
-        """Record one device dispatch at its scheduler seam: a span on
-        the scheduler track + a dispatch-duration histogram sample
-        (tracing) and a ``dispatch_begin``/``dispatch_end`` (or
-        ``dispatch_error``) event pair (flight recorder).  The measured
-        duration is the HOST dispatch call — the async-dispatch cost the
-        latency-hiding protocol is built around — never a device sync.
-        No-op passthrough when both are off."""
-        tr, fr = self._tracer, self._flightrec
-        if tr is None and fr is None:
-            yield
-            return
-        t0 = tr.now() if tr is not None else time.monotonic()
+        """Record one device dispatch at its scheduler seam: the span
+        ``dstpu.sched.dispatch.<program>`` (always a profiler
+        annotation; on the ring's scheduler track under tracing), a
+        dispatch-duration histogram sample (tracing) and a
+        ``dispatch_begin``/``dispatch_end`` (or ``dispatch_error``)
+        event pair (flight recorder) — ONE timing, the span's, for all
+        three.  The measured duration is the HOST dispatch call — the
+        async-dispatch cost the latency-hiding protocol is built around
+        — never a device sync."""
+        fr = self._flightrec
         if fr is not None:
             fr.record("dispatch_begin", program=program, **args)
         try:
-            yield
+            with span("dstpu.sched.dispatch." + program, track="scheduler",
+                      cat="dispatch", program=program, **args) as sp:
+                yield
         except BaseException as e:
             if fr is not None:
                 fr.record("dispatch_error", program=program,
                           error=f"{type(e).__name__}: {e}"[:200], **args)
             raise
-        t1 = tr.now() if tr is not None else time.monotonic()
-        if tr is not None:
-            tr.add(program, "dispatch", t0, t1, track="scheduler", **args)
-            self._hist.dispatch.observe(program, t1 - t0)
+        if self._hist is not None:
+            self._hist.dispatch.observe(program, sp.dur_s)
         if fr is not None:
             fr.record("dispatch_end", program=program,
-                      dur_s=round(t1 - t0, 6), **args)
+                      dur_s=round(sp.dur_s, 6), **args)
 
     def _trace_done(self, req, status):  # lock-held: _lock
         """Terminal-time tracing: compute the request's latency
@@ -765,10 +767,14 @@ class ServingEngine:
         absorbing the remainder, so the parts always sum to
         ``latency_s`` exactly) and emit its span tree onto its slot
         track (requests that never reached a slot land on the ``queue``
-        track).  Returns ``{}`` with tracing off."""
+        track).  With tracing off only ``queue_s`` is known — submit to
+        admission start (or to the end, for a request that never left
+        the queue), two ``time.monotonic()`` stamps."""
         tr = self._tracer
         if tr is None or req.t_trace is None:
-            return {}
+            t_adm = req.t_admit_start if req.t_admit_start is not None \
+                else time.monotonic()
+            return {"queue_s": max(t_adm - req.submit_t, 0.0)}
         t_end = tr.now()
         t_sub = req.t_trace
         bd = {"latency_s": max(t_end - t_sub, 0.0)}
@@ -796,6 +802,12 @@ class ServingEngine:
                    t_end if req.t_prefill_done is None
                    else req.t_prefill_done,
                    track=track, rid=req.rid, phase="prefill")
+        if req.t_prefill_done is not None:
+            # admit dispatched -> first token PROCESSED at the drain
+            # point: the lag-one window RequestResult books as host_s
+            tr.add("first_token_lag", "phase", req.t_prefill_done,
+                   t_end if req.t_first_tok is None else req.t_first_tok,
+                   track=track, rid=req.rid, phase="first_token_lag")
         if req.t_first_tok is not None:
             tr.add("decode", "phase", req.t_first_tok, t_end,
                    track=track, rid=req.rid, phase="decode",
@@ -850,6 +862,13 @@ class ServingEngine:
             tracer = self._tracer
             snap = tracer.span_snapshot()    # (spans, added), lock-held
         return tracer.dump(path, spans=snap)
+
+    def last_lock_wait_s(self):
+        """The calling thread's newest engine-lock wait, in seconds —
+        right after ``submit()`` returns, that submit's own wait (the
+        front end books it on its ``dstpu.frontend.submit`` span).
+        Thread-local; takes no lock."""
+        return self._lock.last_wait_s
 
     def histograms(self):
         """The :class:`~deepspeed_tpu.monitor.trace.ServingHistograms`
@@ -1431,79 +1450,78 @@ class ServingEngine:
     def _step_locked(self):  # lock-held: _lock
         if self._closed:
             raise RuntimeError("step() on a closed ServingEngine")
-        t0 = time.perf_counter()
-        t0_tr = self._tracer.now() if self._tracer is not None else None
-        inject.fire("serving.sigterm_at_iter")
-        self._ensure_workspace()
-        finished = {}
-        self._shed_expired()
-        if self._breaker.enabled:
-            # breaker mode: dispatch failures are ABSORBED (the except
-            # blocks below already restored the bookkeeping and recorded
-            # ABORTED results) and counted; `threshold` consecutive ones
-            # open the breaker — no dispatches until the cooldown's
-            # half-open probe, and submit() rejects with the reason
-            was_open = self._breaker.open
-            dispatched = False
-            try:
-                if self._breaker.allow_dispatch():
-                    self._admit()
-                    dispatched = self._dispatch_decode()
-            except Exception as e:
-                self._breaker.record_failure(e)
-                if self._flightrec is not None:
-                    self._flightrec.record(
-                        "breaker_failure",
-                        consecutive=self._breaker.consecutive_failures,
-                        threshold=self._breaker.threshold,
-                        error=f"{type(e).__name__}: {e}"[:200])
-                    if self._breaker.open and not was_open:
-                        # the moment the server stops trusting its own
-                        # device: capture what led here
+        with span("dstpu.sched.step", track="scheduler", cat="scheduler",
+                  it=self._it, live_slots=int(self._mirror_active.sum()),
+                  queue_depth=len(self._queue)) as step_span:
+            inject.fire("serving.sigterm_at_iter")
+            self._ensure_workspace()
+            finished = {}
+            with span("dstpu.sched.shed", track="scheduler", cat="scheduler"):
+                self._shed_expired()
+            if self._breaker.enabled:
+                # breaker mode: dispatch failures are ABSORBED (the except
+                # blocks below already restored the bookkeeping and recorded
+                # ABORTED results) and counted; `threshold` consecutive ones
+                # open the breaker — no dispatches until the cooldown's
+                # half-open probe, and submit() rejects with the reason
+                was_open = self._breaker.open
+                dispatched = False
+                try:
+                    if self._breaker.allow_dispatch():
+                        self._admit()
+                        dispatched = self._dispatch_decode()
+                except Exception as e:
+                    self._breaker.record_failure(e)
+                    if self._flightrec is not None:
                         self._flightrec.record(
-                            "breaker_open", trips=self._breaker.trips,
-                            last_error=self._breaker.last_error[:200])
-                        self._flight_dump("breaker_open")
-                logger.warning(
-                    f"serving dispatch failure absorbed by the circuit "
-                    f"breaker ({self._breaker.consecutive_failures}"
-                    f"/{self._breaker.threshold} consecutive"
-                    f"{'; OPEN' if self._breaker.open else ''}): "
-                    f"{type(e).__name__}: {e}")
-            if was_open and not self._breaker.open \
-                    and self._flightrec is not None:
-                self._flightrec.record("breaker_close",
-                                       trips=self._breaker.trips)
-        else:
-            self._admit()
-            dispatched = self._dispatch_decode()
-        # lag-one processing: with fresh work in flight, leave the newest
-        # event unread so the device keeps running while the host
-        # does bookkeeping; once nothing new was dispatched, flush fully
-        self._process_events(finished, keep=1 if dispatched else 0)
-        # lock-contention observability: cumulative wall time threads
-        # spent WAITING on the engine lock, scheduler vs handlers
-        # (InstrumentedRLock; exported via /metrics and Serving/ events)
-        self.stats["lock_wait_scheduler_s"] = self._lock.wait_s["scheduler"]
-        self.stats["lock_wait_handler_s"] = self._lock.wait_s["handler"]
-        if self._flightrec is not None \
-                and self.stats["iterations"] % 32 == 0:
-            # periodic lock-wait sample: cheap cumulative snapshot so a
-            # dump shows whether contention grew before the distress
-            self._flightrec.record(
-                "lock_wait",
-                scheduler_s=round(self.stats["lock_wait_scheduler_s"], 6),
-                handler_s=round(self.stats["lock_wait_handler_s"], 6))
-        # interval-gated device-memory sample (serving.memory_telemetry;
-        # a clock compare between samples)
-        self._sample_memory()
-        self._emit_metrics()
-        self.stats["iterations"] += 1
-        self.stats["wall_secs"] += time.perf_counter() - t0
-        if self._tracer is not None:
-            self._tracer.add("step", "scheduler", t0_tr,
-                             self._tracer.now(), track="scheduler",
-                             it=self._it)
+                            "breaker_failure",
+                            consecutive=self._breaker.consecutive_failures,
+                            threshold=self._breaker.threshold,
+                            error=f"{type(e).__name__}: {e}"[:200])
+                        if self._breaker.open and not was_open:
+                            # the moment the server stops trusting its own
+                            # device: capture what led here
+                            self._flightrec.record(
+                                "breaker_open", trips=self._breaker.trips,
+                                last_error=self._breaker.last_error[:200])
+                            self._flight_dump("breaker_open")
+                    logger.warning(
+                        f"serving dispatch failure absorbed by the circuit "
+                        f"breaker ({self._breaker.consecutive_failures}"
+                        f"/{self._breaker.threshold} consecutive"
+                        f"{'; OPEN' if self._breaker.open else ''}): "
+                        f"{type(e).__name__}: {e}")
+                if was_open and not self._breaker.open \
+                        and self._flightrec is not None:
+                    self._flightrec.record("breaker_close",
+                                           trips=self._breaker.trips)
+            else:
+                self._admit()
+                dispatched = self._dispatch_decode()
+            # lag-one processing: with fresh work in flight, leave the newest
+            # event unread so the device keeps running while the host
+            # does bookkeeping; once nothing new was dispatched, flush fully
+            self._process_events(finished, keep=1 if dispatched else 0)
+            # lock-contention observability: cumulative wall time threads
+            # spent WAITING on the engine lock, scheduler vs handlers
+            # (InstrumentedRLock; exported via /metrics and Serving/ events)
+            self.stats["lock_wait_scheduler_s"] = self._lock.wait_s["scheduler"]
+            self.stats["lock_wait_handler_s"] = self._lock.wait_s["handler"]
+            if self._flightrec is not None \
+                    and self.stats["iterations"] % 32 == 0:
+                # periodic lock-wait sample: cheap cumulative snapshot so a
+                # dump shows whether contention grew before the distress
+                self._flightrec.record(
+                    "lock_wait",
+                    scheduler_s=round(self.stats["lock_wait_scheduler_s"], 6),
+                    handler_s=round(self.stats["lock_wait_handler_s"], 6))
+            # interval-gated device-memory sample (serving.memory_telemetry;
+            # a clock compare between samples)
+            with span("dstpu.sched.emit", track="scheduler", cat="scheduler"):
+                self._sample_memory()
+                self._emit_metrics()
+            self.stats["iterations"] += 1
+        self.stats["wall_secs"] += step_span.dur_s
         self._it += 1
         if self._pending_reports:
             finished.update(self._pending_reports)
@@ -1933,6 +1951,21 @@ class ServingEngine:
         return req
 
     def _admit(self):  # lock-held: _lock
+        """Admission under the prefill token budget, as one
+        ``dstpu.sched.admit`` span: queue pop, page allocation, prefix
+        lookup, the chunk dispatches and the fused admit dispatch."""
+        admitted0 = self.stats["admitted"]
+        tokens0 = self.stats["prefill_tokens"]
+        with span("dstpu.sched.admit", track="scheduler",
+                  cat="scheduler") as sp:
+            try:
+                self._admit_under_budget()
+            finally:
+                sp.set(admitted=self.stats["admitted"] - admitted0,
+                       prefill_tokens=self.stats["prefill_tokens"]
+                       - tokens0)
+
+    def _admit_under_budget(self):  # lock-held: _lock
         limit = self.config.prefill_token_budget or math.inf
         spent = 0
         while spent < limit:
@@ -1960,6 +1993,10 @@ class ServingEngine:
                     req.t_admit_start = self._tracer.now()
                     self._hist.queue_wait.observe(
                         req.t_admit_start - req.t_trace)
+                else:
+                    # tracing off: the one stamp RequestResult.queue_s
+                    # needs, on submit_t's clock
+                    req.t_admit_start = time.monotonic()
                 if self._flightrec is not None:
                     self._flightrec.record(
                         "admit_start", rid=req.rid, slot=req.slot,
@@ -2274,7 +2311,7 @@ class ServingEngine:
         self._events.append(("admit", req, p.slot, p.lane, first,
                              p.draft_lane))
         self.stats["admitted"] += 1
-        if self._tracer is not None and req.t_admit_start is not None:
+        if self._tracer is not None and req.t_trace is not None:
             # prefill phase ends: the fused admit is dispatched; what
             # follows until the first token is PROCESSED is the lag-one
             # host window the breakdown books as host_s
@@ -2297,7 +2334,8 @@ class ServingEngine:
             else:
                 with self._observe_dispatch(
                         "decode", phase="decode",
-                        live_slots=int(self._mirror_active.sum())):
+                        live_slots=int(self._mirror_active.sum()),
+                        kv_positions=self._block_kv_positions()):
                     if self.paged:
                         toks, self._cache, self._state = \
                             self.engine._run_guarded(
@@ -2338,6 +2376,32 @@ class ServingEngine:
             # BENCH_r04 bs128 cliff, surfaced instead of silent
             self.stats["paged_attention_fallback"] += 1
         return True
+
+    def _block_kv_positions(self):  # lock-held: _lock
+        """Positions the decode block about to be dispatched attends,
+        summed over its steps and the slots live on the DEVICE — from
+        the host mirror plus what is still in flight: a mirror-live slot
+        is ahead of ``req.tokens`` by the unprocessed decode block, and
+        a slot whose admit event is unread is live with one token.  The
+        step that produces a request's token ``i`` attends ``prompt +
+        i`` positions.  Exact unless a request stops early on eos inside
+        the unread block (then over by less than one block for that
+        slot): the bytes the paged-decode kernel must read are this
+        times the K/V bytes of one position, every layer."""
+        block = self.block
+        unread = block * sum(e[0] == "decode" for e in self._events)
+        live = [(r, len(r.tokens) + unread)
+                for s, r in enumerate(self._slots)
+                if r is not None and self._mirror_active[s]]
+        live += [(e[1], len(e[1].prefix) + 1) for e in self._events
+                 if e[0] == "admit" and e[1].status not in TERMINAL_STATUSES]
+        total = 0
+        for req, have in live:
+            steps = min(block, req.max_new - have)
+            if steps > 0:
+                first = len(req.ids) + have
+                total += steps * first + steps * (steps - 1) // 2
+        return total
 
     def _dispatch_spec(self, sub):  # lock-held: _lock
         """One speculative round, two device-chained dispatches and zero
@@ -2391,9 +2455,10 @@ class ServingEngine:
 
     def _process_admit(self, ev, finished):  # lock-held: _lock
         _, req, slot, lane, first_dev, draft_lane = ev
-        t0 = time.perf_counter()
-        first = int(np.asarray(first_dev))
-        self.stats["sync_secs"] += time.perf_counter() - t0
+        with span("dstpu.sched.wait_device", track="scheduler",
+                  cat="scheduler", event="admit", rid=req.rid) as sp:
+            first = int(np.asarray(first_dev))
+        self.stats["sync_secs"] += sp.dur_s
         self._lane_pool.give_back(lane)
         if self.speculative and draft_lane is not None:
             self._draft_lanes.give_back(draft_lane)
@@ -2464,23 +2529,23 @@ class ServingEngine:
         return False
 
     def _process_decode(self, ev, finished):  # lock-held: _lock
-        t0c = self._tracer.now() if self._tracer is not None else None
         n0 = self.stats["decode_tokens"]
-        t0 = time.perf_counter()
-        toks = np.asarray(ev[1])                         # [block, N]
-        self.stats["sync_secs"] += time.perf_counter() - t0
+        with span("dstpu.sched.wait_device", track="scheduler",
+                  cat="scheduler", event="decode") as sp:
+            toks = np.asarray(ev[1])                     # [block, N]
+        self.stats["sync_secs"] += sp.dur_s
         # mirror the in-program retirement rule step by step: an emitted
         # eos (or max_new reached) ends the request and frees its slot
-        for t in range(toks.shape[0]):
-            row = toks[t]
-            for s in np.nonzero(self._mirror_active)[0]:
-                req = self._slots[s]
-                self._mirror_commit_token(s, req, int(row[s]), finished)
-        committed = self.stats["decode_tokens"] - n0
-        if self._tracer is not None:
-            self._tracer.add("commit", "mirror", t0c, self._tracer.now(),
-                             track="scheduler", kind="decode",
-                             tokens=committed)
+        with span("dstpu.sched.commit", track="scheduler", cat="mirror",
+                  kind="decode") as sp:
+            for t in range(toks.shape[0]):
+                row = toks[t]
+                for s in np.nonzero(self._mirror_active)[0]:
+                    req = self._slots[s]
+                    self._mirror_commit_token(s, req, int(row[s]),
+                                              finished)
+            committed = self.stats["decode_tokens"] - n0
+            sp.set(tokens=committed)
         if self._flightrec is not None:
             self._flightrec.record("commit", kind="decode",
                                    tokens=committed)
@@ -2499,24 +2564,28 @@ class ServingEngine:
         dispatch — and mid-window retirement cuts the stream exactly at
         the terminal token."""
         _, toks_dev, acc_dev = ev
-        t0c = self._tracer.now() if self._tracer is not None else None
         n0 = self.stats["spec_committed_tokens"]
-        t0 = time.perf_counter()
-        toks = np.asarray(toks_dev)                      # [spec_k+1, N]
-        acc = np.asarray(acc_dev)                        # [N]
-        self.stats["sync_secs"] += time.perf_counter() - t0
+        with span("dstpu.sched.wait_device", track="scheduler",
+                  cat="scheduler", event="spec") as sp:
+            toks = np.asarray(toks_dev)                  # [spec_k+1, N]
+            acc = np.asarray(acc_dev)                    # [N]
+        self.stats["sync_secs"] += sp.dur_s
         self.stats["spec_rounds"] += 1
-        for s in np.nonzero(self._mirror_active)[0]:
-            req = self._slots[s]
-            m = int(acc[s])
-            self.stats["spec_windows"] += 1
-            self.stats["spec_committed_tokens"] += m
-            for i in range(m):
-                # by the in-program commit rule the device stopped
-                # committing at exactly the token that retires here
-                if self._mirror_commit_token(s, req, int(toks[i, s]),
-                                             finished):
-                    break
+        with span("dstpu.sched.commit", track="scheduler", cat="mirror",
+                  kind="spec") as commit_span:
+            for s in np.nonzero(self._mirror_active)[0]:
+                req = self._slots[s]
+                m = int(acc[s])
+                self.stats["spec_windows"] += 1
+                self.stats["spec_committed_tokens"] += m
+                for i in range(m):
+                    # by the in-program commit rule the device stopped
+                    # committing at exactly the token that retires here
+                    if self._mirror_commit_token(s, req, int(toks[i, s]),
+                                                 finished):
+                        break
+            commit_span.set(
+                tokens=self.stats["spec_committed_tokens"] - n0)
         # derived rates for /metrics + Serving/spec_* monitor events
         w = self.stats["spec_windows"]
         if w:
@@ -2528,14 +2597,10 @@ class ServingEngine:
         d, v = self.stats["spec_draft_secs"], self.stats["spec_verify_secs"]
         if d + v > 0:
             self.stats["spec_draft_fraction"] = d / (d + v)
-        committed = self.stats["spec_committed_tokens"] - n0
-        if self._tracer is not None:
-            self._tracer.add("commit", "mirror", t0c, self._tracer.now(),
-                             track="scheduler", kind="spec",
-                             tokens=committed)
         if self._flightrec is not None:
-            self._flightrec.record("commit", kind="spec",
-                                   tokens=committed)
+            self._flightrec.record(
+                "commit", kind="spec",
+                tokens=self.stats["spec_committed_tokens"] - n0)
         self.occupancy_trace.append(
             (self._it, int(self._mirror_active.sum())))
 

@@ -83,6 +83,7 @@ priorities ride the snapshot), and every active stream ends with a typed
 
 import asyncio
 import json
+from contextlib import nullcontext
 import signal
 import threading
 import time
@@ -92,6 +93,7 @@ import numpy as np
 from deepspeed_tpu.inference.serving.slo import (CircuitOpen, QueueFull,
                                                  RequestStatus,
                                                  TERMINAL_STATUSES)
+from deepspeed_tpu.monitor.trace import now as span_now, span
 from deepspeed_tpu.tools.lint.hotpath import hot_path
 from deepspeed_tpu.utils.logging import logger
 
@@ -271,7 +273,9 @@ class ServingHTTPFrontend:
                 if srv.work_pending():   # one lock round-trip, not three
                     srv.step()
                 else:
-                    srv.wake.wait(timeout=self.idle_poll_s)
+                    with span("dstpu.sched.idle", track="scheduler",
+                              cat="scheduler"):
+                        srv.wake.wait(timeout=self.idle_poll_s)
                     srv.wake.clear()
         except Exception as e:           # noqa: BLE001 — surfaced via healthz
             self._sched_error = f"{type(e).__name__}: {e}"
@@ -412,6 +416,7 @@ class ServingHTTPFrontend:
             head = await reader.readuntil(b"\r\n\r\n")
         except asyncio.IncompleteReadError:
             return None                  # clean EOF between requests
+        t_head = span_now()              # where dstpu.frontend.parse starts
         lines = head.decode("latin-1").split("\r\n")
         try:
             method, path, _version = lines[0].split(" ", 2)
@@ -434,7 +439,7 @@ class ServingHTTPFrontend:
                                   f"{self.max_body_bytes}-byte limit")
         body = await reader.readexactly(n) if n else b""
         return {"method": method.upper(), "path": path,
-                "headers": headers, "body": body}
+                "headers": headers, "body": body, "t_head": t_head}
 
     @staticmethod
     def _head(code, ctype, extra=""):
@@ -509,17 +514,27 @@ class ServingHTTPFrontend:
                                   f"accepted: {sorted(known)}")
         return spec
 
-    def _submit_from_spec(self, spec):
+    def _submit_from_spec(self, spec, t_handoff=None):
         """Engine submit with the HTTP error mapping (runs in an
-        executor thread: queue_policy='block' may wait here)."""
+        executor thread: queue_policy='block' may wait here).  One
+        ``dstpu.frontend.submit`` span: the annotation covers this
+        thread's part; the ring span starts at ``t_handoff`` — the loop
+        thread's stamp when it handed the request to the executor — so
+        executor queueing is inside it, and it records the request's
+        ``rid`` and ``lock_wait_s``, this submit's own engine-lock
+        wait."""
         try:
-            return self.srv.submit(
-                np.asarray(spec["input_ids"], np.int32),
-                max_new_tokens=int(spec.get("max_new_tokens", 32)),
-                eos_token_id=int(spec.get("eos_token_id", -1)),
-                deadline_s=spec.get("deadline_s"),
-                client_id=spec.get("client_id"),
-                priority=int(spec.get("priority", 0)))
+            with span("dstpu.frontend.submit", cat="frontend",
+                      start=t_handoff) as sp:
+                rid = self.srv.submit(
+                    np.asarray(spec["input_ids"], np.int32),
+                    max_new_tokens=int(spec.get("max_new_tokens", 32)),
+                    eos_token_id=int(spec.get("eos_token_id", -1)),
+                    deadline_s=spec.get("deadline_s"),
+                    client_id=spec.get("client_id"),
+                    priority=int(spec.get("priority", 0)))
+                sp.set(rid=rid, lock_wait_s=self.srv.last_lock_wait_s())
+                return rid
         except QueueFull as e:           # over quota / full queue
             raise _HTTPError(429, str(e))
         except CircuitOpen as e:
@@ -528,6 +543,12 @@ class ServingHTTPFrontend:
             raise _HTTPError(400, str(e))
         except RuntimeError as e:        # closed engine
             raise _HTTPError(503, str(e))
+
+    def _subscribe(self, rid, on_event):
+        """``token_events`` on an executor thread (it takes the engine
+        lock), as one ``dstpu.frontend.subscribe`` span."""
+        with span("dstpu.frontend.subscribe", cat="frontend", rid=rid):
+            return self.srv.token_events(rid, on_event)
 
     def _result_payload(self, rid):
         res = self.srv.result(rid)
@@ -542,11 +563,17 @@ class ServingHTTPFrontend:
                 "client_id": res.client_id}
 
     async def _generate(self, req, writer):
-        spec = self._parse_generate(req["body"])
+        # the ring span reaches back to the request head's arrival, so
+        # the awaited body read is inside it; the annotation covers the
+        # synchronous parse (an await inside an annotation would let
+        # other connections' spans interleave on the loop thread)
+        with span("dstpu.frontend.parse", cat="frontend",
+                  start=req.get("t_head"), bytes=len(req["body"])):
+            spec = self._parse_generate(req["body"])
         loop = asyncio.get_running_loop()
         if not spec.get("stream"):
             rid = await loop.run_in_executor(
-                None, self._submit_from_spec, spec)
+                None, self._submit_from_spec, spec, span_now())
             done = asyncio.Event()
 
             def on_ev(ev, _loop=loop, _done=done):
@@ -556,8 +583,7 @@ class ServingHTTPFrontend:
 
             # engine calls take the engine lock, which the scheduler
             # thread holds across step() — keep them off the loop thread
-            await loop.run_in_executor(
-                None, self.srv.token_events, rid, on_ev)
+            await loop.run_in_executor(None, self._subscribe, rid, on_ev)
             await done.wait()
             payload = await loop.run_in_executor(
                 None, self._result_payload, rid)
@@ -566,14 +592,14 @@ class ServingHTTPFrontend:
         # between submit and subscription (token_events replays anyway —
         # this just keeps the replay empty in the common case)
         rid = await loop.run_in_executor(
-            None, self._submit_from_spec, spec)
+            None, self._submit_from_spec, spec, span_now())
         q = asyncio.Queue()
 
         def on_ev(ev, _loop=loop, _q=q):
             _loop.call_soon_threadsafe(_q.put_nowait, ev)
 
-        await loop.run_in_executor(
-            None, self.srv.token_events, rid, on_ev)
+        await loop.run_in_executor(None, self._subscribe, rid, on_ev)
+        traced = bool(getattr(self.srv, "tracing", False))
         writer.write(
             self._head(200, "application/x-ndjson",
                        "Transfer-Encoding: chunked\r\n"
@@ -583,9 +609,12 @@ class ServingHTTPFrontend:
             while True:
                 ev = await q.get()
                 line = (json.dumps(ev) + "\n").encode()
-                writer.write(f"{len(line):x}\r\n".encode() + line
-                             + b"\r\n")
-                await writer.drain()     # flush per token event
+                # a span per token event: under serving.tracing only
+                with span("dstpu.frontend.write", cat="frontend",
+                          rid=rid) if traced else nullcontext():
+                    writer.write(f"{len(line):x}\r\n".encode() + line
+                                 + b"\r\n")
+                    await writer.drain()     # flush per token event
                 if ev.get("event") == "end":
                     break
             writer.write(b"0\r\n\r\n")

@@ -2,10 +2,19 @@
 serving stack (``docs/observability.md``) — the per-request half of the
 monitor layer the reference framework ships as ``deepspeed/monitor/``.
 
-Two primitives, both pure host bookkeeping (zero jitted programs, zero
+Three primitives, all pure host bookkeeping (zero jitted programs, zero
 device syncs — the overhead contract the serving engine's
 zero-new-executables proof extends over them):
 
+* :func:`span` — THE way to mark host work, in every layer.  A context
+  manager that always enters a ``jax.profiler.TraceAnnotation`` (with a
+  profiler session open the span lands in the ``.xplane.pb`` on the
+  device trace's own clock, its keyword arguments as event stats; with
+  none it is a flag test) and, when the process tracer is on
+  (:func:`enable`; ``serving.tracing`` turns it on), also appends the
+  span to the :class:`SpanTracer` ring.  Names are the fixed literal
+  set ``dstpu.<layer>.<what>`` tabled in ``docs/observability.md`` —
+  never an id or a size in a name; those are args.
 * :class:`SpanTracer` — a bounded ring of finished spans recorded at the
   serving scheduler's existing seams (submit → queue wait → prefill
   chunks → admit dispatch → decode / spec-propose / spec-verify
@@ -35,6 +44,8 @@ import json
 import threading
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 # Default span-ring bound: ~7 spans per request-lifetime plus 1-3 per
 # dispatch; 100k spans ≈ tens of MB and hours of light traffic.
@@ -187,9 +198,10 @@ class SpanTracer:
     ``add`` records one complete span (``t1=None`` = instant event);
     timestamps come from :meth:`now` — the injectable monotonic clock —
     and the wall-clock epoch of the tracer's construction anchors the
-    export.  The caller provides external synchronization for ``add``
-    (the serving engine records lock-held); ``to_chrome``/``dump`` take
-    a point-in-time copy."""
+    export.  ``add`` and ``span_snapshot`` are safe from any thread
+    (a tiny lock of the ring's own, never the engine lock: front-end
+    threads record here too); ``to_chrome``/``dump`` take a
+    point-in-time copy."""
 
     def __init__(self, max_spans=DEFAULT_MAX_SPANS, clock=time.monotonic,
                  wallclock=time.time):
@@ -197,6 +209,7 @@ class SpanTracer:
         self._t0 = clock()               # monotonic epoch
         self.wall_t0 = wallclock()       # wall-clock anchor of _t0
         self._spans = deque(maxlen=int(max_spans))
+        self._ring_lock = threading.Lock()
         self.added = 0                   # total, incl. ring-dropped
 
     def now(self):
@@ -208,26 +221,26 @@ class SpanTracer:
         id int or a named thread track), with ``args`` attached
         (rid/client_id/slot/priority/phase...).  ``None`` args are
         dropped so exports stay compact."""
-        self.added += 1
-        self._spans.append(
-            (name, cat, float(t0),
-             None if t1 is None else float(t1), track,
-             {k: v for k, v in args.items() if v is not None}))
+        rec = (name, cat, float(t0), None if t1 is None else float(t1),
+               track, {k: v for k, v in args.items() if v is not None})
+        with self._ring_lock:
+            self.added += 1
+            self._spans.append(rec)
 
     @property
     def dropped(self):
         return self.added - len(self._spans)
 
     def span_snapshot(self):
-        """A point-in-time ``(spans, added)`` copy of the span ring —
-        take it under whatever lock guards ``add`` (the serving
-        engine's), then render/serialize OUTSIDE it:
+        """A point-in-time ``(spans, added)`` copy of the span ring;
+        render/serialize it OUTSIDE any lock:
         :meth:`to_chrome`/:meth:`dump` on a 100k-span ring build tens
         of MB of JSON, far too long to stall the scheduler for.  The
         paired ``added`` counter keeps the export's ``dropped`` figure
         consistent with the copy: spans recorded AFTER the snapshot
         must not read as ring-dropped."""
-        return list(self._spans), self.added
+        with self._ring_lock:
+            return list(self._spans), self.added
 
     def to_chrome(self, spans=None):
         """The Chrome trace-event JSON object (``{"traceEvents": [...]}``
@@ -235,9 +248,8 @@ class SpanTracer:
         track (scheduler / queue / handler threads, then one per slot),
         ``"X"`` complete events in microseconds, ``"M"`` thread-name
         metadata, and the wall-clock anchor under ``otherData``.
-        ``spans``: a :meth:`span_snapshot` tuple taken lock-held;
-        ``None`` copies the live ring (single-threaded callers
-        only)."""
+        ``spans``: a :meth:`span_snapshot` tuple; ``None`` copies the
+        live ring."""
         spans, added = self.span_snapshot() if spans is None else spans
         tids, events = {}, []
 
@@ -284,7 +296,91 @@ class SpanTracer:
         return path
 
 
-__all__ = ["SpanTracer", "Histogram", "HistogramFamily",
+# ---------------------------------------------------------------------- #
+# The process's one tracer, and the one span helper
+# ---------------------------------------------------------------------- #
+_TRACER = None                           # None = the ring is off
+
+
+def enable(max_spans=DEFAULT_MAX_SPANS, **clocks):
+    """Turn the span ring on: install a FRESH :class:`SpanTracer` as the
+    process's one tracer and return it.  The serving engine calls this
+    under ``serving.tracing``; the tracer stays installed — and
+    readable through :func:`tracer` — after that engine is closed."""
+    global _TRACER
+    _TRACER = SpanTracer(max_spans, **clocks)
+    return _TRACER
+
+
+def disable():
+    """Turn the ring off and drop it (:func:`tracer` gives ``None``)."""
+    global _TRACER
+    _TRACER = None
+
+
+def tracer():
+    """The process's tracer, or ``None`` while the ring is off."""
+    return _TRACER
+
+
+def now():
+    """The clock spans are stamped on: the tracer's (injectable) clock
+    while the ring is on, else ``time.monotonic``."""
+    tr = _TRACER
+    return tr.now() if tr is not None else time.monotonic()
+
+
+class span:
+    """``with span("dstpu.sched.step", it=3):`` — one host span, on the
+    profiler's clock always and in the ring when it is on (module
+    docstring).  ``track`` is the ring's track (default: the calling
+    thread's name, so nested spans nest by thread); ``args`` become the
+    annotation's stats and the ring span's args (``None`` values are
+    dropped).  :meth:`set` adds args learned inside the span.  ``t0`` /
+    ``t1`` / ``dur_s`` are stamped on the tracer's clock when the ring
+    is on, else ``time.monotonic`` — ONE timing a seam can hand on to
+    a histogram or the flight recorder.  ``start`` (a :func:`now`
+    stamp made earlier, e.g. on another thread at a hand-off) moves
+    ``t0`` — and so the ring span's start — back to that stamp; the
+    annotation still covers only the ``with`` block."""
+
+    __slots__ = ("name", "track", "cat", "args", "t0", "t1", "_ann", "_tr")
+
+    def __init__(self, name, track=None, cat="span", start=None, **args):
+        self.name, self.track, self.cat = name, track, cat
+        self.args = {k: v for k, v in args.items() if v is not None}
+        self.t0, self.t1 = start, None
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        tr = self._tr = _TRACER          # one tracer, one clock, per span
+        if self.t0 is None:
+            self.t0 = tr.now() if tr is not None else time.monotonic()
+        return self
+
+    def set(self, **args):
+        args = {k: v for k, v in args.items() if v is not None}
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    @property
+    def dur_s(self):
+        return self.t1 - self.t0
+
+    def __exit__(self, *exc):
+        tr = self._tr
+        self.t1 = tr.now() if tr is not None else time.monotonic()
+        self._ann.__exit__(*exc)
+        if tr is not None:
+            tr.add(self.name, self.cat, self.t0, self.t1,
+                   track=self.track if self.track is not None
+                   else threading.current_thread().name, **self.args)
+        return False
+
+
+__all__ = ["SpanTracer", "span", "now", "tracer", "enable", "disable",
+           "Histogram", "HistogramFamily",
            "ServingHistograms", "LATENCY_BUCKETS_S",
            "LOCK_WAIT_BUCKETS_S", "HISTOGRAM_SERIES",
            "DEFAULT_MAX_SPANS"]

@@ -200,6 +200,7 @@ def _sparse_fwd_pallas(q, k, v, idx, counts, scale, causal, block):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         interpret=_interpret(),
+        name="attn.block_sparse_fwd",
     )(jnp.asarray(idx), jnp.asarray(counts), q, k, v)
 
 

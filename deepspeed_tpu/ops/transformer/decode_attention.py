@@ -467,6 +467,7 @@ def chunk_prefill_attention(q, k_cache, v_cache, starts, scale=None,
                 4 * block_k * KVHD * q.dtype.itemsize
                 + 2 * C * H * D * 4 + 16 * 1024 * 1024)),
         interpret=_interpret(),
+        name="attn.chunk_prefill",
     )(jnp.asarray(starts, jnp.int32), layer_arr, *operands)
     return out.reshape(B, C, H, D)
 
@@ -658,5 +659,6 @@ def decode_attention(q, k_cache, v_cache, lengths,
                 96 * 1024 * 1024,
                 6 * block_k * KVHD * q.dtype.itemsize + 16 * 1024 * 1024)),
         interpret=_interpret(),
+        name="attn.decode",
     )(jnp.asarray(lengths, jnp.int32), layer_arr, *operands)
     return res

@@ -189,6 +189,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="attn.flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -395,6 +396,7 @@ def _bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd, res, do):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="attn.flash_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv computed per (b, h) then reduced over the query-head group for GQA
@@ -437,6 +439,7 @@ def _bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd, res, do):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="attn.flash_dkv",
     )(q, k, v, do, lse, delta)
 
     if KVH != H:

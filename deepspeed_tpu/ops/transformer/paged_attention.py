@@ -230,6 +230,7 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
                 96 * 1024 * 1024,
                 6 * page * KVHD * q.dtype.itemsize + 16 * 1024 * 1024)),
         interpret=_interpret(),
+        name="attn.paged_decode",
     )(jnp.asarray(lengths, jnp.int32), layer_arr, pages_arr, *operands)
     return res
 
@@ -312,5 +313,6 @@ def paged_chunk_prefill_attention(q, k_pool, v_pool, starts, pages, *,
                 4 * page * KVHD * q.dtype.itemsize
                 + 2 * C * H * D * 4 + 16 * 1024 * 1024)),
         interpret=_interpret(),
+        name="attn.paged_chunk_prefill",
     )(jnp.asarray(starts, jnp.int32), layer_arr, pages_arr, *operands)
     return out.reshape(B, C, H, D)

@@ -31,6 +31,7 @@ Delete the cache directory to reclaim space — both layers rebuild on the
 next cold run.
 """
 
+import collections
 import contextlib
 import hashlib
 import json
@@ -81,10 +82,31 @@ def default_cache_dir():
 # --------------------------------------------------------------------- #
 # Cache-hit accounting (process-global; read deltas, not absolutes)
 # --------------------------------------------------------------------- #
+# JAX's compile-phase duration events -> the CacheStats sum each feeds
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_seconds",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_seconds",
+}
+COMPILE_EVENTS_KEPT = 8192               # newest (t, event, seconds) kept
+COMPILE_EVENT_MIN_SECS = 1e-3            # shorter ones are only summed
+
+
 class CacheStats:
     """Counters for both cache layers.  ``persistent_*`` come from JAX's
     monitoring events (the on-disk XLA cache); ``executable_*`` from the
-    framework's :class:`ExecutableStore`."""
+    framework's :class:`ExecutableStore`.  ``trace_seconds`` /
+    ``lower_seconds`` / ``backend_compile_seconds`` sum JAX's own
+    compile-phase durations (Python tracing to a jaxpr — a jit traced
+    inside another's trace counted once —, lowering to MLIR, the
+    backend compile — a persistent-cache hit's load counts there too)
+    over every jit in the process since the first engine configured
+    the cache; ``compile_events`` keeps
+    the newest :data:`COMPILE_EVENTS_KEPT` of at least a millisecond
+    (JAX reports thousands of microsecond-long cached traces) as
+    ``(t_monotonic, event, seconds)`` — the seconds each ADDED to its
+    sum, so a reader sums a stretch of the list and needs no nesting
+    rule of its own."""
 
     def __init__(self):
         self.persistent_requests = 0     # compiles that consulted the cache
@@ -96,11 +118,16 @@ class CacheStats:
         self.executable_errors = 0
         self.aot_fallbacks = 0           # AOT compiles that raised (→ plain jit)
         self.compile_seconds: Dict[str, float] = {}  # tag -> last compile time
+        self.trace_seconds = 0.0
+        self.lower_seconds = 0.0
+        self.backend_compile_seconds = 0.0
+        self.compile_events = collections.deque(maxlen=COMPILE_EVENTS_KEPT)
 
     def snapshot(self):
         d = {k: v for k, v in self.__dict__.items()
              if isinstance(v, (int, float))}
         d["compile_seconds"] = dict(self.compile_seconds)
+        d["compile_events"] = list(self.compile_events)
         return d
 
 
@@ -121,12 +148,40 @@ def _on_jax_event(event, **kwargs):
         _STATS.persistent_hits += 1
 
 
+_open_traces = []                        # (start, seconds) already summed
+_OPEN_TRACES_KEPT = 1 << 16
+
+
+def _on_jax_duration(event, duration_secs, **kwargs):
+    field = _COMPILE_PHASES.get(event)
+    if field is None:
+        return
+    t = time.monotonic()
+    add = duration_secs
+    if field == "trace_seconds":
+        # a jit traced inside another's trace reports first and lies
+        # inside the outer's duration: count that time once
+        start = t - duration_secs
+        while _open_traces and _open_traces[-1][0] >= start:
+            add -= _open_traces.pop()[1]
+        _open_traces.append((start, duration_secs))
+        if len(_open_traces) > 2 * _OPEN_TRACES_KEPT:
+            # top-level traces are never popped; the siblings inside one
+            # unrolled 24-layer trace run to thousands, and all of them
+            # must still be here when their outer trace reports
+            del _open_traces[:-_OPEN_TRACES_KEPT]
+    setattr(_STATS, field, getattr(_STATS, field) + add)
+    if duration_secs >= COMPILE_EVENT_MIN_SECS:
+        _STATS.compile_events.append((t, event, add))
+
+
 def _register_jax_listener():
     global _listener_registered
     if _listener_registered:
         return
     from jax import monitoring
     monitoring.register_event_listener(_on_jax_event)
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
     _listener_registered = True
 
 

@@ -44,6 +44,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from deepspeed_tpu import comm as dist
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.monitor.monitor import MonitorMaster
+from deepspeed_tpu.monitor.trace import span
 from deepspeed_tpu.parallel import topology as topo_mod
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.checkpoint_engine.checkpoint_engine import OrbaxCheckpointEngine
@@ -1198,36 +1199,40 @@ class DeepSpeedEngine:
             self.step()
             return self._last_loss
         self._lazy_init((jax.tree.map(lambda x: x[0], batch),), {})
-        batch = self._curriculum_slice(batch, 2)
-        self._maybe_start_profiler(jax.tree.map(lambda x: x[0], batch))
-        batch = jax.tree.map(
-            lambda x: jax.device_put(  # tpu-lint: disable=TL011 -- host->device batch admission for the fused step: one placement of the host batch into [gas, dp, ...] layout per train_batch, by design
-                jnp.asarray(x),
-                NamedSharding(self.mesh, P(None, *(self._data_sharding(x.ndim - 1).spec)))),
-            batch)
+        step = self.global_steps + 1
+        with span("dstpu.train.batch", step=step):
+            batch = self._curriculum_slice(batch, 2)
+            self._maybe_start_profiler(jax.tree.map(lambda x: x[0], batch))
+            batch = jax.tree.map(
+                lambda x: jax.device_put(  # tpu-lint: disable=TL011 -- host->device batch admission for the fused step: one placement of the host batch into [gas, dp, ...] layout per train_batch, by design
+                    jnp.asarray(x),
+                    NamedSharding(self.mesh, P(None, *(self._data_sharding(x.ndim - 1).spec)))),
+                batch)
         self.tput_timer.start()
-        lr = jnp.asarray(self.get_lr()[0], jnp.float32)
-        step_no = jnp.asarray(self.global_steps + 1, jnp.int32)
-        args = (self._params, self._opt_state, self._scaler_state,
-                lr, step_no, self._rng, batch)
-        (self._params, self._opt_state, self._scaler_state, loss, gnorm) = \
-            self._run_fused_step(args)
-        self._last_global_grad_norm = gnorm
-        self._last_loss = loss
-        self.global_steps += 1
-        self.micro_steps += gas
-        self.global_samples += self.train_batch_size()
-        if self.lr_scheduler is not None:
-            self.lr_scheduler.step()
-        self.tput_timer.stop(global_step=True)
-        self._maybe_finish_profiler()
-        if self.monitor.enabled and self.global_steps % self.steps_per_print() == 0:
-            # same Train/Samples series the 3-call path emits — fetching the
-            # loss here syncs, but only every steps_per_print steps
-            self.monitor.write_events(
-                [("Train/Samples/lr", self.get_lr()[0], self.global_samples),
-                 ("Train/Samples/train_loss", float(jax.device_get(loss)),  # tpu-lint: disable=TL001 -- monitor read, gated on steps_per_print
-                  self.global_samples)] + self._hbm_events())
+        with span("dstpu.train.dispatch", step=step):
+            lr = jnp.asarray(self.get_lr()[0], jnp.float32)
+            step_no = jnp.asarray(step, jnp.int32)
+            args = (self._params, self._opt_state, self._scaler_state,
+                    lr, step_no, self._rng, batch)
+            (self._params, self._opt_state, self._scaler_state, loss,
+             gnorm) = self._run_fused_step(args)
+        with span("dstpu.train.post", step=step):
+            self._last_global_grad_norm = gnorm
+            self._last_loss = loss
+            self.global_steps += 1
+            self.micro_steps += gas
+            self.global_samples += self.train_batch_size()
+            if self.lr_scheduler is not None:
+                self.lr_scheduler.step()
+            self.tput_timer.stop(global_step=True)
+            self._maybe_finish_profiler()
+            if self.monitor.enabled and self.global_steps % self.steps_per_print() == 0:
+                # same Train/Samples series the 3-call path emits — fetching
+                # the loss here syncs, but only every steps_per_print steps
+                self.monitor.write_events(
+                    [("Train/Samples/lr", self.get_lr()[0], self.global_samples),
+                     ("Train/Samples/train_loss", float(jax.device_get(loss)),  # tpu-lint: disable=TL001 -- monitor read, gated on steps_per_print
+                      self.global_samples)] + self._hbm_events())
         return loss
 
     def _hbm_events(self):

@@ -1,12 +1,14 @@
 """Profiler range annotation — reference ``deepspeed/utils/nvtx.py``
 (``instrument_w_nvtx`` wrapping hot functions in NVTX ranges).
 
-TPU analog: ``jax.profiler.TraceAnnotation`` ranges show up in the XLA/xprof
-trace exactly where NVTX ranges show up in nsys."""
+TPU analog: the reference's names, routed through the one span helper
+(``monitor/trace.py::span``) — a ``jax.profiler.TraceAnnotation`` range in
+the XLA/xprof trace exactly where NVTX ranges show up in nsys, and a span
+in the process tracer's ring when that is on."""
 
 import functools
 
-import jax
+from deepspeed_tpu.monitor.trace import span
 
 
 def instrument_w_nvtx(func):
@@ -14,7 +16,7 @@ def instrument_w_nvtx(func):
 
     @functools.wraps(func)
     def wrapped(*args, **kwargs):
-        with jax.profiler.TraceAnnotation(func.__qualname__):
+        with span(func.__qualname__):
             return func(*args, **kwargs)
 
     return wrapped
@@ -22,9 +24,7 @@ def instrument_w_nvtx(func):
 
 def range_push(name):
     """Imperative range open (reference ``accelerator.range_push``)."""
-    ann = jax.profiler.TraceAnnotation(name)
-    ann.__enter__()
-    _stack.append(ann)
+    _stack.append(span(name).__enter__())
 
 
 def range_pop():

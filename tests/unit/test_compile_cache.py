@@ -376,3 +376,36 @@ def test_repro_donated_dus_chain_through_executable_serialization(tmp_path):
     warm_outs, warm_big = drive(warm_admit, warm_decode)
     np.testing.assert_array_equal(warm_outs, ref_outs)
     np.testing.assert_array_equal(warm_big, ref_big)
+
+
+def test_compile_phase_sums_count_nested_traces_once(monkeypatch):
+    """JAX reports a jit traced inside another's trace first, and its
+    time lies inside the outer's: ``trace_seconds`` counts it once —
+    also when one outer trace holds thousands of inner ones (24
+    unrolled layers do) — and ``compile_events`` keeps what each event
+    ADDED, so a reader only sums."""
+    from deepspeed_tpu.runtime import compile_cache as cc
+    T = "/jax/core/compile/jaxpr_trace_duration"
+    B = "/jax/core/compile/backend_compile_duration"
+    clock = [1000.0]
+    monkeypatch.setattr(cc.time, "monotonic", lambda: clock[0])
+    monkeypatch.setattr(cc, "_STATS", cc.CacheStats())
+    monkeypatch.setattr(cc, "_open_traces", [])
+    for i in range(3000):                 # siblings of 10 ms, back to back
+        clock[0] = 1000.0 + 0.01 * (i + 1)
+        cc._on_jax_duration(T, 0.01)
+    clock[0] = 1031.0                     # their outer trace: 1000-1031
+    cc._on_jax_duration(T, 31.0)
+    cc._on_jax_duration(B, 7.0)
+    cc._on_jax_duration("/jax/some/other_duration", 99.0)
+    clock[0] = 1040.0                     # a later top-level trace
+    cc._on_jax_duration(T, 2.0)
+    snap = cc.stats().snapshot()
+    assert snap["trace_seconds"] == pytest.approx(33.0)
+    assert snap["backend_compile_seconds"] == 7.0
+    assert snap["lower_seconds"] == 0.0
+    kept = snap["compile_events"]
+    assert len(kept) == 3003 and kept[3000] == (1031.0, T, pytest.approx(1.0))
+    assert sum(e[2] for e in kept if e[1] == T) == pytest.approx(33.0)
+    cc._on_jax_duration(T, 0.0005)        # under a millisecond: summed only
+    assert len(cc.stats().compile_events) == 3003

@@ -1,0 +1,94 @@
+"""Every ``pl.pallas_call`` of the package carries a stable ``name=``: jax
+wraps a named call in ``named_scope(name)``, so the name is in the lowered
+program's locations (interpret mode here; on the chip it becomes the
+``tpu_custom_call`` instruction's name, which the benchmark's trace readers
+and ``breakdown.device_ops`` tell the kernels apart by)."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.ops.sparse_attention import block_sparse
+from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
+                                           flash_attention as flash_mod,
+                                           paged_attention as paged_mod)
+
+H, D, L = 2, 64, 2
+HD = H * D
+F32, I32 = jnp.float32, jnp.int32
+
+
+def _flash(grad):
+    def loss(q, k, v):
+        return flash_mod.flash_attention(q, k, v, causal=True).sum()
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else loss
+    return fn, [((1, 256, H, D), F32)] * 3
+
+
+def _paged_decode():
+    pool = ((L, 5, 64, HD), F32)
+
+    def fn(q, k_pool, v_pool, lengths, pages):
+        return paged_mod.paged_decode_attention(q, k_pool, v_pool, lengths,
+                                                pages, layer=1)
+    return fn, [((2, H, D), F32), pool, pool, ((2,), I32), ((2, 2), I32)]
+
+
+def _paged_chunk():
+    pool = ((L, 5, 64, HD), F32)
+
+    def fn(q, k_pool, v_pool, starts, pages):
+        return paged_mod.paged_chunk_prefill_attention(
+            q, k_pool, v_pool, starts, pages, layer=1)
+    return fn, [((1, 64, H, D), F32), pool, pool, ((1,), I32), ((1, 2), I32)]
+
+
+def _mono_chunk():
+    cache = ((L, 1, 256, HD), F32)
+
+    def fn(q, k_cache, v_cache, starts):
+        return decode_mod.chunk_prefill_attention(q, k_cache, v_cache, starts,
+                                                  layer=1)
+    return fn, [((1, 64, H, D), F32), cache, cache, ((1,), I32)]
+
+
+def _mono_decode():
+    cache = ((L, 2, 256, HD), F32)
+
+    def fn(q, k_cache, v_cache, lengths):
+        return decode_mod.decode_attention(q, k_cache, v_cache, lengths,
+                                           layer=1)
+    return fn, [((2, H, D), F32), cache, cache, ((2,), I32)]
+
+
+def _block_sparse():
+    layout = np.tril(np.ones((1, 2, 2), np.int32))
+
+    def fn(q, k, v):
+        return block_sparse.block_sparse_attention(q, k, v, layout, 128,
+                                                   causal=True)
+    return fn, [((1, 256, H, D), F32)] * 3
+
+
+CASES = {
+    "attn.paged_decode": _paged_decode,
+    "attn.paged_chunk_prefill": _paged_chunk,
+    "attn.flash_fwd": lambda: _flash(False),
+    "attn.flash_dq": lambda: _flash(True),
+    "attn.flash_dkv": lambda: _flash(True),
+    "attn.chunk_prefill": _mono_chunk,
+    "attn.decode": _mono_decode,
+    "attn.block_sparse_fwd": _block_sparse,
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_name_is_in_the_lowered_location(name):
+    fn, shapes = CASES[name]()
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    # a transform may wrap the scope: "transpose(jvp(attn.flash_dq))"
+    assert any(name + end in text for end in ("/", ")", '"')), \
+        f"no location names {name}"

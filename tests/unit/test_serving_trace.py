@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 import deepspeed_tpu
 from deepspeed_tpu.inference.serving.slo import DrainTimeout
+from deepspeed_tpu.monitor import trace as span_trace
 from deepspeed_tpu.models.transformer import Transformer, TransformerConfig
 
 SERVING = {"enabled": True, "num_slots": 3, "max_cache_len": 64,
@@ -83,6 +84,7 @@ def test_tracing_off_on_bitwise_zero_new_execs_spans_breakdown(
     rng = np.random.default_rng(7)
     prompts, news = _workload(rng)
 
+    span_trace.disable()
     srv_off = eng.serve()
     n_aot_0 = len(eng._aot)
     rids = [srv_off.submit(p, max_new_tokens=n)
@@ -91,9 +93,13 @@ def test_tracing_off_on_bitwise_zero_new_execs_spans_breakdown(
     execs_off = len(eng._aot) - n_aot_0
     assert srv_off.histograms() is None
     assert srv_off.flightrec_snapshot() is None
-    # tracing off: breakdown fields stay None (seed behavior)
+    # tracing off: only queue_s (two monotonic stamps) is known
     res_off = srv_off.result(rids[0])
-    assert res_off.queue_s is None and res_off.latency_s is None
+    assert res_off.queue_s is not None and res_off.latency_s is None
+    assert 0 <= res_off.queue_s <= res_off.ttft_s
+    # every seam's span() ran (as a profiler annotation) and the ring
+    # stayed off: the process tracer holds nothing
+    assert span_trace.tracer() is None
     with pytest.raises(RuntimeError, match="serving.tracing is off"):
         srv_off.dump_trace(str(tmp_path / "no.json"))
     with pytest.raises(RuntimeError, match="flight_recorder is off"):
@@ -119,6 +125,9 @@ def test_tracing_off_on_bitwise_zero_new_execs_spans_breakdown(
         np.testing.assert_array_equal(
             outs_off[r_off], outs_on[r_on],
             err_msg="tracing changed serving outputs")
+
+    # the engine's tracer IS the process's one tracer
+    assert span_trace.tracer() is srv._tracer
 
     # ---- latency breakdown sums exactly to the measured wall total
     for rid in rids_on:
@@ -151,10 +160,37 @@ def test_tracing_off_on_bitwise_zero_new_execs_spans_breakdown(
         if e.get("ph") == "X" and "rid" in a:
             by_rid.setdefault(a["rid"], set()).add(e["name"])
     for rid in rids_on:
-        assert {"request", "queue", "prefill", "decode"} <= by_rid[rid], \
-            (rid, by_rid.get(rid))
+        assert {"request", "queue", "prefill", "first_token_lag",
+                "decode"} <= by_rid[rid], (rid, by_rid.get(rid))
+    # the first_token_lag phase span IS RequestResult.host_s
+    lag = {e["args"]["rid"]: e["dur"] for e in evs
+           if e["name"] == "first_token_lag"}
+    for rid in rids_on:
+        assert abs(lag[rid] * 1e-6 - srv.result(rid).host_s) < 1e-5
+    # ---- the scheduler's seams, all through the one span helper
+    names = {e["name"] for e in evs}
+    assert {"dstpu.sched.step", "dstpu.sched.shed", "dstpu.sched.admit",
+            "dstpu.sched.dispatch.prefill_chunk",
+            "dstpu.sched.dispatch.admit", "dstpu.sched.dispatch.decode",
+            "dstpu.sched.wait_device", "dstpu.sched.commit",
+            "dstpu.sched.emit", "dstpu.engine.lock_wait"} <= names, names
+    assert not {"step", "commit"} & names     # no hand-rolled twin left
+    steps = [e for e in evs if e["name"] == "dstpu.sched.step"]
+    assert [e["args"]["it"] for e in steps] == list(range(len(steps)))
+    assert all({"live_slots", "queue_depth"} <= set(e["args"])
+               for e in steps)
+    admits = [e for e in evs if e["name"] == "dstpu.sched.admit"]
+    assert sum(e["args"]["admitted"] for e in admits) == len(rids_on)
+    assert sum(e["args"]["prefill_tokens"] for e in admits) \
+        == srv.stats["prefill_tokens"]
+    # kv_positions is EXACT (no eos here): the step that makes a
+    # request's token i attends prompt + i positions
+    decodes = [e for e in evs if e["name"] == "dstpu.sched.dispatch.decode"]
+    want = sum(len(p) + i for p, n in zip(prompts, news)
+               for i in range(1, n))
+    assert sum(e["args"]["kv_positions"] for e in decodes) == want
     # commit markers carry tokens-committed counts at the mirror drain
-    commits = [e for e in evs if e["name"] == "commit"]
+    commits = [e for e in evs if e["name"] == "dstpu.sched.commit"]
     assert commits and all("tokens" in e["args"] for e in commits)
     assert sum(e["args"]["tokens"] for e in commits) \
         == srv.stats["decode_tokens"]
@@ -173,6 +209,127 @@ def test_tracing_off_on_bitwise_zero_new_execs_spans_breakdown(
     assert {"submit", "admit_start", "dispatch_begin", "dispatch_end",
             "commit", "terminal"} <= kinds, kinds
     srv.close()
+    # the ring outlives its engine: readers run after close
+    assert span_trace.tracer() is srv._tracer
+    assert span_trace.tracer().span_snapshot()[1] > 0
+    span_trace.disable()
+
+
+# --------------------------------------------------------------------- #
+# The one span helper
+# --------------------------------------------------------------------- #
+def test_span_nests_mirrors_only_when_on_and_is_thread_safe():
+    span_trace.disable()
+    with span_trace.span("dstpu.test.outer", n=1) as sp:
+        pass
+    assert span_trace.tracer() is None and sp.dur_s >= 0
+    tr = span_trace.enable()
+    assert span_trace.tracer() is tr
+
+    def work():
+        for i in range(500):
+            with span_trace.span("dstpu.test.outer", i=i, skipped=None):
+                with span_trace.span("dstpu.test.inner") as inner:
+                    inner.set(found=i)
+
+    threads = [threading.Thread(target=work, name=f"w{k}") for k in (0, 1)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    spans, added = tr.span_snapshot()
+    assert added == len(spans) == 2000 and tr.dropped == 0
+    for track in ("w0", "w1"):
+        mine = [s for s in spans if s[4] == track]
+        outer = [s for s in mine if s[0] == "dstpu.test.outer"]
+        inner = [s for s in mine if s[0] == "dstpu.test.inner"]
+        assert len(outer) == len(inner) == 500
+        # each inner span closes first and lies within its outer span
+        for o, i in zip(outer, inner):
+            assert o[2] <= i[2] <= i[3] <= o[3]
+            assert i[5] == {"found": o[5]["i"]} and "skipped" not in o[5]
+    # a hand-off stamp moves the ring span's start back
+    t_handoff = span_trace.now()
+    time.sleep(0.01)
+    with span_trace.span("dstpu.test.handoff", start=t_handoff) as sp:
+        pass
+    assert sp.dur_s >= 0.01 and tr.span_snapshot()[0][-1][2] == t_handoff
+    span_trace.disable()
+    with span_trace.span("dstpu.test.outer"):
+        pass
+    assert tr.span_snapshot()[1] == 2001     # nothing after disable()
+
+
+def test_nvtx_names_route_through_span():
+    from deepspeed_tpu.accelerator import get_accelerator
+    from deepspeed_tpu.utils.nvtx import (instrument_w_nvtx, range_pop,
+                                          range_push)
+    tr = span_trace.enable()
+
+    @instrument_w_nvtx
+    def hot(x):
+        return x + 1
+
+    assert hot(1) == 2
+    range_push("region")
+    get_accelerator().range_push("inner")
+    get_accelerator().range_pop()
+    range_pop()
+    range_pop()                              # empty stack: a no-op
+    names = [s[0] for s in tr.span_snapshot()[0]]
+    assert names[0].endswith("hot") and names[1:] == ["inner", "region"]
+    span_trace.disable()
+
+
+def test_profiler_sees_scheduler_steps_with_their_children(
+        shared_engine, tmp_path):
+    """On the profiler's clock: a server stepped under
+    ``jax.profiler.start_trace`` leaves ``dstpu.sched.step`` events that
+    contain their ``wait_device`` children on the same thread, with the
+    decode dispatch's ``kv_positions`` as an event stat — tracing off,
+    so the annotations alone carry it."""
+    from jax.profiler import ProfileData
+    import glob
+    eng = shared_engine
+    rng = np.random.default_rng(43)
+    prompts, _ = _workload(rng, n=2)
+    span_trace.disable()
+    srv = eng.serve()
+    srv.submit(prompts[0], max_new_tokens=3)
+    srv.drain()                              # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        srv.submit(prompts[1], max_new_tokens=6)
+        srv.drain()
+    finally:
+        jax.profiler.stop_trace()
+    srv.close()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    by_line = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)) for e in line.events
+                   if e.name.startswith("dstpu.")]
+            if evs:
+                by_line.append(evs)
+    assert len(by_line) == 1                 # one scheduler thread
+    evs = by_line[0]
+    steps = [e for e in evs if e[0] == "dstpu.sched.step"]
+    waits = [e for e in evs if e[0] == "dstpu.sched.wait_device"]
+    assert steps and waits
+    assert [e[3]["it"] for e in steps] == sorted(e[3]["it"] for e in steps)
+    for w in waits:
+        assert sum(s[1] <= w[1] and w[2] <= s[2] for s in steps) == 1, w
+    assert {w[3]["event"] for w in waits} == {"admit", "decode"}
+    decodes = [e for e in evs if e[0] == "dstpu.sched.dispatch.decode"]
+    P = len(prompts[1])
+    assert sum(e[3]["kv_positions"] for e in decodes) \
+        == sum(P + i for i in range(1, 6))
+    assert all(e[3]["program"] == "decode" for e in decodes)
+    assert span_trace.tracer() is None
 
 
 # --------------------------------------------------------------------- #
@@ -376,6 +533,16 @@ def test_metrics_round_trip_histograms_escaping_and_gating(
                  "client_id": NASTY_CLIENT if k == 0 else "plain"}))
             assert conn.getresponse().status == 200
             conn.close()
+        # one streamed request: a dstpu.frontend.write span per event
+        conn = http.client.HTTPConnection("127.0.0.1", fe.port,
+                                          timeout=180)
+        conn.request("POST", "/v1/generate", json.dumps(
+            {"input_ids": [int(t) for t in prompts[0]],
+             "max_new_tokens": 3, "stream": True}))
+        streamed = [json.loads(ln) for ln in
+                    conn.getresponse().read().decode().splitlines()]
+        conn.close()
+        stream_rid = streamed[-1]["rid"]
         conn = http.client.HTTPConnection("127.0.0.1", fe.port,
                                           timeout=60)
         conn.request("GET", "/metrics")
@@ -390,6 +557,25 @@ def test_metrics_round_trip_histograms_escaping_and_gating(
         status, b = _get(fe.port, "/debug/profile?secs=1", "POST")
         assert status == 404 and b"profiling endpoint disabled" in b
     srv.close()
+
+    # ---- the front end's way in, span by span (the ring, after close)
+    ring = span_trace.tracer().span_snapshot()[0]
+    submits = [s for s in ring if s[0] == "dstpu.frontend.submit"]
+    assert sorted(s[5]["rid"] for s in submits) == [0, 1, 2]
+    assert all(0 <= s[5]["lock_wait_s"] <= s[3] - s[2] for s in submits)
+    for name in ("dstpu.frontend.parse", "dstpu.frontend.subscribe"):
+        assert sum(s[0] == name for s in ring) == 3, name
+    subs = {s[5]["rid"] for s in ring if s[0] == "dstpu.frontend.subscribe"}
+    assert subs == {0, 1, 2}
+    writes = [s for s in ring if s[0] == "dstpu.frontend.write"]
+    assert len(writes) == len(streamed) \
+        and {s[5]["rid"] for s in writes} == {stream_rid}
+    # front-end spans sit on their own threads' tracks, never the
+    # scheduler's
+    assert all(s[4] != "scheduler" for s in submits + writes)
+    assert any(s[0] == "dstpu.sched.idle" and s[4] == "scheduler"
+               for s in ring)
+    span_trace.disable()
 
     types, helps, samples = parse_prometheus(body)
     # exposition correctness: every sample belongs to a family with
@@ -432,7 +618,7 @@ def test_metrics_round_trip_histograms_escaping_and_gating(
     # TTFT histogram actually measured the run
     ttft_count = [v for n, la, v in samples
                   if n == "dstpu_serving_ttft_seconds_count"]
-    assert ttft_count == [float(len(prompts))], ttft_count
+    assert ttft_count == [float(len(prompts) + 1)], ttft_count  # + stream
 
 
 # --------------------------------------------------------------------- #

@@ -2335,7 +2335,7 @@ class ServingEngine:
                 with self._observe_dispatch(
                         "decode", phase="decode",
                         live_slots=int(self._mirror_active.sum()),
-                        kv_positions=self._block_kv_positions()):
+                        **self._block_kv_work()):
                     if self.paged:
                         toks, self._cache, self._state = \
                             self.engine._run_guarded(
@@ -2377,17 +2377,24 @@ class ServingEngine:
             self.stats["paged_attention_fallback"] += 1
         return True
 
-    def _block_kv_positions(self):  # lock-held: _lock
-        """Positions the decode block about to be dispatched attends,
-        summed over its steps and the slots live on the DEVICE — from
-        the host mirror plus what is still in flight: a mirror-live slot
-        is ahead of ``req.tokens`` by the unprocessed decode block, and
-        a slot whose admit event is unread is live with one token.  The
-        step that produces a request's token ``i`` attends ``prompt +
-        i`` positions.  Exact unless a request stops early on eos inside
+    def _block_kv_work(self):  # lock-held: _lock
+        """What the decode block about to be dispatched attends, as the
+        dispatch span's args.  ``kv_positions``: positions, summed over
+        its steps and the slots live on the DEVICE — from the host
+        mirror plus what is still in flight: a mirror-live slot is ahead
+        of ``req.tokens`` by the unprocessed decode block, and a slot
+        whose admit event is unread is live with one token.  The step
+        that produces a request's token ``i`` attends ``prompt + i``
+        positions.  Exact unless a request stops early on eos inside
         the unread block (then over by less than one block for that
         slot): the bytes the paged-decode kernel must read are this
-        times the K/V bytes of one position, every layer."""
+        times the K/V bytes of one position, every layer.  A paged
+        engine adds ``kv_pages`` — the pages those steps walk,
+        ``ceil(context / page_size)`` a live slot and step, which is
+        the paged-decode kernel's page loop — and ``kv_pages_table``,
+        the slots x pages-a-slot x steps a walk over the whole table
+        would take: their ratio is the share of the table that is
+        live."""
         block = self.block
         unread = block * sum(e[0] == "decode" for e in self._events)
         live = [(r, len(r.tokens) + unread)
@@ -2395,13 +2402,20 @@ class ServingEngine:
                 if r is not None and self._mirror_active[s]]
         live += [(e[1], len(e[1].prefix) + 1) for e in self._events
                  if e[0] == "admit" and e[1].status not in TERMINAL_STATUSES]
-        total = 0
+        positions = pages = 0
         for req, have in live:
             steps = min(block, req.max_new - have)
             if steps > 0:
                 first = len(req.ids) + have
-                total += steps * first + steps * (steps - 1) // 2
-        return total
+                positions += steps * first + steps * (steps - 1) // 2
+                if self.paged:
+                    pages += sum(-(-(first + i) // self.page)
+                                 for i in range(steps))
+        if not self.paged:
+            return {"kv_positions": positions}
+        return {"kv_positions": positions, "kv_pages": pages,
+                "kv_pages_table":
+                    self.num_slots * self.n_slot_pages * block}
 
     def _dispatch_spec(self, sub):  # lock-held: _lock
         """One speculative round, two device-chained dispatches and zero

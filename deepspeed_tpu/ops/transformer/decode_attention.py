@@ -33,10 +33,18 @@ The KV length mask (cache tail + causality for a single new token collapse
 to ``pos < length``) is applied per block, and blocks entirely past the
 live cache region are skipped: their block index is pinned to the last live
 block (Mosaic elides the repeated DMA) and their compute is pl.when-gated.
+
+Single-token decode is one algorithm under two iteration maps: the row
+state and the per-block update (``_init_row`` / ``_block_update`` /
+``_finish_row`` / ``_write_stripe``) are shared, the drivers are separate —
+``_decode_kernel`` here walks a contiguous cache on a ``(B, nk)`` grid;
+``paged_attention._paged_decode_kernel`` walks a page table with an
+in-kernel loop over the row's live pages.
 """
 
 import functools
 import os as _os
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -51,69 +59,262 @@ from deepspeed_tpu.ops.transformer.flash_attention import (LSE_LANES, NEG_INF,
 DEFAULT_BLOCK_K_DECODE = int(_os.environ.get("DSTPU_DECODE_BLOCK_K", "512"))
 
 
+def _new_rows(kn_ref, vn_ref, quant):
+    """This step's K/V rows, quantized the same way the cache stores
+    them (payload+scale when ``quant``), plus the DEQUANTIZED values
+    this step's attention must see — write-then-read parity with the
+    unfused path."""
+    kn = kn_ref[0].astype(jnp.float32)                   # [KVH, D]
+    vn = vn_ref[0].astype(jnp.float32)
+    if not quant:
+        return kn, vn, kn, vn, None, None
+    ks_n = jnp.max(jnp.abs(kn), axis=1, keepdims=True) / 127.0
+    vs_n = jnp.max(jnp.abs(vn), axis=1, keepdims=True) / 127.0
+    ks_safe = jnp.where(ks_n == 0.0, 1.0, ks_n)
+    vs_safe = jnp.where(vs_n == 0.0, 1.0, vs_n)
+    kq = jnp.clip(jnp.round(kn / ks_safe), -127, 127)
+    vq = jnp.clip(jnp.round(vn / vs_safe), -127, 127)
+    return kq, vq, kq * ks_safe, vq * vs_safe, ks_safe, vs_safe
+
+
+def _expand_scales(st, g):
+    # [bk, KVH] per-(position, kv-head) scales → [H, bk]: row r of the
+    # block-diagonal Q belongs to kv head r // g, so its score column j
+    # dequantizes by scales[j, r // g].  Only this [bk, KVH]-sized tile
+    # is ever transposed — the KV slabs stay in their DMA layout.
+    st = st.astype(jnp.float32).T                        # [KVH, bk]
+    if g == 1:
+        return st
+    return jnp.repeat(st, g, axis=0)                     # [H, bk]
+
+
+class _RowState(NamedTuple):
+    """One batch row's online-softmax state plus the per-row inputs the
+    per-block update reads: the refs both decode drivers (the grid walk
+    over a contiguous cache here, the in-kernel page loop of
+    ``paged_attention``) hand to :func:`_init_row`,
+    :func:`_block_update`, :func:`_finish_row` and :func:`_write_stripe`.
+    ``qs_scr`` is present only in the int8-MXU variant, ``kn_ref`` /
+    ``vn_ref`` only with the fused write."""
+    q_ref: Any
+    m_scr: Any
+    l_scr: Any
+    acc_scr: Any
+    qbd_scr: Any
+    qs_scr: Any = None
+    kn_ref: Any = None
+    vn_ref: Any = None
+
+
+def _init_row(st, *, kvh, g, d):
+    m_scr, l_scr, acc_scr, qbd_scr = (st.m_scr, st.l_scr, st.acc_scr,
+                                      st.qbd_scr)
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    # build the block-diagonal Q once per batch row
+    qbd_scr[:] = jnp.zeros_like(qbd_scr)
+    q = st.q_ref[0]                                      # [H, D]
+    if st.qs_scr is not None:
+        # quantize q per head so the score matmul runs int8×int8 on
+        # the MXU — the [bk, KVH*D] slabs then never get cast
+        qf = q.astype(jnp.float32)
+        qs = jnp.max(jnp.abs(qf), axis=1, keepdims=True) / 127.0
+        qs = jnp.where(qs == 0.0, 1.0, qs)
+        st.qs_scr[:] = jnp.broadcast_to(qs, st.qs_scr.shape)
+        q = jnp.clip(jnp.round(qf / qs), -127, 127)
+    for h in range(kvh):
+        qbd_scr[h * g:(h + 1) * g, h * d:(h + 1) * d] = \
+            q[h * g:(h + 1) * g].astype(qbd_scr.dtype)
+
+
+def _block_update(st, ik, length, k, v, ks, vs, *, scale, block_k, kvh, g,
+                  d, window):
+    """Fold KV block ``ik`` of one row into its online-softmax state.
+    ``k``/``v``: the block's [bk, KVH*D] slabs as loaded; ``ks``/``vs``:
+    its [bk, KVH] dequant scales (None for an unquantized cache).  The
+    one per-block update of single-token decode, whichever driver
+    supplies the block sequence."""
+    m_scr, l_scr, acc_scr, qbd_scr = (st.m_scr, st.l_scr, st.acc_scr,
+                                      st.qbd_scr)
+    quant = ks is not None
+    mxu_int8 = st.qs_scr is not None
+    fused_write = st.kn_ref is not None
+    if quant and not mxu_int8:
+        # int8 payloads: cast for the MXU; the per-entry scale applies
+        # to SCORES (k) and to P (v) — never to the big slabs, so no
+        # [bk, KVH*D]-sized reshape/relayout happens in-kernel
+        k = k.astype(qbd_scr.dtype)
+        v = v.astype(qbd_scr.dtype)
+    # all heads' scores in ONE matmul (see module docstring)
+    if mxu_int8:
+        # int8×int8 MXU path: the slabs go to the matmul untouched
+        s = jax.lax.dot_general(
+            qbd_scr[:], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32).astype(jnp.float32)
+        s = s * (st.qs_scr[:, 0:1] * scale)
+    else:
+        s = jax.lax.dot_general(
+            qbd_scr[:], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+    if quant:
+        s = s * _expand_scales(ks, g)
+    pos = ik * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_k), 1)                      # [1, bk]
+    live = pos < length                                  # cache tail mask
+    if window is not None:
+        # sliding-window decode (mistral-style): the single query sits
+        # at position length-1, so the live window is
+        # [length - window, length)
+        live = jnp.logical_and(live, pos >= length - window)
+    if fused_write:
+        # the cache does NOT yet hold this step's token: its column
+        # (global position length-1, which only occurs in this — the
+        # last live — block) is recomputed from the fresh row and
+        # substituted into the score tile.  Dequantized values keep
+        # write-then-read parity with the unfused path.
+        _, _, kn_used, vn_used, _, _ = _new_rows(st.kn_ref, st.vn_ref,
+                                                 quant)
+        kn_rep = kn_used if g == 1 else jnp.repeat(kn_used, g, axis=0)
+        q_f32 = st.q_ref[0].astype(jnp.float32)          # [H, D]
+        col = jnp.sum(q_f32 * kn_rep, axis=1,
+                      keepdims=True) * scale             # [H, 1]
+        sel_col = (pos == length - 1)                    # [1, bk]
+        s = jnp.where(sel_col, col, s)
+    s = jnp.where(live, s, NEG_INF)                      # [H, bk]
+    m_prev = m_scr[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    p = jnp.where(live, p, 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[:] = jnp.broadcast_to(
+        l_scr[:, 0:1] * corr + jnp.sum(p, axis=1, keepdims=True),
+        l_scr.shape)
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    pv = p * _expand_scales(vs, g) if quant else p
+    if fused_write:
+        # the V slab's row at the write column is stale too: zero that
+        # probability column for the big PV matmul and add its rank-1
+        # contribution from the fresh (dequantized) V row per head.
+        # p_col comes from the RAW probabilities — the fresh row's
+        # scale is already folded into vn_used, the slab's stale
+        # v-scale must not touch it.
+        p_col = jnp.sum(jnp.where(sel_col, p, 0.0), axis=1,
+                        keepdims=True)                   # [H, 1]
+        pv = jnp.where(sel_col, 0.0, pv)
+    if mxu_int8:
+        # fold the v-scale into P, then quantize P per row: the PV
+        # matmul also runs int8×int8 with a per-row rescale after
+        rmax = jnp.max(pv, axis=1, keepdims=True) / 127.0
+        rsafe = jnp.where(rmax == 0.0, 1.0, rmax)
+        pv_i8 = jnp.clip(jnp.round(pv / rsafe), -127, 127) \
+            .astype(jnp.int8)
+        o_flat = jax.lax.dot_general(
+            pv_i8, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32).astype(jnp.float32)
+        o_flat = o_flat * rmax
+    else:
+        o_flat = jax.lax.dot_general(pv.astype(v.dtype), v,
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+    # accumulate each head's D-column diagonal block of [H, KVH*D]
+    for h in range(kvh):
+        rows = slice(h * g, (h + 1) * g)
+        contrib = o_flat[rows, h * d:(h + 1) * d]
+        if fused_write:
+            contrib = contrib + p_col[rows] * vn_used[h:h + 1]
+        acc_scr[rows] = acc_scr[rows] * corr[rows] + contrib
+
+
+def _finish_row(st, o_ref):
+    l = st.l_scr[:, 0:1]
+    safe_l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (st.acc_scr[:] / safe_l).astype(o_ref.dtype)
+
+
+def _write_stripe(st, length, block_k, load8, ko, vo, kso, vso, *, kvh, d):
+    """The fused cache write: this step's row goes out through the
+    ALIASED, 8-ROW-STRIPE outputs ``ko``/``vo`` (and ``kso``/``vso``
+    for a quantized cache) — [8, ...] views of the output blocks, which
+    cover only the 8-sublane-aligned stripe containing the write row
+    (pinned by index map), so per step the flush is 8 rows — not a
+    whole block (a full-block write-back measured ~1.8x on the whole
+    decode step at bs64).  The stripe's other 7 rows are merged from
+    the raw input block (loaded for scores anyway): ``load8(base)``
+    returns its rows ``base .. base+8`` as ``(k, v, k_scale,
+    v_scale)``; Mosaic accepts the dynamic 8-aligned ref read.
+    Clamp: a zero-length row (invalid input — lengths INCLUDE this
+    step's token, so the minimum is 1) would compute
+    row = (-1) % block_k = block_k-1 and merge the slab's FAR stripe
+    into the pinned rows 0-7 of the output (the output index map clamps
+    to stripe 0), silently corrupting the cache head.  Clamped,
+    length=0 degenerates to the benign length=1 write at row 0."""
+    quant = kso is not None
+    row = jnp.maximum(length - 1, 0) % block_k
+    base = (row // 8) * 8
+    off = row - base
+    sel = jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0) == off  # [8, 1]
+    kq, vq, _, _, ks_n, vs_n = _new_rows(st.kn_ref, st.vn_ref, quant)
+    kraw8, vraw8, ks_raw8, vs_raw8 = load8(base)         # [8, KVH*D] raw
+    # per-kv-head merges: Mosaic cannot shape-cast a computed
+    # [KVH, D] f32 tile to [1, KVH*D], so each head's D-column
+    # stripe merges separately
+    for hk in range(kvh):
+        cols = slice(hk * d, (hk + 1) * d)
+        km = jnp.where(sel, kq[hk:hk + 1],
+                       kraw8[:, cols].astype(jnp.float32))
+        vm = jnp.where(sel, vq[hk:hk + 1],
+                       vraw8[:, cols].astype(jnp.float32))
+        ko[:, cols] = km.astype(ko.dtype)
+        vo[:, cols] = vm.astype(vo.dtype)
+    if quant:
+        ksm = ks_raw8.astype(jnp.float32)                # [8, KVH]
+        vsm = vs_raw8.astype(jnp.float32)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, kvh), 1)
+        for hk in range(kvh):
+            m = jnp.logical_and(sel, lane == hk)         # [8, KVH]
+            ksm = jnp.where(m, ks_n[hk, 0], ksm)
+            vsm = jnp.where(m, vs_n[hk, 0], vsm)
+        kso[...] = ksm.astype(kso.dtype)
+        vso[...] = vsm.astype(vso.dtype)
+
+
 def _decode_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
                    scale, block_k, nk, kvh, g, d, stacked, quant, window,
                    mxu_int8, fused_write=False):
+    """The grid-walk driver: grid ``(B, nk)`` over a contiguous cache,
+    the block location resolved by the BlockSpec index maps."""
+    ks_ref = vs_ref = kn_ref = vn_ref = qs_scr = None
+    ko_ref = vo_ref = kso_ref = vso_ref = None
+    rest = list(rest)
+    if quant:
+        ks_ref, vs_ref = rest[:2]
+        del rest[:2]
     if fused_write:
         # in-kernel cache write (see decode_attention new_k/new_v): the
         # new token's raw K/V rows ride extra inputs and the caches come
         # BACK as aliased outputs pinned at each row's write block
+        kn_ref, vn_ref = rest[:2]
+        del rest[:2]
+    o_ref = rest.pop(0)
+    if fused_write:
+        ko_ref, vo_ref = rest[:2]
+        del rest[:2]
         if quant:
-            (ks_ref, vs_ref, kn_ref, vn_ref, o_ref, ko_ref, vo_ref,
-             kso_ref, vso_ref, m_scr, l_scr, acc_scr, qbd_scr) = rest
-            qs_scr = None
-        else:
-            ks_ref = vs_ref = kso_ref = vso_ref = qs_scr = None
-            (kn_ref, vn_ref, o_ref, ko_ref, vo_ref,
-             m_scr, l_scr, acc_scr, qbd_scr) = rest
-    elif quant and mxu_int8:
-        (ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, qbd_scr,
-         qs_scr) = rest
-    elif quant:
-        (ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, qbd_scr) = rest
-        qs_scr = None
-    else:
-        ks_ref = vs_ref = qs_scr = None
-        (o_ref, m_scr, l_scr, acc_scr, qbd_scr) = rest
-
-    def _new_rows():
-        """This step's K/V rows, quantized the same way the cache stores
-        them (payload+scale when ``quant``), plus the DEQUANTIZED values
-        this step's attention must see — write-then-read parity with the
-        unfused path."""
-        kn = kn_ref[0].astype(jnp.float32)               # [KVH, D]
-        vn = vn_ref[0].astype(jnp.float32)
-        if not quant:
-            return kn, vn, kn, vn, None, None
-        ks_n = jnp.max(jnp.abs(kn), axis=1, keepdims=True) / 127.0
-        vs_n = jnp.max(jnp.abs(vn), axis=1, keepdims=True) / 127.0
-        ks_safe = jnp.where(ks_n == 0.0, 1.0, ks_n)
-        vs_safe = jnp.where(vs_n == 0.0, 1.0, vs_n)
-        kq = jnp.clip(jnp.round(kn / ks_safe), -127, 127)
-        vq = jnp.clip(jnp.round(vn / vs_safe), -127, 127)
-        return kq, vq, kq * ks_safe, vq * vs_safe, ks_safe, vs_safe
+            kso_ref, vso_ref = rest[:2]
+            del rest[:2]
+    m_scr, l_scr, acc_scr, qbd_scr = rest[:4]
+    if mxu_int8:
+        qs_scr = rest[4]
+    st = _RowState(q_ref, m_scr, l_scr, acc_scr, qbd_scr, qs_scr,
+                   kn_ref, vn_ref)
+    lead = (0, 0) if stacked else (0,)       # this cell's block
     b = pl.program_id(0)
     ik = pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        # build the block-diagonal Q once per batch row
-        qbd_scr[:] = jnp.zeros_like(qbd_scr)
-        q = q_ref[0]                                     # [H, D]
-        if mxu_int8:
-            # quantize q per head so the score matmul runs int8×int8 on
-            # the MXU — the [bk, KVH*D] slabs then never get cast
-            qf = q.astype(jnp.float32)
-            qs = jnp.max(jnp.abs(qf), axis=1, keepdims=True) / 127.0
-            qs = jnp.where(qs == 0.0, 1.0, qs)
-            qs_scr[:] = jnp.broadcast_to(qs, qs_scr.shape)
-            q = jnp.clip(jnp.round(qf / qs), -127, 127)
-        for h in range(kvh):
-            qbd_scr[h * g:(h + 1) * g, h * d:(h + 1) * d] = \
-                q[h * g:(h + 1) * g].astype(qbd_scr.dtype)
+        _init_row(st, kvh=kvh, g=g, d=d)
 
     length = len_ref[b]
     run = ik * block_k < length
@@ -122,178 +323,31 @@ def _decode_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
         # elided by the matching index-map pin) — decode cost is O(window)
         run = jnp.logical_and(run, (ik + 1) * block_k > length - window)
 
-    def _expand_scales(s_ref):
-        # [bk, KVH] per-(position, kv-head) scales → [H, bk]: row r of the
-        # block-diagonal Q belongs to kv head r // g, so its score column j
-        # dequantizes by scales[j, r // g].  Only this [bk, KVH]-sized tile
-        # is ever transposed — the KV slabs stay in their DMA layout.
-        st = (s_ref[0, 0] if stacked else s_ref[0]).astype(jnp.float32)
-        st = st.T                                        # [KVH, bk]
-        if g == 1:
-            return st
-        return jnp.repeat(st, g, axis=0)                 # [H, bk]
-
     # skip KV blocks entirely past the live cache region (and, with a
     # window, entirely before it)
     @pl.when(run)
     def _body():
-        k = k_ref[0, 0] if stacked else k_ref[0]         # [bk, KVH*D]
-        v = v_ref[0, 0] if stacked else v_ref[0]
-        if quant and not mxu_int8:
-            # int8 payloads: cast for the MXU; the per-entry scale applies
-            # to SCORES (k) and to P (v) — never to the big slabs, so no
-            # [bk, KVH*D]-sized reshape/relayout happens in-kernel
-            k = k.astype(qbd_scr.dtype)
-            v = v.astype(qbd_scr.dtype)
-        # all heads' scores in ONE matmul (see module docstring)
-        if mxu_int8:
-            # int8×int8 MXU path: the slabs go to the matmul untouched
-            s = jax.lax.dot_general(
-                qbd_scr[:], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32).astype(jnp.float32)
-            s = s * (qs_scr[:, 0:1] * scale)
-        else:
-            s = jax.lax.dot_general(
-                qbd_scr[:], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-        if quant:
-            s = s * _expand_scales(ks_ref)
-        pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)                  # [1, bk]
-        live = pos < length                              # cache tail mask
-        if window is not None:
-            # sliding-window decode (mistral-style): the single query sits
-            # at position length-1, so the live window is
-            # [length - window, length)
-            live = jnp.logical_and(live, pos >= length - window)
-        if fused_write:
-            # the cache does NOT yet hold this step's token: its column
-            # (global position length-1, which only occurs in this — the
-            # last live — block) is recomputed from the fresh row and
-            # substituted into the score tile.  Dequantized values keep
-            # write-then-read parity with the unfused path.
-            _, _, kn_used, vn_used, _, _ = _new_rows()
-            kn_rep = kn_used if g == 1 else jnp.repeat(kn_used, g, axis=0)
-            q_f32 = q_ref[0].astype(jnp.float32)         # [H, D]
-            col = jnp.sum(q_f32 * kn_rep, axis=1,
-                          keepdims=True) * scale         # [H, 1]
-            sel_col = (pos == length - 1)                # [1, bk]
-            s = jnp.where(sel_col, col, s)
-        s = jnp.where(live, s, NEG_INF)                  # [H, bk]
-        m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(live, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, 0:1] * corr + jnp.sum(p, axis=1, keepdims=True),
-            l_scr.shape)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        pv = p * _expand_scales(vs_ref) if quant else p
-        if fused_write:
-            # the V slab's row at the write column is stale too: zero that
-            # probability column for the big PV matmul and add its rank-1
-            # contribution from the fresh (dequantized) V row per head.
-            # p_col comes from the RAW probabilities — the fresh row's
-            # scale is already folded into vn_used, the slab's stale
-            # v-scale must not touch it.
-            p_col = jnp.sum(jnp.where(sel_col, p, 0.0), axis=1,
-                            keepdims=True)               # [H, 1]
-            pv = jnp.where(sel_col, 0.0, pv)
-        if mxu_int8:
-            # fold the v-scale into P, then quantize P per row: the PV
-            # matmul also runs int8×int8 with a per-row rescale after
-            rmax = jnp.max(pv, axis=1, keepdims=True) / 127.0
-            rsafe = jnp.where(rmax == 0.0, 1.0, rmax)
-            pv_i8 = jnp.clip(jnp.round(pv / rsafe), -127, 127) \
-                .astype(jnp.int8)
-            o_flat = jax.lax.dot_general(
-                pv_i8, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32).astype(jnp.float32)
-            o_flat = o_flat * rmax
-        else:
-            o_flat = jax.lax.dot_general(pv.astype(v.dtype), v,
-                                         (((1,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        # accumulate each head's D-column diagonal block of [H, KVH*D]
-        for h in range(kvh):
-            rows = slice(h * g, (h + 1) * g)
-            contrib = o_flat[rows, h * d:(h + 1) * d]
-            if fused_write:
-                contrib = contrib + p_col[rows] * vn_used[h:h + 1]
-            acc_scr[rows] = acc_scr[rows] * corr[rows] + contrib
+        _block_update(st, ik, length, k_ref[lead], v_ref[lead],
+                      ks_ref[lead] if quant else None,
+                      vs_ref[lead] if quant else None,
+                      scale=scale, block_k=block_k, kvh=kvh, g=g, d=d,
+                      window=window)
 
     @pl.when(ik == nk - 1)
     def _finish():
-        l = l_scr[:, 0:1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+        _finish_row(st, o_ref)
         if fused_write:
-            # write this step's row into the cache via the ALIASED,
-            # 8-ROW-STRIPE outputs: the output blocks cover only the
-            # 8-sublane-aligned stripe containing the write row (pinned
-            # by index map), so per step the flush is 8 rows — not a
-            # whole block (a full-block write-back measured ~1.8x on the
-            # whole decode step at bs64).  The stripe's other 7 rows are
-            # merged from the raw input slab (loaded for scores anyway);
-            # Mosaic accepts the dynamic 8-aligned ref read.
-            # Clamp: a zero-length row (invalid input — lengths INCLUDE
-            # this step's token, so the minimum is 1) would compute
-            # row = (-1) % block_k = block_k-1 and merge the slab's FAR
-            # stripe into the pinned rows 0-7 of the output (the output
-            # index map clamps to stripe 0), silently corrupting the
-            # cache head.  Clamped, length=0 degenerates to the benign
-            # length=1 write at row 0.
-            row = jnp.maximum(length - 1, 0) % block_k
-            base = (row // 8) * 8
-            off = row - base
-            sel = jax.lax.broadcasted_iota(
-                jnp.int32, (8, 1), 0) == off             # [8, 1]
-            kq, vq, _, _, ks_n, vs_n = _new_rows()
-            if stacked:
-                kraw8 = k_ref[0, 0, pl.dslice(base, 8)]  # [8, KVH*D] raw
-                vraw8 = v_ref[0, 0, pl.dslice(base, 8)]
-            else:
-                kraw8 = k_ref[0, pl.dslice(base, 8)]
-                vraw8 = v_ref[0, pl.dslice(base, 8)]
-            # per-kv-head merges: Mosaic cannot shape-cast a computed
-            # [KVH, D] f32 tile to [1, KVH*D], so each head's D-column
-            # stripe merges separately
-            for hk in range(kvh):
-                cols = slice(hk * d, (hk + 1) * d)
-                km = jnp.where(sel, kq[hk:hk + 1],
-                               kraw8[:, cols].astype(jnp.float32))
-                vm = jnp.where(sel, vq[hk:hk + 1],
-                               vraw8[:, cols].astype(jnp.float32))
-                if stacked:
-                    ko_ref[0, 0, :, cols] = km.astype(ko_ref.dtype)
-                    vo_ref[0, 0, :, cols] = vm.astype(vo_ref.dtype)
-                else:
-                    ko_ref[0, :, cols] = km.astype(ko_ref.dtype)
-                    vo_ref[0, :, cols] = vm.astype(vo_ref.dtype)
-            if quant:
-                if stacked:
-                    ks_raw8 = ks_ref[0, 0, pl.dslice(base, 8)] \
-                        .astype(jnp.float32)             # [8, KVH]
-                    vs_raw8 = vs_ref[0, 0, pl.dslice(base, 8)] \
-                        .astype(jnp.float32)
-                else:
-                    ks_raw8 = ks_ref[0, pl.dslice(base, 8)] \
-                        .astype(jnp.float32)
-                    vs_raw8 = vs_ref[0, pl.dslice(base, 8)] \
-                        .astype(jnp.float32)
-                lane = jax.lax.broadcasted_iota(jnp.int32, (1, kvh), 1)
-                ksm, vsm = ks_raw8, vs_raw8
-                for hk in range(kvh):
-                    m = jnp.logical_and(sel, lane == hk)  # [8, KVH]
-                    ksm = jnp.where(m, ks_n[hk, 0], ksm)
-                    vsm = jnp.where(m, vs_n[hk, 0], vsm)
-                if stacked:
-                    kso_ref[0, 0] = ksm.astype(kso_ref.dtype)
-                    vso_ref[0, 0] = vsm.astype(vso_ref.dtype)
-                else:
-                    kso_ref[0] = ksm.astype(kso_ref.dtype)
-                    vso_ref[0] = vsm.astype(vso_ref.dtype)
+            def load8(base):
+                rows = lead + (pl.dslice(base, 8),)
+                return (k_ref[rows], v_ref[rows],
+                        ks_ref[rows] if quant else None,
+                        vs_ref[rows] if quant else None)
+
+            _write_stripe(st, length, block_k, load8,
+                          ko_ref.at[lead], vo_ref.at[lead],
+                          kso_ref.at[lead] if quant else None,
+                          vso_ref.at[lead] if quant else None,
+                          kvh=kvh, d=d)
 
 
 def _chunk_prefill_kernel(start_ref, layer_ref, q_ref, k_ref, v_ref, *rest,

@@ -2,42 +2,67 @@
 
 TPU-native analog of vLLM's PagedAttention kernel: the KV cache is a shared
 page pool ``[L, num_pages, page_size, KVH*D]`` and each batch row owns a
-block table ``pages[b, virtual_page] -> physical_page``.  Before this
-kernel, the paged serving path materialized a per-layer virtual view with
+block table ``pages[b, virtual_page] -> physical_page``.  Before these
+kernels, the paged serving path materialized a per-layer virtual view with
 ``take_along_axis`` (``models/transformer._paged_gather``) and ran dense
-attention over it — one full gathered cache copy per layer per step, which
-is the BENCH_r04 bs128 decode cliff (8,673 → 1,193 tok/s/chip).
+attention over it — one full gathered cache copy per layer per step.
 
-Design: the monolithic decode/chunk kernels in ``decode_attention.py`` are
-already split-K online-softmax kernels whose grid walks KV blocks of one
-batch row in order, with the block location resolved by a BlockSpec index
-map from scalar-prefetch operands.  A paged cache is the SAME computation
-with a different address map: virtual page ``ik`` of row ``b`` lives at
-pool page ``pages[b, ik]``.  So this module reuses the kernel BODIES
-(``_decode_kernel`` / ``_chunk_prefill_kernel``) unchanged — online
-softmax with cross-page max/sum merge, block-diagonal Q, int8-KV dequant
-fused onto the score/probability tiles, fused aliased cache write — and
-only swaps the index maps:
+A paged cache is the monolithic split-K online-softmax computation of
+``decode_attention.py`` under another address map: virtual page ``i`` of
+row ``b`` lives at pool page ``pages[b, i]``, ``block_k = page_size``, and
+the kernels' virtual position math (``pos = i*block_k + iota``, length
+masks, write row ``(length-1) % block_k``) transfers verbatim.  The
+per-block update — block-diagonal Q, cross-page max/sum merge, int8-KV
+dequant on the score/probability tiles, the fused write's column
+substitution — is the monolithic kernel's own (``_block_update``); what
+differs is who supplies the block sequence.
 
-* ``block_k = page_size`` and the grid's KV dimension walks VIRTUAL pages
-  in order, so the kernels' virtual position math (``pos = ik*block_k +
-  iota``, length masks, write row ``(length-1) % block_k``) transfers
-  verbatim.
-* The page table rides as a THIRD scalar-prefetch operand; input index
-  maps resolve ``(layer, pages[b, virt], 0, 0)``.  Pages past the live
-  region pin to the last live page — Mosaic elides the repeated-index
-  DMA, so dead-tail grid steps fetch nothing (split-K cost is
-  O(ceil(length/page_size)) pages, not O(table width)).
-* The fused decode write targets the pool through the table too: the
+**Decode** (:func:`paged_decode_attention`) follows the LIVE pages of
+each row:
+
+* ``grid=(B,)`` — one grid step a slot.  The K/V pools stay whole in HBM
+  (``memory_space=pl.ANY``); inside the step a ``fori_loop`` over the
+  row's ``ceil(length / page_size)`` pages fetches page ``pages[b, i]`` of
+  the layer with ``make_async_copy`` into a three-deep VMEM ring (two
+  fetches in flight behind the page being folded in) and applies the
+  per-block update.  Grid steps, DMAs and arithmetic are all
+  O(live pages); the table's width costs nothing.  (The grid-per-page
+  form this replaces paid ~0.35 us for every (slot, virtual page) pair,
+  live or not — three fifths of both serving cells' device time, PERF.md
+  PR 26.)
+* A DEAD row — one whose table points at the reserved trash page
+  (``pages[b, 0] == 0``; page 0 is never allocated), which is how the
+  serving decode block presents free, retired and still-prefilling
+  lanes whatever their position counter says — walks no page: no
+  block-diagonal Q, no fetch, no arithmetic, a zero output row, and its
+  fused-write stripe goes to the trash page.  A dead row costs one empty
+  grid step.
+* The fused decode write targets the pool through the table: the
   aliased output's 8-row write stripe pins to ``(layer,
-  pages[b, (len-1)//page], ((len-1)%page)//8, 0)``.  Dead lanes (length
-  0, table redirected to the reserved trash page 0 by the caller) write
-  their garbage stripe into the trash page — the paged analog of the
-  monolithic "dead lanes write into their own lane" safety argument.
+  pages[b, (len-1)//page], ((len-1)%page)//8, 0)`` and is merged from
+  the last live page, which the loop leaves in its buffer.
+* int8 pools keep the grid walk over virtual pages (the monolithic
+  ``_decode_kernel`` body behind table index maps): a page of dequant
+  scales is ``[page_size, KVH]`` with ``KVH < 128`` lanes, which Mosaic
+  refuses to slice out of an HBM ref by hand ("slice shape must be
+  aligned to tiling (128)") — only its own BlockSpec pipeline can fetch
+  it.  Dead rows are presented to it with length 0, so they skip the
+  arithmetic (not the grid steps).
 
-Numerics: with ``block_k = page_size`` the online-softmax block sequence
-is identical to ``decode_attention(block_k=page_size)`` over the gathered
-virtual view, so the two are BITWISE equal (regression-tested in
+**Chunked prefill** (:func:`paged_chunk_prefill_attention`) still walks
+the table: the page table rides as a THIRD scalar-prefetch operand,
+input index maps resolve ``(layer, pages[b, virt], 0, 0)``, and pages
+past the live region pin to the last live page, so Mosaic elides their
+DMA and the body is ``pl.when``-gated off — the grid step itself is
+still paid (B = 1 there; the next kernel to move to the loop).
+
+How far the live-page walk engages in serving is on the
+``dstpu.sched.dispatch.decode`` span: ``kv_pages`` (pages the block's
+steps walk) against ``kv_pages_table`` (slots x pages a slot x steps).
+
+Numerics: the page sequence and the arithmetic per page are those of
+``decode_attention(block_k=page_size)`` over the gathered virtual view,
+so the two are BITWISE equal (regression-tested in
 tests/unit/test_paged_attention.py); greedy serving outputs stay bitwise
 equal to the monolithic engine as before.
 """
@@ -52,11 +77,100 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.transformer.decode_attention import (
-    _chunk_prefill_kernel, _decode_kernel)
+    _RowState, _block_update, _chunk_prefill_kernel, _decode_kernel,
+    _finish_row, _init_row, _write_stripe)
 from deepspeed_tpu.ops.transformer.flash_attention import LSE_LANES, _interpret
 
+# VMEM ring of the decode loop: the page being folded in plus two fetches
+# behind it.  Measured on v5e at the serving cells' shapes (PERF.md PR 26):
+# two buffers 132 us a layer-step, three 117, four 117.
+_DECODE_PAGE_BUFFERS = 3
 
-def _paged_decode_body(len_ref, layer_ref, pages_ref, *args, **kw):
+
+def _live_pages(lens, pages, b, page, nk):
+    """Pages row ``b`` of the decode kernel walks: ``ceil(length /
+    page)`` (at most the table's width), and 0 for a DEAD row — one
+    whose table points at the reserved trash page (``pages[b, 0] == 0``;
+    page 0 is never allocated) or whose length is not positive."""
+    n = jnp.clip((lens[b] + page - 1) // page, 0, nk)
+    return jnp.where(pages[b, 0] == 0, 0, n)
+
+
+def _paged_decode_kernel(len_ref, layer_ref, pages_ref, q_ref, k_hbm, v_hbm,
+                         *rest, scale, page, nk, kvh, g, d, fused_write):
+    """The page-loop driver: one grid step a batch row, the pools whole
+    in HBM.  A live row fetches its pages through the table into the
+    VMEM ring and folds each into the online-softmax state with the
+    monolithic kernel's per-block update; a dead row writes a zero
+    output row and does nothing else."""
+    kn_ref = vn_ref = ko_ref = vo_ref = None
+    rest = list(rest)
+    if fused_write:
+        kn_ref, vn_ref = rest[:2]
+        del rest[:2]
+    o_ref = rest.pop(0)
+    if fused_write:
+        ko_ref, vo_ref = rest[:2]
+        del rest[:2]
+    m_scr, l_scr, acc_scr, qbd_scr, kbuf, vbuf, sem = rest
+    st = _RowState(q_ref, m_scr, l_scr, acc_scr, qbd_scr,
+                   kn_ref=kn_ref, vn_ref=vn_ref)
+    nbuf = _DECODE_PAGE_BUFFERS
+    b = pl.program_id(0)
+    li = layer_ref[0]
+    length = len_ref[b]
+    n_pages = _live_pages(len_ref, pages_ref, b, page, nk)
+
+    def copies(i):
+        # virtual page i of this row → ring slot i % nbuf; a wait
+        # rebuilds the descriptors its start used
+        pg, slot = pages_ref[b, i], i % nbuf
+        return [pltpu.make_async_copy(src.at[li, pg], dst.at[slot],
+                                      sem.at[j, slot])
+                for j, (src, dst) in enumerate([(k_hbm, kbuf),
+                                                (v_hbm, vbuf)])]
+
+    def start(i):
+        @pl.when(i < n_pages)
+        def _():
+            for c in copies(i):
+                c.start()
+
+    @pl.when(n_pages == 0)
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_pages > 0)
+    def _live():
+        _init_row(st, kvh=kvh, g=g, d=d)
+        for i in range(nbuf - 1):
+            start(i)
+
+        def fold(i, carry):
+            start(i + nbuf - 1)
+            for c in copies(i):
+                c.wait()
+            _block_update(st, i, length, kbuf[i % nbuf], vbuf[i % nbuf],
+                          None, None, scale=scale, block_k=page, kvh=kvh,
+                          g=g, d=d, window=None)
+            return carry
+
+        jax.lax.fori_loop(0, n_pages, fold, None)
+        _finish_row(st, o_ref)
+        if fused_write:
+            # the write row lies in the LAST live page, which the loop
+            # left in the ring
+            last = (n_pages - 1) % nbuf
+
+            def load8(base):
+                rows = pl.dslice(base, 8)
+                return kbuf[last, rows], vbuf[last, rows], None, None
+
+            _write_stripe(st, length, page, load8, ko_ref.at[0, 0],
+                          vo_ref.at[0, 0], None, None, kvh=kvh, d=d)
+
+
+def _paged_grid_decode_body(len_ref, layer_ref, pages_ref, *args, **kw):
     # the page table is consumed entirely by the BlockSpec index maps;
     # the kernel body is the monolithic decode kernel, verbatim
     del pages_ref
@@ -88,10 +202,16 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
     ``init_paged_cache`` layout — page-major S-major slabs, heads
     flattened into lanes, so each page is one contiguous full-lane-width
     DMA).  ``pages``: [B, n_virtual_pages] int32 block tables (virtual
-    page ``pos // page_size`` → physical pool page; dead/unmapped rows
-    must point at the reserved trash page 0).  ``lengths``: [B] int32 —
-    valid virtual positions INCLUDING this step's token.  ``layer``: the
-    (traced) layer index into the stacked pools.  Returns [B, H, D].
+    page ``pos // page_size`` → physical pool page).  ``lengths``: [B]
+    int32 — valid virtual positions INCLUDING this step's token.
+    ``layer``: the (traced) layer index into the stacked pools.  Returns
+    [B, H, D].
+
+    A row whose table points at the reserved trash page
+    (``pages[b, 0] == 0``) is DEAD whatever its length says: it reads no
+    page, its output row is zeros, and (fused write) its stripe lands in
+    the trash page.  Live rows cost O(ceil(length / page_size)) pages —
+    see the module docstring for the two drivers.
 
     ``k_scale``/``v_scale`` ([L, num_pages, page_size, KVH]) switch the
     pools to int8 payloads with per-(position, kv-head) dequant scales,
@@ -135,81 +255,84 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
     nk = pages.shape[1]                     # virtual pages per row
     layer_arr = jnp.asarray([layer], jnp.int32)
     pages_arr = jnp.asarray(pages, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
 
-    def _live_page(ik, lens, b):
-        # pin virtual pages past the live region to the LAST live page:
-        # its physical index then repeats across the dead tail and Mosaic
-        # elides the DMA (compute is pl.when-gated off in the body)
-        last = jnp.maximum((lens[b] + page - 1) // page - 1, 0)
-        return jnp.minimum(ik, last)
+    # index maps: (grid indices..., lengths, layer, pages); the row is
+    # always the first grid index
+    def row(b, *refs):
+        return (b, 0, 0)
 
-    kv_spec = pl.BlockSpec(
-        (1, 1, page, KVHD),
-        lambda b, ik, lens, li, pg: (li[0], pg[b, _live_page(ik, lens, b)],
-                                     0, 0))
-    sc_spec = pl.BlockSpec(
-        (1, 1, page, KVH),
-        lambda b, ik, lens, li, pg: (li[0], pg[b, _live_page(ik, lens, b)],
-                                     0, 0))
-
-    in_specs = [
-        pl.BlockSpec((1, H, D), lambda b, ik, lens, li, pg: (b, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
-    operands = [q, k_pool, v_pool]
-    if quant:
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
-
-    out_specs = [pl.BlockSpec((1, H, D),
-                              lambda b, ik, lens, li, pg: (b, 0, 0))]
-    out_shape = [jax.ShapeDtypeStruct((B, H, D), q.dtype)]
-    io_aliases = {}
-    if fused_write:
+    def stripe(b, *refs):
         # table-resolved write stripe: virtual write position lens[b]-1
         # lands on pool page pages[b, (lens[b]-1)//page] at in-page row
         # (lens[b]-1) % page; the output block covers only that row's
         # 8-sublane-aligned stripe (index in 8-row units), constant per
-        # batch row, so Mosaic flushes 8 rows once after the final grid
-        # step — same stripe economics as the monolithic fused write
-        def _wpage(lens, pg, b):
-            return pg[b, jnp.maximum(lens[b] - 1, 0) // page]
+        # batch row, so Mosaic flushes 8 rows once after the row's last
+        # grid step — same stripe economics as the monolithic fused
+        # write.  A dead row's stripe goes to the trash page.
+        lens, li, pg = refs[-3:]
+        pos = jnp.maximum(lens[b] - 1, 0)
+        wpage = jnp.where(_live_pages(lens, pg, b, page, nk) == 0, 0,
+                          pg[b, jnp.minimum(pos // page, nk - 1)])
+        return (li[0], wpage, (pos % page) // 8, 0)
 
-        def _wstripe(lens, b):
-            return (jnp.maximum(lens[b] - 1, 0) % page) // 8
+    if quant:
+        # the grid walk: virtual pages past the live region pin to the
+        # LAST live page, so its physical index repeats across the dead
+        # tail and Mosaic elides the DMA (compute is pl.when-gated off
+        # in the body); dead rows get length 0, which gates every page
+        lengths = jnp.where(pages_arr[:, 0] == 0, 0, lengths)
+        grid = (B, nk)
 
-        kvo_spec = pl.BlockSpec(
-            (1, 1, 8, KVHD),
-            lambda b, ik, lens, li, pg: (li[0], _wpage(lens, pg, b),
-                                         _wstripe(lens, b), 0))
-        sco_spec = pl.BlockSpec(
-            (1, 1, 8, KVH),
-            lambda b, ik, lens, li, pg: (li[0], _wpage(lens, pg, b),
-                                         _wstripe(lens, b), 0))
-        nspec = pl.BlockSpec((1, KVH, D),
-                             lambda b, ik, lens, li, pg: (b, 0, 0))
+        def kv(b, ik, lens, li, pg):
+            last = jnp.maximum(_live_pages(lens, pg, b, page, nk) - 1, 0)
+            return (li[0], pg[b, jnp.minimum(ik, last)], 0, 0)
+
+        kv_spec = pl.BlockSpec((1, 1, page, KVHD), kv)
+        sc_spec = pl.BlockSpec((1, 1, page, KVH), kv)
+        in_specs = [pl.BlockSpec((1, H, D), row), kv_spec, kv_spec,
+                    sc_spec, sc_spec]
+        operands = [q, k_pool, v_pool, k_scale, v_scale]
+        kernel = functools.partial(
+            _paged_grid_decode_body, scale=float(scale), block_k=page,
+            nk=nk, kvh=KVH, g=G, d=D, stacked=True, quant=True,
+            window=None, mxu_int8=mxu_int8, fused_write=fused_write)
+        ring = []
+    else:
+        grid = (B,)
+        pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+        in_specs = [pl.BlockSpec((1, H, D), row), pool_spec, pool_spec]
+        operands = [q, k_pool, v_pool]
+        kernel = functools.partial(
+            _paged_decode_kernel, scale=float(scale), page=page, nk=nk,
+            kvh=KVH, g=G, d=D, fused_write=fused_write)
+        ring = [pltpu.VMEM((_DECODE_PAGE_BUFFERS, page, KVHD), k_pool.dtype),
+                pltpu.VMEM((_DECODE_PAGE_BUFFERS, page, KVHD), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, _DECODE_PAGE_BUFFERS))]
+
+    out_specs = [pl.BlockSpec((1, H, D), row)]
+    out_shape = [jax.ShapeDtypeStruct((B, H, D), q.dtype)]
+    io_aliases = {}
+    if fused_write:
+        nspec = pl.BlockSpec((1, KVH, D), row)
         in_specs += [nspec, nspec]
         operands += [new_k, new_v]
-        out_specs += [kvo_spec, kvo_spec]
+        out_specs += [pl.BlockSpec((1, 1, 8, KVHD), stripe)] * 2
         out_shape += [jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                       jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)]
         # operand indices INCLUDE the three scalar-prefetch args
         io_aliases = {4: 1, 5: 2}
         if quant:
-            out_specs += [sco_spec, sco_spec]
+            out_specs += [pl.BlockSpec((1, 1, 8, KVH), stripe)] * 2
             out_shape += [jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
                           jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype)]
             io_aliases = {4: 1, 5: 2, 6: 3, 7: 4}
 
-    res = pl.pallas_call(
-        functools.partial(_paged_decode_body, scale=float(scale),
-                          block_k=page, nk=nk, kvh=KVH, g=G, d=D,
-                          stacked=True, quant=quant, window=None,
-                          mxu_int8=mxu_int8, fused_write=fused_write),
+    return pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, nk),
+            grid=grid,
             in_specs=in_specs,
             out_specs=out_specs if fused_write else out_specs[0],
             scratch_shapes=[
@@ -219,11 +342,11 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
                 pltpu.VMEM((H, KVHD),
                            jnp.int8 if mxu_int8 else q.dtype),
             ] + ([pltpu.VMEM((H, LSE_LANES), jnp.float32)]
-                 if mxu_int8 else [])),
+                 if mxu_int8 else []) + ring),
         out_shape=out_shape if fused_write else out_shape[0],
         input_output_aliases=io_aliases,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary")[:len(grid)],
             # pages are small (<= a monolithic block_k) — the monolithic
             # slab-sized floor is comfortably enough headroom
             vmem_limit_bytes=max(
@@ -231,8 +354,7 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
                 6 * page * KVHD * q.dtype.itemsize + 16 * 1024 * 1024)),
         interpret=_interpret(),
         name="attn.paged_decode",
-    )(jnp.asarray(lengths, jnp.int32), layer_arr, pages_arr, *operands)
-    return res
+    )(lengths, layer_arr, pages_arr, *operands)
 
 
 def paged_chunk_prefill_attention(q, k_pool, v_pool, starts, pages, *,

@@ -5,12 +5,13 @@ The kernel contract: paged decode/chunk-prefill over the page pool is
 BITWISE equal to the ``take_along_axis`` gather reference — the gathered
 virtual view fed to the monolithic kernel at ``block_k = page_size``,
 which walks the identical online-softmax block sequence — across page
-sizes {16, 64, 128}, fp32 and int8-KV pools, dead lanes and unaligned
-lengths.  (Serving-level mid-stream EOS / slot-churn / greedy-bitwise
+sizes {16, 64, 128}, fp32, bf16 and int8-KV pools, ragged batches with
+dead rows between live ones, and NaN-poisoned pages outside the live
+regions.  (Serving-level mid-stream EOS / slot-churn / greedy-bitwise
 coverage rides ``test_serving_paged.py``, which now exercises these
 kernels end to end.)  The registry contract: one static dispatch table,
 probed identically by the traced programs and the host-side attribution,
-reference fallback warns instead of silently re-creating the BENCH_r04
+reference fallback warns instead of silently re-creating the gather
 cliff, and the traced paged decode step stays host-callback-free with
 its fused write aliased in the jaxpr.
 """
@@ -59,7 +60,8 @@ def _pool_fixture(page, *, int8=False, seed=0):
 
 def _gather(buf, pages, nvirt):
     """The take_along_axis reference view: [B, nvirt*page, last-dim]."""
-    return buf[LAYER, pages].reshape(B, nvirt * buf.shape[2], buf.shape[-1])
+    return buf[LAYER, pages].reshape(pages.shape[0], nvirt * buf.shape[2],
+                                     buf.shape[-1])
 
 
 @pytest.mark.parametrize("page", [16, 64, 128])
@@ -76,6 +78,131 @@ def test_paged_decode_bitwise_vs_gather(page, int8):
     out = paged_decode_attention(q, k, v, lengths, pages, layer=LAYER,
                                  k_scale=ks, v_scale=vs)
     np.testing.assert_array_equal(np.asarray(ref[:2]), np.asarray(out[:2]))
+
+
+def _ragged_fixture(page, variant, *, seed=0):
+    """A ragged batch over one pool: lengths 1, ``page``, ``page + 1``
+    and the full table, with DEAD rows (trash table) between the live
+    ones.  The dead rows carry plausible lengths — a retired serving
+    lane keeps counting — so it is the table, not the length, that must
+    mark them.  Returns the operands, the live rows and, per live row,
+    the pool pages its live region covers."""
+    rng = np.random.RandomState(seed)
+    nvirt = 4
+    lengths = np.asarray([1, 2 * page + 3, page, page + 1, nvirt * page,
+                          nvirt * page], np.int32)
+    live = np.asarray([0, 2, 3, 5])
+    nb = len(lengths)
+    P = nb * nvirt + 1
+    pages = np.zeros((nb, nvirt), np.int32)
+    free = list(rng.permutation(np.arange(1, P)))
+    for b in live:                          # whole rows allocated, like a
+        pages[b] = [free.pop() for _ in range(nvirt)]   # reserved slot
+    shape = (L, P, page, KVHD)
+    if variant == "bf16":
+        k = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+        v = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+        ks = vs = None
+        qdt = jnp.bfloat16
+    else:
+        k = jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
+        v = jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
+        ks = jnp.asarray(rng.rand(L, P, page, KVH) * 0.1 + 0.01, jnp.float32)
+        vs = jnp.asarray(rng.rand(L, P, page, KVH) * 0.1 + 0.01, jnp.float32)
+        qdt = jnp.float32
+    q = jnp.asarray(rng.randn(nb, H, D), qdt)
+    new_k = jnp.asarray(rng.randn(nb, KVH, D), qdt)
+    new_v = jnp.asarray(rng.randn(nb, KVH, D), qdt)
+    live_pages = {int(b): pages[b, :-(-int(lengths[b]) // page)]
+                  for b in live}
+    return (q, k, v, ks, vs, jnp.asarray(pages), jnp.asarray(lengths),
+            new_k, new_v, nvirt, live, live_pages)
+
+
+@pytest.mark.parametrize("variant,fused", [
+    ("bf16", False), ("bf16", True), ("int8", False), ("int8", True),
+    ("int8_mxu", False)])
+def test_paged_decode_ragged_bitwise_vs_gather(variant, fused):
+    """Every variant of paged decode, over a ragged batch with dead rows
+    between live ones, is BITWISE the monolithic kernel at ``block_k =
+    page`` over the gathered view — outputs, and with the fused write
+    the row each live slot writes; dead rows return exactly zero."""
+    page = 16
+    (q, k, v, ks, vs, pages, lengths, new_k, new_v, nvirt, live,
+     _) = _ragged_fixture(page, variant)
+    quant = ks is not None
+    kw = dict(int8_matmuls=variant == "int8_mxu")
+    g = lambda buf: None if buf is None else _gather(buf, pages, nvirt)
+    if fused:
+        kw.update(new_k=new_k, new_v=new_v)
+    ref = decode_attention(q, g(k), g(v), lengths, block_k=page,
+                           k_scale=g(ks), v_scale=g(vs), **kw)
+    out = paged_decode_attention(q, k, v, lengths, pages, layer=LAYER,
+                                 k_scale=ks, v_scale=vs, **kw)
+    if fused:
+        ref, *ref_caches = ref
+        out, *pools = out
+    np.testing.assert_array_equal(np.asarray(ref, np.float32)[live],
+                                  np.asarray(out, np.float32)[live])
+    dead = np.setdiff1d(np.arange(q.shape[0]), live)
+    assert not np.asarray(out, np.float32)[dead].any()
+    if fused:
+        pos = np.asarray(lengths) - 1
+        phys = np.asarray(pages)[live, pos[live] // page]
+        for mono, pool in zip(ref_caches, pools):
+            np.testing.assert_array_equal(
+                np.asarray(mono, np.float32)[live, pos[live]],
+                np.asarray(pool, np.float32)[LAYER, phys, pos[live] % page])
+        assert len(pools) == (4 if quant else 2)
+
+
+@pytest.mark.parametrize("variant", ["bf16", "int8"])
+def test_paged_decode_reads_and_writes_live_pages_only(variant):
+    """Poison everything a live-page walk must not touch — the trash
+    page, the pages no row owns, and the pages a live row owns past its
+    length — with NaN (int8 pools: NaN scales under extreme payloads).
+    The fused-write decode stays finite, returns exactly zero for dead
+    rows and the unpoisoned run's bits for live ones, and changes no
+    allocated page except each live row's write row.  (Positions past
+    the length INSIDE the last live page are not poisoned: their
+    probabilities are zero, and 0 x NaN through the PV matmul is NaN in
+    the gather reference too.)"""
+    page = 16
+    (q, k, v, ks, vs, pages, lengths, new_k, new_v, _, live,
+     live_pages) = _ragged_fixture(page, variant, seed=5)
+    quant = ks is not None
+    keep = np.zeros((k.shape[1],), bool)
+    keep[np.concatenate(list(live_pages.values()))] = True
+    kw = dict(layer=LAYER, new_k=new_k, new_v=new_v)
+
+    def poison(buf, bad):
+        return jnp.where(jnp.asarray(keep)[None, :, None, None], buf,
+                         jnp.asarray(bad, buf.dtype))
+
+    if quant:
+        pk, pv = poison(k, 127), poison(v, -127)
+        pks, pvs = poison(ks, np.nan), poison(vs, np.nan)
+    else:
+        pk, pv, pks, pvs = poison(k, np.nan), poison(v, np.nan), None, None
+    clean = paged_decode_attention(q, k, v, lengths, pages, k_scale=ks,
+                                   v_scale=vs, **kw)
+    got = paged_decode_attention(q, pk, pv, lengths, pages, k_scale=pks,
+                                 v_scale=pvs, **kw)
+    out = np.asarray(got[0], np.float32)
+    assert np.isfinite(out).all()
+    dead = np.setdiff1d(np.arange(q.shape[0]), live)
+    assert not out[dead].any()
+    np.testing.assert_array_equal(np.asarray(clean[0], np.float32)[live], out[live])
+    pos = np.asarray(lengths) - 1
+    for before, after in zip((pk, pv, pks, pvs)[:len(got) - 1], got[1:]):
+        before = np.array(before, np.float32)
+        after = np.array(after, np.float32)
+        for b in live:                      # the write row, then mask it
+            wp, wr = int(np.asarray(pages)[b, pos[b] // page]), pos[b] % page
+            assert np.isfinite(after[LAYER, wp, wr]).all()
+            before[LAYER, wp, wr] = after[LAYER, wp, wr] = 0
+        # every page but the trash page: same bits (NaN == NaN here)
+        np.testing.assert_array_equal(before[:, 1:], after[:, 1:])
 
 
 def test_paged_decode_fused_write_pool_contents():

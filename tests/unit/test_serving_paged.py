@@ -102,6 +102,53 @@ def test_paged_serving_matches_solo_generate(paged_engine):
     assert n_decode_sigs == 1, n_decode_sigs
 
 
+def test_paged_decode_span_counts_live_pages(paged_engine, tmp_path):
+    """The counter that says how far the live-page walk engages: each
+    ``dstpu.sched.dispatch.decode`` span carries ``kv_pages`` — pages
+    the block's steps walk, ``ceil(context / page_size)`` a live slot
+    and step — and ``kv_pages_table`` (slots x pages a slot x steps).
+    A hand-built schedule: more requests than slots, contexts crossing
+    page boundaries, budgets that end mid-block (so a slot retires and
+    is re-occupied inside the run) — the spans' sum is exact, and the
+    outputs stay bitwise equal to solo ``generate()``."""
+    import json
+    from deepspeed_tpu.monitor import trace as span_trace
+    eng = paged_engine
+    rng = np.random.default_rng(11)
+    # (prompt length, new tokens): block 4, so 7 / 6 / 10 new tokens end
+    # two / one / one step into a block; 15 -> 16 -> 17 and 30 -> 33
+    # cross page boundaries
+    plan = [(15, 7), (30, 6), (9, 10), (17, 3), (31, 5)]
+    prompts = [rng.integers(1, 97, (p,)).astype(np.int32) for p, _ in plan]
+    news = [n for _, n in plan]
+    srv = eng.serve(tracing=True, decode_block=4)
+    try:
+        rids = [srv.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, news)]
+        outs = srv.drain()
+        path = srv.dump_trace(str(tmp_path / "trace.json"))
+    finally:
+        srv.close()
+        span_trace.disable()
+    _assert_bitwise(eng, outs, rids, prompts, news)
+    with open(path) as f:
+        evs = json.load(f)["traceEvents"]
+    decodes = [e["args"] for e in evs
+               if e["name"] == "dstpu.sched.dispatch.decode"]
+    page = srv.page
+    # the step that makes a request's token i attends prompt + i positions
+    want = sum(-(-(p + i) // page) for p, n in plan for i in range(1, n))
+    assert sum(a["kv_pages"] for a in decodes) == want
+    table = srv.num_slots * srv.n_slot_pages * srv.block
+    assert {a["kv_pages_table"] for a in decodes} == {table}
+    assert all(0 <= a["kv_pages"] <= table for a in decodes)
+    # a slot that retires mid-block walks fewer steps than the block:
+    # some dispatch counts a slot for less than `block` steps
+    assert any(a["kv_pages"] < a["live_slots"] * srv.block for a in decodes)
+    assert sum(a["kv_positions"] for a in decodes) \
+        == sum(p + i for p, n in plan for i in range(1, n))
+
+
 def test_paged_page_size_invariance(paged_engine):
     """Same tokens for page_size in {16, 64, 128}: the page size only
     changes where K/V rows physically live, never what is attended."""

@@ -72,10 +72,10 @@ def _flash_fwd_bwd():
     return jax.grad(loss, argnums=(0, 1, 2)), [((2, 2048, H, D), BF16)] * 3
 
 
-def _paged_decode(quant, page=64, slots=8, cache_len=512):
+def _paged_decode(quant, page=64, slots=8, cache_len=512, layers=L):
     pages_per_slot = cache_len // page
     n_pages = slots * pages_per_slot + 1
-    pool = ((L, n_pages, page, HD), I8 if quant else BF16)
+    pool = ((layers, n_pages, page, HD), I8 if quant else BF16)
     args = [((slots, H, D), BF16), pool, pool, ((slots,), I32),
             ((slots, pages_per_slot), I32), ((slots, H, D), BF16),
             ((slots, H, D), BF16)]
@@ -115,6 +115,12 @@ CASES = {
     "flash_fwd_bwd_s2048": _flash_fwd_bwd,
     "paged_decode_bf16_fused_write_p64": lambda: _paged_decode(False),
     "paged_decode_int8kv_fused_write_p64": lambda: _paged_decode(True),
+    # the two serving cells' tables: 32 slots x 22 pages, 24 x 29 (four
+    # layers of pool: this jit donates nothing, so pools count twice)
+    "paged_decode_bf16_chat_32x22": lambda: _paged_decode(
+        False, slots=32, cache_len=1408, layers=4),
+    "paged_decode_bf16_batch_24x29": lambda: _paged_decode(
+        False, slots=24, cache_len=1856, layers=4),
     "paged_chunk_prefill_c128_p64": _paged_chunk_prefill,
     "mono_decode_bf16_fused_write": _mono_decode,
 }

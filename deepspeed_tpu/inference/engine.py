@@ -138,11 +138,14 @@ class InferenceEngine:
         abstract = jax.eval_shape(lambda t: t, params)
         self._plan = self._plan_for(abstract)
         cast = self.compute_dtype
-        put = jax.jit(lambda t: jax.tree.map(
-            lambda p: p.astype(cast)
-            if jnp.issubdtype(p.dtype, jnp.floating) else p, t),
-            out_shardings=self._plan.param_shardings)
-        self._params = put(params)
+        # leaf by leaf, so that the cast never holds a second copy of the
+        # whole tree (a model that fills most of the chip has no room for
+        # one); a leaf already in the compute dtype is placed as it is
+        self._params = jax.device_put(
+            jax.tree.map(lambda p: p.astype(cast)
+                         if jnp.issubdtype(p.dtype, jnp.floating) else p,
+                         params),
+            self._plan.param_shardings)
         n = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(self._params))
         log_dist(f"inference params placed: {n/1e6:.1f}M, tp={self.topology.tp}, "
                  f"dtype={cast.__name__}", ranks=[0])

@@ -148,7 +148,8 @@ from deepspeed_tpu.inference.serving.slots import (init_slot_state,
                                                    make_paged_chunk_fn,
                                                    make_paged_decode_block_fn,
                                                    make_paged_spec_verify_fn,
-                                                   make_spec_verify_fn)
+                                                   make_spec_verify_fn,
+                                                   routes_experts)
 from deepspeed_tpu.runtime.fault import inject
 from deepspeed_tpu.utils.logging import log_dist, logger
 
@@ -224,6 +225,9 @@ class _PendingPrefill:
         # speculative serving: the DRAFT model's single-lane prefill
         # cache (the prompt's K/V must land in the draft cache too)
         self.draft_lane = None
+        # expert models: each chunk's device-side load vector
+        # (slots._expert_load), read when the admit event is processed
+        self.expert_loads = []
 
 
 class _LanePool:
@@ -370,6 +374,29 @@ class ServingEngine:
                     f"draft model vocab_size={dvocab} != target "
                     f"vocab_size={tvocab} — speculative verification "
                     f"compares token ids, the vocabularies must match")
+
+        # ---- expert models (docs/serving.md "Expert models"): dropless
+        # routed layers are served by the paged programs only — they
+        # mask dead lanes and padded chunk tails out of the routing and
+        # return the expert load ----
+        self.routed = routes_experts(self.module)
+        if self.routed:
+            if not self.paged:
+                raise ValueError(
+                    "a model with dropless expert layers needs "
+                    "serving.paged=True: only the paged programs keep "
+                    "dead lanes and padded chunk tails out of the routing")
+            if self.speculative:
+                raise ValueError(
+                    "serving.speculative=True is not implemented for a "
+                    "model with expert layers (the verify window's "
+                    "rejected positions would be routed)")
+            if getattr(engine, "_quantizer", None) is not None:
+                raise ValueError(
+                    "weight quantization is not implemented for a model "
+                    "with expert layers: the expert kernel reads its "
+                    "weights as the program holds them, a dequantized "
+                    "copy would be materialized every step")
 
         from deepspeed_tpu.inference.engine import (KVCacheWorkspace,
                                                     build_sample_fn)
@@ -527,8 +554,8 @@ class ServingEngine:
         self._queue = deque()                      # guarded-by: _lock
         self._pending = None                       # guarded-by: _lock
         # dispatched-but-unprocessed device work, processed FIFO one
-        # event behind the newest dispatch: ("decode", toks_dev) |
-        # ("admit", req, slot, lane, first_dev)
+        # event behind the newest dispatch: ("decode", toks_dev, [load]) |
+        # ("admit", req, slot, lane, first_dev, draft_lane, [loads])
         self._events = deque()                     # guarded-by: _lock
         self._rng = jax.random.key(int(cfg.seed))  # guarded-by: _lock
         self._next_rid = 0                         # guarded-by: _lock
@@ -596,6 +623,21 @@ class ServingEngine:
                 "spec_tokens_per_dispatch": 0.0,
                 "spec_draft_secs": 0.0, "spec_verify_secs": 0.0,
                 "spec_draft_fraction": 0.0})
+        if self.routed:
+            # expert load (docs/observability.md): (token, expert)
+            # assignments the expert layers computed, expert-weight reads
+            # (experts with a live token, per layer per call), the busiest
+            # expert's tokens summed likewise — and, outside ``stats``
+            # (every value there is one /metrics gauge), the assignments
+            # by expert layer and expert
+            from deepspeed_tpu.models.transformer import _is_moe_layer
+            mc = self.module.config
+            self.stats.update({"moe_assignments": 0,
+                               "moe_experts_touched": 0,
+                               "moe_max_expert_tokens": 0})
+            self.moe_expert_tokens = np.zeros(
+                (sum(_is_moe_layer(mc, i) for i in range(mc.num_layers)),
+                 mc.moe_num_experts), np.int64)  # guarded-by: _lock
         self.occupancy_trace = []        # (it, n_active)  # guarded-by: _lock
         # ---- observability layer (docs/observability.md): span tracer
         # + histograms + flight recorder.  All default-off = seed
@@ -2127,13 +2169,14 @@ class ServingEngine:
                     # with decode
                     row = jnp.asarray(
                         self._page_table[p.slot:p.slot + 1])
-                    logits, self._cache = self.engine._run_guarded(
+                    logits, self._cache, *load = self.engine._run_guarded(
                         self._chunk_fn,
                         (self.engine._params, self._cache, row,
                          jnp.asarray(
                              p.ids_pad[:, p.ci * C:(p.ci + 1) * C]),
                          jnp.asarray(p.start + p.ci * C, jnp.int32),
                          jnp.asarray([local], jnp.int32)))
+                    p.expert_loads += load      # expert models only
                 else:
                     logits, p.lane = self.engine._run_guarded(
                         self._chunk_fn,
@@ -2309,7 +2352,7 @@ class ServingEngine:
         req.status = RequestStatus.RUNNING
         self._slots[p.slot] = req
         self._events.append(("admit", req, p.slot, p.lane, first,
-                             p.draft_lane))
+                             p.draft_lane, p.expert_loads))
         self.stats["admitted"] += 1
         if self._tracer is not None and req.t_trace is not None:
             # prefill phase ends: the fused admit is dispatched; what
@@ -2336,8 +2379,9 @@ class ServingEngine:
                         "decode", phase="decode",
                         live_slots=int(self._mirror_active.sum()),
                         **self._block_kv_work()):
+                    load = []
                     if self.paged:
-                        toks, self._cache, self._state = \
+                        toks, self._cache, self._state, *load = \
                             self.engine._run_guarded(
                                 self._decode_fn,
                                 (self.engine._params, self._cache,
@@ -2349,7 +2393,7 @@ class ServingEngine:
                                 self._decode_fn,
                                 (self.engine._params, self._cache,
                                  self._state, sub))
-                ev = ("decode", toks)
+                ev = ("decode", toks, load)
         except BaseException:
             # the donated cache/state may be dead — drop them so the next
             # step's workspace take() reallocates, and abort everything
@@ -2467,11 +2511,36 @@ class ServingEngine:
             else:
                 self._process_decode(ev, finished)
 
+    def _account_expert_load(self, loads, sp, steps=1):  # lock-held: _lock
+        """Fold the load vectors (``slots._expert_load``) of programs
+        whose results the scheduler has just waited for — each
+        ``steps`` forward passes — into ``stats`` /
+        ``moe_expert_tokens``, and put their sums on the span ``sp``
+        with ``moe_calls``, the expert-layer calls they cover."""
+        if not loads:
+            return
+        assigned = touched = busiest = 0
+        for vec in map(np.asarray, loads):
+            self.moe_expert_tokens += vec[:-2].reshape(
+                self.moe_expert_tokens.shape)
+            assigned += int(vec[:-2].sum())
+            touched += int(vec[-2])
+            busiest += int(vec[-1])
+        self.stats["moe_assignments"] += assigned
+        self.stats["moe_experts_touched"] += touched
+        self.stats["moe_max_expert_tokens"] += busiest
+        sp.set(moe_assignments=assigned, moe_experts_touched=touched,
+               moe_max_expert_tokens=busiest,
+               moe_calls=len(loads) * steps * len(self.moe_expert_tokens))
+
     def _process_admit(self, ev, finished):  # lock-held: _lock
-        _, req, slot, lane, first_dev, draft_lane = ev
+        _, req, slot, lane, first_dev, draft_lane, expert_loads = ev
         with span("dstpu.sched.wait_device", track="scheduler",
                   cat="scheduler", event="admit", rid=req.rid) as sp:
             first = int(np.asarray(first_dev))
+            # the prompt's chunks ran before the admit that sampled
+            # ``first``: their loads are on the host's side of the wait
+            self._account_expert_load(expert_loads, sp)
         self.stats["sync_secs"] += sp.dur_s
         self._lane_pool.give_back(lane)
         if self.speculative and draft_lane is not None:
@@ -2547,6 +2616,7 @@ class ServingEngine:
         with span("dstpu.sched.wait_device", track="scheduler",
                   cat="scheduler", event="decode") as sp:
             toks = np.asarray(ev[1])                     # [block, N]
+            loads = list(map(np.asarray, ev[2]))         # expert models
         self.stats["sync_secs"] += sp.dur_s
         # mirror the in-program retirement rule step by step: an emitted
         # eos (or max_new reached) ends the request and frees its slot
@@ -2560,6 +2630,7 @@ class ServingEngine:
                                               finished)
             committed = self.stats["decode_tokens"] - n0
             sp.set(tokens=committed)
+            self._account_expert_load(loads, sp, steps=self.block)
         if self._flightrec is not None:
             self._flightrec.record("commit", kind="decode",
                                    tokens=committed)

@@ -55,6 +55,51 @@ def init_slot_state(num_slots):
     }
 
 
+def routes_experts(module):
+    """True for a model with DROPLESS expert layers
+    (``TransformerConfig.moe_capacity_factor=None``, ``moe/dropless.py``)
+    — the models whose paged programs mask dead tokens out of the routing
+    and return the expert load (docs/serving.md "Expert models")."""
+    cfg = getattr(module, "config", None)
+    return getattr(cfg, "moe_num_experts", 0) > 0 \
+        and getattr(cfg, "moe_capacity_factor", 1.0) is None
+
+
+def _decode(module, variables, ids, cache, pos, live=None, **kw):
+    """``module.decode`` as the paged programs call it: ``(logits, cache,
+    counts)``.  Dense model (``live`` None): the plain call, ``counts``
+    None.  Expert model: only ``live [B, S]`` tokens are routed, and
+    ``counts [expert layers, experts]`` int32 are the (token, expert)
+    assignments each expert layer computed — what its ``MoE`` sows."""
+    decode = type(module).decode
+    if live is None:
+        logits, cache = module.apply(variables, ids, cache, pos,
+                                     method=decode, **kw)
+        return logits, cache, None
+    (logits, cache), sown = module.apply(
+        variables, ids, cache, pos, method=decode, live=live,
+        mutable=["moe_stats"], **kw)
+    layers = sown["moe_stats"]
+    # layers_0 .. layers_<L-1>, in layer order (shorter names first)
+    counts = jnp.stack([layers[name]["moe_mlp"]["expert_tokens"]
+                        for name in sorted(layers, key=lambda n: (len(n), n))])
+    return logits, cache, counts
+
+
+def _expert_load(counts):
+    """The load summary a paged program of an expert model returns beside
+    its other outputs, from ``counts [calls, expert layers, experts]`` —
+    ONE int32 vector (one device read for the scheduler):
+    ``expert_tokens [layers x experts]`` (assignments summed over the
+    calls), then ``touched`` (experts with a live token, summed over
+    layers and calls — each is one expert's weights read) and
+    ``max_tokens`` (the busiest expert's tokens, summed likewise)."""
+    return jnp.concatenate([
+        jnp.sum(counts, axis=0).reshape(-1),
+        jnp.sum(counts > 0).astype(jnp.int32)[None],
+        jnp.sum(jnp.max(counts, axis=-1))[None]])
+
+
 def make_decode_block_fn(module, sample_fn, param_transform, block,
                          cache_len):
     """The single reusable decode-step program:
@@ -178,8 +223,14 @@ def make_paged_decode_block_fn(module, sample_fn, param_transform, block,
     Per-step math is identical to :func:`make_decode_block_fn`; only the
     cache write/read routes through the page table (see
     ``models/transformer.py`` ``_paged_write``/``_paged_gather``), so
-    greedy paged serving stays bitwise equal to solo ``generate()``."""
+    greedy paged serving stays bitwise equal to solo ``generate()``.
+
+    For a model with dropless expert layers (:func:`routes_experts`) a
+    lane that is not ``active`` at a step is routed to no expert — it
+    reads no expert's weights and is not counted — and the program
+    returns a fourth output, the block's :func:`_expert_load`."""
     deq = param_transform if param_transform is not None else (lambda p: p)
+    routed = routes_experts(module)
 
     @hot_path("serving.decode_step_paged")
     def decode_block(params, cache, state, pages, rng):
@@ -196,11 +247,11 @@ def make_paged_decode_block_fn(module, sample_fn, param_transform, block,
             # BEFORE the admit flips `active`, so an unmasked free-lane
             # write here would corrupt a freshly prefilled prompt.)
             safe_pages = jnp.where(active[:, None], pages, 0)
-            logits, cache = module.apply(
-                deq(params), tok[:, None],
+            logits, cache, counts = _decode(
+                module, deq(params), tok[:, None],
                 {**cache, "pages": safe_pages,
                  **_paged_kernel_marker(paged_kernel)},
-                pos, method=type(module).decode)
+                pos, live=active[:, None] if routed else None)
             rng, sub = jax.random.split(rng)
             nxt = sample_fn(logits[:, -1], sub).astype(jnp.int32)
             nxt = jnp.where(active, nxt, eos)
@@ -210,13 +261,16 @@ def make_paged_decode_block_fn(module, sample_fn, param_transform, block,
             # entry is the trash page once the host processed retirement
             pos = jnp.minimum(pos + 1, cache_len - 1)
             remaining = jnp.maximum(remaining - 1, 0)
-            return (cache, nxt, pos, active, remaining, rng), nxt
+            return (cache, nxt, pos, active, remaining, rng), (nxt, counts)
 
-        (cache, tok, pos, active, remaining, _), toks = jax.lax.scan(
-            step, (cache, state["token"], state["pos"], state["active"],
-                   state["remaining"], rng), None, length=block)
+        (cache, tok, pos, active, remaining, _), (toks, counts) = \
+            jax.lax.scan(
+                step, (cache, state["token"], state["pos"], state["active"],
+                       state["remaining"], rng), None, length=block)
         new_state = {"token": tok, "pos": pos, "active": active,
                      "remaining": remaining, "eos": eos}
+        if routed:
+            return toks, cache, new_state, _expert_load(counts)
         return toks, cache, new_state
 
     return jax.jit(decode_block, donate_argnums=(1, 2))
@@ -229,16 +283,28 @@ def make_paged_chunk_fn(module, param_transform, paged_kernel=True):
     slot's pool pages through its ``[1, pages_per_slot]`` table row (no
     single-lane staging cache, no admit-time insert).  The POOL is
     donated (argnum 1); the table row is a separate traced input so the
-    donation aliases cleanly."""
+    donation aliases cleanly.
+
+    ``logits_at`` is the chunk's LAST REAL row (the scheduler passes
+    ``chunk - 1`` for a whole chunk and the prompt's last token for the
+    final one), so the rows past it are the padded tail.  For a model
+    with dropless expert layers (:func:`routes_experts`) the tail is
+    routed to no expert, and the program returns ``(logits, cache,
+    load)`` with the chunk's :func:`_expert_load`."""
     deq = param_transform if param_transform is not None else (lambda p: p)
+    routed = routes_experts(module)
 
     @hot_path("serving.prefill_chunk_paged")
     def chunk_step(params, cache, pages, chunk_ids, start, logits_at):
-        return module.apply(deq(params), chunk_ids,
-                            {**cache, "pages": pages,
-                             **_paged_kernel_marker(paged_kernel)}, start,
-                            method=type(module).decode,
-                            logits_at=logits_at)
+        live = jnp.arange(chunk_ids.shape[1])[None, :] \
+            <= logits_at[:, None] if routed else None
+        logits, cache, counts = _decode(
+            module, deq(params), chunk_ids,
+            {**cache, "pages": pages, **_paged_kernel_marker(paged_kernel)},
+            start, live=live, logits_at=logits_at)
+        if routed:
+            return logits, cache, _expert_load(counts[None])
+        return logits, cache
 
     return jax.jit(chunk_step, donate_argnums=(1,))
 

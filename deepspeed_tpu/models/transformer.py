@@ -63,6 +63,9 @@ class TransformerConfig:
     window_size: int = 256
     # None → 1/sqrt(head_dim); gpt-neo uses 1.0 (unscaled logits)
     attention_softmax_scale: Optional[float] = None
+    # olmoe: RMSNorm over the WHOLE projected query and key vectors
+    # (width heads x head_dim, float32 gain) before the head split's rope
+    qk_norm: bool = False
     # MoE trunk (reference Megatron-DeepSpeed MoE-GPT layout): every
     # `moe_every`-th block swaps its MLP for a `moe/layer.py` MoE with
     # `moe_num_experts` experts sharded over the `ep` mesh axis.  0 = dense.
@@ -74,8 +77,14 @@ class TransformerConfig:
     # interval 2) map without remapping layer indices.
     moe_layer_offset: int = -1
     moe_top_k: int = 1
-    moe_capacity_factor: float = 1.25
+    # None = DROPLESS (moe/dropless.py): no capacity, every chosen (token,
+    # expert) pair computed, train or eval — what an OLMoE/Mixtral-style
+    # model is served with.  A number selects the GShard capacity gate.
+    moe_capacity_factor: Optional[float] = 1.25
     moe_eval_capacity_factor: float = 1.0
+    # divide the chosen gates by their sum (HF norm_topk_prob); top-1
+    # gates are never renormalised
+    moe_norm_topk_prob: bool = True
     moe_ep_size: int = 1
     moe_aux_coef: float = 0.01
     # Megatron-style MoE experts carry per-expert biases (dense_h_to_4h.bias
@@ -197,15 +206,23 @@ class TransformerConfig:
         f = self.ffn_size
         kvh = self.kv_heads * self.head_dim
         attn = h * h + h * kvh * 2 + h * h  # q, k, v, o kernels
+        if self.qk_norm:
+            attn += h + kvh
         mlp = h * f * (3 if self.gated_mlp else 2)
         norm_size = h if self.rms_norm else 2 * h
         norms_per_layer = 1 if (self.parallel_residual
                                 and self.shared_attn_mlp_norm) else 2
-        per_layer = attn + mlp + norms_per_layer * norm_size
+        per_layer = attn + norms_per_layer * norm_size
+        # an expert layer holds a router and moe_num_experts MLPs in the
+        # dense MLP's place (kernels only; moe_expert_bias is not counted,
+        # like the dense biases)
+        n_moe = sum(_is_moe_layer(self, i) for i in range(l))
+        mlps = (l - n_moe) * mlp \
+            + n_moe * (h * self.moe_num_experts + self.moe_num_experts * mlp)
         emb = v * h + (self.max_seq_len * h
                        if self.position_embedding == "learned" else 0)
         head = 0 if self.tie_word_embeddings else v * h
-        return emb + l * per_layer + norm_size + head
+        return emb + l * per_layer + mlps + norm_size + head
 
 
 def resolve_remat_policy(name):
@@ -645,6 +662,15 @@ class Attention(nn.Module):
             q = dense(features=(H, D), name="q_proj")(x)
             k = dense(features=(KVH, D), name="k_proj")(x)
             v = dense(features=(KVH, D), name="v_proj")(x)
+        if cfg.qk_norm:
+            # over the whole projected vector, not per head (HF
+            # OlmoeAttention q_norm / k_norm)
+            def whole(t, name):
+                n = nn.RMSNorm(epsilon=cfg.layernorm_epsilon, name=name,
+                               param_dtype=jnp.float32)(
+                    t.reshape(*t.shape[:2], -1))
+                return n.astype(cfg.jnp_dtype).reshape(t.shape)
+            q, k = whole(q, "q_norm"), whole(k, "k_norm")
         if cfg.position_embedding == "rope":
             q, k = _rope(q, k, positions, D, cfg.rope_theta,
                          rope_dim=cfg.rope_dim,
@@ -697,6 +723,15 @@ class Attention(nn.Module):
         return proj, new_cache
 
 
+# ``TransformerConfig.activation`` -> the function, for the dense MLP and
+# the gated experts alike
+ACTIVATIONS = {"relu": nn.relu, "gelu": nn.gelu,
+               "gelu_exact": partial(nn.gelu, approximate=False),
+               "silu": nn.silu,
+               # clip text encoder: x * sigmoid(1.702 x)
+               "quick_gelu": lambda x: x * nn.sigmoid(1.702 * x)}
+
+
 class MLP(nn.Module):
     config: TransformerConfig
 
@@ -705,11 +740,7 @@ class MLP(nn.Module):
         cfg = self.config
         dense = partial(nn.Dense, use_bias=cfg.mlp_bias_enabled,
                         dtype=cfg.jnp_dtype, param_dtype=jnp.float32)
-        act = {"relu": nn.relu, "gelu": nn.gelu,
-               "gelu_exact": partial(nn.gelu, approximate=False),
-               "silu": nn.silu,
-               # clip text encoder: x * sigmoid(1.702 x)
-               "quick_gelu": lambda x: x * nn.sigmoid(1.702 * x)}[cfg.activation]
+        act = ACTIVATIONS[cfg.activation]
         if cfg.gated_mlp:
             gate = dense(cfg.ffn_size, name="gate_proj")(x)
             up = dense(cfg.ffn_size, name="up_proj")(x)
@@ -733,23 +764,31 @@ def _is_moe_layer(cfg, layer_idx):
     return layer_idx >= off and (layer_idx - off) % cfg.moe_every == 0
 
 
-def _block_mlp(cfg, layer_idx, h, train=True):
+def _block_mlp(cfg, layer_idx, h, train=True, live=None):
     """Dense MLP or MoE for one block; returns (out, aux_loss).  A plain
     function (submodules attach to the calling compact method) so flax's
     module summary never re-invokes it as a standalone module method.
     ``train`` selects the gate's capacity/noise regime (reference
-    ``TopKGate`` train vs eval capacity)."""
+    ``TopKGate`` train vs eval capacity).  ``live`` ([B, S] bool, serving
+    only): the tokens an expert layer may route — a dead decode lane or a
+    chunk's padded tail takes no expert; a dense MLP ignores it."""
     if not _is_moe_layer(cfg, layer_idx):
         return MLP(cfg, name="mlp")(h), 0.0
     from deepspeed_tpu.moe.layer import MoE
+    # gated experts follow gated_mlp and share the dense MLP's activation
+    # table; the un-gated Megatron experts keep their gelu
+    gated = dict(gated=True, activation=ACTIVATIONS[cfg.activation]) \
+        if cfg.gated_mlp else {}
     out, aux, _ = MoE(hidden_size=cfg.hidden_size,
                       num_experts=cfg.moe_num_experts,
                       ep_size=cfg.moe_ep_size, k=cfg.moe_top_k,
                       capacity_factor=cfg.moe_capacity_factor,
                       eval_capacity_factor=cfg.moe_eval_capacity_factor,
+                      norm_topk_prob=cfg.moe_norm_topk_prob,
                       ffn_hidden_size=cfg.ffn_size,
                       expert_bias=cfg.moe_expert_bias,
-                      dtype=cfg.jnp_dtype, name="moe_mlp")(h, train=train)
+                      dtype=cfg.jnp_dtype, name="moe_mlp", **gated)(
+        h, train=train, live=live)
     return out.astype(cfg.jnp_dtype), aux
 
 
@@ -760,7 +799,7 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, mask=None, cache=None, train=True,
-                 prefill=False):
+                 prefill=False, live=None):
         # ``prefill``: STATIC bool — this call is a from-zero multi-token
         # prefill, so attention can take the flash path over the fresh
         # q/k/v (see Attention).  Threaded as a positional static arg
@@ -773,7 +812,8 @@ class Block(nn.Module):
                                         name="attn")(x, positions, mask,
                                                      cache, prefill=prefill)
             x = _norm(cfg, "input_norm")(x + attn).astype(cfg.jnp_dtype)
-            mlp_out, aux = _block_mlp(cfg, self.layer_idx, x, train=train)
+            mlp_out, aux = _block_mlp(cfg, self.layer_idx, x, train=train,
+                                      live=live)
             x = _norm(cfg, "post_attn_norm")(x + mlp_out).astype(cfg.jnp_dtype)
             return x, new_cache, aux
         normed = _norm(cfg, "input_norm")(x).astype(cfg.jnp_dtype)
@@ -784,14 +824,14 @@ class Block(nn.Module):
             mlp_in = normed if cfg.shared_attn_mlp_norm else \
                 _norm(cfg, "post_attn_norm")(x).astype(cfg.jnp_dtype)
             mlp_out, aux = _block_mlp(cfg, self.layer_idx, mlp_in,
-                                      train=train)
+                                      train=train, live=live)
             x = x + attn + mlp_out
         else:
             x = x + attn
             mlp_out, aux = _block_mlp(
                 cfg, self.layer_idx,
                 _norm(cfg, "post_attn_norm")(x).astype(cfg.jnp_dtype),
-                train=train)
+                train=train, live=live)
             x = x + mlp_out
         return x, new_cache, aux
 
@@ -871,8 +911,11 @@ class Transformer(nn.Module):
                                     name="lm_head")
 
     def hidden_states(self, input_ids, mask=None, cache=None, start_pos=0,
-                      with_aux=False, train=True):
+                      with_aux=False, train=True, live=None):
         cfg = self.config
+        if live is not None and cfg.scan_layers:
+            raise ValueError("live= reaches expert layers only, and an "
+                             "expert trunk is never scanned")
         B, S = input_ids.shape
         # start_pos: scalar, or [B] per-row offsets (padded-prompt decode —
         # each row continues from its own prompt length).  The RANK of
@@ -930,7 +973,7 @@ class Transformer(nn.Module):
                 # train/prefill positional: static_argnums only covers
                 # positionals
                 x, nc, a = blk(x, positions, mask, layer_cache, train,
-                               prefill)
+                               prefill, live)
                 if cur is not None:
                     cur = _cache_data(nc)
                 aux = aux + a
@@ -982,9 +1025,14 @@ class Transformer(nn.Module):
     def logits(self, input_ids, mask=None):
         return self._head(self.hidden_states(input_ids, mask, train=False))
 
-    def decode(self, input_ids, cache, start_pos, logits_at=None):
+    def decode(self, input_ids, cache, start_pos, logits_at=None, live=None):
         """KV-cached decode/prefill step: returns (logits, new_cache).
         ``input_ids``: [B, S_step]; positions are ``start_pos + arange``.
+
+        ``live`` ([B, S_step] bool, optional): the tokens that are real —
+        the serving programs pass it for a model with expert layers, so
+        that dead lanes and a chunk's padded tail are routed nowhere
+        (``moe/dropless.py``).
 
         ``logits_at`` ([B] int32, optional): project ONLY these per-row
         positions through the vocab head, returning [B, 1, V].  Generation
@@ -992,7 +1040,8 @@ class Transformer(nn.Module):
         [B, S, V] prefill logits are a multi-GB temporary at long prompts
         (bs16 x 3968 x 50k vocab = 6.4 GB bf16) that OOMs a 16 GB chip."""
         h, new_cache = self.hidden_states(input_ids, cache=cache,
-                                          start_pos=start_pos, train=False)
+                                          start_pos=start_pos, train=False,
+                                          live=live)
         if logits_at is not None:
             h = jnp.take_along_axis(
                 h, logits_at.astype(jnp.int32)[:, None, None], axis=1)

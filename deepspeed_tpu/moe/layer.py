@@ -15,36 +15,56 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.moe.sharded_moe import TopKGate, moe_dispatch_combine
 
 
 class ExpertsMLP(nn.Module):
     """Default expert: the standard 2-layer MLP, vectorized over experts
     (reference wraps arbitrary expert modules; ``Experts`` replicates them —
-    here one einsum-batched module computes all local experts on the MXU)."""
+    here one einsum-batched module computes all local experts on the MXU).
+    ``gated``: the three-matrix form ``(act(x wg) * (x wi)) wo`` (SwiGLU
+    with ``activation=silu``; OLMoE, Mixtral).
+
+    Two call forms over the same parameters: ``experts(x [E, C, M])`` —
+    the GShard batch, one capacity-sized queue an expert — and
+    ``experts(tokens [T, M], routed=(combine, counts))`` — the dropless
+    layer of ``moe/dropless.py``, returning ``[T, M]``."""
     num_experts: int
     hidden_size: int
     ffn_hidden_size: int
     activation: Callable = nn.gelu
     dtype: Any = jnp.bfloat16
     use_bias: bool = False
+    gated: bool = False
 
     @nn.compact
-    def __call__(self, x):
-        # x: [E, C, M]
+    def __call__(self, x, routed=None):
         E, M, F = self.num_experts, self.hidden_size, self.ffn_hidden_size
         wi = self.param("experts_wi", nn.initializers.lecun_normal(),
-                        (E, M, F), jnp.float32)
+                        (E, M, F), jnp.float32).astype(x.dtype)
         wo = self.param("experts_wo", nn.initializers.lecun_normal(),
-                        (E, F, M), jnp.float32)
-        h = jnp.einsum("ecm,emf->ecf", x, wi.astype(x.dtype))
+                        (E, F, M), jnp.float32).astype(x.dtype)
+        wg = self.param("experts_wg", nn.initializers.lecun_normal(),
+                        (E, M, F), jnp.float32).astype(x.dtype) \
+            if self.gated else None
+        if routed is not None:
+            if self.use_bias:
+                raise ValueError("the dropless expert kernel carries no "
+                                 "per-expert biases")
+            return dropless.experts(x, *routed, wg, wi, wo, self.activation)
+        # x: [E, C, M]
+        h = jnp.einsum("ecm,emf->ecf", x, wi)
         if self.use_bias:
             # Megatron-style experts carry per-expert biases
             bi = self.param("experts_bi", nn.initializers.zeros, (E, F),
                             jnp.float32)
             h = h + bi[:, None, :].astype(x.dtype)
-        h = self.activation(h)
-        y = jnp.einsum("ecf,efm->ecm", h, wo.astype(x.dtype))
+        if self.gated:
+            h = self.activation(jnp.einsum("ecm,emf->ecf", x, wg)) * h
+        else:
+            h = self.activation(h)
+        y = jnp.einsum("ecf,efm->ecm", h, wo)
         if self.use_bias:
             bo = self.param("experts_bo", nn.initializers.zeros, (E, M),
                             jnp.float32)
@@ -57,12 +77,18 @@ class MoE(nn.Module):
 
     ``__call__(x)`` with x [..., M] returns (y, aux_loss, exp_counts) —
     the reference's output triple.
+
+    ``capacity_factor=None`` is the DROPLESS layer (``moe/dropless.py``):
+    no capacity and no dropped token in either regime, ``aux_loss`` 0,
+    ``exp_counts`` int32 over the ``live`` tokens only — also sown as
+    ``moe_stats/expert_tokens`` for a caller that applies the model with
+    that collection mutable (the serving programs).
     """
     hidden_size: int
     num_experts: int = 1
     ep_size: int = 1
     k: int = 1
-    capacity_factor: float = 1.0
+    capacity_factor: Optional[float] = 1.0
     eval_capacity_factor: float = 1.0
     min_capacity: int = 4
     noisy_gate_policy: Optional[str] = None
@@ -72,26 +98,41 @@ class MoE(nn.Module):
     expert: Optional[nn.Module] = None
     dtype: Any = jnp.bfloat16
     expert_bias: bool = False
+    gated: bool = False
+    activation: Callable = nn.gelu
+    norm_topk_prob: bool = True
 
     @nn.compact
-    def __call__(self, x, train=True):
+    def __call__(self, x, train=True, live=None):
         M = self.hidden_size
         orig_shape = x.shape
         tokens = x.reshape(-1, M)
 
         gate_w = self.param("gate_kernel", nn.initializers.lecun_normal(),
                             (M, self.num_experts), jnp.float32)
+        experts = self.expert or ExpertsMLP(
+            self.num_experts, M, self.ffn_hidden_size or 4 * M,
+            activation=self.activation, dtype=self.dtype,
+            use_bias=self.expert_bias, gated=self.gated)
+        if self.capacity_factor is None:
+            combine, exp_counts = dropless.route(
+                tokens, gate_w, self.k,
+                renormalize=self.norm_topk_prob and self.k > 1,
+                live=None if live is None else live.reshape(-1))
+            y = experts(tokens, routed=(combine, exp_counts))
+            if not self.is_initializing():
+                self.sow("moe_stats", "expert_tokens", exp_counts,
+                         reduce_fn=lambda _, new: new, init_fn=lambda: None)
+            return y.reshape(orig_shape).astype(x.dtype), 0.0, exp_counts
+
         logits = tokens.astype(jnp.float32) @ gate_w
         gate = TopKGate(M, self.num_experts, self.k, self.capacity_factor,
                         self.eval_capacity_factor, self.min_capacity,
-                        self.noisy_gate_policy, self.drop_tokens)
+                        self.noisy_gate_policy, self.drop_tokens,
+                        norm_topk_prob=self.norm_topk_prob)
         rng = self.make_rng("gating") if (train and self.noisy_gate_policy
                                           and self.has_rng("gating")) else None
         aux_loss, combine, dispatch, exp_counts = gate(logits, train, rng)
-
-        experts = self.expert or ExpertsMLP(
-            self.num_experts, M, self.ffn_hidden_size or 4 * M,
-            dtype=self.dtype, use_bias=self.expert_bias)
         y = moe_dispatch_combine(tokens, combine, dispatch, experts)
 
         if self.use_residual:

@@ -73,9 +73,12 @@ def top1gating(logits, capacity_factor=1.0, min_capacity=4,
 
 
 def topkgating(logits, k=2, capacity_factor=1.0, min_capacity=4,
-               noisy_gate_policy=None, rng=None, drop_tokens=True):
-    """Top-k gating with normalized top-k gates (reference top2gating
-    ``sharded_moe.py:277`` generalized)."""
+               noisy_gate_policy=None, rng=None, drop_tokens=True,
+               norm_topk_prob=True):
+    """Top-k gating (reference top2gating ``sharded_moe.py:277``
+    generalized).  ``norm_topk_prob``: divide the chosen gates by their
+    sum, as the reference does; False uses them as the softmax gives them
+    (HF ``norm_topk_prob: false`` — OLMoE)."""
     T, E = logits.shape
     C = _capacity(T, E, capacity_factor, min_capacity, k=k)
     if noisy_gate_policy == "RSample" and rng is not None:
@@ -107,9 +110,9 @@ def topkgating(logits, k=2, capacity_factor=1.0, min_capacity=4,
         masked_logits = jnp.where(aux_masks[-1] > 0, -1e30, masked_logits)
         used = used + mask
 
-    # normalize by the sum of selected gates
-    denom = jnp.maximum(gate_sum, 1e-9)[:, :, None]
-    combine = combine / denom
+    if norm_topk_prob:
+        # normalize by the sum of selected gates
+        combine = combine / jnp.maximum(gate_sum, 1e-9)[:, :, None]
     me = jnp.mean(gates, axis=0)
     ce = jnp.mean(aux_masks[0], axis=0)
     aux_loss = jnp.sum(me * ce) * E
@@ -127,7 +130,8 @@ class TopKGate:
 
     def __init__(self, model_dim, num_experts, k=1, capacity_factor=1.0,
                  eval_capacity_factor=1.0, min_capacity=4,
-                 noisy_gate_policy=None, drop_tokens=True, use_rts=True):
+                 noisy_gate_policy=None, drop_tokens=True, use_rts=True,
+                 norm_topk_prob=True):
         self.model_dim = model_dim
         self.num_experts = num_experts
         self.k = k
@@ -136,6 +140,7 @@ class TopKGate:
         self.min_capacity = min_capacity
         self.noisy_gate_policy = noisy_gate_policy
         self.drop_tokens = drop_tokens
+        self.norm_topk_prob = norm_topk_prob
 
     def __call__(self, logits, train=True, rng=None):
         cf = self.capacity_factor if train else self.eval_capacity_factor
@@ -145,7 +150,7 @@ class TopKGate:
                               self.drop_tokens)
         return topkgating(logits, self.k, cf, self.min_capacity,
                           self.noisy_gate_policy if train else None, rng,
-                          self.drop_tokens)
+                          self.drop_tokens, self.norm_topk_prob)
 
 
 def moe_dispatch_combine(x, combine, dispatch, expert_fn):

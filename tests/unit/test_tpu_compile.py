@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from deepspeed_tpu.moe import dropless as moe_mod
 from deepspeed_tpu.ops.sparse_attention import block_sparse
 from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
                                            flash_attention as flash_mod,
@@ -61,7 +62,7 @@ def no_persistent_cache():
 def mosaic(monkeypatch, no_persistent_cache):
     """``_interpret()`` asks ``jax.default_backend()``, which is the CPU
     here: steer it in the test, not through an option of the program."""
-    for mod in (flash_mod, decode_mod, paged_mod, block_sparse):
+    for mod in (flash_mod, decode_mod, paged_mod, block_sparse, moe_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -111,7 +112,24 @@ def _mono_decode(batch=16, cache_len=1024):
                 ((batch, H, D), BF16), ((batch, H, D), BF16)]
 
 
+def _moe_experts(tokens, hidden=2048, experts=64, width=1024, top_k=8):
+    """OLMoE-1B-7B's expert layer (``moe.route`` + ``moe.experts_gmm``) at
+    a serving program's token count: 64 decode lanes, or one chunk."""
+    up = ((experts, hidden, width), BF16)
+
+    def fn(x, gate_w, live, wg, wu, wd):
+        combine, counts = moe_mod.route(x, gate_w, top_k, live=live)
+        return moe_mod.experts(x, combine, counts, wg, wu, wd,
+                               jax.nn.silu), counts
+    return fn, [((tokens, hidden), BF16), ((hidden, experts), BF16),
+                ((tokens,), jnp.bool_), up, up,
+                ((experts, width, hidden), BF16)]
+
+
 CASES = {
+    "moe_experts_olmoe_t64": lambda: _moe_experts(64),
+    "moe_experts_olmoe_t128": lambda: _moe_experts(128),
+    "moe_experts_olmoe_t512": lambda: _moe_experts(512),
     "flash_fwd_bwd_s2048": _flash_fwd_bwd,
     "paged_decode_bf16_fused_write_p64": lambda: _paged_decode(False),
     "paged_decode_int8kv_fused_write_p64": lambda: _paged_decode(True),

@@ -2162,7 +2162,8 @@ class ServingEngine:
         try:
             with self._observe_dispatch("prefill_chunk", rid=p.req.rid,
                                         slot=p.slot, chunk=p.ci,
-                                        phase="prefill"):
+                                        phase="prefill",
+                                        **self._chunk_kv_work(p)):
                 if self.paged:
                     # the chunk writes straight into the slot's pool
                     # pages — the POOL is the donated buffer, chained
@@ -2261,6 +2262,20 @@ class ServingEngine:
         p.ci += 1
         self.stats["prefill_tokens"] += C
         return p.ci >= p.n_chunks
+
+    def _chunk_kv_work(self, p):  # lock-held: _lock
+        """What the prefill chunk about to be dispatched attends, as the
+        dispatch span's args (paged engines): ``kv_pages`` — the pages
+        its layers fetch, every page of the slot's table up to the
+        chunk's furthest position, which is the paged chunk-prefill
+        kernel's block loop — and ``kv_pages_table``, pages a slot x
+        layers, what a walk over the whole table would take."""
+        if not self.paged:
+            return {}
+        layers = self.module.config.num_layers
+        reach = -(-(p.start + (p.ci + 1) * self.chunk) // self.page)
+        return {"kv_pages": layers * min(reach, self.n_slot_pages),
+                "kv_pages_table": layers * self.n_slot_pages}
 
     def _dispatch_admit(self, p):  # lock-held: _lock
         """Prefill complete: ONE fused dispatch samples the first token,

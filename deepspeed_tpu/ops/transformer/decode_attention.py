@@ -39,7 +39,11 @@ state and the per-block update (``_init_row`` / ``_block_update`` /
 ``_finish_row`` / ``_write_stripe``) are shared, the drivers are separate —
 ``_decode_kernel`` here walks a contiguous cache on a ``(B, nk)`` grid;
 ``paged_attention._paged_decode_kernel`` walks a page table with an
-in-kernel loop over the row's live pages.
+in-kernel loop over the row's live pages.  Chunked prefill is split the
+same way (``_init_chunk`` / ``_chunk_block_update`` / ``_finish_chunk``):
+``_chunk_prefill_kernel`` walks a contiguous cache on a ``(B, nk)`` grid,
+``paged_attention._paged_chunk_kernel`` folds the chunk's reachable pages
+in blocks of ~512 keys inside one grid step.
 """
 
 import functools
@@ -350,83 +354,198 @@ def _decode_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
                           kvh=kvh, d=d)
 
 
+# Head groups one iteration of the chunk update's group loop folds in:
+# two let one group's softmax passes overlap the other's matmuls.  Measured
+# on v5e (PERF.md PR 28), us a layer-chunk at OPT-1.3B's widths, 13 and 28
+# pages reached: one 48.8 / 82.9, two 46.0 / 77.2, four 46.1 / 76.1 (and
+# 13 s to compile at a 512-token chunk against 6).
+_CHUNK_GROUP_UNROLL = 2
+
+
+class _ChunkState(NamedTuple):
+    """One batch row's chunk-prefill state: the refs both chunk drivers
+    (the grid walk over a contiguous cache here, the in-kernel block loop
+    of ``paged_attention``) hand to :func:`_init_chunk`,
+    :func:`_chunk_block_update` and :func:`_finish_chunk`.  The running
+    max and sum are kept per head as lane-dense ``[C, LSE_LANES]`` tiles
+    (``[H, C, LSE_LANES]`` scratch, the flash kernels' layout) — a head's
+    update reads and writes whole tiles, never one lane of a ``[C, H]``
+    one."""
+    q_ref: Any                               # [1, C, H*D]
+    m_scr: Any                               # [H, C, LSE_LANES]
+    l_scr: Any                               # [H, C, LSE_LANES]
+    acc_scr: Any                             # [C, H*D]
+
+
+def _chunk_scratch(c, h, d):
+    return [pltpu.VMEM((h, c, LSE_LANES), jnp.float32),   # running max
+            pltpu.VMEM((h, c, LSE_LANES), jnp.float32),   # running sum
+            pltpu.VMEM((c, h * d), jnp.float32)]          # per-head acc
+
+
+def _chunk_scratch_bytes(c, h, d):
+    # a [C, LSE_LANES] float32 tile pads to 128 lanes in VMEM
+    return 2 * h * c * 128 * 4 + c * h * d * 4
+
+
+def _chunk_grid_vmem_bytes(c, h, d, block_k, kvhd, itemsize):
+    """The VMEM the grid-walk chunk kernel asks for: K and V blocks
+    (double-buffered by the pipeline), the q and output blocks, the
+    online-softmax scratch, and headroom."""
+    return max(64 * 1024 * 1024,
+               4 * block_k * kvhd * itemsize + c * h * d * 4
+               + _chunk_scratch_bytes(c, h, d) + 16 * 1024 * 1024)
+
+
+def _init_chunk(st):
+    st.m_scr[...] = jnp.full_like(st.m_scr, NEG_INF)
+    st.l_scr[...] = jnp.zeros_like(st.l_scr)
+    st.acc_scr[...] = jnp.zeros_like(st.acc_scr)
+
+
+def _chunk_block_update(st, ik, start, k_ref, v_ref, ks, vs, *, scale,
+                        block_k, c, kvh, g, d, masked=True):
+    """Fold KV block ``ik`` (virtual positions ``ik*block_k ..``) into the
+    chunk's online-softmax state: per head ONE ``[C, D] x [D, bk]`` score
+    matmul, a ``[C, bk]`` float32 tile for max / exp / sum, ONE
+    ``[C, bk] x [bk, D]`` value matmul and one rescale of the head's
+    accumulator slice.  ``k_ref``/``v_ref``: the block's ``[bk, KVH*D]``
+    slabs (refs); ``ks``/``vs``: its ``[bk, KVH]`` dequant scales (None
+    for an unquantized cache).  ``masked=False`` is for a block wholly
+    under the causal diagonal (every position ``<= start``): the same
+    numbers without the mask's compares and selects.  The one per-block
+    update of chunked prefill, whichever driver supplies the block
+    sequence.
+
+    Heads are walked in GROUPS of whole 128-lane tiles of the slabs (two
+    kv heads of 64, one of 128, with the ``g`` query heads of each): a
+    group's columns are a tile-aligned slice that Mosaic takes at a
+    dynamic offset, so the walk is a ``fori_loop`` whose body is traced
+    and compiled once — unrolled over 32 heads of [C, 512] tiles the
+    kernel took 10 s to compile.  A head size that does not tile 128
+    lanes (tiny test models) unrolls statically, one head a group."""
+    quant = ks is not None
+    if quant:
+        kst = ks.astype(jnp.float32).T                   # [KVH, bk]
+        vst = vs.astype(jnp.float32).T
+    if masked:
+        pos = ik * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)                  # [1, bk]
+        qpos = start + jax.lax.broadcasted_iota(
+            jnp.int32, (c, 1), 0)                        # [C, 1]
+        live = pos <= qpos                               # [C, bk] causal+tail
+    hpg = 128 // d if 128 % d == 0 and kvh % (128 // d) == 0 else 1
+    lanes, qlanes = hpg * d, hpg * g * d
+    tiled = lanes % 128 == 0 and not quant
+
+    def aligned(x):
+        return pl.multiple_of(x, 128) if tiled else x
+
+    def group(j):
+        # kv heads j*hpg .. (j+1)*hpg and their hpg*g query heads
+        kcols = pl.ds(aligned(j * lanes), lanes)
+        qcols = pl.ds(aligned(j * qlanes), qlanes)
+        qg = st.q_ref[0, :, qcols]                       # [C, hpg*g*D]
+        kg = k_ref[:, kcols]                             # [bk, hpg*D]
+        vg = v_ref[:, kcols]
+        accg = st.acc_scr[:, qcols]
+        parts = []
+        for t in range(hpg * g):
+            h, hk = j * hpg * g + t, j * hpg + t // g
+            cols = slice(t * d, (t + 1) * d)
+            kvcols = slice(t // g * d, (t // g + 1) * d)
+            qh, kh, vh = qg[:, cols], kg[:, kvcols], vg[:, kvcols]
+            if quant:
+                kh = kh.astype(qh.dtype)
+                vh = vh.astype(qh.dtype)
+            s = jax.lax.dot_general(
+                qh, kh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if quant:
+                s = s * kst[hk:hk + 1]                   # [1, bk] k-scales
+            if masked:
+                s = jnp.where(live, s, NEG_INF)
+            m_prev = st.m_scr[h, :, 0:1]                 # [C, 1]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if masked:
+                p = jnp.where(live, p, 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = st.l_scr[h, :, 0:1] * corr + jnp.sum(p, axis=1,
+                                                          keepdims=True)
+            st.l_scr[h] = jnp.broadcast_to(l_new, (c, LSE_LANES))
+            st.m_scr[h] = jnp.broadcast_to(m_new, (c, LSE_LANES))
+            if quant:
+                p = p * vst[hk:hk + 1]                   # v-scales on P
+            o = jax.lax.dot_general(
+                p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)      # [C, D]
+            parts.append(accg[:, cols] * corr + o)
+        st.acc_scr[:, qcols] = parts[0] if len(parts) == 1 \
+            else jnp.concatenate(parts, axis=1)
+
+    if tiled:
+        n_groups = kvh // hpg
+        per = _CHUNK_GROUP_UNROLL if n_groups % _CHUNK_GROUP_UNROLL == 0 \
+            else 1
+
+        def body(jj, carry):
+            for u in range(per):
+                group(jj * per + u)
+            return carry
+        jax.lax.fori_loop(0, n_groups // per, body, None)
+    else:
+        for j in range(kvh // hpg):
+            group(j)
+
+
+def _finish_chunk(st, o_ref, *, heads, d):
+    for h in range(heads):
+        cols = slice(h * d, (h + 1) * d)
+        l = st.l_scr[h, :, 0:1]
+        safe_l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, :, cols] = (st.acc_scr[:, cols] / safe_l).astype(o_ref.dtype)
+
+
 def _chunk_prefill_kernel(start_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
                           scale, block_k, nk, c, kvh, g, d, stacked, quant):
     """Multi-token (chunk) prefill against the cache: rows ``iq`` of the
     chunk attend causally to cache positions ``<= start_b + iq``.  Same
     slab layout + online softmax as ``_decode_kernel``, but with a [C, bk]
     score tile per head instead of the block-diagonal all-heads trick
-    (C×H rows would not fit one matmul)."""
+    (C×H rows would not fit one matmul).  The grid-walk driver: grid
+    ``(B, nk)``, the block location resolved by the BlockSpec index
+    maps."""
+    ks_ref = vs_ref = None
+    rest = list(rest)
     if quant:
-        (ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr) = rest
-    else:
-        ks_ref = vs_ref = None
-        (o_ref, m_scr, l_scr, acc_scr) = rest
+        ks_ref, vs_ref = rest[:2]
+        del rest[:2]
+    o_ref, m_scr, l_scr, acc_scr = rest
+    st = _ChunkState(q_ref, m_scr, l_scr, acc_scr)
+    lead = (0, 0) if stacked else (0,)       # this cell's block
     b = pl.program_id(0)
     ik = pl.program_id(1)
-    h_total = kvh * g
 
     @pl.when(ik == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        _init_chunk(st)
 
     start = start_ref[b]
     limit = start + c                       # rows reach pos <= start+c-1
-    run = ik * block_k < limit
 
-    @pl.when(run)
+    @pl.when(ik * block_k < limit)
     def _body():
-        k = k_ref[0, 0] if stacked else k_ref[0]         # [bk, KVH*D]
-        v = v_ref[0, 0] if stacked else v_ref[0]
-        if quant:
-            k = k.astype(q_ref.dtype)
-            v = v.astype(q_ref.dtype)
-            kst = (ks_ref[0, 0] if stacked else ks_ref[0]) \
-                .astype(jnp.float32).T                   # [KVH, bk]
-            vst = (vs_ref[0, 0] if stacked else vs_ref[0]) \
-                .astype(jnp.float32).T
-        pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)                  # [1, bk]
-        qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (c, 1), 0)                        # [C, 1]
-        live = pos <= qpos                               # [C, bk] causal+tail
-        q_all = q_ref[0]                                 # [C, H*D]
-        for h in range(h_total):
-            hk = h // g
-            qh = q_all[:, h * d:(h + 1) * d]             # [C, D]
-            kh = k[:, hk * d:(hk + 1) * d]               # [bk, D]
-            s = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if quant:
-                s = s * kst[hk:hk + 1]                   # [1, bk] k-scales
-            s = jnp.where(live, s, NEG_INF)
-            m_prev = m_scr[:, h:h + 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            p = jnp.where(live, p, 0.0)
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[:, h:h + 1] = (l_scr[:, h:h + 1] * corr
-                                 + jnp.sum(p, axis=1, keepdims=True))
-            m_scr[:, h:h + 1] = m_new
-            if quant:
-                p = p * vst[hk:hk + 1]                   # v-scales on P
-            o = jax.lax.dot_general(
-                p.astype(v.dtype), v[:, hk * d:(hk + 1) * d],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [C, D]
-            acc_scr[:, h * d:(h + 1) * d] = \
-                acc_scr[:, h * d:(h + 1) * d] * corr + o
+        _chunk_block_update(
+            st, ik, start, k_ref.at[lead], v_ref.at[lead],
+            ks_ref[lead] if quant else None,
+            vs_ref[lead] if quant else None,
+            scale=scale, block_k=block_k, c=c, kvh=kvh, g=g, d=d)
 
     @pl.when(ik == nk - 1)
     def _finish():
-        for h in range(h_total):
-            l = l_scr[:, h:h + 1]
-            safe_l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, :, h * d:(h + 1) * d] = \
-                (acc_scr[:, h * d:(h + 1) * d] / safe_l).astype(o_ref.dtype)
+        _finish_chunk(st, o_ref, heads=kvh * g, d=d)
 
 
 def chunk_prefill_attention(q, k_cache, v_cache, starts, scale=None,
@@ -508,18 +627,12 @@ def chunk_prefill_attention(q, k_cache, v_cache, starts, scale=None,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, C, H * D),
                                    lambda b, ik, st, li: (b, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((C, H), jnp.float32),         # running max
-                pltpu.VMEM((C, H), jnp.float32),         # running sum
-                pltpu.VMEM((C, H * D), jnp.float32),     # per-head acc
-            ]),
+            scratch_shapes=_chunk_scratch(C, H, D)),
         out_shape=jax.ShapeDtypeStruct((B, C, H * D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=max(
-                64 * 1024 * 1024,
-                4 * block_k * KVHD * q.dtype.itemsize
-                + 2 * C * H * D * 4 + 16 * 1024 * 1024)),
+            vmem_limit_bytes=_chunk_grid_vmem_bytes(
+                C, H, D, block_k, KVHD, q.dtype.itemsize)),
         interpret=_interpret(),
         name="attn.chunk_prefill",
     )(jnp.asarray(starts, jnp.int32), layer_arr, *operands)

@@ -49,22 +49,61 @@ each row:
   it.  Dead rows are presented to it with length 0, so they skip the
   arithmetic (not the grid steps).
 
-**Chunked prefill** (:func:`paged_chunk_prefill_attention`) still walks
-the table: the page table rides as a THIRD scalar-prefetch operand,
-input index maps resolve ``(layer, pages[b, virt], 0, 0)``, and pages
-past the live region pin to the last live page, so Mosaic elides their
-DMA and the body is ``pl.when``-gated off — the grid step itself is
-still paid (B = 1 there; the next kernel to move to the loop).
+**Chunked prefill** (:func:`paged_chunk_prefill_attention`) folds the
+REACHABLE pages of each row — those up to the chunk's furthest position,
+``ceil((start + C) / page_size)`` — in wide blocks:
 
-How far the live-page walk engages in serving is on the
-``dstpu.sched.dispatch.decode`` span: ``kv_pages`` (pages the block's
-steps walk) against ``kv_pages_table`` (slots x pages a slot x steps).
+* ``grid=(B,)`` (B = 1 in the serving chunk step), the pools whole in
+  HBM.  Inside the step a ``fori_loop`` over KV blocks of
+  ``min(ceil(512 / page_size), pages a slot)`` pages — a shape-derived
+  constant — fetches each page of a block through the table with
+  ``make_async_copy`` into its rows of a ``[pages * page_size, KVH*D]``
+  VMEM buffer, double-buffered so block i+1 arrives while block i is
+  folded in.  Grid steps, DMAs and arithmetic are O(reachable pages).
+* Per block and head: ONE ``[C, D] x [D, 512]`` score matmul, a
+  lane-dense ``[C, 512]`` float32 tile for max / exp / sum, ONE
+  ``[C, 512] x [512, D]`` value matmul and one rescale of the head's
+  accumulator slice (``decode_attention._chunk_block_update``, the
+  monolithic chunk kernel's own update); the running max and sum are
+  ``[C, LSE_LANES]`` tiles a head.  Heads are walked by a ``fori_loop``
+  over 128-lane groups of the slab, so the body compiles once.  (The
+  grid-per-page form this replaces did two 128x64x64 matmuls, a
+  half-vreg score tile, two single-lane column updates and a rescale for
+  every (head, 64-key page): ~20 us a reachable page, 3% of the chip's
+  roofline — PERF.md PR 28.)
+* The causal mask is applied only in blocks that reach ``start``; blocks
+  wholly under the diagonal run the same arithmetic without its compares
+  and selects.
+* The tail block is partly filled: rows of pages past the reachable
+  ones keep what an earlier block left, and the last page's rows past
+  the chunk hold whatever the slot's previous tenant wrote.  Scores
+  there are masked by the update; the VALUE rows are zeroed in the
+  buffer before the matmul (a probability of 0 against a NaN is a NaN).
+* The call itself is jitted with the layer index a TRACED operand
+  (``_paged_chunk_call``): an unrolled model calls it once a layer with
+  the same shapes, so the kernel is traced and lowered to Mosaic once a
+  program — the 24-layer chunk step builds in 14 s where it took 29
+  (PERF.md PR 28).
+* int8 pools, and pages that are not whole sublane tiles of the pool's
+  dtype, keep the grid walk over virtual pages (the monolithic
+  ``_chunk_prefill_kernel`` driver behind table index maps; pages past
+  the reachable ones pin to the last, so their DMA is elided and their
+  body ``pl.when``-gated off): the scale page cannot be fetched by hand
+  (above).
 
-Numerics: the page sequence and the arithmetic per page are those of
+How far the live-page walks engage in serving is on the dispatch spans:
+``dstpu.sched.dispatch.decode`` carries ``kv_pages`` (pages the block's
+steps walk) against ``kv_pages_table`` (slots x pages a slot x steps),
+``dstpu.sched.dispatch.prefill_chunk`` ``kv_pages`` (pages the chunk's
+layers fetch) against ``kv_pages_table`` (pages a slot x layers).
+
+Numerics: decode's page sequence and arithmetic per page are those of
 ``decode_attention(block_k=page_size)`` over the gathered virtual view,
-so the two are BITWISE equal (regression-tested in
-tests/unit/test_paged_attention.py); greedy serving outputs stay bitwise
-equal to the monolithic engine as before.
+so the two are BITWISE equal; chunked prefill's are those of
+``chunk_prefill_attention(block_k=pages-a-block * page_size)`` over the
+gathered view, bitwise again (an int8 pool: ``block_k=page_size``) —
+both regression-tested in tests/unit/test_paged_attention.py.  Greedy
+serving outputs stay equal to solo ``generate()`` token for token.
 """
 
 import functools
@@ -77,14 +116,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.transformer.decode_attention import (
-    _RowState, _block_update, _chunk_prefill_kernel, _decode_kernel,
-    _finish_row, _init_row, _write_stripe)
+    _ChunkState, _RowState, _block_update, _chunk_block_update,
+    _chunk_grid_vmem_bytes, _chunk_prefill_kernel, _chunk_scratch,
+    _chunk_scratch_bytes,
+    _decode_kernel, _finish_chunk, _finish_row, _init_chunk, _init_row,
+    _write_stripe)
 from deepspeed_tpu.ops.transformer.flash_attention import LSE_LANES, _interpret
 
 # VMEM ring of the decode loop: the page being folded in plus two fetches
 # behind it.  Measured on v5e at the serving cells' shapes (PERF.md PR 26):
 # two buffers 132 us a layer-step, three 117, four 117.
 _DECODE_PAGE_BUFFERS = 3
+
+# Keys one block of the chunk-prefill loop folds at once: the score tile is
+# [C, 512] float32 (whole vregs along lanes) and the two matmuls a head get
+# an N / a K of 512, where a 64-key page gave them 64.
+_CHUNK_BLOCK_KEYS = 512
 
 
 def _live_pages(lens, pages, b, page, nk):
@@ -180,6 +227,83 @@ def _paged_grid_decode_body(len_ref, layer_ref, pages_ref, *args, **kw):
 def _paged_chunk_body(start_ref, layer_ref, pages_ref, *args, **kw):
     del pages_ref
     _chunk_prefill_kernel(start_ref, layer_ref, *args, **kw)
+
+
+def _chunk_block_pages(page, nk):
+    """Pages a block of the chunk-prefill loop holds: ~512 keys' worth,
+    at most the table's width."""
+    return min(-(-_CHUNK_BLOCK_KEYS // page), nk)
+
+
+def _reachable_pages(start, c, page, nk):
+    """Pages of a row a chunk of ``c`` queries starting at ``start`` can
+    reach: its furthest position is ``start + c - 1``."""
+    return jnp.clip((start + c + page - 1) // page, 1, nk)
+
+
+def _paged_chunk_kernel(start_ref, layer_ref, pages_ref, q_ref, k_hbm, v_hbm,
+                        o_ref, m_scr, l_scr, acc_scr, kbuf, vbuf, sem, *,
+                        scale, page, nk, bp, c, kvh, g, d):
+    """The block-loop driver: one grid step a batch row, the pools whole
+    in HBM.  The row's REACHABLE pages are fetched through the table,
+    ``bp`` at a time, into the rows of a double-buffered ``[bp * page,
+    KVH*D]`` block (block i+1 arrives while block i is folded in) and
+    each block goes through the monolithic kernel's per-block update —
+    unmasked while the block lies wholly under the causal diagonal."""
+    st = _ChunkState(q_ref, m_scr, l_scr, acc_scr)
+    b = pl.program_id(0)
+    li = layer_ref[0]
+    start = start_ref[b]
+    limit = start + c                       # rows reach pos <= limit - 1
+    bk = bp * page
+    n_pages = _reachable_pages(start, c, page, nk)
+    n_blocks = (n_pages + bp - 1) // bp
+    # blocks whose every position is <= start need no causal mask
+    n_under = jnp.minimum((start + 1) // bk, n_blocks)
+
+    def each_page(i, fn):
+        # virtual page i*bp + j of this row → rows j*page.. of buffer
+        # i % 2; a wait rebuilds the descriptors its start used.  Pages
+        # past the reachable ones are not fetched: the tail block's rows
+        # there keep what an earlier block left.
+        def one(j, carry):
+            pg, slot = pages_ref[b, i * bp + j], i % 2
+            rows = pl.ds(pl.multiple_of(j * page, page), page)
+            for n, (src, dst) in enumerate([(k_hbm, kbuf), (v_hbm, vbuf)]):
+                fn(pltpu.make_async_copy(src.at[li, pg], dst.at[slot, rows],
+                                         sem.at[n, slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n_pages - i * bp, 0, bp), one, None)
+
+    def fold(masked):
+        def body(i, carry):
+            each_page(i + 1, lambda cp: cp.start())
+            each_page(i, lambda cp: cp.wait())
+            slot = i % 2
+            if masked:
+                # rows no query reaches — the last page's tail and the
+                # tail block's unfetched pages — may hold anything, and
+                # a probability of 0 against a NaN is a NaN: the score
+                # side is masked by the update, the value side here
+                @pl.when((i + 1) * bk > limit)
+                def _zero_tail():
+                    pos = i * bk + jax.lax.broadcasted_iota(
+                        jnp.int32, (bk, 1), 0)
+                    v = vbuf[slot]
+                    vbuf[slot] = jnp.where(pos < limit, v,
+                                           jnp.zeros_like(v))
+            _chunk_block_update(st, i, start, kbuf.at[slot], vbuf.at[slot],
+                                None, None, scale=scale, block_k=bk, c=c,
+                                kvh=kvh, g=g, d=d, masked=masked)
+            return carry
+        return body
+
+    _init_chunk(st)
+    each_page(0, lambda cp: cp.start())
+    jax.lax.fori_loop(0, n_under, fold(False), None)
+    jax.lax.fori_loop(n_under, n_blocks, fold(True), None)
+    _finish_chunk(st, o_ref, heads=kvh * g, d=d)
 
 
 def _pool_dims(q, k_pool):
@@ -357,6 +481,17 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
     )(lengths, layer_arr, pages_arr, *operands)
 
 
+def _chunk_loop_vmem_bytes(c, h, d, bk, kvhd, kv_itemsize, q_itemsize):
+    """The VMEM the chunk kernel's block loop asks for: two K and two V
+    blocks, a head group's [C, bk] float32 score tiles, the q and output
+    blocks (double-buffered by the pipeline), the online-softmax
+    scratch, and headroom."""
+    return max(64 * 1024 * 1024,
+               4 * bk * kvhd * kv_itemsize + 6 * c * bk * 4
+               + 4 * c * h * d * q_itemsize + _chunk_scratch_bytes(c, h, d)
+               + 16 * 1024 * 1024)
+
+
 def paged_chunk_prefill_attention(q, k_pool, v_pool, starts, pages, *,
                                   scale=None, layer=None, k_scale=None,
                                   v_scale=None):
@@ -374,67 +509,92 @@ def paged_chunk_prefill_attention(q, k_pool, v_pool, starts, pages, *,
     start (query row ``iq`` masks virtual positions ``> starts[b]+iq``).
     Returns [B, C, H, D].
     """
-    B, C, H, D = q.shape
-    page, KVHD, KVH = _pool_dims(q, k_pool)
-    G = H // KVH
+    _pool_dims(q, k_pool)
     if layer is None:
         raise ValueError("layer-stacked pools require layer=")
-    quant = k_scale is not None
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
     if scale is None:
-        scale = 1.0 / float(np.sqrt(D))
-    nk = pages.shape[1]
-    layer_arr = jnp.asarray([layer], jnp.int32)
-    pages_arr = jnp.asarray(pages, jnp.int32)
+        scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    scales = () if k_scale is None else (k_scale, v_scale)
+    interpret = _interpret()                # a bool: static by value
+    return _paged_chunk_call(
+        q, k_pool, v_pool, jnp.asarray(starts, jnp.int32),
+        jnp.asarray([layer], jnp.int32), jnp.asarray(pages, jnp.int32),
+        *scales, scale=float(scale), interpret=interpret)
 
-    def _live_page(ik, st, b):
-        # the chunk's furthest reachable virtual position is st[b]+C-1
-        last = jnp.maximum((st[b] + C + page - 1) // page - 1, 0)
-        return jnp.minimum(ik, last)
 
-    kv_spec = pl.BlockSpec(
-        (1, 1, page, KVHD),
-        lambda b, ik, st, li, pg: (li[0], pg[b, _live_page(ik, st, b)],
-                                   0, 0))
-    sc_spec = pl.BlockSpec(
-        (1, 1, page, KVH),
-        lambda b, ik, st, li, pg: (li[0], pg[b, _live_page(ik, st, b)],
-                                   0, 0))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_chunk_call(q, k_pool, v_pool, starts, layer_arr, pages_arr,
+                      k_scale=None, v_scale=None, *, scale, interpret):
+    """The kernel call, jitted with the layer as a traced operand: an
+    unrolled model calls it once a layer with the same shapes, so the
+    kernel is traced to a jaxpr and lowered to Mosaic ONCE a program and
+    not once a layer (two thirds of a serving program's set-up is Python
+    tracing and lowering — PERF.md §5 `setup_s`)."""
+    B, C, H, D = q.shape
+    page, KVHD, KVH = _pool_dims(q, k_pool)
+    G = H // KVH
+    quant = k_scale is not None
+    nk = pages_arr.shape[1]
 
-    in_specs = [
-        pl.BlockSpec((1, C, H * D), lambda b, ik, st, li, pg: (b, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
-    operands = [q.reshape(B, C, H * D), k_pool, v_pool]
-    if quant:
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+    q_spec = pl.BlockSpec((1, C, H * D), lambda b, *refs: (b, 0, 0))
+    itemsize = k_pool.dtype.itemsize
+    # the block loop lands each page on its rows of a VMEM block by hand:
+    # the page must be whole sublane tiles of the pool's dtype, and a
+    # quantized pool's [page, KVH] scale page cannot be sliced out of HBM
+    # at all (see the module docstring) — those keep the grid walk
+    loop = not quant and page % (32 // itemsize) == 0
+    if loop:
+        bp = _chunk_block_pages(page, nk)
+        grid = (B,)
+        pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+        in_specs = [q_spec, pool_spec, pool_spec]
+        operands = [k_pool, v_pool]
+        kernel = functools.partial(
+            _paged_chunk_kernel, scale=scale, page=page, nk=nk,
+            bp=bp, c=C, kvh=KVH, g=G, d=D)
+        scratch = [pltpu.VMEM((2, bp * page, KVHD), k_pool.dtype),
+                   pltpu.VMEM((2, bp * page, KVHD), v_pool.dtype),
+                   pltpu.SemaphoreType.DMA((2, 2))]
+        vmem = _chunk_loop_vmem_bytes(C, H, D, bp * page, KVHD, itemsize,
+                                      q.dtype.itemsize)
+    else:
+        def _live_page(ik, st, b):
+            # the chunk's furthest reachable virtual position is st[b]+C-1
+            return jnp.minimum(ik, _reachable_pages(st[b], C, page, nk) - 1)
+
+        def kv(b, ik, st, li, pg):
+            return (li[0], pg[b, _live_page(ik, st, b)], 0, 0)
+
+        grid = (B, nk)
+        kv_spec = pl.BlockSpec((1, 1, page, KVHD), kv)
+        in_specs = [q_spec, kv_spec, kv_spec]
+        operands = [k_pool, v_pool]
+        if quant:
+            sc_spec = pl.BlockSpec((1, 1, page, KVH), kv)
+            in_specs += [sc_spec, sc_spec]
+            operands += [k_scale, v_scale]
+        kernel = functools.partial(
+            _paged_chunk_body, scale=scale, block_k=page, nk=nk,
+            c=C, kvh=KVH, g=G, d=D, stacked=True, quant=quant)
+        scratch = []
+        vmem = _chunk_grid_vmem_bytes(C, H, D, page, KVHD,
+                                      q.dtype.itemsize)
 
     out = pl.pallas_call(
-        functools.partial(_paged_chunk_body, scale=float(scale),
-                          block_k=page, nk=nk, c=C, kvh=KVH, g=G, d=D,
-                          stacked=True, quant=quant),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, nk),
+            grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, C, H * D),
-                                   lambda b, ik, st, li, pg: (b, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((C, H), jnp.float32),         # running max
-                pltpu.VMEM((C, H), jnp.float32),         # running sum
-                pltpu.VMEM((C, H * D), jnp.float32),     # per-head acc
-            ]),
+            out_specs=pl.BlockSpec((1, C, H * D), lambda b, *refs: (b, 0, 0)),
+            scratch_shapes=_chunk_scratch(C, H, D) + scratch),
         out_shape=jax.ShapeDtypeStruct((B, C, H * D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=max(
-                64 * 1024 * 1024,
-                4 * page * KVHD * q.dtype.itemsize
-                + 2 * C * H * D * 4 + 16 * 1024 * 1024)),
-        interpret=_interpret(),
+            dimension_semantics=("parallel", "arbitrary")[:len(grid)],
+            vmem_limit_bytes=vmem),
+        interpret=interpret,
         name="attn.paged_chunk_prefill",
-    )(jnp.asarray(starts, jnp.int32), layer_arr, pages_arr, *operands)
+    )(starts, layer_arr, pages_arr, q.reshape(B, C, H * D), *operands)
     return out.reshape(B, C, H, D)

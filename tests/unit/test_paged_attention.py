@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.ops.transformer.decode_attention import (
     chunk_prefill_attention, decode_attention)
 from deepspeed_tpu.ops.transformer.paged_attention import (
-    paged_chunk_prefill_attention, paged_decode_attention)
+    _chunk_block_pages, paged_chunk_prefill_attention,
+    paged_decode_attention)
 from deepspeed_tpu.ops.transformer.registry import (
     MAX_CHUNK_S, kernel_modes, select_kernel)
 
@@ -274,7 +275,11 @@ def test_paged_decode_fused_write_int8_quantizes_like_cache():
 def test_paged_chunk_prefill_bitwise_vs_gather(int8):
     """Chunked prefill over the pool == the monolithic chunk kernel over
     the gathered view, bitwise — per-row starts including 0 and an
-    unaligned mid-page start."""
+    unaligned mid-page start.  An int8 pool keeps the grid walk, one
+    page a step: the monolithic kernel at ``block_k = page``.  An
+    unquantized pool folds its reachable pages in blocks of
+    ``_chunk_block_pages`` pages: the monolithic kernel at that block
+    size (here the whole table)."""
     page = 16
     q0, k, v, ks, vs, pages, _, nvirt = _pool_fixture(page, int8=int8)
     del q0
@@ -282,14 +287,100 @@ def test_paged_chunk_prefill_bitwise_vs_gather(int8):
     rng = np.random.RandomState(3)
     qc = jnp.asarray(rng.randn(B, C, H, D), jnp.float32)
     starts = jnp.asarray([13, 0, 0], jnp.int32)
+    block_k = page if int8 else page * _chunk_block_pages(page, nvirt)
     ref = chunk_prefill_attention(
         qc, _gather(k, pages, nvirt), _gather(v, pages, nvirt), starts,
-        block_k=page,
+        block_k=block_k,
         k_scale=None if ks is None else _gather(ks, pages, nvirt),
         v_scale=None if vs is None else _gather(vs, pages, nvirt))
     out = paged_chunk_prefill_attention(qc, k, v, starts, pages,
                                         layer=LAYER, k_scale=ks, v_scale=vs)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
+
+
+# page 64 throughout: a block of the loop is 8 pages = 512 keys
+CHUNK_LOOP_CASES = {
+    # one block, masked, seven of its eight pages never fetched
+    "start0": dict(starts=[0]),
+    # a start inside a page: one unmasked block, one masked
+    "start_mid_page": dict(starts=[717]),
+    # the second (tail) block holds ONE live page
+    "tail_one_page": dict(starts=[448]),
+    # 29 pages in the table, 3 reachable
+    "table_wider_than_reach": dict(starts=[40], nk=29),
+    # the table's width is no multiple of the block: the last block ends
+    # with the table
+    "table_end_mid_block": dict(starts=[1700], nk=29),
+    # two rows whose first page is the SAME pool page (a shared prefix)
+    "shared_first_page": dict(starts=[64, 576], share=True),
+    "c128_h32_d64": dict(starts=[704], heads=(32, 32, 64), nk=32),
+    "c128_h16_d128": dict(starts=[300], heads=(16, 16, 128)),
+    "c512_h32_d64": dict(starts=[512], c=512, heads=(32, 32, 64)),
+    "c512_h16_d128": dict(starts=[0], c=512, heads=(16, 16, 128)),
+    "gqa": dict(starts=[130, 1000], heads=(8, 2, 16), nk=24),
+    "fp32_pool": dict(starts=[5, 600], dtype=jnp.float32),
+    # every page the chunk cannot reach, the trash page and the last
+    # reachable page's rows past the chunk hold NaN
+    "nan_outside_reach": dict(starts=[200, 717], poison=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHUNK_LOOP_CASES))
+def test_paged_chunk_prefill_block_loop(case):
+    """The block-loop driver (unquantized pools): bitwise the monolithic
+    chunk kernel at the loop's block size over the gathered view —
+    whatever the start, the table's width, the head shape or the chunk
+    size — and untouched by what lies in pages and rows no query
+    reaches."""
+    kw = dict(CHUNK_LOOP_CASES[case])
+    starts = np.asarray(kw.pop("starts"), np.int32)
+    C = kw.pop("c", 128)
+    Hq, KVHq, Dq = kw.pop("heads", (4, 4, 16))
+    nk = kw.pop("nk", 16)
+    dtype = kw.pop("dtype", jnp.bfloat16)
+    share, poison = kw.pop("share", False), kw.pop("poison", False)
+    assert not kw
+    page, nb = 64, len(starts)
+    bp = _chunk_block_pages(page, nk)
+    assert bp == 8
+    rng = np.random.RandomState(7)
+    n_pool = nb * nk + 1
+    shape = (L, n_pool, page, KVHq * Dq)
+    k = rng.randn(*shape).astype(np.float32)
+    v = rng.randn(*shape).astype(np.float32)
+    pages = (rng.permutation(n_pool - 1) + 1).reshape(nb, nk) \
+        .astype(np.int32)
+    if share:
+        pages[1, 0] = pages[0, 0]
+    q = jnp.asarray(rng.randn(nb, C, Hq, Dq), dtype)
+
+    def run(k_np, v_np):
+        return paged_chunk_prefill_attention(
+            q, jnp.asarray(k_np, dtype), jnp.asarray(v_np, dtype),
+            jnp.asarray(starts), jnp.asarray(pages), layer=LAYER)
+
+    out = run(k, v)
+    # the reference view: the table padded with the trash page to whole
+    # blocks, so the monolithic kernel walks the same block sequence
+    wide = np.zeros((nb, -(-nk // bp) * bp), np.int32)
+    wide[:, :nk] = pages
+    ref = chunk_prefill_attention(
+        q, _gather(jnp.asarray(k, dtype), wide, wide.shape[1]),
+        _gather(jnp.asarray(v, dtype), wide, wide.shape[1]),
+        jnp.asarray(starts), block_k=bp * page)
+    assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+    np.testing.assert_array_equal(np.asarray(ref, np.float32),
+                                  np.asarray(out, np.float32))
+    if poison:
+        kp, vp = np.full_like(k, np.nan), np.full_like(v, np.nan)
+        for b in range(nb):
+            limit = int(starts[b]) + C
+            for i in range(-(-limit // page)):
+                rows = min(page, limit - i * page)
+                kp[:, pages[b, i], :rows] = k[:, pages[b, i], :rows]
+                vp[:, pages[b, i], :rows] = v[:, pages[b, i], :rows]
+        np.testing.assert_array_equal(
+            np.asarray(out, np.float32), np.asarray(run(kp, vp), np.float32))
 
 
 def test_paged_chunk_prefill_4k_prompt_matches_dense_one_pass():

@@ -149,6 +149,53 @@ def test_paged_decode_span_counts_live_pages(paged_engine, tmp_path):
         == sum(p + i for p, n in plan for i in range(1, n))
 
 
+def test_paged_prefill_chunk_span_counts_reachable_pages(paged_engine,
+                                                         tmp_path):
+    """The counter that says how far the chunk kernel's block loop
+    engages: each ``dstpu.sched.dispatch.prefill_chunk`` span carries
+    ``kv_pages`` — the pages its layers fetch, every page of the slot's
+    table up to the chunk's furthest position — and ``kv_pages_table``
+    (pages a slot x layers).  Known prompts: chunk 8, page 16, two
+    layers, four pages a slot — chunk ``ci`` of a prompt reaches
+    ``ceil(8 * (ci + 1) / 16)`` pages a layer."""
+    import json
+    from deepspeed_tpu.monitor import trace as span_trace
+    eng = paged_engine
+    rng = np.random.default_rng(12)
+    plan = [(5, 2), (16, 3), (17, 2), (40, 2), (57, 3)]
+    prompts = [rng.integers(1, 97, (p,)).astype(np.int32) for p, _ in plan]
+    news = [n for _, n in plan]
+    srv = eng.serve(tracing=True)
+    try:
+        rids = [srv.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, news)]
+        outs = srv.drain()
+        path = srv.dump_trace(str(tmp_path / "trace.json"))
+    finally:
+        srv.close()
+        span_trace.disable()
+    _assert_bitwise(eng, outs, rids, prompts, news)
+    with open(path) as f:
+        evs = json.load(f)["traceEvents"]
+    chunks = [e["args"] for e in evs
+              if e["name"] == "dstpu.sched.dispatch.prefill_chunk"]
+    layers, page, C = 2, srv.page, srv.chunk
+    assert (page, C, srv.n_slot_pages) == (16, 8, 4)
+    table = layers * srv.n_slot_pages
+    assert {a["kv_pages_table"] for a in chunks} == {table}
+    assert all(0 < a["kv_pages"] <= table for a in chunks)
+    by_rid = {rid: sorted((a["chunk"], a["kv_pages"]) for a in chunks
+                          if a["rid"] == rid) for rid in rids}
+    for rid, (plen, _) in zip(rids, plan):
+        n_chunks = -(-plen // C)
+        assert by_rid[rid] == [
+            (ci, layers * -(-(C * (ci + 1)) // page))
+            for ci in range(n_chunks)], (plen, by_rid[rid])
+    # a 5-token prompt reaches one page of four; a 57-token one all four
+    assert by_rid[rids[0]] == [(0, 2)]
+    assert by_rid[rids[-1]][-1] == (7, table)
+
+
 def test_paged_page_size_invariance(paged_engine):
     """Same tokens for page_size in {16, 64, 128}: the page size only
     changes where K/V rows physically live, never what is attended."""

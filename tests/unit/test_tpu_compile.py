@@ -91,15 +91,25 @@ def _paged_decode(quant, page=64, slots=8, cache_len=512, layers=L):
     return fn, args
 
 
-def _paged_chunk_prefill(page=64, chunk=128, cache_len=512):
+def _paged_chunk_prefill(page=64, chunk=128, cache_len=512, heads=H,
+                         head_dim=D, quant=False):
+    """One layer-chunk of the paged chunk step (B = 1).  The block loop
+    (unquantized pools) holds two K and two V blocks of 8 pages, the
+    chunk's q and output and its online-softmax state in VMEM: compiling
+    is the proof that they fit the limit the kernel asks for."""
     pages_per_slot = cache_len // page
-    pool = ((L, 8 * pages_per_slot + 1, page, HD), BF16)
+    n_pages = 8 * pages_per_slot + 1
+    pool = ((4, n_pages, page, heads * head_dim), I8 if quant else BF16)
+    args = [((1, chunk, heads, head_dim), BF16), pool, pool, ((1,), I32),
+            ((1, pages_per_slot), I32)]
+    if quant:
+        args += [((4, n_pages, page, heads), F32)] * 2
 
-    def fn(q, k_pool, v_pool, starts, pages):
+    def fn(q, k_pool, v_pool, starts, pages, *scales):
+        kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
         return paged_mod.paged_chunk_prefill_attention(
-            q, k_pool, v_pool, starts, pages, layer=3)
-    return fn, [((1, chunk, H, D), BF16), pool, pool, ((1,), I32),
-                ((1, pages_per_slot), I32)]
+            q, k_pool, v_pool, starts, pages, layer=3, **kw)
+    return fn, args
 
 
 def _mono_decode(batch=16, cache_len=1024):
@@ -140,15 +150,47 @@ CASES = {
     "paged_decode_bf16_batch_24x29": lambda: _paged_decode(
         False, slots=24, cache_len=1856, layers=4),
     "paged_chunk_prefill_c128_p64": _paged_chunk_prefill,
+    "paged_chunk_prefill_int8kv_c128_p64": lambda: _paged_chunk_prefill(
+        quant=True),
     "mono_decode_bf16_fused_write": _mono_decode,
 }
+
+
+def _compile(fn, shapes, one_chip):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+# the serving cells' chunk steps — OPT-1.3B: 32 heads of 64, 29 pages a
+# slot; OLMoE: 16 heads of 128, 17 pages — at the cells' chunk and at the
+# largest the registry allows (MAX_CHUNK_S)
+@pytest.mark.parametrize("chunk", [128, 512])
+@pytest.mark.parametrize("heads,head_dim,pages_per_slot",
+                         [(32, 64, 29), (16, 128, 17)],
+                         ids=["opt13b_29", "olmoe_17"])
+def test_paged_chunk_block_loop_fits_vmem(heads, head_dim, pages_per_slot,
+                                          chunk, one_chip, mosaic):
+    """The chunk kernel's block loop at both serving shapes: Mosaic
+    accepts it under the VMEM limit the kernel asks for (a kernel over
+    its limit does not compile), and that limit is one a v5e can grant
+    (128 MiB of VMEM)."""
+    page = 64
+    fn, shapes = _paged_chunk_prefill(
+        page=page, chunk=chunk, cache_len=pages_per_slot * page,
+        heads=heads, head_dim=head_dim)
+    asked = paged_mod._chunk_loop_vmem_bytes(
+        chunk, heads, head_dim,
+        paged_mod._chunk_block_pages(page, pages_per_slot) * page,
+        heads * head_dim, 2, 2)
+    assert asked <= 100 * 2 ** 20, f"asks for {asked / 2 ** 20:.0f} MiB"
+    compiled = _compile(fn, shapes, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
     fn, shapes = CASES[case]()
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
+    compiled = _compile(fn, shapes, one_chip)
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{case}: no Mosaic kernel in the compiled program"
     mem = compiled.memory_analysis()

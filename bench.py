@@ -583,7 +583,11 @@ def serving_bench(model_name="opt-1.3b", *, num_slots=8, n_requests=24,
     them mid-decode through ONE reusable decode-step program.
 
     ``speedup_vs_sequential`` is aggregate useful tokens/s over the same
-    requests — the headline serving metric."""
+    requests — the headline serving metric.
+
+    Since PR 29 this phase runs the slot engine's ONE KV layout, the page
+    pool (it used to run the lane layout, now removed): its numbers are
+    not comparable with ``BENCH_r*.json`` records from before."""
     import jax
     from deepspeed_tpu.models.opt import opt_config
     from deepspeed_tpu.models.transformer import Transformer
@@ -937,7 +941,7 @@ def serving_paged_bench(model_name="opt-1.3b", *, slots_list=(96, 128, 192),
                         prefill_chunk=128, prefix_requests=24,
                         prefix_len=512):
     """Paged-KV serving (``inference/serving/paging.py``, ``docs/serving.md``
-    "Paged KV cache") at the throughput serving points where the
+    "KV cache") at the throughput serving points where the
     monolithic per-slot lanes collapsed (r04: int8-KV decode fell 8,673 →
     1,193 tok/s/chip between bs96 and bs128 as ``num_slots × cache_len``
     HBM crossed the chip).  Per concurrency level: ``num_slots`` paged
@@ -961,7 +965,7 @@ def serving_paged_bench(model_name="opt-1.3b", *, slots_list=(96, 128, 192),
     quant = {"enabled": True, "bits": 8, "per_channel": True}
     eng = InferenceEngine(model, DeepSpeedInferenceConfig(
         dtype="bfloat16", quant=quant, compile_cache=_cc_block(),
-        serving={"enabled": True, "paged": True, "page_size": page_size,
+        serving={"enabled": True, "page_size": page_size,
                  "max_cache_len": cache_len, "prefill_chunk": prefill_chunk,
                  "prefill_token_budget": 256, "decode_block": decode_block}))
     eng.init_params()

@@ -227,7 +227,7 @@ def serve_phase(size, seed):
         "dtype": "bfloat16",
         "prefill_chunk_size": None,          # generate(): one-pass prefill
         "compile_cache": {"enabled": True, "executables": False},
-        "serving": {"enabled": True, "paged": True,
+        "serving": {"enabled": True,
                     "page_size": size["page"], "num_slots": size["slots"],
                     "max_cache_len": size["cache_len"],
                     "prefill_chunk": size["chunk"],
@@ -314,7 +314,7 @@ def serve_phase(size, seed):
         match = "near_tie"
     cc = cache_delta(cc0)
     emit(phase="serve", model=size["model"], layers=model.config.num_layers,
-         hidden=model.config.hidden_size, paged=True,
+         hidden=model.config.hidden_size,
          requests=len(prompts), streamed=sum(s for _, _, s in size["requests"]),
          completed=len(prompts), requests_wall_s=round(t_requests, 2),
          warmup_compile_s={k: round(v, 1) for k, v in warm.items()},

@@ -452,10 +452,10 @@ class InferenceEngine:
         return out
 
     def _make_chunk_fn(self):
-        """A fresh (unmemoized) per-chunk prefill program instance — the
-        serving engine uses its own instance so its persist-opt-out never
-        touches the engine-shared one (and a store-reloaded shared
-        executable can never serve admission prefill)."""
+        """A fresh (unmemoized) per-chunk prefill program instance over a
+        monolithic cache — ``generate()``'s split prefill (the serving
+        engine's admission chunk program is ``slots.make_chunk_fn``,
+        over the page pool)."""
         module, deq = self.module, self._deq
 
         @hot_path("inference.prefill_chunk")
@@ -530,10 +530,9 @@ class InferenceEngine:
         new requests join freed KV slots between decode iterations instead
         of waiting for a whole ``generate()`` batch to finish.  Knobs come
         from the ``serving`` config block, overridable per call
-        (``engine.serve(num_slots=16)``); ``serving.paged=True`` swaps
-        the per-slot monolithic KV lanes for a block-table page pool
-        with copy-on-write prefix sharing (``engine.serve(paged=True,
-        page_size=64)``); ``serving.speculative=True`` turns on
+        (``engine.serve(num_slots=16, page_size=64)``); the KV cache is
+        a block-table page pool with copy-on-write prefix sharing;
+        ``serving.speculative=True`` turns on
         draft-assisted speculative decoding — pass the draft model as
         ``engine.serve(speculative=True, draft_module=...,
         draft_params=...)`` or set ``serving.spec_draft_model``

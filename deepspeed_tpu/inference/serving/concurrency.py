@@ -40,8 +40,12 @@ Deliberately NOT in the registry (each with its reason):
   it is reachable ONLY through the engine's ``_fairness`` attribute,
   which IS guarded, so every window read/write inherits the engine
   lock transitively (``frontend/fairness.py``).
+* ``SlotPages`` internals (``paging.py``) — likewise lock-less and
+  reachable only through the guarded ``_pages``; the counters it adds
+  into are the engine's guarded ``stats`` dict, touched only from those
+  lock-held calls.
 * configuration set once in ``__init__`` and never mutated
-  (``num_slots``, ``cache_len``, ``paged``, ``config``, ...).
+  (``num_slots``, ``cache_len``, ``num_pages``, ``config``, ...).
 """
 
 import os
@@ -70,15 +74,15 @@ GUARDED_FIELDS = {
         "_state": "_lock",
         "_cache": "_lock",
         "_rng": "_lock",
-        # paged-KV host bookkeeping
-        "_slot_pages": "_lock",
-        "_page_table": "_lock",
-        "_pool": "_lock",
-        "_prefix": "_lock",
+        # which pages back which slot (paging.SlotPages: pool mirror,
+        # prefix index, page table, slot -> pages map and the pool
+        # workspace — no lock of its own, reachable only through this
+        # field)
+        "_pages": "_lock",
         # speculative-decoding draft mirror (the draft KV workspace
         # handle chains dispatch-to-dispatch like _cache/_state; the
         # draft lane pool hands out admission prefill lanes one event
-        # behind, like _lane_pool's donated-liveness contract)
+        # behind)
         "_draft_cache": "_lock",
         "_draft_lanes": "_lock",
         # results / lifecycle

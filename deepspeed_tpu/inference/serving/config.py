@@ -13,11 +13,11 @@ class ServingConfig(DeepSpeedConfigModel):
     ops configs.  ``ServingEngine.warmup()`` precompiles the serving
     programs."""
     enabled: bool = False
-    # fixed-shape KV slot lanes: the ONE decode-step program is compiled
-    # for exactly this many cache rows; requests map onto freed lanes
+    # fixed-shape KV slots: the ONE decode-step program is compiled for
+    # exactly this many rows; requests map onto freed slots
     num_slots: int = 8
-    # per-slot cache positions (rounded up to a multiple of 8 — the fused
-    # decode kernel's sublane alignment); every request must satisfy
+    # per-slot cache positions, the slot's VIRTUAL lane (rounded up to a
+    # whole number of pages); every request must satisfy
     # ceil(prompt/chunk)*chunk <= max_cache_len and
     # prompt + max_new_tokens <= max_cache_len
     max_cache_len: int = 2048
@@ -37,14 +37,14 @@ class ServingConfig(DeepSpeedConfigModel):
     # admission order: "fcfs" (arrival) | "shortest_first" (shortest
     # prompt first — lowers mean time-to-first-token under backlog)
     admission: str = "fcfs"
-    # ---- paged KV cache (docs/serving.md "Paged KV cache") ----
-    # paged=True replaces the per-slot monolithic lanes with a shared
-    # page pool + per-slot block tables (traced args — still ONE decode
-    # executable per server): HBM cost becomes num_pages * page_size
-    # instead of num_slots * max_cache_len, shared prefixes are stored
-    # once, and capacity pressure degrades into admission backpressure
-    # instead of an allocation cliff.  Default off = seed behavior.
-    paged: bool = False
+    # ---- KV cache (docs/serving.md "KV cache"): one shared page pool +
+    # per-slot block tables (traced args — still ONE decode executable
+    # per server).  HBM cost is num_pages * page_size, shared prefixes
+    # are stored once, and capacity pressure degrades into admission
+    # backpressure instead of an allocation cliff.  (The ``paged``
+    # switch and its kernel A/B knob are gone with the lane layout they
+    # selected against: ``true`` is accepted and ignored, ``false`` is
+    # refused by name — ServingEngine.__init__.) ----
     # positions per page (rounded up to a multiple of 8 — sublane
     # alignment — floor 8).  Smaller pages waste less per-request tail
     # but cost a bigger table and finer gathers
@@ -55,16 +55,7 @@ class ServingConfig(DeepSpeedConfigModel):
     # auto to actual demand for the HBM win; admission then waits for
     # free pages under pressure (queue backpressure, never corruption)
     num_pages: int = 0
-    # Pallas paged-attention kernels (paged only): decode attends
-    # straight over the page pool through the block table (split-K
-    # across pages, online softmax, int8-KV dequant fused into the page
-    # load) and admission prefill takes the paged chunk kernel — the
-    # BENCH_r04 bs128 decode cliff fix.  False = the pre-kernel gather
-    # path (take_along_axis virtual view per layer, for A/B benching);
-    # the registry then warns once and stats["paged_attention_fallback"]
-    # counts every decode dispatch that took the slow path
-    paged_kernel: bool = True
-    # copy-on-write prefix sharing (paged only): page-aligned leading
+    # copy-on-write prefix sharing: page-aligned leading
     # blocks of a prompt that hash-match an earlier prompt map to the
     # SAME physical pages, prefilled once; divergence re-prefills at
     # most one page.  Unreferenced prefix pages evict LRU under pool

@@ -5,21 +5,19 @@ TPU constraint that every program keeps FIXED shapes).
 
 The scheduler loop per iteration (:meth:`ServingEngine.step`):
 
-1. **Admission** — while a KV slot is free and the queue is non-empty,
-   pop a request (``fcfs`` or ``shortest_first``) and stream its prompt
-   through the engine's donated per-chunk prefill executable
-   (a dedicated instance of the same chunk program the split-prefill
-   ``generate()`` path replays) into a single-lane cache, spending at most
-   ``prefill_token_budget`` prompt tokens per iteration so a long prompt
-   cannot starve decoding.  A finished prefill dispatches ONE fused admit
-   program (first-token sample + lane insert + in-program slot-state
-   write).
+1. **Admission** — while a KV slot is free, the page pool can back the
+   request and the queue is non-empty, pop a request (``fcfs`` or
+   ``shortest_first``) and stream its prompt through the donated
+   per-chunk prefill executable straight into the slot's pages, spending
+   at most ``prefill_token_budget`` prompt tokens per iteration so a long
+   prompt cannot starve decoding.  A finished prefill dispatches ONE
+   admit program (first-token sample + in-program slot-state write).
 2. **Decode** — ONE call of the single reusable decode-step program
-   advances every live slot ``decode_block`` tokens (cache + slot state
+   advances every live slot ``decode_block`` tokens (pool + slot state
    donated).  Rows that emit their ``eos`` (or exhaust ``max_new_tokens``)
    retire IN-PROGRAM; the host mirrors the retirement bookkeeping from the
-   emitted tokens, frees their slots mid-flight, and hands the lanes to
-   the admission queue — no request ever waits for a batch to finish.
+   emitted tokens, frees their slots and pages mid-flight, and hands them
+   to the admission queue — no request ever waits for a batch to finish.
 
 **Latency-hiding:** the slot state lives ON DEVICE and every program
 chains through it by data dependency, so the host never synchronizes
@@ -30,27 +28,26 @@ the host does its scheduling bookkeeping.  The price is that a slot freed
 in block N is re-admittable only from block N+2 — at most one block of
 idle per retirement.
 
-Because slot occupancy rides traced arguments, the whole server lifetime
-compiles exactly ONE decode-step executable per (num_slots, cache_len,
-block, sampling) configuration.  The serving programs compile once per
-PROCESS and deliberately bypass the persistent cache layers — reloaded
-serving executables corrupt the donated slot workspace (see the
-``_persist_opt_out`` note in ``__init__``).
+Because slot occupancy and the page tables ride traced arguments, the
+whole server lifetime compiles exactly ONE decode-step executable per
+(num_slots, num_pages, page_size, block, sampling) configuration.  The
+serving programs compile once per PROCESS and deliberately bypass the
+persistent cache layers — reloaded serving executables corrupt the
+donated slot workspace (see the ``_persist_opt_out`` note in
+``__init__``).
 
-**Paged KV cache** (``serving.paged``, ``docs/serving.md`` "Paged KV
-cache"): the per-slot monolithic lanes are replaced by one shared page
-pool ``[L, num_pages, page_size, KVH*D]`` plus per-slot page tables the
-host allocates and ships as TRACED arguments on every dispatch — HBM
-cost becomes ``num_pages × page_size`` instead of ``num_slots ×
-max_cache_len``, admission prefill writes straight into the slot's
-pages (no staging lane, no admit-time insert), hash-matched prompt
-prefixes map to the same refcounted physical pages (prefilled once,
-copy-on-write at page granularity via recompute-on-divergence), and
-pool pressure degrades into admission backpressure handled by the
-bounded queue instead of an allocation cliff.  The int8 KV path
-(``kv_cache_quant``) quantizes pool pages exactly like monolithic
-lanes, roughly doubling page capacity.  Still exactly ONE decode
-executable per server lifetime: page churn only changes table
+**KV cache** (``docs/serving.md`` "KV cache"): one shared page pool
+``[L, num_pages, page_size, KVH*D]`` plus per-slot page tables the host
+allocates (``paging.SlotPages`` — the one cache manager; the scheduler
+here holds no page arithmetic) and ships as TRACED arguments on every
+dispatch — HBM cost is ``num_pages × page_size``, admission prefill
+writes straight into the slot's pages (no staging lane, no admit-time
+insert), hash-matched prompt prefixes map to the same refcounted
+physical pages (prefilled once, copy-on-write at page granularity via
+recompute-on-divergence), and pool pressure degrades into admission
+backpressure handled by the bounded queue instead of an allocation
+cliff.  The int8 KV path (``kv_cache_quant``) quantizes pool pages,
+roughly doubling page capacity.  Page churn only changes table
 CONTENTS, never a program shape.
 
 **Speculative decoding** (``serving.speculative``, ``docs/serving.md``
@@ -66,8 +63,8 @@ greedy speculative serving is BITWISE-identical to the plain decode
 path.  Fixed ``spec_k`` keeps the one-executable discipline: exactly
 one draft-propose and one verify-and-commit executable per server
 lifetime.  Admission streams each prompt chunk through BOTH models
-(the draft lane rides the admit event one-behind like the target
-lane); preemption snapshots committed tokens only, and restore
+(the draft's prefill lane rides the admit event one-behind);
+preemption snapshots committed tokens only, and restore
 re-derives all draft state through the ordinary re-prefill path.
 
 **Robustness / SLO layer** (``docs/serving.md`` "Robustness & SLOs"):
@@ -127,11 +124,7 @@ from deepspeed_tpu.inference.serving.config import ServingConfig
 from deepspeed_tpu.inference.serving.flightrec import FlightRecorder
 from deepspeed_tpu.monitor import trace as span_trace
 from deepspeed_tpu.monitor.trace import ServingHistograms, span
-from deepspeed_tpu.inference.serving.paging import (PagePool,
-                                                    PagedPoolWorkspace,
-                                                    PrefixIndex,
-                                                    compact_page_str,
-                                                    pages_for)
+from deepspeed_tpu.inference.serving.paging import SlotPages
 from deepspeed_tpu.inference.serving.slo import (CircuitBreaker,
                                                  DrainTimeout, QueueFull,
                                                  RequestResult,
@@ -140,14 +133,11 @@ from deepspeed_tpu.inference.serving.slo import (CircuitBreaker,
                                                  TokenStream)
 from deepspeed_tpu.inference.serving.slots import (init_slot_state,
                                                    make_admit_fn,
+                                                   make_chunk_fn,
                                                    make_decode_block_fn,
                                                    make_draft_admit_fn,
                                                    make_draft_chunk_fn,
                                                    make_draft_propose_fn,
-                                                   make_paged_admit_fn,
-                                                   make_paged_chunk_fn,
-                                                   make_paged_decode_block_fn,
-                                                   make_paged_spec_verify_fn,
                                                    make_spec_verify_fn,
                                                    routes_experts)
 from deepspeed_tpu.runtime.fault import inject
@@ -208,20 +198,21 @@ class ServeRequest:
 
 
 class _PendingPrefill:
-    """An admission in progress: the slot is reserved, the prompt streams
-    chunk-by-chunk into the lane cache across scheduler iterations."""
+    """An admission in progress: the slot and its pages are reserved, the
+    prompt streams chunk-by-chunk into them across scheduler iterations."""
 
-    def __init__(self, req, slot, lane, ids_pad, n_chunks, fill_len):
-        self.req, self.slot, self.lane = req, slot, lane
-        self.ids_pad = ids_pad           # [1, n_chunks*C] int32
-        self.n_chunks = n_chunks
-        self.fill_len = fill_len         # real positions incl. resume prefix
+    def __init__(self, req, slot, fill, start, chunk):
+        self.req, self.slot = req, slot
+        self.fill = fill                 # prompt + any resumed tokens
+        self.fill_len = len(fill)
+        # prefill starts at the shared-prefix boundary (chunk-aligned);
+        # positions < start are served by shared pages
+        self.start = start
+        self.n_chunks = -(-(self.fill_len - start) // chunk)
+        self.ids_pad = np.zeros((1, self.n_chunks * chunk), np.int32)
+        self.ids_pad[0, :self.fill_len - start] = fill[start:]
         self.ci = 0                      # chunks completed
         self.sel = None                  # last-real-position logits [1,1,V]
-        # paged admission: prefill starts at the shared-prefix boundary
-        # (page-aligned); positions < start are served by shared pages
-        self.start = 0
-        self.fill_tokens = None          # full fill (prefix registration)
         # speculative serving: the DRAFT model's single-lane prefill
         # cache (the prompt's K/V must land in the draft cache too)
         self.draft_lane = None
@@ -231,10 +222,12 @@ class _PendingPrefill:
 
 
 class _LanePool:
-    """Reusable single-lane prefill caches.  Several admissions can be in
-    flight at once (the admit op that consumes a lane is processed one
-    event behind), so this is a pool, not a single workspace slot — with
-    the same donated-and-dead liveness check ``KVCacheWorkspace`` does."""
+    """The DRAFT model's reusable single-lane prefill caches (speculative
+    serving; the target prefills straight into its pages).  Several
+    admissions can be in flight at once (the draft admit that consumes a
+    lane is processed one event behind), so this is a pool, not a single
+    workspace slot — with the same donated-and-dead liveness check
+    ``KVCacheWorkspace`` does."""
 
     def __init__(self, module):
         self._module = module
@@ -249,8 +242,7 @@ class _LanePool:
         return self._module.init_cache(1, cache_len, dtype=dtype)
 
     def give_back(self, lane):
-        if lane is not None:             # paged admissions have no lane
-            self._lanes.append(lane)
+        self._lanes.append(lane)
 
     def release(self):
         self._lanes.clear()
@@ -281,6 +273,19 @@ class ServingEngine:
         cfg = getattr(engine._config, "serving", None) or ServingConfig()
         if overrides:
             cfg = ServingConfig(**{**cfg.model_dump(), **overrides})
+        for key, val in (cfg.model_extra or {}).items():
+            # the removed layout switches (``paged`` and its kernel A/B
+            # knob) still arrive from config files, which keep unknown
+            # keys: on is what the engine does anyway, off asks for
+            # something it no longer has — refuse by name, never
+            # silently serve another layout
+            if key.startswith("paged") and not val:
+                raise ValueError(
+                    f"serving.{key}={val!r}: the lane KV layout and the "
+                    f"gather A/B switch were removed — the slot engine "
+                    f"has ONE KV cache, the page pool behind the Pallas "
+                    f"paged kernels (docs/serving.md 'KV cache'); drop "
+                    f"the key (true is accepted and ignored)")
         self.engine = engine
         self.module = engine.module
         self.config = cfg
@@ -288,12 +293,43 @@ class ServingEngine:
         self.num_slots = int(cfg.num_slots)
         if self.num_slots < 1:
             raise ValueError(f"serving.num_slots={cfg.num_slots}: need >= 1")
-        # lane length: multiple of 8 (the fused decode kernel's sublane
-        # alignment — same rounding as required_cache_len)
-        self.cache_len = -(-int(cfg.max_cache_len) // 8) * 8
         # admission chunk: align like the engine's prefill_chunk_size
         # (multiple of 8, floor 8, cap 512 — the chunk kernel's bounds)
         self.chunk = min(512, max(8, -(-int(cfg.prefill_chunk) // 8) * 8))
+        if not hasattr(type(self.module), "init_paged_cache"):
+            raise ValueError(
+                f"{type(self.module).__name__} has no init_paged_cache — "
+                f"the slot engine's page pool needs model support "
+                f"(models/transformer.py)")
+        self.speculative = bool(cfg.speculative)
+        # scheduler counters (docs/serving.md) — made before the cache
+        # manager, which counts prefix hits and evictions into them
+        self.stats = {"iterations": 0, "decode_calls": 0,  # guarded-by: _lock
+                      "decode_tokens": 0, "prefill_tokens": 0,
+                      "completed": 0, "admitted": 0, "wall_secs": 0.0,
+                      "sync_secs": 0.0, "shed": 0, "cancelled": 0,
+                      "resumed": 0, "prefix_lookups": 0, "prefix_hits": 0,
+                      "prefix_tokens_reused": 0, "page_evictions": 0,
+                      "admission_stalls": 0, "fairness_rejected": 0,
+                      "paged_attention_fallback": 0,
+                      "stream_bridge_drops": 0,
+                      "lock_wait_scheduler_s": 0.0,
+                      "lock_wait_handler_s": 0.0}
+        # ---- KV cache (docs/serving.md "KV cache"): the page pool and
+        # which pages back which slot.  Speculative serving skips prefix
+        # sharing: the DRAFT cache has no page pool, so its prefill must
+        # run from position 0 anyway — a shared target prefix would
+        # leave the draft side unfilled ----
+        self._pages = SlotPages(                 # guarded-by: _lock
+            self.module, self.num_slots, cfg.max_cache_len, cfg.page_size,
+            cfg.num_pages, self.chunk,
+            share_prefixes=cfg.prefix_cache and not self.speculative,
+            stats=self.stats)
+        self.page = self._pages.page
+        self.num_pages = self._pages.num_pages
+        self.pages_per_slot = self._pages.pages_per_slot
+        # the slot's virtual lane: max_cache_len in whole pages
+        self.cache_len = self._pages.cache_len
         max_seq = getattr(getattr(self.module, "config", None),
                           "max_seq_len", None)
         if max_seq is not None and self.cache_len > max_seq:
@@ -321,35 +357,8 @@ class ServingEngine:
                 float(cfg.fairness_window_s))   # guarded-by: _lock
         else:
             self._fairness = None               # guarded-by: _lock
-        # ---- paged KV cache (docs/serving.md "Paged KV cache") ----
-        self.paged = bool(cfg.paged)
-        if self.paged:
-            if not hasattr(type(self.module), "init_paged_cache"):
-                raise ValueError(
-                    f"serving.paged=True but "
-                    f"{type(self.module).__name__} has no "
-                    f"init_paged_cache — the paged pool needs model "
-                    f"support (models/transformer.py)")
-            # page size: multiple of 8 (sublane alignment), floor 8, and
-            # the virtual lane rounds UP to a whole number of pages
-            self.page = max(8, -(-int(cfg.page_size) // 8) * 8)
-            # Pallas paged-attention kernels (default on); False = the
-            # pre-kernel take_along_axis gather path, for A/B benching
-            self.paged_kernel = bool(cfg.paged_kernel)
-            self.cache_len = -(-self.cache_len // self.page) * self.page
-            self.n_slot_pages = self.cache_len // self.page
-            # pool size incl. the reserved trash page 0; auto = full
-            # worst-case capacity (every slot at max_cache_len) — no HBM
-            # savings but also no pool pressure
-            self.num_pages = int(cfg.num_pages) \
-                or self.num_slots * self.n_slot_pages + 1
-            if self.num_pages < 2:
-                raise ValueError(f"serving.num_pages={cfg.num_pages}: "
-                                 f"need >= 2 (trash + 1 allocatable)")
-
         # ---- speculative decoding (docs/serving.md "Speculative
         # decoding"): draft model + the fixed verify window ----
-        self.speculative = bool(cfg.speculative)
         self.spec_k = int(cfg.spec_k)
         if self.speculative:
             if cfg.do_sample:
@@ -375,17 +384,11 @@ class ServingEngine:
                     f"vocab_size={tvocab} — speculative verification "
                     f"compares token ids, the vocabularies must match")
 
-        # ---- expert models (docs/serving.md "Expert models"): dropless
-        # routed layers are served by the paged programs only — they
-        # mask dead lanes and padded chunk tails out of the routing and
-        # return the expert load ----
+        # ---- expert models (docs/serving.md "Expert models"): the slot
+        # programs mask dead lanes and padded chunk tails out of the
+        # dropless routing and return the expert load ----
         self.routed = routes_experts(self.module)
         if self.routed:
-            if not self.paged:
-                raise ValueError(
-                    "a model with dropless expert layers needs "
-                    "serving.paged=True: only the paged programs keep "
-                    "dead lanes and padded chunk tails out of the routing")
             if self.speculative:
                 raise ValueError(
                     "serving.speculative=True is not implemented for a "
@@ -413,65 +416,35 @@ class ServingEngine:
             kernel_modes as _registry_modes)
         _pe = getattr(getattr(self.module, "config", None),
                       "position_embedding", None)
-        self.kernel_modes = _registry_modes(
-            paged=self.paged,
-            disabled=self.paged and not getattr(self, "paged_kernel", True),
-            has_bias=(_pe == "alibi"))
+        self.kernel_modes = _registry_modes(paged=True,
+                                            has_bias=(_pe == "alibi"))
         self._decode_fn = self._propose_fn = self._verify_fn = None
         self._draft_chunk_fn = self._draft_admit_fn = None
-        if self.paged:
-            # paged programs: the pool + per-slot page tables replace the
-            # monolithic slot lanes.  Page tables are traced arguments
-            # (rebuilt host-side per dispatch), so page churn/sharing
-            # never mints a new executable — still exactly ONE decode
-            # signature per server lifetime.
-            if self.speculative:
-                self._verify_fn = make_paged_spec_verify_fn(
-                    self.module, sample_fn, engine._deq, self.spec_k,
-                    self.cache_len, paged_kernel=self.paged_kernel)
-                engine._tags[id(self._verify_fn)] = (
-                    "serving_spec_verify_paged", self.num_slots,
-                    self.num_pages, self.page, self.spec_k, sampling_key,
-                    self.paged_kernel)
-            else:
-                self._decode_fn = make_paged_decode_block_fn(
-                    self.module, sample_fn, engine._deq, self.block,
-                    self.cache_len, paged_kernel=self.paged_kernel)
-                engine._tags[id(self._decode_fn)] = (
-                    "serving_decode_paged", self.num_slots,
-                    self.num_pages, self.page, self.block, sampling_key,
-                    self.paged_kernel)
-            self._admit_fn = make_paged_admit_fn(sample_fn)
-            engine._tags[id(self._admit_fn)] = (
-                "serving_admit_paged", self.num_slots, sampling_key)
+        # Page tables are traced arguments (rebuilt host-side per
+        # dispatch), so page churn/sharing never mints a new executable
+        # — exactly ONE decode signature per server lifetime.
+        if self.speculative:
+            self._verify_fn = make_spec_verify_fn(
+                self.module, sample_fn, engine._deq, self.spec_k,
+                self.cache_len)
+            engine._tags[id(self._verify_fn)] = (
+                "serving_spec_verify", self.num_slots, self.num_pages,
+                self.page, self.spec_k, sampling_key)
         else:
-            if self.speculative:
-                self._verify_fn = make_spec_verify_fn(
-                    self.module, sample_fn, engine._deq, self.spec_k,
-                    self.cache_len)
-                engine._tags[id(self._verify_fn)] = (
-                    "serving_spec_verify", self.num_slots,
-                    self.cache_len, self.spec_k, sampling_key)
-            else:
-                self._decode_fn = make_decode_block_fn(
-                    self.module, sample_fn, engine._deq, self.block,
-                    self.cache_len)
-                # stable program tags → the engine's AOT path
-                # persists/reloads these executables through the
-                # compile_cache store
-                engine._tags[id(self._decode_fn)] = (
-                    "serving_decode", self.num_slots, self.cache_len,
-                    self.block, sampling_key)
-            self._admit_fn = make_admit_fn(sample_fn)
-            engine._tags[id(self._admit_fn)] = (
-                "serving_admit", self.num_slots, self.cache_len,
-                sampling_key)
+            self._decode_fn = make_decode_block_fn(
+                self.module, sample_fn, engine._deq, self.block,
+                self.cache_len)
+            engine._tags[id(self._decode_fn)] = (
+                "serving_decode", self.num_slots, self.num_pages,
+                self.page, self.block, sampling_key)
+        self._admit_fn = make_admit_fn(sample_fn)
+        engine._tags[id(self._admit_fn)] = (
+            "serving_admit", self.num_slots, sampling_key)
         if self.speculative:
             # the draft side: one propose program, one draft prefill
             # chunk, one draft lane insert — the draft KV cache is
-            # ALWAYS monolithic lanes [L_d, num_slots, cache_len, ...]
-            # (the draft model is small; paging its cache would buy
-            # little and complicate the pool bookkeeping for nothing)
+            # monolithic lanes [L_d, num_slots, cache_len, ...], the
+            # last user of that layout (ROADMAP D15)
             self._draft_deq = engine._deq \
                 if draft_module is self.module else None
             self._propose_fn = make_draft_propose_fn(
@@ -491,60 +464,35 @@ class ServingEngine:
         # The serving programs must NOT be reloaded from either
         # persistent cache layer (serialized-executable store OR the XLA
         # disk cache): they chain one donated slot workspace across three
-        # different programs (chunk lane -> admit insert -> decode
-        # blocks), and running ANY of them from a cross-process reloaded
-        # artifact nondeterministically corrupts the slot cache — wrong
-        # tokens, cross-lane mixing, one lane's KV clobbered the moment
-        # another lane admits — or segfaults outright (reproduced and
-        # bisected with the serving kill-harness driver: cache-less runs
-        # are 100% stable, warm runs flake at ~25-50%; the train and
-        # whole-batch generate paths show no such failures and keep both
-        # layers).  The admission chunk program is a DEDICATED instance
-        # (same body as the engine-shared ('chunkfill', C, 1) memo, via
-        # _make_chunk_fn): the shared one may already sit in eng._aot as
-        # a store-reloaded executable from warmup()/batch-1 split
-        # prefill, and opting IT out would strip generate()'s batch-1
-        # path of its caches.  Each server process compiles its three
-        # serving programs once — the one-decode-executable-per-server-
-        # lifetime invariant is untouched, and overload/drain/resume
-        # cycles mint no further executables
-        # (tests/unit/test_serving_slo.py).
-        if self.paged:
-            # paged prefill writes straight into the slot's pool pages
-            # (no single-lane staging cache; the pool chains chunk ->
-            # decode by donation)
-            self._chunk_fn = make_paged_chunk_fn(
-                self.module, engine._deq, paged_kernel=self.paged_kernel)
-            engine._tags[id(self._chunk_fn)] = (
-                "serving_prefill_paged", self.chunk, self.page,
-                self.paged_kernel)
-        else:
-            self._chunk_fn = engine._make_chunk_fn()
-            engine._tags[id(self._chunk_fn)] = ("serving_prefill",
-                                                self.chunk)
+        # different programs (prefill chunks -> admit -> decode blocks),
+        # and running ANY of them from a cross-process reloaded artifact
+        # nondeterministically corrupts the slot cache — wrong tokens,
+        # cross-lane mixing, one lane's KV clobbered the moment another
+        # lane admits — or segfaults outright (reproduced and bisected
+        # with the serving kill-harness driver: cache-less runs are 100%
+        # stable, warm runs flake at ~25-50%; the train and whole-batch
+        # generate paths show no such failures and keep both layers).
+        # Each server process compiles its three serving programs once —
+        # the one-decode-executable-per-server-lifetime invariant is
+        # untouched, and overload/drain/resume cycles mint no further
+        # executables (tests/unit/test_serving_slo.py).
+        # Prefill writes straight into the slot's pool pages (the pool
+        # chains chunk -> decode by donation).
+        self._chunk_fn = make_chunk_fn(self.module, engine._deq)
+        engine._tags[id(self._chunk_fn)] = (
+            "serving_prefill", self.chunk, self.page)
         for fn in (self._decode_fn, self._admit_fn, self._chunk_fn,
                    self._verify_fn, self._propose_fn,
                    self._draft_chunk_fn, self._draft_admit_fn):
             if fn is not None:
                 engine._persist_opt_out.add(id(fn))
 
-        self._cache_ws = KVCacheWorkspace(self.module)
-        self._lane_pool = _LanePool(self.module)
         if self.speculative:
             self._draft_params = draft_params
             self._draft_ws = KVCacheWorkspace(self.draft_module)
             self._draft_lanes = _LanePool(self.draft_module)  # guarded-by: _lock
             self._draft_cache = None                          # guarded-by: _lock
-        if self.paged:
-            self._pool_ws = PagedPoolWorkspace(self.module)
-            self._pool = PagePool(self.num_pages)   # guarded-by: _lock
-            self._prefix = PrefixIndex()            # guarded-by: _lock
-            # host-owned page tables, shipped as a traced arg on every
-            # dispatch: [num_slots, pages_per_slot]; 0 = the trash page
-            self._page_table = np.zeros(
-                (self.num_slots, self.n_slot_pages), np.int32)  # guarded-by: _lock
-            self._slot_pages = {}        # slot -> [page ids]  # guarded-by: _lock
-        self._cache = None               # guarded-by: _lock
+        self._cache = None               # the pool buffer  # guarded-by: _lock
         self._state = None               # device-resident state  # guarded-by: _lock
         # host mirror of slot occupancy, updated as events are PROCESSED
         # (it lags the device by the in-flight events — by design)
@@ -555,7 +503,7 @@ class ServingEngine:
         self._pending = None                       # guarded-by: _lock
         # dispatched-but-unprocessed device work, processed FIFO one
         # event behind the newest dispatch: ("decode", toks_dev, [load]) |
-        # ("admit", req, slot, lane, first_dev, draft_lane, [loads])
+        # ("admit", req, slot, first_dev, draft_lane, [loads])
         self._events = deque()                     # guarded-by: _lock
         self._rng = jax.random.key(int(cfg.seed))  # guarded-by: _lock
         self._next_rid = 0                         # guarded-by: _lock
@@ -594,20 +542,6 @@ class ServingEngine:
         self._close_report = []          # undrained rids  # guarded-by: _lock
         self._snap_seq = 0               # snapshot lineage  # guarded-by: _lock
         self._slot_last_dispatch = {}    # slot -> mono t  # guarded-by: _lock
-        # observability (docs/serving.md): scheduler counters + the
-        # slot-occupancy trace the correctness test asserts EOS-mid-flight
-        # retirement against
-        self.stats = {"iterations": 0, "decode_calls": 0,  # guarded-by: _lock
-                      "decode_tokens": 0, "prefill_tokens": 0,
-                      "completed": 0, "admitted": 0, "wall_secs": 0.0,
-                      "sync_secs": 0.0, "shed": 0, "cancelled": 0,
-                      "resumed": 0, "prefix_lookups": 0, "prefix_hits": 0,
-                      "prefix_tokens_reused": 0, "page_evictions": 0,
-                      "admission_stalls": 0, "fairness_rejected": 0,
-                      "paged_attention_fallback": 0,
-                      "stream_bridge_drops": 0,
-                      "lock_wait_scheduler_s": 0.0,
-                      "lock_wait_handler_s": 0.0}
         if self.speculative:
             # speculative-decoding observability (docs/serving.md
             # "Speculative decoding"): windows = (dispatch x live slot)
@@ -638,6 +572,8 @@ class ServingEngine:
             self.moe_expert_tokens = np.zeros(
                 (sum(_is_moe_layer(mc, i) for i in range(mc.num_layers)),
                  mc.moe_num_experts), np.int64)  # guarded-by: _lock
+        # the slot-occupancy trace the correctness test asserts
+        # EOS-mid-flight retirement against
         self.occupancy_trace = []        # (it, n_active)  # guarded-by: _lock
         # ---- observability layer (docs/observability.md): span tracer
         # + histograms + flight recorder.  All default-off = seed
@@ -928,14 +864,9 @@ class ServingEngine:
         total; the gap is the unattributed-bytes gauge.  Owner figures
         are ``nbytes`` sums (no device sync)."""
         from deepspeed_tpu.monitor.memwatch import tree_device_bytes
-        owners = {"params": tree_device_bytes(self.engine._params)}
-        key = "page_pool" if self.paged else "kv_slots"
-        owners[key] = tree_device_bytes(self._cache)
-        owners["slot_state"] = tree_device_bytes(self._state)
-        lanes = tree_device_bytes(self._lane_pool._lanes)
-        if self._pending is not None:
-            lanes += tree_device_bytes(self._pending.lane)
-        owners["prefill_lanes"] = lanes
+        owners = {"params": tree_device_bytes(self.engine._params),
+                  "page_pool": tree_device_bytes(self._cache),
+                  "slot_state": tree_device_bytes(self._state)}
         if self.speculative:
             owners["draft_kv"] = tree_device_bytes(self._draft_cache) \
                 + tree_device_bytes(self._draft_lanes._lanes)
@@ -1007,9 +938,9 @@ class ServingEngine:
     def submit(self, input_ids, max_new_tokens=32, eos_token_id=-1,
                deadline_s=None, client_id=None, priority=0):
         """Enqueue one prompt; returns the request id.  The request must
-        fit a slot lane: ``ceil(P/chunk)*chunk <= max_cache_len`` (chunked
-        prefill writes the padded tail) and ``P + max_new_tokens <=
-        max_cache_len``.
+        fit a slot's virtual lane: ``ceil(P/chunk)*chunk <= max_cache_len``
+        (chunked prefill writes the padded tail) and ``P + max_new_tokens
+        <= max_cache_len`` — and the page pool must be able to hold it.
 
         ``deadline_s`` (seconds from now; ``None`` = the config's
         ``default_deadline_s``, ``0`` = already expired): past it the
@@ -1078,17 +1009,12 @@ class ServingEngine:
                 + f", chunk-padded {padded}) but slot lanes hold "
                 f"{self.cache_len} — raise serving.max_cache_len or split "
                 f"the request")
-        if self.paged and pages_for(need, self.page) > self._pool.allocatable:
-            # a request the POOL can never satisfy must not enter the
-            # queue: with every other slot drained it would still stall
-            # admission forever (the per-request check above only bounds
-            # it against the virtual lane)
-            raise ValueError(
-                f"request needs {pages_for(need, self.page)} pages "
-                f"({need} positions at page_size={self.page}) but the "
-                f"pool holds {self._pool.allocatable} allocatable pages "
-                f"(num_pages={self.num_pages} incl. trash) — raise "
-                f"serving.num_pages or split the request")
+        too_big = self._pages.cannot_hold(need)
+        if too_big:
+            # (the per-request check above only bounds it against the
+            # virtual lane)
+            raise ValueError(f"request needs {too_big} — raise "
+                             f"serving.num_pages or split the request")
         self._breaker.check_submit()         # reject-with-reason when open
         if self._fairness is not None and not self._fairness.allow(client_id):
             self.stats["fairness_rejected"] += 1
@@ -1207,10 +1133,7 @@ class ServingEngine:
                 self._cond.notify_all()      # a queue spot freed
                 return True
             if self._pending is not None and self._pending.req is req:
-                self._give_back_lanes(self._pending)
-                self._free.append(int(self._pending.slot))
-                self._release_slot_pages(self._pending.slot)
-                self._pending = None
+                self._drop_pending()
                 self._record_terminal(req, RequestStatus.CANCELLED,
                                       "cancelled during admission prefill")
                 return True
@@ -1300,48 +1223,38 @@ class ServingEngine:
             for s in streams:
                 s.push(ev)
 
-    def _release_draft_workspaces(self):  # lock-held: _lock
-        """Free every draft-side buffer (close/preempt teardown)."""
-        if not self.speculative:
-            return
-        self._draft_cache = None
-        self._draft_ws.release()
-        self._draft_lanes.release()
+    def _release_workspaces(self):  # lock-held: _lock
+        """Free every device buffer but the weights (close/preempt
+        teardown)."""
+        self._cache = self._state = None
+        self._pages.drop_buffer()
+        if self.speculative:
+            self._draft_cache = None
+            self._draft_ws.release()
+            self._draft_lanes.release()
 
-    def _give_back_lanes(self, p):  # lock-held: _lock
-        """Return a dropped admission's prefill lane(s) to their pools —
-        the target lane and, under speculation, the draft lane."""
-        self._lane_pool.give_back(p.lane)
-        if self.speculative and p.draft_lane is not None:
+    def _give_back_draft_lane(self, p):  # lock-held: _lock
+        """Return a dropped admission's draft prefill lane to its pool
+        (speculative serving only)."""
+        if p.draft_lane is not None:
             self._draft_lanes.give_back(p.draft_lane)
             p.draft_lane = None
 
-    def _release_slot_pages(self, slot):  # lock-held: _lock
-        """Paged mode: return a retired slot's pages to the pool (shared
-        prefix pages just drop one reference) and point its table row at
-        the trash page — the NEXT dispatch's table redirects the zombie
-        lane's masked writes there, so a freed page can be reallocated
-        immediately (any write the zombie already has in flight executes
-        in device order BEFORE the new occupant's prefill and is either
-        overwritten or masked — docs/serving.md "Paged KV cache")."""
-        if not self.paged:
-            return
-        pages = self._slot_pages.pop(int(slot), None)
-        if pages is not None:
-            for pg in pages:
-                self._pool.decref(pg)
-        self._page_table[int(slot), :] = 0
+    def _drop_pending(self):  # lock-held: _lock
+        """Drop the admission in progress alone: its slot and pages go
+        back (partial prefill writes are overwritten by the next
+        occupant before any of its queries attend them)."""
+        p, self._pending = self._pending, None
+        self._give_back_draft_lane(p)
+        self._free.append(int(p.slot))
+        self._pages.release(p.slot)
 
-    def _paging_reset(self):  # lock-held: _lock
-        """Drop EVERY page mapping (pool bookkeeping, prefix index, all
-        table rows) — the pool buffer died with a failed dispatch or was
-        just (re)allocated, so no indexed content survives."""
-        if not self.paged:
-            return
-        self._prefix.clear(self._pool)
-        self._pool.reset()
-        self._page_table[:] = 0
-        self._slot_pages.clear()
+    def _free_slot(self, s):  # lock-held: _lock
+        """The host half of a retirement: the slot and its pages return
+        to their free lists."""
+        self._slots[s] = None
+        self._free.append(int(s))
+        self._pages.release(s)
 
     def _retire_slot_host_side(self, req):  # lock-held: _lock
         """Free a retired request's slot in the HOST MIRROR only — the
@@ -1354,9 +1267,7 @@ class ServingEngine:
         s = req.slot
         if s is not None and self._mirror_active[s]:
             self._mirror_active[s] = False
-            self._slots[s] = None
-            self._free.append(int(s))
-            self._release_slot_pages(s)
+            self._free_slot(s)
 
     def _record_terminal(self, req, status, detail):  # lock-held: _lock
         """Mark a non-COMPLETED terminal outcome and queue it for the
@@ -1396,10 +1307,7 @@ class ServingEngine:
         p = self._pending
         if p is not None and p.req.deadline is not None \
                 and now >= p.req.deadline:
-            self._give_back_lanes(p)
-            self._free.append(int(p.slot))
-            self._release_slot_pages(p.slot)
-            self._pending = None
+            self._drop_pending()
             self.stats["shed"] += 1
             self._record_terminal(p.req, RequestStatus.SHED_DEADLINE,
                                   "deadline expired during admission "
@@ -1662,12 +1570,8 @@ class ServingEngine:
                          f"({self._breaker.consecutive_failures} "
                          f"consecutive failures; last: "
                          f"{self._breaker.last_error})")
-        if self.paged:
-            lines.append(f"  page pool: {self._pool.in_use}"
-                         f"/{self._pool.allocatable} in use, "
-                         f"{len(self._prefix)} prefix entries, "
-                         f"{self.stats['admission_stalls']} admission "
-                         f"stall(s)")
+        lines.append(f"  {self._pages.describe()}, "
+                     f"{self.stats['admission_stalls']} admission stall(s)")
         return "\n".join(lines)
 
     def close(self):
@@ -1702,18 +1606,7 @@ class ServingEngine:
                                   "queued")
         self._queue.clear()
         self._abort_in_flight("close()")
-        if self._cache is not None:
-            if self.paged:
-                self._pool_ws.give_back(self._cache)
-            else:
-                self._cache_ws.give_back(self._cache)
-            self._cache = None
-        self._state = None
-        self._cache_ws.release()
-        self._lane_pool.release()
-        self._release_draft_workspaces()
-        if self.paged:
-            self._pool_ws.release()
+        self._release_workspaces()
         self._detach_observability()
         self._closed = True
         self._close_report = undrained
@@ -1748,7 +1641,7 @@ class ServingEngine:
             if req.status not in TERMINAL_STATUSES:
                 self._record_terminal(req, RequestStatus.ABORTED,
                                       f"admission aborted: {why}")
-            self._give_back_lanes(self._pending)
+            self._give_back_draft_lane(self._pending)
             self._pending = None
         self._events.clear()
         self._slots = [None] * self.num_slots
@@ -1761,7 +1654,7 @@ class ServingEngine:
             # — drop it so the next step reallocates a fresh one
             self._draft_ws.give_back(self._draft_cache)
             self._draft_cache = None
-        self._paging_reset()
+        self._pages.reset()
         if lost:
             self.stats["aborted"] = self.stats.get("aborted", 0) + len(lost)
             if self._flightrec is not None:
@@ -1795,9 +1688,9 @@ class ServingEngine:
 
     @property
     def page_pool_utilization(self):
-        """Allocated fraction of the page pool (0.0 when not paged)."""
+        """Allocated fraction of the page pool."""
         with self._lock:
-            return self._pool.utilization() if self.paged else 0.0
+            return self._pages.utilization
 
     @property
     def prefix_hit_rate(self):
@@ -1831,8 +1724,7 @@ class ServingEngine:
                 },
             }
             snap["slot_occupancy"] = snap["active_slots"] / self.num_slots
-            if self.paged:
-                snap["page_pool_utilization"] = self.page_pool_utilization
+            snap["page_pool_utilization"] = self.page_pool_utilization
             return snap
 
     # ------------------------------------------------------------------ #
@@ -1857,15 +1749,9 @@ class ServingEngine:
         eng = self.engine
         N, S, C = self.num_slots, self.cache_len, self.chunk
         dtype = eng.compute_dtype
-        if self.paged:
-            cache = jax.eval_shape(
-                lambda: self.module.init_paged_cache(
-                    self.num_pages, self.page, dtype=dtype))
-        else:
-            cache = jax.eval_shape(
-                lambda: self.module.init_cache(N, S, dtype=dtype))
-            lane = jax.eval_shape(
-                lambda: self.module.init_cache(1, S, dtype=dtype))
+        cache = jax.eval_shape(
+            lambda: self.module.init_paged_cache(
+                self.num_pages, self.page, dtype=dtype))
         state = {
             "token": jax.ShapeDtypeStruct((N,), jnp.int32),
             "pos": jax.ShapeDtypeStruct((N,), jnp.int32),
@@ -1889,47 +1775,26 @@ class ServingEngine:
             eng._aot[sig] = compiled
             return {name: 0.0 if hit else dt}
 
-        if self.paged:
-            row = jax.ShapeDtypeStruct((1, self.n_slot_pages), jnp.int32)
-            tables = jax.ShapeDtypeStruct((N, self.n_slot_pages),
-                                          jnp.int32)
-            cargs = (eng._params, cache, row,
-                     jax.ShapeDtypeStruct((1, C), jnp.int32),
-                     jax.ShapeDtypeStruct((), jnp.int32),
-                     jax.ShapeDtypeStruct((1,), jnp.int32))
-            report.update(warm(self._chunk_fn, cargs,
-                               f"serving_prefill_paged:c{C}p{self.page}"))
-            if self.speculative:
-                draft = jax.ShapeDtypeStruct((N, self.spec_k), jnp.int32)
-                report.update(warm(
-                    self._verify_fn,
-                    (eng._params, cache, state, tables, draft, rng),
-                    f"serving_spec_verify_paged:n{N}s{S}k{self.spec_k}"
-                    f"p{self.page}"))
-            else:
-                report.update(warm(
-                    self._decode_fn,
-                    (eng._params, cache, state, tables, rng),
-                    f"serving_decode_paged:n{N}s{S}b{self.block}"
-                    f"p{self.page}"))
+        row = jax.ShapeDtypeStruct((1, self.pages_per_slot), jnp.int32)
+        tables = jax.ShapeDtypeStruct((N, self.pages_per_slot), jnp.int32)
+        cargs = (eng._params, cache, row,
+                 jax.ShapeDtypeStruct((1, C), jnp.int32),
+                 jax.ShapeDtypeStruct((), jnp.int32),
+                 jax.ShapeDtypeStruct((1,), jnp.int32))
+        report.update(warm(self._chunk_fn, cargs,
+                           f"serving_prefill:c{C}p{self.page}"))
+        if self.speculative:
+            draft = jax.ShapeDtypeStruct((N, self.spec_k), jnp.int32)
+            report.update(warm(
+                self._verify_fn,
+                (eng._params, cache, state, tables, draft, rng),
+                f"serving_spec_verify:n{N}s{S}k{self.spec_k}"
+                f"p{self.page}"))
         else:
-            cargs = (eng._params, lane,
-                     jax.ShapeDtypeStruct((1, C), jnp.int32),
-                     jax.ShapeDtypeStruct((), jnp.int32),
-                     jax.ShapeDtypeStruct((1,), jnp.int32))
-            report.update(warm(self._chunk_fn, cargs,
-                               f"serving_prefill:c{C}"))
-            if self.speculative:
-                draft = jax.ShapeDtypeStruct((N, self.spec_k), jnp.int32)
-                report.update(warm(
-                    self._verify_fn,
-                    (eng._params, cache, state, draft, rng),
-                    f"serving_spec_verify:n{N}s{S}k{self.spec_k}"))
-            else:
-                report.update(warm(self._decode_fn,
-                                   (eng._params, cache, state, rng),
-                                   f"serving_decode:n{N}s{S}"
-                                   f"b{self.block}"))
+            report.update(warm(
+                self._decode_fn,
+                (eng._params, cache, state, tables, rng),
+                f"serving_decode:n{N}s{S}b{self.block}p{self.page}"))
         if self.speculative:
             dcache = jax.eval_shape(
                 lambda: self.draft_module.init_cache(N, S, dtype=dtype))
@@ -2017,17 +1882,16 @@ class ServingEngine:
                 req = self._pop_request()
                 pend = self._start_prefill(req)
                 if pend is None:
-                    # paged pool pressure: not enough free pages even
-                    # after evicting unreferenced prefix pages — the
-                    # request waits at the queue head until retirements
-                    # free pages (backpressure, never a partial grab)
+                    # pool pressure: not enough free pages even after
+                    # evicting unreferenced prefix pages — the request
+                    # waits at the queue head until retirements free
+                    # pages (backpressure, never a partial grab)
                     self._queue.appendleft(req)
                     self.stats["admission_stalls"] += 1
                     if self._flightrec is not None:
                         self._flightrec.record(
                             "admission_stall", rid=req.rid,
-                            pool_in_use=self._pool.in_use
-                            if self.paged else None)
+                            pool_in_use=self._pages.in_use)
                     return
                 if self._tracer is not None and req.t_trace is not None:
                     # queue phase ends here: admission decided, the slot
@@ -2045,7 +1909,7 @@ class ServingEngine:
                         fill_len=pend.fill_len, chunks=pend.n_chunks)
                 if self._fairness is not None and not req.resumed:
                     # charge admitted prefill work once, when admission
-                    # actually starts (a paged stall above retries the
+                    # actually starts (a stall above retries the
                     # same request without double-charging).  Resumed
                     # requests charge NOTHING here: their prompt and
                     # generated-so-far tokens were billed in the prior
@@ -2062,92 +1926,22 @@ class ServingEngine:
                 self._dispatch_admit(pend)
 
     def _start_prefill(self, req):  # lock-held: _lock
+        """Reserve the head free slot and its pages (``paging.SlotPages.
+        reserve``) for ``req``.  Returns ``None`` — nothing popped,
+        nothing allocated — when the pool cannot back the request yet."""
         fill = req.fill_ids              # prompt + any resumed tokens
-        P = len(fill)
-        if self.paged:
-            return self._start_prefill_paged(req, fill, P)
-        slot = self._free.popleft()
-        req.slot = slot
-        req.status = RequestStatus.PREFILLING
-        n = -(-P // self.chunk)
-        ids_pad = np.zeros((1, n * self.chunk), np.int32)
-        ids_pad[0, :P] = fill
-        lane = self._lane_pool.take(self.cache_len,
-                                    self.engine.compute_dtype)
-        pend = _PendingPrefill(req, slot, lane, ids_pad, n, P)
-        if self.speculative:
-            pend.draft_lane = self._draft_lanes.take(
-                self.cache_len, self.engine.compute_dtype)
-        return pend
-
-    def _start_prefill_paged(self, req, fill, P):  # lock-held: _lock
-        """Paged admission: map the longest indexed prefix (full pages,
-        refcounted — prefilled ONCE per unique prefix), allocate private
-        pages for the rest of the virtual lane, and prefill only from
-        the shared boundary on.  Returns ``None`` (nothing popped,
-        nothing allocated) when the pool cannot back the request yet."""
-        dev_new = req.max_new - len(req.prefix)
-        matched = []
-        if self.config.prefix_cache and not self.speculative:
-            # cap the match so the block holding the LAST prompt position
-            # is always recomputed: admission samples the first token
-            # from that position's logits, so at least one chunk must run
-            # (speculative serving skips prefix sharing: the DRAFT cache
-            # has no page pool, so its prefill must run from position 0
-            # anyway — a shared target prefix would leave the draft side
-            # unfilled; docs/serving.md "Speculative decoding")
-            matched = self._prefix.lookup(fill, self.page, self._pool,
-                                          (P - 1) // self.page)
-        m = len(matched)
-        # the prefill start must be CHUNK-aligned, not just page-aligned:
-        # chunk ci writes the full padded span [s0+ci*C, s0+(ci+1)*C),
-        # and only a chunk-aligned s0 keeps the padded end at
-        # ceil(P/C)*C — the bound submit() already checked against the
-        # lane.  A page-aligned-only start can pad PAST the table row
-        # (page 16, chunk 64, P=120, m=7: 112+64=176 > 8-page lane)
-        g = self.chunk // math.gcd(self.page, self.chunk)
-        if m % g:
-            for pg in matched[(m // g) * g:]:
-                self._pool.decref(pg)
-            matched = matched[:(m // g) * g]
-            m = len(matched)
-        s0 = m * self.page               # prefill start
-        n_chunks = -(-(P - s0) // self.chunk)
-        # the slot's virtual extent: decode writes through P+dev_new-1,
-        # the padded last chunk writes through s0+n_chunks*C-1
-        virt = max(P + dev_new, s0 + n_chunks * self.chunk)
-        need_private = pages_for(virt, self.page) - m
-        got = self._pool.alloc(need_private)
-        if got is None and self.config.prefix_cache:
-            freed = self._prefix.evict(
-                self._pool, need_private - self._pool.free_count)
-            self.stats["page_evictions"] += freed
-            got = self._pool.alloc(need_private)
+        slot = self._free[0]
+        got = self._pages.reserve(slot, fill,
+                                  req.max_new - len(req.prefix))
         if got is None:
-            for pg in matched:
-                self._pool.decref(pg)
             return None
-        if self.config.prefix_cache and not self.speculative:
-            # stats count ADMISSIONS, not stalled retries of the same
-            # request (a 50-step stall must not record 50 lookups/hits)
-            self.stats["prefix_lookups"] += 1
-            if matched:
-                self.stats["prefix_hits"] += 1
-                self.stats["prefix_tokens_reused"] += m * self.page
-        slot = self._free.popleft()
+        _, start = got
+        self._free.popleft()
         req.slot = slot
         req.status = RequestStatus.PREFILLING
-        row = matched + got
-        self._slot_pages[slot] = row
-        self._page_table[slot, :] = 0
-        self._page_table[slot, :len(row)] = row
-        ids_pad = np.zeros((1, n_chunks * self.chunk), np.int32)
-        ids_pad[0, :P - s0] = fill[s0:]
-        pend = _PendingPrefill(req, slot, None, ids_pad, n_chunks, P)
-        pend.start = s0
-        pend.fill_tokens = fill
+        pend = _PendingPrefill(req, slot, fill, start, self.chunk)
         if self.speculative:
-            # s0 == 0 under speculation (prefix sharing disabled), so
+            # start == 0 under speculation (prefix sharing disabled), so
             # the draft lane prefills the same chunk spans as the pool
             pend.draft_lane = self._draft_lanes.take(
                 self.cache_len, self.engine.compute_dtype)
@@ -2157,63 +1951,37 @@ class ServingEngine:
         C = self.chunk
         P = p.fill_len
         # chunk ci covers absolute positions [start + ci*C, start +
-        # (ci+1)*C); start > 0 only for paged shared-prefix admissions
+        # (ci+1)*C); start > 0 only for shared-prefix admissions
         local = int(min(max(P - 1 - p.start - p.ci * C, 0), C - 1))
         try:
-            with self._observe_dispatch("prefill_chunk", rid=p.req.rid,
-                                        slot=p.slot, chunk=p.ci,
-                                        phase="prefill",
-                                        **self._chunk_kv_work(p)):
-                if self.paged:
-                    # the chunk writes straight into the slot's pool
-                    # pages — the POOL is the donated buffer, chained
-                    # with decode
-                    row = jnp.asarray(
-                        self._page_table[p.slot:p.slot + 1])
-                    logits, self._cache, *load = self.engine._run_guarded(
-                        self._chunk_fn,
-                        (self.engine._params, self._cache, row,
-                         jnp.asarray(
-                             p.ids_pad[:, p.ci * C:(p.ci + 1) * C]),
-                         jnp.asarray(p.start + p.ci * C, jnp.int32),
-                         jnp.asarray([local], jnp.int32)))
-                    p.expert_loads += load      # expert models only
-                else:
-                    logits, p.lane = self.engine._run_guarded(
-                        self._chunk_fn,
-                        (self.engine._params, p.lane,
-                         jnp.asarray(
-                             p.ids_pad[:, p.ci * C:(p.ci + 1) * C]),
-                         jnp.asarray(p.ci * C, jnp.int32),
-                         jnp.asarray([local], jnp.int32)))
+            with self._observe_dispatch(
+                    "prefill_chunk", rid=p.req.rid, slot=p.slot,
+                    chunk=p.ci, phase="prefill",
+                    **self._pages.chunk_reach(
+                        self.module.config.num_layers,
+                        p.start + (p.ci + 1) * C)):
+                # the chunk writes straight into the slot's pool pages
+                # — the POOL is the donated buffer, chained with decode
+                logits, self._cache, *load = self.engine._run_guarded(
+                    self._chunk_fn,
+                    (self.engine._params, self._cache,
+                     jnp.asarray(self._pages.row(p.slot)),
+                     jnp.asarray(p.ids_pad[:, p.ci * C:(p.ci + 1) * C]),
+                     jnp.asarray(p.start + p.ci * C, jnp.int32),
+                     jnp.asarray([local], jnp.int32)))
+                p.expert_loads += load      # expert models only
         except BaseException as e:
-            if self.paged:
-                # the donated POOL may be dead — this is a decode-grade
-                # failure: every in-flight request's KV lived in it
-                self._pool_ws.give_back(self._cache)
-                self._cache = None
-                if p.req.status not in TERMINAL_STATUSES:
-                    self._record_terminal(
-                        p.req, RequestStatus.ABORTED,
-                        f"admission prefill dispatch failed: "
-                        f"{type(e).__name__}: {e}")
-                self._abort_in_flight(
-                    f"paged prefill dispatch failed "
-                    f"(request {p.req.rid} lost)")
-                raise
-            # the donated lane may be dead — drop only THIS admission
-            # (the decode workspace is untouched by a prefill failure)
-            self._give_back_lanes(p)
-            self._free.append(int(p.slot))
-            self._pending = None
+            # the donated POOL may be dead — this is a decode-grade
+            # failure: every in-flight request's KV lived in it
+            self._pages.give_back(self._cache)
+            self._cache = None
             if p.req.status not in TERMINAL_STATUSES:
                 self._record_terminal(
                     p.req, RequestStatus.ABORTED,
                     f"admission prefill dispatch failed: "
                     f"{type(e).__name__}: {e}")
-                self.stats["aborted"] = self.stats.get("aborted", 0) + 1
-            logger.warning(f"serving prefill failed — request "
-                           f"{p.req.rid} dropped")
+            self._abort_in_flight(
+                f"prefill dispatch failed (request {p.req.rid} lost)")
             raise
         if self.speculative:
             # mirror the chunk into the DRAFT lane: speculation proposes
@@ -2234,14 +2002,8 @@ class ServingEngine:
                          jnp.asarray([local], jnp.int32)))
             except BaseException as e:
                 # the donated draft lane may be dead — drop only THIS
-                # admission.  The target side's partial writes are freed
-                # with the slot (monolithic lane back to the pool, paged
-                # pages decref'd) and overwritten by the next occupant
-                # before any of its queries attend them.
-                self._give_back_lanes(p)
-                self._free.append(int(p.slot))
-                self._release_slot_pages(p.slot)
-                self._pending = None
+                # admission
+                self._drop_pending()
                 if p.req.status not in TERMINAL_STATUSES:
                     self._record_terminal(
                         p.req, RequestStatus.ABORTED,
@@ -2263,24 +2025,10 @@ class ServingEngine:
         self.stats["prefill_tokens"] += C
         return p.ci >= p.n_chunks
 
-    def _chunk_kv_work(self, p):  # lock-held: _lock
-        """What the prefill chunk about to be dispatched attends, as the
-        dispatch span's args (paged engines): ``kv_pages`` — the pages
-        its layers fetch, every page of the slot's table up to the
-        chunk's furthest position, which is the paged chunk-prefill
-        kernel's block loop — and ``kv_pages_table``, pages a slot x
-        layers, what a walk over the whole table would take."""
-        if not self.paged:
-            return {}
-        layers = self.module.config.num_layers
-        reach = -(-(p.start + (p.ci + 1) * self.chunk) // self.page)
-        return {"kv_pages": layers * min(reach, self.n_slot_pages),
-                "kv_pages_table": layers * self.n_slot_pages}
-
     def _dispatch_admit(self, p):  # lock-held: _lock
-        """Prefill complete: ONE fused dispatch samples the first token,
-        inserts the lane and writes the slot state in-program.  The first
-        token is read lazily when the event is processed.  A resumed
+        """Prefill complete: ONE dispatch samples the first token and
+        writes the slot state in-program.  The first token is read
+        lazily when the event is processed.  A resumed
         request (non-empty ``prefix``) admits with the REMAINING token
         budget — its prefix already counts against ``max_new``."""
         req = p.req
@@ -2291,36 +2039,23 @@ class ServingEngine:
             with self._observe_dispatch("admit", rid=req.rid,
                                         slot=int(p.slot),
                                         phase="admit"):
-                if self.paged:
-                    # the prompt's K/V already sits in the slot's pages
-                    # — paged admission is just the first-token sample +
-                    # the in-program slot-state write (state donated)
-                    self._state, first = self.engine._run_guarded(
-                        self._admit_fn,
-                        (self._state, p.sel, sub,
-                         jnp.asarray(p.slot, jnp.int32),
-                         jnp.asarray(p.fill_len, jnp.int32),
-                         jnp.asarray(dev_new, jnp.int32),
-                         jnp.asarray(req.eos, jnp.int32)))
-                else:
-                    self._cache, self._state, first = \
-                        self.engine._run_guarded(
-                            self._admit_fn,
-                            (self._cache, self._state, p.lane, p.sel, sub,
-                             jnp.asarray(p.slot, jnp.int32),
-                             jnp.asarray(p.fill_len, jnp.int32),
-                             jnp.asarray(dev_new, jnp.int32),
-                             jnp.asarray(req.eos, jnp.int32)))
+                # the prompt's K/V already sits in the slot's pages —
+                # admission is just the first-token sample + the
+                # in-program slot-state write (state donated)
+                self._state, first = self.engine._run_guarded(
+                    self._admit_fn,
+                    (self._state, p.sel, sub,
+                     jnp.asarray(p.slot, jnp.int32),
+                     jnp.asarray(p.fill_len, jnp.int32),
+                     jnp.asarray(dev_new, jnp.int32),
+                     jnp.asarray(req.eos, jnp.int32)))
         except BaseException as e:
-            # cache/state were donated — same recovery as a decode
-            # failure (this admission's request is lost with them).
-            # Paged: only the STATE died (the pool is not an admit
-            # argument); _abort_in_flight still resets all paging
-            # bookkeeping, so stale KV is never attended.
-            if not self.paged:
-                self._cache_ws.give_back(self._cache)
-                self._cache = None
-            self._give_back_lanes(p)
+            # the state was donated — same recovery as a decode failure
+            # (this admission's request is lost with it).  Only the
+            # STATE died (the pool is not an admit argument);
+            # _abort_in_flight still resets all page bookkeeping, so
+            # stale KV is never attended.
+            self._give_back_draft_lane(p)
             if req.status not in TERMINAL_STATUSES:
                 self._record_terminal(req, RequestStatus.ABORTED,
                                       f"admit dispatch failed: "
@@ -2329,18 +2064,9 @@ class ServingEngine:
                                   f"(request {req.rid} lost)")
             raise
         self._breaker.record_success()
-        if self.paged and self.config.prefix_cache \
-                and not self.speculative and p.fill_tokens is not None:
-            # index this request's full-prompt pages as sharable —
-            # their prefill writes are complete (dispatched before this
-            # admit) and nothing ever writes them again (the slot's own
-            # writes land at positions >= fill_len)
-            self._prefix.register(p.fill_tokens, self.page,
-                                  self._slot_pages[p.slot], self._pool,
-                                  p.fill_len // self.page)
+        self._pages.share(p.slot, p.fill)
         if self.speculative:
-            # insert the prefilled draft lane into the draft cache (the
-            # draft-side twin of the target admit's lane insert)
+            # insert the prefilled draft lane into the draft cache
             t0s = time.perf_counter()
             try:
                 with self._observe_dispatch("draft_admit", rid=req.rid,
@@ -2353,7 +2079,7 @@ class ServingEngine:
             except BaseException as e:
                 # the donated draft cache may be dead — decode-grade
                 # failure: every live slot's draft K/V lived in it
-                self._give_back_lanes(p)
+                self._give_back_draft_lane(p)
                 if req.status not in TERMINAL_STATUSES:
                     self._record_terminal(
                         req, RequestStatus.ABORTED,
@@ -2366,8 +2092,8 @@ class ServingEngine:
         self._slot_last_dispatch[int(p.slot)] = time.monotonic()
         req.status = RequestStatus.RUNNING
         self._slots[p.slot] = req
-        self._events.append(("admit", req, p.slot, p.lane, first,
-                             p.draft_lane, p.expert_loads))
+        self._events.append(("admit", req, p.slot, first, p.draft_lane,
+                             p.expert_loads))
         self.stats["admitted"] += 1
         if self._tracer is not None and req.t_trace is not None:
             # prefill phase ends: the fused admit is dispatched; what
@@ -2394,20 +2120,12 @@ class ServingEngine:
                         "decode", phase="decode",
                         live_slots=int(self._mirror_active.sum()),
                         **self._block_kv_work()):
-                    load = []
-                    if self.paged:
-                        toks, self._cache, self._state, *load = \
-                            self.engine._run_guarded(
-                                self._decode_fn,
-                                (self.engine._params, self._cache,
-                                 self._state,
-                                 jnp.asarray(self._page_table), sub))
-                    else:
-                        toks, self._cache, self._state = \
-                            self.engine._run_guarded(
-                                self._decode_fn,
-                                (self.engine._params, self._cache,
-                                 self._state, sub))
+                    toks, self._cache, self._state, *load = \
+                        self.engine._run_guarded(
+                            self._decode_fn,
+                            (self.engine._params, self._cache,
+                             self._state,
+                             jnp.asarray(self._pages.table()), sub))
                 ev = ("decode", toks, load)
         except BaseException:
             # the donated cache/state may be dead — drop them so the next
@@ -2415,10 +2133,7 @@ class ServingEngine:
             # past admission (its KV rows died with the buffers; stale
             # events/slot bookkeeping must not survive into the fresh
             # state).  Queued requests are untouched.
-            if self.paged:
-                self._pool_ws.give_back(self._cache)
-            else:
-                self._cache_ws.give_back(self._cache)
+            self._pages.give_back(self._cache)
             self._cache = None
             self._abort_in_flight("decode dispatch failed")
             raise
@@ -2429,10 +2144,10 @@ class ServingEngine:
                 self._slot_last_dispatch[s] = now
         self._events.append(ev)
         self.stats["decode_calls"] += 1
-        if self.paged and self.kernel_modes["decode"] == "reference_fallback":
+        if self.kernel_modes["decode"] == "reference_fallback":
             # this decode dispatch took the take_along_axis gather path
-            # (serving.paged_kernel=False, or no Pallas / alibi) — the
-            # BENCH_r04 bs128 cliff, surfaced instead of silent
+            # (no Pallas, or alibi) — the BENCH_r04 bs128 cliff,
+            # surfaced instead of silent
             self.stats["paged_attention_fallback"] += 1
         return True
 
@@ -2447,13 +2162,9 @@ class ServingEngine:
         positions.  Exact unless a request stops early on eos inside
         the unread block (then over by less than one block for that
         slot): the bytes the paged-decode kernel must read are this
-        times the K/V bytes of one position, every layer.  A paged
-        engine adds ``kv_pages`` — the pages those steps walk,
-        ``ceil(context / page_size)`` a live slot and step, which is
-        the paged-decode kernel's page loop — and ``kv_pages_table``,
-        the slots x pages-a-slot x steps a walk over the whole table
-        would take: their ratio is the share of the table that is
-        live."""
+        times the K/V bytes of one position, every layer.  With them,
+        the pages those steps walk beside the whole table's
+        (``paging.SlotPages.block_reach``)."""
         block = self.block
         unread = block * sum(e[0] == "decode" for e in self._events)
         live = [(r, len(r.tokens) + unread)
@@ -2461,20 +2172,12 @@ class ServingEngine:
                 if r is not None and self._mirror_active[s]]
         live += [(e[1], len(e[1].prefix) + 1) for e in self._events
                  if e[0] == "admit" and e[1].status not in TERMINAL_STATUSES]
-        positions = pages = 0
-        for req, have in live:
-            steps = min(block, req.max_new - have)
-            if steps > 0:
-                first = len(req.ids) + have
-                positions += steps * first + steps * (steps - 1) // 2
-                if self.paged:
-                    pages += sum(-(-(first + i) // self.page)
-                                 for i in range(steps))
-        if not self.paged:
-            return {"kv_positions": positions}
-        return {"kv_positions": positions, "kv_pages": pages,
-                "kv_pages_table":
-                    self.num_slots * self.n_slot_pages * block}
+        # (context the first step attends, steps) a live slot
+        work = [(len(req.ids) + have, min(block, req.max_new - have))
+                for req, have in live if req.max_new > have]
+        return {"kv_positions": sum(steps * first + steps * (steps - 1) // 2
+                                    for first, steps in work),
+                **self._pages.block_reach(work, block)}
 
     def _dispatch_spec(self, sub):  # lock-held: _lock
         """One speculative round, two device-chained dispatches and zero
@@ -2497,18 +2200,11 @@ class ServingEngine:
         self.stats["spec_draft_secs"] += t1 - t0
         with self._observe_dispatch("spec_verify", phase="decode",
                                     live_slots=live):
-            if self.paged:
-                toks, accepted, self._cache, self._state = \
-                    self.engine._run_guarded(
-                        self._verify_fn,
-                        (self.engine._params, self._cache, self._state,
-                         jnp.asarray(self._page_table), draft, sub))
-            else:
-                toks, accepted, self._cache, self._state = \
-                    self.engine._run_guarded(
-                        self._verify_fn,
-                        (self.engine._params, self._cache, self._state,
-                         draft, sub))
+            toks, accepted, self._cache, self._state = \
+                self.engine._run_guarded(
+                    self._verify_fn,
+                    (self.engine._params, self._cache, self._state,
+                     jnp.asarray(self._pages.table()), draft, sub))
         self.stats["spec_verify_secs"] += time.perf_counter() - t1
         return ("spec", toks, accepted)
 
@@ -2549,7 +2245,7 @@ class ServingEngine:
                moe_calls=len(loads) * steps * len(self.moe_expert_tokens))
 
     def _process_admit(self, ev, finished):  # lock-held: _lock
-        _, req, slot, lane, first_dev, draft_lane, expert_loads = ev
+        _, req, slot, first_dev, draft_lane, expert_loads = ev
         with span("dstpu.sched.wait_device", track="scheduler",
                   cat="scheduler", event="admit", rid=req.rid) as sp:
             first = int(np.asarray(first_dev))
@@ -2557,17 +2253,14 @@ class ServingEngine:
             # ``first``: their loads are on the host's side of the wait
             self._account_expert_load(expert_loads, sp)
         self.stats["sync_secs"] += sp.dur_s
-        self._lane_pool.give_back(lane)
-        if self.speculative and draft_lane is not None:
+        if draft_lane is not None:
             self._draft_lanes.give_back(draft_lane)
         if req.status in TERMINAL_STATUSES:
             # shed/cancelled while the admit event was in flight: free
             # the slot now (the shed path left it to us), discard the
             # token — the device lane stays a masked no-op until its
             # next occupant's admit overwrites it
-            self._slots[slot] = None
-            self._free.append(int(slot))
-            self._release_slot_pages(slot)
+            self._free_slot(slot)
             return
         if req.first_tok_t is None:
             req.first_tok_t = time.monotonic()
@@ -2588,9 +2281,7 @@ class ServingEngine:
         # REMAINING budget max_new - len(prefix))
         dev_new = req.max_new - len(req.prefix)
         if (req.eos >= 0 and first == req.eos) or dev_new == 1:
-            self._slots[slot] = None
-            self._free.append(int(slot))
-            self._release_slot_pages(slot)
+            self._free_slot(slot)
             finished[req.rid] = self._finalize(req)
         else:
             self._mirror_active[slot] = True
@@ -2618,9 +2309,7 @@ class ServingEngine:
         if (req.eos >= 0 and tok == req.eos) \
                 or len(req.tokens) >= req.max_new:
             self._mirror_active[s] = False
-            self._slots[s] = None
-            self._free.append(int(s))
-            self._release_slot_pages(s)
+            self._free_slot(s)
             finished[req.rid] = self._finalize(req)
             return True
         self._publish_progress(req)
@@ -2811,27 +2500,14 @@ class ServingEngine:
         snapped = [r.rid for r in undrained]
         # retire the engine without ABORTED accounting: the snapshotted
         # requests are not lost, they resume elsewhere
-        if self._pending is not None:
-            self._give_back_lanes(self._pending)
-            self._pending = None
+        self._pending = None
         self._queue.clear()
         self._events.clear()
         self._slots = [None] * self.num_slots
         self._free = deque(range(self.num_slots))
         self._mirror_active[:] = False
-        if self._cache is not None:
-            if self.paged:
-                self._pool_ws.give_back(self._cache)
-            else:
-                self._cache_ws.give_back(self._cache)
-            self._cache = None
-        self._state = None
-        self._cache_ws.release()
-        self._lane_pool.release()
-        self._release_draft_workspaces()
-        self._paging_reset()
-        if self.paged:
-            self._pool_ws.release()
+        self._release_workspaces()
+        self._pages.reset()
         self._detach_observability()
         self._closed = True
         self._close_report = sorted(snapped)
@@ -2893,13 +2569,13 @@ class ServingEngine:
                 "submitted_it": int(r.submitted_it),
                 "priority": int(r.priority),
             }
-            if self.paged and r.slot is not None \
-                    and int(r.slot) in self._slot_pages:
+            pages = None if r.slot is None \
+                else self._pages.slot_pages_str(r.slot)
+            if pages is not None:
                 # diagnostics only (restore re-prefills; physical pages
                 # are meaningless in another process) — range-compressed,
                 # never one JSON int per table entry
-                entry["pages"] = compact_page_str(
-                    self._slot_pages[int(r.slot)])
+                entry["pages"] = pages
             reqs.append(entry)
         fcfg = getattr(self.engine._config, "fault", None)
         state = {
@@ -2993,19 +2669,16 @@ class ServingEngine:
                                f"not fit this server's lanes — ABORTED")
                 self._next_rid = max(self._next_rid, req.rid + 1)
                 continue
-            if self.paged and pages_for(need, self.page) \
-                    > self._pool.allocatable:
+            too_big = self._pages.cannot_hold(need)
+            if too_big:
                 # the snapshot may come from a server with a bigger page
                 # pool — mirror submit()'s pool-capacity check instead
                 # of stalling admission forever on an unfittable request
                 self._requests[req.rid] = req
                 self._record_terminal(
                     req, RequestStatus.ABORTED,
-                    f"restored request needs "
-                    f"{pages_for(need, self.page)} pages but this "
-                    f"server's pool holds {self._pool.allocatable} "
-                    f"allocatable (num_pages={self.num_pages} incl. "
-                    f"trash) — raise serving.num_pages to resume it")
+                    f"restored request needs {too_big} — raise "
+                    f"serving.num_pages to resume it")
                 logger.warning(f"serving restore: request {req.rid} does "
                                f"not fit this server's page pool — "
                                f"ABORTED")
@@ -3044,16 +2717,7 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
     def _ensure_workspace(self):  # lock-held: _lock
         if self._cache is None:
-            if self.paged:
-                self._cache = self._pool_ws.take(
-                    self.num_pages, self.page, self.engine.compute_dtype)
-                # fresh (or reallocated) pool buffer: the host mirror
-                # must match it — everything free, nothing indexed
-                self._paging_reset()
-            else:
-                self._cache = self._cache_ws.take(
-                    self.num_slots, self.cache_len,
-                    self.engine.compute_dtype)
+            self._cache = self._pages.take(self.engine.compute_dtype)
         if self.speculative and self._draft_cache is None:
             self._draft_cache = self._draft_ws.take(
                 self.num_slots, self.cache_len, self.engine.compute_dtype)
@@ -3090,11 +2754,11 @@ class ServingEngine:
         ] + ([
             ("Serving/fairness_rejected",
              self.stats["fairness_rejected"], self._it),
-        ] if self._fairness is not None else []) + ([
+        ] if self._fairness is not None else []) + [
             ("Serving/page_pool_util", self.page_pool_utilization,
              self._it),
             ("Serving/prefix_hit_rate", self.prefix_hit_rate, self._it),
-        ] if self.paged else []) + ([
+        ] + ([
             ("Serving/hbm_bytes_in_use",
              self.stats["hbm_bytes_in_use"], self._it),
             ("Serving/hbm_peak_bytes",

@@ -725,8 +725,7 @@ class ServingHTTPFrontend:
                 "active_slots": srv.active_slots,
                 "in_flight": srv.in_flight,
                 "breaker_open": srv._breaker.open,
-                "paged_util": srv.page_pool_utilization
-                if srv.paged else None,
+                "page_pool_util": srv.page_pool_utilization,
                 "fairness": None if srv._fairness is None
                 else sorted(srv._fairness.window_usage().items()),
                 "fairness_budget": None if srv._fairness is None
@@ -776,9 +775,8 @@ class ServingHTTPFrontend:
                "thread class", "gauge",
                [("", {"thread_class": cls}, lock_wait[cls])
                 for cls in sorted(lock_wait)])
-        if snap["paged_util"] is not None:
-            gauge("page_pool_utilization", snap["paged_util"],
-                  "allocated fraction of the KV page pool")
+        gauge("page_pool_utilization", snap["page_pool_util"],
+              "allocated fraction of the KV page pool")
         if snap["fairness"] is not None:
             series("dstpu_serving_fairness_window_tokens",
                    "per-client decayed window balance", "gauge",

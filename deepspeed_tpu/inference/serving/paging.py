@@ -1,5 +1,5 @@
 """Host-side paged-KV bookkeeping for the serving engine
-(``docs/serving.md``, "Paged KV cache").
+(``docs/serving.md``, "KV cache").
 
 The device holds one page POOL (``Transformer.init_paged_cache``:
 ``[L, num_pages, page_size, KVH*D]``) shared by every slot; which
@@ -9,7 +9,7 @@ pages_per_slot]`` page-table argument on every dispatch — page churn
 never changes a program shape (vLLM's PagedAttention block tables, Kwon
 et al. SOSP'23, under this framework's one-executable constraint).
 
-Three pieces:
+The pieces:
 
 * :class:`PagePool` — the refcounted free-list mirror of the device
   pool.  Page 0 is the reserved TRASH page: never allocated, and every
@@ -27,12 +27,18 @@ Three pieces:
   one-executable invariant).  Unreferenced entries evict LRU, leaves
   first (an interior chain node with live children never evicts — a
   broken chain would strand its descendants' refcounts).
-* :class:`PagedPoolWorkspace` — the donated-buffer pool workspace with
-  the same dead-after-failed-dispatch liveness check
-  ``KVCacheWorkspace`` does.
+* :class:`SlotPages` — how the two combine: the serving engine's ONE
+  cache manager.  It owns the pool mirror, the prefix index, the page
+  table, the slot -> pages map and the pool's donated device buffer
+  (with the same dead-after-failed-dispatch liveness check
+  ``KVCacheWorkspace`` does); the scheduler
+  asks it to back a slot (:meth:`SlotPages.reserve`), to free one
+  (:meth:`SlotPages.release`) and for the table to ship
+  (:meth:`SlotPages.table`), and holds no page arithmetic of its own.
 """
 
 import hashlib
+import math
 from collections import deque
 
 import numpy as np
@@ -252,35 +258,218 @@ class PrefixIndex:
         self._entries.clear()
 
 
-class PagedPoolWorkspace:
-    """The serving engine's persistent page-pool buffer: donated into
-    every paged program and reclaimed from its output, reallocated only
-    when the geometry changes or a failed dispatch left the returned
-    buffers dead (same liveness contract as ``KVCacheWorkspace``)."""
+class SlotPages:
+    """Which physical pages back which slot — the serving engine's cache
+    manager.  Host-side only; it takes NO lock of its own: every call
+    runs under the engine's ``_lock`` (the engine's ``_pages`` field is
+    the one guarded paging field, ``serving/concurrency.py``).
 
-    def __init__(self, module):
+    ``page_size`` is rounded up to a multiple of 8 (sublane alignment,
+    floor 8) and the slot's virtual lane, ``cache_len``, up to a whole
+    number of pages.  ``num_pages`` INCLUDES the reserved trash page 0;
+    0 = auto, the full worst case (every slot at ``cache_len`` — no HBM
+    savings, no pool pressure).  ``share_prefixes`` turns the prefix
+    index on.  ``stats`` is the dict (the engine's) whose
+    ``prefix_lookups`` / ``prefix_hits`` / ``prefix_tokens_reused`` /
+    ``page_evictions`` entries the admissions are counted into."""
+
+    def __init__(self, module, num_slots, cache_len, page_size, num_pages,
+                 chunk, share_prefixes, stats):
+        self.page = max(8, -(-int(page_size) // 8) * 8)
+        self.pages_per_slot = pages_for(cache_len, self.page)
+        self.cache_len = self.pages_per_slot * self.page
+        self.num_slots = int(num_slots)
+        self.num_pages = int(num_pages) \
+            or self.num_slots * self.pages_per_slot + 1
+        if self.num_pages < 2:
+            raise ValueError(f"serving.num_pages={num_pages}: "
+                             f"need >= 2 (trash + 1 allocatable)")
+        self.chunk = int(chunk)
+        self.share_prefixes = bool(share_prefixes)
+        self._stats = stats
         self._module = module
-        self._key = None
-        self._pool = None
+        self._buffer = None              # the device pool, between uses
+        self._pool = PagePool(self.num_pages)
+        self._prefix = PrefixIndex()
+        # shipped as a traced arg on every dispatch; 0 = the trash page
+        self._table = np.zeros((self.num_slots, self.pages_per_slot),
+                               np.int32)
+        self._rows = {}                  # slot -> [page ids]
 
-    def take(self, num_pages, page_size, dtype):
-        import jax.numpy as jnp
-        key = (int(num_pages), int(page_size), jnp.dtype(dtype).name)
-        pool, self._pool = self._pool, None
-        if pool is not None and any(
-                getattr(l, "is_deleted", lambda: False)()
-                for l in jax.tree.leaves(pool)):
-            pool = None
-        if pool is None or self._key != key:
-            pool = None
-            self._key = key
-            pool = self._module.init_paged_cache(num_pages, page_size,
+    # ---- the device buffer ----
+    def take(self, dtype):
+        """The pool buffer for the next dispatches: donated into every
+        slot program and reclaimed from its output, reallocated only
+        when a failed dispatch left the returned buffers dead — with
+        every mapping dropped, so the host mirror matches it: everything
+        free, nothing indexed."""
+        pool, self._buffer = self._buffer, None
+        if pool is None or any(getattr(l, "is_deleted", lambda: False)()
+                               for l in jax.tree.leaves(pool)):
+            pool = self._module.init_paged_cache(self.num_pages, self.page,
                                                  dtype=dtype)
+        self.reset()
         return pool
 
     def give_back(self, pool):
-        self._pool = pool
+        self._buffer = pool
 
-    def release(self):
-        self._pool = None
-        self._key = None
+    def drop_buffer(self):
+        """Forget the held buffer (the server is retiring)."""
+        self._buffer = None
+
+    # ---- slots ----
+    def cannot_hold(self, positions):
+        """Why the pool can NEVER back a request of ``positions`` cache
+        positions, or ``None`` when it can: such a request must not
+        enter the queue — with every other slot drained it would still
+        stall admission forever."""
+        n = pages_for(positions, self.page)
+        if n <= self._pool.allocatable:
+            return None
+        return (f"{n} pages ({positions} positions at page_size="
+                f"{self.page}) but the pool holds "
+                f"{self._pool.allocatable} allocatable pages "
+                f"(num_pages={self.num_pages} incl. trash)")
+
+    def reserve(self, slot, fill, max_new):
+        """Back ``slot`` for a request that prefills ``fill`` tokens and
+        then decodes ``max_new``: map the longest indexed prefix (full
+        pages, refcounted — prefilled ONCE per unique prefix) and
+        allocate private pages for the rest of the virtual lane.
+        Returns ``(row pages, prefill start)`` — prefill runs from the
+        shared boundary on — or ``None``, with nothing allocated, when
+        the pool cannot back the request yet even after evicting
+        unreferenced prefix pages."""
+        P, page, chunk, pool = len(fill), self.page, self.chunk, self._pool
+        matched = []
+        if self.share_prefixes:
+            # cap the match so the block holding the LAST prompt position
+            # is always recomputed: admission samples the first token
+            # from that position's logits, so at least one chunk must run
+            matched = self._prefix.lookup(fill, page, pool, (P - 1) // page)
+        m = len(matched)
+        # the prefill start must be CHUNK-aligned, not just page-aligned:
+        # chunk ci writes the full padded span [s0+ci*C, s0+(ci+1)*C),
+        # and only a chunk-aligned s0 keeps the padded end at
+        # ceil(P/C)*C — the bound submit() already checked against the
+        # lane.  A page-aligned-only start can pad PAST the table row
+        # (page 16, chunk 64, P=120, m=7: 112+64=176 > 8-page lane)
+        g = chunk // math.gcd(page, chunk)
+        if m % g:
+            for pg in matched[(m // g) * g:]:
+                pool.decref(pg)
+            matched = matched[:(m // g) * g]
+            m = len(matched)
+        s0 = m * page                    # prefill start
+        n_chunks = -(-(P - s0) // chunk)
+        # the slot's virtual extent: decode writes through P+max_new-1,
+        # the padded last chunk writes through s0+n_chunks*C-1
+        virt = max(P + max_new, s0 + n_chunks * chunk)
+        need_private = pages_for(virt, page) - m
+        got = pool.alloc(need_private)
+        if got is None and self.share_prefixes:
+            self._stats["page_evictions"] += self._prefix.evict(
+                pool, need_private - pool.free_count)
+            got = pool.alloc(need_private)
+        if got is None:
+            for pg in matched:
+                pool.decref(pg)
+            return None
+        if self.share_prefixes:
+            # stats count ADMISSIONS, not stalled retries of the same
+            # request (a 50-step stall must not record 50 lookups/hits)
+            self._stats["prefix_lookups"] += 1
+            if matched:
+                self._stats["prefix_hits"] += 1
+                self._stats["prefix_tokens_reused"] += s0
+        row = matched + got
+        self._rows[int(slot)] = row
+        self._table[slot, :] = TRASH_PAGE
+        self._table[slot, :len(row)] = row
+        return row, s0
+
+    def share(self, slot, fill):
+        """Index ``slot``'s full pages of ``fill`` as sharable — its
+        prefill writes are complete (dispatched before the admit) and
+        nothing ever writes them again (the slot's own writes land at
+        positions >= ``len(fill)``)."""
+        if self.share_prefixes:
+            self._prefix.register(fill, self.page, self._rows[int(slot)],
+                                  self._pool, len(fill) // self.page)
+
+    def release(self, slot):
+        """Return a retired slot's pages to the pool (shared prefix
+        pages just drop one reference) and point its table row at the
+        trash page — the NEXT dispatch's table redirects the zombie
+        lane's masked writes there, so a freed page can be reallocated
+        immediately (any write the zombie already has in flight executes
+        in device order BEFORE the new occupant's prefill and is either
+        overwritten or masked — docs/serving.md "KV cache")."""
+        for pg in self._rows.pop(int(slot), ()):
+            self._pool.decref(pg)
+        self._table[int(slot), :] = TRASH_PAGE
+
+    def reset(self):
+        """Drop EVERY mapping (pool bookkeeping, prefix index, all table
+        rows) — the pool buffer died with a failed dispatch or was just
+        (re)allocated, so no indexed content survives."""
+        self._prefix.clear(self._pool)
+        self._pool.reset()
+        self._table[:] = TRASH_PAGE
+        self._rows.clear()
+
+    # ---- what the dispatches ship ----
+    def table(self):
+        """``[num_slots, pages_per_slot]`` int32, every slot's row."""
+        return self._table
+
+    def row(self, slot):
+        """``[1, pages_per_slot]`` int32, one slot's row."""
+        return self._table[slot:slot + 1]
+
+    # ---- observability ----
+    @property
+    def in_use(self):
+        return self._pool.in_use
+
+    @property
+    def utilization(self):
+        """Allocated fraction of the pool."""
+        return self._pool.utilization()
+
+    def describe(self):
+        return (f"page pool: {self._pool.in_use}/{self._pool.allocatable} "
+                f"in use, {len(self._prefix)} prefix entries")
+
+    def slot_pages_str(self, slot):
+        """``slot``'s pages, range-compressed (:func:`compact_page_str`)
+        — ``None`` for a slot that holds none."""
+        row = self._rows.get(int(slot))
+        return None if row is None else compact_page_str(row)
+
+    def chunk_reach(self, layers, end):
+        """What a prefill chunk writing through position ``end - 1``
+        attends, as its dispatch span's args: ``kv_pages`` — the pages
+        its layers fetch, every page of the slot's table up to the
+        chunk's furthest position, which is the paged chunk-prefill
+        kernel's block loop — and ``kv_pages_table``, pages a slot x
+        layers, what a walk over the whole table would take."""
+        reach = pages_for(end, self.page)
+        return {"kv_pages": layers * min(reach, self.pages_per_slot),
+                "kv_pages_table": layers * self.pages_per_slot}
+
+    def block_reach(self, live, block):
+        """What a decode block of ``block`` steps walks, as its dispatch
+        span's args, from ``live`` — ``(context, steps)`` per live slot,
+        the positions its first step attends and the steps it takes:
+        ``kv_pages``, ``ceil(context / page_size)`` a live slot and
+        step, which is the paged-decode kernel's page loop, and
+        ``kv_pages_table``, the slots x pages-a-slot x steps a walk over
+        the whole table would take — their ratio is the share of the
+        table that is live."""
+        return {"kv_pages": sum(pages_for(first + i, self.page)
+                                for first, steps in live
+                                for i in range(steps)),
+                "kv_pages_table":
+                    self.num_slots * self.pages_per_slot * block}

@@ -1,30 +1,37 @@
-"""Slot-lane programs for the continuous-batching serving engine.
+"""Slot programs for the continuous-batching serving engine.
 
-The fixed-shape contract (``docs/serving.md``): the KV workspace holds
-``num_slots`` cache lanes ``[L, num_slots, cache_len, KVH*D]`` and every
-piece of per-slot occupancy state (last token, write position, live flag,
-steps remaining, eos id) is a TRACED argument — so admissions, EOS
-retirements and request churn never change a program shape, and exactly ONE
-decode-step executable serves the whole server lifetime (compiled once per
-process — the serving programs bypass the persistent caches, see
-``ServingEngine.__init__``).
+The fixed-shape contract (``docs/serving.md``): the KV workspace is one
+page POOL ``[L, num_pages, page_size, KVH*D]`` shared by all slots; the
+per-slot page tables (``[num_slots, pages_per_slot]`` int32 — the host
+allocates, frees and shares pages, ``paging.py``) and every piece of
+per-slot occupancy state (last token, write position, live flag, steps
+remaining, eos id) are TRACED arguments — so admissions, EOS
+retirements, request and page churn never change a program shape, and
+exactly ONE decode-step executable serves the whole server lifetime
+(compiled once per process — the serving programs bypass the persistent
+caches, see ``ServingEngine.__init__``).
 
-Two programs:
+The programs:
 
 * :func:`make_decode_block_fn` — the decode step.  One call advances every
   slot ``block`` tokens through the model's per-row decode path (rank-1
-  ``start_pos`` selects the scatter cache write and the per-row length
-  masks; free/retired lanes write masked garbage that the next occupant
-  overwrites position-by-position before ever attending to it).  The cache
-  AND the slot state are donated — the workspace updates in place.
-* :func:`make_admit_fn` — admission, fused into one dispatch: sample the
-  first token from the prefill's last-position logits (the SAME sampling
-  rule the decode step uses, ``build_sample_fn`` — keeping serving
-  outputs bitwise equal to solo ``generate()`` runs under greedy
-  decoding), insert the prefilled single-lane cache into the slot's lane
-  (``dynamic_update_slice`` over the traced slot index; cache donated),
-  and write the slot's state entries in-program — so the host scheduler
-  never synchronizes inside the admission path.
+  ``start_pos`` selects the per-row cache write and length masks, both
+  routed through the page table; free/retired lanes write masked garbage
+  to the trash page).  The pool AND the slot state are donated — the
+  workspace updates in place.
+* :func:`make_chunk_fn` — one admission-prefill chunk, written straight
+  into the slot's pool pages through its table row (pool donated).
+* :func:`make_admit_fn` — admission, one dispatch: sample the first token
+  from the prefill's last-position logits (the SAME sampling rule the
+  decode step uses, ``build_sample_fn`` — keeping serving outputs bitwise
+  equal to solo ``generate()`` runs under greedy decoding) and write the
+  slot's state entries in-program — so the host scheduler never
+  synchronizes inside the admission path.
+* :func:`make_spec_verify_fn` and the draft side's three
+  (:func:`make_draft_propose_fn`, :func:`make_draft_chunk_fn`,
+  :func:`make_draft_admit_fn`) — speculative decoding; the DRAFT model
+  keeps a monolithic cache ``[L_d, num_slots, cache_len, KVH*D]`` and
+  single-lane prefill caches of its own.
 
 Per-step semantics mirror ``make_generate_fn``'s decode loop exactly
 (write K/V at ``pos``, sample from the new logits, emit ``eos`` once done,
@@ -58,7 +65,7 @@ def init_slot_state(num_slots):
 def routes_experts(module):
     """True for a model with DROPLESS expert layers
     (``TransformerConfig.moe_capacity_factor=None``, ``moe/dropless.py``)
-    — the models whose paged programs mask dead tokens out of the routing
+    — the models whose slot programs mask dead tokens out of the routing
     and return the expert load (docs/serving.md "Expert models")."""
     cfg = getattr(module, "config", None)
     return getattr(cfg, "moe_num_experts", 0) > 0 \
@@ -66,7 +73,7 @@ def routes_experts(module):
 
 
 def _decode(module, variables, ids, cache, pos, live=None, **kw):
-    """``module.decode`` as the paged programs call it: ``(logits, cache,
+    """``module.decode`` as the slot programs call it: ``(logits, cache,
     counts)``.  Dense model (``live`` None): the plain call, ``counts``
     None.  Expert model: only ``live [B, S]`` tokens are routed, and
     ``counts [expert layers, experts]`` int32 are the (token, expert)
@@ -87,7 +94,7 @@ def _decode(module, variables, ids, cache, pos, live=None, **kw):
 
 
 def _expert_load(counts):
-    """The load summary a paged program of an expert model returns beside
+    """The load summary a slot program of an expert model returns beside
     its other outputs, from ``counts [calls, expert layers, experts]`` —
     ONE int32 vector (one device read for the scheduler):
     ``expert_tokens [layers x experts]`` (assignments summed over the
@@ -103,127 +110,22 @@ def _expert_load(counts):
 def make_decode_block_fn(module, sample_fn, param_transform, block,
                          cache_len):
     """The single reusable decode-step program:
-    ``fn(params, cache, state, rng) -> (tokens [block, N], cache, state)``
-    with the cache and slot state donated (argnums 1, 2).
-
-    Each of the ``block`` in-program steps writes every slot's pending
-    token at its own ``pos`` (per-row scatter write + per-row length
-    mask), samples the next token, emits the slot's ``eos`` for lanes that
-    already finished, and flips ``active`` off when a lane emits its eos
-    or exhausts ``remaining`` — identical math to ``make_generate_fn``'s
-    loop body, so greedy serving tokens match solo ``generate()`` bitwise.
-    Retired/free lanes keep decoding as masked no-ops for at most
-    ``block - 1`` steps until the host scheduler reclaims them; their
-    writes land at a clamped ``pos`` and are overwritten by the next
-    occupant before any of its queries can attend to them.
-    """
-    deq = param_transform if param_transform is not None else (lambda p: p)
-
-    @hot_path("serving.decode_step")
-    def decode_block(params, cache, state, rng):
-        eos = state["eos"]
-
-        def step(carry, _):
-            cache, tok, pos, active, remaining, rng = carry
-            logits, cache = module.apply(deq(params), tok[:, None], cache,
-                                         pos, method=type(module).decode)
-            rng, sub = jax.random.split(rng)
-            nxt = sample_fn(logits[:, -1], sub).astype(jnp.int32)
-            nxt = jnp.where(active, nxt, eos)
-            done_now = active & ((nxt == eos) | (remaining <= 1))
-            active = active & jnp.logical_not(done_now)
-            # clamp: identity for live lanes (submit() bounds
-            # prompt+max_new by cache_len); keeps dead lanes' masked
-            # no-op writes inside the buffer forever
-            pos = jnp.minimum(pos + 1, cache_len - 1)
-            remaining = jnp.maximum(remaining - 1, 0)
-            return (cache, nxt, pos, active, remaining, rng), nxt
-
-        (cache, tok, pos, active, remaining, _), toks = jax.lax.scan(
-            step, (cache, state["token"], state["pos"], state["active"],
-                   state["remaining"], rng), None, length=block)
-        new_state = {"token": tok, "pos": pos, "active": active,
-                     "remaining": remaining, "eos": eos}
-        return toks, cache, new_state
-
-    return jax.jit(decode_block, donate_argnums=(1, 2))
-
-
-def make_admit_fn(sample_fn):
-    """The fused admission program:
-    ``fn(cache, state, lane, logits, rng, slot, pos0, max_new, eos)
-    -> (cache, state, first_token)`` with the cache and slot state
-    donated (argnums 0, 1).
-
-    One dispatch does everything an admission needs ON DEVICE: sample the
-    first token from the prefill's last-position logits (same fp32 rule
-    as the decode step — ``build_sample_fn`` — so greedy admission tokens
-    match solo runs bitwise), write the [L, 1, S, ...] prefilled lane into
-    slot ``slot`` of the big cache (``dynamic_update_slice`` over the
-    traced slot index), and flip the slot's state entries live — inactive
-    when the request already finished at admission (first token == eos,
-    or ``max_new == 1``).  Because the state write happens in-program,
-    the host scheduler never has to synchronize on the first token before
-    the next decode block can be dispatched: it reads ``first_token``
-    lazily, one block behind (see ``ServingEngine``)."""
-
-    @hot_path("serving.admit")
-    def admit(cache, state, lane, logits, rng, slot, pos0, max_new, eos):
-        first = sample_fn(logits[:, 0], rng).astype(jnp.int32)[0]
-
-        def ins(buf, lbuf):
-            return jax.lax.dynamic_update_slice(
-                buf, lbuf.astype(buf.dtype), (0, slot, 0, 0))
-
-        cache = {k: ins(cache[k], lane[k]) for k in cache}
-        # finished-at-admission: eos on the first token (eos=-1 never
-        # matches: sampled ids are >= 0), or a 1-token request
-        active0 = (max_new > 1) & jnp.logical_not(first == eos)
-        upd = lambda arr, val: arr.at[slot].set(val)
-        state = {"token": upd(state["token"], first),
-                 "pos": upd(state["pos"], pos0),
-                 "active": upd(state["active"], active0),
-                 "remaining": upd(state["remaining"],
-                                  jnp.maximum(max_new - 1, 0)),
-                 "eos": upd(state["eos"], eos)}
-        return cache, state, first
-
-    return jax.jit(admit, donate_argnums=(0, 1))
-
-
-# --------------------------------------------------------------------- #
-# Paged variants (docs/serving.md "Paged KV cache"): the KV workspace is
-# a page POOL [L, num_pages, page_size, KVH*D] shared by all slots, and
-# the per-slot page tables ([num_slots, pages_per_slot] int32) arrive as
-# a TRACED argument on every dispatch — the host allocates/frees/shares
-# pages, the programs' shapes never change.  Prefill writes land in the
-# pool directly (make_paged_chunk_fn), so the paged admit has no lane to
-# insert: it only samples the first token and flips the slot state.
-# --------------------------------------------------------------------- #
-
-def _paged_kernel_marker(paged_kernel):
-    """Cache-dict marker for ``serving.paged_kernel=False``: its PRESENCE
-    (static pytree structure) routes the attention-kernel registry back to
-    the pre-kernel ``take_along_axis`` gather path — A/B benching the
-    paged Pallas kernels without a code change.  Built INSIDE the traced
-    program so dispatch signatures (and donation) never change."""
-    if paged_kernel:
-        return {}
-    return {"paged_kernel_off": jnp.zeros((), jnp.int32)}
-
-
-def make_paged_decode_block_fn(module, sample_fn, param_transform, block,
-                               cache_len, paged_kernel=True):
-    """The paged decode step:
-    ``fn(params, cache, state, pages, rng) -> (tokens, cache, state)``
-    with the page POOL and the slot state donated (argnums 1, 2) and the
-    page table a plain traced input (tiny; rebuilt host-side per
+    ``fn(params, cache, state, pages, rng) -> (tokens [block, N], cache,
+    state)`` with the page POOL and the slot state donated (argnums 1, 2)
+    and the page table a plain traced input (tiny; rebuilt host-side per
     dispatch).  ``cache_len`` is the VIRTUAL lane length
     (pages_per_slot * page_size) — the dead-lane position clamp bound.
-    Per-step math is identical to :func:`make_decode_block_fn`; only the
-    cache write/read routes through the page table (see
-    ``models/transformer.py`` ``_paged_write``/``_paged_gather``), so
-    greedy paged serving stays bitwise equal to solo ``generate()``.
+
+    Each of the ``block`` in-program steps writes every slot's pending
+    token at its own ``pos`` (per-row write + per-row length mask, both
+    through the page table — ``models/transformer.py`` ``_paged_write``,
+    ``ops/transformer/registry.py``), samples the next token, emits the
+    slot's ``eos`` for lanes that already finished, and flips ``active``
+    off when a lane emits its eos or exhausts ``remaining`` — identical
+    math to ``make_generate_fn``'s loop body, so greedy serving tokens
+    match solo ``generate()`` bitwise.  Retired/free lanes keep decoding
+    as masked no-ops for at most ``block - 1`` steps until the host
+    scheduler reclaims them.
 
     For a model with dropless expert layers (:func:`routes_experts`) a
     lane that is not ``active`` at a step is routed to no expert — it
@@ -232,7 +134,7 @@ def make_paged_decode_block_fn(module, sample_fn, param_transform, block,
     deq = param_transform if param_transform is not None else (lambda p: p)
     routed = routes_experts(module)
 
-    @hot_path("serving.decode_step_paged")
+    @hot_path("serving.decode_step")
     def decode_block(params, cache, state, pages, rng):
         eos = state["eos"]
 
@@ -241,16 +143,14 @@ def make_paged_decode_block_fn(module, sample_fn, param_transform, block,
             # inactive lanes decode as masked no-ops but still WRITE a
             # k/v row each step — point their whole table row at the
             # trash page so the write can never land in pages the host
-            # already handed to a newer occupant.  (The monolithic path
-            # tolerates those writes because the next admit re-inserts
-            # the whole lane; paged prefill writes the pool directly
-            # BEFORE the admit flips `active`, so an unmasked free-lane
-            # write here would corrupt a freshly prefilled prompt.)
+            # already handed to a newer occupant.  (Prefill writes the
+            # pool directly BEFORE the admit flips `active`, so an
+            # unmasked free-lane write here would corrupt a freshly
+            # prefilled prompt.)
             safe_pages = jnp.where(active[:, None], pages, 0)
             logits, cache, counts = _decode(
                 module, deq(params), tok[:, None],
-                {**cache, "pages": safe_pages,
-                 **_paged_kernel_marker(paged_kernel)},
+                {**cache, "pages": safe_pages},
                 pos, live=active[:, None] if routed else None)
             rng, sub = jax.random.split(rng)
             nxt = sample_fn(logits[:, -1], sub).astype(jnp.int32)
@@ -276,14 +176,14 @@ def make_paged_decode_block_fn(module, sample_fn, param_transform, block,
     return jax.jit(decode_block, donate_argnums=(1, 2))
 
 
-def make_paged_chunk_fn(module, param_transform, paged_kernel=True):
-    """The paged admission-prefill chunk program:
+def make_chunk_fn(module, param_transform):
+    """The admission-prefill chunk program:
     ``fn(params, cache, pages, chunk_ids, start, logits_at)`` — same
-    body as the engine's per-chunk program but writing straight into the
-    slot's pool pages through its ``[1, pages_per_slot]`` table row (no
-    single-lane staging cache, no admit-time insert).  The POOL is
-    donated (argnum 1); the table row is a separate traced input so the
-    donation aliases cleanly.
+    body as the engine's per-chunk program (``generate()``'s split
+    prefill) but writing straight into the slot's pool pages through its
+    ``[1, pages_per_slot]`` table row (no single-lane staging cache, no
+    admit-time insert).  The POOL is donated (argnum 1); the table row
+    is a separate traced input so the donation aliases cleanly.
 
     ``logits_at`` is the chunk's LAST REAL row (the scheduler passes
     ``chunk - 1`` for a whole chunk and the prompt's last token for the
@@ -294,14 +194,14 @@ def make_paged_chunk_fn(module, param_transform, paged_kernel=True):
     deq = param_transform if param_transform is not None else (lambda p: p)
     routed = routes_experts(module)
 
-    @hot_path("serving.prefill_chunk_paged")
+    @hot_path("serving.prefill_chunk")
     def chunk_step(params, cache, pages, chunk_ids, start, logits_at):
         live = jnp.arange(chunk_ids.shape[1])[None, :] \
             <= logits_at[:, None] if routed else None
         logits, cache, counts = _decode(
             module, deq(params), chunk_ids,
-            {**cache, "pages": pages, **_paged_kernel_marker(paged_kernel)},
-            start, live=live, logits_at=logits_at)
+            {**cache, "pages": pages}, start, live=live,
+            logits_at=logits_at)
         if routed:
             return logits, cache, _expert_load(counts[None])
         return logits, cache
@@ -313,7 +213,7 @@ def make_paged_chunk_fn(module, param_transform, paged_kernel=True):
 # Speculative decoding (docs/serving.md "Speculative decoding"): a small
 # DRAFT model proposes k tokens per live slot, the target model verifies
 # all of them in ONE batched forward, and the accepted prefix advances
-# both KV caches through the existing per-row scatter writes.  Fixed k,
+# both KV caches through the existing per-row writes.  Fixed k,
 # accept math entirely in-program, the accept-mask and per-slot accepted
 # length as traced values riding the donated slot state — so exactly one
 # draft-propose program and one verify-and-commit program serve the whole
@@ -324,8 +224,7 @@ def make_paged_chunk_fn(module, param_transform, paged_kernel=True):
 # --------------------------------------------------------------------- #
 
 def _spec_commit(t, draft, state, k, cache_len):
-    """The in-program accept-and-commit rule shared by the monolithic and
-    paged verify programs.
+    """The verify program's in-program accept-and-commit rule.
 
     ``t`` ``[N, k+1]``: the target's sampled token at every window
     position (``t[:, i]`` is sampled from the logits AFTER feeding
@@ -414,55 +313,31 @@ def make_draft_propose_fn(draft_module, param_transform, k, cache_len):
 
 def make_spec_verify_fn(module, sample_fn, param_transform, k, cache_len):
     """The verify-and-commit program:
-    ``fn(params, cache, state, draft, rng) -> (tokens [k+1, N],
-    accepted [N], cache, state)`` with the TARGET cache and the slot
-    state donated (argnums 1, 2).
+    ``fn(params, cache, state, pages, draft, rng) -> (tokens [k+1, N],
+    accepted [N], cache, state)`` with the TARGET pool and the slot
+    state donated (argnums 1, 2), the per-slot page tables a plain
+    traced input.
 
     ONE batched target forward over ``[token, d_1..d_k]`` per slot
     (per-row start positions — the cache write is the per-row
-    MULTI-token scatter, ``models/transformer.py``), then the shared
+    MULTI-token scatter through the page table), then the
     :func:`_spec_commit` accept rule.  Every committed token is the
     target's ``sample_fn`` output over exactly the committed history
     (the accepted drafts match it position by position), which is the
     bitwise-greedy contract; K/V written for rejected window positions
     is overwritten position-by-position by later windows before any
     query can attend it — the same argument chunked prefill's padded
-    tail already relies on."""
+    tail already relies on.  Like the decode step, inactive lanes' whole
+    table row redirects to the trash page so their window writes can
+    never land in pages the host already handed to a newer occupant."""
     deq = param_transform if param_transform is not None else (lambda p: p)
 
     @hot_path("serving.spec_verify")
-    def verify(params, cache, state, draft, rng):
-        ids = jnp.concatenate([state["token"][:, None], draft], axis=1)
-        logits, cache = module.apply(deq(params), ids, cache,
-                                     state["pos"],
-                                     method=type(module).decode)
-        rngs = jax.random.split(rng, k + 1)
-        t = jnp.stack([sample_fn(logits[:, i], rngs[i]).astype(jnp.int32)
-                       for i in range(k + 1)], axis=1)
-        toks, accepted, new_state = _spec_commit(t, draft, state, k,
-                                                 cache_len)
-        return toks, accepted, cache, new_state
-
-    return jax.jit(verify, donate_argnums=(1, 2))
-
-
-def make_paged_spec_verify_fn(module, sample_fn, param_transform, k,
-                              cache_len, paged_kernel=True):
-    """The PAGED verify-and-commit program: pool + slot state donated
-    (argnums 1, 2), the per-slot page tables a plain traced input.  Same
-    accept math as :func:`make_spec_verify_fn`; like the paged decode
-    step, inactive lanes' whole table row redirects to the trash page so
-    their window writes can never land in pages the host already handed
-    to a newer occupant."""
-    deq = param_transform if param_transform is not None else (lambda p: p)
-
-    @hot_path("serving.spec_verify_paged")
     def verify(params, cache, state, pages, draft, rng):
         safe_pages = jnp.where(state["active"][:, None], pages, 0)
         ids = jnp.concatenate([state["token"][:, None], draft], axis=1)
         logits, cache = module.apply(deq(params), ids,
-                                     {**cache, "pages": safe_pages,
-                                      **_paged_kernel_marker(paged_kernel)},
+                                     {**cache, "pages": safe_pages},
                                      state["pos"],
                                      method=type(module).decode)
         rngs = jax.random.split(rng, k + 1)
@@ -510,17 +385,24 @@ def make_draft_admit_fn():
     return jax.jit(admit, donate_argnums=(0,))
 
 
-def make_paged_admit_fn(sample_fn):
-    """The paged admission program:
+def make_admit_fn(sample_fn):
+    """The admission program:
     ``fn(state, logits, rng, slot, pos0, max_new, eos) -> (state,
     first_token)`` with the slot state donated (argnum 0).  The prefill
     already wrote the prompt's K/V into the slot's pages, so admission
     is just the first-token sample (same ``build_sample_fn`` rule — the
-    bitwise contract) plus the in-program slot-state write."""
+    bitwise contract) plus the in-program slot-state write — inactive
+    when the request already finished at admission (first token == eos,
+    or ``max_new == 1``).  Because the state write happens in-program,
+    the host scheduler never has to synchronize on the first token
+    before the next decode block can be dispatched: it reads
+    ``first_token`` lazily, one block behind (see ``ServingEngine``)."""
 
-    @hot_path("serving.admit_paged")
+    @hot_path("serving.admit")
     def admit(state, logits, rng, slot, pos0, max_new, eos):
         first = sample_fn(logits[:, 0], rng).astype(jnp.int32)[0]
+        # finished-at-admission: eos on the first token (eos=-1 never
+        # matches: sampled ids are >= 0), or a 1-token request
         active0 = (max_new > 1) & jnp.logical_not(first == eos)
         upd = lambda arr, val: arr.at[slot].set(val)
         state = {"token": upd(state["token"], first),

@@ -939,12 +939,6 @@ class Transformer(nn.Module):
             # paged pool: the per-row page table threads every layer's
             # cache dict unchanged (pages are constant across layers)
             marker["pages"] = cache["pages"]
-            if "paged_kernel_off" in cache:
-                # serving.paged_kernel=False: the registry routes paged
-                # attention back to the gather path.  STATIC pytree
-                # structure (like per_row) — flipping the knob is a
-                # different program, never a retrace surprise
-                marker["paged_kernel_off"] = cache["paged_kernel_off"]
         # from-zero multi-token prefill, decided where the start is
         # still STATICALLY visible (generation passes a literal 0;
         # inside the remat-wrapped block `positions` is a tracer):

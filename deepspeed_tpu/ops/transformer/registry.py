@@ -40,11 +40,9 @@ Modes (the ``KERNEL_MODES`` table, probed in order, first hit wins):
                             (``stats["paged_attention_fallback"]``)
 ==========================  ==================================================
 
-A paged cache opts out of the Pallas paged kernels (back to the gather
-path, e.g. for A/B benching) via ``ServingConfig.paged_kernel=False``,
-which rides the cache dict as a ``paged_kernel_off`` marker — STATIC
-pytree structure, so flipping it is a different program, never a retrace
-surprise.
+The gather path is also the paged kernels' bitwise reference in the
+tests: ``DSTPU_DISABLE_FLASH=1`` (``pallas_supported()`` false) routes
+every call to it.
 """
 
 import jax.numpy as jnp
@@ -56,10 +54,6 @@ from deepspeed_tpu.utils.logging import warning_once
 # longer blocks would blow VMEM and keep the dense fallback
 MAX_CHUNK_S = 512
 
-# marker key on a cache dict: paged Pallas kernels disabled
-# (ServingConfig.paged_kernel=False) — presence only, value unused
-PAGED_KERNEL_OFF = "paged_kernel_off"
-
 KERNEL_MODES = (
     "pallas_paged_decode",
     "pallas_decode",
@@ -68,21 +62,21 @@ KERNEL_MODES = (
 )
 
 
-def _probe_paged_decode(s, paged, has_bias, has_window, disabled):
+def _probe_paged_decode(s, paged, has_bias, has_window):
     # the paged kernel has no sliding-window mode: windowed paged decode
     # keeps the gather path (whose monolithic kernel masks the window)
     return (paged and s == 1 and not has_bias and not has_window
-            and not disabled and pallas_supported())
+            and pallas_supported())
 
 
-def _probe_decode(s, paged, has_bias, has_window, disabled):
+def _probe_decode(s, paged, has_bias, has_window):
     # monolithic decode masks sliding windows in-kernel
     return (not paged and s == 1 and not has_bias and pallas_supported())
 
 
-def _probe_chunk(s, paged, has_bias, has_window, disabled):
+def _probe_chunk(s, paged, has_bias, has_window):
     return (1 < s <= MAX_CHUNK_S and not has_bias and not has_window
-            and not (paged and disabled) and pallas_supported())
+            and pallas_supported())
 
 
 _REGISTRY = (
@@ -92,40 +86,35 @@ _REGISTRY = (
 )
 
 
-def select_kernel(*, s, paged=False, has_bias=False, has_window=False,
-                  disabled=False):
+def select_kernel(*, s, paged=False, has_bias=False, has_window=False):
     """The attention-kernel dispatch decision for one cached-attention
     call.  All inputs are static: ``s`` (this block's token count),
     ``paged`` (block-table pool vs monolithic lanes), ``has_bias``
-    (alibi), ``has_window`` (sliding-window layer) and ``disabled``
-    (the cache's ``paged_kernel_off`` marker).  Returns a
+    (alibi) and ``has_window`` (sliding-window layer).  Returns a
     :data:`KERNEL_MODES` name; ``reference_fallback`` when no Pallas
     kernel applies."""
     for mode, probe in _REGISTRY:
-        if probe(s, paged, has_bias, has_window, disabled):
+        if probe(s, paged, has_bias, has_window):
             return mode
     return "reference_fallback"
 
 
-def kernel_modes(*, paged, disabled=False, has_bias=False,
-                 has_window=False):
+def kernel_modes(*, paged, has_bias=False, has_window=False):
     """Host-side attribution of which kernel mode each serving program
     class will take (what ``prefill_plan`` reasons and bench records
     report).  Probes the same table the traced programs dispatch
     through, so the attribution cannot drift from reality."""
     return {
         "decode": select_kernel(s=1, paged=paged, has_bias=has_bias,
-                                has_window=has_window, disabled=disabled),
+                                has_window=has_window),
         "prefill_chunk": select_kernel(s=2, paged=paged, has_bias=has_bias,
-                                       has_window=has_window,
-                                       disabled=disabled),
+                                       has_window=has_window),
     }
 
 
 def _cache_markers(cache):
     """The bookkeeping keys a write must thread through unchanged."""
-    return {kk: cache[kk]
-            for kk in ("layer", "pages", "per_row", PAGED_KERNEL_OFF)
+    return {kk: cache[kk] for kk in ("layer", "pages", "per_row")
             if kk in cache}
 
 
@@ -288,7 +277,7 @@ def _attend(cfg, mode, q, cache, positions, bias, window):
         if q.shape[1] == 1:
             warning_once(
                 "paged decode fell back to the take_along_axis gather "
-                "path (" + _fallback_reason(cfg, bias, window, cache)
+                "path (" + _fallback_reason(bias, window)
                 + ") — expect the BENCH_r04 bs128 decode cliff; see "
                 "docs/serving.md 'Paged attention kernels'")
         g = _paged_gather(cache)
@@ -304,9 +293,7 @@ def _attend(cfg, mode, q, cache, positions, bias, window):
         int8_matmuls=cfg.decode_int8_matmuls)
 
 
-def _fallback_reason(cfg, bias, window, cache):
-    if PAGED_KERNEL_OFF in cache:
-        return "serving.paged_kernel=False"
+def _fallback_reason(bias, window):
     if bias is not None:
         return "alibi bias"
     if window is not None:
@@ -333,10 +320,9 @@ def write_and_attend(cfg, q, k, v, positions, cache, *, bias=None,
     B_, S_ = k.shape[0], k.shape[1]
     KVHD = k.shape[-2] * k.shape[-1]
     paged = "pages" in cache
-    disabled = PAGED_KERNEL_OFF in cache
     prefill_from_zero = bool(prefill) and S_ > 1 and bias is None
     mode = select_kernel(s=S_, paged=paged, has_bias=bias is not None,
-                         has_window=window is not None, disabled=disabled)
+                         has_window=window is not None)
     if not prefill_from_zero:
         fused = _fused_decode(cfg, q, k, v, positions, cache, mode, window)
         if fused is not None:

@@ -146,68 +146,7 @@ def inference_prefill_chunk():
                       expect_donation=True)
 
 
-def serving_decode_step():
-    """The serving loop's single reusable decode-step program
-    (``inference/serving/slots.py``): cache AND slot-state donated — the
-    whole continuous-batching design rests on this one executable updating
-    the slot workspace in place with no host callbacks."""
-    from deepspeed_tpu.inference.engine import build_sample_fn
-    from deepspeed_tpu.inference.serving.slots import make_decode_block_fn
-    engine = _tiny_inference_engine()
-    N, S = 2, 32
-    fn = make_decode_block_fn(engine.module,
-                              build_sample_fn(False, 1.0, 0, 1.0),
-                              None, 2, S)
-    cache = engine.module.init_cache(N, S, dtype=engine.compute_dtype)
-    state = {"token": jnp.zeros((N,), jnp.int32),
-             "pos": jnp.asarray([8, 3], jnp.int32),
-             "active": jnp.asarray([True, False]),
-             "remaining": jnp.asarray([4, 0], jnp.int32),
-             "eos": jnp.asarray([-1, -1], jnp.int32)}
-    args = (engine._params, cache, state, jax.random.key(0))
-    return EntryPoint("serving.decode_step", fn, args, expect_donation=True)
-
-
-def serving_admission_prefill():
-    """The serving admission prefill — the donated per-chunk program at
-    lane width B=1, replayed for every admitted prompt (the serving
-    engine holds a dedicated instance of this program; same body)."""
-    engine = _tiny_inference_engine()
-    C = 8
-    chunk_fn = engine._make_chunk_fn()
-    lane = engine.module.init_cache(1, 32, dtype=engine.compute_dtype)
-    ids = jnp.asarray(np.random.default_rng(3).integers(0, 97, (1, C)),
-                      jnp.int32)
-    args = (engine._params, lane, ids, jnp.asarray(0, jnp.int32),
-            jnp.zeros((1,), jnp.int32))
-    return EntryPoint("serving.admission_prefill", chunk_fn, args,
-                      expect_donation=True)
-
-
-def serving_admit():
-    """The fused admission program (first-token sample + lane insert +
-    in-program slot-state write; slot index traced, cache AND slot state
-    donated)."""
-    from deepspeed_tpu.inference.engine import build_sample_fn
-    from deepspeed_tpu.inference.serving.slots import make_admit_fn
-    engine = _tiny_inference_engine()
-    fn = make_admit_fn(build_sample_fn(False, 1.0, 0, 1.0))
-    N, S = 2, 32
-    cache = engine.module.init_cache(N, S, dtype=engine.compute_dtype)
-    lane = engine.module.init_cache(1, S, dtype=engine.compute_dtype)
-    state = {"token": jnp.zeros((N,), jnp.int32),
-             "pos": jnp.zeros((N,), jnp.int32),
-             "active": jnp.zeros((N,), bool),
-             "remaining": jnp.zeros((N,), jnp.int32),
-             "eos": jnp.full((N,), -1, jnp.int32)}
-    logits = jnp.zeros((1, 1, 97), jnp.float32)
-    args = (cache, state, lane, logits, jax.random.key(0),
-            jnp.asarray(1, jnp.int32), jnp.asarray(8, jnp.int32),
-            jnp.asarray(4, jnp.int32), jnp.asarray(-1, jnp.int32))
-    return EntryPoint("serving.admit", fn, args, expect_donation=True)
-
-
-def _paged_state(N):
+def _slot_state(N):
     return {"token": jnp.zeros((N,), jnp.int32),
             "pos": jnp.asarray([8, 3], jnp.int32),
             "active": jnp.asarray([True, False]),
@@ -215,38 +154,38 @@ def _paged_state(N):
             "eos": jnp.full((N,), -1, jnp.int32)}
 
 
-def serving_decode_step_paged():
-    """The PAGED decode-step program (``serving.paged``): page pool +
-    slot state donated, the per-slot page tables a plain traced input —
-    the pool/state donations must alias (the whole paged design rests on
-    in-place pool updates) and the program must stay callback-free even
-    though every cache touch routes through a gather/scatter."""
+def serving_decode_step():
+    """The serving loop's single reusable decode-step program
+    (``inference/serving/slots.py``): page pool + slot state donated, the
+    per-slot page tables a plain traced input — the pool/state donations
+    must alias (the whole continuous-batching design rests on this one
+    executable updating the pool in place) and the program must stay
+    callback-free even though every cache touch routes through the page
+    table."""
     from deepspeed_tpu.inference.engine import build_sample_fn
-    from deepspeed_tpu.inference.serving.slots import \
-        make_paged_decode_block_fn
+    from deepspeed_tpu.inference.serving.slots import make_decode_block_fn
     engine = _tiny_inference_engine()
     N, NP, PG = 2, 9, 8                 # 9 pages of 8 (page 0 = trash)
-    fn = make_paged_decode_block_fn(engine.module,
-                                    build_sample_fn(False, 1.0, 0, 1.0),
-                                    None, 2, 4 * PG)
+    fn = make_decode_block_fn(engine.module,
+                              build_sample_fn(False, 1.0, 0, 1.0),
+                              None, 2, 4 * PG)
     pool = engine.module.init_paged_cache(NP, PG,
                                           dtype=engine.compute_dtype)
     pages = jnp.asarray([[3, 5, 2, 7], [1, 4, 0, 0]], jnp.int32)
-    args = (engine._params, pool, _paged_state(N), pages,
+    args = (engine._params, pool, _slot_state(N), pages,
             jax.random.key(0))
-    return EntryPoint("serving.decode_step_paged", fn, args,
-                      expect_donation=True)
+    return EntryPoint("serving.decode_step", fn, args, expect_donation=True)
 
 
-def serving_admission_prefill_paged():
-    """The PAGED admission-prefill chunk program: the pool is the
-    donated buffer (chunk writes land in the slot's pages directly —
-    no staging lane), the [1, pages_per_slot] table row a separate
-    traced input so the pool donation aliases cleanly."""
-    from deepspeed_tpu.inference.serving.slots import make_paged_chunk_fn
+def serving_prefill_chunk():
+    """The admission-prefill chunk program: the pool is the donated
+    buffer (chunk writes land in the slot's pages directly — no staging
+    lane), the [1, pages_per_slot] table row a separate traced input so
+    the pool donation aliases cleanly."""
+    from deepspeed_tpu.inference.serving.slots import make_chunk_fn
     engine = _tiny_inference_engine()
     C, NP, PG = 8, 9, 8
-    chunk_fn = make_paged_chunk_fn(engine.module, None)
+    chunk_fn = make_chunk_fn(engine.module, None)
     pool = engine.module.init_paged_cache(NP, PG,
                                           dtype=engine.compute_dtype)
     pages = jnp.asarray([[3, 5, 2, 7]], jnp.int32)
@@ -254,23 +193,22 @@ def serving_admission_prefill_paged():
                       jnp.int32)
     args = (engine._params, pool, pages, ids, jnp.asarray(0, jnp.int32),
             jnp.zeros((1,), jnp.int32))
-    return EntryPoint("serving.prefill_chunk_paged", chunk_fn, args,
+    return EntryPoint("serving.prefill_chunk", chunk_fn, args,
                       expect_donation=True)
 
 
-def serving_admit_paged():
-    """The PAGED admission program (first-token sample + in-program
-    slot-state write; no cache argument at all — prefill already wrote
-    the pages)."""
+def serving_admit():
+    """The admission program (first-token sample + in-program slot-state
+    write, slot index traced, slot state donated; no cache argument at
+    all — prefill already wrote the pages)."""
     from deepspeed_tpu.inference.engine import build_sample_fn
-    from deepspeed_tpu.inference.serving.slots import make_paged_admit_fn
-    fn = make_paged_admit_fn(build_sample_fn(False, 1.0, 0, 1.0))
+    from deepspeed_tpu.inference.serving.slots import make_admit_fn
+    fn = make_admit_fn(build_sample_fn(False, 1.0, 0, 1.0))
     logits = jnp.zeros((1, 1, 97), jnp.float32)
-    args = (_paged_state(2), logits, jax.random.key(0),
+    args = (_slot_state(2), logits, jax.random.key(0),
             jnp.asarray(1, jnp.int32), jnp.asarray(8, jnp.int32),
             jnp.asarray(4, jnp.int32), jnp.asarray(-1, jnp.int32))
-    return EntryPoint("serving.admit_paged", fn, args,
-                      expect_donation=True)
+    return EntryPoint("serving.admit", fn, args, expect_donation=True)
 
 
 def serving_spec_propose():
@@ -283,7 +221,7 @@ def serving_spec_propose():
     N, S, K = 2, 32, 2
     fn = make_draft_propose_fn(engine.module, None, K, S)
     dcache = engine.module.init_cache(N, S, dtype=engine.compute_dtype)
-    args = (engine._params, dcache, _paged_state(N))
+    args = (engine._params, dcache, _slot_state(N))
     return EntryPoint("serving.spec_propose", fn, args,
                       expect_donation=True)
 
@@ -291,46 +229,26 @@ def serving_spec_propose():
 def serving_spec_verify():
     """The speculative verify-and-commit program: ONE batched target
     forward over [token, drafts], in-program accept mask + per-slot
-    accepted length, per-row MULTI-token scatter cache writes — target
-    cache AND slot state donated, no host callbacks (the whole point is
-    committing up to k+1 tokens per dispatch without a sync)."""
+    accepted length — pool AND slot state donated, page tables traced,
+    no host callbacks (the whole point is committing up to k+1 tokens
+    per dispatch without a sync); inactive lanes' window writes redirect
+    to the trash page in-program, live lanes' per-row multi-token
+    scatter routes through the table."""
     from deepspeed_tpu.inference.engine import build_sample_fn
     from deepspeed_tpu.inference.serving.slots import make_spec_verify_fn
     engine = _tiny_inference_engine()
-    N, S, K = 2, 32, 2
+    N, NP, PG, K = 2, 9, 8, 2
     fn = make_spec_verify_fn(engine.module,
                              build_sample_fn(False, 1.0, 0, 1.0),
-                             None, K, S)
-    cache = engine.module.init_cache(N, S, dtype=engine.compute_dtype)
-    draft = jnp.asarray(np.random.default_rng(6).integers(0, 97, (N, K)),
-                        jnp.int32)
-    args = (engine._params, cache, _paged_state(N), draft,
-            jax.random.key(0))
-    return EntryPoint("serving.spec_verify", fn, args,
-                      expect_donation=True)
-
-
-def serving_spec_verify_paged():
-    """The PAGED speculative verify program: pool + slot state donated,
-    page tables traced; inactive lanes' window writes redirect to the
-    trash page in-program, live lanes' per-row multi-token scatter
-    routes through the table."""
-    from deepspeed_tpu.inference.engine import build_sample_fn
-    from deepspeed_tpu.inference.serving.slots import \
-        make_paged_spec_verify_fn
-    engine = _tiny_inference_engine()
-    N, NP, PG, K = 2, 9, 8, 2
-    fn = make_paged_spec_verify_fn(engine.module,
-                                   build_sample_fn(False, 1.0, 0, 1.0),
-                                   None, K, 4 * PG)
+                             None, K, 4 * PG)
     pool = engine.module.init_paged_cache(NP, PG,
                                           dtype=engine.compute_dtype)
     pages = jnp.asarray([[3, 5, 2, 7], [1, 4, 0, 0]], jnp.int32)
     draft = jnp.asarray(np.random.default_rng(7).integers(0, 97, (N, K)),
                         jnp.int32)
-    args = (engine._params, pool, _paged_state(N), pages, draft,
+    args = (engine._params, pool, _slot_state(N), pages, draft,
             jax.random.key(0))
-    return EntryPoint("serving.spec_verify_paged", fn, args,
+    return EntryPoint("serving.spec_verify", fn, args,
                       expect_donation=True)
 
 
@@ -402,17 +320,14 @@ def hybrid_rollout():
 
 BUILDERS = (runtime_train_step, runtime_apply_update, inference_decode,
             inference_prefill_chunk, serving_decode_step,
-            serving_admission_prefill, serving_admit,
-            serving_decode_step_paged, serving_admission_prefill_paged,
-            serving_admit_paged, serving_spec_propose,
-            serving_spec_verify, serving_spec_verify_paged,
-            serving_spec_draft_prefill, serving_spec_draft_admit,
-            hybrid_rollout)
+            serving_prefill_chunk, serving_admit, serving_spec_propose,
+            serving_spec_verify, serving_spec_draft_prefill,
+            serving_spec_draft_admit, hybrid_rollout)
 
 # builder function name -> the EntryPoint name it constructs.  Lets
 # name-filtered sweeps (``ds_lint --mem <program>``, the bench
 # memory_snapshot subset) skip the engine builds of filtered-out
-# programs instead of paying all 16 just to learn their names.  Kept
+# programs instead of paying all 12 just to learn their names.  Kept
 # honest mechanically: every consumer cross-checks ``ep.name`` against
 # this map after building, so drift fails loudly instead of silently
 # skipping the wrong program.
@@ -422,14 +337,10 @@ BUILDER_PROGRAMS = {
     "inference_decode": "inference.decode",
     "inference_prefill_chunk": "inference.prefill_chunk",
     "serving_decode_step": "serving.decode_step",
-    "serving_admission_prefill": "serving.admission_prefill",
+    "serving_prefill_chunk": "serving.prefill_chunk",
     "serving_admit": "serving.admit",
-    "serving_decode_step_paged": "serving.decode_step_paged",
-    "serving_admission_prefill_paged": "serving.prefill_chunk_paged",
-    "serving_admit_paged": "serving.admit_paged",
     "serving_spec_propose": "serving.spec_propose",
     "serving_spec_verify": "serving.spec_verify",
-    "serving_spec_verify_paged": "serving.spec_verify_paged",
     "serving_spec_draft_prefill": "serving.spec_draft_prefill",
     "serving_spec_draft_admit": "serving.spec_draft_admit",
     "hybrid_rollout": "hybrid.rollout",

@@ -90,11 +90,9 @@ _JAXLIB_0_9 = ("compiler, not program: XLA:CPU buffer assignment of jaxlib "
                "parent tree under the installed JAX")
 DECLARED_GROWTH = {name: _JAXLIB_0_9 for name in (
     "hybrid.rollout", "inference.decode", "inference.prefill_chunk",
-    "runtime.apply_update", "serving.admission_prefill",
-    "serving.decode_step", "serving.decode_step_paged",
-    "serving.prefill_chunk_paged", "serving.spec_draft_prefill",
-    "serving.spec_propose", "serving.spec_verify",
-    "serving.spec_verify_paged", "parallel.moe_ep")}
+    "runtime.apply_update", "serving.decode_step",
+    "serving.prefill_chunk", "serving.spec_draft_prefill",
+    "serving.spec_propose", "serving.spec_verify", "parallel.moe_ep")}
 
 
 # ------------------------------------------------------------------ #

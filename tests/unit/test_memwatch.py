@@ -150,8 +150,7 @@ def test_memory_telemetry_off_on_bitwise_zero_new_execs(shared_engine,
     assert srv.stats["memory_samples"] > 0
     assert srv.stats["hbm_owned_bytes"] > 0
     owners = srv.memory_snapshot()["owners"]
-    assert {"params", "kv_slots", "slot_state", "prefill_lanes"} \
-        <= set(owners)
+    assert set(owners) == {"params", "page_pool", "slot_state"}
     assert owners["params"] == tree_device_bytes(eng._params)
     # flight recorder carries the trajectory + a dump round-trips it
     snap = srv.flightrec_snapshot()
@@ -215,8 +214,7 @@ def test_metrics_round_trip_device_memory_gauges(shared_engine):
     assert limit[0]["source"] == "runtime" and limit[1] == 16000.0
     owned = {la["owner"]: v for la, v in
              by_name["dstpu_device_memory_owned_bytes"]}
-    assert {"params", "kv_slots", "slot_state", "prefill_lanes"} \
-        <= set(owned)
+    assert set(owned) == {"params", "page_pool", "slot_state"}
     # reconciliation holds inside one scrape: unattributed =
     # max(0, in_use - sum(owned))
     unattr = by_name["dstpu_device_memory_unattributed_bytes"][0][1]

@@ -107,7 +107,7 @@ def test_paged_chunks_then_decode_match_the_reference_logits(fam):
     prompt, n_new, C = _tokens((21,), seed=3), 6, 8
     pool, tables = _pool_and_tables(module, 1)
     row = jnp.asarray(tables[:1])
-    chunk_fn = slots.make_paged_chunk_fn(module, None)
+    chunk_fn = slots.make_chunk_fn(module, None)
     padded = np.zeros((1, 24), np.int32)
     padded[0, :21] = prompt
     chunk_logits = []
@@ -191,8 +191,7 @@ def test_slot_engine_counts_live_assignments_only(served):
         srv.stats["moe_assignments"] / EXPERTS
 
 
-@pytest.mark.parametrize("refused", [
-    {"paged": False}, {"speculative": True, "spec_k": 2}])
+@pytest.mark.parametrize("refused", [{"speculative": True, "spec_k": 2}])
 def test_slot_engine_refuses_what_it_cannot_route(refused):
     module, params = _program("float32")
     eng = deepspeed_tpu.init_inference(module, config={

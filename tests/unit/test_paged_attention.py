@@ -423,9 +423,10 @@ def test_paged_chunk_prefill_4k_prompt_matches_dense_one_pass():
 # The registry: one static dispatch table, host attribution included
 # --------------------------------------------------------------------- #
 
-def test_registry_dispatch_table():
+def test_registry_dispatch_table(monkeypatch):
     """The capability probes, in table order: paged decode only without
-    bias/window/opt-out; monolithic decode masks windows in-kernel; the
+    bias/window, and with Pallas; monolithic decode masks windows
+    in-kernel; the
     chunk kernel covers 1 < S <= MAX_CHUNK_S; everything else is the
     reference fallback."""
     assert select_kernel(s=1, paged=True) == "pallas_paged_decode"
@@ -434,8 +435,6 @@ def test_registry_dispatch_table():
                          has_window=True) == "pallas_decode"
     assert select_kernel(s=1, paged=True,
                          has_window=True) == "reference_fallback"
-    assert select_kernel(s=1, paged=True,
-                         disabled=True) == "reference_fallback"
     assert select_kernel(s=1, paged=True,
                          has_bias=True) == "reference_fallback"
     for s in (2, 8, MAX_CHUNK_S):
@@ -447,12 +446,14 @@ def test_registry_dispatch_table():
     assert kernel_modes(paged=True) == {
         "decode": "pallas_paged_decode",
         "prefill_chunk": "pallas_chunked_prefill"}
-    assert kernel_modes(paged=True, disabled=True) == {
-        "decode": "reference_fallback",
-        "prefill_chunk": "reference_fallback"}
     assert kernel_modes(paged=False) == {
         "decode": "pallas_decode",
         "prefill_chunk": "pallas_chunked_prefill"}
+    monkeypatch.setenv("DSTPU_DISABLE_FLASH", "1")
+    assert select_kernel(s=1, paged=True) == "reference_fallback"
+    assert kernel_modes(paged=True) == {
+        "decode": "reference_fallback",
+        "prefill_chunk": "reference_fallback"}
 
 
 def test_registry_backend_gate(monkeypatch):

@@ -37,13 +37,10 @@ _COVERED = {
     "inference.decode": "inference.decode",
     "inference.prefill_chunk": "inference.prefill_chunk",
     "serving.decode_step": "serving.decode_step",
+    "serving.prefill_chunk": "serving.prefill_chunk",
     "serving.admit": "serving.admit",
-    "serving.decode_step_paged": "serving.decode_step_paged",
-    "serving.prefill_chunk_paged": "serving.prefill_chunk_paged",
-    "serving.admit_paged": "serving.admit_paged",
     "serving.spec_propose": "serving.spec_propose",
     "serving.spec_verify": "serving.spec_verify",
-    "serving.spec_verify_paged": "serving.spec_verify_paged",
     "serving.spec_draft_prefill": "serving.spec_draft_prefill",
     "serving.spec_draft_admit": "serving.spec_draft_admit",
     "hybrid.rollout_generate": "hybrid.rollout",
@@ -97,9 +94,9 @@ def test_lockfile_covers_registered_hot_paths(lock):
     programs = lock["programs"]
     missing = {v for v in _COVERED.values()} - set(programs)
     assert not missing, f"contracts missing from {LOCK.name}: {missing}"
-    # the paged serving programs are explicitly part of the acceptance bar
-    for name in ("serving.decode_step_paged", "serving.prefill_chunk_paged",
-                 "serving.admit_paged"):
+    # the slot engine's programs are explicitly part of the acceptance bar
+    for name in ("serving.decode_step", "serving.prefill_chunk",
+                 "serving.admit"):
         assert name in programs
 
 
@@ -411,7 +408,7 @@ ENTRY %main {
 # Memory/FLOP contracts (PROGRAMS.lock format 3, tools/lint/
 # mem_contract.py) — artifact invariants + the synthetic-break proof
 # run fast (no hot-path compiles); the per-program regen-and-diff is
-# slow-marked (16 compiles) like the mesh-scaling sweep
+# slow-marked (12 compiles) like the mesh-scaling sweep
 # ------------------------------------------------------------------ #
 def test_lockfile_format3_carries_memory_and_cost(lock):
     """Every locked program AND plan carries a memory_analysis byte
@@ -546,7 +543,7 @@ def test_program_memory_contract_matches_lockfile(lock, builder_name):
     """The full memory regen-and-diff of one program: compile it and
     hold its byte footprint + cost budget against the committed lock
     within tolerance.  ``slow``: one compile per program (the PR 14
-    budget discipline — tier-1's wall clock cannot absorb 16 compiles);
+    budget discipline — tier-1's wall clock cannot absorb 12 compiles);
     run via ``ds_lint --mem`` or ``-m slow``."""
     name, fresh = contract.build_program_contract(builder_name,
                                                   with_memory=True)

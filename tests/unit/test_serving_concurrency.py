@@ -77,13 +77,10 @@ def test_checks_off_by_default(shared_engine, monkeypatch):
 def test_registry_matches_engine_fields(shared_engine):
     """Every registry field must exist on a live engine — a renamed
     field with a stale registry entry would silently uncheck it."""
-    paged_only = {"_slot_pages", "_page_table", "_pool", "_prefix"}
     spec_only = {"_draft_cache", "_draft_lanes"}
     srv = shared_engine.serve()
     with srv._lock:
         for field in GUARDED_FIELDS["ServingEngine"]:
-            if field in paged_only and not srv.paged:
-                continue
             if field in spec_only and not srv.speculative:
                 continue
             assert hasattr(srv, field), \

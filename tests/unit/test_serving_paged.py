@@ -1,9 +1,9 @@
-"""Paged-KV serving tests (``inference/serving/paging.py``,
-``docs/serving.md`` "Paged KV cache").
+"""KV-cache tests of the slot engine (``inference/serving/paging.py``,
+``docs/serving.md`` "KV cache").
 
-The paged acceptance contract: with the slot lanes replaced by a shared
-page pool + block tables, greedy serving outputs stay BITWISE-identical
-to solo ``generate()`` runs, tokens are invariant to the page size, a
+The acceptance contract: over the shared page pool + block tables,
+greedy serving outputs stay BITWISE-identical to solo ``generate()``
+runs, tokens are invariant to the page size, a
 shared prompt prefix is prefilled exactly once (copy-on-write at page
 granularity), pool exhaustion degrades into admission backpressure
 (``QueueFull`` / stalls — never corruption), paged snapshots
@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 import deepspeed_tpu
 from deepspeed_tpu.inference.serving.paging import (PagePool, PrefixIndex,
+                                                    SlotPages,
                                                     compact_page_str,
                                                     expand_page_str)
 from deepspeed_tpu.inference.serving.slo import QueueFull, RequestStatus
@@ -87,7 +88,7 @@ def test_paged_serving_matches_solo_generate(paged_engine):
         else:
             eos_ids.append(-1)
     srv = eng.serve()
-    assert srv.paged and srv.page == 16
+    assert srv.page == 16
     rids = [srv.submit(p, max_new_tokens=n, eos_token_id=e)
             for p, n, e in zip(prompts, news, eos_ids)]
     outs = srv.drain()
@@ -95,8 +96,8 @@ def test_paged_serving_matches_solo_generate(paged_engine):
     _assert_bitwise(eng, outs, rids, prompts, news, eos_ids)
     # every slot's pages returned to the pool; only the prefix index may
     # still hold references
-    assert not srv._slot_pages
-    assert (srv._page_table == 0).all()
+    assert not srv._pages._rows
+    assert (srv._pages.table() == 0).all()
     n_decode_sigs = sum(1 for sig in eng._aot
                         if sig and sig[0] == id(srv._decode_fn))
     assert n_decode_sigs == 1, n_decode_sigs
@@ -139,7 +140,7 @@ def test_paged_decode_span_counts_live_pages(paged_engine, tmp_path):
     # the step that makes a request's token i attends prompt + i positions
     want = sum(-(-(p + i) // page) for p, n in plan for i in range(1, n))
     assert sum(a["kv_pages"] for a in decodes) == want
-    table = srv.num_slots * srv.n_slot_pages * srv.block
+    table = srv.num_slots * srv.pages_per_slot * srv.block
     assert {a["kv_pages_table"] for a in decodes} == {table}
     assert all(0 <= a["kv_pages"] <= table for a in decodes)
     # a slot that retires mid-block walks fewer steps than the block:
@@ -180,8 +181,8 @@ def test_paged_prefill_chunk_span_counts_reachable_pages(paged_engine,
     chunks = [e["args"] for e in evs
               if e["name"] == "dstpu.sched.dispatch.prefill_chunk"]
     layers, page, C = 2, srv.page, srv.chunk
-    assert (page, C, srv.n_slot_pages) == (16, 8, 4)
-    table = layers * srv.n_slot_pages
+    assert (page, C, srv.pages_per_slot) == (16, 8, 4)
+    table = layers * srv.pages_per_slot
     assert {a["kv_pages_table"] for a in chunks} == {table}
     assert all(0 < a["kv_pages"] <= table for a in chunks)
     by_rid = {rid: sorted((a["chunk"], a["kv_pages"]) for a in chunks
@@ -322,7 +323,7 @@ def test_paged_pool_exhaustion_backpressure(paged_engine):
     assert srv.stats["admission_stalls"] > 0
     _assert_bitwise(eng, outs, rids, prompts, news)
     # nothing leaked: the pool drains back to empty
-    assert srv._pool.in_use == 0
+    assert srv._pages.in_use == 0
 
     # a request the pool can NEVER hold is rejected at submit, not
     # queued into a deadlock
@@ -389,9 +390,8 @@ def test_paged_int8_kv_serving_matches_solo(tmp_path):
     rng = np.random.default_rng(23)
     prompts, news = _mixed_workload(rng, n=5)
     srv = eng.serve()
-    assert "k_scale" in srv._pool_ws.take(srv.num_pages, srv.page,
-                                          eng.compute_dtype)
-    srv._pool_ws.release()
+    assert "k_scale" in srv._pages.take(eng.compute_dtype)
+    srv._pages.drop_buffer()
     rids = [srv.submit(p, max_new_tokens=n)
             for p, n in zip(prompts, news)]
     outs = srv.drain()
@@ -433,14 +433,26 @@ def test_paged_overload_cycle_zero_new_decode_executables(paged_engine,
         assert n_decode == 1, n_decode
 
 
-def test_paged_default_off_and_validation():
-    """serving.paged defaults OFF (seed behavior: monolithic lanes,
-    no pool attributes consulted), and bad paged configs fail loudly."""
+@pytest.mark.parametrize("gone", ["paged", "paged_kernel"])
+def test_removed_layout_switch_refused_by_name(gone):
+    """The lane layout and both of its switches are gone: neither is a
+    config field, ``true`` (what config files still carry) is accepted
+    and ignored, and ``false`` — a request for a layout the engine no
+    longer has — is refused by name, from the config file and from a
+    ``serve()`` override alike."""
     from deepspeed_tpu.inference.serving.config import ServingConfig
-    assert ServingConfig().paged is False
-    eng = _build_engine(serving={**PAGED, "paged": False})
-    srv = eng.serve()
-    assert not srv.paged and not hasattr(srv, "_pool")
+    assert gone not in ServingConfig.model_fields
+    eng = _build_engine(serving={**PAGED, gone: True})
+    assert eng.serve().kernel_modes["decode"] == "pallas_paged_decode"
+    with pytest.raises(ValueError, match=rf"serving\.{gone}=False.*removed"):
+        eng.serve(**{gone: False})
+    with pytest.raises(ValueError, match=rf"serving\.{gone}=False.*removed"):
+        _build_engine(serving={**PAGED, gone: False}).serve()
+
+
+def test_pool_size_validation():
+    """A pool without one allocatable page beside the trash page fails
+    loudly."""
     with pytest.raises(ValueError, match="num_pages"):
         _build_engine(serving={**PAGED, "num_pages": 1}).serve()
 
@@ -489,21 +501,119 @@ def test_page_pool_and_prefix_index_unit():
     assert compact_page_str([]) == "" and expand_page_str("") == []
 
 
-def test_paged_kernel_knob_ab_bitwise_and_fallback_counter():
-    """serving.paged_kernel=False is the A/B switch back to the
-    pre-kernel gather path: greedy outputs stay BITWISE-identical to the
-    kernel path (both match solo generate()), the engine's kernel_modes
-    attribution flips to reference_fallback, and every gather-path decode
-    dispatch is counted in stats["paged_attention_fallback"] (the kernel
-    path counts zero)."""
+# --------------------------------------------------------------------- #
+# SlotPages: which pages back which slot (host bookkeeping, no device)
+# --------------------------------------------------------------------- #
+def _slot_pages(num_pages=0, share=True, num_slots=2):
+    """An 8-page virtual lane of 16-position pages, prefill chunks of
+    64 — the geometry of the chunk-alignment comment in ``reserve``."""
+    stats = {"prefix_lookups": 0, "prefix_hits": 0,
+             "prefix_tokens_reused": 0, "page_evictions": 0}
+    sp = SlotPages(None, num_slots, cache_len=128, page_size=16,
+                   num_pages=num_pages, chunk=64, share_prefixes=share,
+                   stats=stats)
+    assert (sp.pages_per_slot, sp.cache_len) == (8, 128)
+    return sp, stats
+
+
+def _fill(seed, n=120):
+    return np.random.default_rng(seed).integers(1, 97, (n,)).astype(np.int32)
+
+
+def test_slot_pages_trims_the_matched_prefix_to_a_chunk_boundary():
+    """Page 16, chunk 64, P=120: 7 indexed pages match, but a prefill
+    from 112 would pad through 176, past the 8-page lane.  The match is
+    trimmed to 4 pages — the start lands on a chunk boundary, the padded
+    end on the lane's — and the 3 trimmed pages give their reference
+    back."""
+    sp, stats = _slot_pages()
+    fill = _fill(1)
+    row0, start0 = sp.reserve(0, fill, max_new=8)
+    assert (len(row0), start0) == (8, 0)
+    sp.share(0, fill)
+    assert len(sp._prefix) == 7                  # 120 // 16 full pages
+    row1, start1 = sp.reserve(1, fill, max_new=8)
+    assert start1 == 64 and row1[:4] == row0[:4]
+    assert not set(row1[4:]) & set(row0)
+    assert len(row1) == 8                        # 64 + one chunk of 64
+    ref = sp._pool.refcount
+    assert [ref(p) for p in row0] == [3] * 4 + [2] * 3 + [1]
+    assert (sp.row(1)[0] == row1).all() and (sp.table()[0] == row0).all()
+    assert stats == {"prefix_lookups": 2, "prefix_hits": 1,
+                     "prefix_tokens_reused": 64, "page_evictions": 0}
+
+
+@pytest.mark.parametrize("share", [False, True])
+def test_slot_pages_reserve_that_cannot_be_backed_allocates_nothing(share):
+    """A pool of 9 pages with a slot holding 5 (still prefilling: nothing
+    indexed yet): a request for 5 more is refused — no page allocated,
+    every refcount as it was, no row, no lookup counted."""
+    sp, stats = _slot_pages(num_pages=10, share=share)
+    assert sp.cannot_hold(128) is None and "10 pages" in sp.cannot_hold(145)
+    sp.reserve(0, _fill(1, 60), max_new=10)      # 70 positions: 5 pages
+    refs, free, counted = sp._pool._ref.copy(), sp._pool.free_count, \
+        dict(stats)
+    assert sp.reserve(1, _fill(2, 60), max_new=10) is None
+    assert (sp._pool._ref == refs).all() and sp._pool.free_count == free == 4
+    assert 1 not in sp._rows and (sp.row(1) == 0).all()
+    assert stats == counted
+    # one chunk of 64 with its decode tail inside it: 4 pages
+    assert sp.reserve(1, _fill(2, 40), max_new=8) is not None
+
+
+def test_slot_pages_evicts_unreferenced_prefix_pages_and_retries():
+    """Under pressure the index's pages go before the request waits: a
+    retired request's 7 indexed pages are all the pool has left, and the
+    next, unrelated request takes them."""
+    sp, stats = _slot_pages(num_pages=9)
+    fill = _fill(1)
+    sp.reserve(0, fill, max_new=8)
+    sp.share(0, fill)
+    sp.release(0)
+    assert sp.in_use == 7 == len(sp._prefix)     # the index's alone
+    row, start = sp.reserve(1, _fill(2), max_new=8)
+    assert (len(row), start) == (8, 0)
+    assert stats["page_evictions"] == 7 and len(sp._prefix) == 0
+    assert sp.in_use == 8 and sp.utilization == 1.0
+
+
+def test_slot_pages_release_keeps_the_other_holders_pages():
+    """Two slots behind one prefix: the first one's retirement drops one
+    reference of each shared page — the second keeps reading them."""
+    sp, _ = _slot_pages()
+    fill = _fill(1)
+    row0, _ = sp.reserve(0, fill, max_new=8)
+    sp.share(0, fill)
+    row1, _ = sp.reserve(1, fill, max_new=8)
+    sp.release(0)
+    ref = sp._pool.refcount
+    assert [ref(p) for p in row0] == [2] * 4 + [1] * 3 + [0]
+    assert (sp.table()[0] == 0).all() and (sp.row(1)[0] == row1).all()
+    assert sp.slot_pages_str(0) is None
+    assert expand_page_str(sp.slot_pages_str(1)) == row1
+    sp.release(1)
+    assert sp.in_use == 7 == len(sp._prefix)
+    sp.reset()
+    assert sp.in_use == 0 and len(sp._prefix) == 0
+
+
+def test_gather_fallback_bitwise_and_fallback_counter(monkeypatch):
+    """The pre-kernel gather path (the registry's ``reference_fallback``,
+    reached here through the tests' ``DSTPU_DISABLE_FLASH=1`` switch):
+    greedy outputs stay BITWISE-identical to the kernel path (both match
+    solo generate()), the engine's kernel_modes attribution flips to
+    reference_fallback, and every gather-path decode dispatch is counted
+    in stats["paged_attention_fallback"] (the kernel path counts
+    zero)."""
     eng_on = _build_engine()
-    eng_off = _build_engine(serving={**PAGED, "paged_kernel": False})
+    eng_off = _build_engine()
     rng = np.random.default_rng(31)
     prompts, news = _mixed_workload(rng, n=5)
     outs = {}
     for tag, eng in (("on", eng_on), ("off", eng_off)):
+        if tag == "off":
+            monkeypatch.setenv("DSTPU_DISABLE_FLASH", "1")
         srv = eng.serve()
-        assert srv.paged_kernel is (tag == "on")
         want = ("pallas_paged_decode" if tag == "on"
                 else "reference_fallback")
         assert srv.kernel_modes["decode"] == want

@@ -70,32 +70,65 @@ def _prompts(rng, n, lo=9, hi=21):
             for p in rng.integers(lo, hi, (n,))]
 
 
+# What a waiting request waits FOR: a free slot (the pool at its auto
+# size, a page for every position of every slot) or free pages (a pool
+# smaller than two of the test's requests need, with slots to spare) —
+# the robustness flows below run under both.
+SLOT_BOUND = pytest.param({}, id="slot-bound")
+
+
+def _page_bound(num_pages):
+    return pytest.param({"num_slots": 3, "page_size": 16,
+                         "num_pages": num_pages}, id="page-bound")
+
+
+def _pages_all_returned(srv):
+    """No slot holds a page and every table row points at the trash
+    page: what is still referenced is the prefix index's alone."""
+    sp = srv._pages
+    return (not sp._rows and (sp.table() == 0).all()
+            and sp.in_use == len(sp._prefix))
+
+
 # --------------------------------------------------------------------- #
 # Deadlines: shed before admission, retire in-slot
 # --------------------------------------------------------------------- #
-def test_deadline_shed_before_admission(served_engine):
-    """An already-expired deadline sheds the request from the queue with
-    terminal status SHED_DEADLINE — it never occupies a slot — while
-    deadline-less requests complete bitwise."""
+@pytest.mark.parametrize("pool", [SLOT_BOUND, _page_bound(5)])
+def test_deadline_shed_before_admission(served_engine, pool):
+    """A deadline that expires while the request waits — already expired
+    at submit, or run out behind a running request, waiting for its slot
+    or for its pages — sheds it from the queue with terminal status
+    SHED_DEADLINE: it never occupies a slot and never holds a page,
+    while the deadline-less request completes bitwise."""
     eng = served_engine
     rng = np.random.default_rng(41)
-    p1, p2 = _prompts(rng, 2)
-    srv = eng.serve()
-    r_ok = srv.submit(p1, max_new_tokens=5, client_id="ok")
+    p1, p2, p3 = _prompts(rng, 3)
+    srv = eng.serve(**{"num_slots": 1, **pool})
+    r_ok = srv.submit(p1, max_new_tokens=36, client_id="ok")   # 3-4 pages
     r_shed = srv.submit(p2, max_new_tokens=5, deadline_s=0.0)
-    outs = srv.drain()
-    assert sorted(outs) == sorted([r_ok, r_shed])
-    assert outs[r_shed] is None
-    res = srv.result(r_shed)
-    assert res.status == RequestStatus.SHED_DEADLINE
-    assert "never occupied a slot" in res.detail
-    assert srv.stats["admitted"] == 1, "shed request must not admit"
-    assert srv.stats["shed"] == 1
+    r_late = srv.submit(p3, max_new_tokens=20, deadline_s=60.0)  # 2-3
+    outs = {}
+    while srv.active_slots == 0:
+        outs.update(srv.step())
+    outs.update(srv.step())
+    assert srv.status(r_late) == RequestStatus.QUEUED
+    assert (srv.stats["admission_stalls"] > 0) == ("num_pages" in pool)
+    srv._requests[r_late].deadline = time.monotonic() - 1.0  # force expiry
+    outs.update(srv.drain())
+    assert sorted(outs) == sorted([r_ok, r_shed, r_late])
+    for rid in (r_shed, r_late):
+        assert outs[rid] is None
+        res = srv.result(rid)
+        assert res.status == RequestStatus.SHED_DEADLINE
+        assert "never occupied a slot" in res.detail
+    assert srv.stats["admitted"] == 1, "shed requests must not admit"
+    assert srv.stats["shed"] == 2
+    assert _pages_all_returned(srv)
     ok = srv.result(r_ok)
     assert ok.status == RequestStatus.COMPLETED
     assert ok.client_id == "ok" and ok.ttft_s is not None
     np.testing.assert_array_equal(
-        outs[r_ok], np.asarray(eng.generate(p1[None], max_new_tokens=5))[0])
+        outs[r_ok], np.asarray(eng.generate(p1[None], max_new_tokens=36))[0])
 
 
 def test_deadline_retires_in_slot_and_slot_is_reusable(served_engine):
@@ -119,13 +152,14 @@ def test_deadline_retires_in_slot_and_slot_is_reusable(served_engine):
         outs[r2], np.asarray(eng.generate(p2[None], max_new_tokens=4))[0])
 
 
-def test_cancel_queued_and_running(served_engine):
+@pytest.mark.parametrize("pool", [SLOT_BOUND, _page_bound(5)])
+def test_cancel_queued_and_running(served_engine, pool):
     eng = served_engine
     rng = np.random.default_rng(45)
     p1, p2, p3 = _prompts(rng, 3)
-    srv = eng.serve(num_slots=1)
-    r1 = srv.submit(p1, max_new_tokens=30)
-    r2 = srv.submit(p2, max_new_tokens=5)
+    srv = eng.serve(**{"num_slots": 1, **pool})
+    r1 = srv.submit(p1, max_new_tokens=30)          # 3-4 pages of 16
+    r2 = srv.submit(p2, max_new_tokens=20)          # 2-3
     # queued cancellation is immediate
     assert srv.cancel(r2) is True
     assert srv.result(r2).status == RequestStatus.CANCELLED
@@ -138,12 +172,14 @@ def test_cancel_queued_and_running(served_engine):
         srv.step()
     assert srv.cancel(r1) is True
     assert srv.active_slots == 0
+    assert _pages_all_returned(srv), "a cancelled slot's pages return"
     r3 = srv.submit(p3, max_new_tokens=4)
     outs = srv.drain()
     assert outs.get(r1, None) is None and outs.get(r2, "x") in (None, "x")
     np.testing.assert_array_equal(
         outs[r3], np.asarray(eng.generate(p3[None], max_new_tokens=4))[0])
     assert srv.stats["cancelled"] == 2
+    assert _pages_all_returned(srv)
 
 
 # --------------------------------------------------------------------- #
@@ -176,15 +212,18 @@ def test_backpressure_reject_and_block(served_engine):
 # --------------------------------------------------------------------- #
 # Circuit breaker
 # --------------------------------------------------------------------- #
-def test_circuit_breaker_trips_rejects_and_recovers(served_engine):
+@pytest.mark.parametrize("pool", [SLOT_BOUND, _page_bound(4)])
+def test_circuit_breaker_trips_rejects_and_recovers(served_engine, pool):
     """N consecutive failed dispatches trip the breaker: failures are
-    absorbed (requests ABORTED, scheduler stays consistent), submit()
-    rejects with the reason, and after the cooldown a half-open probe
-    closes it — the queued requests then complete bitwise."""
+    absorbed (requests ABORTED, scheduler stays consistent, every page
+    mapping dropped with the pool buffer), submit() rejects with the
+    reason, and after the cooldown a half-open probe closes it — the
+    queued requests then complete bitwise."""
     eng = served_engine
     rng = np.random.default_rng(49)
     prompts = _prompts(rng, 4)
-    srv = eng.serve(num_slots=2, breaker_threshold=2,
+    # page-bound: 3 allocatable pages, 2 a request — one runs at a time
+    srv = eng.serve(**{"num_slots": 2, **pool}, breaker_threshold=2,
                     breaker_cooldown_s=0.05)
     rids = [srv.submit(p, max_new_tokens=4) for p in prompts]
 
@@ -202,6 +241,7 @@ def test_circuit_breaker_trips_rejects_and_recovers(served_engine):
         assert not srv._breaker.open
         srv.step()                       # failure 2 — breaker trips
         assert srv._breaker.open
+        assert srv._pages.in_use == 0 and _pages_all_returned(srv)
         with pytest.raises(CircuitOpen, match="consecutive dispatch"):
             srv.submit(prompts[0], max_new_tokens=2)
         # open breaker: no dispatches are attempted at all
@@ -225,9 +265,12 @@ def test_circuit_breaker_trips_rejects_and_recovers(served_engine):
         np.testing.assert_array_equal(
             outs[r], np.asarray(eng.generate(p[None], max_new_tokens=4))[0])
     assert srv._breaker.trips == 1
+    assert (srv.stats["admission_stalls"] > 0) == ("num_pages" in pool)
     # after recovery a fresh submit works again
     r_new = srv.submit(prompts[0], max_new_tokens=3)
-    assert srv.drain()[r_new] is not None
+    np.testing.assert_array_equal(
+        srv.drain()[r_new],
+        np.asarray(eng.generate(prompts[0][None], max_new_tokens=3))[0])
 
 
 def test_circuit_breaker_half_open_admits_submissions(served_engine):
@@ -324,7 +367,8 @@ def test_serving_seams_registered_and_fire(served_engine):
 # --------------------------------------------------------------------- #
 # Graceful preemption: drain -> snapshot -> bitwise resume (in-process)
 # --------------------------------------------------------------------- #
-def test_preempt_snapshot_resume_bitwise(served_engine, tmp_path):
+@pytest.mark.parametrize("pool", [SLOT_BOUND, _page_bound(4)])
+def test_preempt_snapshot_resume_bitwise(served_engine, tmp_path, pool):
     """Mid-flight preemption: undrained requests (including ones with
     PARTIAL token progress) snapshot crash-atomically; a fresh server
     restores them — same rids, prefix continuation — and every request's
@@ -334,12 +378,17 @@ def test_preempt_snapshot_resume_bitwise(served_engine, tmp_path):
     rng = np.random.default_rng(55)
     prompts = _prompts(rng, 5)
     news = [int(n) for n in rng.integers(6, 13, (5,))]
-    srv = eng.serve(num_slots=2)
+    # page-bound: 3 allocatable pages, 2 a request — one runs at a time
+    pool = {"num_slots": 2, **pool}
+    srv = eng.serve(**pool)
     rids = [srv.submit(p, max_new_tokens=n, client_id=i)
             for i, (p, n) in enumerate(zip(prompts, news))]
     early = {}
-    for _ in range(6):                    # some requests mid-decode
+    for i in range(40):                   # some requests mid-decode
         early.update(srv.step())
+        if i >= 5 and any(r is not None and 0 < len(r.tokens) < r.max_new
+                          for r in srv._slots):
+            break
     tag, snapped, finished = srv.preempt(str(tmp_path), drain_budget_s=0.0)
     finished = {**early, **finished}
     assert snapped, "expected undrained work at preemption"
@@ -351,7 +400,7 @@ def test_preempt_snapshot_resume_bitwise(served_engine, tmp_path):
     with pytest.raises(RuntimeError, match="closed"):
         srv.submit(prompts[0], max_new_tokens=2)
 
-    srv2 = eng.serve(num_slots=2)
+    srv2 = eng.serve(**pool)
     restored = srv2.restore(str(tmp_path))
     assert sorted(restored) == sorted(snapped)
     assert srv2.stats["resumed"] == len(restored)
@@ -364,9 +413,11 @@ def test_preempt_snapshot_resume_bitwise(served_engine, tmp_path):
             err_msg=f"resumed request {rid} diverges from solo run")
         assert srv2.result(rid).client_id == rids.index(rid) \
             if rid in restored else True
+    assert (srv2.stats["admission_stalls"] > 0) == ("num_pages" in pool)
     # a new submission on the resumed server gets a fresh, unused rid
     assert srv2.submit(prompts[0], max_new_tokens=2) not in rids
     srv2.drain()
+    assert _pages_all_returned(srv2)
 
 
 def test_snapshot_corruption_walks_back(tmp_path):

@@ -209,9 +209,9 @@ def test_hot_path_decorator_is_identity():
 @pytest.mark.parametrize("builder_name", [
     "runtime_train_step", "runtime_apply_update", "inference_decode",
     "inference_prefill_chunk", "serving_decode_step",
-    "serving_admission_prefill", "serving_admit",
-    "serving_decode_step_paged", "serving_admission_prefill_paged",
-    "serving_admit_paged", "hybrid_rollout"])
+    "serving_prefill_chunk", "serving_admit", "serving_spec_propose",
+    "serving_spec_verify", "serving_spec_draft_prefill",
+    "serving_spec_draft_admit", "hybrid_rollout"])
 def test_jaxpr_entry_point(builder_name):
     from deepspeed_tpu.parallel.topology import reset_topology
     from deepspeed_tpu.tools.lint import entry_points, jaxpr_check

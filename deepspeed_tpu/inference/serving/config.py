@@ -25,9 +25,13 @@ class ServingConfig(DeepSpeedConfigModel):
     # per-chunk executable in blocks of this many tokens (aligned to a
     # multiple of 8, floor 8, cap 512 like prefill_chunk_size)
     prefill_chunk: int = 128
-    # prefill tokens spent per scheduler iteration before decode resumes
-    # (the Sarathi/Orca-style interleave bound); 0 = finish each admission's
-    # prefill in one iteration
+    # the prefill stall a FULL batch tolerates between two of its decode
+    # blocks (the Sarathi/Orca-style interleave bound): an iteration
+    # prefills at most budget * num_slots / live lanes prompt tokens (whole
+    # chunks, never fewer than the budget alone buys) before decode
+    # resumes — the budget itself with every lane live, more the fewer
+    # streams are waiting on it, num_slots budgets with none; 0 = unbounded
+    # (finish every admission's prefill in one iteration)
     prefill_token_budget: int = 512
     # decode steps per host round trip: one compiled program advances all
     # slots `decode_block` tokens between scheduling points.  Larger blocks

@@ -9,9 +9,12 @@ The scheduler loop per iteration (:meth:`ServingEngine.step`):
    request and the queue is non-empty, pop a request (``fcfs`` or
    ``shortest_first``) and stream its prompt through the donated
    per-chunk prefill executable straight into the slot's pages, spending
-   at most ``prefill_token_budget`` prompt tokens per iteration so a long
-   prompt cannot starve decoding.  A finished prefill dispatches ONE
-   admit program (first-token sample + in-program slot-state write).
+   at most ``prefill_token_budget x num_slots / live lanes`` prompt tokens
+   per iteration (``_prefill_limit``: the budget is the stall a FULL
+   batch tolerates, so empty slots fill fast and a full server keeps the
+   bound) so a long prompt cannot starve decoding.  A finished prefill
+   dispatches ONE admit program (first-token sample + in-program
+   slot-state write).
 2. **Decode** — ONE call of the single reusable decode-step program
    advances every live slot ``decode_block`` tokens (pool + slot state
    donated).  Rows that emit their ``eos`` (or exhaust ``max_new_tokens``)
@@ -310,7 +313,8 @@ class ServingEngine:
                       "sync_secs": 0.0, "shed": 0, "cancelled": 0,
                       "resumed": 0, "prefix_lookups": 0, "prefix_hits": 0,
                       "prefix_tokens_reused": 0, "page_evictions": 0,
-                      "admission_stalls": 0, "fairness_rejected": 0,
+                      "admission_stalls": 0, "prefill_budget_widened": 0,
+                      "fairness_rejected": 0,
                       "paged_attention_fallback": 0,
                       "stream_bridge_drops": 0,
                       "lock_wait_scheduler_s": 0.0,
@@ -1863,17 +1867,42 @@ class ServingEngine:
         lookup, the chunk dispatches and the fused admit dispatch."""
         admitted0 = self.stats["admitted"]
         tokens0 = self.stats["prefill_tokens"]
+        limit, live, widened = self._prefill_limit()
+        self.stats["prefill_budget_widened"] += widened
         with span("dstpu.sched.admit", track="scheduler",
-                  cat="scheduler") as sp:
+                  cat="scheduler", live_slots=live,
+                  budget_tokens=limit) as sp:
             try:
-                self._admit_under_budget()
+                self._admit_under_budget(limit or math.inf)
             finally:
                 sp.set(admitted=self.stats["admitted"] - admitted0,
                        prefill_tokens=self.stats["prefill_tokens"]
                        - tokens0)
 
-    def _admit_under_budget(self):  # lock-held: _lock
-        limit = self.config.prefill_token_budget or math.inf
+    def _prefill_limit(self):  # lock-held: _lock
+        """``(prompt tokens this iteration's admission may prefill — 0 =
+        unbounded, like the option —, live lanes, whether the limit is
+        more than the configured budget alone buys)``.
+        ``prefill_token_budget`` is the stall a FULL batch tolerates
+        between two of its decode blocks — ``budget x num_slots``
+        lane-tokens of waiting — so the limit is the budget scaled by
+        ``num_slots / live``: the budget itself with every lane live,
+        ``num_slots`` budgets with none (nobody waits).  ``live`` is
+        what the coming decode block will serve, read BEFORE this
+        iteration's admissions (what ``_dispatch_decode`` tests):
+        mirror-live slots plus unread admit events.  Whole chunks,
+        rounded down, never fewer than ``ceil(budget / chunk)``."""
+        live = int(self._mirror_active.sum()) \
+            + sum(e[0] == "admit" for e in self._events)
+        budget = self.config.prefill_token_budget
+        if not budget:
+            return 0, live, False
+        base = -(-budget // self.chunk)
+        chunks = max(base,
+                     budget * self.num_slots // (self.chunk * max(live, 1)))
+        return chunks * self.chunk, live, chunks > base
+
+    def _admit_under_budget(self, limit):  # lock-held: _lock
         spent = 0
         while spent < limit:
             if self._pending is None:

@@ -161,6 +161,76 @@ def test_serving_submit_while_running(served_engine):
         outs[r2], np.asarray(eng.generate(p2[None], max_new_tokens=5))[0])
 
 
+def _fill_lanes_then_queue(eng, live, slots=4, **over):
+    """A ``slots``-slot server with ``live`` lanes decoding (long outputs:
+    they stay live) and a queue deeper than any limit can drain: prompts
+    of 40 tokens = 5 chunks of 8 apiece."""
+    rng = np.random.default_rng(19)
+    srv = eng.serve(num_slots=slots, **over)
+    for _ in range(live):
+        srv.submit(rng.integers(1, 97, (9,)).astype(np.int32),
+                   max_new_tokens=40)
+    while srv.queue_depth or srv.active_slots < live:
+        srv.step()
+    for _ in range(2 * slots):
+        srv.submit(rng.integers(1, 97, (40,)).astype(np.int32),
+                   max_new_tokens=2)
+    return srv
+
+
+@pytest.mark.parametrize("budget,live,want_chunks", [
+    (16, 0, 8),     # nobody waits: num_slots budgets (16 x 4 / 8)
+    (16, 1, 8),     # floor(16 x 4 / (8 x 1))
+    (16, 2, 4),     # half the lanes wait: twice the budget
+    (16, 3, 2),     # floor(2.67) = ceil(16 / 8): never fewer than that
+    (12, 3, 2),     # a budget that is no whole chunk: ceil(12 / 8) = 2
+    (0, 2, 10),     # 0 stays unbounded: both free slots fill, 5 chunks each
+])
+def test_prefill_budget_follows_live_lanes(served_engine, budget, live,
+                                           want_chunks):
+    """One ``step()`` of a 4-slot server with ``live`` lanes live and a
+    deep queue prefills ``floor(budget x slots / (chunk x live))``
+    chunks — never fewer than the configured budget alone buys, up to
+    ``slots`` budgets when no lane is live — and ``prefill_token_budget
+    = 0`` is still unbounded.  ``live`` is read BEFORE the iteration's
+    own admissions."""
+    srv = _fill_lanes_then_queue(served_engine, live,
+                                 prefill_token_budget=budget)
+    assert srv.active_slots == live
+    tokens0 = srv.stats["prefill_tokens"]
+    widened0 = srv.stats["prefill_budget_widened"]
+    srv.step()
+    assert (srv.stats["prefill_tokens"] - tokens0) // srv.chunk \
+        == want_chunks
+    base = -(-budget // srv.chunk)
+    assert srv.stats["prefill_budget_widened"] - widened0 \
+        == (budget > 0 and want_chunks > base)
+    srv.close()
+
+
+def test_prefill_budget_changes_the_schedule_not_the_tokens(served_engine):
+    """The same request set under the live-lane budget (16) and under
+    no budget (0) gives bit-identical greedy outputs — each equal to its
+    solo ``generate()`` — in no more iterations the wider the limit."""
+    eng = served_engine
+    rng = np.random.default_rng(23)
+    prompts, news = _mixed_workload(rng, n=9)
+    outs, iters = [], []
+    for budget in (16, 0):
+        srv = eng.serve(num_slots=4, prefill_token_budget=budget)
+        rids = [srv.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, news)]
+        got = srv.drain()
+        outs.append([got[r] for r in rids])
+        iters.append(srv.stats["iterations"])
+        srv.close()
+    for a, b, p, n in zip(*outs, prompts, news):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(
+            a, np.asarray(eng.generate(p[None], max_new_tokens=n))[0])
+    assert iters[1] <= iters[0]
+
+
 def test_serving_admission_policies_and_validation(served_engine):
     eng = served_engine
     rng = np.random.default_rng(9)

@@ -215,6 +215,60 @@ def test_tracing_off_on_bitwise_zero_new_execs_spans_breakdown(
     span_trace.disable()
 
 
+def test_admit_span_carries_the_limit_and_widened_is_counted(
+        shared_engine, tmp_path):
+    """``dstpu.sched.admit`` says what it was held to: ``budget_tokens``
+    (the iteration's limit: the configured 16 scaled by slots / live
+    lanes, whole chunks) and ``live_slots`` (read before the iteration's
+    admissions).  ``stats["prefill_budget_widened"]`` counts exactly the
+    iterations whose limit exceeded what the budget alone buys — none
+    while every lane is live."""
+    eng = shared_engine
+    rng = np.random.default_rng(47)
+    prompts, news = _workload(rng)
+    srv = eng.serve(tracing=True)
+    for p, n in zip(prompts, news):
+        srv.submit(p, max_new_tokens=n)
+    srv.drain()
+    with open(srv.dump_trace(str(tmp_path / "trace.json"))) as f:
+        evs = json.load(f)["traceEvents"]
+    admits = [e["args"] for e in evs if e["name"] == "dstpu.sched.admit"]
+    assert len(admits) == srv.stats["iterations"]
+    S, C, B = srv.num_slots, srv.chunk, 16
+    for a in admits:
+        assert a["budget_tokens"] \
+            == max(B, B * S // (C * max(a["live_slots"], 1)) * C), a
+        assert a["prefill_tokens"] <= a["budget_tokens"], a
+    # the first iteration meets no live lane and spends S budgets; with
+    # 7 requests over 3 slots some later one meets all three live
+    assert admits[0]["live_slots"] == 0 \
+        and admits[0]["budget_tokens"] == B * S
+    full = [a for a in admits if a["live_slots"] == S]
+    assert full and all(a["budget_tokens"] == B for a in full)
+    assert srv.stats["prefill_budget_widened"] \
+        == sum(a["budget_tokens"] > B for a in admits) > 0
+    srv.close()
+
+    # one slot is never less than full (slots / max(live, 1) = 1), and
+    # an unbounded budget (0, which the span shows as 0) has nothing to
+    # widen: the counter stays 0
+    for budget in (B, 0):
+        srv = eng.serve(tracing=True, num_slots=1,
+                        prefill_token_budget=budget)
+        for p in prompts[:2]:
+            srv.submit(p, max_new_tokens=3)
+        srv.drain()
+        assert srv.stats["prefill_budget_widened"] == 0
+        with open(srv.dump_trace(str(tmp_path / "one.json"))) as f:
+            evs = json.load(f)["traceEvents"]
+        mine = [e["args"] for e in evs if e["name"] == "dstpu.sched.admit"
+                ][-srv.stats["iterations"]:]
+        assert {a["budget_tokens"] for a in mine} == {budget}
+        assert {a["live_slots"] for a in mine} == {0, 1}
+        srv.close()
+    span_trace.disable()
+
+
 # --------------------------------------------------------------------- #
 # The one span helper
 # --------------------------------------------------------------------- #

@@ -157,6 +157,12 @@ class InferenceEngine:
             return params
         return self._quantizer.dequantize_tree(params, self.compute_dtype)
 
+    def release_params(self):
+        """Drop this engine's parameters (their device memory with them)
+        before the next ``set_params``: a model that fills more than half
+        the chip has no room for its successor beside it."""
+        self._params = None
+
     def init_params(self, example_ids=None, seed=0):
         """Random init (testing / benchmarking without a checkpoint)."""
         if example_ids is None:

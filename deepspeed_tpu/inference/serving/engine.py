@@ -142,7 +142,8 @@ from deepspeed_tpu.inference.serving.slots import (init_slot_state,
                                                    make_draft_chunk_fn,
                                                    make_draft_propose_fn,
                                                    make_spec_verify_fn,
-                                                   routes_experts)
+                                                   routes_experts,
+                                                   holds_share)
 from deepspeed_tpu.runtime.fault import inject
 from deepspeed_tpu.utils.logging import log_dist, logger
 
@@ -297,8 +298,10 @@ class ServingEngine:
         if self.num_slots < 1:
             raise ValueError(f"serving.num_slots={cfg.num_slots}: need >= 1")
         # admission chunk: align like the engine's prefill_chunk_size
-        # (multiple of 8, floor 8, cap 512 — the chunk kernel's bounds)
-        self.chunk = min(512, max(8, -(-int(cfg.prefill_chunk) // 8) * 8))
+        # (multiple of 8, floor 8, cap 512 — the chunk kernel's bounds;
+        # a model whose chunk path has other bounds names its own cap)
+        self.chunk = min(getattr(self.module, "prefill_chunk_cap", 512),
+                         max(8, -(-int(cfg.prefill_chunk) // 8) * 8))
         if not hasattr(type(self.module), "init_paged_cache"):
             raise ValueError(
                 f"{type(self.module).__name__} has no init_paged_cache — "
@@ -329,9 +332,24 @@ class ServingEngine:
             cfg.num_pages, self.chunk,
             share_prefixes=cfg.prefix_cache and not self.speculative,
             stats=self.stats)
+        if self.stats.get("prefix_sharing_refused"):
+            logger.warning(
+                f"serving.prefix_cache: {type(self.module).__name__} has "
+                f"window layers whose rows live in a per-slot ring — a "
+                f"shared prefix's pages would not carry them, so prefix "
+                f"sharing is OFF for this server "
+                f"(stats['prefix_sharing_refused'])")
         self.page = self._pages.page
         self.num_pages = self._pages.num_pages
         self.pages_per_slot = self._pages.pages_per_slot
+        # the shipped table's width (lane pages, then a window model's ring
+        # pages) and the pools' constructor: geometry, fixed for good
+        self.table_width = self._pages.table_width
+        self._new_pools = self._pages.new_pools
+        # a model that counts its own attention work names the span args
+        # to sum into ``stats`` (``work_counters``); the names are its own
+        self._work_keys = tuple(getattr(self.module, "work_counters", ()))
+        self.stats.update(dict.fromkeys(self._work_keys, 0))
         # the slot's virtual lane: max_cache_len in whole pages
         self.cache_len = self._pages.cache_len
         max_seq = getattr(getattr(self.module, "config", None),
@@ -573,6 +591,11 @@ class ServingEngine:
             self.stats.update({"moe_assignments": 0,
                                "moe_experts_touched": 0,
                                "moe_max_expert_tokens": 0})
+            # a model that holds a share of the experts also reports the
+            # choices that fell on the experts other chips hold
+            self._moe_share = holds_share(self.module)
+            if self._moe_share:
+                self.stats["moe_assignments_elsewhere"] = 0
             self.moe_expert_tokens = np.zeros(
                 (sum(_is_moe_layer(mc, i) for i in range(mc.num_layers)),
                  mc.moe_num_experts), np.int64)  # guarded-by: _lock
@@ -713,6 +736,13 @@ class ServingEngine:
     # Observability: span tracing, flight recorder, histograms
     # (docs/observability.md) — host bookkeeping only, all default-off
     # ------------------------------------------------------------------ #
+    def _count_work(self, args):  # lock-held: _lock
+        """Sum a dispatch's attention-work counters into ``stats`` and hand
+        the span's args back."""
+        for key in self._work_keys:
+            self.stats[key] += args.get(key, 0)
+        return args
+
     @contextmanager
     def _observe_dispatch(self, program, **args):  # lock-held: _lock
         """Record one device dispatch at its scheduler seam: the span
@@ -1753,9 +1783,7 @@ class ServingEngine:
         eng = self.engine
         N, S, C = self.num_slots, self.cache_len, self.chunk
         dtype = eng.compute_dtype
-        cache = jax.eval_shape(
-            lambda: self.module.init_paged_cache(
-                self.num_pages, self.page, dtype=dtype))
+        cache = jax.eval_shape(lambda: self._new_pools(dtype))
         state = {
             "token": jax.ShapeDtypeStruct((N,), jnp.int32),
             "pos": jax.ShapeDtypeStruct((N,), jnp.int32),
@@ -1779,8 +1807,8 @@ class ServingEngine:
             eng._aot[sig] = compiled
             return {name: 0.0 if hit else dt}
 
-        row = jax.ShapeDtypeStruct((1, self.pages_per_slot), jnp.int32)
-        tables = jax.ShapeDtypeStruct((N, self.pages_per_slot), jnp.int32)
+        row = jax.ShapeDtypeStruct((1, self.table_width), jnp.int32)
+        tables = jax.ShapeDtypeStruct((N, self.table_width), jnp.int32)
         cargs = (eng._params, cache, row,
                  jax.ShapeDtypeStruct((1, C), jnp.int32),
                  jax.ShapeDtypeStruct((), jnp.int32),
@@ -1986,9 +2014,9 @@ class ServingEngine:
             with self._observe_dispatch(
                     "prefill_chunk", rid=p.req.rid, slot=p.slot,
                     chunk=p.ci, phase="prefill",
-                    **self._pages.chunk_reach(
+                    **self._count_work(self._pages.chunk_reach(
                         self.module.config.num_layers,
-                        p.start + (p.ci + 1) * C)):
+                        p.start + (p.ci + 1) * C, live_end=P))):
                 # the chunk writes straight into the slot's pool pages
                 # — the POOL is the donated buffer, chained with decode
                 logits, self._cache, *load = self.engine._run_guarded(
@@ -2148,7 +2176,7 @@ class ServingEngine:
                 with self._observe_dispatch(
                         "decode", phase="decode",
                         live_slots=int(self._mirror_active.sum()),
-                        **self._block_kv_work()):
+                        **self._count_work(self._block_kv_work())):
                     toks, self._cache, self._state, *load = \
                         self.engine._run_guarded(
                             self._decode_fn,
@@ -2259,13 +2287,18 @@ class ServingEngine:
         with ``moe_calls``, the expert-layer calls they cover."""
         if not loads:
             return
-        assigned = touched = busiest = 0
+        assigned = touched = busiest = elsewhere = 0
+        held = self.moe_expert_tokens.size
         for vec in map(np.asarray, loads):
-            self.moe_expert_tokens += vec[:-2].reshape(
+            self.moe_expert_tokens += vec[:held].reshape(
                 self.moe_expert_tokens.shape)
-            assigned += int(vec[:-2].sum())
+            assigned += int(vec[:held].sum())
             touched += int(vec[-2])
             busiest += int(vec[-1])
+            elsewhere += int(vec[held]) if self._moe_share else 0
+        if self._moe_share:
+            self.stats["moe_assignments_elsewhere"] += elsewhere
+            sp.set(moe_assignments_elsewhere=elsewhere)
         self.stats["moe_assignments"] += assigned
         self.stats["moe_experts_touched"] += touched
         self.stats["moe_max_expert_tokens"] += busiest

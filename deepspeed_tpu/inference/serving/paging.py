@@ -291,8 +291,24 @@ class SlotPages:
         self._buffer = None              # the device pool, between uses
         self._pool = PagePool(self.num_pages)
         self._prefix = PrefixIndex()
+        # a model with window layers (``window_ring_pages``) keeps their
+        # rows in a pool of its own: every slot owns ``ring_pages`` pages
+        # there for good — a ring over the window's positions, whatever
+        # the prompt's length — behind one trash page; the slot's table
+        # row carries them after its lane pages
+        self.ring_pages = int(getattr(
+            module, "window_ring_pages", lambda page: 0)(self.page))
+        self.window_pages = 1 + self.num_slots * self.ring_pages \
+            if self.ring_pages else 0
+        self.table_width = self.pages_per_slot + self.ring_pages
+        if self.ring_pages and self.share_prefixes:
+            # a shared prefix's pages hold the full layers' rows only: the
+            # sharer's rings would miss the window's rows before its
+            # first private position
+            self.share_prefixes = False
+            stats["prefix_sharing_refused"] = 1
         # shipped as a traced arg on every dispatch; 0 = the trash page
-        self._table = np.zeros((self.num_slots, self.pages_per_slot),
+        self._table = np.zeros((self.num_slots, self.table_width),
                                np.int32)
         self._rows = {}                  # slot -> [page ids]
 
@@ -306,10 +322,17 @@ class SlotPages:
         pool, self._buffer = self._buffer, None
         if pool is None or any(getattr(l, "is_deleted", lambda: False)()
                                for l in jax.tree.leaves(pool)):
-            pool = self._module.init_paged_cache(self.num_pages, self.page,
-                                                 dtype=dtype)
+            pool = self.new_pools(dtype)
         self.reset()
         return pool
+
+    def new_pools(self, dtype):
+        """The model's zero pool(s) at this manager's sizes — one ``k`` /
+        ``v`` pair, or pools by row kind (``models/dots3.py``)."""
+        kinds = {"window_pages": self.window_pages} if self.ring_pages \
+            else {}
+        return self._module.init_paged_cache(self.num_pages, self.page,
+                                             dtype=dtype, **kinds)
 
     def give_back(self, pool):
         self._buffer = pool
@@ -387,6 +410,9 @@ class SlotPages:
         self._rows[int(slot)] = row
         self._table[slot, :] = TRASH_PAGE
         self._table[slot, :len(row)] = row
+        # the slot's own ring: pages 1 + slot * ring .. of the window pool
+        self._table[slot, self.pages_per_slot:] = \
+            1 + int(slot) * self.ring_pages + np.arange(self.ring_pages)
         return row, s0
 
     def share(self, slot, fill):
@@ -421,11 +447,12 @@ class SlotPages:
 
     # ---- what the dispatches ship ----
     def table(self):
-        """``[num_slots, pages_per_slot]`` int32, every slot's row."""
+        """``[num_slots, table_width]`` int32, every slot's row: its lane
+        pages, then (a model with window layers) its ring pages."""
         return self._table
 
     def row(self, slot):
-        """``[1, pages_per_slot]`` int32, one slot's row."""
+        """``[1, table_width]`` int32, one slot's row."""
         return self._table[slot:slot + 1]
 
     # ---- observability ----
@@ -439,8 +466,16 @@ class SlotPages:
         return self._pool.utilization()
 
     def describe(self):
-        return (f"page pool: {self._pool.in_use}/{self._pool.allocatable} "
+        text = (f"page pool: {self._pool.in_use}/{self._pool.allocatable} "
                 f"in use, {len(self._prefix)} prefix entries")
+        if self.ring_pages:
+            held = int((self._table[:, self.pages_per_slot]
+                        != TRASH_PAGE).sum())
+            text += (f"; by row kind: latent + index rows "
+                     f"{self._pool.in_use} pages, window rows "
+                     f"{held * self.ring_pages}/{self.window_pages - 1} "
+                     f"pages ({self.ring_pages} a slot, a ring)")
+        return text
 
     def slot_pages_str(self, slot):
         """``slot``'s pages, range-compressed (:func:`compact_page_str`)
@@ -448,16 +483,22 @@ class SlotPages:
         row = self._rows.get(int(slot))
         return None if row is None else compact_page_str(row)
 
-    def chunk_reach(self, layers, end):
+    def chunk_reach(self, layers, end, live_end=None):
         """What a prefill chunk writing through position ``end - 1``
         attends, as its dispatch span's args: ``kv_pages`` — the pages
         its layers fetch, every page of the slot's table up to the
         chunk's furthest position, which is the paged chunk-prefill
         kernel's block loop — and ``kv_pages_table``, pages a slot x
-        layers, what a walk over the whole table would take."""
+        layers, what a walk over the whole table would take.  A model
+        that counts its own attention work (a ``chunk_work`` method, the
+        names its own) adds it, over the chunk's REAL positions — through
+        ``live_end - 1``, the padded tail left out."""
         reach = pages_for(end, self.page)
+        work = getattr(self._module, "chunk_work", None)
         return {"kv_pages": layers * min(reach, self.pages_per_slot),
-                "kv_pages_table": layers * self.pages_per_slot}
+                "kv_pages_table": layers * self.pages_per_slot,
+                **(work(end - self.chunk, min(end, live_end or end),
+                        self.page, self.ring_pages) if work else {})}
 
     def block_reach(self, live, block):
         """What a decode block of ``block`` steps walks, as its dispatch
@@ -468,8 +509,10 @@ class SlotPages:
         ``kv_pages_table``, the slots x pages-a-slot x steps a walk over
         the whole table would take — their ratio is the share of the
         table that is live."""
+        work = getattr(self._module, "block_work", None)
         return {"kv_pages": sum(pages_for(first + i, self.page)
                                 for first, steps in live
                                 for i in range(steps)),
                 "kv_pages_table":
-                    self.num_slots * self.pages_per_slot * block}
+                    self.num_slots * self.pages_per_slot * block,
+                **(work(live, self.ring_pages) if work else {})}

@@ -72,6 +72,14 @@ def routes_experts(module):
         and getattr(cfg, "moe_capacity_factor", 1.0) is None
 
 
+def holds_share(module):
+    """True for a model that holds a SHARE of each expert layer's experts
+    (``held_experts``, ``moe/layer.py``): its counts carry one more column,
+    the live choices that fell on absent experts."""
+    return getattr(getattr(module, "config", None), "held_experts",
+                   None) is not None
+
+
 def _decode(module, variables, ids, cache, pos, live=None, **kw):
     """``module.decode`` as the slot programs call it: ``(logits, cache,
     counts)``.  Dense model (``live`` None): the plain call, ``counts``
@@ -88,23 +96,34 @@ def _decode(module, variables, ids, cache, pos, live=None, **kw):
         mutable=["moe_stats"], **kw)
     layers = sown["moe_stats"]
     # layers_0 .. layers_<L-1>, in layer order (shorter names first)
-    counts = jnp.stack([layers[name]["moe_mlp"]["expert_tokens"]
-                        for name in sorted(layers, key=lambda n: (len(n), n))])
+    names = sorted(layers, key=lambda n: (len(n), n))
+    counts = jnp.stack([layers[n]["moe_mlp"]["expert_tokens"]
+                        for n in names])
+    if holds_share(module):
+        # the last column: choices that fell on experts held elsewhere
+        counts = jnp.concatenate([counts, jnp.stack(
+            [layers[n]["moe_mlp"]["elsewhere"] for n in names])[:, None]],
+            axis=1)
     return logits, cache, counts
 
 
-def _expert_load(counts):
+def _expert_load(counts, share=False):
     """The load summary a slot program of an expert model returns beside
     its other outputs, from ``counts [calls, expert layers, experts]`` —
     ONE int32 vector (one device read for the scheduler):
     ``expert_tokens [layers x experts]`` (assignments summed over the
     calls), then ``touched`` (experts with a live token, summed over
     layers and calls — each is one expert's weights read) and
-    ``max_tokens`` (the busiest expert's tokens, summed likewise)."""
-    return jnp.concatenate([
-        jnp.sum(counts, axis=0).reshape(-1),
-        jnp.sum(counts > 0).astype(jnp.int32)[None],
-        jnp.sum(jnp.max(counts, axis=-1))[None]])
+    ``max_tokens`` (the busiest expert's tokens, summed likewise).
+    ``share`` (:func:`holds_share`): the counts' last column is the
+    choices of absent experts — their sum goes between the held experts'
+    tokens and ``touched``."""
+    held = counts[..., :-1] if share else counts
+    return jnp.concatenate(
+        [jnp.sum(held, axis=0).reshape(-1)]
+        + ([jnp.sum(counts[..., -1])[None]] if share else [])
+        + [jnp.sum(held > 0).astype(jnp.int32)[None],
+           jnp.sum(jnp.max(held, axis=-1))[None]])
 
 
 def make_decode_block_fn(module, sample_fn, param_transform, block,
@@ -133,6 +152,7 @@ def make_decode_block_fn(module, sample_fn, param_transform, block,
     returns a fourth output, the block's :func:`_expert_load`."""
     deq = param_transform if param_transform is not None else (lambda p: p)
     routed = routes_experts(module)
+    share = holds_share(module)
 
     @hot_path("serving.decode_step")
     def decode_block(params, cache, state, pages, rng):
@@ -170,7 +190,7 @@ def make_decode_block_fn(module, sample_fn, param_transform, block,
         new_state = {"token": tok, "pos": pos, "active": active,
                      "remaining": remaining, "eos": eos}
         if routed:
-            return toks, cache, new_state, _expert_load(counts)
+            return toks, cache, new_state, _expert_load(counts, share)
         return toks, cache, new_state
 
     return jax.jit(decode_block, donate_argnums=(1, 2))
@@ -193,6 +213,7 @@ def make_chunk_fn(module, param_transform):
     load)`` with the chunk's :func:`_expert_load`."""
     deq = param_transform if param_transform is not None else (lambda p: p)
     routed = routes_experts(module)
+    share = holds_share(module)
 
     @hot_path("serving.prefill_chunk")
     def chunk_step(params, cache, pages, chunk_ids, start, logits_at):
@@ -203,7 +224,7 @@ def make_chunk_fn(module, param_transform):
             {**cache, "pages": pages}, start, live=live,
             logits_at=logits_at)
         if routed:
-            return logits, cache, _expert_load(counts[None])
+            return logits, cache, _expert_load(counts[None], share)
         return logits, cache
 
     return jax.jit(chunk_step, donate_argnums=(1,))

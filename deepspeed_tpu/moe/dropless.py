@@ -34,6 +34,20 @@ sort, gather or scatter stands around the matmuls: the weighted combine
 is the kernel's own accumulation.  Rows are independent: what a dead lane
 holds changes no live lane's bits.
 
+Two more pieces serve a model whose router scores sigmoid + selection
+bias and whose chip holds a SHARE of the experts (``models/dots3.py``):
+
+* :func:`route_scored` (XLA, under the scope ``moe.route``) — float32
+  sigmoid scores, the top-k of ``score + bias`` (``noaux_tc``), gates the
+  chosen scores over their sum; the choices come back as indices, since a
+  chunk's grouped form sorts by them.
+* ``moe.experts_grouped`` (:func:`experts_grouped`) — a chunk's rows
+  sorted by the HELD expert they chose, each expert's rows padded to a
+  whole row tile, and one kernel over the live tiles: a tile reads its
+  expert's matrices and computes its own rows only.  At 8 of 256 experts
+  a token, a 2,048-token chunk hands each of 32 held experts ~64 rows;
+  the dense form above would compute 32 x 2,048.
+
 No VJP: a model that trains through expert layers gives its gate a
 capacity (``moe_capacity_factor``) and takes the GShard path.
 """
@@ -49,6 +63,15 @@ from deepspeed_tpu.ops.transformer.flash_attention import _interpret
 
 # rows are padded to the bf16 sublane tile
 _ROW_TILE = 16
+# rows from which a call of the scored layer sorts by expert
+# (``moe.experts_grouped``) instead of computing every touched expert over
+# every row (``moe.experts_gmm``).  On a v5e, 32 held experts of 5120 x
+# 1536, 8 of 256 a token (PR 31, ms a call, gmm / grouped): 64 rows 1.82 /
+# 2.07, 128 2.00 / 2.32, 256 2.09 / 2.55, 512 4.05 / 2.86, 1024 9.17 /
+# 3.57 — the sort and the gathers cost ~0.4 ms, every row past ~256 costs
+# the dense form another expert-row product.  OLMoE's sizes (64 of 64,
+# 2048 x 1024): 128 rows 1.11 / 1.35, 512 rows 2.18 / 1.53
+GROUPED_MIN_ROWS = 512
 
 
 def _pad_rows(*arrays):
@@ -214,3 +237,152 @@ def experts(x, combine, counts, wg, wu, wd, act):
         name="moe.experts_gmm",
     )(on, src, pin, x, combine, *([wg] if gated else []), wu, wd)
     return out[:T]
+
+
+# --------------------------------------------------------------------- #
+# Sigmoid + bias routing, and the share of the experts a chip holds
+# --------------------------------------------------------------------- #
+def route_scored(x, gate_w, bias, k, renormalize=True, scaling=1.0,
+                 live=None):
+    """``noaux_tc`` routing of ``x [T, M]`` through ``gate_w [M, E]``:
+    float32 scores ``sigmoid(x @ gate_w)``, the ``k`` largest of
+    ``score + bias`` chosen (ties to the lower index), gates the chosen
+    SCORES (not the biased ones) over their sum where ``renormalize``,
+    times ``scaling``.  Returns ``(choice [T, k] int32, gate [T, k]
+    float32)``; a token that is not ``live`` has gates 0 and choice -1."""
+    with jax.named_scope("moe.route"):
+        scores = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), gate_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, choice = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        gate = jnp.take_along_axis(scores, choice, axis=1)
+        if renormalize:
+            gate = gate / jnp.sum(gate, axis=1, keepdims=True)
+        gate = gate * scaling
+        if live is not None:
+            gate = jnp.where(live[:, None], gate, 0.0)
+            choice = jnp.where(live[:, None], choice, -1)
+        return choice.astype(jnp.int32), gate
+
+
+def held_load(choice, first, count):
+    """What a chip that holds experts ``first .. first + count - 1`` sees
+    of ``choice [T, k]``: ``(local [T, k]`` — the held expert's index or
+    ``count`` for a choice that fell elsewhere or on a dead token —,
+    ``counts [count]`` int32, ``elsewhere`` — live choices of absent
+    experts)``."""
+    held = (choice >= first) & (choice < first + count)
+    local = jnp.where(held, choice - first, count)
+    counts = jnp.sum(jax.nn.one_hot(local, count + 1, dtype=jnp.int32),
+                     axis=(0, 1))
+    return local, counts[:count], \
+        jnp.sum((choice >= 0) & ~held).astype(jnp.int32)
+
+
+def combine_of(local, gate, count):
+    """The dense ``[T, count]`` combine matrix of the held choices — what
+    :func:`experts` takes (a decode step's few rows)."""
+    return jnp.sum(jax.nn.one_hot(local, count + 1, dtype=jnp.float32)
+                   * gate[..., None], axis=1)[:, :count]
+
+
+def _grouped_kernel(tiles_ref, expert_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                    o_ref, acc_ref, *, act):
+    t, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(t < tiles_ref[0])
+    def _tile():
+        @pl.when(f == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        x = x_ref[...]
+        h = act(jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)) \
+            * jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        acc_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[0],
+                                preferred_element_type=jnp.float32)
+
+        @pl.when(f == pl.num_programs(1) - 1)
+        def _finish():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def grouped_layout(local, count, tile):
+    """Where each (token, choice) pair's row sits once the rows are sorted
+    by held expert and every expert's rows are padded to whole tiles of
+    ``tile``.  From ``local [T, k]`` (:func:`held_load`): ``(dest [T, k]``
+    — the pair's row, or ``rows`` for a pair no held expert takes —,
+    ``source [rows]`` — the token each row holds (padding rows name token
+    0) —, ``tile_expert [rows / tile]``, ``live_tiles)``; ``rows`` is the
+    static worst case ``T x k`` rounded up plus one tile an expert."""
+    T, k = local.shape
+    rows = (-(-T * k // tile) + count) * tile
+    flat = local.reshape(-1)
+    counts = jnp.sum(jax.nn.one_hot(flat, count + 1, dtype=jnp.int32),
+                     axis=0)[:count]
+    tiles = -(-counts // tile)
+    first_tile = jnp.cumsum(tiles) - tiles
+    order = jnp.argsort(flat, stable=True)
+    start = jnp.cumsum(counts) - counts                   # in sorted order
+    sorted_e = flat[order]
+    safe_e = jnp.minimum(sorted_e, count - 1)
+    rank = jnp.arange(T * k, dtype=jnp.int32) - start[safe_e]
+    row = jnp.where(sorted_e < count,
+                    first_tile[safe_e] * tile + rank, rows)
+    dest = jnp.zeros((T * k,), jnp.int32).at[order].set(row)
+    source = jnp.zeros((rows,), jnp.int32).at[row].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    live_tiles = jnp.sum(tiles).astype(jnp.int32)
+    tile_expert = jnp.searchsorted(
+        jnp.cumsum(tiles), jnp.arange(rows // tile, dtype=jnp.int32),
+        side="right").astype(jnp.int32)
+    return dest.reshape(T, k), source, \
+        jnp.minimum(tile_expert, count - 1), live_tiles
+
+
+def experts_grouped(x, local, gate, wg, wu, wd, act, tile=128):
+    """The held experts' part of the routed layer on a chunk ``x [T, M]``:
+    ``sum_j gate[t, j] * E_{local[t, j]}(x[t])`` over the pairs a held
+    expert takes — real rows only, each expert's matrices read once a
+    row tile.  ``local``/``gate [T, k]`` from :func:`held_load` /
+    :func:`route_scored`; ``wg``/``wu [E, M, F]``, ``wd [E, F, M]``."""
+    T, M = x.shape
+    E, _, F = wu.shape
+    dest, source, tile_expert, live_tiles = grouped_layout(local, E, tile)
+    rows = source.shape[0]
+    xs = x[source]
+    tf = _width_tile(F)
+    nf = F // tf
+    itemsize = x.dtype.itemsize
+    # a dead tile names the blocks the last live tile left in place
+    last = lambda tiles: jnp.maximum(tiles[0] - 1, 0)
+    row_of = lambda t, tiles: jnp.minimum(t, last(tiles))
+    f_of = lambda t, f, tiles: jnp.where(t < tiles[0], f, nf - 1)
+    up_spec = pl.BlockSpec((1, M, tf), lambda t, f, tiles, ex: (
+        ex[row_of(t, tiles)], 0, f_of(t, f, tiles)))
+    down_spec = pl.BlockSpec((1, tf, M), lambda t, f, tiles, ex: (
+        ex[row_of(t, tiles)], f_of(t, f, tiles), 0))
+    row_spec = pl.BlockSpec((tile, M),
+                            lambda t, f, tiles, ex: (row_of(t, tiles), 0))
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, act=act),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(rows // tile, nf),
+            in_specs=[row_spec, up_spec, up_spec, down_spec],
+            out_specs=row_spec,
+            scratch_shapes=[pltpu.VMEM((tile, M), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((rows, M), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=min(
+                100 * 1024 * 1024,
+                6 * M * tf * itemsize + tile * (8 * M + 16 * tf)
+                + 16 * 1024 * 1024)),
+        interpret=_interpret(),
+        name="moe.experts_grouped",
+    )(live_tiles[None], tile_expert, xs, wg, wu, wd)
+    # each token gathers its pairs' rows back, weighted by their gates
+    # (a pair no held expert took reads the zero row appended here)
+    out = jnp.concatenate([out, jnp.zeros((1, M), out.dtype)])
+    return jnp.einsum("tkm,tk->tm", out[dest], gate,
+                      preferred_element_type=jnp.float32).astype(x.dtype)

@@ -29,7 +29,8 @@ class ExpertsMLP(nn.Module):
     Two call forms over the same parameters: ``experts(x [E, C, M])`` —
     the GShard batch, one capacity-sized queue an expert — and
     ``experts(tokens [T, M], routed=(combine, counts))`` — the dropless
-    layer of ``moe/dropless.py``, returning ``[T, M]``."""
+    layer of ``moe/dropless.py``, returning ``[T, M]`` (``grouped=(local,
+    gate)``: its sorted-by-expert form for a chunk's many rows)."""
     num_experts: int
     hidden_size: int
     ffn_hidden_size: int
@@ -39,7 +40,7 @@ class ExpertsMLP(nn.Module):
     gated: bool = False
 
     @nn.compact
-    def __call__(self, x, routed=None):
+    def __call__(self, x, routed=None, grouped=None):
         E, M, F = self.num_experts, self.hidden_size, self.ffn_hidden_size
         wi = self.param("experts_wi", nn.initializers.lecun_normal(),
                         (E, M, F), jnp.float32).astype(x.dtype)
@@ -48,6 +49,10 @@ class ExpertsMLP(nn.Module):
         wg = self.param("experts_wg", nn.initializers.lecun_normal(),
                         (E, M, F), jnp.float32).astype(x.dtype) \
             if self.gated else None
+        if grouped is not None:
+            # a chunk's rows sorted by expert: ``(local, gate)``
+            return dropless.experts_grouped(x, *grouped, wg, wi, wo,
+                                            self.activation)
         if routed is not None:
             if self.use_bias:
                 raise ValueError("the dropless expert kernel carries no "
@@ -101,6 +106,17 @@ class MoE(nn.Module):
     gated: bool = False
     activation: Callable = nn.gelu
     norm_topk_prob: bool = True
+    # the dropless layer's further forms (config, not a fork):
+    # ``scoring="sigmoid"`` with a stored selection bias (``noaux_tc``:
+    # the top-k of score + bias, gates from the scores), a shared expert
+    # every token takes, and ``held_experts=(first, count)`` — the chip's
+    # share under expert parallelism: the router scores all
+    # ``num_experts``, this layer holds and computes ``count`` of them
+    # and adds nothing for the absent ones (``moe/dropless.py``)
+    scoring: str = "softmax"
+    routed_scaling: float = 1.0
+    shared_ffn_hidden_size: int = 0
+    held_experts: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, x, train=True, live=None):
@@ -110,10 +126,21 @@ class MoE(nn.Module):
 
         gate_w = self.param("gate_kernel", nn.initializers.lecun_normal(),
                             (M, self.num_experts), jnp.float32)
+        first, held = self.held_experts or (0, self.num_experts)
         experts = self.expert or ExpertsMLP(
-            self.num_experts, M, self.ffn_hidden_size or 4 * M,
+            held, M, self.ffn_hidden_size or 4 * M,
             activation=self.activation, dtype=self.dtype,
             use_bias=self.expert_bias, gated=self.gated)
+        if self.scoring == "sigmoid":
+            if self.capacity_factor is not None or not self.gated:
+                raise ValueError("sigmoid + bias routing is the dropless "
+                                 "layer's, over gated experts")
+            return self._scored(tokens, gate_w, experts, first, held,
+                                live).reshape(orig_shape).astype(x.dtype), \
+                0.0, None
+        if self.held_experts is not None or self.shared_ffn_hidden_size:
+            raise ValueError("a share of the experts and a shared expert "
+                             "are implemented for scoring='sigmoid'")
         if self.capacity_factor is None:
             combine, exp_counts = dropless.route(
                 tokens, gate_w, self.k,
@@ -144,3 +171,34 @@ class MoE(nn.Module):
             y = y * coef[..., 0:1] + mlp_out * coef[..., 1:2]
 
         return y.reshape(orig_shape).astype(x.dtype), aux_loss, exp_counts
+
+    def _scored(self, tokens, gate_w, experts, first, held, live):
+        """``shared(x) + sum over the chosen experts that are HELD of
+        gate_e * E_e(x)``; sows the held experts' tokens and the choices
+        that fell on absent experts."""
+        M, F = self.hidden_size, self.shared_ffn_hidden_size
+        bias = self.param("select_bias", nn.initializers.zeros,
+                          (self.num_experts,), jnp.float32)
+        choice, gate = dropless.route_scored(
+            tokens, gate_w, bias, self.k,
+            renormalize=self.norm_topk_prob and self.k > 1,
+            scaling=self.routed_scaling,
+            live=None if live is None else live.reshape(-1))
+        local, counts, elsewhere = dropless.held_load(choice, first, held)
+        if self.is_initializing() or tokens.shape[0] < dropless.GROUPED_MIN_ROWS:
+            y = experts(tokens, routed=(
+                dropless.combine_of(local, gate, held), counts))
+        else:
+            y = experts(tokens, grouped=(local, gate))
+        if F:
+            dense = lambda n, name: nn.Dense(n, use_bias=False,
+                                             dtype=tokens.dtype, name=name)
+            y = y + dense(M, "shared_down")(
+                self.activation(dense(F, "shared_gate")(tokens))
+                * dense(F, "shared_up")(tokens))
+        if not self.is_initializing():
+            for name, value in (("expert_tokens", counts),
+                                ("elsewhere", elsewhere)):
+                self.sow("moe_stats", name, value,
+                         reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        return y

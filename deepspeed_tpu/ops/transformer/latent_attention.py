@@ -1,0 +1,347 @@
+"""Kernels for latent (MLA) attention with a learned sparse selection
+(DeepSeek-V3.2's DSA indexer) and for windowed latent attention — what
+``models/latent_attention.py`` calls on the serving path.
+
+Three Pallas kernels, each findable in a device trace by its own name:
+
+* ``attn.dsa_index`` (:func:`index_scores`) — the indexer's scores of a
+  chunk of queries against a slot's cached indexer keys:
+  ``I[t, s] = sum_j w[t, j] * relu(q[t, j] . k[s])`` in float32, the heads
+  stacked along the rows of ONE matmul per tile and summed back in groups.
+  Key blocks past the chunk's last position are skipped, DMA and body.
+* ``attn.mla_chunk_prefill`` / ``attn.mla_window`` (:func:`masked_flash`)
+  — flash attention of a chunk's queries over decompressed keys and values
+  under an explicit ``[queries, keys]`` int8 mask (the per-query kept set
+  of a full layer, the band of a window layer).  The key has two parts —
+  a per-head ``nope`` part and ONE ``rope`` part shared by all heads — so
+  the shared part is never copied per head.  Tiles in which the mask
+  keeps nothing are skipped, DMA and body.
+
+* ``attn.dsa_topk`` (:func:`kth_largest`) — a chunk's exact per-query
+  top-k threshold: the k-th largest score by bisection over the scores'
+  bit patterns, 32 compare-and-count passes over a row that stays in VMEM,
+  no sort (:func:`kept_mask` makes the mask ``score >= it``).
+
+and three parts left to XLA, each under a ``jax.named_scope`` of its name:
+
+* ``attn.dsa_topk`` for a decode step (:func:`kept_indices`) —
+  ``lax.top_k``'s indices.
+* ``attn.dsa_index`` for a decode step (:func:`index_scores_rows`) — one
+  query a lane against its lane's keys.
+* ``attn.mla_sparse_decode`` (:func:`sparse_decode`) — a decode step's
+  attention over the KEPT rows only, in the absorbed form: the latent row
+  is key and value at once (the value is its first ``rank`` columns), so a
+  lane reads ``kept x row`` bytes a layer, not ``context x row``.
+
+No VJP: training through latent attention is not implemented.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops.transformer.flash_attention import _interpret
+
+NEG = -1e30
+
+
+def _block(n, want):
+    """The largest block <= ``want`` that divides ``n`` (a toy size takes
+    the whole axis)."""
+    b = min(want, n)
+    while n % b:
+        b //= 2
+    return max(b, 1)
+
+
+# --------------------------------------------------------------------- #
+# attn.dsa_index
+# --------------------------------------------------------------------- #
+def _index_kernel(live_ref, q_ref, w_ref, k_ref, o_ref, *, heads, lanes):
+    @pl.when(pl.program_id(1) < live_ref[0])
+    def _scores():
+        s = jax.lax.dot_general(q_ref[...], k_ref[...],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        bq = s.shape[0] // heads
+        w = w_ref[...]
+        for c in range(s.shape[1] // lanes):
+            part = jnp.maximum(s[:, c * lanes:(c + 1) * lanes], 0.0) * w
+            o_ref[:, c * lanes:(c + 1) * lanes] = jnp.sum(
+                part.reshape(bq, heads, lanes), axis=1)
+
+    @pl.when(pl.program_id(1) >= live_ref[0])
+    def _dead():
+        o_ref[...] = jnp.full(o_ref.shape, NEG, o_ref.dtype)
+
+
+def index_scores(q, w, k, live_keys, block_q=32, block_k=512):
+    """``I [C, L]`` float32 from ``q [C, J, D]``, ``w [C, J]`` (float32,
+    the score's constant factors folded in) and the cached keys
+    ``k [L, D]``.  Keys at or past ``live_keys`` (a traced scalar, rounded
+    up to a key block) are not scored: their entries read ``NEG``."""
+    C, J, D = q.shape
+    L = k.shape[0]
+    bq, bk = _block(C, block_q), _block(L, block_k)
+    lanes = 128 if bk % 128 == 0 else bk
+    live = jnp.reshape(-(-live_keys // bk), (1,)).astype(jnp.int32)
+    key_block = lambda i, j, live: (jnp.minimum(j, live[0] - 1), 0)
+    return pl.pallas_call(
+        functools.partial(_index_kernel, heads=J, lanes=lanes),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(C // bq, L // bk),
+            in_specs=[pl.BlockSpec((bq * J, D), lambda i, j, live: (i, 0)),
+                      pl.BlockSpec((bq * J, lanes),
+                                   lambda i, j, live: (i, 0)),
+                      pl.BlockSpec((bk, D), key_block)],
+            out_specs=pl.BlockSpec((bq, bk), lambda i, j, live: (i, j))),
+        out_shape=jax.ShapeDtypeStruct((C, L), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+        name="attn.dsa_index",
+    )(live, q.reshape(C * J, D),
+      jnp.broadcast_to(w.astype(jnp.float32).reshape(C * J, 1),
+                       (C * J, lanes)), k)
+
+
+def index_scores_rows(q, w, k):
+    """A decode step's scores ``[N, L]``: ``q [N, J, D]``, ``w [N, J]``,
+    each lane's own keys ``k [N, L, D]``."""
+    with jax.named_scope("attn.dsa_index"):
+        s = jnp.einsum("njd,nld->njl", q, k,
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("njl,nj->nl", jnp.maximum(s, 0.0),
+                          w.astype(jnp.float32))
+
+
+# --------------------------------------------------------------------- #
+# attn.dsa_topk
+# --------------------------------------------------------------------- #
+_INT_MIN = -2 ** 31
+
+
+def _ordered(x):
+    """float32 -> int32 whose (signed) order is the floats' order."""
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+
+
+def _kth_kernel(s_ref, pos_ref, kth_ref, count_ref, *, k):
+    key = _ordered(s_ref[...])
+    col = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+    visible = col <= pos_ref[:, :1]
+    key = jnp.where(visible, key, jnp.int32(_INT_MIN))
+
+    def at_least(cand):
+        """Visible keys >= ``cand``, a bit pattern in the order of the
+        uint32 ``key ^ 0x80000000`` (an exact float32 count: a row is
+        short)."""
+        return jnp.sum((visible & (key >= (cand ^ jnp.int32(_INT_MIN))))
+                       .astype(jnp.float32), axis=1, keepdims=True)
+
+    def bit(i, kth):
+        cand = kth | jnp.left_shift(jnp.int32(1), jnp.int32(31) - i)
+        return jnp.where(at_least(cand) >= k, cand, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros((key.shape[0], 1), jnp.int32))
+    count_ref[...] = jnp.broadcast_to(at_least(kth).astype(jnp.int32),
+                                      count_ref.shape)
+    kth_ref[...] = jnp.broadcast_to(kth ^ jnp.int32(_INT_MIN),
+                                    kth_ref.shape)
+
+
+def kth_largest(scores, positions, k, block_q=32):
+    """For each query ``t`` the ``k``-th largest of ``scores[t, :
+    positions[t] + 1]`` as an ordered int32 (:func:`_ordered`; the
+    smallest int32 where fewer than ``k`` keys are visible), and how many
+    visible keys are at least that.  Exact, with no sort: a bisection
+    over the 32 bits of the scores' patterns, each pass a compare and a
+    count over the query's row, which stays in VMEM for all of them."""
+    C, L = scores.shape
+    bq = _block(C, block_q)
+    rows = lambda width: pl.BlockSpec((bq, width), lambda i: (i, 0))
+    kth, count = pl.pallas_call(
+        functools.partial(_kth_kernel, k=k),
+        grid=(C // bq,),
+        in_specs=[rows(L), rows(128)],
+        out_specs=[rows(128), rows(128)],
+        out_shape=[jax.ShapeDtypeStruct((C, 128), jnp.int32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            # the row block in, double-buffered, its keys and a pass's
+            # compare beside them
+            vmem_limit_bytes=min(100 * 1024 * 1024,
+                                 6 * bq * L * 4 + 8 * 1024 * 1024)),
+        interpret=_interpret(),
+        name="attn.dsa_topk",
+    )(scores, jnp.broadcast_to(positions.astype(jnp.int32)[:, None],
+                               (C, 128)))
+    return kth[:, :1], count[:, :1]
+
+
+def kept_mask(scores, positions, k):
+    """``[C, L]`` int8: for query ``t`` the ``k`` keys ``s <= positions[t]``
+    of largest score — all of them where fewer are visible.  Exact: the
+    k-th largest score comes from :func:`kth_largest` (Pallas,
+    ``attn.dsa_topk``) and a key is kept iff its score is at least that.
+    Scores that tie AT the k-th value go to the lower positions, as
+    ``lax.top_k`` breaks ties — a running count over the ties, taken only
+    where a row has any (with float32 sums of 64 products it has none;
+    four toy heads whose products are all negative give exact zeros)."""
+    kth, count = kth_largest(scores, positions, k)
+    with jax.named_scope("attn.dsa_topk"):
+        visible = jnp.arange(scores.shape[1])[None, :] <= positions[:, None]
+        key = _ordered(scores)
+        at_least = visible & (key >= kth)
+
+        def lower_ties_first(_):
+            above = visible & (key > kth)
+            tie = visible & (key == kth)
+            room = k - jnp.sum(above, axis=1, dtype=jnp.int32, keepdims=True)
+            return above | (tie & (jnp.cumsum(tie, axis=1,
+                                              dtype=jnp.int32) <= room))
+
+        return jax.lax.cond(jnp.any(count > k), lower_ties_first,
+                            lambda _: at_least, None).astype(jnp.int8)
+
+
+def kept_indices(scores, visible, k):
+    """A decode step's kept set: ``(idx [N, k] int32, valid [N, k])`` —
+    the ``k`` visible positions of largest score (``lax.top_k``: ties to
+    the lower index), ``valid`` false past the visible ones."""
+    with jax.named_scope("attn.dsa_topk"):
+        vals, idx = jax.lax.top_k(jnp.where(visible, scores, NEG), k)
+        return idx.astype(jnp.int32), vals > NEG / 2
+
+
+# --------------------------------------------------------------------- #
+# attn.mla_sparse_decode
+# --------------------------------------------------------------------- #
+def sparse_decode(q_lat, q_rope, rows, valid, rank, scale):
+    """Absorbed latent attention of one query a lane over its kept rows.
+    ``q_lat [N, H, rank]`` (the query's nope part through the key
+    up-projection), ``q_rope [N, H, R]``, ``rows [N, K, >= rank + R]``
+    (``[latent | rope key | padding]``), ``valid [N, K]``.  Returns the
+    attended latent ``[N, H, rank]`` (the caller applies the value
+    up-projection)."""
+    with jax.named_scope("attn.mla_sparse_decode"):
+        R = q_rope.shape[-1]
+        lat, kr = rows[..., :rank], rows[..., rank:rank + R]
+        s = jnp.einsum("nhr,nkr->nhk", q_lat, lat,
+                       preferred_element_type=jnp.float32) \
+            + jnp.einsum("nhd,nkd->nhk", q_rope, kr,
+                         preferred_element_type=jnp.float32)
+        s = jnp.where(valid[:, None, :], s * scale, NEG)
+        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(valid[:, None, :], p, 0.0)
+        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        return jnp.einsum("nhk,nkr->nhr", p.astype(rows.dtype), lat,
+                          preferred_element_type=jnp.float32)
+
+
+# --------------------------------------------------------------------- #
+# attn.mla_chunk_prefill / attn.mla_window
+# --------------------------------------------------------------------- #
+def _flash_kernel(live_ref, fetch_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
+                  mask_ref, o_ref, m_ref, l_ref, acc_ref, *, scale):
+    j = pl.program_id(2)
+    tile = pl.program_id(1) * pl.num_programs(2) + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, NEG, m_ref.dtype)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live_ref[tile] > 0)
+    def _tile():
+        keep = mask_ref[...] != 0
+        contract = (((1,), (1,)), ((), ()))
+        kr = kr_ref[...]
+        for h in range(qn_ref.shape[0]):
+            s = jax.lax.dot_general(qn_ref[h], kn_ref[h], contract,
+                                    preferred_element_type=jnp.float32) \
+                + jax.lax.dot_general(qr_ref[h], kr, contract,
+                                      preferred_element_type=jnp.float32)
+            s = jnp.where(keep, s * scale, NEG)
+            m_old = m_ref[h]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_old - m_new)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+                p.astype(v_ref.dtype), v_ref[h],
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+def _tile_plan(mask, bq, bk):
+    """Which ``[bq, bk]`` tiles of ``mask [C, L]`` keep anything, and for
+    each tile the key block to name: its own where it is live, else the
+    live one before it in its row of tiles (the first live one ahead of the
+    row's first) — a block already fetched, so a dead tile costs no DMA.
+    Both flat ``[C / bq * L / bk]`` int32."""
+    nq, nk = mask.shape[0] // bq, mask.shape[1] // bk
+    live = jnp.any(mask.reshape(nq, bq, nk, bk) != 0, axis=(1, 3))
+    ids = jnp.arange(nk, dtype=jnp.int32)[None, :]
+    before = jax.lax.cummax(jnp.where(live, ids, -1), axis=1)
+    first = jnp.argmax(live, axis=1).astype(jnp.int32)[:, None]
+    fetch = jnp.where(before < 0, first, before)
+    return live.astype(jnp.int32).reshape(-1), fetch.reshape(-1)
+
+
+def masked_flash(q_nope, q_rope, k_nope, k_rope, v, mask, scale, name,
+                 block_q=512, block_k=512, block_h=2):
+    """``out [H, C, Dv]``: softmax over the keys ``mask [C, L]`` keeps of
+    ``(q_nope . k_nope + q_rope . k_rope) * scale``, times ``v``.
+    ``q_nope [H, C, Dn]``, ``q_rope [H, C, Dr]``, ``k_nope [H, L, Dn]``,
+    ``k_rope [L, Dr]`` (one for all heads), ``v [H, L, Dv]``.  A tile of
+    ``block_q`` queries by ``block_k`` keys in which the mask keeps nothing
+    is skipped, DMA and body — the keys past a chunk's last position, the
+    chunk's own upper triangle, everything off a window layer's band.  A
+    query whose mask keeps nothing gets zeros."""
+    H, C, Dn = q_nope.shape
+    L, Dr = k_rope.shape
+    Dv = v.shape[-1]
+    bq, bk, bh = _block(C, block_q), _block(L, block_k), _block(H, block_h)
+    nk = L // bk
+    live, fetch = _tile_plan(mask, bq, bk)
+    kb = lambda i, j, fetch: fetch[i * nk + j]
+    return pl.pallas_call(
+        functools.partial(_flash_kernel, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(H // bh, C // bq, nk),
+            in_specs=[
+                pl.BlockSpec((bh, bq, Dn), lambda h, i, j, lv, f: (h, i, 0)),
+                pl.BlockSpec((bh, bq, Dr), lambda h, i, j, lv, f: (h, i, 0)),
+                pl.BlockSpec((bh, bk, Dn),
+                             lambda h, i, j, lv, f: (h, kb(i, j, f), 0)),
+                pl.BlockSpec((bk, Dr),
+                             lambda h, i, j, lv, f: (kb(i, j, f), 0)),
+                pl.BlockSpec((bh, bk, Dv),
+                             lambda h, i, j, lv, f: (h, kb(i, j, f), 0)),
+                pl.BlockSpec((bq, bk),
+                             lambda h, i, j, lv, f: (i, kb(i, j, f)))],
+            out_specs=pl.BlockSpec((bh, bq, Dv),
+                                   lambda h, i, j, lv, f: (h, i, 0)),
+            scratch_shapes=[pltpu.VMEM((bh, bq, 1), jnp.float32),
+                            pltpu.VMEM((bh, bq, 1), jnp.float32),
+                            pltpu.VMEM((bh, bq, Dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((H, C, Dv), q_nope.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+        name=name,
+    )(live, fetch, q_nope, q_rope, k_nope, k_rope, v, mask)

@@ -23,6 +23,7 @@ from deepspeed_tpu.moe import dropless as moe_mod
 from deepspeed_tpu.ops.sparse_attention import block_sparse
 from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
                                            flash_attention as flash_mod,
+                                           latent_attention as latent_mod,
                                            paged_attention as paged_mod)
 
 H, D, L = 32, 64, 24            # OPT-1.3B (models/opt.py)
@@ -62,7 +63,8 @@ def no_persistent_cache():
 def mosaic(monkeypatch, no_persistent_cache):
     """``_interpret()`` asks ``jax.default_backend()``, which is the CPU
     here: steer it in the test, not through an option of the program."""
-    for mod in (flash_mod, decode_mod, paged_mod, block_sparse, moe_mod):
+    for mod in (flash_mod, decode_mod, paged_mod, block_sparse, moe_mod,
+                latent_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -136,7 +138,54 @@ def _moe_experts(tokens, hidden=2048, experts=64, width=1024, top_k=8):
                 ((experts, width, hidden), BF16)]
 
 
+# dots3-note-prev's kernels at the long-document cell's sizes: a chunk of
+# 2,048 queries against a 16,448-position lane padded to 512-key blocks
+DOTS3_CHUNK, DOTS3_LANE = 2048, 16896
+
+
+def _dsa_index():
+    def fn(q, w, k, live):
+        return latent_mod.index_scores(q, w, k, live)
+    return fn, [((DOTS3_CHUNK, 64, 128), BF16), ((DOTS3_CHUNK, 64), F32),
+                ((DOTS3_LANE, 128), BF16), ((), I32)]
+
+
+def _dsa_topk():
+    def fn(scores, positions):
+        return latent_mod.kept_mask(scores, positions, 2048)
+    return fn, [((DOTS3_CHUNK, DOTS3_LANE), F32), ((DOTS3_CHUNK,), I32)]
+
+
+def _mla_flash(name, heads, nope, keys):
+    """Full layers: 128 heads of 128 + 64 over the lane; window layers: 64
+    heads of 192 + 64 over the chunk and its 512 predecessors."""
+    def fn(qn, qr, kn, kr, v, mask):
+        return latent_mod.masked_flash(qn, qr, kn, kr, v, mask, 0.07, name)
+    return fn, [((heads, DOTS3_CHUNK, nope), BF16),
+                ((heads, DOTS3_CHUNK, 64), BF16), ((heads, keys, nope), BF16),
+                ((keys, 64), BF16), ((heads, keys, 128), BF16),
+                ((DOTS3_CHUNK, keys), I8)]
+
+
+def _moe_grouped(tokens=DOTS3_CHUNK, hidden=5120, held=32, width=1536,
+                 top_k=8):
+    up = ((held, hidden, width), BF16)
+
+    def fn(x, local, gate, wg, wu, wd):
+        return moe_mod.experts_grouped(x, local, gate, wg, wu, wd,
+                                       jax.nn.silu)
+    return fn, [((tokens, hidden), BF16), ((tokens, top_k), I32),
+                ((tokens, top_k), F32), up, up, ((held, width, hidden), BF16)]
+
+
 CASES = {
+    "dots3_dsa_index_c2048": _dsa_index,
+    "dots3_dsa_topk_c2048": _dsa_topk,
+    "dots3_mla_chunk_prefill_c2048": lambda: _mla_flash(
+        "attn.mla_chunk_prefill", 128, 128, DOTS3_LANE),
+    "dots3_mla_window_c2048": lambda: _mla_flash(
+        "attn.mla_window", 64, 192, DOTS3_CHUNK + 512),
+    "dots3_moe_grouped_c2048": _moe_grouped,
     "moe_experts_olmoe_t64": lambda: _moe_experts(64),
     "moe_experts_olmoe_t128": lambda: _moe_experts(128),
     "moe_experts_olmoe_t512": lambda: _moe_experts(512),

@@ -73,10 +73,16 @@ def bench_adam(numel=50_000_000, iters=20):
             "effective_GB/s": round(gbps, 1)}
 
 
-def bench_flash_attention(b=4, s=2048, h=16, d=64, iters=20, bwd=False):
+def bench_flash_attention(b=2, s=2048, h=32, d=64, iters=20, bwd=False):
+    """Causal flash attention at the ``opt13b-sft-1chip`` cell's shape
+    (micro-batch 2, 32 heads of 64, sequence 2048).  Beside the time: the
+    (query, key) pairs the kernels EXECUTE over the pairs causal attention
+    needs, from the tile plan the kernels walk — a kernel's rate is only
+    as good as the work it is credited with."""
     import jax
     import jax.numpy as jnp
-    from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        flash_attention, tile_plan)
 
     rng = np.random.default_rng(0)
     q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.bfloat16)
@@ -91,20 +97,25 @@ def bench_flash_attention(b=4, s=2048, h=16, d=64, iters=20, bwd=False):
             # feed grads back in as next inputs: full data dependence
             return (dq.astype(jnp.bfloat16), dk.astype(jnp.bfloat16),
                     dv.astype(jnp.bfloat16))
-
-        dt = _timeit_chained(step, (q, k, v), iters)
     else:
         def step(carry):
             qq, kk, vv = carry
             out = flash_attention(qq, kk, vv, causal=True)
             return (out, kk, vv)
 
-        dt = _timeit_chained(step, (q, k, v), iters)
+    dt = _timeit_chained(step, (q, k, v), iters)
+    needed = s * (s + 1) / 2
+    executed = {}
+    for kernel in ("fwd", "dq", "dkv") if bwd else ("fwd",):
+        c = tile_plan(kernel, s, s, d, q.dtype, True).counts()
+        executed[kernel] = round(
+            c["tiles_run"] * c["tile_q"] * c["tile_k"] / needed, 3)
     # causal attention flops: 2 gemms, half the square
     flops = (2 * 2 * b * h * s * s * d) / 2 * (3.5 if bwd else 1)
     return {"op": f"flash_attention_{'bwd' if bwd else 'fwd'}",
             "shape": [b, s, h, d], "ms": round(dt * 1e3, 3),
-            "TFLOP/s": round(flops / dt / 1e12, 2)}
+            "TFLOP/s": round(flops / dt / 1e12, 2),
+            "pairs_executed_over_needed": executed}
 
 
 def bench_quantizer(numel=64 * 1024 * 1024, bits=8, iters=20):

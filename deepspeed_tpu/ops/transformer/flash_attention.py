@@ -11,13 +11,19 @@ Layout: inputs [B, S, H, D] (model-native); kernel operates in [B, H, S, D].
 GQA is handled in the BlockSpec index maps (kv head = h * KVH // H) — no
 jnp.repeat materialization.
 
-Causal masking skips fully-masked KV blocks via ``pl.when`` predication.
-The backward pass uses the saved LSE (log-sum-exp) rows, with two kernels:
-one accumulating dq over kv blocks, one accumulating (dk, dv) over q blocks —
-the standard flash-attention-2 decomposition.
+Each kernel keeps one block of its own axis resident and walks the other
+axis itself: an in-kernel loop over square tiles that stops at the causal
+diagonal, masks only the tiles the diagonal crosses (and a ragged tail), and
+carries the running statistics as values (:func:`tile_plan` decides the
+tiles and is where the loop bounds come from).  The backward pass uses the
+saved LSE (log-sum-exp) rows, with two kernels: one accumulating dq over key
+tiles, one accumulating (dk, dv) over query tiles — the standard
+flash-attention-2 decomposition.
 """
 
 import functools
+import os as _os
+from typing import NamedTuple
 
 import numpy as np
 
@@ -26,15 +32,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-import os as _os
+from deepspeed_tpu.monitor.trace import span
 
-# tuned on v5e at seq 2048/head_dim 64: large kv blocks amortize the
-# VPU-bound online-softmax bookkeeping; q=512 beats 256 and 1024 on the
-# OPT-1.3B train workload (larger bwd blocks overflow scoped vmem)
-DEFAULT_BLOCK_Q = int(_os.environ.get("DSTPU_FLASH_BLOCK_Q", "512"))
-DEFAULT_BLOCK_K = int(_os.environ.get("DSTPU_FLASH_BLOCK_K", "2048"))
-DEFAULT_BLOCK_Q_BWD = int(_os.environ.get("DSTPU_FLASH_BLOCK_Q_BWD", "1024"))
-DEFAULT_BLOCK_K_BWD = int(_os.environ.get("DSTPU_FLASH_BLOCK_K_BWD", "1024"))
+
+def _env_block(name):
+    v = _os.environ.get(name)
+    return int(v) if v else None
+
+
+# The GRID's blocks.  Each kernel keeps one block of its own axis resident
+# (a query block in ``attn.flash_fwd`` / ``attn.flash_dq``, a key block in
+# ``attn.flash_dkv``) and is handed the opposite axis in MAJOR blocks, inside
+# which it walks the causal triangle itself (:func:`tile_plan`).  Skipping
+# the masked half with the GRID does not pay on a v5e: every step along the
+# walked axis is another softmax pass (two lane reductions a row, a rescale
+# of the accumulator, the statistics' stores), and at 512 x 512 grid blocks
+# the forward took 1.74 ms where it took 1.21 at 512 x 2048 with nothing
+# skipped (PERF.md §6, PR 32).  So the major block is a whole head's K and V
+# at the lengths trained on.  Unset, :func:`tile_plan` picks per kernel.
+DEFAULT_BLOCK_Q = _env_block("DSTPU_FLASH_BLOCK_Q")
+DEFAULT_BLOCK_K = _env_block("DSTPU_FLASH_BLOCK_K")
+DEFAULT_BLOCK_Q_BWD = _env_block("DSTPU_FLASH_BLOCK_Q_BWD")
+DEFAULT_BLOCK_K_BWD = _env_block("DSTPU_FLASH_BLOCK_K_BWD")
 NEG_INF = -1e30
 # LSE/delta row vectors carry a small broadcast trailing dim: Mosaic requires
 # the last block dim be 128-divisible OR equal to the full array dim, so an
@@ -56,140 +75,328 @@ def pallas_supported():
 
 
 # --------------------------------------------------------------------- #
+# The tile plan
+# --------------------------------------------------------------------- #
+# The side of the square score tile the kernels walk, and the most rows of
+# the walked axis one grid step holds in VMEM.
+_TILE = 512
+_MAJOR = 2048
+
+
+class TilePlan(NamedTuple):
+    """How one kernel covers the ``[q_len, kv_len]`` score square: the
+    resident block of its own axis against ``tile_q x tile_k`` tiles of the
+    walked axis' major block.  The kernels take their walks' bounds from
+    :meth:`segments`; :meth:`counts` sums the same bounds over the grid."""
+    kernel: str                 # "fwd" | "dq" | "dkv"
+    tile_q: int
+    tile_k: int
+    block_q: int                # grid blocks: the resident one is its tile
+    block_k: int
+    q_len: int
+    kv_len: int
+    causal: bool
+
+    @property
+    def walks_q(self):
+        return self.kernel == "dkv"
+
+    @property
+    def _axes(self):
+        """(tile, major block, length) of the walked axis, then the
+        resident tile and length."""
+        if self.walks_q:
+            return (self.tile_q, self.block_q, self.q_len,
+                    self.tile_k, self.kv_len)
+        return (self.tile_k, self.block_k, self.kv_len,
+                self.tile_q, self.q_len)
+
+    @property
+    def n_resident(self):
+        _, _, _, res_tile, res_len = self._axes
+        return pl.cdiv(res_len, res_tile)
+
+    @property
+    def n_major(self):
+        _, major, length, _, _ = self._axes
+        return pl.cdiv(length, major)
+
+    @property
+    def ragged(self):
+        """The walked axis ends inside a tile: that tile masks its tail."""
+        tile, _, length, _, _ = self._axes
+        return length % tile != 0
+
+    def segments(self, i_res, i_major, xp=jnp):
+        """``[(lo, hi, masked), ...]``: the runs of tiles of major block
+        ``i_major`` that resident block ``i_res`` folds in, in order.
+        Tiles the causal diagonal crosses and a ragged tail tile are
+        ``masked``; tiles past the diagonal are in no run.  Indices may be
+        traced (``xp=jnp``, inside a kernel) or plain ints (``xp=np``)."""
+        tile, major, length, res_tile, _ = self._axes
+        n = major // tile
+        base = i_major * major
+        full = xp.clip((length - base) // tile, 0, n)       # wholly valid
+        valid = xp.clip(-((base - length) // tile), 0, n)   # any row valid
+        if not self.causal:
+            tail = [(full, valid, True)] if self.ragged else []
+            return [(0, full, False)] + tail
+        first, last = i_res * res_tile, i_res * res_tile + res_tile - 1
+        if not self.walks_q:
+            # key tile j is seen if its first column <= the last query row,
+            # and needs no mask if its last column <= the first query row
+            seen = xp.clip(-((base - last - 1) // tile), 0, valid)
+            under = xp.clip((first + 1 - base) // tile, 0,
+                            xp.minimum(full, seen))
+            return [(0, under, False), (under, seen, True)]
+        # query tile j sees the key block if its last row >= the first key,
+        # and needs no mask if its first row >= the last key
+        lo = xp.clip((first - base) // tile, 0, valid)
+        clear = -((base - last) // tile)
+        diag_end = xp.clip(clear, lo, valid)
+        segs = [(lo, diag_end, True), (xp.clip(clear, lo, full), full, False)]
+        if self.ragged:
+            segs.append((xp.maximum(full, diag_end), valid, True))
+        return segs
+
+    def _grid_walks(self):
+        """:meth:`segments` of every grid step of a head, as plain ints."""
+        return [tuple((int(lo), int(hi), m)
+                      for lo, hi, m in self.segments(i, im, xp=np))
+                for i in range(self.n_resident) for im in range(self.n_major)]
+
+    def walks(self):
+        """The distinct walks: what the kernels specialise their bodies
+        on."""
+        return sorted(set(self._grid_walks()))
+
+    def counts(self):
+        """The plan event's fields, a head: tiles the kernel runs, those of
+        them that pay the mask, and the tiles of the whole square."""
+        runs = [(max(0, hi - lo), m)
+                for walk in self._grid_walks() for lo, hi, m in walk]
+        return {"tile_q": self.tile_q, "tile_k": self.tile_k,
+                "tiles_run": sum(n for n, _ in runs),
+                "tiles_masked": sum(n for n, m in runs if m),
+                "tiles_square": pl.cdiv(self.q_len, self.tile_q)
+                * pl.cdiv(self.kv_len, self.tile_k)}
+
+
+def tile_plan(kernel, q_len, kv_len, head_dim, dtype, causal,
+              block_q=None, block_k=None):
+    """The one place the tiling is decided, from what the call sees.
+    ``block_q`` / ``block_k`` are the grid's blocks where the caller (or a
+    ``DSTPU_FLASH_BLOCK_*`` variable) fixes them: the resident axis' block
+    is that kernel's tile, the walked axis' block the major block."""
+    walks_q = kernel == "dkv"
+    res_blk, walk_blk = (block_k, block_q) if walks_q else (block_q, block_k)
+    res_len, walk_len = (kv_len, q_len) if walks_q else (q_len, kv_len)
+    # two walked operands, double-buffered, stay within ~4 MB of VMEM
+    row_bytes = head_dim * jnp.dtype(dtype).itemsize
+    major = min(walk_blk or (_MAJOR if row_bytes <= 512 else _MAJOR // 2),
+                walk_len)
+    res = min(res_blk or _TILE, res_len)
+    # the largest tile that divides the major block; else the block whole
+    tile = next((t for t in (_TILE, _TILE // 2, _TILE // 4)
+                 if major % t == 0), major)
+    tile_q, tile_k = (tile, res) if walks_q else (res, tile)
+    block_q, block_k = (major, res) if walks_q else (res, major)
+    return TilePlan(kernel, tile_q, tile_k, block_q, block_k,
+                    q_len, kv_len, bool(causal))
+
+
+def _planned_call(plan, name, kernel, **kw):
+    """``pl.pallas_call`` under the plan's event: one
+    ``dstpu.kernel.tile_plan`` span a traced call, the counts as its args."""
+    call = pl.pallas_call(
+        kernel, name=name, interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        **kw)
+
+    def run(*operands):
+        with span("dstpu.kernel.tile_plan", kernel=name, **plan.counts()):
+            return call(*operands)
+    return run
+
+
+def _for_each_walk(plan, i_res, i_major, fold, nothing=None):
+    """Run ``fold(first, pieces)`` for THIS grid step's walk: tiles
+    ``first ..`` of the major block as one slab, ``pieces`` its runs
+    ``(a, b, masked)`` in tiles from ``first`` — or ``nothing()`` where
+    the block lies wholly past the diagonal.  A walk's bounds depend on
+    the grid step, a slab's width must not: every distinct walk of the
+    plan (a handful — the diagonal's position inside a major block) is
+    traced as its own straight-line body and the step's own is picked by
+    its bounds.  One softmax pass (or one set of matmuls) a slab is why a
+    slab and not a ``fori_loop`` over its tiles: a trip of such a loop
+    pays two lane reductions a row and a rescale of the accumulator for
+    512 columns, and the forward measured 1.45x this one (PERF.md §6,
+    PR 32)."""
+    mine = plan.segments(i_res, i_major)
+    for walk in plan.walks():
+        runs = sorted((lo, hi, m) for lo, hi, m in walk if hi > lo)
+        hit = functools.reduce(
+            jnp.logical_and,
+            [c for (lo, hi, _), (wlo, whi, _) in zip(mine, walk)
+             for c in (lo == wlo, hi == whi)])
+        if runs:
+            first = runs[0][0]
+            pieces = [(lo - first, hi - first, m) for lo, hi, m in runs]
+            pl.when(hit)(functools.partial(fold, first, pieces))
+        elif nothing is not None:
+            pl.when(hit)(nothing)
+
+
+def _slab_rows(plan, ref, base, first, pieces):
+    """Rows ``first .. first + width`` (in tiles) of a walked operand's
+    major block, whose first row is position ``base``.  Rows past the
+    axis' length are out-of-bounds reads — undefined, and garbage x
+    0-probability still poisons a matmul with NaN — so a ragged plan's
+    tail run zeroes them."""
+    tile, _, length, _, _ = plan._axes
+    x = ref[0, 0, first * tile:(first + pieces[-1][1]) * tile, :]
+    if plan.ragged and pieces[-1][2]:
+        pos = base + first * tile + jax.lax.broadcasted_iota(
+            jnp.int32, (x.shape[0], 1), 0)
+        x = jnp.where(pos < length, x, jnp.zeros_like(x))
+    return x
+
+
+def _mask_runs(plan, x, pieces, res0, walk0, fill):
+    """``x``: a slab's scores, the resident block's positions (from
+    ``res0``) down the rows and the walked axis' (from ``walk0``) along
+    the lanes.  The runs the diagonal crosses (and a ragged tail) are
+    masked to ``fill``; the others pass untouched — no iota, no compare,
+    no select."""
+    tile, _, length, _, _ = plan._axes
+    res = res0 + jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
+    parts = []
+    for a, b, masked in pieces:
+        part = x[:, a * tile:b * tile] if len(pieces) > 1 else x
+        if masked:
+            walk = walk0 + a * tile + jax.lax.broadcasted_iota(
+                jnp.int32, (1, part.shape[1]), 1)
+            tail = walk < length
+            # causal is top-left aligned: query i sees keys <= i
+            qpos, kpos = (walk, res) if plan.walks_q else (res, walk)
+            mask = qpos >= kpos if plan.causal else tail
+            if plan.causal and plan.ragged:
+                mask = mask & tail
+            part = jnp.where(mask, part, fill)
+        parts.append(part)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _dot(a, b, contract):
+    # operands stay bf16 — the MXU accumulates in fp32 via
+    # preferred_element_type; casting inputs to fp32 would halve matmul
+    # throughput
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))          # a @ b.T
+_NN = ((1,), (0,))          # a @ b
+
+
+def _scaled(x, scale):
+    return x if scale == 1.0 else x * scale   # 1.0: folded into q outside
+
+
+# --------------------------------------------------------------------- #
 # Forward
 # --------------------------------------------------------------------- #
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, block_q, block_k, causal, nk, kv_len):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scr, scale, plan):
+    iq, ik = pl.program_id(1), pl.program_id(2)
+    base = ik * plan.block_k
 
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # block classification: interior blocks (fully inside the causal
-    # triangle and inside the sequence) skip all mask/iota VPU work — with
-    # online softmax that work is a large share of kernel time at small D
-    even_kv = kv_len % block_k == 0
-    run = (not causal) or (ik * block_k <= iq * block_q + block_q - 1)
-    diag = causal and (ik * block_k + block_k > iq * block_q)
-    needs_mask = diag if even_kv else True
-
-    def _softmax_update(s, v):
-        m_prev = m_scr[:, 0:1]                        # [bq, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                        # [bq, bk] f32
-        corr = jnp.exp(m_prev - m_new)                # [bq, 1]
-        l_new = l_scr[:, 0:1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(run & jnp.logical_not(needs_mask))
-    def _interior():
-        # operands stay bf16 — the MXU accumulates in fp32 via
-        # preferred_element_type; casting inputs to fp32 would halve
-        # matmul throughput
-        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if scale != 1.0:        # scale is folded into q by the wrapper
-            s = s * scale
-        _softmax_update(s, v_ref[0, 0])
-
-    @pl.when(run & needs_mask)
-    def _masked():
-        q = q_ref[0, 0]                              # [bq, d]
-        k = k_ref[0, 0]                              # [bk, d]
-        v = v_ref[0, 0]                              # [bk, d]
-        if not even_kv:
-            # zero padded tail rows: OOB block reads are undefined, and
-            # garbage * 0-probability still poisons the matmul with NaN
-            kv_rows = ik * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                              (block_k, 1), 0)
-            valid_kv = kv_rows < kv_len
-            k = jnp.where(valid_kv, k, jnp.zeros_like(k))
-            v = jnp.where(valid_kv, v, jnp.zeros_like(v))
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if scale != 1.0:
-            s = s * scale
-        cols = ik * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                       (block_q, block_k), 1)
-        if even_kv:
-            # only diagonal blocks reach here — causal mask alone
-            rows = iq * block_q + jax.lax.broadcasted_iota(jnp.int32,
-                                                           (block_q, block_k), 0)
-            mask = rows >= cols
-        else:
-            mask = cols < kv_len       # tail-block padding
-            if causal:
-                rows = iq * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                mask = mask & (rows >= cols)
-        s = jnp.where(mask, s, NEG_INF)
-        _softmax_update(s, v)
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        l = l_scr[:, 0:1]
+    def finish(m, l, acc):
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
-        # LSE rides a 128-lane trailing dim: Mosaic requires output block
-        # shapes tiled (8, 128) on the last two dims, so a [block_q]-shaped
-        # row per (b, h) cannot be written directly
-        lse_ref[0, 0] = jnp.broadcast_to(m_scr[:, 0:1] + jnp.log(safe_l),
+        o_ref[0, 0] = (acc / safe_l).astype(o_ref.dtype)
+        # Mosaic wants output blocks tiled on the last two dims, so the
+        # LSE row rides a small broadcast trailing dim
+        lse_ref[0, 0] = jnp.broadcast_to(m + jnp.log(safe_l),
                                          lse_ref.shape[2:])
+
+    def fold(first, pieces):
+        k = _slab_rows(plan, k_ref, base, first, pieces)
+        v = _slab_rows(plan, v_ref, base, first, pieces)
+        s = _scaled(_dot(q_ref[0, 0], k, _NT), scale)     # [tq, w] f32
+        s = _mask_runs(plan, s, pieces, iq * plan.tile_q,
+                       base + first * plan.tile_k, NEG_INF)
+        m = jnp.max(s, axis=1, keepdims=True)
+        if scr:
+            m_prev = m_scr[:, 0:1]
+            m = jnp.maximum(m_prev, m)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=1, keepdims=True)
+        acc = _dot(p.astype(v.dtype), v, _NN)
+        if not scr:
+            return finish(m, l, acc)
+        corr = jnp.exp(m_prev - m)
+        m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_scr[:, 0:1] * corr + l, l_scr.shape)
+        acc_scr[:] = acc_scr[:] * corr + acc
+
+    # a single major block is one softmax pass; more of them merge online
+    # through scratch, one rescale a block
+    if scr:
+        m_scr, l_scr, acc_scr = scr
+
+        @pl.when(ik == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    _for_each_walk(plan, iq, ik, fold)
+
+    if scr:
+        @pl.when(ik == plan.n_major - 1)
+        def _finish():
+            finish(m_scr[:, 0:1], l_scr[:, 0:1], acc_scr[:])
+
+
+def _index_maps(H, KVH, walks_q=False):
+    """(own-head map of the query axis, kv-head map of the key axis) for a
+    ``(B*H, resident, major)`` grid; GQA lives here (kv head =
+    h * KVH // H)."""
+    def q_map(bh, i, j):
+        return (bh // H, bh % H, j if walks_q else i, 0)
+
+    def kv_map(bh, i, j):
+        return (bh // H, (bh % H) * KVH // H, i if walks_q else j, 0)
+    return q_map, kv_map
 
 
 def _fwd(q, k, v, scale, causal, block_q, block_k):
     B, H, S, D = q.shape
     KVH, Sk = k.shape[1], k.shape[2]
-    block_q = min(block_q, S)
-    block_k = min(block_k, Sk)
-    nq = pl.cdiv(S, block_q)
-    nk = pl.cdiv(Sk, block_k)
-    grid = (B * H, nq, nk)
-
-    def q_map(bh, iq, ik):
-        return (bh // H, bh % H, iq, 0)
-
-    def kv_map(bh, iq, ik):
-        return (bh // H, (bh % H) * KVH // H, ik, 0)
-
-    kernel = functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
-                               block_k=block_k, causal=causal, nk=nk, kv_len=Sk)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+    plan = tile_plan("fwd", S, Sk, D, q.dtype, causal, block_q, block_k)
+    q_map, kv_map = _index_maps(H, KVH)
+    merged = [] if plan.n_major == 1 else [
+        pltpu.VMEM((plan.block_q, 128), jnp.float32),
+        pltpu.VMEM((plan.block_q, 128), jnp.float32),
+        pltpu.VMEM((plan.block_q, D), jnp.float32)]
+    out, lse = _planned_call(
+        plan, "attn.flash_fwd",
+        functools.partial(_fwd_kernel, scale=scale, plan=plan),
+        grid=(B * H, plan.n_resident, plan.n_major),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), q_map),
-            pl.BlockSpec((1, 1, block_k, D), kv_map),
-            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec((1, 1, plan.block_q, D), q_map),
+            pl.BlockSpec((1, 1, plan.block_k, D), kv_map),
+            pl.BlockSpec((1, 1, plan.block_k, D), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), q_map),
-            pl.BlockSpec((1, 1, block_q, LSE_LANES),
-                         lambda bh, iq, ik: (bh // H, bh % H, iq, 0)),
+            pl.BlockSpec((1, 1, plan.block_q, D), q_map),
+            pl.BlockSpec((1, 1, plan.block_q, LSE_LANES), q_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, LSE_LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-        name="attn.flash_fwd",
+        scratch_shapes=merged,
     )(q, k, v)
     return out, lse
 
@@ -197,257 +404,133 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
 # --------------------------------------------------------------------- #
 # Backward
 # --------------------------------------------------------------------- #
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, scale, block_q, block_k, causal, nk, kv_len):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+def _accumulate(plan, i_res, i_major, refs, scr, fold):
+    """The backward kernels' sums over major blocks: with one block a walk
+    writes its result; with more, scratch adds them up (a block past the
+    diagonal adds nothing) and the last grid step writes."""
+    if not scr:
+        def write(first, pieces):
+            for ref, x in zip(refs, fold(first, pieces)):
+                ref[0, 0] = x.astype(ref.dtype)
 
-    @pl.when(ik == 0)
+        def zero():
+            for ref in refs:
+                ref[0, 0] = jnp.zeros(ref.shape[2:], ref.dtype)
+        return _for_each_walk(plan, i_res, i_major, write, zero)
+
+    @pl.when(i_major == 0)
     def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+        for acc in scr:
+            acc[:] = jnp.zeros_like(acc)
 
-    even_kv = kv_len % block_k == 0
-    run = (not causal) or (ik * block_k <= iq * block_q + block_q - 1)
-    diag = causal and (ik * block_k + block_k > iq * block_q)
-    needs_mask = diag if even_kv else True
+    def add(first, pieces):
+        for acc, x in zip(scr, fold(first, pieces)):
+            acc[:] += x
+    _for_each_walk(plan, i_res, i_major, add)
 
-    def _accum(p, do, v, k, delta):
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        if scale != 1.0:
-            ds = ds * scale
-        ds = ds.astype(k.dtype)
-        dq_scr[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-
-    @pl.when(run & jnp.logical_not(needs_mask))
-    def _interior():
-        lse = lse_ref[0, 0][:, 0:1]                  # [bq, 1]
-        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if scale != 1.0:        # scale is folded into q by the wrapper
-            s = s * scale
-        p = jnp.exp(s - lse)                          # [bq, bk]
-        _accum(p, do_ref[0, 0], v_ref[0, 0], k_ref[0, 0],
-               delta_ref[0, 0][:, 0:1])
-
-    @pl.when(run & needs_mask)
-    def _masked():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, 0:1]                  # [bq, 1]
-        delta = delta_ref[0, 0][:, 0:1]              # [bq, 1]
-        if not even_kv:
-            kv_rows = ik * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                              (block_k, 1), 0)
-            valid_kv = kv_rows < kv_len
-            k = jnp.where(valid_kv, k, jnp.zeros_like(k))
-            v = jnp.where(valid_kv, v, jnp.zeros_like(v))
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if scale != 1.0:
-            s = s * scale
-        cols = ik * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                       (block_q, block_k), 1)
-        if even_kv:
-            rows = iq * block_q + jax.lax.broadcasted_iota(jnp.int32,
-                                                           (block_q, block_k), 0)
-            mask = rows >= cols
-        else:
-            mask = cols < kv_len
-            if causal:
-                rows = iq * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                mask = mask & (rows >= cols)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)    # [bq, bk]
-        _accum(p, do, v, k, delta)
-
-    @pl.when(ik == nk - 1)
+    @pl.when(i_major == plan.n_major - 1)
     def _finish():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        for ref, acc in zip(refs, scr):
+            ref[0, 0] = acc[:].astype(ref.dtype)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   *scr, scale, plan):
+    iq, ik = pl.program_id(1), pl.program_id(2)
+    base = ik * plan.block_k
+
+    def fold(first, pieces):
+        k = _slab_rows(plan, k_ref, base, first, pieces)
+        v = _slab_rows(plan, v_ref, base, first, pieces)
+        s = _scaled(_dot(q_ref[0, 0], k, _NT), scale)     # [tq, w]
+        p = jnp.exp(s - lse_ref[0, 0][:, 0:1])
+        p = _mask_runs(plan, p, pieces, iq * plan.tile_q,
+                       base + first * plan.tile_k, 0.0)
+        dp = _dot(do_ref[0, 0], v, _NT)
+        ds = _scaled(p * (dp - delta_ref[0, 0][:, 0:1]), scale)
+        return [_dot(ds.astype(k.dtype), k, _NN)]
+
+    _accumulate(plan, iq, ik, [dq_ref], scr, fold)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, block_q, block_k, causal, nq, q_len):
-    ik = pl.program_id(1)
-    iq = pl.program_id(2)
+                    dk_ref, dv_ref, *scr, scale, plan):
+    """The mirror: a key block against the query tiles from its diagonal
+    on.  Scores are held TRANSPOSED, ``[keys, queries]``: ``p.T @ do`` and
+    ``ds.T @ q`` are then plain matmuls (no transposed operand), and the
+    per-query ``lse`` / ``delta`` are row vectors that broadcast along
+    sublanes."""
+    ik, iq = pl.program_id(1), pl.program_id(2)
+    base = iq * plan.block_q
 
-    @pl.when(iq == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+    def fold(first, pieces):
+        k, v = k_ref[0, 0], v_ref[0, 0]                   # [tk, d]
+        q = _slab_rows(plan, q_ref, base, first, pieces)  # [w, d]
+        do = _slab_rows(plan, do_ref, base, first, pieces)
+        lanes = slice(first * plan.tile_q,
+                      (first + pieces[-1][1]) * plan.tile_q)
+        lse, delta = lse_ref[0, 0, :, lanes], delta_ref[0, 0, :, lanes]
+        if plan.ragged and pieces[-1][2]:
+            # past the last query these too are out-of-bounds reads, and
+            # 0 x garbage must stay finite
+            live = base + lanes.start + jax.lax.broadcasted_iota(
+                jnp.int32, lse.shape, 1) < plan.q_len
+            lse, delta = jnp.where(live, lse, 0.0), jnp.where(live, delta, 0.0)
+        st = _scaled(_dot(k, q, _NT), scale)              # [tk, w]
+        pt = _mask_runs(plan, jnp.exp(st - lse), pieces, ik * plan.tile_k,
+                        base + first * plan.tile_q, 0.0)
+        dv = _dot(pt.astype(do.dtype), do, _NN)
+        dst = _scaled(pt * (_dot(v, do, _NT) - delta), scale)
+        return [_dot(dst.astype(q.dtype), q, _NN), dv]
 
-    even_q = q_len % block_q == 0
-    run = (not causal) or (iq * block_q + block_q - 1 >= ik * block_k)
-    diag = causal and (iq * block_q < ik * block_k + block_k)
-    needs_mask = diag if even_q else True
-
-    def _accum(p, q, v, do, delta):
-        dv_scr[:] += jax.lax.dot_general(p.astype(do.dtype), do,
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)                         # [bq, bk]
-        if scale != 1.0:
-            ds = ds * scale
-        ds = ds.astype(q.dtype)
-        dk_scr[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-
-    @pl.when(run & jnp.logical_not(needs_mask))
-    def _interior():
-        lse = lse_ref[0, 0][:, 0:1]
-        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if scale != 1.0:        # scale is folded into q by the wrapper
-            s = s * scale
-        p = jnp.exp(s - lse)
-        _accum(p, q_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
-               delta_ref[0, 0][:, 0:1])
-
-    @pl.when(run & needs_mask)
-    def _masked():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, 0:1]
-        delta = delta_ref[0, 0][:, 0:1]
-        if not even_q:
-            q_rows = iq * block_q + jax.lax.broadcasted_iota(jnp.int32,
-                                                             (block_q, 1), 0)
-            valid_q = q_rows < q_len
-            q = jnp.where(valid_q, q, jnp.zeros_like(q))
-            do = jnp.where(valid_q, do, jnp.zeros_like(do))
-            # delta/lse of padded rows are OOB reads; 0*garbage must stay
-            # finite
-            delta = jnp.where(valid_q, delta, 0.0)
-            lse = jnp.where(valid_q, lse, 0.0)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if scale != 1.0:
-            s = s * scale
-        rows = iq * block_q + jax.lax.broadcasted_iota(jnp.int32,
-                                                       (block_q, block_k), 0)
-        if even_q:
-            cols = ik * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                           (block_q, block_k), 1)
-            mask = rows >= cols
-        else:
-            mask = rows < q_len
-            if causal:
-                cols = ik * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                mask = mask & (rows >= cols)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)    # [bq, bk]
-        _accum(p, q, v, do, delta)
-
-    @pl.when(iq == nq - 1)
-    def _finish():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+    _accumulate(plan, ik, iq, [dk_ref, dv_ref], scr, fold)
 
 
 def _bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd, res, do):
     q, k, v, out, lse = res
     B, H, S, D = q.shape
     KVH, Sk = k.shape[1], k.shape[2]
-    block_q = min(block_q_bwd, S)
-    block_k = min(block_k_bwd, Sk)
-    nq = pl.cdiv(S, block_q)
-    nk = pl.cdiv(Sk, block_k)
 
-    delta = jnp.broadcast_to(
-        jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                axis=-1)[..., None],
-        lse.shape)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    # per query row, twice: a column for the kernel that holds queries
+    # resident, a lane-dense row for the one that walks them
+    cols = lse, jnp.broadcast_to(delta[..., None], lse.shape)
+    rows = lse[..., 0][:, :, None, :], delta[:, :, None, :]
 
-    def q_map(bh, iq, ik):
-        return (bh // H, bh % H, iq, 0)
+    def call(kind, name, kernel, n_out):
+        plan = tile_plan(kind, S, Sk, D, q.dtype, causal,
+                         block_q_bwd, block_k_bwd)
+        q_map, kv_map = _index_maps(H, KVH, plan.walks_q)
+        q_spec = pl.BlockSpec((1, 1, plan.block_q, D), q_map)
+        kv_spec = pl.BlockSpec((1, 1, plan.block_k, D), kv_map)
+        if plan.walks_q:
+            # dk/dv per QUERY head (reduced over the group below): the
+            # key axis' map with KVH = H
+            out_map, res_rows = _index_maps(H, H, True)[1], plan.block_k
+            stats = rows
+            stat_spec = pl.BlockSpec(
+                (1, 1, 1, plan.block_q),
+                lambda bh, i, j: (bh // H, bh % H, 0, j))
+        else:
+            out_map, res_rows, stats = q_map, plan.block_q, cols
+            stat_spec = pl.BlockSpec((1, 1, plan.block_q, LSE_LANES), q_map)
+        return _planned_call(
+            plan, name, functools.partial(kernel, scale=scale, plan=plan),
+            grid=(B * H, plan.n_resident, plan.n_major),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+            out_specs=[pl.BlockSpec((1, 1, res_rows, D), out_map)] * n_out,
+            out_shape=[jax.ShapeDtypeStruct(
+                (B, H, Sk if plan.walks_q else S, D), q.dtype)] * n_out,
+            scratch_shapes=[] if plan.n_major == 1 else
+            [pltpu.VMEM((res_rows, D), jnp.float32)] * n_out,
+        )(q, k, v, do, *stats)
 
-    def kv_map(bh, iq, ik):
-        return (bh // H, (bh % H) * KVH // H, ik, 0)
-
-    def lse_map(bh, iq, ik):
-        return (bh // H, bh % H, iq, 0)
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, causal=causal, nk=nk, kv_len=Sk),
-        grid=(B * H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), q_map),
-            pl.BlockSpec((1, 1, block_k, D), kv_map),
-            pl.BlockSpec((1, 1, block_k, D), kv_map),
-            pl.BlockSpec((1, 1, block_q, D), q_map),
-            pl.BlockSpec((1, 1, block_q, LSE_LANES), lse_map),
-            pl.BlockSpec((1, 1, block_q, LSE_LANES), lse_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D), q_map),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-        name="attn.flash_dq",
-    )(q, k, v, do, lse, delta)
-
-    # dk/dv computed per (b, h) then reduced over the query-head group for GQA
-    def kv_out_map(bh, ik, iq):
-        return (bh // H, bh % H, ik, 0)
-
-    def q_map2(bh, ik, iq):
-        return (bh // H, bh % H, iq, 0)
-
-    def kv_map2(bh, ik, iq):
-        return (bh // H, (bh % H) * KVH // H, ik, 0)
-
-    def lse_map2(bh, ik, iq):
-        return (bh // H, bh % H, iq, 0)
-
-    dk_full, dv_full = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, causal=causal, nq=nq, q_len=S),
-        grid=(B * H, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), q_map2),
-            pl.BlockSpec((1, 1, block_k, D), kv_map2),
-            pl.BlockSpec((1, 1, block_k, D), kv_map2),
-            pl.BlockSpec((1, 1, block_q, D), q_map2),
-            pl.BlockSpec((1, 1, block_q, LSE_LANES), lse_map2),
-            pl.BlockSpec((1, 1, block_q, LSE_LANES), lse_map2),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, D), kv_out_map),
-            pl.BlockSpec((1, 1, block_k, D), kv_out_map),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, Sk, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sk, D), q.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-        name="attn.flash_dkv",
-    )(q, k, v, do, lse, delta)
-
+    dq, = call("dq", "attn.flash_dq", _bwd_dq_kernel, 1)
+    dk, dv = call("dkv", "attn.flash_dkv", _bwd_dkv_kernel, 2)
     if KVH != H:
         rep = H // KVH
-        dk = dk_full.reshape(B, KVH, rep, Sk, D).sum(axis=2)
-        dv = dv_full.reshape(B, KVH, rep, Sk, D).sum(axis=2)
-    else:
-        dk, dv = dk_full, dv_full
+        dk = dk.reshape(B, KVH, rep, Sk, D).sum(axis=2)
+        dv = dv.reshape(B, KVH, rep, Sk, D).sum(axis=2)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -484,9 +567,9 @@ def flash_attention(q, k, v, causal=True, scale=None,
     """Flash attention on [B, S, H, D] tensors (model-native layout).
 
     ``k``/``v`` may have fewer heads (GQA).  Returns [B, S, H, D].
-    The backward kernels tile independently (their accumulators iterate the
-    opposite grid dim; v5e sweep favors 1024x1024 there): ``block_q_bwd`` /
-    ``block_k_bwd`` default from DSTPU_FLASH_BLOCK_{Q,K}_BWD.
+    ``block_q`` / ``block_k`` are the forward grid's blocks, ``block_q_bwd``
+    / ``block_k_bwd`` the backward kernels' (default: DSTPU_FLASH_BLOCK_*);
+    left unset, :func:`tile_plan` picks them per kernel.
     """
     B, S, H, D = q.shape
     if scale is None:
@@ -510,8 +593,8 @@ def flash_attention(q, k, v, causal=True, scale=None,
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     out = _flash_bhsd(qt, kt, vt, kernel_scale, bool(causal),
-                      int(block_q), int(block_k),
-                      int(block_q_bwd), int(block_k_bwd))
+                      *(b and int(b) for b in (block_q, block_k,
+                                                block_q_bwd, block_k_bwd)))
     return out.transpose(0, 2, 1, 3)
 
 

@@ -68,11 +68,12 @@ def mosaic(monkeypatch, no_persistent_cache):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
-def _flash_fwd_bwd():
+def _flash_fwd_bwd(batch=2, seq=2048, heads=H, head_dim=D):
     def loss(q, k, v):
         out = flash_mod.flash_attention(q, k, v, causal=True)
         return out.astype(F32).sum()
-    return jax.grad(loss, argnums=(0, 1, 2)), [((2, 2048, H, D), BF16)] * 3
+    return (jax.grad(loss, argnums=(0, 1, 2)),
+            [((batch, seq, heads, head_dim), BF16)] * 3)
 
 
 def _paged_decode(quant, page=64, slots=8, cache_len=512, layers=L):
@@ -190,6 +191,10 @@ CASES = {
     "moe_experts_olmoe_t128": lambda: _moe_experts(128),
     "moe_experts_olmoe_t512": lambda: _moe_experts(512),
     "flash_fwd_bwd_s2048": _flash_fwd_bwd,
+    # opt67b-zero3-4chip's shard: micro-batch 4, 32 heads of 128
+    "flash_fwd_bwd_s2048_d128": lambda: _flash_fwd_bwd(4, head_dim=128),
+    # two major blocks: the walk's state crosses a grid step in scratch
+    "flash_fwd_bwd_s4096": lambda: _flash_fwd_bwd(1, seq=4096, heads=4),
     "paged_decode_bf16_fused_write_p64": lambda: _paged_decode(False),
     "paged_decode_int8kv_fused_write_p64": lambda: _paged_decode(True),
     # the two serving cells' tables: 32 slots x 22 pages, 24 x 29 (four

@@ -334,10 +334,10 @@ class ServingEngine:
             stats=self.stats)
         if self.stats.get("prefix_sharing_refused"):
             logger.warning(
-                f"serving.prefix_cache: {type(self.module).__name__} has "
-                f"window layers whose rows live in a per-slot ring — a "
-                f"shared prefix's pages would not carry them, so prefix "
-                f"sharing is OFF for this server "
+                f"serving.prefix_cache: {type(self.module).__name__} keeps "
+                f"cache state a slot owns (a window ring, a fixed-size "
+                f"state row) — a shared prefix's pages would not carry it, "
+                f"so prefix sharing is OFF for this server "
                 f"(stats['prefix_sharing_refused'])")
         self.page = self._pages.page
         self.num_pages = self._pages.num_pages

@@ -35,6 +35,11 @@ The pieces:
   asks it to back a slot (:meth:`SlotPages.reserve`), to free one
   (:meth:`SlotPages.release`) and for the table to ship
   (:meth:`SlotPages.table`), and holds no page arithmetic of its own.
+  Beside pages it owns a second KIND of state: for a model whose layers
+  keep a FIXED-SIZE state a slot and no row a position (``state_kinds``,
+  ``models/lfm2.py``'s short-convolution layers), one state ROW a slot in
+  a pool of ``1 + num_slots`` rows — row 0 the trash row, as page 0 is
+  the trash page — shipped as the LAST entry of the slot's table row.
 """
 
 import hashlib
@@ -300,13 +305,28 @@ class SlotPages:
             module, "window_ring_pages", lambda page: 0)(self.page))
         self.window_pages = 1 + self.num_slots * self.ring_pages \
             if self.ring_pages else 0
-        self.table_width = self.pages_per_slot + self.ring_pages
-        if self.ring_pages and self.share_prefixes:
-            # a shared prefix's pages hold the full layers' rows only: the
-            # sharer's rings would miss the window's rows before its
-            # first private position
+        # a model whose layers keep a fixed-size state a slot names the
+        # pools that hold it (``state_kinds``: keys of its cache, each
+        # ``[layers, state rows, ...]``): slot ``s`` owns row ``1 + s`` of
+        # them for as long as it is reserved, row 0 is the trash row, and
+        # the row's index is the LAST entry of the slot's table row — so
+        # whatever sends a dead lane's pages to the trash page sends its
+        # state writes to the trash row
+        self.state_kinds = tuple(getattr(module, "state_kinds", ()))
+        self.state_rows = 1 + self.num_slots if self.state_kinds else 0
+        self.state_row_bytes = 0         # known once the pools are made
+        self.page_bytes = 0
+        self.table_width = self.pages_per_slot + self.ring_pages \
+            + bool(self.state_kinds)
+        if (self.ring_pages or self.state_kinds) and self.share_prefixes:
+            # a shared prefix's pages hold the positional rows of the lane
+            # pools only: the sharer's rings would miss the window's rows
+            # before its first private position, and its state row the
+            # state at the shared boundary
             self.share_prefixes = False
             stats["prefix_sharing_refused"] = 1
+        if self.state_kinds:
+            stats.update(state_rows_live=0, state_bytes=0)
         # shipped as a traced arg on every dispatch; 0 = the trash page
         self._table = np.zeros((self.num_slots, self.table_width),
                                np.int32)
@@ -328,11 +348,21 @@ class SlotPages:
 
     def new_pools(self, dtype):
         """The model's zero pool(s) at this manager's sizes — one ``k`` /
-        ``v`` pair, or pools by row kind (``models/dots3.py``)."""
+        ``v`` pair, pools by row kind (``models/dots3.py``), or page pools
+        beside state pools (``models/lfm2.py``)."""
         kinds = {"window_pages": self.window_pages} if self.ring_pages \
             else {}
-        return self._module.init_paged_cache(self.num_pages, self.page,
-                                             dtype=dtype, **kinds)
+        if self.state_kinds:
+            kinds["state_rows"] = self.state_rows
+        pools = self._module.init_paged_cache(self.num_pages, self.page,
+                                              dtype=dtype, **kinds)
+        if self.state_kinds:
+            nbytes = lambda keys: sum(pools[k].size * pools[k].dtype.itemsize
+                                      for k in keys)
+            self.state_row_bytes = nbytes(self.state_kinds) // self.state_rows
+            self.page_bytes = nbytes(set(pools) - set(self.state_kinds)) \
+                // self.num_pages
+        return pools
 
     def give_back(self, pool):
         self._buffer = pool
@@ -411,8 +441,15 @@ class SlotPages:
         self._table[slot, :] = TRASH_PAGE
         self._table[slot, :len(row)] = row
         # the slot's own ring: pages 1 + slot * ring .. of the window pool
-        self._table[slot, self.pages_per_slot:] = \
+        ring_end = self.pages_per_slot + self.ring_pages
+        self._table[slot, self.pages_per_slot:ring_end] = \
             1 + int(slot) * self.ring_pages + np.arange(self.ring_pages)
+        if self.state_kinds:
+            # the slot's own state row; whatever its last occupant left
+            # there, the request's first chunk starts from zeros (it starts
+            # at position 0: prefix sharing is off)
+            self._table[slot, -1] = 1 + int(slot)
+            self._count_state()
         return row, s0
 
     def share(self, slot, fill):
@@ -435,6 +472,17 @@ class SlotPages:
         for pg in self._rows.pop(int(slot), ()):
             self._pool.decref(pg)
         self._table[int(slot), :] = TRASH_PAGE
+        self._count_state()
+
+    def _state_bytes(self):
+        return len(self._rows) * self.state_row_bytes
+
+    def _count_state(self):
+        """``stats``' levels of the state kind: rows that slots hold, and
+        their bytes."""
+        if self.state_kinds:
+            self._stats["state_rows_live"] = len(self._rows)
+            self._stats["state_bytes"] = self._state_bytes()
 
     def reset(self):
         """Drop EVERY mapping (pool bookkeeping, prefix index, all table
@@ -444,11 +492,13 @@ class SlotPages:
         self._pool.reset()
         self._table[:] = TRASH_PAGE
         self._rows.clear()
+        self._count_state()
 
     # ---- what the dispatches ship ----
     def table(self):
         """``[num_slots, table_width]`` int32, every slot's row: its lane
-        pages, then (a model with window layers) its ring pages."""
+        pages, then (a model with window layers) its ring pages, then (a
+        model with a fixed-size state a slot) its state row."""
         return self._table
 
     def row(self, slot):
@@ -475,6 +525,11 @@ class SlotPages:
                      f"{self._pool.in_use} pages, window rows "
                      f"{held * self.ring_pages}/{self.window_pages - 1} "
                      f"pages ({self.ring_pages} a slot, a ring)")
+        if self.state_kinds:
+            text += (f"; state ({', '.join(self.state_kinds)}): "
+                     f"state_rows_live {len(self._rows)}/"
+                     f"{self.state_rows - 1} (one a slot, row 0 trash), "
+                     f"state_bytes {self._state_bytes()}")
         return text
 
     def slot_pages_str(self, slot):
@@ -497,6 +552,7 @@ class SlotPages:
         work = getattr(self._module, "chunk_work", None)
         return {"kv_pages": layers * min(reach, self.pages_per_slot),
                 "kv_pages_table": layers * self.pages_per_slot,
+                **({"state_rows": 1} if self.state_kinds else {}),
                 **(work(end - self.chunk, min(end, live_end or end),
                         self.page, self.ring_pages) if work else {})}
 
@@ -515,4 +571,15 @@ class SlotPages:
                                 for i in range(steps)),
                 "kv_pages_table":
                     self.num_slots * self.pages_per_slot * block,
+                **(self._state_reach(live) if self.state_kinds else {}),
                 **(work(live, self.ring_pages) if work else {})}
+
+    def _state_reach(self, live):
+        """A decode block's state work and the cache's split, as span
+        args: ``state_rows`` — state rows read and written, one a live
+        slot and step —, ``state_bytes`` — the state rows slots hold — and
+        ``kv_bytes_mapped`` — the pages slots hold, over every pool the
+        page table indexes."""
+        return {"state_rows": sum(steps for _, steps in live),
+                "state_bytes": self._state_bytes(),
+                "kv_bytes_mapped": self._pool.in_use * self.page_bytes}

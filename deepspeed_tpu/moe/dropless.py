@@ -243,13 +243,15 @@ def experts(x, combine, counts, wg, wu, wd, act):
 # Sigmoid + bias routing, and the share of the experts a chip holds
 # --------------------------------------------------------------------- #
 def route_scored(x, gate_w, bias, k, renormalize=True, scaling=1.0,
-                 live=None):
+                 live=None, sum_eps=0.0):
     """``noaux_tc`` routing of ``x [T, M]`` through ``gate_w [M, E]``:
     float32 scores ``sigmoid(x @ gate_w)``, the ``k`` largest of
     ``score + bias`` chosen (ties to the lower index), gates the chosen
-    SCORES (not the biased ones) over their sum where ``renormalize``,
-    times ``scaling``.  Returns ``(choice [T, k] int32, gate [T, k]
-    float32)``; a token that is not ``live`` has gates 0 and choice -1."""
+    SCORES (not the biased ones) over their sum where ``renormalize``
+    (plus ``sum_eps``, a family's guard in that denominator: LFM2's
+    ``1e-6``), times ``scaling``.  Returns ``(choice [T, k] int32, gate
+    [T, k] float32)``; a token that is not ``live`` has gates 0 and
+    choice -1."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.matmul(
             x.astype(jnp.float32), gate_w.astype(jnp.float32),
@@ -257,7 +259,9 @@ def route_scored(x, gate_w, bias, k, renormalize=True, scaling=1.0,
         _, choice = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
         gate = jnp.take_along_axis(scores, choice, axis=1)
         if renormalize:
-            gate = gate / jnp.sum(gate, axis=1, keepdims=True)
+            total = jnp.sum(gate, axis=1, keepdims=True)
+            # no add at the default: the other families' HLO stays as it was
+            gate = gate / (total + sum_eps if sum_eps else total)
         gate = gate * scaling
         if live is not None:
             gate = jnp.where(live[:, None], gate, 0.0)

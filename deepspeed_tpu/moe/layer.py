@@ -112,9 +112,12 @@ class MoE(nn.Module):
     # every token takes, and ``held_experts=(first, count)`` — the chip's
     # share under expert parallelism: the router scores all
     # ``num_experts``, this layer holds and computes ``count`` of them
-    # and adds nothing for the absent ones (``moe/dropless.py``)
+    # and adds nothing for the absent ones (``moe/dropless.py``);
+    # ``gate_sum_eps`` is what a family adds to the chosen scores' sum
+    # before dividing by it (LFM2: 1e-6; 0 leaves the division as it is)
     scoring: str = "softmax"
     routed_scaling: float = 1.0
+    gate_sum_eps: float = 0.0
     shared_ffn_hidden_size: int = 0
     held_experts: Optional[tuple] = None
 
@@ -183,7 +186,8 @@ class MoE(nn.Module):
             tokens, gate_w, bias, self.k,
             renormalize=self.norm_topk_prob and self.k > 1,
             scaling=self.routed_scaling,
-            live=None if live is None else live.reshape(-1))
+            live=None if live is None else live.reshape(-1),
+            sum_eps=self.gate_sum_eps)
         local, counts, elsewhere = dropless.held_load(choice, first, held)
         if self.is_initializing() or tokens.shape[0] < dropless.GROUPED_MIN_ROWS:
             y = experts(tokens, routed=(

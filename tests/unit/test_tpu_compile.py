@@ -76,37 +76,39 @@ def _flash_fwd_bwd(batch=2, seq=2048, heads=H, head_dim=D):
             [((batch, seq, heads, head_dim), BF16)] * 3)
 
 
-def _paged_decode(quant, page=64, slots=8, cache_len=512, layers=L):
+def _paged_decode(quant, page=64, slots=8, cache_len=512, layers=L,
+                  kv_heads=H):
     pages_per_slot = cache_len // page
     n_pages = slots * pages_per_slot + 1
-    pool = ((layers, n_pages, page, HD), I8 if quant else BF16)
+    pool = ((layers, n_pages, page, kv_heads * D), I8 if quant else BF16)
     args = [((slots, H, D), BF16), pool, pool, ((slots,), I32),
-            ((slots, pages_per_slot), I32), ((slots, H, D), BF16),
-            ((slots, H, D), BF16)]
+            ((slots, pages_per_slot), I32), ((slots, kv_heads, D), BF16),
+            ((slots, kv_heads, D), BF16)]
     if quant:
-        args += [((L, n_pages, page, H), F32)] * 2
+        args += [((layers, n_pages, page, kv_heads), F32)] * 2
 
     def fn(q, k_pool, v_pool, lengths, pages, new_k, new_v, *scales):
         kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
         return paged_mod.paged_decode_attention(
-            q, k_pool, v_pool, lengths, pages, layer=3, new_k=new_k,
-            new_v=new_v, **kw)
+            q, k_pool, v_pool, lengths, pages, layer=layers - 1,
+            new_k=new_k, new_v=new_v, **kw)
     return fn, args
 
 
 def _paged_chunk_prefill(page=64, chunk=128, cache_len=512, heads=H,
-                         head_dim=D, quant=False):
+                         head_dim=D, quant=False, kv_heads=None):
     """One layer-chunk of the paged chunk step (B = 1).  The block loop
     (unquantized pools) holds two K and two V blocks of 8 pages, the
     chunk's q and output and its online-softmax state in VMEM: compiling
     is the proof that they fit the limit the kernel asks for."""
+    kv_heads = kv_heads or heads
     pages_per_slot = cache_len // page
     n_pages = 8 * pages_per_slot + 1
-    pool = ((4, n_pages, page, heads * head_dim), I8 if quant else BF16)
+    pool = ((4, n_pages, page, kv_heads * head_dim), I8 if quant else BF16)
     args = [((1, chunk, heads, head_dim), BF16), pool, pool, ((1,), I32),
             ((1, pages_per_slot), I32)]
     if quant:
-        args += [((4, n_pages, page, heads), F32)] * 2
+        args += [((4, n_pages, page, kv_heads), F32)] * 2
 
     def fn(q, k_pool, v_pool, starts, pages, *scales):
         kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
@@ -179,6 +181,32 @@ def _moe_grouped(tokens=DOTS3_CHUNK, hidden=5120, held=32, width=1536,
                 ((tokens, top_k), F32), up, up, ((held, width, hidden), BF16)]
 
 
+# LFM2-24B-A2B's expert layer at the wide-generation cell's sizes: 64
+# experts of 2048 x 1536 with 4 a token — the dense form for a decode step's
+# 256 rows, the grouped form for a 512-token chunk (its paged kernels, 32
+# query heads over 8 KV heads of 64 at 256 lanes of 21 pages, are cases of
+# ``_paged_decode`` / ``_paged_chunk_prefill``)
+def _moe_scored(tokens, hidden=2048, experts=64, width=1536, top_k=4):
+    """The sigmoid-routed layer as ``MoE._scored`` runs it with every
+    expert held: ``route_scored``, then the dense or the grouped form by
+    ``GROUPED_MIN_ROWS``."""
+    up = ((experts, hidden, width), BF16)
+
+    def fn(x, gate_w, bias, live, wg, wu, wd):
+        choice, gate = moe_mod.route_scored(x, gate_w, bias, top_k,
+                                            live=live, sum_eps=1e-6)
+        local, counts, _ = moe_mod.held_load(choice, 0, experts)
+        if tokens < moe_mod.GROUPED_MIN_ROWS:
+            return moe_mod.experts(
+                x, moe_mod.combine_of(local, gate, experts), counts, wg, wu,
+                wd, jax.nn.silu), counts
+        return moe_mod.experts_grouped(x, local, gate, wg, wu, wd,
+                                       jax.nn.silu), counts
+    return fn, [((tokens, hidden), BF16), ((hidden, experts), F32),
+                ((experts,), F32), ((tokens,), jnp.bool_), up, up,
+                ((experts, width, hidden), BF16)]
+
+
 CASES = {
     "dots3_dsa_index_c2048": _dsa_index,
     "dots3_dsa_topk_c2048": _dsa_topk,
@@ -187,6 +215,12 @@ CASES = {
     "dots3_mla_window_c2048": lambda: _mla_flash(
         "attn.mla_window", 64, 192, DOTS3_CHUNK + 512),
     "dots3_moe_grouped_c2048": _moe_grouped,
+    "lfm2_moe_scored_gmm_t256": lambda: _moe_scored(256),
+    "lfm2_moe_scored_grouped_c512": lambda: _moe_scored(512),
+    "lfm2_paged_decode_gqa_256x21": lambda: _paged_decode(
+        False, slots=256, cache_len=1344, layers=2, kv_heads=8),
+    "lfm2_paged_chunk_prefill_gqa_c512": lambda: _paged_chunk_prefill(
+        chunk=512, cache_len=1344, kv_heads=8),
     "moe_experts_olmoe_t64": lambda: _moe_experts(64),
     "moe_experts_olmoe_t128": lambda: _moe_experts(128),
     "moe_experts_olmoe_t512": lambda: _moe_experts(512),

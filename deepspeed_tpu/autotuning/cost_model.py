@@ -60,7 +60,11 @@ def xla_memory_analysis(compiled):
     (``compiled.memory_analysis()``): argument / output / temp / alias /
     generated-code bytes, plus ``total_bytes`` = arg + out + temp −
     alias (the program's live working set — what it actually costs the
-    device on top of buffers it aliases in place).  Exact on TPU,
+    device on top of buffers it aliases in place; it counts every
+    temporary as if none shared memory) and ``peak_memory_in_bytes``, the
+    compiler's own peak over arguments, outputs and temporaries (what
+    XLA:TPU holds against the chip's HBM; 0 where it reports none).
+    Exact on TPU,
     stable on the tier-1 CPU backend (the memory/FLOP contracts in
     ``PROGRAMS.lock`` are locked from this).  Returns ``None`` when the
     backend does not expose the analysis.
@@ -72,7 +76,7 @@ def xla_memory_analysis(compiled):
         out = {}
         for key in ("temp_size_in_bytes", "argument_size_in_bytes",
                     "output_size_in_bytes", "alias_size_in_bytes",
-                    "generated_code_size_in_bytes"):
+                    "generated_code_size_in_bytes", "peak_memory_in_bytes"):
             out[key] = int(getattr(ma, key, 0) or 0)
         out["total_bytes"] = (out["temp_size_in_bytes"] + out["argument_size_in_bytes"]
                               + out["output_size_in_bytes"] - out["alias_size_in_bytes"])

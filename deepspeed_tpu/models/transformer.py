@@ -28,6 +28,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.utils.logging import logger
@@ -123,7 +124,12 @@ class TransformerConfig:
     # seq sharding to GSPMD constraint propagation
     sequence_parallel_impl: Optional[str] = None
     remat: bool = True
-    remat_policy: str = "nothing_saveable"
+    # what a rematerialized block keeps for its backward.  "fit": the
+    # training engine picks a rung of REMAT_LADDER from the device memory
+    # its compiled step leaves (runtime/engine.py `_fit_train_exe`); with
+    # no engine choosing, "fit" is rung 0 = "nothing_saveable".  "fit:N"
+    # pins rung N; any other name is resolve_remat_policy's
+    remat_policy: str = "fit"
     scan_layers: bool = True
 
     def __post_init__(self):
@@ -200,6 +206,20 @@ class TransformerConfig:
         return {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
                 "float16": jnp.float16}[self.dtype]
 
+    def remat_saved_bytes(self, tokens, rung):
+        """Bytes rung ``rung`` of :data:`REMAT_LADDER` keeps from the
+        forward of ``tokens`` tokens through every layer, by the shapes of
+        the named values (the engine's reckoning before it compiles)."""
+        item = jnp.dtype(self.jnp_dtype).itemsize
+        q, kv = self.num_heads * self.head_dim, self.kv_heads * self.head_dim
+        a_token = {"flash_out": q * item, "flash_lse": self.num_heads * 4,
+                   "mlp_up": self.ffn_size * item,
+                   "mlp_gate": self.ffn_size * item if self.gated_mlp else 0,
+                   "attn_q": q * item, "attn_k": kv * item, "attn_v": kv * item,
+                   "attn_o": self.hidden_size * item}
+        return tokens * self.num_layers * sum(
+            a_token[n] for n in remat_rung_names(rung))
+
     def num_params(self):
         """Analytic parameter count (embeddings + blocks + final norm)."""
         h, v, l = self.hidden_size, self.vocab_size, self.num_layers
@@ -225,14 +245,46 @@ class TransformerConfig:
         return emb + l * per_layer + mlps + norm_size + head
 
 
+# What a rematerialized block can keep, as ``checkpoint_name`` tags, in
+# the order worth keeping: milliseconds of replay bought per byte held.
+# Rung N of the ladder saves the names of its first N steps; rung 0 saves
+# nothing.  (a) the flash kernel's out + lse: one [tokens, h] buys a
+# whole kernel call; (b) the up-projection (and a gated MLP's gate): 4 h
+# of bytes a token for 4 h^2 of replay; (c) q/k/v: 3 for 3; (d) o_proj: 1
+# for 1.  The down-projection's output is deliberately NOT a name: it
+# feeds only the residual sum, nothing replays it.
+REMAT_LADDER = (
+    ("flash_out", "flash_lse"),
+    ("mlp_up", "mlp_gate"),
+    ("attn_q", "attn_k", "attn_v"),
+    ("attn_o",),
+)
+
+
+def remat_rung_names(rung):
+    """The names rung ``rung`` of :data:`REMAT_LADDER` saves."""
+    if not 0 <= rung <= len(REMAT_LADDER):
+        raise ValueError(f"remat rung {rung}: the ladder has rungs 0.."
+                         f"{len(REMAT_LADDER)}")
+    return tuple(n for step in REMAT_LADDER[:rung] for n in step)
+
+
 def resolve_remat_policy(name):
     """Map a policy name to a jax.checkpoint policy.
+
+    ``"fit"`` is rung 0 of :data:`REMAT_LADDER` here (an engine that fits
+    the saved set hands the module ``"fit:N"``); ``"fit:N"`` saves rung
+    N's names.
 
     Beyond the stock ``jax.checkpoint_policies`` names, ``dots_and_attn_saveable``
     saves weight-stationary dot outputs AND the flash-attention residuals
     (tagged ``flash_out``/``flash_lse`` in the kernel's vjp) — the backward
     pass then reuses the O(S) attention residuals instead of re-running the
     forward kernel, the right default trade on HBM-rich chips."""
+    if name == "fit" or name.startswith("fit:"):
+        names = remat_rung_names(int(name[4:] or 0))
+        return jax.checkpoint_policies.save_only_these_names(*names) \
+            if names else jax.checkpoint_policies.nothing_saveable
     if name in ("dots_and_attn_saveable", "attn_residuals_saveable"):
         cp = jax.checkpoint_policies
         return cp.save_from_both_policies(
@@ -662,6 +714,14 @@ class Attention(nn.Module):
             q = dense(features=(H, D), name="q_proj")(x)
             k = dense(features=(KVH, D), name="k_proj")(x)
             v = dense(features=(KVH, D), name="v_proj")(x)
+        if cache is None:
+            # names a remat policy may save (REMAT_LADDER; a cached forward
+            # takes no gradient).  Named in their lane-dense [B, S, heads *
+            # D] form: kept as [.., heads, D=64] a stacked copy is padded
+            # to 128 lanes in HBM, twice its bytes
+            q, k, v = (checkpoint_name(t.reshape(*t.shape[:2], -1), n)
+                       .reshape(t.shape) for t, n in
+                       ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
         if cfg.qk_norm:
             # over the whole projected vector, not per head (HF
             # OlmoeAttention q_norm / k_norm)
@@ -720,7 +780,7 @@ class Attention(nn.Module):
         proj = dense(features=cfg.hidden_size, axis=(-2, -1),
                      use_bias=cfg.attn_out_bias_enabled, name="o_proj")(
             out.reshape(*out.shape[:2], H, D))
-        return proj, new_cache
+        return checkpoint_name(proj, "attn_o"), new_cache
 
 
 # ``TransformerConfig.activation`` -> the function, for the dense MLP and
@@ -742,11 +802,15 @@ class MLP(nn.Module):
                         dtype=cfg.jnp_dtype, param_dtype=jnp.float32)
         act = ACTIVATIONS[cfg.activation]
         if cfg.gated_mlp:
-            gate = dense(cfg.ffn_size, name="gate_proj")(x)
-            up = dense(cfg.ffn_size, name="up_proj")(x)
+            gate = checkpoint_name(
+                dense(cfg.ffn_size, name="gate_proj")(x), "mlp_gate")
+            up = checkpoint_name(
+                dense(cfg.ffn_size, name="up_proj")(x), "mlp_up")
             h = act(gate) * up
         else:
-            h = act(dense(cfg.ffn_size, name="up_proj")(x))
+            h = act(checkpoint_name(
+                dense(cfg.ffn_size, name="up_proj")(x), "mlp_up"))
+        # the down-projection's output is not a name: nothing replays it
         return dense(cfg.hidden_size, name="down_proj")(h)
 
 

@@ -549,12 +549,21 @@ def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k,
     out, lse = _fwd(q, k, v, scale, causal, block_q, block_k)
     # tag residuals so a remat policy can elect to SAVE them — without the
     # tags, any rematerialized layer re-runs the whole forward kernel inside
-    # the backward pass just to regenerate lse (out: bf16 B·S·H·D; lse: 8-lane
-    # f32 — together ~20MB/layer at opt-350m/2048, far cheaper than a
-    # recompute)
+    # the backward pass just to regenerate lse.  What is tagged is each
+    # residual's lane-dense form: out as [B, S, H*D] (the layout the model
+    # takes it in anyway) and lse as [B, H, S] — saved as the kernel writes
+    # them, [.., S, D=64] and [.., S, 8 lanes] are padded to 128 lanes in
+    # HBM, 2x and 16x their bytes (2.4 GB where 0.5 GB will do at
+    # opt-1.3b/4096 tokens).  The residuals below are derived from the
+    # tagged values, so a backward that has them replays a transpose and a
+    # broadcast, not the kernel.
     from jax.ad_checkpoint import checkpoint_name
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    B, H, S, D = out.shape
+    flat = checkpoint_name(
+        out.transpose(0, 2, 1, 3).reshape(B, S, H * D), "flash_out")
+    out = flat.reshape(B, S, H, D).transpose(0, 2, 1, 3)
+    lse = jnp.broadcast_to(
+        checkpoint_name(lse[..., 0], "flash_lse")[..., None], lse.shape)
     return out, (q, k, v, out, lse)
 
 

@@ -417,6 +417,8 @@ class ProgramCache:
         self.config = config
         cache_dir = configure_persistent_cache(
             config.cache_dir, config.min_compile_time_secs)
+        # what engines memoise about a program besides the program itself
+        self.notes_dir = os.path.join(cache_dir, "notes")
         self.store = None
         if config.executables:
             self.store = ExecutableStore(
@@ -434,6 +436,33 @@ class ProgramCache:
         if not config.enabled:
             return None
         return cls(config)
+
+    def _note_path(self, tag, key_parts):
+        return os.path.join(
+            self.notes_dir, f"{tag}-{cache_key(tag, *key_parts)}.json")
+
+    def load_note(self, tag, key_parts):
+        """A small JSON fact an engine memoised beside its programs
+        (:meth:`save_note`), or None: missing, unreadable, or written by
+        another build (the fingerprint is part of the key)."""
+        try:
+            with open(self._note_path(tag, key_parts)) as f:
+                return json.load(f)
+        except (OSError, ValueError):
+            return None
+
+    def save_note(self, tag, key_parts, note):
+        """Persist ``note`` (JSON) under the executable store's keying;
+        atomic, and best-effort like the store."""
+        path = self._note_path(tag, key_parts)
+        tmp = path + f".tmp.{os.getpid()}"
+        try:
+            os.makedirs(self.notes_dir, exist_ok=True)
+            with open(tmp, "w") as f:
+                json.dump(note, f)
+            os.replace(tmp, path)
+        except OSError as e:
+            logger.debug(f"compile cache note {tag} not saved: {e}")
 
     def get_or_compile(self, tag, key_parts, compile_fn):
         """Returns ``(compiled, seconds, hit)``.  ``compile_fn`` runs only

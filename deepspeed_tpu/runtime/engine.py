@@ -89,6 +89,39 @@ def _unscale_and_clip(grads, scale, clip):
     return grads, gnorm
 
 
+def _bytes_on_first_device(tree):
+    """Bytes of ``tree``'s arrays on the first device that holds them."""
+    total = 0
+    for x in jax.tree.leaves(tree):
+        if isinstance(x, jax.Array):
+            total += x.addressable_shards[0].data.nbytes
+    return total
+
+
+def _tokens_on_first_device(batches):
+    """Tokens of one micro-batch of ``batches`` (``[gas, rows, ...]``
+    arrays, placed) on the first device that holds them."""
+    return max(int(np.prod(x.addressable_shards[0].data.shape[1:]))
+               for x in jax.tree.leaves(batches))
+
+
+def _remat_record(cfg, rung, tokens, **read):
+    """``remat_choice()``'s record: what rung ``rung`` of the model's remat
+    ladder saves on a device for ``tokens`` tokens, and what the fit
+    ``read``."""
+    from deepspeed_tpu.models.transformer import remat_rung_names
+    return {"rung": rung, "remat_saved": list(remat_rung_names(rung)),
+            "remat_saved_bytes": cfg.remat_saved_bytes(tokens, rung),
+            "step_peak_bytes": None, "bytes_limit": 0, "rungs_tried": [rung],
+            **read}
+
+
+def _flat_args(record):
+    """A record's values as span args (lists joined)."""
+    return {k: ",".join(map(str, v)) if isinstance(v, list) else v
+            for k, v in (record or {}).items()}
+
+
 def _is_flax_module(model):
     try:
         import flax.linen as nn
@@ -166,6 +199,8 @@ class DeepSpeedEngine:
         self._program_cache = ProgramCache.from_config(
             getattr(self._config, "compile_cache", None))
         self._train_aot = {}     # abstract signature -> AOT executable
+        self._remat_choice = None   # remat_choice(): what "fit" chose
+        self._remat_limit = None    # the device's bytes_limit, once read
 
         # ZeRO-Offload (reference stage_1_and_2.py:1037 CPU-offload path /
         # stage3.py:1637 NVMe): host-resident fp32 masters + moments stepped
@@ -335,10 +370,12 @@ class DeepSpeedEngine:
         if model_parameters is not None and not _is_generator(model_parameters):
             self._init_params_from(model_parameters)
 
-    def _apply_model(self, params, args, kwargs, rng, train):
+    def _apply_model(self, params, args, kwargs, rng, train, apply=None):
         """Call the model with compute-dtype params (mixed precision: master
         fp32 params cast at use — the bf16/fp16 cast the reference does once
-        at wrap time, ``engine.py:1020``)."""
+        at wrap time, ``engine.py:1020``).  ``apply``: the module's apply at
+        a fitted remat rung (``_get_fused_step``); default the module's own."""
+        apply = apply or self._raw_apply
         cast = jax.tree.map(
             lambda p: p.astype(self.compute_dtype)
             if (hasattr(p, "dtype") and jnp.issubdtype(p.dtype, jnp.floating)) else p,
@@ -348,12 +385,12 @@ class DeepSpeedEngine:
             if train:
                 kw.setdefault("rngs", {"dropout": rng})
             try:
-                out = self._raw_apply(cast, *args, **kw)
+                out = apply(cast, *args, **kw)
             except TypeError:
                 kw.pop("rngs", None)
-                out = self._raw_apply(cast, *args, **kw)
+                out = apply(cast, *args, **kw)
         else:
-            out = self._raw_apply(cast, *args, **kwargs)
+            out = apply(cast, *args, **kwargs)
         return out
 
     def _extract_loss(self, out):
@@ -992,9 +1029,16 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------ #
     # Fully-fused train step (scan over GAS) — the benchmark hot path
     # ------------------------------------------------------------------ #
-    def _get_fused_step(self):
-        key = "fused_step"
+    def _get_fused_step(self, rung=0):
+        """The fused step's jit.  ``rung`` > 0 (``_fit_train_exe``): the
+        module's blocks keep rung ``rung`` of the model's remat ladder —
+        same parameters, same operations, another saved set."""
+        key = "fused_step" if not rung else f"fused_step:fit{rung}"
         if key not in self._compiled:
+            import dataclasses
+            apply = None if not rung else self.module.clone(
+                config=dataclasses.replace(
+                    self.module.config, remat_policy=f"fit:{rung}")).apply
             gas = self.gradient_accumulation_steps()
             clip = float(self.gradient_clipping() or 0.0)
             scaler = self.loss_scaler
@@ -1018,7 +1062,8 @@ class DeepSpeedEngine:
                     r, sub = jax.random.split(r)
 
                     def loss_of(p):
-                        out = self._apply_model(p, (mb,), {}, sub, train=True)
+                        out = self._apply_model(p, (mb,), {}, sub, train=True,
+                                                apply=apply)
                         loss, _ = self._extract_loss(out)
                         return loss.astype(jnp.float32) * scaler_state.scale / gas, loss
 
@@ -1069,7 +1114,8 @@ class DeepSpeedEngine:
         through the plain jit call otherwise (exactly the seed behavior
         when the compile_cache block is off)."""
         fused = self._get_fused_step()
-        if self._program_cache is None and not self._train_aot:
+        if self._program_cache is None and not self._train_aot \
+                and not self._remat_fit_budget()[1]:
             return fused(*args)
         from deepspeed_tpu.runtime import compile_cache as cc
         sig = cc.abstract_signature(args)
@@ -1094,25 +1140,155 @@ class DeepSpeedEngine:
     def _train_exe_for(self, fused, args, sig):
         """AOT-compile the fused step (consulting the executable store when
         enabled); falls back to the jit callable itself on any failure.
-        Returns ``(exe, compile_seconds, store_hit)``."""
+        Returns ``(exe, compile_seconds, store_hit)``.  Where the module's
+        remat policy is ``"fit"`` and the device reports a memory limit,
+        the step compiled is the one ``_fit_train_exe`` chooses."""
         from deepspeed_tpu.runtime.compile_cache import aot_compile_with_store
-        exe, dt, hit = aot_compile_with_store(
-            self._program_cache, "train_step", self._train_key_parts(sig),
-            fused, args)
+        cfg, limit = self._remat_fit_budget()
+        with span("dstpu.train.compile") as sp:
+            if limit:
+                exe, dt, hit = self._fit_train_exe(cfg, limit, args, sig)
+            else:
+                exe, dt, hit = aot_compile_with_store(
+                    self._program_cache, "train_step",
+                    self._train_key_parts(sig), fused, args)
+                if cfg is not None:     # nothing to fit against: rung 0
+                    self._remat_choice = _remat_record(
+                        cfg, 0, _tokens_on_first_device(args[-1]))
+            sp.set(**_flat_args(self._remat_choice))
         if exe is None:            # AOT failed (warned): plain jit call —
             exe = fused            # no fake 0.0s compile event
         else:
-            self._report_compile("train_step", dt, hit)
+            self._report_compile("train_step", dt, hit, self._remat_choice)
         self._train_aot[sig] = exe
         return exe, dt, hit
 
-    def _report_compile(self, name, seconds, cache_hit):
+    # the share of ``bytes_limit`` a fitted step leaves free: what the
+    # allocator loses to fragmentation, and what the caller's loop holds
+    # beside the step (the next batch, an eval program's buffers)
+    REMAT_FIT_RESERVE = 0.05
+    # a subclass whose programs take device memory AFTER the train step is
+    # compiled (the hybrid engine's rollout workspace) cannot be fitted
+    _remat_fit_enabled = True
+
+    def _remat_fit_budget(self):
+        """``(config, bytes_limit)``: the module's config where its
+        blocks' remat policy is ``"fit"`` (else None), and the device's
+        memory limit where a rung is to be chosen against it (else 0: a
+        policy given by name, a backend that reports no limit — the CPU)."""
+        cfg = getattr(self.module, "config", None)
+        if not (self._is_flax and self._remat_fit_enabled
+                and getattr(cfg, "remat", False)
+                and getattr(cfg, "remat_policy", None) == "fit"
+                and hasattr(cfg, "remat_saved_bytes")):
+            return None, 0
+        if self._remat_limit is None:       # read once: it does not change
+            self._remat_limit = self._device_memory()[0]
+        return cfg, self._remat_limit
+
+    def _device_memory(self):
+        """``(bytes_limit, bytes_in_use)`` of the fullest local device."""
+        snap = max(get_accelerator().memory_snapshots(),
+                   key=lambda s: s["bytes_in_use"])
+        return snap["bytes_limit"], snap["bytes_in_use"]
+
+    def _fit_train_exe(self, cfg, limit, args, sig):
+        """Compile the fused step at the richest rung of the model's remat
+        ladder that fits: reckon the first rung from the shapes, read the
+        compiled step's memory, step down by what it reads over — at most
+        one compile is thrown away — and memoise the choice beside the
+        executable, so a warm run compiles one program."""
+        from deepspeed_tpu.models.transformer import REMAT_LADDER
+        from deepspeed_tpu.runtime.compile_cache import aot_compile_with_store
+        key_parts = self._train_key_parts(sig)
+        cache = self._program_cache
+
+        def compile_at(rung):
+            return aot_compile_with_store(
+                cache, "train_step", key_parts + (f"fit:{rung}",),
+                self._get_fused_step(rung), args)
+
+        memo = cache.load_note("train_step.remat", key_parts) \
+            if cache is not None else None
+        if memo is not None and memo.get("bytes_limit") == limit:
+            exe, dt, hit = compile_at(memo["rung"])
+            self._remat_choice = dict(memo, rungs_tried=[memo["rung"]])
+            return exe, dt, hit
+
+        _, in_use = self._device_memory()
+        held = _bytes_on_first_device(args)
+        # what the step may take: the limit less the reserve less what is
+        # resident and is not the step's own arguments
+        budget = int(limit * (1 - self.REMAT_FIT_RESERVE)) \
+            - max(0, in_use - held)
+        tokens = _tokens_on_first_device(args[-1])
+        saved = lambda r: cfg.remat_saved_bytes(tokens, r)
+        rungs = range(len(REMAT_LADDER), -1, -1)
+        # reckoned before any compile: arguments, one gradient tree, and
+        # the saved set (the rest of the step's temporaries are the
+        # compiler's to say)
+        reckoned = held + _bytes_on_first_device(args[0])
+        rung = next((r for r in rungs if reckoned + saved(r) <= budget), 0)
+        exe, dt, hit = compile_at(rung)
+        tried, peak = [rung], self._step_memory(exe)
+        if peak is not None and peak > budget and rung > 0:
+            # over: the richest lower rung that fits by this reading; it is
+            # kept whatever it reads (one compile thrown away, never two)
+            rung = next((r for r in rungs if r < rung
+                         and peak - saved(tried[0]) + saved(r) <= budget), 0)
+            exe, again, hit = compile_at(rung)
+            dt += again
+            tried.append(rung)
+            peak = self._step_memory(exe)
+            if peak is not None and peak > budget:
+                logger.warning(
+                    f"remat fit: the train step at rung {rung} reads "
+                    f"{peak / 1e9:.2f} GB against {budget / 1e9:.2f} GB free")
+        if exe is None:             # AOT failed: the caller runs rung 0's jit
+            rung = 0
+        self._remat_choice = _remat_record(
+            cfg, rung, tokens, step_peak_bytes=peak, bytes_limit=limit,
+            rungs_tried=tried)
+        if cache is not None and peak is not None:
+            cache.save_note("train_step.remat", key_parts, self._remat_choice)
+        return exe, dt, hit
+
+    def _step_memory(self, compiled):
+        """Bytes the compiled step holds at its peak on one device — the
+        compiler's own figure, what XLA:TPU holds against the chip's HBM —
+        or None where there is none to read (the AOT compile failed, the
+        backend reports no peak).  Not arguments + outputs + temporaries -
+        aliased: that sum counts every temporary as if none shared memory,
+        and reads 1.2-3.1 GB over the peak at the benchmark's sizes."""
+        from deepspeed_tpu.autotuning.cost_model import xla_memory_analysis
+        mem = xla_memory_analysis(compiled) if compiled is not None else None
+        return (mem or {}).get("peak_memory_in_bytes") or None
+
+    def remat_choice(self):
+        """What the fused step's blocks keep for their backward, where the
+        module's remat policy is ``"fit"`` and the step has been compiled
+        (else None): ``rung``, ``remat_saved`` (names), ``remat_saved_bytes``
+        (by the shapes, a device), ``step_peak_bytes`` (the compiled step's
+        peak as ``_step_memory`` reads it), ``bytes_limit`` (the device's;
+        0 where it reports none and the rung is 0), ``rungs_tried`` (every
+        rung compiled, in order)."""
+        return self._remat_choice
+
+    def _report_compile(self, name, seconds, cache_hit, remat=None):
+        """``remat``: ``remat_choice()``'s record, where the step was
+        compiled under the remat policy ``"fit"``."""
         log_dist(f"compile[{name}]: "
                  + ("executable-cache hit" if cache_hit
-                    else f"{seconds:.1f}s"), ranks=[0])
+                    else f"{seconds:.1f}s")
+                 + (f"; remat fit {_flat_args(remat)}" if remat else ""),
+                 ranks=[0])
         if self.monitor.enabled:
+            numbers = {k: v for k, v in (remat or {}).items()
+                       if isinstance(v, int)}
             self.monitor.write_events(
-                [(f"Compile/{name}_secs", seconds, self.global_steps)])
+                [(f"Compile/{name}_secs", seconds, self.global_steps)]
+                + [(f"Compile/{name}_{k}", v, self.global_steps)
+                   for k, v in numbers.items()])
 
     def warmup(self, batch=None, data_iter=None):
         """Pre-compile the fused whole-step train program for this batch's

@@ -31,6 +31,10 @@ from deepspeed_tpu.utils.logging import log_dist, logger
 
 class DeepSpeedHybridEngine(DeepSpeedEngine):
 
+    # the rollout's KV workspace and inference view take device memory the
+    # train step's compile cannot see: a remat policy of "fit" stays rung 0
+    _remat_fit_enabled = False
+
     def __init__(self, *args, **kwargs):
         super().__init__(*args, **kwargs)
         self._infer_params = None

@@ -118,12 +118,13 @@ def _expert_load(counts, share=False):
     ``share`` (:func:`holds_share`): the counts' last column is the
     choices of absent experts — their sum goes between the held experts'
     tokens and ``touched``."""
-    held = counts[..., :-1] if share else counts
-    return jnp.concatenate(
-        [jnp.sum(held, axis=0).reshape(-1)]
-        + ([jnp.sum(counts[..., -1])[None]] if share else [])
-        + [jnp.sum(held > 0).astype(jnp.int32)[None],
-           jnp.sum(jnp.max(held, axis=-1))[None]])
+    with jax.named_scope("slots.expert_load"):
+        held = counts[..., :-1] if share else counts
+        return jnp.concatenate(
+            [jnp.sum(held, axis=0).reshape(-1)]
+            + ([jnp.sum(counts[..., -1])[None]] if share else [])
+            + [jnp.sum(held > 0).astype(jnp.int32)[None],
+               jnp.sum(jnp.max(held, axis=-1))[None]])
 
 
 def make_decode_block_fn(module, sample_fn, param_transform, block,
@@ -172,15 +173,18 @@ def make_decode_block_fn(module, sample_fn, param_transform, block,
                 module, deq(params), tok[:, None],
                 {**cache, "pages": safe_pages},
                 pos, live=active[:, None] if routed else None)
-            rng, sub = jax.random.split(rng)
-            nxt = sample_fn(logits[:, -1], sub).astype(jnp.int32)
-            nxt = jnp.where(active, nxt, eos)
-            done_now = active & ((nxt == eos) | (remaining <= 1))
-            active = active & jnp.logical_not(done_now)
-            # dead lanes clamp to the last virtual position — its table
-            # entry is the trash page once the host processed retirement
-            pos = jnp.minimum(pos + 1, cache_len - 1)
-            remaining = jnp.maximum(remaining - 1, 0)
+            with jax.named_scope("head.sample"):
+                rng, sub = jax.random.split(rng)
+                nxt = sample_fn(logits[:, -1], sub).astype(jnp.int32)
+            with jax.named_scope("slots.state"):
+                nxt = jnp.where(active, nxt, eos)
+                done_now = active & ((nxt == eos) | (remaining <= 1))
+                active = active & jnp.logical_not(done_now)
+                # dead lanes clamp to the last virtual position — its
+                # table entry is the trash page once the host processed
+                # retirement
+                pos = jnp.minimum(pos + 1, cache_len - 1)
+                remaining = jnp.maximum(remaining - 1, 0)
             return (cache, nxt, pos, active, remaining, rng), (nxt, counts)
 
         (cache, tok, pos, active, remaining, _), (toks, counts) = \
@@ -421,17 +425,19 @@ def make_admit_fn(sample_fn):
 
     @hot_path("serving.admit")
     def admit(state, logits, rng, slot, pos0, max_new, eos):
-        first = sample_fn(logits[:, 0], rng).astype(jnp.int32)[0]
-        # finished-at-admission: eos on the first token (eos=-1 never
-        # matches: sampled ids are >= 0), or a 1-token request
-        active0 = (max_new > 1) & jnp.logical_not(first == eos)
-        upd = lambda arr, val: arr.at[slot].set(val)
-        state = {"token": upd(state["token"], first),
-                 "pos": upd(state["pos"], pos0),
-                 "active": upd(state["active"], active0),
-                 "remaining": upd(state["remaining"],
-                                  jnp.maximum(max_new - 1, 0)),
-                 "eos": upd(state["eos"], eos)}
+        with jax.named_scope("head.sample"):
+            first = sample_fn(logits[:, 0], rng).astype(jnp.int32)[0]
+        with jax.named_scope("slots.state"):
+            # finished-at-admission: eos on the first token (eos=-1 never
+            # matches: sampled ids are >= 0), or a 1-token request
+            active0 = (max_new > 1) & jnp.logical_not(first == eos)
+            upd = lambda arr, val: arr.at[slot].set(val)
+            state = {"token": upd(state["token"], first),
+                     "pos": upd(state["pos"], pos0),
+                     "active": upd(state["active"], active0),
+                     "remaining": upd(state["remaining"],
+                                      jnp.maximum(max_new - 1, 0)),
+                     "eos": upd(state["eos"], eos)}
         return state, first
 
     return jax.jit(admit, donate_argnums=(0,))

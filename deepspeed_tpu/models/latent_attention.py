@@ -227,8 +227,9 @@ class LatentAttention(nn.Module):
         z = self.spec
         w_k, w_v = self._kv_up()
         lat = keys[:, :z.kv_rank]
-        k_nope = jnp.einsum("lr,rhd->hld", lat, w_k)
-        v = jnp.einsum("lr,rhd->hld", lat, w_v)
+        with jax.named_scope("attn.mla_decompress"):      # c_kv W_kvb
+            k_nope = jnp.einsum("lr,rhd->hld", lat, w_k)
+            v = jnp.einsum("lr,rhd->hld", lat, w_v)
         return ops.masked_flash(
             q[..., :z.nope], q[..., z.nope:], k_nope,
             keys[:, z.kv_rank:z.row], v, mask, z.scale, name)
@@ -241,8 +242,9 @@ class LatentAttention(nn.Module):
             pools, keys, index_keys = None, row, ki
         else:
             (latent, index), layer, table = cache
-            latent = write_rows(latent, layer, table, positions, row)
-            index = write_rows(index, layer, table, positions, ki)
+            with jax.named_scope("cache.write"):
+                latent = write_rows(latent, layer, table, positions, row)
+                index = write_rows(index, layer, table, positions, ki)
             pools = (latent, index)
             # the slot's whole lane, in position order, in 512-key blocks
             pages = max(1, 512 // latent.shape[2])
@@ -270,7 +272,8 @@ class LatentAttention(nn.Module):
             keep = jnp.ones((C,), bool) if live is None else live
             last = jnp.max(jnp.where(keep, positions, -1))
             keep = keep & (positions > last - n * page)
-            pool = write_rows(pool, layer, ring, positions, row, keep)
+            with jax.named_scope("cache.write"):
+                pool = write_rows(pool, layer, ring, positions, row, keep)
         key_pos = jnp.concatenate([before, positions])
         mask = (key_pos[None, :] >= 0) \
             & (key_pos[None, :] <= positions[:, None]) \
@@ -293,7 +296,8 @@ class LatentAttention(nn.Module):
         q_lat = jnp.einsum("nhd,rhd->nhr", q[..., :z.nope], w_k)
         pools, layer, table = cache
         if z.window:
-            pool = write_rows(pools, layer, table, positions, row)
+            with jax.named_scope("cache.write"):
+                pool = write_rows(pools, layer, table, positions, row)
             page, n = pool.shape[2], table.shape[1]
             rows = pool[layer, table].reshape(N, n * page, -1)
             r = jnp.arange(n * page, dtype=jnp.int32)[None, :]
@@ -308,8 +312,9 @@ class LatentAttention(nn.Module):
             qi, ki, w = jax.vmap(
                 lambda xr, cr, p: self._index(xr[None], cr[None], p[None]))(
                     x, c_q, positions)
-            latent = write_rows(latent, layer, table, positions, row)
-            index = write_rows(index, layer, table, positions, ki[:, 0])
+            with jax.named_scope("cache.write"):
+                latent = write_rows(latent, layer, table, positions, row)
+                index = write_rows(index, layer, table, positions, ki[:, 0])
             page = latent.shape[2]
             index_keys = index[layer, table].reshape(N, -1, index.shape[-1])
             L = index_keys.shape[1]

@@ -308,6 +308,7 @@ def _norm(config, name):
                         param_dtype=jnp.float32)
 
 
+@jax.named_scope("attn.rope")
 def _rope(q, k, positions, head_dim, theta, rope_dim=None, interleaved=False):
     """Rotary position embeddings.  Default: neox/llama half-split layout;
     ``interleaved`` selects the gptj rotate-every-two layout; ``rope_dim``
@@ -1238,6 +1239,7 @@ def derive_causal_labels(input_ids, attention_mask=None, ignore_index=-100):
     return labels
 
 
+@jax.named_scope("loss")
 def chunked_cross_entropy_loss(h, labels, head_fn, n_chunks,
                                ignore_index=-100):
     """Sequence-chunked causal-LM loss: the head matmul + CE run per chunk
@@ -1253,7 +1255,9 @@ def chunked_cross_entropy_loss(h, labels, head_fn, n_chunks,
     @jax.checkpoint
     def one(args):
         hb, lb = args
-        logits = head_fn(hb).astype(jnp.float32)
+        # the head closure carries no flax frame (``_head_pure``)
+        with jax.named_scope("head"):
+            logits = head_fn(hb).astype(jnp.float32)
         valid = lb != ignore_index
         safe = jnp.where(valid, lb, 0)
         logz = jax.scipy.special.logsumexp(logits, axis=-1)
@@ -1279,6 +1283,7 @@ def chunked_cross_entropy_loss(h, labels, head_fn, n_chunks,
     return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1)
 
 
+@jax.named_scope("loss")
 def cross_entropy_loss(logits, labels, ignore_index=-100, z_loss=0.0):
     """Causal-LM loss with ignore-index masking, computed in fp32."""
     logits = logits.astype(jnp.float32)

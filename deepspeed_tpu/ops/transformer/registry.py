@@ -45,6 +45,7 @@ tests: ``DSTPU_DISABLE_FLASH=1`` (``pallas_supported()`` false) routes
 every call to it.
 """
 
+import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.ops.transformer.flash_attention import pallas_supported
@@ -334,7 +335,9 @@ def write_and_attend(cfg, q, k, v, positions, cache, *, bias=None,
         kvh = k.shape[-2]
         k_new, ks_new = _quant_rows(k_new, kvh)
         v_new, vs_new = _quant_rows(v_new, kvh)
-    new_cache = _write_cache(cache, k_new, v_new, ks_new, vs_new, positions)
+    with jax.named_scope("cache.write"):
+        new_cache = _write_cache(cache, k_new, v_new, ks_new, vs_new,
+                                 positions)
     if prefill_from_zero:
         # one shared prefill attend for every cache layout: the cache
         # was written above; the attention itself is plain causal flash

@@ -7,9 +7,10 @@ cold compile).  This module makes compilation a per-machine cost instead of
 a per-process cost, at two layers:
 
 1. **Persistent XLA compilation cache** (:func:`configure_persistent_cache`)
-   — JAX's on-disk cache keyed by the optimized HLO + compile options, under
-   a framework-owned directory.  Transparent: any jit anywhere in the
-   process benefits.  Hits/misses are counted through JAX's monitoring
+   — JAX's on-disk cache keyed by the optimized HLO + compile options (+
+   :data:`SCOPES_VERSION`, see there), under a framework-owned directory.
+   Transparent: any jit anywhere in the process benefits.  Hits/misses
+   are counted through JAX's monitoring
    events (:func:`stats`).
 2. **Serialized executables** (:class:`ExecutableStore`) — AOT-compiled
    ``jax.stages.Compiled`` programs (``jax.experimental
@@ -66,6 +67,18 @@ class CompileCacheConfig(DeepSpeedConfigModel):
 _CHECKOUT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     ".jax_cache")
+
+# Both cache layers key a program WITHOUT its instructions' metadata: a
+# process is handed the executable — and with it the ``op_name``s that
+# every device profile shows and ``profiler.device_time_by_scope`` reads —
+# of whichever process compiled that computation first, from before a
+# ``jax.named_scope`` or a module was renamed.  This number is in both
+# keys (JAX's, through its ``custom_hook``; the executable store's, through
+# :func:`runtime_fingerprint`): whoever changes a name that
+# ``profiler.SCOPE_PARTS`` reads raises it, and every entry is compiled
+# once more — not on every moved source line, which metadata in JAX's key
+# would cost.
+SCOPES_VERSION = 1
 
 
 def env_cache_dir():
@@ -218,6 +231,8 @@ def configure_persistent_cache(cache_dir=None, min_compile_time_secs=None):
                 f"avoid fragmenting the cache)")
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
+    from jax._src import cache_key
+    cache_key.custom_hook = lambda: f"dstpu.scopes={SCOPES_VERSION}"
     if min_compile_time_secs is not None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(min_compile_time_secs))
@@ -298,6 +313,7 @@ def runtime_fingerprint():
         "n_devices": jax.device_count(),
         "n_processes": jax.process_count(),
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
+        "scopes": SCOPES_VERSION,
     }
 
 

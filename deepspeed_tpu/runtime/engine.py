@@ -735,6 +735,9 @@ class DeepSpeedEngine:
         profile step's report prints at the end of its step)."""
         if self.flops_profiler is not None and self.flops_profiler.started:
             pcfg = self._config.flops_profiler
+            # the step is dispatched, not done: fence it, so that the
+            # clock and the trace hold the whole of it
+            jax.block_until_ready(self._params)  # tpu-lint: disable=TL001 -- the profiled step only
             self.flops_profiler.stop_profile()
             self.flops_profiler.print_model_profile(
                 profile_step=pcfg.profile_step,
@@ -1069,12 +1072,16 @@ class DeepSpeedEngine:
 
                     grads, loss = jax.grad(loss_of, has_aux=True)(params)
                     if not static_scale:
-                        flat = jax.tree.leaves(grads)
-                        inf = jnp.logical_not(jnp.all(jnp.stack(
-                            [jnp.all(jnp.isfinite(g)) for g in flat])))
-                        inf_acc = jnp.logical_or(inf_acc, inf)
-                    acc = jax.tree.map(jnp.add, acc, grads) if acc is not None \
-                        else grads
+                        with jax.named_scope("optim.clip"):
+                            flat = jax.tree.leaves(grads)
+                            inf = jnp.logical_not(jnp.all(jnp.stack(
+                                [jnp.all(jnp.isfinite(g)) for g in flat])))
+                            inf_acc = jnp.logical_or(inf_acc, inf)
+                    if acc is None:
+                        acc = grads
+                    else:
+                        with jax.named_scope("optim.accumulate"):
+                            acc = jax.tree.map(jnp.add, acc, grads)
                     return (acc, inf_acc, r), loss
 
                 if gas == 1:
@@ -1089,16 +1096,19 @@ class DeepSpeedEngine:
                         lambda p: jnp.zeros(p.shape, jnp.float32), params)
                     (acc, found_inf, _), losses = jax.lax.scan(
                         micro, (zero_acc, jnp.asarray(False), rng), batches)
-                grads, gnorm = _unscale_and_clip(
-                    acc, 1.0 if static_scale else scaler_state.scale, clip)
-                new_params, new_opt = self.optimizer.update(grads, opt_state, params,
-                                                            lr=lr, step=step)
-                if not static_scale:
-                    keep = lambda new, old: jax.tree.map(
-                        lambda n, o: jnp.where(found_inf, o, n), new, old)
-                    new_params = keep(new_params, params)
-                    new_opt = keep(new_opt, opt_state)
-                new_scaler = scaler.update(scaler_state, found_inf)
+                with jax.named_scope("optim.clip"):
+                    grads, gnorm = _unscale_and_clip(
+                        acc, 1.0 if static_scale else scaler_state.scale,
+                        clip)
+                with jax.named_scope("optim.update"):
+                    new_params, new_opt = self.optimizer.update(
+                        grads, opt_state, params, lr=lr, step=step)
+                    if not static_scale:
+                        keep = lambda new, old: jax.tree.map(
+                            lambda n, o: jnp.where(found_inf, o, n), new, old)
+                        new_params = keep(new_params, params)
+                        new_opt = keep(new_opt, opt_state)
+                    new_scaler = scaler.update(scaler_state, found_inf)
                 return new_params, new_opt, new_scaler, jnp.mean(losses), gnorm
 
             self._compiled[key] = jax.jit(

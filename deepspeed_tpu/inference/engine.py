@@ -338,13 +338,27 @@ class InferenceEngine:
         ``ops/transformer/registry.py``), so bench records attribute
         which kernel path ran, not just which pipeline was planned.
         ``paged=True`` asks for the paged-serving attribution (block
-        tables + page-pool kernels) instead of the monolithic one."""
+        tables + page-pool kernels) instead of the monolithic one, and
+        names the form the admission chunk's K/V write takes at the
+        ``serving`` block's page and chunk (``chunk_write=page_runs`` or
+        ``row_scatter``, ``registry.paged_write_form``)."""
         from deepspeed_tpu.ops.transformer.registry import kernel_modes
         pe = getattr(getattr(self.module, "config", None),
                      "position_embedding", None)
         modes = kernel_modes(paged=bool(paged), has_bias=(pe == "alibi"))
-        tail = (" [kernels: prefill=%s decode=%s]"
+        tail = ("prefill=%s decode=%s"
                 % (modes["prefill_chunk"], modes["decode"]))
+        if paged:
+            from deepspeed_tpu.inference.serving.paging import page_rows
+            from deepspeed_tpu.inference.serving.slots import (
+                admission_chunk, chunk_write_form)
+            scfg = self._config.serving
+            form = chunk_write_form(
+                self.module, admission_chunk(self.module, scfg.prefill_chunk),
+                page_rows(scfg.page_size))
+            if form is not None:
+                tail += " chunk_write=" + form
+        tail = " [kernels: " + tail + "]"
         cfg = self._config.prefill_chunk_size
         chunk = self._prefill_chunk_for(int(batch_size), int(prompt_len))
         if chunk is not None and chunk < prompt_len:

@@ -134,7 +134,9 @@ from deepspeed_tpu.inference.serving.slo import (CircuitBreaker,
                                                  RequestStatus,
                                                  TERMINAL_STATUSES,
                                                  TokenStream)
-from deepspeed_tpu.inference.serving.slots import (init_slot_state,
+from deepspeed_tpu.inference.serving.slots import (admission_chunk,
+                                                   chunk_write_form,
+                                                   init_slot_state,
                                                    make_admit_fn,
                                                    make_chunk_fn,
                                                    make_decode_block_fn,
@@ -297,11 +299,7 @@ class ServingEngine:
         self.num_slots = int(cfg.num_slots)
         if self.num_slots < 1:
             raise ValueError(f"serving.num_slots={cfg.num_slots}: need >= 1")
-        # admission chunk: align like the engine's prefill_chunk_size
-        # (multiple of 8, floor 8, cap 512 — the chunk kernel's bounds;
-        # a model whose chunk path has other bounds names its own cap)
-        self.chunk = min(getattr(self.module, "prefill_chunk_cap", 512),
-                         max(8, -(-int(cfg.prefill_chunk) // 8) * 8))
+        self.chunk = admission_chunk(self.module, cfg.prefill_chunk)
         if not hasattr(type(self.module), "init_paged_cache"):
             raise ValueError(
                 f"{type(self.module).__name__} has no init_paged_cache — "
@@ -440,6 +438,12 @@ class ServingEngine:
                       "position_embedding", None)
         self.kernel_modes = _registry_modes(paged=True,
                                             has_bias=(_pe == "alibi"))
+        # ... and the form the chunk program's K/V write takes, by the
+        # predicate the traced write asks.  A stat, not a third key of
+        # kernel_modes: callers compare that dict whole
+        form = chunk_write_form(self.module, self.chunk, self.page)
+        if form is not None:
+            self.stats["chunk_write"] = form
         self._decode_fn = self._propose_fn = self._verify_fn = None
         self._draft_chunk_fn = self._draft_admit_fn = None
         # Page tables are traced arguments (rebuilt host-side per

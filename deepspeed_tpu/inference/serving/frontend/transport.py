@@ -757,6 +757,12 @@ class ServingHTTPFrontend:
                    [("", labels or {}, value)])
 
         for key, val in sorted(stats.items()):
+            if isinstance(val, str):
+                # a name, not a number (``chunk_write``): the value is a
+                # label of a gauge that reads 1
+                gauge(key, 1.0, help_=f"serving engine setting {key!r}",
+                      labels={"value": val})
+                continue
             gauge(key, val, help_=f"serving engine counter {key!r}")
         gauge("queue_depth", snap["queue_depth"],
               "queued + pending prefill")

@@ -53,6 +53,12 @@ import jax
 TRASH_PAGE = 0
 
 
+def page_rows(page_size):
+    """``serving.page_size`` as the pool is built: a multiple of 8 (the
+    fused decode write's stripes are 8-sublane-aligned), at least 8."""
+    return max(8, -(-int(page_size) // 8) * 8)
+
+
 def pages_for(virtual_len, page_size):
     """Physical pages needed to back ``virtual_len`` cache positions."""
     return -(-int(virtual_len) // int(page_size))
@@ -280,7 +286,7 @@ class SlotPages:
 
     def __init__(self, module, num_slots, cache_len, page_size, num_pages,
                  chunk, share_prefixes, stats):
-        self.page = max(8, -(-int(page_size) // 8) * 8)
+        self.page = page_rows(page_size)
         self.pages_per_slot = pages_for(cache_len, self.page)
         self.cache_len = self.pages_per_slot * self.page
         self.num_slots = int(num_slots)

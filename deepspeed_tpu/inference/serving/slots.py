@@ -200,6 +200,27 @@ def make_decode_block_fn(module, sample_fn, param_transform, block,
     return jax.jit(decode_block, donate_argnums=(1, 2))
 
 
+def admission_chunk(module, prefill_chunk):
+    """``serving.prefill_chunk`` as the server runs it: aligned like the
+    engine's ``prefill_chunk_size`` (multiple of 8, floor 8, cap 512 — the
+    chunk kernel's bounds; a model whose chunk path has other bounds
+    names its own cap)."""
+    return min(getattr(module, "prefill_chunk_cap", 512),
+               max(8, -(-int(prefill_chunk) // 8) * 8))
+
+
+def chunk_write_form(module, chunk, page):
+    """The form in which :func:`make_chunk_fn`'s program writes a chunk's
+    K/V into the pool: ``registry.paged_write_form`` at the server's
+    chunk and page under the marker that program sets — what the traced
+    write asks — or ``None`` for a model whose pools hold no K/V pages
+    (latent attention writes its own rows, ``models/dots3.py``)."""
+    from deepspeed_tpu.ops.transformer.registry import paged_write_form
+    if "k" not in jax.eval_shape(lambda: module.init_paged_cache(2, page)):
+        return None
+    return paged_write_form(chunk, page, page_runs=True)
+
+
 def make_chunk_fn(module, param_transform):
     """The admission-prefill chunk program:
     ``fn(params, cache, pages, chunk_ids, start, logits_at)`` — same
@@ -223,9 +244,13 @@ def make_chunk_fn(module, param_transform):
     def chunk_step(params, cache, pages, chunk_ids, start, logits_at):
         live = jnp.arange(chunk_ids.shape[1])[None, :] \
             <= logits_at[:, None] if routed else None
+        # SlotPages.reserve starts every chunk on a common multiple of
+        # page and chunk, which no shape shows: the marker says it, and
+        # the K/V write goes in as page runs (registry.paged_write_form)
         logits, cache, counts = _decode(
             module, deq(params), chunk_ids,
-            {**cache, "pages": pages}, start, live=live,
+            {**cache, "pages": pages,
+             "page_runs": jnp.zeros((), jnp.int32)}, start, live=live,
             logits_at=logits_at)
         if routed:
             return logits, cache, _expert_load(counts[None], share)

@@ -322,7 +322,8 @@ class Lfm2Model(nn.Module):
             marker = {"per_row": jnp.zeros((), jnp.int32)}
         else:
             positions = (start_pos + jnp.arange(input_ids.shape[1]))[None]
-            marker = {}
+            marker = {"page_runs": cache["page_runs"]} \
+                if "page_runs" in cache else {}
         last = None if logits_at is None else logits_at[0].astype(jnp.int32)
         for i, layer in enumerate(self.layers):
             if cfg.layer_types[i] == "conv":

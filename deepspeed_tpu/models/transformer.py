@@ -495,8 +495,9 @@ def _cache_data(cache):
     return {kk: cache[kk] for kk in _CACHE_DATA_KEYS if kk in cache}
 
 
-def _paged_write(cache, k_new, v_new, ks_new, vs_new, positions, per_row):
-    """Scatter this step's K/V rows into a PAGED cache pool.
+def _paged_write(cache, k_new, v_new, ks_new, vs_new, positions, per_row,
+                 page_runs=False):
+    """Write this step's K/V rows into a PAGED cache pool.
 
     Pool layout (``init_paged_cache``): ``[L, num_pages, page_size,
     KVH*D]``; ``cache["pages"]`` is the per-row page table ``[B,
@@ -508,12 +509,39 @@ def _paged_write(cache, k_new, v_new, ks_new, vs_new, positions, per_row):
     reserved TRASH page 0: retired/free lanes keep scattering masked
     garbage there instead of into reclaimed pages (the paged analog of
     the dense path's "dead lanes write into their own lane" safety
-    argument)."""
+    argument).
+
+    ``page_runs`` (static; ``ops/transformer/registry.py::
+    paged_write_form`` decides it): the row-uniform block is WHOLE
+    PAGES, or one run inside one page, and its start is run-aligned —
+    the caller's promise, which no shape shows.  Each run then goes into
+    the pool as one contiguous ``dynamic_update_slice`` at ``(layer,
+    pages[b, start // page + j], start % page, 0)``, in place on a
+    donated pool: the same values on the same pool rows as the scatter,
+    one block write a page in place of ``page`` row updates."""
     li = cache["layer"]
     pages = cache["pages"]                      # [B, n_pages] int32
     page = cache["k"].shape[-2]
     B_, S_ = k_new.shape[0], k_new.shape[1]
-    if per_row and S_ == 1:
+    if page_runs:
+        start = positions[0, 0].astype(jnp.int32)
+        run = min(S_, page)
+        first = start // page
+        # whole pages start at row 0 of each; a shorter run at its offset
+        off = start % page if run < page else jnp.zeros((), jnp.int32)
+        lane0 = jnp.zeros((), jnp.int32)
+
+        def w(buf, new):
+            new = new.astype(buf.dtype)
+            for b in range(B_):
+                for j in range(S_ // run):
+                    # indexed one entry at a time, so a run's page index
+                    # clamps to the table row like the scatter's would
+                    buf = jax.lax.dynamic_update_slice(
+                        buf, new[b, j * run:(j + 1) * run][None, None],
+                        (li, pages[b, first + j], off, lane0))
+            return buf
+    elif per_row and S_ == 1:
         pos = positions[:, 0]                   # [B] per-row decode
         pidx = (pos // page).astype(jnp.int32)
         off = (pos % page).astype(jnp.int32)
@@ -1002,8 +1030,12 @@ class Transformer(nn.Module):
         marker = {"per_row": jnp.zeros((), jnp.int32)} if per_row_pos else {}
         if cache is not None and "pages" in cache:
             # paged pool: the per-row page table threads every layer's
-            # cache dict unchanged (pages are constant across layers)
+            # cache dict unchanged (pages are constant across layers),
+            # and so does the caller's promise of run-aligned starts
+            # (``_paged_write``)
             marker["pages"] = cache["pages"]
+            if "page_runs" in cache:
+                marker["page_runs"] = cache["page_runs"]
         # from-zero multi-token prefill, decided where the start is
         # still STATICALLY visible (generation passes a literal 0;
         # inside the remat-wrapped block `positions` is a tracer):

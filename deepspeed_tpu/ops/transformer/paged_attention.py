@@ -485,11 +485,14 @@ def _chunk_loop_vmem_bytes(c, h, d, bk, kvhd, kv_itemsize, q_itemsize):
     """The VMEM the chunk kernel's block loop asks for: two K and two V
     blocks, a head group's [C, bk] float32 score tiles, the q and output
     blocks (double-buffered by the pipeline), the online-softmax
-    scratch, and headroom."""
-    return max(64 * 1024 * 1024,
-               4 * bk * kvhd * kv_itemsize + 6 * c * bk * 4
-               + 4 * c * h * d * q_itemsize + _chunk_scratch_bytes(c, h, d)
-               + 16 * 1024 * 1024)
+    scratch, and headroom — and no more (29 MiB at OPT-1.3B's chunk of
+    128).  What the kernel is granted XLA cannot use across it: under a
+    64 MiB floor the chunk step's next weights could not be prefetched
+    into VMEM while the kernel ran, 0.66 ms of a 4.70 ms chunk
+    (PERF.md, PR 36)."""
+    return (4 * bk * kvhd * kv_itemsize + 6 * c * bk * 4
+            + 4 * c * h * d * q_itemsize + _chunk_scratch_bytes(c, h, d)
+            + 16 * 1024 * 1024)
 
 
 def paged_chunk_prefill_attention(q, k_pool, v_pool, starts, pages, *,

@@ -113,10 +113,30 @@ def kernel_modes(*, paged, has_bias=False, has_window=False):
     }
 
 
+def paged_write_form(block, page, *, page_runs, per_row=False):
+    """How a ``block``-token write reaches a paged pool of ``page``-row
+    pages: ``"page_runs"`` — one in-place block write a page run — or
+    ``"row_scatter"`` through the table, row by row.  All static, like
+    :func:`select_kernel`: the traced write (``_write_cache``) and the
+    host-side attribution (``ServingEngine.stats["chunk_write"]``,
+    ``prefill_plan`` reasons) ask this one predicate.
+
+    Page runs need a row-uniform multi-token block that is whole pages
+    (``block % page == 0``) or one run inside a page (``page % block ==
+    0``), AND a run-aligned start — which no shape shows, so the caller
+    says it with the cache's ``page_runs`` marker (the serving chunk
+    program does: ``SlotPages.reserve`` starts every chunk on a common
+    multiple of page and chunk)."""
+    if page_runs and not per_row and block > 1 \
+            and (block % page == 0 or page % block == 0):
+        return "page_runs"
+    return "row_scatter"
+
+
 def _cache_markers(cache):
     """The bookkeeping keys a write must thread through unchanged."""
-    return {kk: cache[kk] for kk in ("layer", "pages", "per_row")
-            if kk in cache}
+    return {kk: cache[kk] for kk in ("layer", "pages", "per_row",
+                                     "page_runs") if kk in cache}
 
 
 def _quant_rows(new, kvh):
@@ -133,16 +153,21 @@ def _quant_rows(new, kvh):
 
 def _write_cache(cache, k_new, v_new, ks_new, vs_new, positions):
     """This step's K/V rows into the cache — ONE implementation of what
-    used to be three branch copies: paged pools scatter through the page
-    table; monolithic caches (layer-stacked or per-layer) pick the
-    per-row-single-token scatter, the per-row multi-token scatter
-    (speculative verify) or the row-uniform dynamic_update_slice."""
+    used to be three branch copies: paged pools take page runs or scatter
+    through the page table (:func:`paged_write_form`); monolithic caches
+    (layer-stacked or per-layer) pick the per-row-single-token scatter,
+    the per-row multi-token scatter (speculative verify) or the
+    row-uniform dynamic_update_slice."""
     import jax
     from deepspeed_tpu.models.transformer import _paged_write
     markers = _cache_markers(cache)
     if "pages" in cache:
+        per_row = "per_row" in cache
+        form = paged_write_form(k_new.shape[1], cache["k"].shape[-2],
+                                page_runs="page_runs" in cache,
+                                per_row=per_row)
         data = _paged_write(cache, k_new, v_new, ks_new, vs_new, positions,
-                            per_row=("per_row" in cache))
+                            per_row=per_row, page_runs=form == "page_runs")
         return {**data, **markers}
     B_, S_ = k_new.shape[0], k_new.shape[1]
     li = cache.get("layer")

@@ -149,6 +149,12 @@ D = "jit(decode_block)/while/body/closed_call/"
      "attn.mla_decompress", "fwd"),
     ("jit(chunk_step)/Transformer.decode/layers_0/attn/cache.write/scatter",
      "cache.write", "fwd"),
+    # the chunk's K/V as page runs (the name the compiled step carries)
+    ("jit(chunk_step)/Transformer.decode/Transformer.hidden_states/"
+     "checkpoint/layers_23/attn/cache.write/dynamic_update_slice",
+     "cache.write", "fwd"),
+    ("jit(chunk_step)/Lfm2Model.decode/layers_2/self_attn/cache.write/"
+     "dynamic_update_slice", "cache.write", "fwd"),
     (F + "layers_0/mlp/down_proj/dot_general", "mlp", "fwd"),
     (T + "jvp(Transformer)/Transformer.hidden_states/checkpoint/layers_0/"
      "mlp/up_proj/dot_general", "mlp", "bwd"),

@@ -643,6 +643,10 @@ def test_metrics_round_trip_histograms_escaping_and_gating(
                 if n == "dstpu_serving_fairness_window_tokens"]
     assert any(la.get("client") == NASTY_CLIENT for la, _ in fairness), \
         fairness
+    # a stat that is a NAME is a gauge of 1 with the name in a label
+    assert [(la, v) for n, la, v in samples
+            if n == "dstpu_serving_chunk_write"] \
+        == [({"value": srv.stats["chunk_write"]}, 1.0)]
     # the five histogram families, each parsing as a real histogram
     for fam in ("dstpu_serving_ttft_seconds",
                 "dstpu_serving_tbt_seconds",

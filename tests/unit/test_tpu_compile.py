@@ -14,6 +14,8 @@ under xdist exactly one worker — the one handed this file — may do it,
 and only after collection.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -273,6 +275,62 @@ def test_paged_chunk_block_loop_fits_vmem(heads, head_dim, pages_per_slot,
     assert asked <= 100 * 2 ** 20, f"asks for {asked / 2 ** 20:.0f} MiB"
     compiled = _compile(fn, shapes, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_chunk_step_writes_page_runs_in_place(one_chip, mosaic):
+    """The WHOLE serving chunk step at OPT-1.3B's widths and the batch
+    cell's pool (24 layers x 697 pages of 64 rows: 4.4 GB a buffer): the
+    chunk's K/V goes in as page runs — ``dynamic_update_slice`` under
+    ``cache.write``, no ``scatter`` — and XLA keeps the pool in place
+    through the chain of 96 updates interleaved with the kernel's reads:
+    no pool-sized ``copy``, both pools aliased input -> output, nothing
+    pool-sized among the temporaries.  And the chunk kernel asks for
+    little enough VMEM that XLA keeps a layer's next weights in flight
+    ACROSS it: the MLP's up-projection (33.5 MB a layer) is sliced into
+    VMEM asynchronously, started before the kernel and awaited after it
+    — under the kernel's old 64 MiB floor it was read from HBM when the
+    matmul ran, and only the scatter gave XLA a window to prefetch in."""
+    from deepspeed_tpu.inference.serving.slots import make_chunk_fn
+    from deepspeed_tpu.models.opt import opt_config
+    from deepspeed_tpu.models.transformer import Transformer
+    pages, page, chunk, slot_pages = 697, 64, 128, 29
+    model = Transformer(opt_config(
+        "opt-125m", hidden_size=HD, num_layers=L, num_heads=H,
+        ffn_hidden_size=4 * HD, vocab_size=50272, max_seq_len=2048,
+        dtype="bfloat16", scan_layers=False))
+    on_chip = lambda tree, dtype=None: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                       sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), I32))), BF16)
+    pool = on_chip(jax.eval_shape(
+        lambda: model.init_paged_cache(pages, page, BF16)))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+    compiled = make_chunk_fn(model, None).lower(
+        params, pool, ints(1, slot_pages), ints(1, chunk), ints(),
+        ints(1)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    writes = {line.split("cache.write/")[1].split('"')[0]
+              for line in text.splitlines() if "cache.write/" in line}
+    assert "dynamic_update_slice" in writes and "scatter" not in writes, \
+        writes
+    pool_shape = f"bf16[{L},{pages},{page},{HD}]"
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if " copy(" in line and pool_shape in line.split(" copy(")[0]]
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * L * pages * page * HD * 2
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 16, mem.temp_size_in_bytes
+    asked = paged_mod._chunk_loop_vmem_bytes(
+        chunk, H, D, paged_mod._chunk_block_pages(page, slot_pages) * page,
+        HD, 2, 2)
+    assert asked <= 40 * 2 ** 20, f"asks for {asked / 2 ** 20:.0f} MiB"
+    prefetched = {int(m) for m in re.findall(
+        r"slice-start\(%params__params____layers_(\d+)____mlp____up_proj",
+        text)}
+    assert len(prefetched) >= L - 2, sorted(prefetched)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))

@@ -40,6 +40,11 @@ The pieces:
   ``models/lfm2.py``'s short-convolution layers), one state ROW a slot in
   a pool of ``1 + num_slots`` rows — row 0 the trash row, as page 0 is
   the trash page — shipped as the LAST entry of the slot's table row.
+  And a lane need not hold a row a POSITION: a model whose lane pages hold
+  one row a stride of positions (``lane_stride``, ``models/evabyte.py``:
+  one pooled K/V row a 16-byte chunk) has every page count here reckoned
+  in ROWS, ``ceil(positions / stride)``; ``serving.max_cache_len`` and
+  every position the scheduler speaks of stay positions.
 """
 
 import hashlib
@@ -287,8 +292,11 @@ class SlotPages:
     def __init__(self, module, num_slots, cache_len, page_size, num_pages,
                  chunk, share_prefixes, stats):
         self.page = page_rows(page_size)
-        self.pages_per_slot = pages_for(cache_len, self.page)
-        self.cache_len = self.pages_per_slot * self.page
+        # positions a lane row stands for (1 for every model but one that
+        # names a ``lane_stride``): all page arithmetic below is in rows
+        self.stride = int(getattr(module, "lane_stride", 1))
+        self.pages_per_slot = self._lane_pages(cache_len)
+        self.cache_len = self.pages_per_slot * self.page * self.stride
         self.num_slots = int(num_slots)
         self.num_pages = int(num_pages) \
             or self.num_slots * self.pages_per_slot + 1
@@ -324,11 +332,13 @@ class SlotPages:
         self.page_bytes = 0
         self.table_width = self.pages_per_slot + self.ring_pages \
             + bool(self.state_kinds)
-        if (self.ring_pages or self.state_kinds) and self.share_prefixes:
+        if (self.ring_pages or self.state_kinds or self.stride > 1) \
+                and self.share_prefixes:
             # a shared prefix's pages hold the positional rows of the lane
             # pools only: the sharer's rings would miss the window's rows
             # before its first private position, and its state row the
-            # state at the shared boundary
+            # state at the shared boundary (and a strided lane's page
+            # boundary is no position the prefix index hashes to)
             self.share_prefixes = False
             stats["prefix_sharing_refused"] = 1
         if self.state_kinds:
@@ -378,16 +388,24 @@ class SlotPages:
         self._buffer = None
 
     # ---- slots ----
+    def _lane_pages(self, positions):
+        """Lane pages that back ``positions`` cache positions: a row a
+        ``stride`` of them."""
+        return pages_for(-(-int(positions) // self.stride), self.page)
+
     def cannot_hold(self, positions):
         """Why the pool can NEVER back a request of ``positions`` cache
         positions, or ``None`` when it can: such a request must not
         enter the queue — with every other slot drained it would still
         stall admission forever."""
-        n = pages_for(positions, self.page)
+        n = self._lane_pages(positions)
         if n <= self._pool.allocatable:
             return None
         return (f"{n} pages ({positions} positions at page_size="
-                f"{self.page}) but the pool holds "
+                f"{self.page}"
+                + (f", a row a {self.stride} positions"
+                   if self.stride > 1 else "")
+                + f") but the pool holds "
                 f"{self._pool.allocatable} allocatable pages "
                 f"(num_pages={self.num_pages} incl. trash)")
 
@@ -425,7 +443,7 @@ class SlotPages:
         # the slot's virtual extent: decode writes through P+max_new-1,
         # the padded last chunk writes through s0+n_chunks*C-1
         virt = max(P + max_new, s0 + n_chunks * chunk)
-        need_private = pages_for(virt, page) - m
+        need_private = self._lane_pages(virt) - m
         got = pool.alloc(need_private)
         if got is None and self.share_prefixes:
             self._stats["page_evictions"] += self._prefix.evict(
@@ -527,10 +545,15 @@ class SlotPages:
         if self.ring_pages:
             held = int((self._table[:, self.pages_per_slot]
                         != TRASH_PAGE).sum())
-            text += (f"; by row kind: latent + index rows "
-                     f"{self._pool.in_use} pages, window rows "
+            lane_rows, ring_rows = getattr(
+                self._module, "row_kinds",
+                ("latent + index rows", "window rows"))
+            text += (f"; by row kind: {lane_rows} "
+                     f"{self._pool.in_use} pages, {ring_rows} "
                      f"{held * self.ring_pages}/{self.window_pages - 1} "
                      f"pages ({self.ring_pages} a slot, a ring)")
+        if self.stride > 1:
+            text += f"; a lane row a {self.stride} positions"
         if self.state_kinds:
             text += (f"; state ({', '.join(self.state_kinds)}): "
                      f"state_rows_live {len(self._rows)}/"
@@ -554,7 +577,7 @@ class SlotPages:
         that counts its own attention work (a ``chunk_work`` method, the
         names its own) adds it, over the chunk's REAL positions — through
         ``live_end - 1``, the padded tail left out."""
-        reach = pages_for(end, self.page)
+        reach = self._lane_pages(end)
         work = getattr(self._module, "chunk_work", None)
         return {"kv_pages": layers * min(reach, self.pages_per_slot),
                 "kv_pages_table": layers * self.pages_per_slot,
@@ -572,7 +595,7 @@ class SlotPages:
         the whole table would take — their ratio is the share of the
         table that is live."""
         work = getattr(self._module, "block_work", None)
-        return {"kv_pages": sum(pages_for(first + i, self.page)
+        return {"kv_pages": sum(self._lane_pages(first + i)
                                 for first, steps in live
                                 for i in range(steps)),
                 "kv_pages_table":

@@ -204,9 +204,15 @@ def admission_chunk(module, prefill_chunk):
     """``serving.prefill_chunk`` as the server runs it: aligned like the
     engine's ``prefill_chunk_size`` (multiple of 8, floor 8, cap 512 — the
     chunk kernel's bounds; a model whose chunk path has other bounds
-    names its own cap)."""
-    return min(getattr(module, "prefill_chunk_cap", 512),
-               max(8, -(-int(prefill_chunk) // 8) * 8))
+    names its own cap, and one whose chunk must fit its cache's geometry —
+    ``models/evabyte.py``: no chunk straddles a window — says why a chunk
+    does not, ``prefill_chunk_fault``)."""
+    chunk = min(getattr(module, "prefill_chunk_cap", 512),
+                max(8, -(-int(prefill_chunk) // 8) * 8))
+    fault = getattr(module, "prefill_chunk_fault", lambda chunk: None)(chunk)
+    if fault:
+        raise ValueError(f"serving.prefill_chunk={prefill_chunk}: {fault}")
+    return chunk
 
 
 def chunk_write_form(module, chunk, page):

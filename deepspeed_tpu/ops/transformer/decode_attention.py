@@ -404,7 +404,7 @@ def _init_chunk(st):
 
 
 def _chunk_block_update(st, ik, start, k_ref, v_ref, ks, vs, *, scale,
-                        block_k, c, kvh, g, d, masked=True):
+                        block_k, c, kvh, g, d, masked=True, prefix=False):
     """Fold KV block ``ik`` (virtual positions ``ik*block_k ..``) into the
     chunk's online-softmax state: per head ONE ``[C, D] x [D, bk]`` score
     matmul, a ``[C, bk]`` float32 tile for max / exp / sum, ONE
@@ -413,9 +413,13 @@ def _chunk_block_update(st, ik, start, k_ref, v_ref, ks, vs, *, scale,
     slabs (refs); ``ks``/``vs``: its ``[bk, KVH]`` dequant scales (None
     for an unquantized cache).  ``masked=False`` is for a block wholly
     under the causal diagonal (every position ``<= start``): the same
-    numbers without the mask's compares and selects.  The one per-block
-    update of chunked prefill, whichever driver supplies the block
-    sequence.
+    numbers without the mask's compares and selects.  ``prefix=True``
+    (with ``masked``): the block's keys are no positions of the chunk's
+    sequence but rows every query sees alike up to a bound — ``start`` is
+    then that bound, and row ``r`` is live while ``ik*block_k + r <
+    start`` (EVA's chunk summaries, ``eva_attention.py``).  The one
+    per-block update of chunked prefill, whichever driver supplies the
+    block sequence.
 
     Heads are walked in GROUPS of whole 128-lane tiles of the slabs (two
     kv heads of 64, one of 128, with the ``g`` query heads of each): a
@@ -431,9 +435,12 @@ def _chunk_block_update(st, ik, start, k_ref, v_ref, ks, vs, *, scale,
     if masked:
         pos = ik * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)                  # [1, bk]
-        qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (c, 1), 0)                        # [C, 1]
-        live = pos <= qpos                               # [C, bk] causal+tail
+        if prefix:
+            live = pos < start                           # [1, bk], all rows
+        else:
+            qpos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (c, 1), 0)                    # [C, 1]
+            live = pos <= qpos                           # [C, bk] causal+tail
     hpg = 128 // d if 128 % d == 0 and kvh % (128 // d) == 0 else 1
     lanes, qlanes = hpg * d, hpg * g * d
     tiled = lanes % 128 == 0 and not quant

@@ -305,9 +305,9 @@ def _scope_to_path(op_name):
 # Device time by part (docs/observability.md "Device time by part")
 # --------------------------------------------------------------------- #
 PARTS = ("embed", "attn.proj", "attn.core", "attn.mla_decompress",
-         "cache.write", "mlp", "moe.route", "moe.experts", "conv.short",
-         "norm", "residual", "head", "loss", "optim", "scan.stack", "slots",
-         "comm", "xla.prefetch")
+         "attn.eva", "eva.summarise", "cache.write", "mlp", "moe.route",
+         "moe.experts", "conv.short", "norm", "residual", "head", "loss",
+         "optim", "scan.stack", "slots", "comm", "xla.prefetch")
 PHASES = ("fwd", "bwd", "replay")
 # collectives are found by opcode, whatever scope they carry (the list is
 # ``benchmark/trace.py::COLLECTIVES``)
@@ -339,6 +339,7 @@ SCOPE_PARTS = (
     (r"optim\.(accumulate|clip|update)", "optim"),
     (r"slots\.(state|expert_load)", "slots"),
     (r"conv\.short|conv/(in_proj|out_proj)", "conv.short"),
+    (r"eva\.summarise", "eva.summarise"),
     # the layer scan's own operations (``scan_layers``): a layer's slice
     # out of the stacked parameters and saved residuals, the saves' and the
     # gradients' writes back into the stacks, the stacks' zeros and copies
@@ -353,7 +354,9 @@ SCOPE_PARTS = (
     (r"\w*attn\.(flash_(fwd|dq|dkv)|block_sparse_fwd|chunk_prefill|decode"
      r"|paged_decode|paged_chunk_prefill|dsa_index|dsa_topk"
      r"|mla_chunk_prefill|mla_window|mla_sparse_decode)\w*", "attn.core"),
+    (r"\w*attn\.eva_(decode|chunk)\w*", "attn.eva"),
     # flax modules and their methods
+    (r"\w+\._eva_attend_(chunk|step)", "attn.eva"),
     (r"\w+\._kv_up", "attn.mla_decompress"),
     (r"\w+\._(chunk_full|chunk_window|attend)", "attn.core"),
     (r"(q|k|v|qkv|o|out)_proj|(q|k)_(layer)?norm|\w+\._(project|out|index)",

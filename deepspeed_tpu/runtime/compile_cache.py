@@ -78,7 +78,7 @@ _CHECKOUT_CACHE_DIR = os.path.join(
 # ``profiler.SCOPE_PARTS`` reads raises it, and every entry is compiled
 # once more — not on every moved source line, which metadata in JAX's key
 # would cost.
-SCOPES_VERSION = 1
+SCOPES_VERSION = 2
 
 
 def env_cache_dir():

@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from deepspeed_tpu.moe import dropless as moe_mod
 from deepspeed_tpu.ops.sparse_attention import block_sparse
 from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
+                                           eva_attention as eva_mod,
                                            flash_attention as flash_mod,
                                            latent_attention as latent_mod,
                                            paged_attention as paged_mod)
@@ -66,7 +67,7 @@ def mosaic(monkeypatch, no_persistent_cache):
     """``_interpret()`` asks ``jax.default_backend()``, which is the CPU
     here: steer it in the test, not through an option of the program."""
     for mod in (flash_mod, decode_mod, paged_mod, block_sparse, moe_mod,
-                latent_mod):
+                latent_mod, eva_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -209,7 +210,63 @@ def _moe_scored(tokens, hidden=2048, experts=64, width=1536, top_k=4):
                 ((experts, width, hidden), BF16)]
 
 
+# evabyte-serve-bytedoc-batch: 32 heads of 128, 8 layers, page 64, window
+# 2048 = 32 ring pages a slot, 24 slots, 14 lane pages a slot (one summary
+# row a 16-byte chunk: 13,376 positions = 836 rows)
+EVA = dict(heads=32, dim=128, layers=8, page=64, window=2048, chunk_size=16,
+           slots=24, lane_pages=14)
+
+
+def _eva_pools():
+    e = EVA
+    width = e["heads"] * e["dim"]
+    ring = ((e["layers"], 1 + e["slots"] * e["window"] // e["page"],
+             e["page"], width), BF16)
+    lane = ((e["layers"], 1 + e["slots"] * e["lane_pages"], e["page"],
+             width), BF16)
+    return [ring, ring, lane, lane]
+
+
+def _eva_decode():
+    """One layer-step of the decode block: 24 lanes, each its ring pages
+    and its visible summary pages through one VMEM ring of pages, the
+    step's K/V row written as an 8-row stripe of the aliased ring pools."""
+    e = EVA
+    n, vec = e["slots"], ((e["slots"], e["heads"], e["dim"]), BF16)
+    args = [vec] + _eva_pools() + [
+        ((n,), I32), ((n, e["window"] // e["page"]), I32),
+        ((n, e["lane_pages"]), I32), vec, vec]
+
+    def fn(q, k_ring, v_ring, k_sum, v_sum, pos, ring, lane, new_k, new_v):
+        return eva_mod.eva_decode_attention(
+            q, k_ring, v_ring, k_sum, v_sum, pos, ring, lane,
+            layer=e["layers"] - 1, window=e["window"],
+            chunk_size=e["chunk_size"], new_k=new_k, new_v=new_v)
+    return fn, args
+
+
+def _eva_chunk(chunk):
+    """One layer-chunk of the chunk step: ``chunk`` queries in blocks of
+    512 along the grid — a chunk of a whole window is four —, each block's
+    [512, 512] score tiles, two K and two V blocks of 8 pages, q, output
+    and online-softmax state in VMEM: compiling is the proof they fit."""
+    e = EVA
+    args = [((1, chunk, e["heads"], e["dim"]), BF16)] + _eva_pools() + [
+        ((), I32), ((1, e["window"] // e["page"]), I32),
+        ((1, e["lane_pages"]), I32)]
+
+    def fn(q, k_ring, v_ring, k_sum, v_sum, start, ring, lane):
+        return eva_mod.eva_chunk_attention(
+            q, k_ring, v_ring, k_sum, v_sum, start, ring, lane,
+            layer=e["layers"] - 1, window=e["window"],
+            chunk_size=e["chunk_size"])
+    return fn, args
+
+
 CASES = {
+    "evabyte_eva_decode_24x46": _eva_decode,
+    "evabyte_eva_chunk_c512": lambda: _eva_chunk(512),
+    "evabyte_eva_chunk_c2048": lambda: _eva_chunk(2048),
     "dots3_dsa_index_c2048": _dsa_index,
     "dots3_dsa_topk_c2048": _dsa_topk,
     "dots3_mla_chunk_prefill_c2048": lambda: _mla_flash(
@@ -331,6 +388,59 @@ def test_chunk_step_writes_page_runs_in_place(one_chip, mosaic):
         r"slice-start\(%params__params____layers_(\d+)____mlp____up_proj",
         text)}
     assert len(prefetched) >= L - 2, sorted(prefetched)
+
+
+@pytest.mark.parametrize("program", ["chunk_step", "decode_block"])
+def test_evabyte_slot_programs_compile_at_the_cells_sizes(program, one_chip,
+                                                          mosaic):
+    """The two programs ``evabyte-serve-bytedoc-batch`` runs, whole, as
+    ``serving/slots.py`` builds them over ``SlotPages``' pools at the cell's
+    own settings: eight Mosaic calls each (a layer's ``attn.eva_chunk`` /
+    ``attn.eva_decode``), the pools aliased input -> output, and the 12.5 GB
+    the cell's sizing reckons — weights 3.26 GB, 24 rings of 32 pages and
+    the summary lane, 8 layers — inside one chip."""
+    import os
+    from benchmark import spec
+    from deepspeed_tpu.inference.serving import slots
+    from deepspeed_tpu.inference.serving.paging import SlotPages
+    bench = spec.Benchmark(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    cell = bench.cell("evabyte-serve-bytedoc-batch")
+    module = bench.family("evabyte").program_model(cell["config"])
+    s = cell["system"]["serving"]
+    chunk = slots.admission_chunk(module, s["prefill_chunk"])
+    pages = SlotPages(module, s["num_slots"], s["max_cache_len"],
+                      s["page_size"], 0, chunk, False, {})
+    assert (pages.pages_per_slot, pages.ring_pages) == (14, 32)
+    on_chip = lambda tree, dtype=None: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                       sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(lambda: module.init(
+        jax.random.key(0), {"input_ids": jnp.zeros((1, 8), I32)})), BF16)
+    pool = on_chip(jax.eval_shape(lambda: pages.new_pools(BF16)))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+    if program == "chunk_step":
+        compiled = slots.make_chunk_fn(module, None).lower(
+            params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
+            ints(1)).compile()
+    else:
+        n = s["num_slots"]
+        state = on_chip({k: jnp.asarray(v) for k, v in
+                         slots.init_slot_state(n).items()})
+        rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                   sharding=one_chip)
+        compiled = slots.make_decode_block_fn(
+            module, lambda logits, rng: jnp.argmax(logits, -1), None,
+            s["decode_block"], pages.cache_len).lower(
+                params, pool, state, ints(n, pages.table_width),
+                rng).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 8
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * 2 for x in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 12.4e9 < total < 14e9, f"{total / 1e9:.2f} GB"
 
 
 @pytest.mark.parametrize("case", sorted(CASES))

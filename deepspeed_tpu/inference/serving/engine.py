@@ -135,6 +135,7 @@ from deepspeed_tpu.inference.serving.slo import (CircuitBreaker,
                                                  TERMINAL_STATUSES,
                                                  TokenStream)
 from deepspeed_tpu.inference.serving.slots import (admission_chunk,
+                                                   chunk_rows,
                                                    chunk_write_form,
                                                    init_slot_state,
                                                    make_admit_fn,
@@ -217,8 +218,11 @@ class _PendingPrefill:
         self.n_chunks = -(-(self.fill_len - start) // chunk)
         self.ids_pad = np.zeros((1, self.n_chunks * chunk), np.int32)
         self.ids_pad[0, :self.fill_len - start] = fill[start:]
-        self.ci = 0                      # chunks completed
-        self.sel = None                  # last-real-position logits [1,1,V]
+        self.ci = 0                      # chunks handed to a dispatch
+        # the logits [R, 1, V] of the dispatch that held the prompt's last
+        # real position, and which of its rows that chunk rode
+        self.sel = None
+        self.sel_row = 0
         # speculative serving: the DRAFT model's single-lane prefill
         # cache (the prompt's K/V must land in the draft cache too)
         self.draft_lane = None
@@ -310,6 +314,7 @@ class ServingEngine:
         # manager, which counts prefix hits and evictions into them
         self.stats = {"iterations": 0, "decode_calls": 0,  # guarded-by: _lock
                       "decode_tokens": 0, "prefill_tokens": 0,
+                      "prefill_dispatches": 0, "prefill_rows": 0,
                       "completed": 0, "admitted": 0, "wall_secs": 0.0,
                       "sync_secs": 0.0, "shed": 0, "cancelled": 0,
                       "resumed": 0, "prefix_lookups": 0, "prefix_hits": 0,
@@ -444,6 +449,12 @@ class ServingEngine:
         form = chunk_write_form(self.module, self.chunk, self.page)
         if form is not None:
             self.stats["chunk_write"] = form
+        # chunk rows a prefill dispatch takes (docs/serving.md "Prefill
+        # dispatches"): what the chunk kernel's bound holds of the chunk
+        # the user set, 1 where rows would depend on each other through
+        # more than the K/V pages — observed, not set
+        self.chunk_rows = chunk_rows(self.module, self.chunk, self.page,
+                                     self.speculative)
         self._decode_fn = self._propose_fn = self._verify_fn = None
         self._draft_chunk_fn = self._draft_admit_fn = None
         # Page tables are traced arguments (rebuilt host-side per
@@ -463,7 +474,7 @@ class ServingEngine:
             engine._tags[id(self._decode_fn)] = (
                 "serving_decode", self.num_slots, self.num_pages,
                 self.page, self.block, sampling_key)
-        self._admit_fn = make_admit_fn(sample_fn)
+        self._admit_fn = make_admit_fn(sample_fn, self.chunk_rows)
         engine._tags[id(self._admit_fn)] = (
             "serving_admit", self.num_slots, sampling_key)
         if self.speculative:
@@ -506,7 +517,7 @@ class ServingEngine:
         # chains chunk -> decode by donation).
         self._chunk_fn = make_chunk_fn(self.module, engine._deq)
         engine._tags[id(self._chunk_fn)] = (
-            "serving_prefill", self.chunk, self.page)
+            "serving_prefill", self.chunk, self.page, self.chunk_rows)
         for fn in (self._decode_fn, self._admit_fn, self._chunk_fn,
                    self._verify_fn, self._propose_fn,
                    self._draft_chunk_fn, self._draft_admit_fn):
@@ -1609,7 +1620,10 @@ class ServingEngine:
                          f"consecutive failures; last: "
                          f"{self._breaker.last_error})")
         lines.append(f"  {self._pages.describe()}, "
-                     f"{self.stats['admission_stalls']} admission stall(s)")
+                     f"{self.stats['admission_stalls']} admission stall(s), "
+                     f"{self.stats['prefill_rows']} chunk row(s) in "
+                     f"{self.stats['prefill_dispatches']} prefill "
+                     f"dispatch(es) of {self.chunk_rows}")
         return "\n".join(lines)
 
     def close(self):
@@ -1811,14 +1825,16 @@ class ServingEngine:
             eng._aot[sig] = compiled
             return {name: 0.0 if hit else dt}
 
-        row = jax.ShapeDtypeStruct((1, self.table_width), jnp.int32)
+        R = self.chunk_rows
+        rows = jax.ShapeDtypeStruct((R, self.table_width), jnp.int32)
         tables = jax.ShapeDtypeStruct((N, self.table_width), jnp.int32)
-        cargs = (eng._params, cache, row,
-                 jax.ShapeDtypeStruct((1, C), jnp.int32),
-                 jax.ShapeDtypeStruct((), jnp.int32),
-                 jax.ShapeDtypeStruct((1,), jnp.int32))
+        cargs = (eng._params, cache, rows,
+                 jax.ShapeDtypeStruct((R, C), jnp.int32),
+                 jax.ShapeDtypeStruct((R,) if R > 1 else (), jnp.int32),
+                 jax.ShapeDtypeStruct((R,), jnp.int32))
         report.update(warm(self._chunk_fn, cargs,
-                           f"serving_prefill:c{C}p{self.page}"))
+                           f"serving_prefill:c{C}p{self.page}"
+                           + (f"r{R}" if R > 1 else "")))
         if self.speculative:
             draft = jax.ShapeDtypeStruct((N, self.spec_k), jnp.int32)
             report.update(warm(
@@ -1923,68 +1939,130 @@ class ServingEngine:
         what the coming decode block will serve, read BEFORE this
         iteration's admissions (what ``_dispatch_decode`` tests):
         mirror-live slots plus unread admit events.  Whole chunks,
-        rounded down, never fewer than ``ceil(budget / chunk)``."""
+        rounded down, never fewer than ``ceil(budget / chunk)`` — and
+        where that is more than one dispatch's ``chunk_rows``, whole
+        dispatches, so a queue that lasts leaves no row dead."""
         live = int(self._mirror_active.sum()) \
             + sum(e[0] == "admit" for e in self._events)
         budget = self.config.prefill_token_budget
         if not budget:
             return 0, live, False
         base = -(-budget // self.chunk)
-        chunks = max(base,
-                     budget * self.num_slots // (self.chunk * max(live, 1)))
+        chunks = budget * self.num_slots // (self.chunk * max(live, 1))
+        if chunks > self.chunk_rows:
+            chunks -= chunks % self.chunk_rows
+        chunks = max(base, chunks)
         return chunks * self.chunk, live, chunks > base
 
     def _admit_under_budget(self, limit):  # lock-held: _lock
+        """Prefill dispatches until ``limit`` tokens are spent (live rows
+        x chunk) or nothing is left to prefill: each takes up to
+        ``chunk_rows`` granted chunks, in order."""
         spent = 0
         while spent < limit:
-            if self._pending is None:
-                if not self._queue or not self._free:
-                    return
-                req = self._pop_request()
-                pend = self._start_prefill(req)
-                if pend is None:
-                    # pool pressure: not enough free pages even after
-                    # evicting unreferenced prefix pages — the request
-                    # waits at the queue head until retirements free
-                    # pages (backpressure, never a partial grab)
-                    self._queue.appendleft(req)
-                    self.stats["admission_stalls"] += 1
-                    if self._flightrec is not None:
-                        self._flightrec.record(
-                            "admission_stall", rid=req.rid,
-                            pool_in_use=self._pages.in_use)
-                    return
-                if self._tracer is not None and req.t_trace is not None:
-                    # queue phase ends here: admission decided, the slot
-                    # is reserved and prefill chunks start streaming
-                    req.t_admit_start = self._tracer.now()
-                    self._hist.queue_wait.observe(
-                        req.t_admit_start - req.t_trace)
-                else:
-                    # tracing off: the one stamp RequestResult.queue_s
-                    # needs, on submit_t's clock
-                    req.t_admit_start = time.monotonic()
-                if self._flightrec is not None:
-                    self._flightrec.record(
-                        "admit_start", rid=req.rid, slot=req.slot,
-                        fill_len=pend.fill_len, chunks=pend.n_chunks)
-                if self._fairness is not None and not req.resumed:
-                    # charge admitted prefill work once, when admission
-                    # actually starts (a stall above retries the
-                    # same request without double-charging).  Resumed
-                    # requests charge NOTHING here: their prompt and
-                    # generated-so-far tokens were billed in the prior
-                    # incarnation and ride the snapshot balance — the
-                    # re-prefill is the server's preemption cost, not
-                    # the client's
-                    self._fairness.charge(req.client_id,
-                                          len(req.fill_ids))
-                self._pending = pend
-            done = self._run_prefill_chunk(self._pending)
-            spent += self.chunk
-            if done:
-                pend, self._pending = self._pending, None
-                self._dispatch_admit(pend)
+            cap = int(min(self.chunk_rows, (limit - spent) / self.chunk))
+            rows = self._fill_rows(cap)
+            if rows:
+                self._run_prefill_dispatch(rows)
+                spent += len(rows) * self.chunk
+            if len(rows) < cap:
+                return      # the queue, the slots or the pages ran out
+
+    def _fill_rows(self, cap):  # lock-held: _lock
+        """The next prefill dispatch's live rows, ``[(admission, chunk
+        index)]``, at most ``cap``: the pending prompt's next chunks, then
+        — while rows, a free slot, pages and the queue last — the next
+        admission's.  Rows fill in order, so every admission but the
+        last-started one rides to its end: ``_pending`` is that one, or
+        ``None``.  A prompt whose last chunk is placed publishes its
+        prefix pages here, BEFORE the next admission looks prefixes up:
+        whoever maps them attends them no sooner than this dispatch, whose
+        every layer writes all rows before any row attends."""
+        rows = []
+        try:
+            while len(rows) < cap:
+                if self._pending is None and not self._begin_admission():
+                    break
+                p = self._pending
+                take = min(p.n_chunks - p.ci, cap - len(rows))
+                rows += [(p, p.ci + i) for i in range(take)]
+                p.ci += take
+                if p.ci == p.n_chunks:
+                    self._pages.share(p.slot, p.fill)
+                    self._pending = None
+        except BaseException as e:
+            if rows:
+                # rows placed and never dispatched: a published prefix
+                # would promise K/V nobody wrote — lose them like a
+                # failed dispatch does
+                self._lose_rows(rows, f"admission failed before its "
+                                      f"prefill dispatch: "
+                                      f"{type(e).__name__}: {e}")
+            raise
+        return rows
+
+    def _lose_rows(self, rows, why):  # lock-held: _lock
+        """Every admission that rode a lost dispatch ends ``ABORTED``, and
+        with it everything in flight (the pool they wrote is gone)."""
+        riders = list(dict.fromkeys(p for p, _ in rows))
+        for p in riders:
+            self._give_back_draft_lane(p)
+            if p.req.status not in TERMINAL_STATUSES:
+                self._record_terminal(p.req, RequestStatus.ABORTED, why)
+        # _abort_in_flight counts the slots' requests and the pending one
+        self.stats["aborted"] = self.stats.get("aborted", 0) \
+            + sum(p is not self._pending for p in riders)
+        self._abort_in_flight(
+            f"prefill dispatch lost (request(s) "
+            f"{', '.join(str(p.req.rid) for p in riders)} lost)")
+
+    def _begin_admission(self):  # lock-held: _lock
+        """Pop the next request and reserve its slot and pages as
+        ``_pending``; False — nothing popped — when the queue or the free
+        slots ran out, or the pool cannot back the head request yet."""
+        if not self._queue or not self._free:
+            return False
+        req = self._pop_request()
+        pend = self._start_prefill(req)
+        if pend is None:
+            # pool pressure: not enough free pages even after
+            # evicting unreferenced prefix pages — the request
+            # waits at the queue head until retirements free
+            # pages (backpressure, never a partial grab)
+            self._queue.appendleft(req)
+            self.stats["admission_stalls"] += 1
+            if self._flightrec is not None:
+                self._flightrec.record(
+                    "admission_stall", rid=req.rid,
+                    pool_in_use=self._pages.in_use)
+            return False
+        if self._tracer is not None and req.t_trace is not None:
+            # queue phase ends here: admission decided, the slot
+            # is reserved and prefill chunks start streaming
+            req.t_admit_start = self._tracer.now()
+            self._hist.queue_wait.observe(
+                req.t_admit_start - req.t_trace)
+        else:
+            # tracing off: the one stamp RequestResult.queue_s
+            # needs, on submit_t's clock
+            req.t_admit_start = time.monotonic()
+        if self._flightrec is not None:
+            self._flightrec.record(
+                "admit_start", rid=req.rid, slot=req.slot,
+                fill_len=pend.fill_len, chunks=pend.n_chunks)
+        if self._fairness is not None and not req.resumed:
+            # charge admitted prefill work once, when admission
+            # actually starts (a stall above retries the
+            # same request without double-charging).  Resumed
+            # requests charge NOTHING here: their prompt and
+            # generated-so-far tokens were billed in the prior
+            # incarnation and ride the snapshot balance — the
+            # re-prefill is the server's preemption cost, not
+            # the client's
+            self._fairness.charge(req.client_id,
+                                  len(req.fill_ids))
+        self._pending = pend
+        return True
 
     def _start_prefill(self, req):  # lock-held: _lock
         """Reserve the head free slot and its pages (``paging.SlotPages.
@@ -2008,83 +2086,106 @@ class ServingEngine:
                 self.cache_len, self.engine.compute_dtype)
         return pend
 
-    def _run_prefill_chunk(self, p):  # lock-held: _lock
-        C = self.chunk
-        P = p.fill_len
-        # chunk ci covers absolute positions [start + ci*C, start +
-        # (ci+1)*C); start > 0 only for shared-prefix admissions
-        local = int(min(max(P - 1 - p.start - p.ci * C, 0), C - 1))
+    def _run_prefill_dispatch(self, rows):  # lock-held: _lock
+        """ONE dispatch of the chunk program over ``rows`` (``[(admission,
+        chunk index)]``, row order; fewer than ``chunk_rows`` leaves the
+        rest dead: an all-trash table row, start 0, logits never read),
+        then the fused admit of every prompt whose last chunk rode it."""
+        C, R, n = self.chunk, self.chunk_rows, len(rows)
+        tables = np.zeros((R, self.table_width), np.int32)   # trash rows
+        ids = np.zeros((R, C), np.int32)
+        starts = np.zeros((R,), np.int32)
+        last = np.zeros((R,), np.int32)
+        work = {}
+        for r, (p, ci) in enumerate(rows):
+            # chunk ci covers absolute positions [start + ci*C, start +
+            # (ci+1)*C); start > 0 only for shared-prefix admissions
+            tables[r] = self._pages.row(p.slot)[0]
+            ids[r] = p.ids_pad[0, ci * C:(ci + 1) * C]
+            starts[r] = p.start + ci * C
+            last[r] = min(max(p.fill_len - 1 - starts[r], 0), C - 1)
+            for key, val in self._pages.chunk_reach(
+                    self.module.config.num_layers, int(starts[r]) + C,
+                    live_end=p.fill_len).items():
+                work[key] = work.get(key, 0) + val
+        p0, ci0 = rows[0]
         try:
             with self._observe_dispatch(
-                    "prefill_chunk", rid=p.req.rid, slot=p.slot,
-                    chunk=p.ci, phase="prefill",
-                    **self._count_work(self._pages.chunk_reach(
-                        self.module.config.num_layers,
-                        p.start + (p.ci + 1) * C, live_end=P))):
-                # the chunk writes straight into the slot's pool pages
+                    "prefill_chunk", rid=p0.req.rid, slot=p0.slot,
+                    chunk=ci0, phase="prefill", rows=n, rows_cap=R,
+                    **self._count_work(work)):
+                # the chunks write straight into the slots' pool pages
                 # — the POOL is the donated buffer, chained with decode
                 logits, self._cache, *load = self.engine._run_guarded(
                     self._chunk_fn,
                     (self.engine._params, self._cache,
-                     jnp.asarray(self._pages.row(p.slot)),
-                     jnp.asarray(p.ids_pad[:, p.ci * C:(p.ci + 1) * C]),
-                     jnp.asarray(p.start + p.ci * C, jnp.int32),
-                     jnp.asarray([local], jnp.int32)))
-                p.expert_loads += load      # expert models only
+                     jnp.asarray(tables), jnp.asarray(ids),
+                     jnp.asarray(starts if R > 1 else starts[0]),
+                     jnp.asarray(last)))
+                p0.expert_loads += load     # expert models only (R = 1)
         except BaseException as e:
             # the donated POOL may be dead — this is a decode-grade
             # failure: every in-flight request's KV lived in it
             self._pages.give_back(self._cache)
             self._cache = None
-            if p.req.status not in TERMINAL_STATUSES:
-                self._record_terminal(
-                    p.req, RequestStatus.ABORTED,
-                    f"admission prefill dispatch failed: "
-                    f"{type(e).__name__}: {e}")
-            self._abort_in_flight(
-                f"prefill dispatch failed (request {p.req.rid} lost)")
+            self._lose_rows(rows, f"admission prefill dispatch failed: "
+                                  f"{type(e).__name__}: {e}")
             raise
         if self.speculative:
             # mirror the chunk into the DRAFT lane: speculation proposes
             # from the draft model's own cache, so it needs the prompt's
             # K/V too (same spans — prefix sharing is disabled under
-            # speculation, p.start is always 0)
+            # speculation, p.start is always 0; R = 1)
             t0s = time.perf_counter()
             try:
                 with self._observe_dispatch("draft_prefill_chunk",
-                                            rid=p.req.rid, slot=p.slot,
-                                            chunk=p.ci, phase="prefill"):
-                    _, p.draft_lane = self.engine._run_guarded(
+                                            rid=p0.req.rid, slot=p0.slot,
+                                            chunk=ci0, phase="prefill"):
+                    _, p0.draft_lane = self.engine._run_guarded(
                         self._draft_chunk_fn,
-                        (self._draft_params, p.draft_lane,
-                         jnp.asarray(
-                             p.ids_pad[:, p.ci * C:(p.ci + 1) * C]),
-                         jnp.asarray(p.start + p.ci * C, jnp.int32),
-                         jnp.asarray([local], jnp.int32)))
+                        (self._draft_params, p0.draft_lane,
+                         jnp.asarray(ids), jnp.asarray(starts[0]),
+                         jnp.asarray(last)))
             except BaseException as e:
                 # the donated draft lane may be dead — drop only THIS
                 # admission
+                self._pending = p0
                 self._drop_pending()
-                if p.req.status not in TERMINAL_STATUSES:
+                if p0.req.status not in TERMINAL_STATUSES:
                     self._record_terminal(
-                        p.req, RequestStatus.ABORTED,
+                        p0.req, RequestStatus.ABORTED,
                         f"draft prefill dispatch failed: "
                         f"{type(e).__name__}: {e}")
                     self.stats["aborted"] = \
                         self.stats.get("aborted", 0) + 1
                 logger.warning(f"serving draft prefill failed — request "
-                               f"{p.req.rid} dropped")
+                               f"{p0.req.rid} dropped")
                 raise
             self.stats["spec_draft_secs"] += time.perf_counter() - t0s
         self._breaker.record_success()
-        if (P - 1 - p.start) // C == p.ci:
-            # this chunk held the prompt's last real position — its
-            # selected logits seed the first sampled token (device-side;
-            # never synchronized here)
-            p.sel = logits
-        p.ci += 1
-        self.stats["prefill_tokens"] += C
-        return p.ci >= p.n_chunks
+        self.stats["prefill_tokens"] += n * C
+        self.stats["prefill_dispatches"] += 1
+        self.stats["prefill_rows"] += n
+        done = []
+        for r, (p, ci) in enumerate(rows):
+            if ci == p.n_chunks - 1:
+                # this row held the prompt's last real position — its
+                # selected logits seed the first sampled token
+                # (device-side; never synchronized here)
+                p.sel, p.sel_row = logits, r
+                done.append(p)
+        for i, p in enumerate(done):
+            try:
+                self._dispatch_admit(p)
+            except BaseException:
+                # the state died under the admissions behind this one too
+                for q in done[i + 1:]:
+                    self._give_back_draft_lane(q)
+                    self._record_terminal(
+                        q.req, RequestStatus.ABORTED,
+                        f"admission aborted: admit dispatch of request "
+                        f"{p.req.rid} failed")
+                raise
 
     def _dispatch_admit(self, p):  # lock-held: _lock
         """Prefill complete: ONE dispatch samples the first token and
@@ -2109,7 +2210,9 @@ class ServingEngine:
                      jnp.asarray(p.slot, jnp.int32),
                      jnp.asarray(p.fill_len, jnp.int32),
                      jnp.asarray(dev_new, jnp.int32),
-                     jnp.asarray(req.eos, jnp.int32)))
+                     jnp.asarray(req.eos, jnp.int32))
+                    + ((jnp.asarray(p.sel_row, jnp.int32),)
+                       if self.chunk_rows > 1 else ()))
         except BaseException as e:
             # the state was donated — same recovery as a decode failure
             # (this admission's request is lost with it).  Only the
@@ -2125,7 +2228,6 @@ class ServingEngine:
                                   f"(request {req.rid} lost)")
             raise
         self._breaker.record_success()
-        self._pages.share(p.slot, p.fill)
         if self.speculative:
             # insert the prefilled draft lane into the draft cache
             t0s = time.perf_counter()

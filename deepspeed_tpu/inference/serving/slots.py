@@ -19,8 +19,10 @@ The programs:
   routed through the page table; free/retired lanes write masked garbage
   to the trash page).  The pool AND the slot state are donated — the
   workspace updates in place.
-* :func:`make_chunk_fn` — one admission-prefill chunk, written straight
-  into the slot's pool pages through its table row (pool donated).
+* :func:`make_chunk_fn` — one admission-prefill dispatch: up to
+  :func:`chunk_rows` chunks, of one prompt or of several, each written
+  straight into its slot's pool pages through its own table row, in one
+  pass of the weights (pool donated).
 * :func:`make_admit_fn` — admission, one dispatch: sample the first token
   from the prefill's last-position logits (the SAME sampling rule the
   decode step uses, ``build_sample_fn`` — keeping serving outputs bitwise
@@ -227,16 +229,49 @@ def chunk_write_form(module, chunk, page):
     return paged_write_form(chunk, page, page_runs=True)
 
 
+def chunk_rows(module, chunk, page, speculative=False):
+    """How many ``chunk``-token rows one dispatch of :func:`make_chunk_fn`'s
+    program takes: as many as the chunk kernel's bound holds
+    (``registry.MAX_CHUNK_S // chunk`` — 4 at a chunk of 128), so one pass
+    of the weights serves up to 512 prompt tokens — where rows depend on
+    each other through the K/V pages alone, written as page runs
+    (:func:`chunk_write_form`), which ``write_and_attend`` orders: a layer
+    writes every row's K/V before any row attends.  ONE row — the
+    scalar-``start`` program — where they depend through more: per-slot
+    state (``state_kinds``) or a chunk geometry of the model's own
+    (``prefill_chunk_cap`` / ``prefill_chunk_fault``: windows, latent
+    lanes), dropless experts (the load vector is a request's,
+    :func:`_expert_load`), and under speculation (the draft lane mirrors
+    one chunk at a time)."""
+    from deepspeed_tpu.ops.transformer.registry import MAX_CHUNK_S
+    own_path = (routes_experts(module) or speculative
+                or getattr(module, "state_kinds", ())
+                or hasattr(module, "prefill_chunk_cap")
+                or hasattr(module, "prefill_chunk_fault")
+                or chunk_write_form(module, chunk, page) != "page_runs")
+    return 1 if own_path else max(1, MAX_CHUNK_S // chunk)
+
+
 def make_chunk_fn(module, param_transform):
     """The admission-prefill chunk program:
     ``fn(params, cache, pages, chunk_ids, start, logits_at)`` — same
     body as the engine's per-chunk program (``generate()``'s split
-    prefill) but writing straight into the slot's pool pages through its
-    ``[1, pages_per_slot]`` table row (no single-lane staging cache, no
-    admit-time insert).  The POOL is donated (argnum 1); the table row
-    is a separate traced input so the donation aliases cleanly.
+    prefill) but writing straight into the slots' pool pages through
+    their table rows (no single-lane staging cache, no admit-time
+    insert).  The POOL is donated (argnum 1); the table rows are a
+    separate traced input so the donation aliases cleanly.
 
-    ``logits_at`` is the chunk's LAST REAL row (the scheduler passes
+    The scheduler hands it ``R`` = :func:`chunk_rows` rows a dispatch:
+    ``pages [R, table_width]``, ``chunk_ids [R, C]``, ``start [R]``,
+    ``logits_at [R]`` → ``logits [R, 1, V]`` — each row one chunk with its
+    own table row, start and last real position.  Rows may be consecutive
+    chunks of ONE prompt (row r+1 attends what row r wrote: a layer's K/V
+    write precedes its attention call) or chunks of different prompts; a
+    dead row carries an all-trash table row and start 0, and its logits
+    are never read.  At ``R`` = 1 ``start`` is a SCALAR (the row-uniform
+    program, no ``per_row`` marker).
+
+    ``logits_at`` is each chunk's LAST REAL row (the scheduler passes
     ``chunk - 1`` for a whole chunk and the prompt's last token for the
     final one), so the rows past it are the padded tail.  For a model
     with dropless expert layers (:func:`routes_experts`) the tail is
@@ -251,8 +286,9 @@ def make_chunk_fn(module, param_transform):
         live = jnp.arange(chunk_ids.shape[1])[None, :] \
             <= logits_at[:, None] if routed else None
         # SlotPages.reserve starts every chunk on a common multiple of
-        # page and chunk, which no shape shows: the marker says it, and
-        # the K/V write goes in as page runs (registry.paged_write_form)
+        # page and chunk, which no shape shows: the marker says it — of
+        # every row's start — and the K/V write goes in as page runs
+        # (registry.paged_write_form)
         logits, cache, counts = _decode(
             module, deq(params), chunk_ids,
             {**cache, "pages": pages,
@@ -441,7 +477,7 @@ def make_draft_admit_fn():
     return jax.jit(admit, donate_argnums=(0,))
 
 
-def make_admit_fn(sample_fn):
+def make_admit_fn(sample_fn, rows=1):
     """The admission program:
     ``fn(state, logits, rng, slot, pos0, max_new, eos) -> (state,
     first_token)`` with the slot state donated (argnum 0).  The prefill
@@ -452,11 +488,19 @@ def make_admit_fn(sample_fn):
     or ``max_new == 1``).  Because the state write happens in-program,
     the host scheduler never has to synchronize on the first token
     before the next decode block can be dispatched: it reads
-    ``first_token`` lazily, one block behind (see ``ServingEngine``)."""
+    ``first_token`` lazily, one block behind (see ``ServingEngine``).
+
+    ``rows`` > 1 (:func:`chunk_rows`): ``logits`` are a whole prefill
+    dispatch's ``[rows, 1, V]`` and an eighth argument, ``row``, says
+    which row held this prompt's last real position — selected
+    in-program, so a dispatch that finished several prompts costs no
+    slicing dispatch of its own."""
 
     @hot_path("serving.admit")
-    def admit(state, logits, rng, slot, pos0, max_new, eos):
+    def admit(state, logits, rng, slot, pos0, max_new, eos, *row):
         with jax.named_scope("head.sample"):
+            if rows > 1:
+                logits = jax.lax.dynamic_slice_in_dim(logits, row[0], 1)
             first = sample_fn(logits[:, 0], rng).astype(jnp.int32)[0]
         with jax.named_scope("slots.state"):
             # finished-at-admission: eos on the first token (eos=-1 never

@@ -512,17 +512,26 @@ def _paged_write(cache, k_new, v_new, ks_new, vs_new, positions, per_row,
     argument).
 
     ``page_runs`` (static; ``ops/transformer/registry.py::
-    paged_write_form`` decides it): the row-uniform block is WHOLE
-    PAGES, or one run inside one page, and its start is run-aligned —
-    the caller's promise, which no shape shows.  Each run then goes into
-    the pool as one contiguous ``dynamic_update_slice`` at ``(layer,
-    pages[b, start // page + j], start % page, 0)``, in place on a
-    donated pool: the same values on the same pool rows as the scatter,
-    one block write a page in place of ``page`` row updates."""
+    paged_write_form`` decides it): each row's block is WHOLE PAGES, or
+    one run inside one page, and its start — ``positions[0, 0]`` for a
+    block of one row, the row's own ``positions[b, 0]`` for several
+    (:func:`_write_row_runs`) — is run-aligned: the caller's promise,
+    which no shape shows.  Each run then goes into the pool as one
+    contiguous ``dynamic_update_slice`` at ``(layer, pages[b, start_b //
+    page + j], start_b % page, 0)``, in place on a donated pool: the same
+    values on the same pool rows as the scatter, one block write a page
+    in place of ``page`` row updates."""
     li = cache["layer"]
     pages = cache["pages"]                      # [B, n_pages] int32
     page = cache["k"].shape[-2]
     B_, S_ = k_new.shape[0], k_new.shape[1]
+    if page_runs and B_ > 1:
+        # the chunk program's rows (serving/slots.py): a start a row
+        news = {"k": k_new, "v": v_new}
+        if ks_new is not None:
+            news.update(k_scale=ks_new, v_scale=vs_new)
+        return _write_row_runs(_cache_data(cache), news, li, pages,
+                               positions[:, 0].astype(jnp.int32))
     if page_runs:
         start = positions[0, 0].astype(jnp.int32)
         run = min(S_, page)
@@ -578,6 +587,39 @@ def _paged_write(cache, k_new, v_new, ks_new, vs_new, positions, per_row,
     if ks_new is not None:
         out["k_scale"] = w(cache["k_scale"], ks_new)
         out["v_scale"] = w(cache["v_scale"], vs_new)
+    return out
+
+
+@jax.jit
+def _write_row_runs(bufs, news, li, pages, starts):
+    """:func:`_paged_write`'s page runs for a block of several rows, each
+    from ITS OWN run-aligned start (``starts [B]``): ``news[key] [B, S,
+    ...]`` into ``bufs[key]``, one ``dynamic_update_slice`` a row, run
+    and buffer.  Jitted, with the layer a traced operand (like the chunk
+    kernel's call, ``paged_attention._paged_chunk_call``): an unrolled
+    model calls it once a layer with the same shapes, so the ``buffers x
+    rows x runs`` block writes are traced and lowered ONCE a program —
+    unrolled into every layer they were 4 s of a serving program's
+    set-up at 4 rows x 24 layers (PERF.md §6, PR 38).  XLA inlines the
+    call: the pool stays in place."""
+    page = bufs["k"].shape[-2]
+    B_, S_ = news["k"].shape[:2]
+    run = min(S_, page)
+    first = starts // page
+    # whole pages start at row 0 of each; a shorter run at its offset
+    off = starts % page if run < page else jnp.zeros_like(starts)
+    lane0 = jnp.zeros((), jnp.int32)
+    out = {}
+    for key, buf in bufs.items():
+        new = news[key].astype(buf.dtype)
+        for b in range(B_):
+            for j in range(S_ // run):
+                # indexed one entry at a time, so a run's page index
+                # clamps to the table row like the scatter's would
+                buf = jax.lax.dynamic_update_slice(
+                    buf, new[b, j * run:(j + 1) * run][None, None],
+                    (li, pages[b, first[b] + j], off[b], lane0))
+        out[key] = buf
     return out
 
 

@@ -113,7 +113,7 @@ def kernel_modes(*, paged, has_bias=False, has_window=False):
     }
 
 
-def paged_write_form(block, page, *, page_runs, per_row=False):
+def paged_write_form(block, page, *, page_runs):
     """How a ``block``-token write reaches a paged pool of ``page``-row
     pages: ``"page_runs"`` — one in-place block write a page run — or
     ``"row_scatter"`` through the table, row by row.  All static, like
@@ -121,13 +121,16 @@ def paged_write_form(block, page, *, page_runs, per_row=False):
     host-side attribution (``ServingEngine.stats["chunk_write"]``,
     ``prefill_plan`` reasons) ask this one predicate.
 
-    Page runs need a row-uniform multi-token block that is whole pages
-    (``block % page == 0``) or one run inside a page (``page % block ==
-    0``), AND a run-aligned start — which no shape shows, so the caller
-    says it with the cache's ``page_runs`` marker (the serving chunk
-    program does: ``SlotPages.reserve`` starts every chunk on a common
-    multiple of page and chunk)."""
-    if page_runs and not per_row and block > 1 \
+    Page runs need a multi-token block that is whole pages (``block %
+    page == 0``) or one run inside a page (``page % block == 0``), AND a
+    run-aligned start — which no shape shows, so the caller says it with
+    the cache's ``page_runs`` marker (the serving chunk program does:
+    ``SlotPages.reserve`` starts every chunk on a common multiple of page
+    and chunk).  The promise is of every ROW's start: marked, a per-row
+    block (the chunk program's rows, ``serving/slots.py``) is page runs
+    like a row-uniform one; unmarked (speculative verify: a window
+    starts wherever its slot stands) it keeps the scatter."""
+    if page_runs and block > 1 \
             and (block % page == 0 or page % block == 0):
         return "page_runs"
     return "row_scatter"
@@ -164,8 +167,7 @@ def _write_cache(cache, k_new, v_new, ks_new, vs_new, positions):
     if "pages" in cache:
         per_row = "per_row" in cache
         form = paged_write_form(k_new.shape[1], cache["k"].shape[-2],
-                                page_runs="page_runs" in cache,
-                                per_row=per_row)
+                                page_runs="page_runs" in cache)
         data = _paged_write(cache, k_new, v_new, ks_new, vs_new, positions,
                             per_row=per_row, page_runs=form == "page_runs")
         return {**data, **markers}

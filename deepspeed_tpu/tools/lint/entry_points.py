@@ -178,21 +178,26 @@ def serving_decode_step():
 
 
 def serving_prefill_chunk():
-    """The admission-prefill chunk program: the pool is the donated
-    buffer (chunk writes land in the slot's pages directly — no staging
-    lane), the [1, pages_per_slot] table row a separate traced input so
-    the pool donation aliases cleanly."""
+    """The admission-prefill chunk program as a dense model's server
+    dispatches it — several chunk rows a pass of the weights, each with
+    its own [table_width] table row, start and last real position (two
+    chunks of one prompt, one of another, a dead row): the pool is the
+    donated buffer (chunk writes land in the slots' pages directly — no
+    staging lane), the table rows a separate traced input so the pool
+    donation aliases cleanly."""
     from deepspeed_tpu.inference.serving.slots import make_chunk_fn
     engine = _tiny_inference_engine()
     C, NP, PG = 8, 9, 8
     chunk_fn = make_chunk_fn(engine.module, None)
     pool = engine.module.init_paged_cache(NP, PG,
                                           dtype=engine.compute_dtype)
-    pages = jnp.asarray([[3, 5, 2, 7]], jnp.int32)
-    ids = jnp.asarray(np.random.default_rng(4).integers(0, 97, (1, C)),
+    pages = jnp.asarray([[3, 5, 2, 7], [3, 5, 2, 7], [1, 4, 6, 8],
+                         [0, 0, 0, 0]], jnp.int32)
+    ids = jnp.asarray(np.random.default_rng(4).integers(0, 97, (4, C)),
                       jnp.int32)
-    args = (engine._params, pool, pages, ids, jnp.asarray(0, jnp.int32),
-            jnp.zeros((1,), jnp.int32))
+    args = (engine._params, pool, pages, ids,
+            jnp.asarray([0, 8, 0, 0], jnp.int32),
+            jnp.asarray([7, 3, 7, 0], jnp.int32))
     return EntryPoint("serving.prefill_chunk", chunk_fn, args,
                       expect_donation=True)
 
@@ -200,7 +205,8 @@ def serving_prefill_chunk():
 def serving_admit():
     """The admission program (first-token sample + in-program slot-state
     write, slot index traced, slot state donated; no cache argument at
-    all — prefill already wrote the pages)."""
+    all — prefill already wrote the pages).  The one-row form; a server
+    whose prefill dispatches take rows adds a traced row index."""
     from deepspeed_tpu.inference.engine import build_sample_fn
     from deepspeed_tpu.inference.serving.slots import make_admit_fn
     fn = make_admit_fn(build_sample_fn(False, 1.0, 0, 1.0))

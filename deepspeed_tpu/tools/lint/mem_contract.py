@@ -91,8 +91,12 @@ _JAXLIB_0_9 = ("compiler, not program: XLA:CPU buffer assignment of jaxlib "
 DECLARED_GROWTH = {name: _JAXLIB_0_9 for name in (
     "hybrid.rollout", "inference.decode", "inference.prefill_chunk",
     "runtime.apply_update", "serving.decode_step",
-    "serving.prefill_chunk", "serving.spec_draft_prefill",
+    "serving.spec_draft_prefill",
     "serving.spec_propose", "serving.spec_verify", "parallel.moe_ep")}
+DECLARED_GROWTH["serving.prefill_chunk"] = (
+    "the locked entry point is a dispatch of FOUR chunk rows since PR 38 "
+    "(one row before): 4x the activations for 4x the tokens, one pass of "
+    "the weights — docs/serving.md 'Prefill dispatches'")
 
 
 # ------------------------------------------------------------------ #

@@ -183,18 +183,19 @@ def test_paged_prefill_chunk_span_counts_reachable_pages(paged_engine,
     layers, page, C = 2, srv.page, srv.chunk
     assert (page, C, srv.pages_per_slot) == (16, 8, 4)
     table = layers * srv.pages_per_slot
-    assert {a["kv_pages_table"] for a in chunks} == {table}
-    assert all(0 < a["kv_pages"] <= table for a in chunks)
-    by_rid = {rid: sorted((a["chunk"], a["kv_pages"]) for a in chunks
-                          if a["rid"] == rid) for rid in rids}
-    for rid, (plen, _) in zip(rids, plan):
-        n_chunks = -(-plen // C)
-        assert by_rid[rid] == [
-            (ci, layers * -(-(C * (ci + 1)) // page))
-            for ci in range(n_chunks)], (plen, by_rid[rid])
-    # a 5-token prompt reaches one page of four; a 57-token one all four
-    assert by_rid[rids[0]] == [(0, 2)]
-    assert by_rid[rids[-1]][-1] == (7, table)
+    # a dispatch sums its live rows (rows_cap = 512 // 8 of them at most)
+    assert {a["rows_cap"] for a in chunks} == {srv.chunk_rows} == {64}
+    assert all(a["kv_pages_table"] == a["rows"] * table for a in chunks)
+    assert all(0 < a["kv_pages"] <= a["rows"] * table for a in chunks)
+    reach = [layers * -(-(C * (ci + 1)) // page)
+             for plen, _ in plan for ci in range(-(-plen // C))]
+    assert sum(a["rows"] for a in chunks) == len(reach) \
+        == srv.stats["prefill_rows"]
+    assert len(chunks) == srv.stats["prefill_dispatches"] < len(reach)
+    assert sum(a["kv_pages"] for a in chunks) == sum(reach)
+    # the first dispatch starts with the 5-token prompt: one page of four
+    assert (chunks[0]["rid"], chunks[0]["chunk"]) == (rids[0], 0)
+    assert reach[0] == 2 and reach[-1] == table
 
 
 def test_paged_page_size_invariance(paged_engine):
@@ -670,10 +671,14 @@ def _pool_and_block(page, block, batch, quant, seed, table, layers=3,
 
 
 def _write(cache, rows, start, marked):
+    """``start``: one for all rows, or a list — a start a row (per-row)."""
     from deepspeed_tpu.ops.transformer.registry import _write_cache
     if marked:
         cache = {**cache, "page_runs": jnp.zeros((), jnp.int32)}
     block, batch = rows[0].shape[1], rows[0].shape[0]
+    if isinstance(start, list):
+        cache = {**cache, "per_row": jnp.zeros((), jnp.int32)}
+        start = jnp.asarray(start, jnp.int32)[:, None]
     positions = start + jnp.broadcast_to(jnp.arange(block), (batch, block))
     fn = lambda c, r, p: _write_cache(c, *r, p)
     prims = _prims_under(jax.make_jaxpr(fn)(cache, rows, positions).jaxpr, "")
@@ -699,6 +704,16 @@ PAGE_RUN_CASES = {
     "ends_at_the_rows_end_half_page": (64, 32, 608, [_ROW_A], False),
     "int8_pool_with_scale_pages": (64, 128, 128, [_ROW_A, _ROW_B], True),
     "int8_pool_half_page_at_32": (64, 32, 32, [_ROW_A], True),
+    # the chunk program's rows: a start a ROW — consecutive chunks of one
+    # prompt (one table), two prompts, a dead row (all trash, start 0)
+    "per_row_one_prompts_chunks": (64, 128, [0, 128, 256, 384],
+                                   [_ROW_A] * 4, False),
+    "per_row_two_prompts": (64, 128, [256, 0, 384, 128],
+                            [_ROW_A, _ROW_B, _ROW_A, _ROW_B], False),
+    "per_row_dead_row": (64, 128, [128, 0], [_ROW_A, [0] * 10], False),
+    "per_row_half_pages": (64, 32, [96, 32, 0], [_ROW_A, _ROW_B, _ROW_B],
+                           False),
+    "per_row_int8_pool": (64, 128, [128, 384], [_ROW_A, _ROW_B], True),
 }
 
 
@@ -722,8 +737,8 @@ def test_page_runs_write_is_bitwise_the_row_scatter(case):
                                       np.asarray(want[key]), err_msg=key)
     assert "page_runs" in got and "page_runs" not in want
     # what the write may touch: the run's own pages of this layer
-    first = start // page
-    mine = {row[first + j] for row in table
+    starts = start if isinstance(start, list) else [start] * len(table)
+    mine = {row[first // page + j] for row, first in zip(table, starts)
             for j in range(max(1, block // page))}
     before, after = np.asarray(cache["k"]), np.asarray(got["k"])
     changed = {int(p) for layer, p in zip(*np.nonzero(
@@ -738,18 +753,16 @@ def test_page_runs_write_is_bitwise_the_row_scatter(case):
     (64, 96, True, False),          # neither multiple nor divisor
     (64, 160, True, False),
     (64, 1, True, False),           # one token is a row, not a run
-    (64, 128, True, True),          # per-row blocks start where they like
+    (64, 128, False, True),         # an unmarked per-row block (speculative
+                                    # verify) starts where its slot stands
 ])
 def test_what_is_not_page_runs_keeps_the_scatter(page, block, marked,
                                                  per_row):
     from deepspeed_tpu.ops.transformer.registry import paged_write_form
-    assert paged_write_form(block, page, page_runs=marked,
-                            per_row=per_row) == "row_scatter"
+    assert paged_write_form(block, page, page_runs=marked) == "row_scatter"
     cache, rows = _pool_and_block(page, block, 1, False, seed=block,
                                   table=[_ROW_A])
-    if per_row:
-        cache["per_row"] = jnp.zeros((), jnp.int32)
-    _, prims = _write(cache, rows, 64, marked=marked)
+    _, prims = _write(cache, rows, [64] if per_row else 64, marked=marked)
     assert "scatter" in prims and "dynamic_update_slice" not in prims
 
 
@@ -819,3 +832,112 @@ def test_kernel_modes_keeps_exactly_its_two_keys(paged_engine):
         assert "chunk_write" not in paged_engine.prefill_plan(1, 29)[2]
     finally:
         srv.close()
+
+
+# --------------------------------------------------------------------- #
+# The chunk program takes rows (docs/serving.md "Prefill dispatches"): a
+# dispatch of R chunk rows — of one prompt or of several, each with its
+# own table row, start and last real position — leaves the pool and each
+# row's selected logits what R one-row dispatches leave.
+# --------------------------------------------------------------------- #
+_RC, _RPAGE, _RSLOT_PAGES = 16, 16, 6
+_TABLE_A = [1, 2, 3, 4, 5, 6]
+_TABLE_B = [7, 8, 9, 10, 11, 12]
+
+
+def _row_prompts():
+    rng = np.random.default_rng(41)
+    a = rng.integers(1, 97, (58,)).astype(np.int32)     # 4 chunks, 10 real
+    b = rng.integers(1, 97, (40,)).astype(np.int32)     # 3 chunks, 8 real
+    # shares a's first two pages, then its own 20 tokens: starts at 32
+    c = np.concatenate([a[:32], rng.integers(1, 97, (20,))]).astype(np.int32)
+    return {"a": (a, _TABLE_A, 0), "b": (b, _TABLE_B, 0),
+            "c": (c, _TABLE_A[:2] + _TABLE_B[2:], 32)}
+
+
+ROW_CASES = {
+    # name: (rows run one at a time beforehand, the dispatch's rows);
+    # a row is (prompt, chunk index), None a dead row
+    "four_chunks_of_one_prompt": ([], [("a", 0), ("a", 1), ("a", 2),
+                                       ("a", 3)]),
+    "two_prompts_interleaved": ([], [("a", 0), ("b", 0), ("a", 1),
+                                     ("b", 1)]),
+    "padded_last_chunk_beside_anothers_first": (
+        [("a", 0), ("a", 1), ("a", 2)],
+        [("a", 3), ("b", 0), ("b", 1), ("b", 2)]),
+    "dead_rows": ([], [("a", 0), None, ("a", 1), None]),
+    "shared_prefix_start": ([], [("a", 0), ("a", 1), ("c", 0), ("c", 1)]),
+}
+
+
+def _row_args(rows):
+    """``(tables, ids, starts, last)`` of a dispatch: one entry a row."""
+    prompts = _row_prompts()
+    tables = np.zeros((len(rows), _RSLOT_PAGES), np.int32)
+    ids = np.zeros((len(rows), _RC), np.int32)
+    starts = np.zeros((len(rows),), np.int32)
+    last = np.zeros((len(rows),), np.int32)
+    for r, row in enumerate(rows):
+        if row is None:
+            continue
+        (fill, table, s0), ci = prompts[row[0]], row[1]
+        starts[r] = s0 + ci * _RC
+        tables[r] = table
+        part = fill[starts[r]:starts[r] + _RC]
+        ids[r, :len(part)] = part
+        last[r] = min(max(len(fill) - 1 - starts[r], 0), _RC - 1)
+    return tables, ids, starts, last
+
+
+@pytest.mark.parametrize("case", sorted(ROW_CASES))
+def test_a_dispatch_of_rows_equals_one_row_dispatches(case, paged_engine,
+                                                      monkeypatch):
+    """The R-row program over the Pallas chunk kernel against the one-row
+    program (scalar start) on the gather reference, row after row: the
+    same selected logits a live row, the same pool outside the trash
+    page.  Rows of one prompt attend what earlier rows wrote in the same
+    dispatch; a shared-prefix row attends another slot's pages written in
+    it; a dead row (all-trash table, start 0) touches nothing live."""
+    from deepspeed_tpu.inference.serving.slots import make_chunk_fn
+    eng = paged_engine
+    before, rows = ROW_CASES[case]
+    new_pool = lambda: eng.module.init_paged_cache(
+        1 + 2 * _RSLOT_PAGES, _RPAGE, dtype=eng.compute_dtype)
+
+    def one_at_a_time(fn, pool, todo):
+        out = {}
+        for r, row in enumerate(todo):
+            if row is None:
+                continue
+            tables, ids, starts, last = _row_args([row])
+            out[r], pool = fn(eng._params, pool, jnp.asarray(tables),
+                              jnp.asarray(ids), jnp.asarray(starts[0]),
+                              jnp.asarray(last))
+        return out, pool
+
+    kernel = make_chunk_fn(eng.module, None)
+    _, pool = one_at_a_time(kernel, new_pool(), before)
+    tables, ids, starts, last = _row_args(rows)
+    got, got_pool = kernel(eng._params, pool, jnp.asarray(tables),
+                           jnp.asarray(ids), jnp.asarray(starts),
+                           jnp.asarray(last))
+    assert got.shape == (len(rows), 1, 97)
+
+    monkeypatch.setenv("DSTPU_DISABLE_FLASH", "1")
+    gather = make_chunk_fn(eng.module, None)
+    _, pool = one_at_a_time(gather, new_pool(), before)
+    want, want_pool = one_at_a_time(gather, pool, rows)
+    for r, logits in want.items():
+        np.testing.assert_allclose(np.asarray(got[r]), np.asarray(logits[0]),
+                                   rtol=2e-5, atol=2e-5, err_msg=f"row {r}")
+    for key in ("k", "v"):
+        np.testing.assert_allclose(np.asarray(got_pool[key])[:, 1:],
+                                   np.asarray(want_pool[key])[:, 1:],
+                                   rtol=2e-5, atol=2e-5, err_msg=key)
+    # every page a live row covers was written; nothing else was
+    covered = {_row_prompts()[row[0]][1][(s // _RPAGE)]
+               for row, s in zip(rows, starts) if row is not None}
+    written = {int(p) for p in np.nonzero(
+        np.asarray(got_pool["k"])[0].any(axis=(1, 2)))[0]} - {0}
+    assert written == covered | {_row_prompts()[r[0]][1][
+        (_row_args([r])[2][0]) // _RPAGE] for r in before}

@@ -395,7 +395,9 @@ def test_flightrec_dump_on_breaker_open(shared_engine, tmp_path):
     sequence (dispatch_begin -> dispatch_error -> breaker_open)."""
     eng = shared_engine
     rng = np.random.default_rng(23)
-    prompts, _ = _workload(rng, n=2)
+    # two failed dispatches need two steps' worth of admissions: a lost
+    # dispatch loses every admission that rode it (both slots' here)
+    prompts, _ = _workload(rng, n=4)
     srv = eng.serve(num_slots=2, breaker_threshold=2,
                     breaker_cooldown_s=30.0, flight_recorder=True,
                     flight_recorder_dir=str(tmp_path / "fr"))

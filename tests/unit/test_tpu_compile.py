@@ -334,10 +334,13 @@ def test_paged_chunk_block_loop_fits_vmem(heads, head_dim, pages_per_slot,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_chunk_step_writes_page_runs_in_place(one_chip, mosaic):
+@pytest.mark.parametrize("rows", [1, 4], ids=["one_row", "four_rows"])
+def test_chunk_step_writes_page_runs_in_place(rows, one_chip, mosaic):
     """The WHOLE serving chunk step at OPT-1.3B's widths and the batch
-    cell's pool (24 layers x 697 pages of 64 rows: 4.4 GB a buffer): the
-    chunk's K/V goes in as page runs — ``dynamic_update_slice`` under
+    cell's pool (24 layers x 697 pages of 64 rows: 4.4 GB a buffer), as
+    the one-row program (scalar start) and as the cell's own dispatch of
+    ``chunk_rows`` = 4 rows (a start a row, 8 page runs a layer and
+    buffer): the chunk's K/V goes in as page runs — ``dynamic_update_slice`` under
     ``cache.write``, no ``scatter`` — and XLA keeps the pool in place
     through the chain of 96 updates interleaved with the kernel's reads:
     no pool-sized ``copy``, both pools aliased input -> output, nothing
@@ -347,7 +350,8 @@ def test_chunk_step_writes_page_runs_in_place(one_chip, mosaic):
     VMEM asynchronously, started before the kernel and awaited after it
     — under the kernel's old 64 MiB floor it was read from HBM when the
     matmul ran, and only the scatter gave XLA a window to prefetch in."""
-    from deepspeed_tpu.inference.serving.slots import make_chunk_fn
+    from deepspeed_tpu.inference.serving.slots import (chunk_rows,
+                                                       make_chunk_fn)
     from deepspeed_tpu.models.opt import opt_config
     from deepspeed_tpu.models.transformer import Transformer
     pages, page, chunk, slot_pages = 697, 64, 128, 29
@@ -363,15 +367,17 @@ def test_chunk_step_writes_page_runs_in_place(one_chip, mosaic):
     pool = on_chip(jax.eval_shape(
         lambda: model.init_paged_cache(pages, page, BF16)))
     ints = lambda *shape: jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+    assert chunk_rows(model, chunk, page) == 4
     compiled = make_chunk_fn(model, None).lower(
-        params, pool, ints(1, slot_pages), ints(1, chunk), ints(),
-        ints(1)).compile()
+        params, pool, ints(rows, slot_pages), ints(rows, chunk),
+        ints(rows) if rows > 1 else ints(), ints(rows)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     writes = {line.split("cache.write/")[1].split('"')[0]
               for line in text.splitlines() if "cache.write/" in line}
-    assert "dynamic_update_slice" in writes and "scatter" not in writes, \
-        writes
+    # (the rows' writes sit one call deeper: jit(_write_row_runs)/...)
+    assert any(w.endswith("dynamic_update_slice") for w in writes) \
+        and not any("scatter" in w for w in writes), writes
     pool_shape = f"bf16[{L},{pages},{page},{HD}]"
     copies = [line.strip()[:160] for line in text.splitlines()
               if " copy(" in line and pool_shape in line.split(" copy(")[0]]
@@ -380,6 +386,10 @@ def test_chunk_step_writes_page_runs_in_place(one_chip, mosaic):
     pool_bytes = 2 * L * pages * page * HD * 2
     assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 16, mem.temp_size_in_bytes
+    # weights + pools + temporaries inside the chip's 16 GB
+    peak = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert peak < 16 * 10 ** 9, peak
     asked = paged_mod._chunk_loop_vmem_bytes(
         chunk, H, D, paged_mod._chunk_block_pages(page, slot_pages) * page,
         HD, 2, 2)

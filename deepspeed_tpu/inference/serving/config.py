@@ -88,7 +88,10 @@ class ServingConfig(DeepSpeedConfigModel):
     # compute), or an OPT preset name ("opt-125m") built against the
     # target's vocab — pass its trained weights via
     # serve(draft_params=...), else they are RANDOMLY initialized
-    # (accept rate ~0; smoke/bench floor only, warned loudly)
+    # (accept rate ~0; smoke/bench floor only, warned loudly).  "mtp" =
+    # the model's OWN multi-token-prediction module drafts (a model with a
+    # ``draft`` method, models/glm5.py): spec_k is 1, decode_block windows
+    # ride one dispatch, the module's rows live in the model's page pool
     spec_draft_model: str = ""
     # sampling applied to every request (greedy when do_sample=False);
     # per-request eos_token_id/max_new_tokens ride the slot state instead

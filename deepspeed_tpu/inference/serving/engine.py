@@ -69,6 +69,12 @@ lifetime.  Admission streams each prompt chunk through BOTH models
 (the draft's prefill lane rides the admit event one-behind);
 preemption snapshots committed tokens only, and restore
 re-derives all draft state through the ordinary re-prefill path.
+A model with a multi-token-prediction module of its own
+(``spec_draft_model="mtp"``, ``slots.drafts_itself``) drafts for ITSELF:
+no draft model, cache or programs — ``decode_block`` verify windows of two
+rows a lane ride ONE dispatch (``slots.make_spec_block_fn``), the module's
+rows are one more layer of the model's own pools, and the mirror commits
+one or two tokens a lane a window from the program's ``accepted``.
 
 **Robustness / SLO layer** (``docs/serving.md`` "Robustness & SLOs"):
 every request ends in a typed terminal status (``COMPLETED`` |
@@ -144,7 +150,9 @@ from deepspeed_tpu.inference.serving.slots import (admission_chunk,
                                                    make_draft_admit_fn,
                                                    make_draft_chunk_fn,
                                                    make_draft_propose_fn,
+                                                   make_spec_block_fn,
                                                    make_spec_verify_fn,
+                                                   drafts_itself,
                                                    routes_experts,
                                                    holds_share)
 from deepspeed_tpu.runtime.fault import inject
@@ -226,6 +234,9 @@ class _PendingPrefill:
         # speculative serving: the DRAFT model's single-lane prefill
         # cache (the prompt's K/V must land in the draft cache too)
         self.draft_lane = None
+        # self-drafting: the last chunk's guess after the first sampled
+        # token (device-side), the slot's first pending draft
+        self.draft0 = None
         # expert models: each chunk's device-side load vector
         # (slots._expert_load), read when the admit event is processed
         self.expert_loads = []
@@ -310,6 +321,22 @@ class ServingEngine:
                 f"the slot engine's page pool needs model support "
                 f"(models/transformer.py)")
         self.speculative = bool(cfg.speculative)
+        # self-drafting (``spec_draft_model: "mtp"``): the model's own
+        # multi-token-prediction module drafts, from the pools' own last
+        # layer — no draft model, no draft cache, no draft programs
+        self.self_draft = self.speculative and \
+            (cfg.spec_draft_model or "").strip() == "mtp"
+        if self.self_draft and not drafts_itself(self.module):
+            raise ValueError(
+                f"serving.spec_draft_model='mtp': "
+                f"{type(self.module).__name__} has no multi-token-"
+                f"prediction module to draft with")
+        # ... or a separate draft MODEL with a lane cache of its own
+        self.separate_draft = self.speculative and not self.self_draft
+        # layers the drafting module adds to every dispatch (the pools'
+        # last; the load vector's last rows)
+        self._draft_layers = self.module.draft_expert_layers \
+            if self.self_draft else 0
         # scheduler counters (docs/serving.md) — made before the cache
         # manager, which counts prefix hits and evictions into them
         self.stats = {"iterations": 0, "decode_calls": 0,  # guarded-by: _lock
@@ -352,6 +379,9 @@ class ServingEngine:
         # a model that counts its own attention work names the span args
         # to sum into ``stats`` (``work_counters``); the names are its own
         self._work_keys = tuple(getattr(self.module, "work_counters", ()))
+        if self.self_draft:
+            self._pages.work_layers = self.module.config.num_layers \
+                + self._draft_layers
         self.stats.update(dict.fromkeys(self._work_keys, 0))
         # the slot's virtual lane: max_cache_len in whole pages
         self.cache_len = self._pages.cache_len
@@ -395,6 +425,14 @@ class ServingEngine:
             if not 1 <= self.spec_k <= 64:
                 raise ValueError(f"serving.spec_k={cfg.spec_k}: need "
                                  f"1 <= spec_k <= 64")
+        if self.self_draft:
+            if self.spec_k != 1 or draft_module is not None \
+                    or draft_params is not None:
+                raise ValueError(
+                    "serving.spec_draft_model='mtp' drafts ONE token a "
+                    "window with the model's own module: spec_k is 1 and "
+                    "no draft model is passed")
+        elif self.speculative:
             draft_module, draft_params = self._resolve_draft(
                 engine, cfg, draft_module, draft_params)
             self.draft_module = draft_module
@@ -414,11 +452,13 @@ class ServingEngine:
         # dropless routing and return the expert load ----
         self.routed = routes_experts(self.module)
         if self.routed:
-            if self.speculative:
+            if self.speculative and not self.self_draft:
                 raise ValueError(
-                    "serving.speculative=True is not implemented for a "
-                    "model with expert layers (the verify window's "
-                    "rejected positions would be routed)")
+                    "serving.speculative=True with a separate draft model "
+                    "is not implemented for a model with expert layers "
+                    "(the verify program does not route; a model that "
+                    "drafts for itself — spec_draft_model='mtp' — routes "
+                    "and counts its window's rows)")
             if getattr(engine, "_quantizer", None) is not None:
                 raise ValueError(
                     "weight quantization is not implemented for a model "
@@ -460,7 +500,15 @@ class ServingEngine:
         # Page tables are traced arguments (rebuilt host-side per
         # dispatch), so page churn/sharing never mints a new executable
         # — exactly ONE decode signature per server lifetime.
-        if self.speculative:
+        self._spec_fn = None
+        if self.self_draft:
+            self._spec_fn = make_spec_block_fn(
+                self.module, sample_fn, engine._deq, self.block,
+                self.cache_len)
+            engine._tags[id(self._spec_fn)] = (
+                "serving_spec_block", self.num_slots, self.num_pages,
+                self.page, self.block, sampling_key)
+        elif self.speculative:
             self._verify_fn = make_spec_verify_fn(
                 self.module, sample_fn, engine._deq, self.spec_k,
                 self.cache_len)
@@ -474,10 +522,11 @@ class ServingEngine:
             engine._tags[id(self._decode_fn)] = (
                 "serving_decode", self.num_slots, self.num_pages,
                 self.page, self.block, sampling_key)
-        self._admit_fn = make_admit_fn(sample_fn, self.chunk_rows)
+        self._admit_fn = make_admit_fn(sample_fn, self.chunk_rows,
+                                       self.self_draft)
         engine._tags[id(self._admit_fn)] = (
             "serving_admit", self.num_slots, sampling_key)
-        if self.speculative:
+        if self.separate_draft:
             # the draft side: one propose program, one draft prefill
             # chunk, one draft lane insert — the draft KV cache is
             # monolithic lanes [L_d, num_slots, cache_len, ...], the
@@ -515,16 +564,17 @@ class ServingEngine:
         # executables (tests/unit/test_serving_slo.py).
         # Prefill writes straight into the slot's pool pages (the pool
         # chains chunk -> decode by donation).
-        self._chunk_fn = make_chunk_fn(self.module, engine._deq)
+        self._chunk_fn = make_chunk_fn(self.module, engine._deq,
+                                       self.self_draft)
         engine._tags[id(self._chunk_fn)] = (
             "serving_prefill", self.chunk, self.page, self.chunk_rows)
         for fn in (self._decode_fn, self._admit_fn, self._chunk_fn,
-                   self._verify_fn, self._propose_fn,
+                   self._verify_fn, self._spec_fn, self._propose_fn,
                    self._draft_chunk_fn, self._draft_admit_fn):
             if fn is not None:
                 engine._persist_opt_out.add(id(fn))
 
-        if self.speculative:
+        if self.separate_draft:
             self._draft_params = draft_params
             self._draft_ws = KVCacheWorkspace(self.draft_module)
             self._draft_lanes = _LanePool(self.draft_module)  # guarded-by: _lock
@@ -594,6 +644,17 @@ class ServingEngine:
                 "spec_tokens_per_dispatch": 0.0,
                 "spec_draft_secs": 0.0, "spec_verify_secs": 0.0,
                 "spec_draft_fraction": 0.0})
+            if self.self_draft:
+                # drafts offered (one a window), drafts the target
+                # reproduced AND committed, and verify rows whose token
+                # was not committed — the work speculation wasted
+                self.stats.update({"spec_proposed": 0, "spec_accepted": 0,
+                                   "spec_rows_rejected": 0})
+                # what the windows read since the last dispatch came to:
+                # the next dispatch span's args (the mirror lags by one)
+                self._spec_unreported = dict(
+                    windows=0, proposed=0, accepted=0,
+                    rows_rejected=0)            # guarded-by: _lock
         if self.routed:
             # expert load (docs/observability.md): (token, expert)
             # assignments the expert layers computed, expert-weight reads
@@ -611,8 +672,11 @@ class ServingEngine:
             self._moe_share = holds_share(self.module)
             if self._moe_share:
                 self.stats["moe_assignments_elsewhere"] = 0
+            # the load vector's rows: the model's expert layers, then —
+            # self-drafting — its drafting module's
             self.moe_expert_tokens = np.zeros(
-                (sum(_is_moe_layer(mc, i) for i in range(mc.num_layers)),
+                (sum(_is_moe_layer(mc, i) for i in range(mc.num_layers))
+                 + self._draft_layers,
                  mc.moe_num_experts), np.int64)  # guarded-by: _lock
         # the slot-occupancy trace the correctness test asserts
         # EOS-mid-flight retirement against
@@ -916,7 +980,7 @@ class ServingEngine:
         owners = {"params": tree_device_bytes(self.engine._params),
                   "page_pool": tree_device_bytes(self._cache),
                   "slot_state": tree_device_bytes(self._state)}
-        if self.speculative:
+        if self.separate_draft:
             owners["draft_kv"] = tree_device_bytes(self._draft_cache) \
                 + tree_device_bytes(self._draft_lanes._lanes)
             if self._draft_params is not self.engine._params:
@@ -1277,7 +1341,7 @@ class ServingEngine:
         teardown)."""
         self._cache = self._state = None
         self._pages.drop_buffer()
-        if self.speculative:
+        if self.separate_draft:
             self._draft_cache = None
             self._draft_ws.release()
             self._draft_lanes.release()
@@ -1700,7 +1764,7 @@ class ServingEngine:
         self._free = deque(range(self.num_slots))
         self._mirror_active[:] = False
         self._state = None
-        if self.speculative:
+        if self.separate_draft:
             # the draft cache's contents mirror the aborted in-flight
             # requests (and may be donated-dead after a failed propose)
             # — drop it so the next step reallocates a fresh one
@@ -1809,6 +1873,8 @@ class ServingEngine:
             "remaining": jax.ShapeDtypeStruct((N,), jnp.int32),
             "eos": jax.ShapeDtypeStruct((N,), jnp.int32),
         }
+        if self.self_draft:
+            state["draft"] = jax.ShapeDtypeStruct((N,), jnp.int32)
         rng = jax.eval_shape(lambda: jax.random.key(0))
         report = {}
 
@@ -1831,11 +1897,18 @@ class ServingEngine:
         cargs = (eng._params, cache, rows,
                  jax.ShapeDtypeStruct((R, C), jnp.int32),
                  jax.ShapeDtypeStruct((R,) if R > 1 else (), jnp.int32),
-                 jax.ShapeDtypeStruct((R,), jnp.int32))
+                 jax.ShapeDtypeStruct((R,), jnp.int32)) \
+            + ((jax.ShapeDtypeStruct((R,), jnp.int32),)
+               if self.self_draft else ())
         report.update(warm(self._chunk_fn, cargs,
                            f"serving_prefill:c{C}p{self.page}"
                            + (f"r{R}" if R > 1 else "")))
-        if self.speculative:
+        if self.self_draft:
+            report.update(warm(
+                self._spec_fn,
+                (eng._params, cache, state, tables, rng),
+                f"serving_spec_block:n{N}s{S}b{self.block}p{self.page}"))
+        elif self.speculative:
             draft = jax.ShapeDtypeStruct((N, self.spec_k), jnp.int32)
             report.update(warm(
                 self._verify_fn,
@@ -1847,7 +1920,7 @@ class ServingEngine:
                 self._decode_fn,
                 (eng._params, cache, state, tables, rng),
                 f"serving_decode:n{N}s{S}b{self.block}p{self.page}"))
-        if self.speculative:
+        if self.separate_draft:
             dcache = jax.eval_shape(
                 lambda: self.draft_module.init_cache(N, S, dtype=dtype))
             dlane = jax.eval_shape(
@@ -2079,7 +2152,7 @@ class ServingEngine:
         req.slot = slot
         req.status = RequestStatus.PREFILLING
         pend = _PendingPrefill(req, slot, fill, start, self.chunk)
-        if self.speculative:
+        if self.separate_draft:
             # start == 0 under speculation (prefix sharing disabled), so
             # the draft lane prefills the same chunk spans as the pool
             pend.draft_lane = self._draft_lanes.take(
@@ -2105,7 +2178,8 @@ class ServingEngine:
             starts[r] = p.start + ci * C
             last[r] = min(max(p.fill_len - 1 - starts[r], 0), C - 1)
             for key, val in self._pages.chunk_reach(
-                    self.module.config.num_layers, int(starts[r]) + C,
+                    self.module.config.num_layers + self._draft_layers,
+                    int(starts[r]) + C,
                     live_end=p.fill_len).items():
                 work[key] = work.get(key, 0) + val
         p0, ci0 = rows[0]
@@ -2116,12 +2190,26 @@ class ServingEngine:
                     **self._count_work(work)):
                 # the chunks write straight into the slots' pool pages
                 # — the POOL is the donated buffer, chained with decode
+                more = ()
+                if self.self_draft:
+                    # the token after the chunk (R = 1): the next chunk's
+                    # first, or -1 — the first sampled one, taken
+                    # in-program — after the prompt's last chunk
+                    after = (ci0 + 1) * C
+                    more = (jnp.asarray(
+                        [p0.ids_pad[0, after] if ci0 < p0.n_chunks - 1
+                         else -1], jnp.int32),)
                 logits, self._cache, *load = self.engine._run_guarded(
                     self._chunk_fn,
                     (self.engine._params, self._cache,
                      jnp.asarray(tables), jnp.asarray(ids),
                      jnp.asarray(starts if R > 1 else starts[0]),
-                     jnp.asarray(last)))
+                     jnp.asarray(last)) + more)
+                if self.self_draft:
+                    # the module's guess after the chunk's last real token:
+                    # the slot's first pending draft, once this was the
+                    # prompt's last chunk
+                    p0.draft0 = load.pop()
                 p0.expert_loads += load     # expert models only (R = 1)
         except BaseException as e:
             # the donated POOL may be dead — this is a decode-grade
@@ -2131,7 +2219,7 @@ class ServingEngine:
             self._lose_rows(rows, f"admission prefill dispatch failed: "
                                   f"{type(e).__name__}: {e}")
             raise
-        if self.speculative:
+        if self.separate_draft:
             # mirror the chunk into the DRAFT lane: speculation proposes
             # from the draft model's own cache, so it needs the prompt's
             # K/V too (same spans — prefix sharing is disabled under
@@ -2212,7 +2300,8 @@ class ServingEngine:
                      jnp.asarray(dev_new, jnp.int32),
                      jnp.asarray(req.eos, jnp.int32))
                     + ((jnp.asarray(p.sel_row, jnp.int32),)
-                       if self.chunk_rows > 1 else ()))
+                       if self.chunk_rows > 1 else ())
+                    + ((p.draft0,) if self.self_draft else ()))
         except BaseException as e:
             # the state was donated — same recovery as a decode failure
             # (this admission's request is lost with it).  Only the
@@ -2228,7 +2317,7 @@ class ServingEngine:
                                   f"(request {req.rid} lost)")
             raise
         self._breaker.record_success()
-        if self.speculative:
+        if self.separate_draft:
             # insert the prefilled draft lane into the draft cache
             t0s = time.perf_counter()
             try:
@@ -2276,7 +2365,9 @@ class ServingEngine:
         self._rng, sub = jax.random.split(self._rng)
         try:
             inject.fire("serving.pre_decode_dispatch")
-            if self.speculative:
+            if self.self_draft:
+                ev = self._dispatch_spec_block(sub)
+            elif self.speculative:
                 ev = self._dispatch_spec(sub)
             else:
                 with self._observe_dispatch(
@@ -2327,9 +2418,17 @@ class ServingEngine:
         slot): the bytes the paged-decode kernel must read are this
         times the K/V bytes of one position, every layer.  With them,
         the pages those steps walk beside the whole table's
-        (``paging.SlotPages.block_reach``)."""
+        (``paging.SlotPages.block_reach``).
+
+        A self-drafting block (``_dispatch_spec_block``) is ``block``
+        WINDOWS of two rows a lane, at the lane's position and the next: a
+        window moves its lane one or two positions and the host learns
+        which an event later, so every unread or coming window is reckoned
+        at ONE — under by the drafts accepted meanwhile, a few positions
+        of a context of thousands."""
         block = self.block
-        unread = block * sum(e[0] == "decode" for e in self._events)
+        unread = block * sum(e[0] in ("decode", "spec")
+                             for e in self._events)
         live = [(r, len(r.tokens) + unread)
                 for s, r in enumerate(self._slots)
                 if r is not None and self._mirror_active[s]]
@@ -2338,9 +2437,35 @@ class ServingEngine:
         # (context the first step attends, steps) a live slot
         work = [(len(req.ids) + have, min(block, req.max_new - have))
                 for req, have in live if req.max_new > have]
+        if self.self_draft:
+            work = [(first + i, 2) for first, windows in work
+                    for i in range(windows)]
+            block *= 2
         return {"kv_positions": sum(steps * first + steps * (steps - 1) // 2
                                     for first, steps in work),
                 **self._pages.block_reach(work, block)}
+
+    def _dispatch_spec_block(self, sub):  # lock-held: _lock
+        """``decode_block`` self-drafted verify windows in ONE dispatch
+        (``slots.make_spec_block_fn``): pool and slot state donated, the
+        pending drafts ride the state.  The span carries the attention
+        work of two rows a lane a window (``_block_kv_work``) and, under
+        ``windows`` / ``proposed`` / ``accepted`` / ``rows_rejected``, what
+        the windows READ since the last dispatch came to — the mirror lags
+        the device by one event, so a dispatch reports its predecessors'
+        commits."""
+        seen, self._spec_unreported = self._spec_unreported, dict.fromkeys(
+            self._spec_unreported, 0)
+        with self._observe_dispatch(
+                "spec_block", phase="decode",
+                live_slots=int(self._mirror_active.sum()), **seen,
+                **self._count_work(self._block_kv_work())):
+            toks, accepted, self._cache, self._state, *load = \
+                self.engine._run_guarded(
+                    self._spec_fn,
+                    (self.engine._params, self._cache, self._state,
+                     jnp.asarray(self._pages.table()), sub))
+        return ("spec", toks, accepted, load)
 
     def _dispatch_spec(self, sub):  # lock-held: _lock
         """One speculative round, two device-chained dispatches and zero
@@ -2369,7 +2494,7 @@ class ServingEngine:
                     (self.engine._params, self._cache, self._state,
                      jnp.asarray(self._pages.table()), draft, sub))
         self.stats["spec_verify_secs"] += time.perf_counter() - t1
-        return ("spec", toks, accepted)
+        return ("spec", toks[None], accepted[None], [])
 
     # ------------------------------------------------------------------ #
     # Event processing (the host's lagging mirror of the device)
@@ -2520,29 +2645,46 @@ class ServingEngine:
         per-token events with monotonic indices — never one blob per
         dispatch — and mid-window retirement cuts the stream exactly at
         the terminal token."""
-        _, toks_dev, acc_dev = ev
-        n0 = self.stats["spec_committed_tokens"]
+        _, toks_dev, acc_dev, loads = ev
+        n0, w0 = (self.stats["spec_committed_tokens"],
+                  self.stats["spec_windows"])
         with span("dstpu.sched.wait_device", track="scheduler",
                   cat="scheduler", event="spec") as sp:
-            toks = np.asarray(toks_dev)                  # [spec_k+1, N]
-            acc = np.asarray(acc_dev)                    # [N]
+            # the dispatch's windows, in order: one (the draft model's
+            # verify) or ``decode_block`` (a self-drafting block)
+            toks = np.asarray(toks_dev)           # [windows, spec_k+1, N]
+            acc = np.asarray(acc_dev)             # [windows, N]
+            loads = list(map(np.asarray, loads))  # expert models
         self.stats["sync_secs"] += sp.dur_s
         self.stats["spec_rounds"] += 1
         with span("dstpu.sched.commit", track="scheduler", cat="mirror",
                   kind="spec") as commit_span:
-            for s in np.nonzero(self._mirror_active)[0]:
-                req = self._slots[s]
-                m = int(acc[s])
-                self.stats["spec_windows"] += 1
-                self.stats["spec_committed_tokens"] += m
-                for i in range(m):
-                    # by the in-program commit rule the device stopped
-                    # committing at exactly the token that retires here
-                    if self._mirror_commit_token(s, req, int(toks[i, s]),
-                                                 finished):
-                        break
-            commit_span.set(
-                tokens=self.stats["spec_committed_tokens"] - n0)
+            for w in range(toks.shape[0]):
+                for s in np.nonzero(self._mirror_active)[0]:
+                    req = self._slots[s]
+                    m = int(acc[w, s])
+                    self.stats["spec_windows"] += 1
+                    self.stats["spec_committed_tokens"] += m
+                    for i in range(m):
+                        # by the in-program commit rule the device stopped
+                        # committing at exactly the token that retires here
+                        if self._mirror_commit_token(
+                                s, req, int(toks[w, i, s]), finished):
+                            break
+            committed = self.stats["spec_committed_tokens"] - n0
+            commit_span.set(tokens=committed)
+            self._account_expert_load(loads, commit_span,
+                                      steps=toks.shape[0])
+        if self.self_draft:
+            # spec_k is 1: a window offers one draft, verifies two rows
+            # and commits one or two tokens
+            windows = self.stats["spec_windows"] - w0
+            for key, n in (("windows", windows), ("proposed", windows),
+                           ("accepted", committed - windows),
+                           ("rows_rejected", 2 * windows - committed)):
+                self._spec_unreported[key] += n
+                if key != "windows":        # spec_windows: counted above
+                    self.stats["spec_" + key] += n
         # derived rates for /metrics + Serving/spec_* monitor events
         w = self.stats["spec_windows"]
         if w:
@@ -2886,12 +3028,12 @@ class ServingEngine:
     def _ensure_workspace(self):  # lock-held: _lock
         if self._cache is None:
             self._cache = self._pages.take(self.engine.compute_dtype)
-        if self.speculative and self._draft_cache is None:
+        if self.separate_draft and self._draft_cache is None:
             self._draft_cache = self._draft_ws.take(
                 self.num_slots, self.cache_len, self.engine.compute_dtype)
         if self._state is None:
-            self._state = {k: jnp.asarray(v) for k, v in
-                           init_slot_state(self.num_slots).items()}
+            self._state = {k: jnp.asarray(v) for k, v in init_slot_state(
+                self.num_slots, draft=self.self_draft).items()}
             self._mirror_active[:] = False
 
     def _emit_metrics(self):  # lock-held: _lock

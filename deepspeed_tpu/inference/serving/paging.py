@@ -307,6 +307,10 @@ class SlotPages:
         self.share_prefixes = bool(share_prefixes)
         self._stats = stats
         self._module = module
+        # layers a model's own work counters sum over, where that is more
+        # than the model's (the engine sets it under self-drafting: the
+        # drafting module's layer rides every dispatch)
+        self.work_layers = None
         self._buffer = None              # the device pool, between uses
         self._pool = PagePool(self.num_pages)
         self._prefix = PrefixIndex()
@@ -583,7 +587,8 @@ class SlotPages:
                 "kv_pages_table": layers * self.pages_per_slot,
                 **({"state_rows": 1} if self.state_kinds else {}),
                 **(work(end - self.chunk, min(end, live_end or end),
-                        self.page, self.ring_pages) if work else {})}
+                        self.page, self.ring_pages, **self._work_kw())
+                   if work else {})}
 
     def block_reach(self, live, block):
         """What a decode block of ``block`` steps walks, as its dispatch
@@ -601,7 +606,11 @@ class SlotPages:
                 "kv_pages_table":
                     self.num_slots * self.pages_per_slot * block,
                 **(self._state_reach(live) if self.state_kinds else {}),
-                **(work(live, self.ring_pages) if work else {})}
+                **(work(live, self.ring_pages, **self._work_kw())
+                   if work else {})}
+
+    def _work_kw(self):
+        return {"layers": self.work_layers} if self.work_layers else {}
 
     def _state_reach(self, live):
         """A decode block's state work and the cache's split, as span

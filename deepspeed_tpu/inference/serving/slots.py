@@ -29,6 +29,11 @@ The programs:
   equal to solo ``generate()`` runs under greedy decoding) and write the
   slot's state entries in-program — so the host scheduler never
   synchronizes inside the admission path.
+* :func:`make_spec_block_fn` — self-drafting (a model with a
+  multi-token-prediction module of its own, :func:`drafts_itself`):
+  ``block`` verify windows of two rows a lane in one program, the module
+  drafting from the pools' own last layer; the chunk and admit programs
+  carry the module's rows and the first draft.
 * :func:`make_spec_verify_fn` and the draft side's three
   (:func:`make_draft_propose_fn`, :func:`make_draft_chunk_fn`,
   :func:`make_draft_admit_fn`) — speculative decoding; the DRAFT model
@@ -51,17 +56,22 @@ from deepspeed_tpu.tools.lint.hotpath import hot_path
 SLOT_STATE_KEYS = ("token", "pos", "active", "remaining", "eos")
 
 
-def init_slot_state(num_slots):
+def init_slot_state(num_slots, draft=False):
     """Host-side slot state: all lanes free.  ``eos=-1`` never matches a
-    sampled token (ids are >= 0), so free lanes emit -1 and retire nothing."""
+    sampled token (ids are >= 0), so free lanes emit -1 and retire nothing.
+    ``draft``: one more leaf, the self-drafting programs' pending draft a
+    lane (:func:`make_spec_block_fn`)."""
     import numpy as np
-    return {
+    state = {
         "token": np.zeros((num_slots,), np.int32),
         "pos": np.zeros((num_slots,), np.int32),
         "active": np.zeros((num_slots,), bool),
         "remaining": np.zeros((num_slots,), np.int32),
         "eos": np.full((num_slots,), -1, np.int32),
     }
+    if draft:
+        state["draft"] = np.zeros((num_slots,), np.int32)
+    return state
 
 
 def routes_experts(module):
@@ -82,31 +92,56 @@ def holds_share(module):
                    None) is not None
 
 
-def _decode(module, variables, ids, cache, pos, live=None, **kw):
-    """``module.decode`` as the slot programs call it: ``(logits, cache,
-    counts)``.  Dense model (``live`` None): the plain call, ``counts``
-    None.  Expert model: only ``live [B, S]`` tokens are routed, and
-    ``counts [expert layers, experts]`` int32 are the (token, expert)
-    assignments each expert layer computed — what its ``MoE`` sows."""
-    decode = type(module).decode
-    if live is None:
-        logits, cache = module.apply(variables, ids, cache, pos,
-                                     method=decode, **kw)
-        return logits, cache, None
-    (logits, cache), sown = module.apply(
-        variables, ids, cache, pos, method=decode, live=live,
-        mutable=["moe_stats"], **kw)
-    layers = sown["moe_stats"]
-    # layers_0 .. layers_<L-1>, in layer order (shorter names first)
-    names = sorted(layers, key=lambda n: (len(n), n))
-    counts = jnp.stack([layers[n]["moe_mlp"]["expert_tokens"]
-                        for n in names])
-    if holds_share(module):
+def drafts_itself(module):
+    """True for a model with a multi-token-prediction module of its own: a
+    ``draft`` method beside ``decode`` (``models/glm5.py``) — what
+    ``serving.spec_draft_model: "mtp"`` asks for."""
+    return hasattr(type(module), "draft") and getattr(
+        getattr(module, "config", None), "mtp_layers", 0) > 0
+
+
+def _sown_counts(sown, share):
+    """``counts [expert layers, experts (+ 1)]`` from what the expert
+    layers of one ``apply`` sowed, in layer order: every ``moe_mlp`` under
+    ``moe_stats``, whatever holds it (``layers_<i>``, a drafting module's
+    block), shorter names first."""
+    found = []
+
+    def walk(tree):
+        for name in sorted(tree, key=lambda n: (len(n), n)):
+            if name == "moe_mlp":
+                found.append(tree[name])
+            elif isinstance(tree[name], dict):
+                walk(tree[name])
+
+    walk(sown["moe_stats"])
+    counts = jnp.stack([f["expert_tokens"] for f in found])
+    if share:
         # the last column: choices that fell on experts held elsewhere
-        counts = jnp.concatenate([counts, jnp.stack(
-            [layers[n]["moe_mlp"]["elsewhere"] for n in names])[:, None]],
+        counts = jnp.concatenate(
+            [counts, jnp.stack([f["elsewhere"] for f in found])[:, None]],
             axis=1)
-    return logits, cache, counts
+    return counts
+
+
+def _decode(module, variables, ids, cache, pos, live=None, method=None,
+            **kw):
+    """``module.decode`` as the slot programs call it: ``(logits, cache,
+    counts)`` — ``(logits, hidden, cache, counts)`` where ``hidden=True``
+    asks the model for its rows' last hidden state too.  Dense model
+    (``live`` None): the plain call, ``counts`` None.  Expert model: only
+    ``live [B, S]`` tokens are routed, and ``counts [expert layers,
+    experts]`` int32 are the (token, expert) assignments each expert layer
+    computed — what its ``MoE`` sows.  ``method``: another method of the
+    same call form (a self-drafting model's ``draft``, whose first
+    argument is the hidden state)."""
+    method = method or type(module).decode
+    if live is None:
+        return module.apply(variables, ids, cache, pos, method=method,
+                            **kw) + (None,)
+    out, sown = module.apply(variables, ids, cache, pos, method=method,
+                             live=live, mutable=["moe_stats"], **kw)
+    return out + (_sown_counts(sown, holds_share(module)),)
 
 
 def _expert_load(counts, share=False):
@@ -252,7 +287,7 @@ def chunk_rows(module, chunk, page, speculative=False):
     return 1 if own_path else max(1, MAX_CHUNK_S // chunk)
 
 
-def make_chunk_fn(module, param_transform):
+def make_chunk_fn(module, param_transform, self_draft=False):
     """The admission-prefill chunk program:
     ``fn(params, cache, pages, chunk_ids, start, logits_at)`` — same
     body as the engine's per-chunk program (``generate()``'s split
@@ -276,15 +311,29 @@ def make_chunk_fn(module, param_transform):
     final one), so the rows past it are the padded tail.  For a model
     with dropless expert layers (:func:`routes_experts`) the tail is
     routed to no expert, and the program returns ``(logits, cache,
-    load)`` with the chunk's :func:`_expert_load`."""
+    load)`` with the chunk's :func:`_expert_load`.
+
+    ``self_draft`` (:func:`drafts_itself`; ``R`` = 1): the chunk also
+    fills the multi-token-prediction module's rows — row ``t`` from the
+    main model's ``h_t`` and token ``t + 1``.  The token after the chunk's
+    LAST real position is not in the chunk: a seventh argument, ``next_id
+    [1]``, is the next chunk's first token, or negative for the prompt's
+    last chunk, whose last row takes the first sampled token — the greedy
+    choice from this chunk's own logits, the one the admit program makes.
+    One more output, ``draft [1]``: the module's guess after that token,
+    the slot's first pending draft (read of the last chunk only)."""
     deq = param_transform if param_transform is not None else (lambda p: p)
     routed = routes_experts(module)
     share = holds_share(module)
 
     @hot_path("serving.prefill_chunk")
-    def chunk_step(params, cache, pages, chunk_ids, start, logits_at):
+    def chunk_step(params, cache, pages, chunk_ids, start, logits_at,
+                   *next_id):
         live = jnp.arange(chunk_ids.shape[1])[None, :] \
             <= logits_at[:, None] if routed else None
+        if self_draft:
+            return drafted_chunk(params, cache, pages, chunk_ids, start,
+                                 logits_at, next_id[0], live)
         # SlotPages.reserve starts every chunk on a common multiple of
         # page and chunk, which no shape shows: the marker says it — of
         # every row's start — and the K/V write goes in as page runs
@@ -297,6 +346,31 @@ def make_chunk_fn(module, param_transform):
         if routed:
             return logits, cache, _expert_load(counts[None], share)
         return logits, cache
+
+    def drafted_chunk(params, cache, pages, chunk_ids, start, logits_at,
+                      next_id, live):
+        variables = deq(params)
+        paged = lambda pools: {**pools, "pages": pages,
+                               "page_runs": jnp.zeros((), jnp.int32)}
+        logits, hidden, cache, counts = _decode(
+            module, variables, chunk_ids, paged(cache), start, live=live,
+            logits_at=logits_at, hidden=True)
+        first = jnp.argmax(logits[:, 0].astype(jnp.float32), axis=-1)
+        after = jnp.where(next_id >= 0, next_id, first.astype(jnp.int32))
+        at_last = jnp.arange(chunk_ids.shape[1])[None, :] \
+            == logits_at[:, None]
+        nxt = jnp.where(at_last, after[:, None],
+                        jnp.roll(chunk_ids, -1, axis=1))
+        guess, cache, drafted = _decode(
+            module, variables, nxt, paged(cache), start, live=live,
+            method=type(module).draft, hidden=hidden, logits_at=logits_at)
+        draft = jnp.argmax(guess[:, 0].astype(jnp.float32),
+                           axis=-1).astype(jnp.int32)
+        if routed:
+            load = _expert_load(
+                jnp.concatenate([counts, drafted])[None], share)
+            return logits, cache, load, draft
+        return logits, cache, draft
 
     return jax.jit(chunk_step, donate_argnums=(1,))
 
@@ -442,6 +516,89 @@ def make_spec_verify_fn(module, sample_fn, param_transform, k, cache_len):
     return jax.jit(verify, donate_argnums=(1, 2))
 
 
+def make_spec_block_fn(module, sample_fn, param_transform, block,
+                       cache_len):
+    """The self-drafting decode program (:func:`drafts_itself`):
+    ``fn(params, cache, state, pages, rng) -> (tokens [block, 2, N],
+    accepted [block, N], cache, state[, load])`` — ``block`` verify
+    WINDOWS in one program, as the decode block carries steps; the pool and
+    the slot state (with its ``draft`` leaf) donated (argnums 1, 2).
+
+    A window, per live lane holding committed token ``x_p`` (not yet in the
+    cache) and pending draft ``d``:
+
+    1. verify — ONE main forward over rows ``[x_p, d]`` at ``p, p + 1``:
+       both rows' cache rows written through the lane's table, row ``p +
+       1`` attending row ``p``, each row its own kept set — the target's
+       tokens ``t_0, t_1`` and hidden states ``h_p, h_{p+1}``;
+    2. :func:`_spec_commit` at ``k = 1``: ``t_0`` always, ``t_1`` iff ``d
+       == t_0``, budget and eos as in every decode path — so the committed
+       tokens are the non-speculative step's, token for token;
+    3. draft — the module over rows ``(h_p, t_0)``, ``(h_{p+1}, t_1)`` at
+       ``p, p + 1``, writing ITS rows there; the next pending draft is the
+       row's guess that the commit names (row 0 after one token, row 1
+       after two).  Both rows every window: when both tokens commit the
+       module's lane has no hole at ``p`` (``d == t_0`` then, so row ``p``
+       is what a step at ``p`` would have written).
+
+    A rejected ``p + 1`` row — the main model's and the module's — is
+    overwritten by the next window, whose first row sits there, before any
+    query can attend it (:func:`make_spec_verify_fn`'s argument).  Inactive
+    lanes' table rows go to the trash page; for a model with expert layers
+    a dead lane's rows are routed nowhere, a live lane's BOTH rows are
+    routed and counted (a rejected row's experts are what speculation
+    costs), and ``load`` is the block's :func:`_expert_load` over the main
+    model's expert layers and then the module's."""
+    deq = param_transform if param_transform is not None else (lambda p: p)
+    routed = routes_experts(module)
+    share = holds_share(module)
+
+    @hot_path("serving.spec_block")
+    def spec_block(params, cache, state, pages, rng):
+        variables = deq(params)
+
+        def window(carry, _):
+            cache, state, rng = carry
+            active, pos = state["active"], state["pos"]
+            draft = jnp.maximum(state["draft"], 0)
+            paged = lambda pools: {
+                **pools, "pages": jnp.where(active[:, None], pages, 0)}
+            live = jnp.repeat(active[:, None], 2, axis=1) if routed \
+                else None
+            ids = jnp.stack([state["token"], draft], axis=1)
+            logits, hidden, cache, counts = _decode(
+                module, variables, ids, paged(cache), pos, live=live,
+                hidden=True)
+            with jax.named_scope("head.sample"):
+                rng, *subs = jax.random.split(rng, 3)
+                t = jnp.stack(
+                    [sample_fn(logits[:, i], subs[i]).astype(jnp.int32)
+                     for i in range(2)], axis=1)
+            with jax.named_scope("slots.state"):
+                toks, accepted, new = _spec_commit(
+                    t, draft[:, None], state, 1, cache_len)
+            guess, cache, drafted = _decode(
+                module, variables, t, paged(cache), pos, live=live,
+                method=type(module).draft, hidden=hidden)
+            with jax.named_scope("slots.state"):
+                guess = jnp.argmax(guess.astype(jnp.float32),
+                                   axis=-1).astype(jnp.int32)
+                new["draft"] = jnp.take_along_axis(
+                    guess, jnp.clip(accepted - 1, 0, 1)[:, None],
+                    axis=1)[:, 0]
+            both = None if counts is None \
+                else jnp.concatenate([counts, drafted])
+            return (cache, new, rng), (toks, accepted, both)
+
+        (cache, state, _), (toks, accepted, counts) = jax.lax.scan(
+            window, (cache, state, rng), None, length=block)
+        if routed:
+            return toks, accepted, cache, state, _expert_load(counts, share)
+        return toks, accepted, cache, state
+
+    return jax.jit(spec_block, donate_argnums=(1, 2))
+
+
 def make_draft_chunk_fn(draft_module, param_transform):
     """The draft-side admission-prefill chunk program — same body as the
     engine's per-chunk program, bound to the DRAFT module: speculation
@@ -477,7 +634,7 @@ def make_draft_admit_fn():
     return jax.jit(admit, donate_argnums=(0,))
 
 
-def make_admit_fn(sample_fn, rows=1):
+def make_admit_fn(sample_fn, rows=1, self_draft=False):
     """The admission program:
     ``fn(state, logits, rng, slot, pos0, max_new, eos) -> (state,
     first_token)`` with the slot state donated (argnum 0).  The prefill
@@ -494,13 +651,15 @@ def make_admit_fn(sample_fn, rows=1):
     dispatch's ``[rows, 1, V]`` and an eighth argument, ``row``, says
     which row held this prompt's last real position — selected
     in-program, so a dispatch that finished several prompts costs no
-    slicing dispatch of its own."""
+    slicing dispatch of its own.  ``self_draft``: the last argument is
+    the chunk program's ``draft [1]``, written as the slot's pending
+    draft."""
 
     @hot_path("serving.admit")
-    def admit(state, logits, rng, slot, pos0, max_new, eos, *row):
+    def admit(state, logits, rng, slot, pos0, max_new, eos, *more):
         with jax.named_scope("head.sample"):
             if rows > 1:
-                logits = jax.lax.dynamic_slice_in_dim(logits, row[0], 1)
+                logits = jax.lax.dynamic_slice_in_dim(logits, more[0], 1)
             first = sample_fn(logits[:, 0], rng).astype(jnp.int32)[0]
         with jax.named_scope("slots.state"):
             # finished-at-admission: eos on the first token (eos=-1 never
@@ -512,7 +671,9 @@ def make_admit_fn(sample_fn, rows=1):
                      "active": upd(state["active"], active0),
                      "remaining": upd(state["remaining"],
                                       jnp.maximum(max_new - 1, 0)),
-                     "eos": upd(state["eos"], eos)}
+                     "eos": upd(state["eos"], eos),
+                     **({"draft": upd(state["draft"], more[-1][0])}
+                        if self_draft else {})}
         return state, first
 
     return jax.jit(admit, donate_argnums=(0,))

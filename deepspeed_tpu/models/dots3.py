@@ -10,7 +10,10 @@ first ``first_k_dense_replace`` layers and, in the rest, a routed expert
 layer: sigmoid scores, the top ``num_experts_per_tok`` of score + a stored
 bias (``noaux_tc``), gates the chosen scores over their sum, plus
 ``n_shared_experts`` shared experts.  Untied head.  The vision and audio
-towers and the multi-token-prediction module are not built.
+towers and the multi-token-prediction module are not built.  The block
+itself lives in ``models/latent_block.py`` (``models/glm5.py`` is built from
+the same one); this file holds the config keys, the two layer kinds' pools
+and the model's call forms.
 
 ``held_experts=(first, count)`` gives the model one chip's share of each
 expert layer (``moe/layer.py``); the router keeps its published width.
@@ -22,14 +25,15 @@ VJP through its kernels.
 """
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.latent_attention import (LatentAttention,
-                                                   LatentSpec, _rms, padded)
-from deepspeed_tpu.moe.layer import MoE
+from deepspeed_tpu.models.latent_attention import (LatentSpec,
+                                                   causal_pairs, padded)
+from deepspeed_tpu.models.latent_block import (LatentBlock,  # noqa: F401
+                                               _Mlp, _Norm)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,66 +145,6 @@ def dots3_model(hf, held_experts=None, **overrides):
     return Dots3Model(dots3_config(hf, held_experts, **overrides))
 
 
-class _Norm(nn.Module):
-    eps: float
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        return _rms(x, scale, self.eps)
-
-
-class _Mlp(nn.Module):
-    width: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
-                                         name=name)
-        return dense(x.shape[-1], "down_proj")(
-            nn.silu(dense(self.width, "gate_proj")(x))
-            * dense(self.width, "up_proj")(x))
-
-
-class Dots3Layer(nn.Module):
-    config: Dots3Config
-    layer_idx: int
-
-    def setup(self):
-        cfg, i = self.config, self.layer_idx
-        kind = cfg.layer_types[i]
-        self.spec = cfg.full if kind == "full_attention" else cfg.window
-        self.attn = LatentAttention(self.spec, cfg.jnp_dtype)
-        self.input_norm = _Norm(cfg.rms_norm_eps)
-        self.post_attn_norm = _Norm(cfg.rms_norm_eps)
-        if i < cfg.first_k_dense:
-            self.mlp = _Mlp(cfg.intermediate_size, cfg.jnp_dtype)
-        else:
-            self.moe_mlp = MoE(
-                hidden_size=cfg.hidden_size,
-                num_experts=cfg.n_routed_experts, k=cfg.moe_top_k,
-                capacity_factor=None, norm_topk_prob=cfg.norm_topk_prob,
-                ffn_hidden_size=cfg.moe_intermediate_size,
-                dtype=cfg.jnp_dtype, gated=True, activation=nn.silu,
-                scoring="sigmoid", routed_scaling=cfg.routed_scaling_factor,
-                shared_ffn_hidden_size=cfg.n_shared_experts
-                * cfg.moe_intermediate_size,
-                held_experts=cfg.held_experts)
-
-    def __call__(self, x, attend, live=None):
-        """``attend(attn, normed x) -> (out, pools)``: the call form the
-        model chose (chunk or step) with this layer's cache."""
-        a, pools = attend(self.attn, self.input_norm(x))
-        x = x + a
-        h = self.post_attn_norm(x)
-        if self.layer_idx < self.config.first_k_dense:
-            return x + self.mlp(h), pools
-        y, _, _ = self.moe_mlp(h, train=False, live=live)
-        return x + y, pools
-
-
 class Dots3Model(nn.Module):
     config: Dots3Config
 
@@ -212,7 +156,10 @@ class Dots3Model(nn.Module):
         cfg = self.config
         self.embed_tokens = nn.Embed(cfg.vocab_size, cfg.hidden_size,
                                      dtype=cfg.jnp_dtype)
-        self.layers = [Dots3Layer(cfg, i) for i in range(cfg.num_layers)]
+        self.layers = [LatentBlock(
+            cfg, cfg.full if kind == "full_attention" else cfg.window,
+            dense=i < cfg.first_k_dense)
+            for i, kind in enumerate(cfg.layer_types)]
         self.final_norm = _Norm(cfg.rms_norm_eps)
         self.lm_head = nn.Dense(cfg.vocab_size, use_bias=False,
                                 dtype=cfg.jnp_dtype)
@@ -255,15 +202,9 @@ class Dots3Model(nn.Module):
         window layers hold for the slot —, ``window_keys`` — pairs the
         window layers attend."""
         cfg = self.config
-        n = end - start
         full = len(cfg.layers_of("full_attention"))
         swa = len(cfg.layers_of("sliding_attention"))
-
-        def pairs(limit):
-            """sum over the chunk's queries t of min(t + 1, limit)."""
-            low = max(min(limit, end) - start, 0)  # queries under the limit
-            return low * start + low * (low + 1) // 2 + (n - low) * limit
-
+        pairs = lambda limit: causal_pairs(start, end, limit)
         return {"dsa_keys_scored": full * pairs(end),
                 "dsa_keys_kept": full * pairs(cfg.full.index_topk),
                 "latent_rows_read": full * -(-end // page_size) * page_size,

@@ -11,20 +11,31 @@ values; every head's key and value are up-projections of the latent.
 * A **window** layer attends the token and its ``window - 1`` predecessors
   and keeps its rows in a bounded ring a slot (``paging.SlotPages``).
 
-Three call forms over the same parameters (``ops/transformer/
+Call forms over the same parameters (``ops/transformer/
 latent_attention.py`` has the kernels):
 
 * :meth:`LatentAttention.chunk` — a prefill chunk of one slot, keys and
   values decompressed from the slot's cached rows, flash attention under
   the kept-set (or band) mask; with no cache it is the plain causal
   forward over the chunk alone (init, tests).
-* :meth:`LatentAttention.step` — one token a lane, in the absorbed form:
-  the query goes through the key up-projection, attends latent rows
-  directly, and the value up-projection follows.  A full layer READS only
-  the kept rows of the latent pool.
+* :meth:`LatentAttention.window` — a few rows a lane at consecutive
+  positions (a verify window; one row is a decode step), in the absorbed
+  form: the query goes through the key up-projection, attends latent rows
+  directly, and the value up-projection follows.  A full layer takes one
+  of two forms, chosen from what the module sees — the slot's table
+  against ``index_topk`` (:data:`LANE_FORM_KEPT_SETS`): the LANE form
+  reads a lane's rows once for all its rows and heads, each row's kept
+  set a mask over them (``attn.mla_lane_decode``); the per-row form sorts
+  a row's scores and READS only its kept rows.
+* :meth:`LatentAttention.step` — one token a lane: a window layer's ring,
+  or :meth:`window` at one row.
 
-The headwise output gate ``sigmoid(x W_g)`` scales each head's output
-before ``o_proj``.
+What differs between the models that share this module is a size of the
+:class:`LatentSpec`, fixed when the module is built: ``gated`` — the
+headwise output gate ``sigmoid(x W_g)`` on each head's output before
+``o_proj`` (``models/dots3.py`` has it, ``models/glm5.py`` has neither the
+gate nor its parameter) —, ``rescale`` and ``interleaved``, the rotary
+pairing (``(i, i + d/2)`` or ``(2i, 2i + 1)``).
 """
 
 import dataclasses
@@ -38,6 +49,15 @@ import flax.linen as nn
 from deepspeed_tpu.ops.transformer import latent_attention as ops
 
 LANES = 128
+# A full layer's decode takes the LANE form — a lane's rows read once for
+# all its rows and heads, a row's kept set a mask over them — while the
+# slot's table spans at most this many kept sets (``index_topk``), and
+# beyond that the per-row form, a sort and a gather of the kept rows:
+# the lane form reads ``table`` rows a lane, the per-row one writes and
+# reads ``index_topk`` gathered rows a ROW, after a sort of the whole row.
+# (GLM-5's cell: 4,672 / 2,048 = 2.3, lane; dots3's: 16,448 / 2,048 = 8,
+# per row.  The crossing was not measured: PERF.md section 7.)
+LANE_FORM_KEPT_SETS = 4
 
 
 def padded(width):
@@ -63,6 +83,8 @@ class LatentSpec:
     index_dim: int = 0
     index_topk: int = 0
     rescale: bool = True
+    gated: bool = True           # the headwise output gate and its W_g
+    interleaved: bool = False    # rotary pairs (2i, 2i + 1), not (i, i + d/2)
 
     @property
     def row(self):
@@ -73,18 +95,27 @@ class LatentSpec:
         return float(1.0 / np.sqrt(self.nope + self.rope))
 
 
-def rope(x, positions, theta, dims=None):
+def rope(x, positions, theta, dims=None, interleaved=False):
     """Rotary positions on the first ``dims`` features of ``x [..., T,
-    D]`` (default all), half-split layout; ``positions [T]``."""
+    D]`` (default all); ``positions [T]``.  Feature ``i`` pairs with
+    ``i + dims/2`` (the half-split layout) or, ``interleaved``, ``2i``
+    with ``2i + 1``."""
     dims = dims or x.shape[-1]
     half = dims // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions.astype(jnp.float32)[:, None] * freqs
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:dims]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
-                           xf[..., dims:]], axis=-1)
+    if interleaved:
+        pairs = xf[..., :dims].reshape(xf.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        rot = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-1).reshape(xf.shape[:-1] + (dims,))
+        out = jnp.concatenate([rot, xf[..., dims:]], axis=-1)
+    else:
+        x1, x2 = xf[..., :half], xf[..., half:dims]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                               xf[..., dims:]], axis=-1)
     return out.astype(x.dtype)
 
 
@@ -100,6 +131,14 @@ def _layer_norm(x, scale, bias, eps):
     var = jnp.mean(jnp.square(xf - mu), -1, keepdims=True)
     return ((xf - mu) * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
             + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def causal_pairs(start, end, limit):
+    """(query, key) pairs of a chunk's queries ``start .. end - 1`` when
+    query ``t`` sees ``min(t + 1, limit)`` keys — the host-side count
+    behind a latent model's ``chunk_work``."""
+    low = max(min(limit, end) - start, 0)      # queries under the limit
+    return low * start + low * (low + 1) // 2 + (end - start - low) * limit
 
 
 def lane_rows(pool, table_row, multiple):
@@ -146,7 +185,8 @@ class LatentAttention(nn.Module):
         self.kv_a_norm = ones("kv_a_norm", z.kv_rank)
         self.kv_b = p("kv_b", z.kv_rank, z.heads * (z.nope + z.v))
         self.o_proj = p("o_proj", z.heads * z.v, z.hidden)
-        self.gate = p("gate", z.hidden, z.heads)
+        if z.gated:
+            self.gate = p("gate", z.hidden, z.heads)
         if z.index_topk:
             self.index_q = p("index_q", z.q_rank,
                              z.index_heads * z.index_dim)
@@ -172,12 +212,13 @@ class LatentAttention(nn.Module):
                    up(z.q_rank))
         q = jnp.einsum("tr,rhd->htd", c_q, self._w(self.q_b).reshape(
             z.q_rank, z.heads, z.nope + z.rope))
-        q = jnp.concatenate([q[..., :z.nope],
-                             rope(q[..., z.nope:], positions, z.theta)], -1)
+        turn = lambda t: rope(t, positions, z.theta,
+                              interleaved=z.interleaved)
+        q = jnp.concatenate([q[..., :z.nope], turn(q[..., z.nope:])], -1)
         kv = x @ self._w(self.kv_a)
         row = jnp.concatenate([
             _rms(kv[:, :z.kv_rank], self.kv_a_norm, z.eps, up(z.kv_rank)),
-            rope(kv[:, z.kv_rank:], positions, z.theta)], axis=-1)
+            turn(kv[:, z.kv_rank:])], axis=-1)
         return q, row, c_q
 
     def _index(self, x, c_q, positions):
@@ -186,11 +227,11 @@ class LatentAttention(nn.Module):
         z = self.spec
         q = (c_q @ self._w(self.index_q)).reshape(-1, z.index_heads,
                                                   z.index_dim)
-        q = rope(q.transpose(1, 0, 2), positions, z.theta,
-                 z.rope).transpose(1, 0, 2)
+        q = rope(q.transpose(1, 0, 2), positions, z.theta, z.rope,
+                 z.interleaved).transpose(1, 0, 2)
         k = _layer_norm(x @ self._w(self.index_k), self.index_k_scale,
                         self.index_k_bias, z.eps)
-        k = rope(k, positions, z.theta, z.rope)
+        k = rope(k, positions, z.theta, z.rope, z.interleaved)
         w = (x @ self._w(self.index_w)).astype(jnp.float32) \
             * float(z.index_heads ** -0.5 * z.index_dim ** -0.5)
         return q, k, w
@@ -201,9 +242,12 @@ class LatentAttention(nn.Module):
         return w[..., :z.nope], w[..., z.nope:]
 
     def _out(self, x, o):
-        """``o [T, H, v]`` gated head by head, through ``o_proj``."""
-        g = jax.nn.sigmoid((x @ self._w(self.gate)).astype(jnp.float32))
-        o = (o.astype(jnp.float32) * g[..., None]).astype(self.dtype)
+        """``o [T, H, v]``, gated head by head where the spec has the
+        gate, through ``o_proj``."""
+        if self.spec.gated:
+            g = jax.nn.sigmoid((x @ self._w(self.gate)).astype(jnp.float32))
+            o = o.astype(jnp.float32) * g[..., None]
+        o = o.astype(self.dtype)
         return o.reshape(o.shape[0], -1) @ self._w(self.o_proj)
 
     # ---- a chunk of one slot ---- #
@@ -282,51 +326,116 @@ class LatentAttention(nn.Module):
         return self._attend(q, keys, mask.astype(jnp.int8),
                             "attn.mla_window"), pool
 
+    # ---- a few rows a lane ---- #
+    def window(self, x, start, cache):
+        """``x [N W, h]``: lane ``n``'s ``W`` rows at positions ``start[n]
+        .. + W - 1`` (``W`` 1: a decode step; 2: a verify window, the later
+        row attending the earlier); ``cache = (pools, layer, table [N,
+        n])`` of a full layer.  Every row's cache rows are written first;
+        then each row's kept set is the exact top ``index_topk`` of its
+        scores and the absorbed softmax runs over it, in the form the
+        slot's table decides (:data:`LANE_FORM_KEPT_SETS`).  Returns
+        ``(out [N W, h], pools)``."""
+        z = self.spec
+        N, W = start.shape[0], x.shape[0] // start.shape[0]
+        positions = start if W == 1 else (
+            start[:, None] + jnp.arange(W, dtype=start.dtype)).reshape(-1)
+        # every row has its own position: rope row by row
+        q, row, c_q = jax.vmap(
+            lambda xr, p: self._project(xr[None], p[None]))(x, positions)
+        q, row, c_q = q[:, :, 0], row[:, 0], c_q[:, 0]    # [T, H, D] ...
+        w_k, w_v = self._kv_up()
+        q_lat = jnp.einsum("thd,rhd->thr", q[..., :z.nope], w_k)
+        (latent, index), layer, table = cache
+        qi, ki, w = jax.vmap(
+            lambda xr, cr, p: self._index(xr[None], cr[None], p[None]))(
+                x, c_q, positions)
+        per_row = table if W == 1 else jnp.repeat(table, W, axis=0)
+        with jax.named_scope("cache.write"):
+            latent = write_rows(latent, layer, per_row, positions, row)
+            index = write_rows(index, layer, per_row, positions, ki[:, 0])
+        page = latent.shape[2]
+        if table.shape[1] * page <= LANE_FORM_KEPT_SETS * z.index_topk:
+            lat, latent, index = self._lanes(
+                q_lat, q[..., z.nope:], qi[:, 0], w[:, 0], latent, index,
+                layer, table, start, positions)
+        else:
+            lat = self._kept_rows(q_lat, q[..., z.nope:], qi[:, 0], w[:, 0],
+                                  latent, index, layer, per_row, positions)
+        out = jnp.einsum("thr,rhd->thd", lat.astype(self.dtype), w_v)
+        return self._out(x, out), (latent, index)
+
+    def _lanes(self, q_lat, q_rope, qi, w, latent, index, layer, table,
+               start, positions):
+        """The lane form: the lane's index keys and latent rows are read
+        through the table ONCE for all its rows and heads, a row's kept
+        set is a MASK over them (``ops.kept_mask``, the chunk's) — no
+        sort, no gather of kept rows a row.  Returns ``(attended latent,
+        latent pool, index pool)``, the pools as the kernels hand them
+        through."""
+        z = self.spec
+        N, W = start.shape[0], positions.shape[0] // start.shape[0]
+        # the lane in 512-key blocks (the kept-set kernel's rows are whole
+        # lane tiles), trash-page entries past the table
+        lane, bp = ops.lane_pages(table, latent.shape[2])
+        L = lane.shape[1] * latent.shape[2]
+        ctx = jnp.minimum(start + W, L)
+        by_lane = lambda t: t.reshape((N, W) + t.shape[1:])
+        scores, index = ops.lane_index_scores(by_lane(qi), by_lane(w), index,
+                                              layer, lane, bp, ctx)
+        kept = ops.kept_mask(scores.reshape(N * W, L), positions,
+                             min(z.index_topk, L))
+        q_row = jnp.concatenate([q_lat, q_rope], axis=-1)
+        q_row = jnp.pad(q_row, ((0, 0), (0, 0),
+                                (0, latent.shape[-1] - q_row.shape[-1])))
+        lat, latent = ops.lane_decode(by_lane(q_row), by_lane(kept), latent,
+                                      layer, lane, bp, ctx, z.kv_rank,
+                                      z.scale)
+        return lat.reshape((N * W,) + lat.shape[2:]), latent, index
+
+    def _kept_rows(self, q_lat, q_rope, qi, w, latent, index, layer, table,
+                   positions):
+        """The per-row form (``table [T, n]``, a row's own): a sort of
+        the row's scores and a gather of its kept rows, which are all it
+        READS of the latent pool."""
+        z = self.spec
+        T, page = positions.shape[0], latent.shape[2]
+        index_keys = index[layer, table].reshape(T, -1, index.shape[-1])
+        L = index_keys.shape[1]
+        scores = ops.index_scores_rows(qi, w, index_keys)
+        visible = jnp.arange(L)[None, :] <= positions[:, None]
+        kept, valid = ops.kept_indices(scores, visible, min(z.index_topk, L))
+        flat = jnp.take_along_axis(table, kept // page, axis=1) * page \
+            + kept % page
+        rows = latent[layer].reshape(-1, latent.shape[-1])[flat]
+        return ops.sparse_decode(q_lat, q_rope, rows, valid, z.kv_rank,
+                                 z.scale)
+
     # ---- one token a lane ---- #
     def step(self, x, positions, cache):
         """``x [N, h]``, lane ``n`` at ``positions[n]``; ``cache =
-        (pools, layer, table [N, n])``.  Returns ``(out [N, h], pools)``."""
+        (pools, layer, table [N, n])``.  Returns ``(out [N, h], pools)``.
+        A full layer's step is :meth:`window` at one row a lane."""
         z = self.spec
+        if not z.window:
+            return self.window(x, positions, cache)
         N = x.shape[0]
         # every lane has its own position: rope row by row
         q, row, c_q = jax.vmap(
             lambda xr, p: self._project(xr[None], p[None]))(x, positions)
-        q, row, c_q = q[:, :, 0], row[:, 0], c_q[:, 0]    # [N, H, D] ...
+        q, row = q[:, :, 0], row[:, 0]                    # [N, H, D] ...
         w_k, w_v = self._kv_up()
         q_lat = jnp.einsum("nhd,rhd->nhr", q[..., :z.nope], w_k)
-        pools, layer, table = cache
-        if z.window:
-            with jax.named_scope("cache.write"):
-                pool = write_rows(pools, layer, table, positions, row)
-            page, n = pool.shape[2], table.shape[1]
-            rows = pool[layer, table].reshape(N, n * page, -1)
-            r = jnp.arange(n * page, dtype=jnp.int32)[None, :]
-            held = positions[:, None] - (positions[:, None] - r) % (n * page)
-            valid = (held >= 0) & (held > positions[:, None] - z.window)
-            with jax.named_scope("attn.mla_window"):
-                lat = ops.sparse_decode(q_lat, q[..., z.nope:], rows, valid,
-                                        z.kv_rank, z.scale)
-            pools = pool
-        else:
-            latent, index = pools
-            qi, ki, w = jax.vmap(
-                lambda xr, cr, p: self._index(xr[None], cr[None], p[None]))(
-                    x, c_q, positions)
-            with jax.named_scope("cache.write"):
-                latent = write_rows(latent, layer, table, positions, row)
-                index = write_rows(index, layer, table, positions, ki[:, 0])
-            page = latent.shape[2]
-            index_keys = index[layer, table].reshape(N, -1, index.shape[-1])
-            L = index_keys.shape[1]
-            scores = ops.index_scores_rows(qi[:, 0], w[:, 0], index_keys)
-            visible = jnp.arange(L)[None, :] <= positions[:, None]
-            kept, valid = ops.kept_indices(scores, visible,
-                                           min(z.index_topk, L))
-            flat = jnp.take_along_axis(table, kept // page, axis=1) * page \
-                + kept % page
-            rows = latent[layer].reshape(-1, latent.shape[-1])[flat]
+        pool, layer, table = cache
+        with jax.named_scope("cache.write"):
+            pool = write_rows(pool, layer, table, positions, row)
+        page, n = pool.shape[2], table.shape[1]
+        rows = pool[layer, table].reshape(N, n * page, -1)
+        r = jnp.arange(n * page, dtype=jnp.int32)[None, :]
+        held = positions[:, None] - (positions[:, None] - r) % (n * page)
+        valid = (held >= 0) & (held > positions[:, None] - z.window)
+        with jax.named_scope("attn.mla_window"):
             lat = ops.sparse_decode(q_lat, q[..., z.nope:], rows, valid,
                                     z.kv_rank, z.scale)
-            pools = (latent, index)
         out = jnp.einsum("nhr,rhd->nhd", lat.astype(self.dtype), w_v)
-        return self._out(x, out), pools
+        return self._out(x, out), pool

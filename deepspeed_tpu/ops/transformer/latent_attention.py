@@ -2,7 +2,7 @@
 (DeepSeek-V3.2's DSA indexer) and for windowed latent attention — what
 ``models/latent_attention.py`` calls on the serving path.
 
-Three Pallas kernels, each findable in a device trace by its own name:
+Pallas kernels, each findable in a device trace by its own name:
 
 * ``attn.dsa_index`` (:func:`index_scores`) — the indexer's scores of a
   chunk of queries against a slot's cached indexer keys:
@@ -22,16 +22,27 @@ Three Pallas kernels, each findable in a device trace by its own name:
   bit patterns, 32 compare-and-count passes over a row that stays in VMEM,
   no sort (:func:`kept_mask` makes the mask ``score >= it``).
 
-and three parts left to XLA, each under a ``jax.named_scope`` of its name:
+* ``attn.dsa_lane_index`` (:func:`lane_index_scores`) and
+  ``attn.mla_lane_decode`` (:func:`lane_decode`) — a decode step's or a
+  verify window's LANE form: one grid step a lane, the pool whole in HBM,
+  the lane's pages fetched through its table in blocks of ~512 keys
+  (double-buffered) ONCE for all the lane's rows and heads — the index
+  scores of its ``W`` rows, then the absorbed softmax of ``W x heads``
+  query rows under each row's kept mask, the latent row key and value at
+  once.  Blocks past the lane's last position, and a dead lane's, are not
+  fetched.
 
-* ``attn.dsa_topk`` for a decode step (:func:`kept_indices`) —
-  ``lax.top_k``'s indices.
-* ``attn.dsa_index`` for a decode step (:func:`index_scores_rows`) — one
-  query a lane against its lane's keys.
-* ``attn.mla_sparse_decode`` (:func:`sparse_decode`) — a decode step's
-  attention over the KEPT rows only, in the absorbed form: the latent row
-  is key and value at once (the value is its first ``rank`` columns), so a
-  lane reads ``kept x row`` bytes a layer, not ``context x row``.
+and three parts left to XLA, each under a ``jax.named_scope`` of its name
+(the per-row form of a decode step, where the context is many times the
+kept set):
+
+* ``attn.dsa_topk`` (:func:`kept_indices`) — ``lax.top_k``'s indices.
+* ``attn.dsa_index`` (:func:`index_scores_rows`) — one query a lane
+  against its lane's gathered keys.
+* ``attn.mla_sparse_decode`` (:func:`sparse_decode`) — attention over the
+  KEPT rows only, in the absorbed form: the latent row is key and value at
+  once (the value is its first ``rank`` columns), so a row reads ``kept x
+  row`` bytes a layer, not ``context x row``.
 
 No VJP: training through latent attention is not implemented.
 """
@@ -117,6 +128,128 @@ def index_scores_rows(q, w, k):
                        preferred_element_type=jnp.float32)
         return jnp.einsum("njl,nj->nl", jnp.maximum(s, 0.0),
                           w.astype(jnp.float32))
+
+
+def _lane_blocks(ctx_ref, pages_ref, n, bk):
+    """Key blocks lane ``n`` walks: those up to its last live position —
+    none for a DEAD lane, one whose table points at the trash page."""
+    return jnp.where(pages_ref[n, 0] == 0, 0, (ctx_ref[n] + bk - 1) // bk)
+
+
+def _lane_fetch(pages_ref, layer_ref, pool, buf, sem, n, page, bp):
+    """``(start, wait)`` of key block ``i`` of lane ``n``: its ``bp``
+    pages through the table into the rows of ``buf[i % 2]``."""
+    def each(i, fn):
+        slot = i % 2
+        for j in range(bp):
+            fn(pltpu.make_async_copy(
+                pool.at[layer_ref[0], pages_ref[n, i * bp + j]],
+                buf.at[slot, pl.ds(j * page, page)], sem.at[slot]))
+
+    return (lambda i: each(i, lambda cp: cp.start()),
+            lambda i: each(i, lambda cp: cp.wait()))
+
+
+def _lane_walk(n_blocks, start, wait, fold):
+    """Block ``i + 1`` arrives while ``fold(i, slot)`` runs on block
+    ``i``."""
+    @pl.when(n_blocks > 0)
+    def _first():
+        start(0)
+
+    def body(i, carry):
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            start(i + 1)
+
+        wait(i)
+        fold(i, i % 2)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, body, None)
+
+
+def _lane_index_kernel(ctx_ref, layer_ref, pages_ref, q_ref, w_ref, pool,
+                       o_ref, _pool_out, buf, sem, *, page, bp, rows, heads):
+    n = pl.program_id(0)
+    bk = bp * page
+    start, wait = _lane_fetch(pages_ref, layer_ref, pool, buf, sem, n, page,
+                              bp)
+    o_ref[...] = jnp.full(o_ref.shape, NEG, o_ref.dtype)
+
+    def fold(i, slot):
+        s = jax.lax.dot_general(q_ref[0], buf[slot],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        part = jnp.maximum(s, 0.0) * w_ref[0][:, :1]
+        o_ref[0, :, pl.ds(pl.multiple_of(i * bk, bk), bk)] = jnp.concatenate(
+            [jnp.sum(part[r * heads:(r + 1) * heads], axis=0, keepdims=True)
+             for r in range(rows)], axis=0)
+
+    _lane_walk(_lane_blocks(ctx_ref, pages_ref, n, bk), start, wait, fold)
+
+
+def _lane_call(kernel, name, ctx, layer, table, operands, pool, out_width,
+               scratch, bp):
+    """One grid step a lane, the pool whole in HBM, the table and the
+    lanes' contexts in SMEM; ``operands [N, ...]`` a lane's block each.
+    Returns ``(out [N, *out_width] float32, pool)`` — the pool handed
+    through as an aliased output nothing writes: the next cache write then
+    follows the kernel in the program's dataflow, and XLA has no earlier
+    value of the pool to keep or recompute beside it."""
+    N = table.shape[0]
+    page = pool.shape[2]
+    lane = lambda n, *refs: (n, 0, 0)
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(N,),
+            in_specs=[pl.BlockSpec((1,) + t.shape[1:], lane)
+                      for t in operands] + [whole],
+            out_specs=[pl.BlockSpec((1,) + out_width, lane), whole],
+            scratch_shapes=scratch + [
+                pltpu.VMEM((2, bp * page, pool.shape[-1]), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct((N,) + out_width, jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={3 + len(operands): 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+        name=name,
+    )(ctx.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      table.astype(jnp.int32), *operands, pool)
+
+
+def lane_pages(table, page, block_keys=512):
+    """``(table, pages a key block)`` for the lane kernels: the table
+    padded with trash-page entries to whole blocks of ~``block_keys``
+    keys."""
+    bp = min(max(1, block_keys // page), table.shape[1])
+    return jnp.pad(table, ((0, 0), (0, -table.shape[1] % bp))), bp
+
+
+def lane_index_scores(q, w, pool, layer, table, bp, ctx):
+    """The scores ``[N, W, L]`` float32 of ``W`` rows a lane against the
+    lane's cached index keys, read THROUGH the table (``table [N, n]``,
+    ``n`` a multiple of ``bp``; ``L = n x page``) from ``pool [layers,
+    pages, page, D]``, each lane's keys fetched once for its rows: ``q
+    [N, W, J, D]``, ``w [N, W, J]`` (the score's constant factors folded
+    in).  Key blocks past ``ctx[n]`` positions, and every block of a dead
+    lane (its table at the trash page), are not fetched: ``NEG``."""
+    N, W, J, D = q.shape
+    page = pool.shape[2]
+    L = table.shape[1] * page
+    return _lane_call(
+        functools.partial(_lane_index_kernel, page=page, bp=bp, rows=W,
+                          heads=J),
+        "attn.dsa_lane_index", ctx, layer, table,
+        [q.reshape(N, W * J, D),
+         jnp.broadcast_to(w.astype(jnp.float32).reshape(N, W * J, 1),
+                          (N, W * J, 128))],
+        pool, (W, L), [], bp)
 
 
 # --------------------------------------------------------------------- #
@@ -243,6 +376,67 @@ def sparse_decode(q_lat, q_rope, rows, valid, rank, scale):
         p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
         return jnp.einsum("nhk,nkr->nhr", p.astype(rows.dtype), lat,
                           preferred_element_type=jnp.float32)
+
+
+def _lane_decode_kernel(ctx_ref, layer_ref, pages_ref, q_ref, keep_ref, pool,
+                        o_ref, _pool_out, m_ref, l_ref, acc_ref, buf, sem, *,
+                        page, bp, rows, heads, rank, scale):
+    n = pl.program_id(0)
+    bk = bp * page
+    start, wait = _lane_fetch(pages_ref, layer_ref, pool, buf, sem, n, page,
+                              bp)
+    m_ref[...] = jnp.full(m_ref.shape, NEG, m_ref.dtype)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def fold(i, slot):
+        keys = buf[slot]
+        s = jax.lax.dot_general(q_ref[0], keys, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        keep = keep_ref[0, :, pl.ds(pl.multiple_of(i * bk, bk), bk)] != 0
+        on = jnp.concatenate(
+            [jnp.broadcast_to(keep[r:r + 1], (heads, bk))
+             for r in range(rows)], axis=0)
+        s = jnp.where(on, s * scale, NEG)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(on, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_old - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(keys.dtype), keys[:, :rank],
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    _lane_walk(_lane_blocks(ctx_ref, pages_ref, n, bk), start, wait, fold)
+    o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def lane_decode(q, kept, pool, layer, table, bp, ctx, rank, scale):
+    """Absorbed latent attention of ``W`` rows a lane over the lane's
+    rows under each row's kept mask, the rows read THROUGH the table from
+    ``pool [layers, pages, page, width]`` once a lane for all its rows and
+    heads — Pallas, ``attn.mla_lane_decode``; nothing is sorted or
+    gathered row by row (:func:`sparse_decode` reads the kept rows only,
+    which wins where the context is many times the kept set).  ``q [N,
+    W, H, width]`` — ``[the nope part through the key up-projection |
+    rope part | zeros]``, laid out as a pool row, so a score is ONE
+    product —, ``kept [N, W, L]`` (nonzero: attended; ``L = n x page``,
+    ``table [N, n]``, ``n`` a multiple of ``bp``), ``ctx [N]`` the
+    positions a lane has (blocks past them, and a dead lane's, are not
+    fetched).  Returns ``(the attended latent [N, W, H, rank] float32 —
+    zeros for a row that keeps nothing —, pool)``."""
+    N, W, H, width = q.shape
+    out, pool = _lane_call(
+        functools.partial(_lane_decode_kernel, page=pool.shape[2], bp=bp,
+                          rows=W, heads=H, rank=rank, scale=scale),
+        "attn.mla_lane_decode", ctx, layer, table,
+        [q.reshape(N, W * H, width), kept.astype(jnp.int32)], pool,
+        (W * H, rank),
+        [pltpu.VMEM((W * H, 1), jnp.float32),
+         pltpu.VMEM((W * H, 1), jnp.float32),
+         pltpu.VMEM((W * H, rank), jnp.float32)], bp)
+    return out.reshape(N, W, H, rank), pool
 
 
 # --------------------------------------------------------------------- #

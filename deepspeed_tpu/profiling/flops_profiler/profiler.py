@@ -307,7 +307,8 @@ def _scope_to_path(op_name):
 PARTS = ("embed", "attn.proj", "attn.core", "attn.mla_decompress",
          "attn.eva", "eva.summarise", "cache.write", "mlp", "moe.route",
          "moe.experts", "conv.short", "norm", "residual", "head", "loss",
-         "optim", "scan.stack", "slots", "comm", "xla.prefetch")
+         "optim", "scan.stack", "slots", "mtp.combine", "comm",
+         "xla.prefetch")
 PHASES = ("fwd", "bwd", "replay")
 # collectives are found by opcode, whatever scope they carry (the list is
 # ``benchmark/trace.py::COLLECTIVES``)
@@ -340,6 +341,12 @@ SCOPE_PARTS = (
     (r"slots\.(state|expert_load)", "slots"),
     (r"conv\.short|conv/(in_proj|out_proj)", "conv.short"),
     (r"eva\.summarise", "eva.summarise"),
+    # a multi-token-prediction module (``models/glm5.py``): what its three
+    # scopes hold outside a module the rows below know — the concatenation
+    # and ``eh_proj``, the block's row bookkeeping, the shared head
+    (r"mtp\.combine|eh_proj", "mtp.combine"),
+    (r"mtp\.block", "residual"),
+    (r"mtp\.head", "head"),
     # the layer scan's own operations (``scan_layers``): a layer's slice
     # out of the stacked parameters and saved residuals, the saves' and the
     # gradients' writes back into the stacks, the stacks' zeros and copies
@@ -352,20 +359,22 @@ SCOPE_PARTS = (
     (r"\w*moe\.route\w*", "moe.route"),
     (r"\w*moe\.experts_(gmm|grouped)\w*", "moe.experts"),
     (r"\w*attn\.(flash_(fwd|dq|dkv)|block_sparse_fwd|chunk_prefill|decode"
-     r"|paged_decode|paged_chunk_prefill|dsa_index|dsa_topk"
-     r"|mla_chunk_prefill|mla_window|mla_sparse_decode)\w*", "attn.core"),
+     r"|paged_decode|paged_chunk_prefill|dsa_index|dsa_lane_index|dsa_topk"
+     r"|mla_chunk_prefill|mla_window|mla_sparse_decode|mla_lane_decode)\w*",
+     "attn.core"),
     (r"\w*attn\.eva_(decode|chunk)\w*", "attn.eva"),
     # flax modules and their methods
     (r"\w+\._eva_attend_(chunk|step)", "attn.eva"),
     (r"\w+\._kv_up", "attn.mla_decompress"),
-    (r"\w+\._(chunk_full|chunk_window|attend)", "attn.core"),
+    (r"\w+\._(chunk_full|chunk_window|attend|lanes|kept_rows)", "attn.core"),
     (r"(q|k|v|qkv|o|out)_proj|(q|k)_(layer)?norm|\w+\._(project|out|index)",
      "attn.proj"),
     (r"gate_proj|up_proj|down_proj|shared_(gate|up|down)|mlp|feed_forward",
      "mlp"),
     (r"moe_mlp(\.\w+)?|ExpertsMLP_\d+", "moe.experts"),
     (r"conv", "conv.short"),
-    (r"final_norm|embedding_norm|lm_head|project_out|\w+\._head", "head"),
+    (r"final_norm|head_norm|embedding_norm|lm_head|project_out|\w+\._head",
+     "head"),
     (r"embed_tokens|embed_positions|project_in", "embed"),
     (r"\w+_norm", "norm"),
     # what the attention module does outside its projections: the head

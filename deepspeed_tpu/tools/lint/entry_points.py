@@ -258,6 +258,39 @@ def serving_spec_verify():
                       expect_donation=True)
 
 
+def serving_spec_block():
+    """The self-drafting decode program (``models/glm5.py``'s multi-token-
+    prediction module drafting for its own model): ``block`` verify windows
+    of two rows a lane in one scan — main forward, in-program commit of one
+    or two tokens, the module's two rows — pool AND slot state (with its
+    pending-draft leaf) donated, page tables traced, the expert load of
+    main layers and module as one vector, no host callbacks."""
+    from deepspeed_tpu.inference.engine import build_sample_fn
+    from deepspeed_tpu.inference.serving.slots import make_spec_block_fn
+    from deepspeed_tpu.models.glm5 import Glm5Config, Glm5Model
+    from deepspeed_tpu.models.latent_attention import LatentSpec
+    attn = LatentSpec(hidden=32, heads=2, q_rank=16, kv_rank=16, nope=8,
+                      rope=8, v=8, theta=1e6, index_heads=2, index_dim=16,
+                      index_topk=8, rescale=False, gated=False,
+                      interleaved=True)
+    model = Glm5Model(Glm5Config(
+        vocab_size=97, hidden_size=32, num_layers=2, first_k_dense=1,
+        intermediate_size=48, moe_intermediate_size=16, n_routed_experts=8,
+        n_shared_experts=1, moe_top_k=2, norm_topk_prob=True,
+        routed_scaling_factor=2.5, attn=attn, mtp_layers=1, max_seq_len=64,
+        held_experts=(0, 4), dtype="float32"))
+    params = model.init(jax.random.key(0),
+                        {"input_ids": jnp.zeros((1, 8), jnp.int32)})
+    N, NP, PG = 2, 9, 8
+    fn = make_spec_block_fn(model, build_sample_fn(False, 1.0, 0, 1.0),
+                            None, 2, 4 * PG)
+    pool = model.init_paged_cache(NP, PG, dtype=jnp.float32)
+    pages = jnp.asarray([[3, 5, 2, 7], [1, 4, 0, 0]], jnp.int32)
+    state = dict(_slot_state(N), draft=jnp.asarray([5, 0], jnp.int32))
+    args = (params, pool, state, pages, jax.random.key(0))
+    return EntryPoint("serving.spec_block", fn, args, expect_donation=True)
+
+
 def serving_spec_draft_prefill():
     """The draft-side admission-prefill chunk program (the draft cache
     needs the prompt's K/V too): same body as the engine chunk program
@@ -327,13 +360,14 @@ def hybrid_rollout():
 BUILDERS = (runtime_train_step, runtime_apply_update, inference_decode,
             inference_prefill_chunk, serving_decode_step,
             serving_prefill_chunk, serving_admit, serving_spec_propose,
-            serving_spec_verify, serving_spec_draft_prefill,
-            serving_spec_draft_admit, hybrid_rollout)
+            serving_spec_verify, serving_spec_block,
+            serving_spec_draft_prefill, serving_spec_draft_admit,
+            hybrid_rollout)
 
 # builder function name -> the EntryPoint name it constructs.  Lets
 # name-filtered sweeps (``ds_lint --mem <program>``, the bench
 # memory_snapshot subset) skip the engine builds of filtered-out
-# programs instead of paying all 12 just to learn their names.  Kept
+# programs instead of paying all 13 just to learn their names.  Kept
 # honest mechanically: every consumer cross-checks ``ep.name`` against
 # this map after building, so drift fails loudly instead of silently
 # skipping the wrong program.
@@ -347,6 +381,7 @@ BUILDER_PROGRAMS = {
     "serving_admit": "serving.admit",
     "serving_spec_propose": "serving.spec_propose",
     "serving_spec_verify": "serving.spec_verify",
+    "serving_spec_block": "serving.spec_block",
     "serving_spec_draft_prefill": "serving.spec_draft_prefill",
     "serving_spec_draft_admit": "serving.spec_draft_admit",
     "hybrid_rollout": "hybrid.rollout",

@@ -41,6 +41,7 @@ _COVERED = {
     "serving.admit": "serving.admit",
     "serving.spec_propose": "serving.spec_propose",
     "serving.spec_verify": "serving.spec_verify",
+    "serving.spec_block": "serving.spec_block",
     "serving.spec_draft_prefill": "serving.spec_draft_prefill",
     "serving.spec_draft_admit": "serving.spec_draft_admit",
     "hybrid.rollout_generate": "hybrid.rollout",

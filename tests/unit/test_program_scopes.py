@@ -203,6 +203,26 @@ D = "jit(decode_block)/while/body/closed_call/"
      "loss", "fwd"),
     (D + "slots.state/select_n", "slots", "fwd"),
     ("jit(decode_block)/slots.expert_load/reduce_sum", "slots", "fwd"),
+    # a multi-token-prediction module's scopes: the deepest known frame
+    # decides, so its block's attention is attention and its experts experts
+    ("jit(spec_block)/while/body/closed_call/Glm5Model.draft/mtp.combine/"
+     "mtp.combine/eh_proj/dot_general", "mtp.combine", "fwd"),
+    ("jit(spec_block)/while/body/closed_call/Glm5Model.draft/mtp.combine/"
+     "mtp.combine/concatenate", "mtp.combine", "fwd"),
+    ("jit(spec_block)/while/body/closed_call/Glm5Model.draft/mtp.combine/"
+     "mtp.combine/hidden_norm/reduce_sum", "norm", "fwd"),
+    ("jit(spec_block)/while/body/closed_call/Glm5Model.draft/mtp.block/"
+     "Glm5Model._blocks/block/attn.step/attn._out/dot_general", "attn.proj",
+     "fwd"),
+    ("jit(spec_block)/while/body/closed_call/Glm5Model.draft/mtp.block/"
+     "Glm5Model._blocks/block/moe_mlp/moe_mlp._scored/moe.route/div",
+     "moe.route", "fwd"),
+    ("jit(spec_block)/while/body/closed_call/Glm5Model.draft/mtp.block/"
+     "Glm5Model._blocks/block/add", "residual", "fwd"),
+    ("jit(chunk_step)/Glm5Model.draft/mtp.head/head_norm/mul", "head",
+     "fwd"),
+    ("jit(chunk_step)/Glm5Model.draft/mtp.head/lm_head/dot_general", "head",
+     "fwd"),
     # nothing the table knows: unattributed
     ("jit(train_step)/mul", None, "fwd"),
     ("jit(train_step)/transpose(jvp(Transformer))/broadcast_in_dim", None,
@@ -220,7 +240,7 @@ def test_part_and_phase_of_an_op_name(op_name, part, phase):
 def test_the_table_is_a_fixed_literal_set_of_known_parts():
     assert {part for _, part in profiler.SCOPE_PARTS} <= set(profiler.PARTS)
     # never a size or an index in a scope name the programs add
-    for rx, _ in profiler.SCOPE_PARTS[:10]:
+    for rx, _ in profiler.SCOPE_PARTS[:13]:
         assert not any(ch.isdigit() for ch in rx)
 
 

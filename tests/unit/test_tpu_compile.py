@@ -453,6 +453,66 @@ def test_evabyte_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     assert 12.4e9 < total < 14e9, f"{total / 1e9:.2f} GB"
 
 
+@pytest.mark.parametrize("program", ["chunk_step", "spec_block"])
+def test_glm5_slot_programs_compile_at_the_cells_sizes(program, one_chip,
+                                                       mosaic):
+    """The two programs ``glm5-serve-reasongen-batch`` runs, whole, as
+    ``serving/slots.py`` builds them for a self-drafting model at the
+    cell's own settings: the chunk that fills the multi-token-prediction
+    module's rows beside the main model's, and the block of verify windows
+    of two rows a lane — the pools (six layers: five main, the module's)
+    aliased input -> output, 9.61 GB of weights + 2.76 GB of pools and the
+    programs' temporaries inside one chip."""
+    import os
+    from benchmark import spec
+    from deepspeed_tpu.inference.serving import slots
+    from deepspeed_tpu.inference.serving.paging import SlotPages
+    bench = spec.Benchmark(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    cell = bench.cell("glm5-serve-reasongen-batch")
+    module = bench.family("glm5").program_model(cell["config"])
+    s = cell["system"]["serving"]
+    chunk = slots.admission_chunk(module, s["prefill_chunk"])
+    pages = SlotPages(module, s["num_slots"], s["max_cache_len"],
+                      s["page_size"], 0, chunk, False, {})
+    assert (pages.pages_per_slot, pages.num_pages) == (73, 64 * 73 + 1)
+    on_chip = lambda tree, dtype=None: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                       sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(lambda: module.init(
+        jax.random.key(0), {"input_ids": jnp.zeros((1, 8), I32)})), BF16)
+    pool = on_chip(jax.eval_shape(lambda: pages.new_pools(BF16)))
+    assert {k: v.shape[0] for k, v in pool.items()} \
+        == {"latent": 6, "index": 6}
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+    if program == "chunk_step":
+        compiled = slots.make_chunk_fn(module, None, self_draft=True).lower(
+            params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
+            ints(1), ints(1)).compile()
+        calls = 6 * 3                   # index, top-k, flash a layer
+    else:
+        n = s["num_slots"]
+        state = on_chip({k: jnp.asarray(v) for k, v in
+                         slots.init_slot_state(n, draft=True).items()})
+        rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                   sharding=one_chip)
+        compiled = slots.make_spec_block_fn(
+            module, lambda logits, rng: jnp.argmax(logits, -1), None,
+            s["decode_block"], pages.cache_len).lower(
+                params, pool, state, ints(n, pages.table_width),
+                rng).compile()
+        # the expert kernel a routed layer; lane index, top-k, lane
+        # decode a pool layer
+        calls = 5 + 6 * 3
+    assert compiled.as_text().count("tpu_custom_call") >= calls
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * 2 for x in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 12.3e9 < total < 14.5e9, f"{total / 1e9:.2f} GB"
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
     fn, shapes = CASES[case]()

@@ -55,6 +55,8 @@ import numpy as np
 
 import jax
 
+from deepspeed_tpu.ops.transformer.paged_attention import _decode_block_pages
+
 TRASH_PAGE = 0
 
 
@@ -334,6 +336,7 @@ class SlotPages:
         self.state_rows = 1 + self.num_slots if self.state_kinds else 0
         self.state_row_bytes = 0         # known once the pools are made
         self.page_bytes = 0
+        self.fold_pages = 1              # (their dtype decides it)
         self.table_width = self.pages_per_slot + self.ring_pages \
             + bool(self.state_kinds)
         if (self.ring_pages or self.state_kinds or self.stride > 1) \
@@ -376,6 +379,8 @@ class SlotPages:
             kinds["state_rows"] = self.state_rows
         pools = self._module.init_paged_cache(self.num_pages, self.page,
                                               dtype=dtype, **kinds)
+        self.fold_pages = _decode_block_pages(
+            self.page, self.pages_per_slot, jax.numpy.dtype(dtype).itemsize)
         if self.state_kinds:
             nbytes = lambda keys: sum(pools[k].size * pools[k].dtype.itemsize
                                       for k in keys)
@@ -595,14 +600,18 @@ class SlotPages:
         span's args, from ``live`` — ``(context, steps)`` per live slot,
         the positions its first step attends and the steps it takes:
         ``kv_pages``, ``ceil(context / page_size)`` a live slot and
-        step, which is the paged-decode kernel's page loop, and
-        ``kv_pages_table``, the slots x pages-a-slot x steps a walk over
-        the whole table would take — their ratio is the share of the
-        table that is live."""
+        step, which is the paged-decode kernel's page loop;
+        ``kv_folds``, the online-softmax updates that loop makes of
+        them, ``ceil(pages / pages a block)`` a live slot and step by
+        the kernel's own rule (``kv_pages / kv_folds`` is the pages an
+        update really carried); and ``kv_pages_table``, the slots x
+        pages-a-slot x steps a walk over the whole table would take —
+        ``kv_pages`` over it is the share of the table that is live."""
         work = getattr(self._module, "block_work", None)
-        return {"kv_pages": sum(self._lane_pages(first + i)
-                                for first, steps in live
-                                for i in range(steps)),
+        pages = [self._lane_pages(first + i)
+                 for first, steps in live for i in range(steps)]
+        return {"kv_pages": sum(pages),
+                "kv_folds": sum(-(-n // self.fold_pages) for n in pages),
                 "kv_pages_table":
                     self.num_slots * self.pages_per_slot * block,
                 **(self._state_reach(live) if self.state_kinds else {}),

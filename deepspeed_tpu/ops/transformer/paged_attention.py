@@ -9,45 +9,65 @@ attention over it — one full gathered cache copy per layer per step.
 
 A paged cache is the monolithic split-K online-softmax computation of
 ``decode_attention.py`` under another address map: virtual page ``i`` of
-row ``b`` lives at pool page ``pages[b, i]``, ``block_k = page_size``, and
-the kernels' virtual position math (``pos = i*block_k + iota``, length
-masks, write row ``(length-1) % block_k``) transfers verbatim.  The
-per-block update — block-diagonal Q, cross-page max/sum merge, int8-KV
-dequant on the score/probability tiles, the fused write's column
-substitution — is the monolithic kernel's own (``_block_update``); what
-differs is who supplies the block sequence.
+row ``b`` lives at pool page ``pages[b, i]``, a block is a whole number of
+pages, and the kernels' virtual position math (``pos = i*block_k +
+iota``, length masks, write row ``(length-1) % block_k``) transfers
+verbatim.  The per-block update — block-diagonal Q, cross-block max/sum
+merge, int8-KV dequant on the score/probability tiles, the fused write's
+column substitution — is the monolithic kernel's own (``_block_update``);
+what differs is who supplies the block sequence.
 
-**Decode** (:func:`paged_decode_attention`) follows the LIVE pages of
-each row:
+**Decode** (:func:`paged_decode_attention`) is ONE software pipeline over
+the call's sequence of (live row, block of pages) pairs:
 
-* ``grid=(B,)`` — one grid step a slot.  The K/V pools stay whole in HBM
-  (``memory_space=pl.ANY``); inside the step a ``fori_loop`` over the
-  row's ``ceil(length / page_size)`` pages fetches page ``pages[b, i]`` of
-  the layer with ``make_async_copy`` into a three-deep VMEM ring (two
-  fetches in flight behind the page being folded in) and applies the
-  per-block update.  Grid steps, DMAs and arithmetic are all
-  O(live pages); the table's width costs nothing.  (The grid-per-page
-  form this replaces paid ~0.35 us for every (slot, virtual page) pair,
-  live or not — three fifths of both serving cells' device time, PERF.md
-  PR 26.)
+* ``grid=(1,)`` — the K/V pools stay whole in HBM
+  (``memory_space=pl.ANY``), every row's q, new K/V row and output sit in
+  VMEM, and a loop walks the LIVE rows.  A row's ``ceil(length /
+  page_size)`` pages are folded ``min(ceil(512 / page_size), pages a
+  slot)`` at a time (``_decode_block_pages``, a shape-derived constant):
+  each page of a block is fetched through the table with
+  ``make_async_copy`` into its rows of a double-buffered ``[pages *
+  page_size, KVH*D]`` VMEM block, all of a block's copies in flight at
+  once and block i+1's started before block i is folded in, one
+  per-block update a block.  DMAs and arithmetic are O(live pages); the
+  table's width costs nothing.  (The page-an-update form this replaces
+  paid a fetch, a wait and a whole online-softmax update — a half-vreg
+  score tile, matmuls with an N / a K of 64, a sub-vreg accumulator
+  update a KV head — for every 64-key page: 0.62 us a page where the
+  DMA of LFM2's 128 KiB page takes 0.16, PERF.md PR 41.)
+* **The pipeline outlives the row.**  While a row folds its LAST block,
+  the first block of the NEXT LIVE row is already on its way into the
+  other buffer, so no row begins with a wait for a fetch nobody had asked
+  for; only the call's first block is exposed.
+* The tail block is partly filled: rows of pages past the live ones keep
+  what an earlier block or an earlier row left (or nobody wrote), and
+  the last page's rows past ``length`` hold what the slot's previous
+  tenant wrote.  Scores there are masked by the update; the VALUE rows
+  at ``pos >= length`` are zeroed in the buffer before the matmul (a
+  probability of 0 against a NaN is a NaN).
 * A DEAD row — one whose table points at the reserved trash page
   (``pages[b, 0] == 0``; page 0 is never allocated), which is how the
   serving decode block presents free, retired and still-prefilling
-  lanes whatever their position counter says — walks no page: no
-  block-diagonal Q, no fetch, no arithmetic, a zero output row, and its
-  fused-write stripe goes to the trash page.  A dead row costs one empty
-  grid step.
-* The fused decode write targets the pool through the table: the
-  aliased output's 8-row write stripe pins to ``(layer,
-  pages[b, (len-1)//page], ((len-1)%page)//8, 0)`` and is merged from
-  the last live page, which the loop leaves in its buffer.
+  lanes whatever their position counter says — is skipped by the row
+  loop and by the hand-over: a table lookup, a zero output row, no
+  write.  (As a grid step of its own it cost 0.35 us: 8.4 of the chat
+  cell's 51 us a call.)
+* The fused decode write targets the pool through the table: the row's
+  8-row write stripe — ``(layer, pages[b, (len-1)//page], 8-row stripe
+  of (len-1)%page)`` — is merged from the last block's buffer (before
+  its tail is zeroed: the stripe's other rows keep the pool's bits),
+  staged in VMEM and sent to the aliased pool by ``make_async_copy``,
+  two stripes in flight.  A row's write page is private to its slot and
+  shared prefix pages are full and read-only, so a block fetched ahead
+  of a stripe write reads nothing that is being written.
 * int8 pools keep the grid walk over virtual pages (the monolithic
-  ``_decode_kernel`` body behind table index maps): a page of dequant
-  scales is ``[page_size, KVH]`` with ``KVH < 128`` lanes, which Mosaic
-  refuses to slice out of an HBM ref by hand ("slice shape must be
-  aligned to tiling (128)") — only its own BlockSpec pipeline can fetch
-  it.  Dead rows are presented to it with length 0, so they skip the
-  arithmetic (not the grid steps).
+  ``_decode_kernel`` body behind table index maps, ``grid=(B, pages a
+  slot)``, ``block_k = page_size``): a page of dequant scales is
+  ``[page_size, KVH]`` with ``KVH < 128`` lanes, which Mosaic refuses to
+  slice out of an HBM ref by hand ("slice shape must be aligned to
+  tiling (128)") — only its own BlockSpec pipeline can fetch it.  Dead
+  rows are presented to it with length 0, so they skip the arithmetic
+  (not the grid steps), and their stripes go to the trash page.
 
 **Chunked prefill** (:func:`paged_chunk_prefill_attention`) folds the
 REACHABLE pages of each row — those up to the chunk's furthest position,
@@ -93,16 +113,20 @@ REACHABLE pages of each row — those up to the chunk's furthest position,
 
 How far the live-page walks engage in serving is on the dispatch spans:
 ``dstpu.sched.dispatch.decode`` carries ``kv_pages`` (pages the block's
-steps walk) against ``kv_pages_table`` (slots x pages a slot x steps),
+steps walk) against ``kv_pages_table`` (slots x pages a slot x steps)
+and ``kv_folds`` (the per-block updates made of those pages:
+``kv_pages / kv_folds`` is the pages an update really carried),
 ``dstpu.sched.dispatch.prefill_chunk`` ``kv_pages`` (pages the chunk's
 layers fetch) against ``kv_pages_table`` (pages a slot x layers).
 
-Numerics: decode's page sequence and arithmetic per page are those of
-``decode_attention(block_k=page_size)`` over the gathered virtual view,
-so the two are BITWISE equal; chunked prefill's are those of
+Numerics: decode's block sequence and arithmetic per block are those of
+``decode_attention(block_k=pages-a-block * page_size)`` over the gathered
+virtual view (partial last block included), so the two are BITWISE
+equal; chunked prefill's are those of
 ``chunk_prefill_attention(block_k=pages-a-block * page_size)`` over the
-gathered view, bitwise again (an int8 pool: ``block_k=page_size``) —
-both regression-tested in tests/unit/test_paged_attention.py.  Greedy
+gathered view, bitwise again (an int8 pool, either kernel:
+``block_k=page_size``) — both regression-tested in
+tests/unit/test_paged_attention.py.  Greedy
 serving outputs stay equal to solo ``generate()`` token for token.
 """
 
@@ -123,15 +147,24 @@ from deepspeed_tpu.ops.transformer.decode_attention import (
     _write_stripe)
 from deepspeed_tpu.ops.transformer.flash_attention import LSE_LANES, _interpret
 
-# VMEM ring of the decode loop: the page being folded in plus two fetches
-# behind it.  Measured on v5e at the serving cells' shapes (PERF.md PR 26):
-# two buffers 132 us a layer-step, three 117, four 117.
+# VMEM ring of a decode loop that folds a page an update (``attn.eva_decode``
+# still does; this module's decode loop did until PR 41): the page being
+# folded in plus two fetches behind it.  Measured on v5e at the serving
+# cells' shapes (PERF.md PR 26): two buffers 132 us a layer-step, three 117,
+# four 117.
 _DECODE_PAGE_BUFFERS = 3
 
 # Keys one block of the chunk-prefill loop folds at once: the score tile is
 # [C, 512] float32 (whole vregs along lanes) and the two matmuls a head get
 # an N / a K of 512, where a 64-key page gave them 64.
 _CHUNK_BLOCK_KEYS = 512
+
+# Keys one block of the decode loop folds at once.  Measured on v5e, us a
+# call at the four serving cells' shapes (PERF.md PR 41; a page an update
+# and a row a grid step read 58 / 128 / 443 / 1771): 256 keys 43 / 119 /
+# 383 / 782, 512 keys 44 / 119 / 381 / 662 — pages of 512 lanes (the last)
+# are bound by the updates, not the bytes, and want them wide.
+_DECODE_BLOCK_KEYS = 512
 
 
 def _live_pages(lens, pages, b, page, nk):
@@ -143,78 +176,215 @@ def _live_pages(lens, pages, b, page, nk):
     return jnp.where(pages[b, 0] == 0, 0, n)
 
 
+def _decode_block_pages(page, nk, itemsize):
+    """Pages a block of the decode loop folds at once — the ONE rule, from
+    the shapes the call sees (the dispatch span's ``kv_folds`` reads it
+    too, ``serving/paging.py``): ~512 keys' worth, at most the table's
+    width — and one where a page is not whole sublane tiles of the
+    pool's dtype, so that it cannot land on its rows of a wider buffer."""
+    if page % (32 // itemsize):
+        return 1
+    return min(-(-_DECODE_BLOCK_KEYS // page), nk)
+
+
+def _page_rows(j, page, bp):
+    """Rows of a ``[bp * page, lanes]`` block buffer that page ``j`` of
+    the block lands on."""
+    if bp == 1:
+        return slice(None)
+    return pl.ds(pl.multiple_of(j * page, page), page)
+
+
+def _each_page(fn, pages_ref, li, row, first, count, slot, pools, bufs, sem,
+               *, page, bp):
+    """``fn`` over the async copies that bring virtual pages ``first ..
+    first + count`` of table row ``row`` through the table to rows
+    ``0.., page.., ..`` of block buffer ``slot`` — K and V of layer
+    ``li``, every copy of a buffer on that buffer's semaphore.  A wait
+    rebuilds the descriptors its start used."""
+    def one(j, carry):
+        pg = pages_ref[row, first + j]
+        rows = _page_rows(j, page, bp)
+        for n, (src, dst) in enumerate(zip(pools, bufs)):
+            fn(pltpu.make_async_copy(src.at[li, pg], dst.at[slot, rows],
+                                     sem.at[n, slot]))
+        return carry
+
+    jax.lax.fori_loop(0, count, one, None)
+
+
+def _zero_value_tail(vbuf, slot, base, limit, *, page, bp):
+    """Zero the rows of value block ``slot`` (row 0 at position
+    ``base``) at positions ``>= limit``.  Rows nobody attends — the last
+    page's tail and the pages of a partly filled block that were not
+    fetched — hold what the slot's previous tenant, an earlier block, an
+    earlier row or nobody wrote; the score side is masked by the update,
+    but a probability of 0 against a NaN is a NaN."""
+    def one(j, carry):
+        rows = _page_rows(j, page, bp)
+        pos = base + j * page + jax.lax.broadcasted_iota(
+            jnp.int32, (page, 1), 0)
+        v = vbuf[slot, rows]
+        vbuf[slot, rows] = jnp.where(pos < limit, v, jnp.zeros_like(v))
+        return carry
+
+    jax.lax.fori_loop(jnp.clip((limit - base) // page, 0, bp), bp, one, None)
+
+
+class _Row:
+    """Row ``r`` of a ``[B, ...]`` ref as the ``[1, ...]`` block the row
+    state reads and writes at ``[0]`` (Mosaic refuses the ref slice
+    where the row's lanes are not whole tiles; the indexed load and
+    store it takes)."""
+
+    def __init__(self, ref, r):
+        self.ref, self.r, self.dtype = ref, r, ref.dtype
+
+    def __getitem__(self, _):
+        return self.ref[self.r]
+
+    def __setitem__(self, _, value):
+        self.ref[self.r] = value
+
+
 def _paged_decode_kernel(len_ref, layer_ref, pages_ref, q_ref, k_hbm, v_hbm,
-                         *rest, scale, page, nk, kvh, g, d, fused_write):
-    """The page-loop driver: one grid step a batch row, the pools whole
-    in HBM.  A live row fetches its pages through the table into the
-    VMEM ring and folds each into the online-softmax state with the
-    monolithic kernel's per-block update; a dead row writes a zero
-    output row and does nothing else."""
-    kn_ref = vn_ref = ko_ref = vo_ref = None
+                         *rest, scale, page, nk, bp, nb, kvh, g, d,
+                         fused_write):
+    """The block-loop driver: ONE grid step a call, the pools whole in
+    HBM, and one software pipeline over the call's sequence of (live
+    row, block of pages) pairs.  A loop over the LIVE rows (a dead row
+    costs a table lookup); a row's pages are fetched through the table,
+    ``bp`` at a time, into the rows of a double-buffered ``[bp * page,
+    KVH*D]`` block and each block goes through the monolithic kernel's
+    per-block update; while a row folds its LAST block the first block
+    of the next live row is already on its way into the other buffer.
+    The fused write's 8-row stripes go out to the aliased pools by hand,
+    two in flight."""
+    kn_ref = vn_ref = None
     rest = list(rest)
     if fused_write:
         kn_ref, vn_ref = rest[:2]
         del rest[:2]
     o_ref = rest.pop(0)
     if fused_write:
-        ko_ref, vo_ref = rest[:2]
+        pools_out = rest[:2]
         del rest[:2]
-    m_scr, l_scr, acc_scr, qbd_scr, kbuf, vbuf, sem = rest
-    st = _RowState(q_ref, m_scr, l_scr, acc_scr, qbd_scr,
-                   kn_ref=kn_ref, vn_ref=vn_ref)
-    nbuf = _DECODE_PAGE_BUFFERS
-    b = pl.program_id(0)
+    m_scr, l_scr, acc_scr, qbd_scr, kbuf, vbuf, sem = rest[:7]
+    if fused_write:
+        kstage, vstage, wsem = rest[7:]
     li = layer_ref[0]
-    length = len_ref[b]
-    n_pages = _live_pages(len_ref, pages_ref, b, page, nk)
+    bk = bp * page
 
-    def copies(i):
-        # virtual page i of this row → ring slot i % nbuf; a wait
-        # rebuilds the descriptors its start used
-        pg, slot = pages_ref[b, i], i % nbuf
-        return [pltpu.make_async_copy(src.at[li, pg], dst.at[slot],
-                                      sem.at[j, slot])
-                for j, (src, dst) in enumerate([(k_hbm, kbuf),
-                                                (v_hbm, vbuf)])]
+    def live_pages(r):
+        return _live_pages(len_ref, pages_ref, jnp.minimum(r, nb - 1), page,
+                           nk)
 
-    def start(i):
-        @pl.when(i < n_pages)
-        def _():
-            for c in copies(i):
-                c.start()
+    def next_live(r):
+        # the first live row from ``r`` on, ``nb`` where there is none
+        return jax.lax.while_loop(
+            lambda r: jnp.logical_and(r < nb, live_pages(r) == 0),
+            lambda r: r + 1, r)
 
-    @pl.when(n_pages == 0)
-    def _dead():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def each_page(fn, row, first, count, slot):
+        _each_page(fn, pages_ref, li, row, first, count, slot,
+                   (k_hbm, v_hbm), (kbuf, vbuf), sem, page=page, bp=bp)
 
-    @pl.when(n_pages > 0)
-    def _live():
+    def start(cp):
+        cp.start()
+
+    def wait(cp):
+        cp.wait()
+
+    def each_stripe(fn, stage, wpage=0, base=0):
+        # staged stripe ``stage`` → rows ``base .. base + 8`` of pool page
+        # ``wpage``; a wait needs the descriptors' shapes alone
+        for n, (src, dst) in enumerate(zip((kstage, vstage), pools_out)):
+            fn(pltpu.make_async_copy(src.at[stage],
+                                     dst.at[li, wpage, pl.ds(base, 8)],
+                                     wsem.at[n, stage]))
+
+    def one_row(carry):
+        # ``slot0``: the buffer this row's first block is on its way
+        # into; ``done``: live rows before this one
+        r, slot0, done = carry
+        length = len_ref[r]
+        n_pages = live_pages(r)
+        n_blocks = (n_pages + bp - 1) // bp
+        nxt = next_live(r + 1)
+        nxt_pages = jnp.where(nxt < nb, jnp.minimum(live_pages(nxt), bp), 0)
+        st = _RowState(_Row(q_ref, r), m_scr, l_scr, acc_scr, qbd_scr,
+                       kn_ref=_Row(kn_ref, r) if fused_write else None,
+                       vn_ref=_Row(vn_ref, r) if fused_write else None)
         _init_row(st, kvh=kvh, g=g, d=d)
-        for i in range(nbuf - 1):
-            start(i)
 
         def fold(i, carry):
-            start(i + nbuf - 1)
-            for c in copies(i):
-                c.wait()
-            _block_update(st, i, length, kbuf[i % nbuf], vbuf[i % nbuf],
-                          None, None, scale=scale, block_k=page, kvh=kvh,
-                          g=g, d=d, window=None)
+            slot = (slot0 + i) % 2
+            last = i == n_blocks - 1
+            # what follows block i in the call's sequence — this row's
+            # block i + 1, or the next live row's first — starts into
+            # the buffer block i - 1 (or the row before) has been folded
+            # out of.  A row's write page is private to its slot and
+            # shared prefix pages are full and read-only, so a fetch
+            # ahead of this row's stripe write reads nothing that is
+            # being written.
+            each_page(start, jnp.where(last, jnp.minimum(nxt, nb - 1), r),
+                      jnp.where(last, 0, (i + 1) * bp),
+                      jnp.where(last, nxt_pages,
+                                jnp.minimum(n_pages - (i + 1) * bp, bp)),
+                      1 - slot)
+            each_page(wait, r, i * bp, jnp.minimum(n_pages - i * bp, bp),
+                      slot)
+
+            @pl.when(last)
+            def _tail():
+                if fused_write:
+                    # the write row lies in the last live page: rows of
+                    # THIS buffer, read before the tail below is zeroed
+                    # so that the stripe's other rows keep the pool's
+                    # bits.  The stripe is staged and sent on its way;
+                    # its stage is free once the stripe two rows back
+                    # has landed.
+                    stage = done % 2
+
+                    @pl.when(done >= 2)
+                    def _():
+                        each_stripe(wait, stage)
+
+                    def load8(base):
+                        rows = pl.ds(base, 8)
+                        return kbuf[slot, rows], vbuf[slot, rows], None, None
+
+                    _write_stripe(st, length, bk, load8, kstage.at[stage],
+                                  vstage.at[stage], None, None, kvh=kvh, d=d)
+                    pos = jnp.maximum(length - 1, 0)
+                    each_stripe(
+                        start, stage,
+                        pages_ref[r, jnp.minimum(pos // page, nk - 1)],
+                        pl.multiple_of((pos % page) // 8 * 8, 8))
+                _zero_value_tail(vbuf, slot, i * bk, length, page=page,
+                                 bp=bp)
+
+            _block_update(st, i, length, kbuf[slot], vbuf[slot], None, None,
+                          scale=scale, block_k=bk, kvh=kvh, g=g, d=d,
+                          window=None)
             return carry
 
-        jax.lax.fori_loop(0, n_pages, fold, None)
-        _finish_row(st, o_ref)
-        if fused_write:
-            # the write row lies in the LAST live page, which the loop
-            # left in the ring
-            last = (n_pages - 1) % nbuf
+        jax.lax.fori_loop(0, n_blocks, fold, None)
+        _finish_row(st, _Row(o_ref, r))
+        return nxt, (slot0 + n_blocks) % 2, done + 1
 
-            def load8(base):
-                rows = pl.dslice(base, 8)
-                return kbuf[last, rows], vbuf[last, rows], None, None
-
-            _write_stripe(st, length, page, load8, ko_ref.at[0, 0],
-                          vo_ref.at[0, 0], None, None, kvh=kvh, d=d)
+    o_ref[...] = jnp.zeros_like(o_ref)      # dead rows return zeros
+    first = next_live(0)
+    each_page(start, jnp.minimum(first, nb - 1), 0,
+              jnp.where(first < nb, jnp.minimum(live_pages(first), bp), 0),
+              0)
+    _, _, done = jax.lax.while_loop(lambda c: c[0] < nb, one_row,
+                                    (first, 0, 0))
+    if fused_write:
+        for back in (1, 2):                 # the stripes still in flight
+            @pl.when(done >= back)
+            def _():
+                each_stripe(wait, (done - back) % 2)
 
 
 def _paged_grid_decode_body(len_ref, layer_ref, pages_ref, *args, **kw):
@@ -262,19 +432,12 @@ def _paged_chunk_kernel(start_ref, layer_ref, pages_ref, q_ref, k_hbm, v_hbm,
     n_under = jnp.minimum((start + 1) // bk, n_blocks)
 
     def each_page(i, fn):
-        # virtual page i*bp + j of this row → rows j*page.. of buffer
-        # i % 2; a wait rebuilds the descriptors its start used.  Pages
-        # past the reachable ones are not fetched: the tail block's rows
-        # there keep what an earlier block left.
-        def one(j, carry):
-            pg, slot = pages_ref[b, i * bp + j], i % 2
-            rows = pl.ds(pl.multiple_of(j * page, page), page)
-            for n, (src, dst) in enumerate([(k_hbm, kbuf), (v_hbm, vbuf)]):
-                fn(pltpu.make_async_copy(src.at[li, pg], dst.at[slot, rows],
-                                         sem.at[n, slot]))
-            return carry
-
-        jax.lax.fori_loop(0, jnp.clip(n_pages - i * bp, 0, bp), one, None)
+        # block i of this row → buffer i % 2.  Pages past the reachable
+        # ones are not fetched: the tail block's rows there keep what an
+        # earlier block left.
+        _each_page(fn, pages_ref, li, b, i * bp,
+                   jnp.clip(n_pages - i * bp, 0, bp), i % 2, (k_hbm, v_hbm),
+                   (kbuf, vbuf), sem, page=page, bp=bp)
 
     def fold(masked):
         def body(i, carry):
@@ -282,17 +445,12 @@ def _paged_chunk_kernel(start_ref, layer_ref, pages_ref, q_ref, k_hbm, v_hbm,
             each_page(i, lambda cp: cp.wait())
             slot = i % 2
             if masked:
-                # rows no query reaches — the last page's tail and the
-                # tail block's unfetched pages — may hold anything, and
-                # a probability of 0 against a NaN is a NaN: the score
-                # side is masked by the update, the value side here
+                # rows no query reaches: the score side is masked by the
+                # update, the value side here
                 @pl.when((i + 1) * bk > limit)
                 def _zero_tail():
-                    pos = i * bk + jax.lax.broadcasted_iota(
-                        jnp.int32, (bk, 1), 0)
-                    v = vbuf[slot]
-                    vbuf[slot] = jnp.where(pos < limit, v,
-                                           jnp.zeros_like(v))
+                    _zero_value_tail(vbuf, slot, i * bk, limit, page=page,
+                                     bp=bp)
             _chunk_block_update(st, i, start, kbuf.at[slot], vbuf.at[slot],
                                 None, None, scale=scale, block_k=bk, c=c,
                                 kvh=kvh, g=g, d=d, masked=masked)
@@ -333,8 +491,8 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
 
     A row whose table points at the reserved trash page
     (``pages[b, 0] == 0``) is DEAD whatever its length says: it reads no
-    page, its output row is zeros, and (fused write) its stripe lands in
-    the trash page.  Live rows cost O(ceil(length / page_size)) pages —
+    page, its output row is zeros, and (fused write) it writes no
+    allocated page.  Live rows cost O(ceil(length / page_size)) pages —
     see the module docstring for the two drivers.
 
     ``k_scale``/``v_scale`` ([L, num_pages, page_size, KVH]) switch the
@@ -381,32 +539,37 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
     pages_arr = jnp.asarray(pages, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
 
-    # index maps: (grid indices..., lengths, layer, pages); the row is
-    # always the first grid index
-    def row(b, *refs):
-        return (b, 0, 0)
-
-    def stripe(b, *refs):
-        # table-resolved write stripe: virtual write position lens[b]-1
-        # lands on pool page pages[b, (lens[b]-1)//page] at in-page row
-        # (lens[b]-1) % page; the output block covers only that row's
-        # 8-sublane-aligned stripe (index in 8-row units), constant per
-        # batch row, so Mosaic flushes 8 rows once after the row's last
-        # grid step — same stripe economics as the monolithic fused
-        # write.  A dead row's stripe goes to the trash page.
-        lens, li, pg = refs[-3:]
-        pos = jnp.maximum(lens[b] - 1, 0)
-        wpage = jnp.where(_live_pages(lens, pg, b, page, nk) == 0, 0,
-                          pg[b, jnp.minimum(pos // page, nk - 1)])
-        return (li[0], wpage, (pos % page) // 8, 0)
-
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     if quant:
-        # the grid walk: virtual pages past the live region pin to the
-        # LAST live page, so its physical index repeats across the dead
-        # tail and Mosaic elides the DMA (compute is pl.when-gated off
-        # in the body); dead rows get length 0, which gates every page
+        # the grid walk, a row a grid step.  Index maps: (grid
+        # indices..., lengths, layer, pages).  Virtual pages past the
+        # live region pin to the LAST live page, so its physical index
+        # repeats across the dead tail and Mosaic elides the DMA (compute
+        # is pl.when-gated off in the body); dead rows get length 0,
+        # which gates every page
         lengths = jnp.where(pages_arr[:, 0] == 0, 0, lengths)
         grid = (B, nk)
+
+        def rows(*tail):
+            return pl.BlockSpec((1,) + tail, lambda b, *refs: (b, 0, 0))
+
+        def stripe(b, *refs):
+            # table-resolved write stripe: virtual write position
+            # lens[b]-1 lands on pool page pages[b, (lens[b]-1)//page] at
+            # in-page row (lens[b]-1) % page; the output block covers
+            # only that row's 8-sublane-aligned stripe (index in 8-row
+            # units), constant per batch row, so Mosaic flushes 8 rows
+            # once after the row's last grid step — same stripe economics
+            # as the monolithic fused write.  A dead row's stripe goes to
+            # the trash page.
+            lens, li, pg = refs[-3:]
+            pos = jnp.maximum(lens[b] - 1, 0)
+            wpage = jnp.where(_live_pages(lens, pg, b, page, nk) == 0, 0,
+                              pg[b, jnp.minimum(pos // page, nk - 1)])
+            return (li[0], wpage, (pos % page) // 8, 0)
+
+        def write_spec(lanes):
+            return pl.BlockSpec((1, 1, 8, lanes), stripe)
 
         def kv(b, ik, lens, li, pg):
             last = jnp.maximum(_live_pages(lens, pg, b, page, nk) - 1, 0)
@@ -414,40 +577,56 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
 
         kv_spec = pl.BlockSpec((1, 1, page, KVHD), kv)
         sc_spec = pl.BlockSpec((1, 1, page, KVH), kv)
-        in_specs = [pl.BlockSpec((1, H, D), row), kv_spec, kv_spec,
-                    sc_spec, sc_spec]
+        in_specs = [rows(H, D), kv_spec, kv_spec, sc_spec, sc_spec]
         operands = [q, k_pool, v_pool, k_scale, v_scale]
         kernel = functools.partial(
             _paged_grid_decode_body, scale=float(scale), block_k=page,
             nk=nk, kvh=KVH, g=G, d=D, stacked=True, quant=True,
             window=None, mxu_int8=mxu_int8, fused_write=fused_write)
-        ring = []
+        loop_scratch = []
+        block_bytes = page * KVHD * q.dtype.itemsize
     else:
-        grid = (B,)
-        pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-        in_specs = [pl.BlockSpec((1, H, D), row), pool_spec, pool_spec]
+        # the block loop: ONE grid step, every row's q / new rows / output
+        # in VMEM, the pools whole in HBM on both sides (the kernel sends
+        # the write stripes itself)
+        grid = (1,)
+        bp = _decode_block_pages(page, nk, k_pool.dtype.itemsize)
+
+        def rows(*tail):
+            return pl.BlockSpec((B,) + tail, lambda i, *refs: (0, 0, 0))
+
+        def write_spec(lanes):
+            return pool_spec
+
+        in_specs = [rows(H, D), pool_spec, pool_spec]
         operands = [q, k_pool, v_pool]
         kernel = functools.partial(
             _paged_decode_kernel, scale=float(scale), page=page, nk=nk,
-            kvh=KVH, g=G, d=D, fused_write=fused_write)
-        ring = [pltpu.VMEM((_DECODE_PAGE_BUFFERS, page, KVHD), k_pool.dtype),
-                pltpu.VMEM((_DECODE_PAGE_BUFFERS, page, KVHD), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, _DECODE_PAGE_BUFFERS))]
+            bp=bp, nb=B, kvh=KVH, g=G, d=D, fused_write=fused_write)
+        # two K and two V blocks; with the fused write, two staged
+        # stripes a pool
+        loop_scratch = [pltpu.VMEM((2, bp * page, KVHD), k_pool.dtype),
+                        pltpu.VMEM((2, bp * page, KVHD), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2))]
+        if fused_write:
+            loop_scratch += [pltpu.VMEM((2, 8, KVHD), k_pool.dtype),
+                             pltpu.VMEM((2, 8, KVHD), v_pool.dtype),
+                             pltpu.SemaphoreType.DMA((2, 2))]
+        block_bytes = bp * page * KVHD * k_pool.dtype.itemsize
 
-    out_specs = [pl.BlockSpec((1, H, D), row)]
+    out_specs = [rows(H, D)]
     out_shape = [jax.ShapeDtypeStruct((B, H, D), q.dtype)]
     io_aliases = {}
     if fused_write:
-        nspec = pl.BlockSpec((1, KVH, D), row)
-        in_specs += [nspec, nspec]
+        in_specs += [rows(KVH, D)] * 2
         operands += [new_k, new_v]
-        out_specs += [pl.BlockSpec((1, 1, 8, KVHD), stripe)] * 2
+        out_specs += [write_spec(KVHD)] * 2
         out_shape += [jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                       jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)]
         # operand indices INCLUDE the three scalar-prefetch args
         io_aliases = {4: 1, 5: 2}
         if quant:
-            out_specs += [pl.BlockSpec((1, 1, 8, KVH), stripe)] * 2
+            out_specs += [write_spec(KVH)] * 2
             out_shape += [jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
                           jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype)]
             io_aliases = {4: 1, 5: 2, 6: 3, 7: 4}
@@ -466,16 +645,15 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
                 pltpu.VMEM((H, KVHD),
                            jnp.int8 if mxu_int8 else q.dtype),
             ] + ([pltpu.VMEM((H, LSE_LANES), jnp.float32)]
-                 if mxu_int8 else []) + ring),
+                 if mxu_int8 else []) + loop_scratch),
         out_shape=out_shape if fused_write else out_shape[0],
         input_output_aliases=io_aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")[:len(grid)],
-            # pages are small (<= a monolithic block_k) — the monolithic
+            # blocks are small (<= a monolithic block_k) — the monolithic
             # slab-sized floor is comfortably enough headroom
-            vmem_limit_bytes=max(
-                96 * 1024 * 1024,
-                6 * page * KVHD * q.dtype.itemsize + 16 * 1024 * 1024)),
+            vmem_limit_bytes=max(96 * 1024 * 1024,
+                                 6 * block_bytes + 16 * 1024 * 1024)),
         interpret=_interpret(),
         name="attn.paged_decode",
     )(lengths, layer_arr, pages_arr, *operands)

@@ -3,11 +3,11 @@ and the attention-kernel registry (``ops/transformer/registry.py``).
 
 The kernel contract: paged decode/chunk-prefill over the page pool is
 BITWISE equal to the ``take_along_axis`` gather reference — the gathered
-virtual view fed to the monolithic kernel at ``block_k = page_size``,
-which walks the identical online-softmax block sequence — across page
-sizes {16, 64, 128}, fp32, bf16 and int8-KV pools, ragged batches with
-dead rows between live ones, and NaN-poisoned pages outside the live
-regions.  (Serving-level mid-stream EOS / slot-churn / greedy-bitwise
+virtual view fed to the monolithic kernel at ``block_k = pages-a-block *
+page_size`` (an int8 pool: ``block_k = page_size``), which walks the
+identical online-softmax block sequence — across page sizes {16, 64,
+128}, fp32, bf16 and int8-KV pools, ragged batches with dead rows
+between live ones, and NaN-poisoned pages outside the live regions.  (Serving-level mid-stream EOS / slot-churn / greedy-bitwise
 coverage rides ``test_serving_paged.py``, which now exercises these
 kernels end to end.)  The registry contract: one static dispatch table,
 probed identically by the traced programs and the host-side attribution,
@@ -24,8 +24,9 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.ops.transformer.decode_attention import (
     chunk_prefill_attention, decode_attention)
+from deepspeed_tpu.ops.transformer import paged_attention as paged_mod
 from deepspeed_tpu.ops.transformer.paged_attention import (
-    _chunk_block_pages, paged_chunk_prefill_attention,
+    _chunk_block_pages, _decode_block_pages, paged_chunk_prefill_attention,
     paged_decode_attention)
 from deepspeed_tpu.ops.transformer.registry import (
     MAX_CHUNK_S, kernel_modes, select_kernel)
@@ -65,6 +66,16 @@ def _gather(buf, pages, nvirt):
                                      buf.shape[-1])
 
 
+def _decode_block_k(pool, nvirt, int8=False):
+    """Keys one online-softmax update of paged decode folds: the block
+    loop's pages a block (an int8 pool keeps the grid walk, a page a
+    step)."""
+    page = pool.shape[2]
+    if int8:
+        return page
+    return page * _decode_block_pages(page, nvirt, pool.dtype.itemsize)
+
+
 @pytest.mark.parametrize("page", [16, 64, 128])
 @pytest.mark.parametrize("int8", [False, True], ids=["fp32", "int8"])
 def test_paged_decode_bitwise_vs_gather(page, int8):
@@ -73,7 +84,7 @@ def test_paged_decode_bitwise_vs_gather(page, int8):
     q, k, v, ks, vs, pages, lengths, nvirt = _pool_fixture(page, int8=int8)
     ref = decode_attention(
         q, _gather(k, pages, nvirt), _gather(v, pages, nvirt), lengths,
-        block_k=page,
+        block_k=_decode_block_k(k, nvirt, int8),
         k_scale=None if ks is None else _gather(ks, pages, nvirt),
         v_scale=None if vs is None else _gather(vs, pages, nvirt))
     out = paged_decode_attention(q, k, v, lengths, pages, layer=LAYER,
@@ -125,9 +136,10 @@ def _ragged_fixture(page, variant, *, seed=0):
     ("int8_mxu", False)])
 def test_paged_decode_ragged_bitwise_vs_gather(variant, fused):
     """Every variant of paged decode, over a ragged batch with dead rows
-    between live ones, is BITWISE the monolithic kernel at ``block_k =
-    page`` over the gathered view — outputs, and with the fused write
-    the row each live slot writes; dead rows return exactly zero."""
+    between live ones, is BITWISE the monolithic kernel at the same
+    ``block_k`` over the gathered view — outputs, and with the fused
+    write the row each live slot writes; dead rows return exactly
+    zero."""
     page = 16
     (q, k, v, ks, vs, pages, lengths, new_k, new_v, nvirt, live,
      _) = _ragged_fixture(page, variant)
@@ -136,7 +148,8 @@ def test_paged_decode_ragged_bitwise_vs_gather(variant, fused):
     g = lambda buf: None if buf is None else _gather(buf, pages, nvirt)
     if fused:
         kw.update(new_k=new_k, new_v=new_v)
-    ref = decode_attention(q, g(k), g(v), lengths, block_k=page,
+    ref = decode_attention(q, g(k), g(v), lengths,
+                           block_k=_decode_block_k(k, nvirt, quant),
                            k_scale=g(ks), v_scale=g(vs), **kw)
     out = paged_decode_attention(q, k, v, lengths, pages, layer=LAYER,
                                  k_scale=ks, v_scale=vs, **kw)
@@ -206,6 +219,98 @@ def test_paged_decode_reads_and_writes_live_pages_only(variant):
         np.testing.assert_array_equal(before[:, 1:], after[:, 1:])
 
 
+# The block loop's cases, at a page of 16 and blocks of TWO pages (the
+# rule's keys a block shrunk to 32 for the test): per row its length, 0 a
+# DEAD row (trash table, a plausible length all the same).
+_PIPELINE_CASES = {
+    # 3, 5 and 2 pages: blocks of 2 + 1, 2 + 2 + 1 and 2
+    "pages_not_a_multiple_of_the_block": [3 * 16 - 2, 5 * 16, 16 + 1],
+    "rows_shorter_than_one_block": [7, 16, 1],
+    # the hand-over skips the dead rows between live ones
+    "dead_rows_between_live_ones": [40, 0, 0, 70, 0, 5],
+    # nothing to hand over to: dead rows end the call
+    "last_live_row_then_dead_rows": [0, 33, 80, 0, 0],
+    # row 0's last block (pages 2, 3) leaves its NaN tail in buffer 1;
+    # row 1's second block lands ONE page there, under that tail
+    "nan_tail_of_an_earlier_row": [3 * 16 + 5, 2 * 16 + 9, 16 + 3],
+}
+
+
+@pytest.mark.parametrize("lanes", [512, 2048])
+@pytest.mark.parametrize("case", list(_PIPELINE_CASES))
+def test_paged_decode_block_pipeline(case, lanes, monkeypatch):
+    """The decode kernel as ONE pipeline over (live row, block of pages)
+    pairs: whatever the rows' lengths, the dead rows between them and
+    what an earlier row left in the block buffers, the outputs are
+    BITWISE the monolithic kernel's at ``block_k = pages-a-block * page``
+    over the gathered view, fused write or not; dead rows return zeros;
+    and after the fused write the pools equal the unfused
+    write-then-read's — each live row's write row holds its fresh K/V,
+    every other row of every allocated page keeps its bits (dead rows'
+    stripes land in the trash page), although a row's successor was
+    fetched before its stripe was flushed.  Lanes of 512 are LFM2's
+    grouped KV heads (32 query heads over 8), 2048 OPT's."""
+    monkeypatch.setattr(paged_mod, "_DECODE_BLOCK_KEYS", 32)
+    page, nvirt, d, h = 16, 5, 64, 32
+    kvh = lanes // d
+    bp = _decode_block_pages(page, nvirt, 2)
+    assert bp == 2
+    lengths = np.asarray(_PIPELINE_CASES[case], np.int32)
+    live = np.flatnonzero(lengths)
+    nb = len(lengths)
+    rng = np.random.RandomState(len(case) + lanes)
+    P = nb * nvirt + 1
+    pages = np.zeros((nb, nvirt), np.int32)
+    free = list(rng.permutation(np.arange(1, P)))
+    for b in live:
+        pages[b] = [free.pop() for _ in range(nvirt)]
+    lengths = np.where(lengths == 0, 37, lengths)       # dead by the TABLE
+    shape = (L, P, page, lanes)
+    k = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+    q = jnp.asarray(rng.randn(nb, h, d), jnp.bfloat16)
+    new_k = jnp.asarray(rng.randn(nb, kvh, d), jnp.bfloat16)
+    new_v = jnp.asarray(rng.randn(nb, kvh, d), jnp.bfloat16)
+
+    # poison what the walk must not read or must not let through: the
+    # trash page, every page past a row's live ones and — stricter than
+    # the gather reference, which lets a stale NaN of the last page
+    # through as 0 x NaN — every position past a live row's length
+    bad = np.ones((P, page), bool)
+    for b in live:
+        n = int(lengths[b])
+        for i in range(-(-n // page)):
+            bad[pages[b, i]] = np.arange(page) + i * page >= n
+    poison = lambda buf: jnp.where(jnp.asarray(bad)[None, :, :, None],
+                                   jnp.asarray(np.nan, buf.dtype), buf)
+    pk, pv = poison(k), poison(v)
+
+    def view(buf):                          # gathered, padded to whole blocks
+        g = _gather(buf, jnp.asarray(pages), nvirt)
+        return jnp.pad(g, ((0, 0), (0, -nvirt % bp * page), (0, 0)))
+
+    dead = np.setdiff1d(np.arange(nb), live)
+    pos = lengths - 1
+    phys, off = pages[live, pos[live] // page], pos[live] % page
+    for fused in (False, True):
+        kw = dict(new_k=new_k, new_v=new_v) if fused else {}
+        ref = decode_attention(q, view(k), view(v), lengths,
+                               block_k=bp * page, **kw)
+        got = paged_decode_attention(q, pk, pv, lengths, jnp.asarray(pages),
+                                     layer=LAYER, **kw)
+        if fused:
+            ref, got, pools = ref[0], got[0], got[1:]
+        out = np.asarray(got, np.float32)
+        np.testing.assert_array_equal(np.asarray(ref, np.float32)[live],
+                                      out[live])
+        assert not out[dead].any()
+    for before, fresh, after in zip((pk, pv), (new_k, new_v), pools):
+        want = before.at[LAYER, phys, off].set(
+            fresh[live].reshape(len(live), lanes))
+        np.testing.assert_array_equal(np.asarray(want, np.float32)[:, 1:],
+                                      np.asarray(after, np.float32)[:, 1:])
+
+
 def test_paged_decode_fused_write_pool_contents():
     """The fused aliased write: the step's K/V row lands BITWISE at the
     table-resolved (page, offset), every untouched pool page is bitwise
@@ -225,7 +330,8 @@ def test_paged_decode_fused_write_pool_contents():
     kw = k.at[LAYER, phys, off].set(new_k.reshape(B, KVHD))
     vw = v.at[LAYER, phys, off].set(new_v.reshape(B, KVHD))
     ref = decode_attention(q, _gather(kw, pages, nvirt),
-                           _gather(vw, pages, nvirt), lengths, block_k=page)
+                           _gather(vw, pages, nvirt), lengths,
+                           block_k=_decode_block_k(k, nvirt))
     out, ko, vo = paged_decode_attention(q, k, v, lengths, pages,
                                          layer=LAYER, new_k=new_k,
                                          new_v=new_v)

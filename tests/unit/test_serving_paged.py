@@ -140,6 +140,10 @@ def test_paged_decode_span_counts_live_pages(paged_engine, tmp_path):
     # the step that makes a request's token i attends prompt + i positions
     want = sum(-(-(p + i) // page) for p, n in plan for i in range(1, n))
     assert sum(a["kv_pages"] for a in decodes) == want
+    # a 4-page lane is one block of the kernel's loop: a fold a slot-step
+    assert srv._pages.fold_pages == 4
+    assert sum(a["kv_folds"] for a in decodes) \
+        == sum(n - 1 for _, n in plan)
     table = srv.num_slots * srv.pages_per_slot * srv.block
     assert {a["kv_pages_table"] for a in decodes} == {table}
     assert all(0 <= a["kv_pages"] <= table for a in decodes)
@@ -148,6 +152,54 @@ def test_paged_decode_span_counts_live_pages(paged_engine, tmp_path):
     assert any(a["kv_pages"] < a["live_slots"] * srv.block for a in decodes)
     assert sum(a["kv_positions"] for a in decodes) \
         == sum(p + i for p, n in plan for i in range(1, n))
+
+
+def test_decode_span_kv_folds_is_the_kernels_own_fold_count(monkeypatch):
+    """``kv_folds`` beside ``kv_pages`` on the decode dispatch span is
+    the online-softmax updates the paged-decode kernel makes of those
+    pages — ``ceil(pages / pages a block)`` a live slot and step, the
+    pages a block read from the kernel's own rule.  A hand-made set of
+    live slots over a 40-page lane (blocks of 32 pages at a page of
+    16): contexts that stay inside one block, cross into the second
+    between two steps, and fill the lane; the KERNEL, run a step at a
+    time over the same contexts with its per-block update counted,
+    makes exactly that many."""
+    from deepspeed_tpu.ops.transformer import paged_attention as paged_mod
+    model = Transformer(tiny_cfg(max_seq_len=640))
+    sp = SlotPages(model, 5, cache_len=640, page_size=16, num_pages=0,
+                   chunk=64, share_prefixes=False, stats={})
+    pools = sp.new_pools(jnp.float32)
+    assert sp.fold_pages == paged_mod._decode_block_pages(16, 40, 4) == 32
+    # (context the first step attends, steps); slot 3 is dead
+    live = {0: (500, 2), 1: (511, 3), 2: (5, 1), 4: (639, 2)}
+    reach = sp.block_reach(list(live.values()), 4)
+    assert reach["kv_pages"] == 32 + 32 + 32 + 32 + 33 + 1 + 40 + 40
+    assert reach["kv_folds"] == 1 + 1 + 1 + 1 + 2 + 1 + 2 + 2
+    assert reach["kv_pages_table"] == 5 * 40 * 4
+
+    folds = []
+    update = paged_mod._block_update
+
+    def counted(*args, **kw):
+        jax.debug.callback(lambda: folds.append(1))
+        return update(*args, **kw)
+
+    monkeypatch.setattr(paged_mod, "_block_update", counted)
+    table = np.zeros((5, 40), np.int32)
+    for slot in live:
+        table[slot] = 1 + slot * 40 + np.arange(40)
+    q = jnp.ones((5, 4, 16), jnp.float32)
+    for step in range(4):
+        rows = {s: first + step for s, (first, steps) in live.items()
+                if step < steps}
+        lengths = np.full((5,), 77, np.int32)           # dead by the table
+        lengths[list(rows)] = list(rows.values())
+        paged_mod.paged_decode_attention(
+            q, pools["k"], pools["v"], jnp.asarray(lengths),
+            jnp.asarray(np.where(np.isin(np.arange(5), list(rows))[:, None],
+                                 table, 0)), layer=0)
+    jax.effects_barrier()
+    assert len(folds) == reach["kv_folds"]
 
 
 def test_paged_prefill_chunk_span_counts_reachable_pages(paged_engine,

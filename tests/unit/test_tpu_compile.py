@@ -80,13 +80,15 @@ def _flash_fwd_bwd(batch=2, seq=2048, heads=H, head_dim=D):
 
 
 def _paged_decode(quant, page=64, slots=8, cache_len=512, layers=L,
-                  kv_heads=H):
+                  kv_heads=H, heads=H, head_dim=D):
     pages_per_slot = cache_len // page
     n_pages = slots * pages_per_slot + 1
-    pool = ((layers, n_pages, page, kv_heads * D), I8 if quant else BF16)
-    args = [((slots, H, D), BF16), pool, pool, ((slots,), I32),
-            ((slots, pages_per_slot), I32), ((slots, kv_heads, D), BF16),
-            ((slots, kv_heads, D), BF16)]
+    pool = ((layers, n_pages, page, kv_heads * head_dim),
+            I8 if quant else BF16)
+    args = [((slots, heads, head_dim), BF16), pool, pool, ((slots,), I32),
+            ((slots, pages_per_slot), I32),
+            ((slots, kv_heads, head_dim), BF16),
+            ((slots, kv_heads, head_dim), BF16)]
     if quant:
         args += [((layers, n_pages, page, kv_heads), F32)] * 2
 
@@ -332,6 +334,45 @@ def test_paged_chunk_block_loop_fits_vmem(heads, head_dim, pages_per_slot,
     assert asked <= 100 * 2 ** 20, f"asks for {asked / 2 ** 20:.0f} MiB"
     compiled = _compile(fn, shapes, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the four cells that run ``attn.paged_decode``: slots, query heads, KV
+# heads, head size, pages a slot (pages of 64)
+_DECODE_CELLS = {
+    "opt13b_chat": (32, 32, 32, 64, 22),
+    "opt13b_longprompt": (24, 32, 32, 64, 29),
+    "olmoe_gen": (64, 16, 16, 128, 17),
+    "lfm2_widegen": (256, 32, 8, 64, 21),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE_CELLS))
+def test_paged_decode_block_loop_at_the_cells_shapes(cell, one_chip, mosaic):
+    """The decode kernel's block loop — a loop over the live rows, blocks
+    of pages double-buffered, the next row's first block handed over,
+    the fused write's stripes sent to the pools by hand — lowers through
+    Mosaic at each serving cell's shapes with the fused write: the two
+    pools stay aliased input -> output (donated, they are updated in
+    place: nothing pool-sized among the temporaries), and the block
+    buffers fit the VMEM the kernel asks for (a kernel over its limit
+    does not compile) with the ask at its floor."""
+    slots, heads, kv_heads, head_dim, slot_pages = _DECODE_CELLS[cell]
+    page, layers = 64, 2
+    fn, shapes = _paged_decode(False, page=page, slots=slots,
+                               cache_len=slot_pages * page, layers=layers,
+                               kv_heads=kv_heads, heads=heads,
+                               head_dim=head_dim)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * layers * (slots * slot_pages + 1) * page \
+        * kv_heads * head_dim * 2
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 16, mem.temp_size_in_bytes
+    bp = paged_mod._decode_block_pages(page, slot_pages, 2)
+    block_bytes = bp * page * kv_heads * head_dim * 2
+    assert 6 * block_bytes + 16 * 2 ** 20 <= 96 * 2 ** 20, block_bytes
 
 
 @pytest.mark.parametrize("rows", [1, 4], ids=["one_row", "four_rows"])

@@ -31,7 +31,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from deepspeed_tpu.models.latent_attention import (LatentSpec,
-                                                   causal_pairs, padded)
+                                                   causal_pairs,
+                                                   live_block_rows, padded)
 from deepspeed_tpu.models.latent_block import (LatentBlock,  # noqa: F401
                                                _Mlp, _Norm)
 
@@ -197,8 +198,11 @@ class Dots3Model(nn.Module):
         over the layers of each kind): ``dsa_keys_scored`` — (query, key)
         pairs the indexer scores, the causal ones —, ``dsa_keys_kept`` —
         pairs the softmax runs over —, ``latent_rows_read`` — latent rows
-        fetched from the pool (the chunk form decompresses the slot's
-        live rows once a layer) —, ``window_pages`` — ring pages the
+        fetched from the pool (the slot's live rows, once a layer) —,
+        ``latent_rows_decompressed`` — rows the full layers up-project
+        into every head's keys and values: the live key blocks, whole, not
+        the lane (a padded last chunk's blocks past ``end`` run too and
+        are not counted) —, ``window_pages`` — ring pages the
         window layers hold for the slot —, ``window_keys`` — pairs the
         window layers attend."""
         cfg = self.config
@@ -208,6 +212,7 @@ class Dots3Model(nn.Module):
         return {"dsa_keys_scored": full * pairs(end),
                 "dsa_keys_kept": full * pairs(cfg.full.index_topk),
                 "latent_rows_read": full * -(-end // page_size) * page_size,
+                "latent_rows_decompressed": full * live_block_rows(end),
                 "window_pages": ring_pages * swa,
                 "window_keys": swa * pairs(cfg.window.window)}
 

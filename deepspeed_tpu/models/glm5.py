@@ -53,7 +53,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from deepspeed_tpu.models.latent_attention import (LatentSpec,
-                                                   causal_pairs, padded)
+                                                   causal_pairs,
+                                                   live_block_rows, padded)
 from deepspeed_tpu.models.latent_block import LatentBlock, _Norm
 
 
@@ -230,14 +231,18 @@ class Glm5Model(nn.Module):
         ``layers`` (default the main model's): ``dsa_keys_scored`` —
         (query, key) pairs the indexer scores, the causal ones —,
         ``dsa_keys_kept`` — pairs the softmax runs over —,
-        ``latent_rows_read`` — latent rows fetched from the pool (the chunk
-        form decompresses the slot's live rows once a layer)."""
+        ``latent_rows_read`` — latent rows fetched from the pool (the
+        slot's live rows, once a layer) —, ``latent_rows_decompressed`` —
+        rows up-projected into every head's keys and values: the live key
+        blocks, whole, not the lane (a padded last chunk's blocks past
+        ``end`` run too and are not counted)."""
         cfg = self.config
         layers = layers or cfg.num_layers
         pairs = lambda limit: causal_pairs(start, end, limit)
         return {"dsa_keys_scored": layers * pairs(end),
                 "dsa_keys_kept": layers * pairs(cfg.attn.index_topk),
-                "latent_rows_read": layers * -(-end // page_size) * page_size}
+                "latent_rows_read": layers * -(-end // page_size) * page_size,
+                "latent_rows_decompressed": layers * live_block_rows(end)}
 
     def block_work(self, live, ring_pages, layers=None):
         """The same for the rows of a decode dispatch, from ``live`` —

@@ -15,9 +15,11 @@ Call forms over the same parameters (``ops/transformer/
 latent_attention.py`` has the kernels):
 
 * :meth:`LatentAttention.chunk` — a prefill chunk of one slot, keys and
-  values decompressed from the slot's cached rows, flash attention under
-  the kept-set (or band) mask; with no cache it is the plain causal
-  forward over the chunk alone (init, tests).
+  values decompressed from the slot's cached rows — a full layer's lane
+  gathered through the slot's table, its key blocks up to the chunk's last
+  position decompressed and no others (``attn.mla_decompress``) —, flash
+  attention under the kept-set (or band) mask; with no cache it is the
+  plain causal forward over the chunk alone (init, tests).
 * :meth:`LatentAttention.window` — a few rows a lane at consecutive
   positions (a verify window; one row is a decode step), in the absorbed
   form: the query goes through the key up-projection, attends latent rows
@@ -141,12 +143,21 @@ def causal_pairs(start, end, limit):
     return low * start + low * (low + 1) // 2 + (end - start - low) * limit
 
 
-def lane_rows(pool, table_row, multiple):
-    """One slot's virtual lane of a pool layer ``[pages, page, W]``
-    through its table row ``[n]``: ``[n' * page, W]`` in position order,
-    ``n'`` rounded up to ``multiple`` pages with trash-page entries."""
+def live_block_rows(end):
+    """Rows a full layer's cached chunk decompresses when its last
+    position is ``end - 1``: the live key blocks, whole — the host-side
+    count behind ``chunk_work``'s ``latent_rows_decompressed``."""
+    return -(-end // ops.KEY_BLOCK) * ops.KEY_BLOCK
+
+
+def lane_rows(pool, layer, table_row, multiple):
+    """One slot's virtual lane of ``pool [layers, pages, page, W]`` at
+    ``layer`` through its table row ``[n]``: ``[n' * page, W]`` in position
+    order, ``n'`` rounded up to ``multiple`` pages with trash-page entries.
+    ONE gather by ``(layer, page)``: the pool's layer is never sliced out
+    (a copy of every slot's pages to read one slot's)."""
     pad = -table_row.shape[0] % multiple
-    rows = pool[jnp.pad(table_row, (0, pad))]
+    rows = pool[layer, jnp.pad(table_row, (0, pad))]
     return rows.reshape(-1, rows.shape[-1])
 
 
@@ -266,14 +277,21 @@ class LatentAttention(nn.Module):
             out, cache = self._chunk_full(x, q, row, c_q, positions, cache)
         return self._out(x, out.transpose(1, 0, 2)), cache
 
-    def _attend(self, q, keys, mask, name):
-        """Decompress ``keys [L, row]`` and attend under ``mask``."""
+    def _attend(self, q, keys, mask, name, live_keys=None):
+        """Decompress ``keys [L, >= row]`` and attend under ``mask``.
+        ``live_keys``: the mask keeps no key at or past it, and only the
+        key blocks before it are decompressed (``ops.decompress``) — the
+        blocks the flash kernel can fetch."""
         z = self.spec
-        w_k, w_v = self._kv_up()
-        lat = keys[:, :z.kv_rank]
         with jax.named_scope("attn.mla_decompress"):      # c_kv W_kvb
-            k_nope = jnp.einsum("lr,rhd->hld", lat, w_k)
-            v = jnp.einsum("lr,rhd->hld", lat, w_v)
+            if live_keys is None:
+                w_k, w_v = self._kv_up()
+                lat = keys[:, :z.kv_rank]
+                k_nope = jnp.einsum("lr,rhd->hld", lat, w_k)
+                v = jnp.einsum("lr,rhd->hld", lat, w_v)
+            else:
+                k_nope, v = ops.decompress(keys, self._w(self.kv_b), z.heads,
+                                           z.nope, live_keys)
         return ops.masked_flash(
             q[..., :z.nope], q[..., z.nope:], k_nope,
             keys[:, z.kv_rank:z.row], v, mask, z.scale, name)
@@ -290,14 +308,15 @@ class LatentAttention(nn.Module):
                 latent = write_rows(latent, layer, table, positions, row)
                 index = write_rows(index, layer, table, positions, ki)
             pools = (latent, index)
-            # the slot's whole lane, in position order, in 512-key blocks
-            pages = max(1, 512 // latent.shape[2])
-            keys = lane_rows(latent[layer], table, pages)
-            index_keys = lane_rows(index[layer], table, pages)
+            # the slot's whole lane, in position order, in whole key blocks
+            pages = max(1, ops.KEY_BLOCK // latent.shape[2])
+            keys = lane_rows(latent, layer, table, pages)
+            index_keys = lane_rows(index, layer, table, pages)
         live_keys = positions[-1] + 1
         scores = ops.index_scores(qi, w, index_keys, live_keys)
         mask = ops.kept_mask(scores, positions, z.index_topk)
-        return self._attend(q, keys, mask, "attn.mla_chunk_prefill"), pools
+        return self._attend(q, keys, mask, "attn.mla_chunk_prefill",
+                            None if cache is None else live_keys), pools
 
     def _chunk_window(self, q, row, positions, live, cache):
         z = self.spec

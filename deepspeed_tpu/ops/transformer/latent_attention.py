@@ -17,6 +17,12 @@ Pallas kernels, each findable in a device trace by its own name:
   the shared part is never copied per head.  Tiles in which the mask
   keeps nothing are skipped, DMA and body.
 
+* ``attn.mla_decompress`` (:func:`decompress`) — a cached full layer's
+  ``c_kv W_kvb``: every head's keys and values of the lane's key blocks up
+  to the chunk's last position, written head-major as :func:`masked_flash`
+  takes them.  A block past them is neither read, computed nor written
+  (no flash tile lies in one); the weights stay in VMEM along the keys.
+
 * ``attn.dsa_topk`` (:func:`kth_largest`) — a chunk's exact per-query
   top-k threshold: the k-th largest score by bisection over the scores'
   bit patterns, 32 compare-and-count passes over a row that stays in VMEM,
@@ -57,6 +63,10 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.transformer.flash_attention import _interpret
 
 NEG = -1e30
+# a chunk's keys are scored, decompressed and attended in blocks of this
+# many rows: a lane is whole blocks, and what one kernel leaves out past
+# the live ones (``decompress``) no other fetches (``masked_flash``)
+KEY_BLOCK = 512
 
 
 def _block(n, want):
@@ -89,7 +99,7 @@ def _index_kernel(live_ref, q_ref, w_ref, k_ref, o_ref, *, heads, lanes):
         o_ref[...] = jnp.full(o_ref.shape, NEG, o_ref.dtype)
 
 
-def index_scores(q, w, k, live_keys, block_q=32, block_k=512):
+def index_scores(q, w, k, live_keys, block_q=32, block_k=KEY_BLOCK):
     """``I [C, L]`` float32 from ``q [C, J, D]``, ``w [C, J]`` (float32,
     the score's constant factors folded in) and the cached keys
     ``k [L, D]``.  Keys at or past ``live_keys`` (a traced scalar, rounded
@@ -440,6 +450,60 @@ def lane_decode(q, kept, pool, layer, table, bp, ctx, rank, scale):
 
 
 # --------------------------------------------------------------------- #
+# attn.mla_decompress
+# --------------------------------------------------------------------- #
+def _decompress_kernel(live_ref, lat_ref, w_ref, k_ref, v_ref):
+    @pl.when(pl.program_id(1) < live_ref[0])
+    def _block():
+        lat = lat_ref[...]
+        heads, nope = k_ref.shape[0], k_ref.shape[2]
+        per = w_ref.shape[1] // heads
+        for h in range(heads):
+            kv = jnp.dot(lat, w_ref[:, h * per:(h + 1) * per],
+                         preferred_element_type=jnp.float32)
+            k_ref[h] = kv[:, :nope].astype(k_ref.dtype)
+            v_ref[h] = kv[:, nope:].astype(v_ref.dtype)
+
+
+def decompress(rows, w, heads, nope, live_keys, block_k=KEY_BLOCK,
+               block_h=8):
+    """Every head's keys and values of a lane's LIVE rows: ``(k_nope [H,
+    L, nope], v [H, L, Dv])`` — head-major, as :func:`masked_flash` takes
+    them — from the cached ``rows [L, >= rank]`` (the latent ``c_kv`` its
+    first ``rank`` columns: on the chip whole 128-lane tiles, or the row)
+    and the up-projection ``w [rank, H x (nope + Dv)]`` in its parameter's
+    own layout.  Key blocks at or past ``live_keys`` (a traced scalar,
+    rounded up to a key block) are neither read nor computed NOR WRITTEN:
+    what the result holds there is not defined.  A head block's weights
+    stay in VMEM along the key axis."""
+    L, (rank, width) = rows.shape[0], w.shape
+    per = width // heads
+    bk, bh = _block(L, block_k), _block(heads, block_h)
+    live = jnp.reshape(-(-live_keys // bk), (1,)).astype(jnp.int32)
+    # a dead step names the last live block on both sides: a block already
+    # fetched, and an output block that has not changed — no DMA either way
+    key_block = lambda h, j, live: (jnp.minimum(j, live[0] - 1), 0)
+    out_block = lambda h, j, live: (h, jnp.minimum(j, live[0] - 1), 0)
+    return pl.pallas_call(
+        _decompress_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(heads // bh, L // bk),
+            in_specs=[pl.BlockSpec((bk, rank), key_block),
+                      pl.BlockSpec((rank, bh * per),
+                                   lambda h, j, live: (0, h))],
+            out_specs=[pl.BlockSpec((bh, bk, nope), out_block),
+                       pl.BlockSpec((bh, bk, per - nope), out_block)]),
+        out_shape=[jax.ShapeDtypeStruct((heads, L, nope), rows.dtype),
+                   jax.ShapeDtypeStruct((heads, L, per - nope), rows.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+        name="attn.mla_decompress",
+    )(live, rows, w)
+
+
+# --------------------------------------------------------------------- #
 # attn.mla_chunk_prefill / attn.mla_window
 # --------------------------------------------------------------------- #
 def _flash_kernel(live_ref, fetch_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
@@ -496,7 +560,7 @@ def _tile_plan(mask, bq, bk):
 
 
 def masked_flash(q_nope, q_rope, k_nope, k_rope, v, mask, scale, name,
-                 block_q=512, block_k=512, block_h=2):
+                 block_q=512, block_k=KEY_BLOCK, block_h=2):
     """``out [H, C, Dv]``: softmax over the keys ``mask [C, L]`` keeps of
     ``(q_nope . k_nope + q_rope . k_rope) * scale``, times ``v``.
     ``q_nope [H, C, Dn]``, ``q_rope [H, C, Dr]``, ``k_nope [H, L, Dn]``,

@@ -361,6 +361,75 @@ def test_masked_flash_kernel(live):
     assert (tiles[:, live // 32:] == 0).all()
 
 
+@pytest.mark.parametrize("live", [20, 70, 128],
+                         ids=["first_block", "mid_lane", "whole_lane"])
+def test_decompress_kernel(live):
+    """``attn.mla_decompress`` against ``_attend``'s two einsums over the
+    live key blocks, head-major; a dead block is not written (the
+    interpreter hands back ``nan`` where a kernel wrote nothing)."""
+    rng = np.random.default_rng(live)
+    H, nope, dv, rank, L, bk = 4, 16, 8, 32, 128, 32
+    rows = rng.normal(size=(L, 48)).astype(np.float32)
+    blocks = -(-live // bk) * bk
+    w = rng.normal(size=(rank, H * (nope + dv))).astype(np.float32)
+    k, v = ops.decompress(jnp.asarray(rows), jnp.asarray(w), H, nope,
+                          jnp.int32(live), block_k=bk, block_h=2)
+    assert k.shape == (H, L, nope) and v.shape == (H, L, dv)
+    by_head = w.reshape(rank, H, nope + dv)
+    lat = rows[:blocks, :rank]
+    want_k = np.einsum("lr,rhd->hld", lat, by_head[..., :nope])
+    want_v = np.einsum("lr,rhd->hld", lat, by_head[..., nope:])
+    assert np.abs(np.asarray(k)[:, :blocks] - want_k).max() < 1e-4
+    assert np.abs(np.asarray(v)[:, :blocks] - want_v).max() < 1e-4
+    assert np.isnan(np.asarray(k)[:, blocks:]).all() \
+        and np.isnan(np.asarray(v)[:, blocks:]).all()
+
+
+@pytest.mark.parametrize("chunks", [1, 5, 9],
+                         ids=["first_block", "mid_lane", "whole_lane"])
+def test_a_cached_chunk_touches_its_live_blocks_only(chunks):
+    """A full layer's cached chunks through a table that is NOT in page
+    order, a lane of three 512-key blocks: the last chunk's output is the
+    uncached forward's over the same tokens, and it does not change — and
+    stays finite — when every page outside its live blocks holds ``inf``:
+    the other slots' pages, the slot's own pages past its live blocks and
+    the trash page its table is padded with there, in both pools.  Nothing
+    dead is read, and nothing the decompression left unwritten reaches the
+    softmax."""
+    from deepspeed_tpu.models.latent_attention import (LatentAttention,
+                                                       live_block_rows)
+    z = fam.program_model(TOY).config.full
+    attn = LatentAttention(z, dtype=jnp.float32)
+    page, C, lane_pages, n_pages = 8, 128, 3 * ops.KEY_BLOCK // 8 - 10, 400
+    T = chunks * C
+    x = jnp.asarray(np.random.default_rng(chunks).normal(size=(T, z.hidden)),
+                    jnp.float32)
+    chunk = LatentAttention.chunk
+    params = attn.init(jax.random.key(1), x[:C], jnp.int32(0), method=chunk)
+    want, _ = attn.apply(params, x, jnp.int32(0), method=chunk)
+    table = jnp.asarray(np.random.default_rng(5).permutation(
+        np.arange(1, n_pages))[:lane_pages], jnp.int32)
+    pools = (jnp.zeros((2, n_pages, page, 128), jnp.float32),
+             jnp.zeros((2, n_pages, page, z.index_dim), jnp.float32))
+    step = jax.jit(lambda pools, xs, start: attn.apply(
+        params, xs, start, cache=(pools, 1, table), method=chunk))
+    for c in range(chunks - 1):
+        _, pools = step(pools, x[c * C:(c + 1) * C], jnp.int32(c * C))
+    last = x[T - C:], jnp.int32(T - C)
+    got, _ = step(pools, *last)
+    assert np.abs(np.asarray(got) - np.asarray(want[T - C:])).max() < TOL
+    # (the lane's padded tail reads the trash page: live in its last block)
+    lane = np.pad(np.asarray(table),
+                  (0, -lane_pages % (ops.KEY_BLOCK // page)))
+    dead = np.setdiff1d(np.arange(n_pages),
+                        lane[:live_block_rows(T) // page])
+    assert (0 in dead) == (chunks < 9)
+    poisoned, _ = step(tuple(p.at[:, dead].set(jnp.inf) for p in pools),
+                       *last)
+    assert np.isfinite(np.asarray(poisoned)).all()
+    assert np.array_equal(np.asarray(poisoned), np.asarray(got))
+
+
 # ---- what the spans carry -------------------------------------------------- #
 @pytest.mark.parametrize("start,end,scored,kept,window", [
     (0, 32, 528, 492, 408),            # 24 x 25 / 2 + 8 x 24;  17-key band
@@ -370,8 +439,12 @@ def test_chunk_work_counts_pairs(start, end, scored, kept, window):
     module = fam.program_model(TOY)
     work = module.chunk_work(start, end, 8, 3)
     assert work == {"dsa_keys_scored": 2 * scored, "dsa_keys_kept": 2 * kept,
-                    "latent_rows_read": 2 * end, "window_pages": 6,
+                    "latent_rows_read": 2 * end,
+                    "latent_rows_decompressed": 2 * 512, "window_pages": 6,
                     "window_keys": 2 * window}
+    # the live 512-key blocks, whole: what ``attn.mla_decompress`` runs
+    assert [module.chunk_work(e - 1024, e, 64, 9)["latent_rows_decompressed"]
+            for e in (1024, 1500, 15360)] == [2 * 1024, 2 * 1536, 2 * 15360]
 
 
 def test_block_work_reads_the_kept_rows_only():

@@ -466,6 +466,22 @@ def test_dots3s_parameter_tree_is_what_it_was():
         == "feb253ac5e79eaf5"
 
 
+# ---- (g2) what a chunk's span carries ------------------------------------ #
+@pytest.mark.parametrize("layers", [None, "with_the_module"])
+def test_chunk_work_counts_the_live_blocks(program, layers):
+    """A chunk's attention work over the main model's layers, or those and
+    the module's (the self-drafting server's ``work_layers``): the rows it
+    decompresses are the live 512-key blocks, whole — not the slot's lane."""
+    module, _ = program
+    n = module.config.num_layers + (module.config.mtp_layers if layers else 0)
+    work = module.chunk_work(1024, 2500, 64, 0, **(
+        {"layers": n} if layers else {}))
+    assert work["latent_rows_read"] == n * 40 * 64
+    assert work["latent_rows_decompressed"] == n * 5 * 512
+    assert work["dsa_keys_kept"] <= work["dsa_keys_scored"] \
+        == n * sum(range(1025, 2501))
+
+
 # ---- (h) the lane kernels against plain math ---------------------------- #
 @pytest.mark.parametrize("rows,contexts", [
     (1, (37, 64, 5)), (2, (38, 0, 64)), (2, (17, 33, 49)), (1, (1, 2, 3))])

@@ -147,6 +147,10 @@ D = "jit(decode_block)/while/body/closed_call/"
     ("jit(chunk_step)/Dots3Model.decode/layers_1/attn.chunk/"
      "attn._chunk_full/attn._attend/attn._kv_up/reshape",
      "attn.mla_decompress", "fwd"),
+    # the kernel of that name under the scope (a cached full layer)
+    ("jit(chunk_step)/Dots3Model.decode/layers_1/attn.chunk/"
+     "attn._chunk_full/attn._attend/attn.mla_decompress/"
+     "attn.mla_decompress/pallas_call", "attn.mla_decompress", "fwd"),
     ("jit(chunk_step)/Transformer.decode/layers_0/attn/cache.write/scatter",
      "cache.write", "fwd"),
     # the chunk's K/V as page runs (the name the compiled step carries)

@@ -175,6 +175,18 @@ def _mla_flash(name, heads, nope, keys):
                 ((DOTS3_CHUNK, keys), I8)]
 
 
+def _mla_decompress(heads, nope, v, lane):
+    """A full layer's ``c_kv W_kvb`` over a lane's live key blocks — dots3:
+    128 heads of 128 + 128 over 16,896 rows; GLM-5: 64 heads of 192 + 256
+    (a head's 448 columns start 64 lanes off a tile in every odd head) over
+    5,120 — from the pool's 640-wide rows, the weight as the parameter
+    lies."""
+    def fn(rows, w, live):
+        return latent_mod.decompress(rows, w, heads, nope, live)
+    return fn, [((lane, 640), BF16), ((512, heads * (nope + v)), BF16),
+                ((), I32)]
+
+
 def _moe_grouped(tokens=DOTS3_CHUNK, hidden=5120, held=32, width=1536,
                  top_k=8):
     up = ((held, hidden, width), BF16)
@@ -275,6 +287,9 @@ CASES = {
         "attn.mla_chunk_prefill", 128, 128, DOTS3_LANE),
     "dots3_mla_window_c2048": lambda: _mla_flash(
         "attn.mla_window", 64, 192, DOTS3_CHUNK + 512),
+    "dots3_mla_decompress_l16896": lambda: _mla_decompress(
+        128, 128, 128, DOTS3_LANE),
+    "glm5_mla_decompress_l5120": lambda: _mla_decompress(64, 192, 256, 5120),
     "dots3_moe_grouped_c2048": _moe_grouped,
     "lfm2_moe_scored_gmm_t256": lambda: _moe_scored(256),
     "lfm2_moe_scored_grouped_c512": lambda: _moe_scored(512),
@@ -441,6 +456,36 @@ def test_chunk_step_writes_page_runs_in_place(rows, one_chip, mosaic):
     assert len(prefetched) >= L - 2, sorted(prefetched)
 
 
+def _slot_programs_of(cell, family, one_chip):
+    """What a serving cell's slot programs are lowered from, at the cell's
+    own settings: the module, the cell's ``serving`` block, its chunk and
+    ``SlotPages`` — and ``params`` / ``pool`` as shapes on the described
+    chip, with ``on_chip`` and ``ints`` to make more of them."""
+    import os
+    import types
+    from benchmark import spec
+    from deepspeed_tpu.inference.serving import slots
+    from deepspeed_tpu.inference.serving.paging import SlotPages
+    bench = spec.Benchmark(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    cell = bench.cell(cell)
+    module = bench.family(family).program_model(cell["config"])
+    s = cell["system"]["serving"]
+    chunk = slots.admission_chunk(module, s["prefill_chunk"])
+    pages = SlotPages(module, s["num_slots"], s["max_cache_len"],
+                      s["page_size"], 0, chunk, False, {})
+    on_chip = lambda tree, dtype=None: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                       sharding=one_chip), tree)
+    return types.SimpleNamespace(
+        module=module, serving=s, chunk=chunk, pages=pages, on_chip=on_chip,
+        params=on_chip(jax.eval_shape(lambda: module.init(
+            jax.random.key(0), {"input_ids": jnp.zeros((1, 8), I32)})), BF16),
+        pool=on_chip(jax.eval_shape(lambda: pages.new_pools(BF16))),
+        ints=lambda *shape: jax.ShapeDtypeStruct(shape, I32,
+                                                 sharding=one_chip))
+
+
 @pytest.mark.parametrize("program", ["chunk_step", "decode_block"])
 def test_evabyte_slot_programs_compile_at_the_cells_sizes(program, one_chip,
                                                           mosaic):
@@ -450,26 +495,11 @@ def test_evabyte_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     ``attn.eva_decode``), the pools aliased input -> output, and the 12.5 GB
     the cell's sizing reckons — weights 3.26 GB, 24 rings of 32 pages and
     the summary lane, 8 layers — inside one chip."""
-    import os
-    from benchmark import spec
     from deepspeed_tpu.inference.serving import slots
-    from deepspeed_tpu.inference.serving.paging import SlotPages
-    bench = spec.Benchmark(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))))
-    cell = bench.cell("evabyte-serve-bytedoc-batch")
-    module = bench.family("evabyte").program_model(cell["config"])
-    s = cell["system"]["serving"]
-    chunk = slots.admission_chunk(module, s["prefill_chunk"])
-    pages = SlotPages(module, s["num_slots"], s["max_cache_len"],
-                      s["page_size"], 0, chunk, False, {})
+    c = _slot_programs_of("evabyte-serve-bytedoc-batch", "evabyte", one_chip)
+    module, s, chunk, pages = c.module, c.serving, c.chunk, c.pages
+    params, pool, ints, on_chip = c.params, c.pool, c.ints, c.on_chip
     assert (pages.pages_per_slot, pages.ring_pages) == (14, 32)
-    on_chip = lambda tree, dtype=None: jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
-                                       sharding=one_chip), tree)
-    params = on_chip(jax.eval_shape(lambda: module.init(
-        jax.random.key(0), {"input_ids": jnp.zeros((1, 8), I32)})), BF16)
-    pool = on_chip(jax.eval_shape(lambda: pages.new_pools(BF16)))
-    ints = lambda *shape: jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
     if program == "chunk_step":
         compiled = slots.make_chunk_fn(module, None).lower(
             params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
@@ -494,43 +524,65 @@ def test_evabyte_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     assert 12.4e9 < total < 14e9, f"{total / 1e9:.2f} GB"
 
 
+def _no_pool_layer_is_sliced_out(text, pages):
+    """A full layer's chunk gathers the slot's lane from the pool by
+    ``(layer, page)``: the compiled program holds NO value the size of a
+    pool layer (``[pages, 64, 640]`` latent rows, ``[pages, 64, 128]``
+    index keys — XLA used to copy the whole layer out, then gather one
+    slot's pages from the copy)."""
+    layer_sized = re.compile(
+        r"= \w+\[(1,)?%d,64,(640|128)\]\S* (?!parameter\()"
+        % pages.num_pages)
+    found = [line.strip()[:160] for line in text.splitlines()
+             if layer_sized.search(line)]
+    assert not found, found
+
+
+def test_dots3_chunk_step_compiles_at_the_cells_sizes(one_chip, mosaic):
+    """The chunk program ``dots3-serve-longdoc-batch`` runs, whole: index,
+    top-k, decompress and flash a full layer (two), flash a window layer
+    (three), the grouped experts a routed layer (four) as Mosaic calls; the
+    lane read through the table; the pools aliased input -> output."""
+    from deepspeed_tpu.inference.serving import slots
+    c = _slot_programs_of("dots3-serve-longdoc-batch", "dots3", one_chip)
+    module, s, chunk, pages = c.module, c.serving, c.chunk, c.pages
+    params, pool, ints = c.params, c.pool, c.ints
+    assert (pages.num_pages, s["page_size"]) == (4113, 64)
+    compiled = slots.make_chunk_fn(module, None).lower(
+        params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
+        ints(1)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2 * 4 + 3 + 4
+    _no_pool_layer_is_sliced_out(text, pages)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * 2 for x in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+
+
 @pytest.mark.parametrize("program", ["chunk_step", "spec_block"])
 def test_glm5_slot_programs_compile_at_the_cells_sizes(program, one_chip,
                                                        mosaic):
     """The two programs ``glm5-serve-reasongen-batch`` runs, whole, as
     ``serving/slots.py`` builds them for a self-drafting model at the
     cell's own settings: the chunk that fills the multi-token-prediction
-    module's rows beside the main model's, and the block of verify windows
+    module's rows beside the main model's — each layer's lane read through
+    the table —, and the block of verify windows
     of two rows a lane — the pools (six layers: five main, the module's)
     aliased input -> output, 9.61 GB of weights + 2.76 GB of pools and the
     programs' temporaries inside one chip."""
-    import os
-    from benchmark import spec
     from deepspeed_tpu.inference.serving import slots
-    from deepspeed_tpu.inference.serving.paging import SlotPages
-    bench = spec.Benchmark(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))))
-    cell = bench.cell("glm5-serve-reasongen-batch")
-    module = bench.family("glm5").program_model(cell["config"])
-    s = cell["system"]["serving"]
-    chunk = slots.admission_chunk(module, s["prefill_chunk"])
-    pages = SlotPages(module, s["num_slots"], s["max_cache_len"],
-                      s["page_size"], 0, chunk, False, {})
+    c = _slot_programs_of("glm5-serve-reasongen-batch", "glm5", one_chip)
+    module, s, chunk, pages = c.module, c.serving, c.chunk, c.pages
+    params, pool, ints, on_chip = c.params, c.pool, c.ints, c.on_chip
     assert (pages.pages_per_slot, pages.num_pages) == (73, 64 * 73 + 1)
-    on_chip = lambda tree, dtype=None: jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
-                                       sharding=one_chip), tree)
-    params = on_chip(jax.eval_shape(lambda: module.init(
-        jax.random.key(0), {"input_ids": jnp.zeros((1, 8), I32)})), BF16)
-    pool = on_chip(jax.eval_shape(lambda: pages.new_pools(BF16)))
     assert {k: v.shape[0] for k, v in pool.items()} \
         == {"latent": 6, "index": 6}
-    ints = lambda *shape: jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
     if program == "chunk_step":
         compiled = slots.make_chunk_fn(module, None, self_draft=True).lower(
             params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
             ints(1), ints(1)).compile()
-        calls = 6 * 3                   # index, top-k, flash a layer
+        calls = 6 * 4         # index, top-k, decompress, flash a layer
+        _no_pool_layer_is_sliced_out(compiled.as_text(), pages)
     else:
         n = s["num_slots"]
         state = on_chip({k: jnp.asarray(v) for k, v in

@@ -6,17 +6,17 @@
 One set of weights; a server a ``prefill_chunk`` (its two programs compile
 anew), and on it a short window a ``prefill_token_budget`` (``default``:
 the program's own), each behind the mix's own ramp.  A line a window:
-tokens completed per second, requests, mean slots live, iterations.  The
-cell's ``workloads`` file keeps the table under ``defined_by`` and the
-winner under ``serving``; a later ``benchmark`` PR finds them again with
-this command.  (``sweep.py`` is the open loop's.)
+tokens per second as the cell counts them, requests completed, mean slots
+live, iterations.  The cell's ``workloads`` file keeps the table under
+``defined_by`` and the winner under ``serving``; a later ``benchmark`` PR
+finds them again with this command.  (``sweep.py`` is the open loop's.)
 """
 
 import argparse
 import json
 import os
 import sys
-import time
+import types
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -24,37 +24,40 @@ sys.path.insert(0, ROOT)
 from benchmark import harness, stats, trafficgen  # noqa: E402
 
 
-def window(srv, mix, vocab, seed, seconds):
-    """Ramp, then ``seconds`` of the closed loop on ``srv``; the server is
-    drained of what is left, so the next window starts empty."""
-    stream = trafficgen.closed_loop_requests(mix, vocab, seed)
-    live, tokens, done = {}, 0, 0
+class _Remembering:
+    """The server, remembering what was submitted, so that a window's
+    leftovers can be cancelled."""
 
-    def submit_one():
-        _, prompt, new = next(stream)
-        live[srv.submit(prompt, max_new_tokens=new)] = len(prompt) + new
+    def __init__(self, srv):
+        self._srv, self.rids = srv, []
 
-    for _ in range(mix["callers"]):
-        submit_one()
-    t_ramp = time.monotonic()
-    while time.monotonic() - t_ramp < mix["ramp_s"]:
-        for rid in srv.step():
-            live.pop(rid)
-            submit_one()
-    t0, it0, occ0 = time.monotonic(), srv.stats["iterations"], \
-        len(srv.occupancy_trace)
-    while time.monotonic() - t0 < seconds:
-        for rid in srv.step():
-            tokens += live.pop(rid)
-            done += 1
-            submit_one()
-    took = time.monotonic() - t0
-    occupancy = [n for _, n in srv.occupancy_trace[occ0:]]
-    row = {"batch_tokens_per_s": stats.rate(tokens, took), "completed": done,
-           "iterations": srv.stats["iterations"] - it0,
-           "slots_live_mean": sum(occupancy) / max(len(occupancy), 1)}
-    for rid in list(live):
-        srv.cancel(rid)
+    def __getattr__(self, name):
+        return getattr(self._srv, name)
+
+    def submit(self, *args, **kwargs):
+        self.rids.append(self._srv.submit(*args, **kwargs))
+        return self.rids[-1]
+
+
+def window(drive, srv, mix, vocab, seed, seconds):
+    """Ramp, then ``seconds`` of the cell's own closed loop (``drive`` of
+    ``drivers/closed_loop_engine.py``, so the count is the cell's) on
+    ``srv``; the server is drained of what is left, so the next window
+    starts empty."""
+    srv = _Remembering(srv)
+    quiet = types.SimpleNamespace(poll=lambda now: None, finish=lambda: None)
+    w, _ = drive(srv, trafficgen.closed_loop_requests(mix, vocab, seed),
+                 mix, seconds, quiet, lambda t0: None)
+    row = {"batch_tokens_per_s": stats.rate(w["credited_tokens"],
+                                            w["credited_s"]),
+           "completed": w["completed"], "iterations": w["iterations"],
+           "slots_live_mean": sum(w["occupancy"])
+           / max(len(w["occupancy"]), 1)}
+    for rid in srv.rids:
+        try:
+            srv.cancel(rid)
+        except KeyError:        # long gone
+            pass
     srv.drain()
     return row
 
@@ -70,6 +73,7 @@ def main(argv=None):
     import deepspeed_tpu
     ctx = harness.open_cell(ROOT, args.workload, args.seed, args.seconds)
     model, mix = ctx.cell["config"], ctx.cell["traffic"]
+    drive = ctx.bench.driver("closed_loop_engine").drive
     base = dict(ctx.cell["system"]["serving"])
     module = ctx.family.program_model(model, scan_layers=False)
     engine = deepspeed_tpu.init_inference(module, config={
@@ -92,7 +96,7 @@ def main(argv=None):
                     "prefill_token_budget": default if budget == "default"
                     else int(budget)})
                 row = {"prefill_chunk": chunk, "prefill_token_budget": budget,
-                       **window(srv, mix, model["vocab_size"],
+                       **window(drive, srv, mix, model["vocab_size"],
                                 args.seed + k, args.seconds),
                        "compile_s": compile_s}
                 table.append(row)

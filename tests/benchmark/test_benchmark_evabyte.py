@@ -193,11 +193,12 @@ def test_cell_is_the_issues(bench):
 
 
 def test_traffic_is_the_issues(bench):
-    """Issue 37's mix, but for ``ramp_s``, half a second later: at the
-    issue's 16 s the ramp closes 41 ms after a scheduler step and the
-    window 27 ms before one that completes a request, and one run in four
-    lost that request (1.05%); the issue says to move the ramp then
-    (PERF.md §2; the cell's ``defined_by.window_edges``)."""
+    """Issue 37's mix, but for ``ramp_s``, half a second later: PR 37 moved
+    it when whole requests were credited to the window they completed in
+    and one run in four lost a request at the edge (1.05%; the cell's
+    ``defined_by.window_edges``).  Since PR 44 a request is credited where
+    its tokens are produced and no edge needs siting; the value stays so
+    that the traffic is the same."""
     mix = bench.cell(CELL)["traffic"]
     assert 16.0 <= mix["ramp_s"] <= 17.0
     assert {k: v for k, v in mix.items()

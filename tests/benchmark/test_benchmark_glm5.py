@@ -249,11 +249,14 @@ def _windows(fam, first_phase, new):
 
 
 def test_a_lengths_dispatches_do_not_depend_on_its_first_phase(bench):
-    """The cell's siting (the workload file's ``defined_by.sited``): a
-    request's window count follows the phase of its first token, which is
-    the seed's; the mix's draw and ``decode_block`` are chosen so that its
-    DISPATCH count does not, and every scheduler step then completes the
-    same requests on every seed."""
+    """A request's window count follows the phase of its first token,
+    which is the seed's; the mix's draw and ``decode_block`` are chosen so
+    that its DISPATCH count does not, and every scheduler step then
+    completes the same requests on every seed (the workload file's
+    ``defined_by.sited``).  PR 40 needed that to site the window's edges;
+    since PR 44 tokens are credited where they are produced and no edge is
+    sited, but equal schedules across seeds are still worth having: the
+    seeds then differ by their weights' and tokens' work alone."""
     fam = bench.family("glm5")
     mix = bench.cell(CELL)["traffic"]
     block = bench.cell(CELL)["system"]["serving"]["decode_block"]

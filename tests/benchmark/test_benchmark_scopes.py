@@ -221,8 +221,11 @@ def test_the_table_prints_by_hand_from_the_trace_alone(
 def test_the_nine_entries_are_appended_with_readers(bench):
     assert spec.validate(bench) == []
     assert spec.check_files(bench) == []
-    tail = bench.doc["per_layer"][-len(NEW):]
+    # found by name, in the order they were appended; later PRs append
+    tail = [m for m in bench.doc["per_layer"] if m["name"] in NEW]
     assert [m["name"] for m in tail] == list(NEW)
+    first = bench.doc["per_layer"].index(tail[0])
+    assert bench.doc["per_layer"][first:first + len(NEW)] == tail
     cells = {w["name"] for w in bench.doc["workloads"]}
     for m in tail:
         assert (m["layer"], m["moves"]) == NEW[m["name"]]

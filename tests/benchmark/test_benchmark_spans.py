@@ -270,11 +270,22 @@ def test_host_spans_from_a_recorded_trace(tmp_path):
     assert spans.host_spans(str(tmp_path / "nothing_here")) == []
 
 
+NEW_METRICS = [
+    "frontend.submit_wait_p50_ms", "frontend.lock_wait_p50_ms",
+    "sched.queue_wait_p50_ms", "sched.prefill_p50_ms",
+    "sched.first_token_lag_p50_ms", "sched.host_ms_per_iter.chat",
+    "sched.host_ms_per_iter.batch", "kernel.paged_decode_share_pct.batch",
+    "kernel.flash_fwd_ms_per_step", "kernel.flash_bwd_ms_per_step",
+    "setup.trace_lower_s", "setup.backend_compile_s"]
+
+
 def test_the_new_metrics_are_entries_with_readers(bench):
     assert spec.validate(bench) == [] and spec.check_files(bench) == []
     names = [m["name"] for m in bench.doc["per_layer"]]
-    new = names[15:]
-    assert len(names) == 27 and new[0] == "frontend.submit_wait_p50_ms"
+    # the twelve this test knew, found by name, side by side in their
+    # order; later PRs append
+    first = names.index(NEW_METRICS[0])
+    assert names[first:first + len(NEW_METRICS)] == NEW_METRICS
     chat = {m["name"] for m in bench.cell("opt13b-serve-chat")["per_layer"]}
     assert {"frontend.lock_wait_p50_ms", "sched.first_token_lag_p50_ms",
             "sched.host_ms_per_iter.chat", "setup.trace_lower_s"} <= chat

@@ -89,9 +89,9 @@ def xla_cost_analysis(compiled):
     """XLA's raw cost-analysis dict for a compiled program, normalized
     to a plain dict (some backends return a one-element list).  Keys of
     interest: ``'flops'`` and ``'bytes accessed'`` — THE shared cost
-    model: the flops profiler, the memory/FLOP program contracts
-    (``tools/lint/mem_contract.py``) and the bench roofline blocks all
-    read compiled programs through this one extraction."""
+    model: the flops profiler and the memory/FLOP program contracts
+    (``tools/lint/mem_contract.py``) both read compiled programs through
+    this one extraction."""
     try:
         ca = compiled.cost_analysis()
         if isinstance(ca, (list, tuple)):

@@ -352,9 +352,11 @@ class InferenceEngine:
             from deepspeed_tpu.inference.serving.paging import page_rows
             from deepspeed_tpu.inference.serving.slots import (
                 admission_chunk, chunk_write_form)
+            from deepspeed_tpu.models import contract as slot_contract
             scfg = self._config.serving
+            declared = slot_contract.read(self.module)
             form = chunk_write_form(
-                self.module, admission_chunk(self.module, scfg.prefill_chunk),
+                declared, admission_chunk(declared, scfg.prefill_chunk),
                 page_rows(scfg.page_size))
             if form is not None:
                 tail += " chunk_write=" + form

@@ -70,9 +70,9 @@ lifetime.  Admission streams each prompt chunk through BOTH models
 preemption snapshots committed tokens only, and restore
 re-derives all draft state through the ordinary re-prefill path.
 A model with a multi-token-prediction module of its own
-(``spec_draft_model="mtp"``, ``slots.drafts_itself``) drafts for ITSELF:
-no draft model, cache or programs — ``decode_block`` verify windows of two
-rows a lane ride ONE dispatch (``slots.make_spec_block_fn``), the module's
+(``spec_draft_model="mtp"``, the contract's ``drafts_itself``) drafts for
+ITSELF: no draft model, cache or programs — ``decode_block`` verify windows of
+two rows a lane ride ONE dispatch (``slots.make_spec_block_fn``), the module's
 rows are one more layer of the model's own pools, and the mirror commits
 one or two tokens a lane a window from the program's ``accepted``.
 
@@ -151,10 +151,8 @@ from deepspeed_tpu.inference.serving.slots import (admission_chunk,
                                                    make_draft_chunk_fn,
                                                    make_draft_propose_fn,
                                                    make_spec_block_fn,
-                                                   make_spec_verify_fn,
-                                                   drafts_itself,
-                                                   routes_experts,
-                                                   holds_share)
+                                                   make_spec_verify_fn)
+from deepspeed_tpu.models import contract as slot_contract
 from deepspeed_tpu.runtime.fault import inject
 from deepspeed_tpu.utils.logging import log_dist, logger
 
@@ -314,19 +312,16 @@ class ServingEngine:
         self.num_slots = int(cfg.num_slots)
         if self.num_slots < 1:
             raise ValueError(f"serving.num_slots={cfg.num_slots}: need >= 1")
-        self.chunk = admission_chunk(self.module, cfg.prefill_chunk)
-        if not hasattr(type(self.module), "init_paged_cache"):
-            raise ValueError(
-                f"{type(self.module).__name__} has no init_paged_cache — "
-                f"the slot engine's page pool needs model support "
-                f"(models/transformer.py)")
+        # what the model declares (models/contract.py), read ONCE
+        self.contract = slot_contract.read(self.module)
+        self.chunk = admission_chunk(self.contract, cfg.prefill_chunk)
         self.speculative = bool(cfg.speculative)
         # self-drafting (``spec_draft_model: "mtp"``): the model's own
         # multi-token-prediction module drafts, from the pools' own last
         # layer — no draft model, no draft cache, no draft programs
         self.self_draft = self.speculative and \
             (cfg.spec_draft_model or "").strip() == "mtp"
-        if self.self_draft and not drafts_itself(self.module):
+        if self.self_draft and not self.contract.drafts_itself:
             raise ValueError(
                 f"serving.spec_draft_model='mtp': "
                 f"{type(self.module).__name__} has no multi-token-"
@@ -334,9 +329,10 @@ class ServingEngine:
         # ... or a separate draft MODEL with a lane cache of its own
         self.separate_draft = self.speculative and not self.self_draft
         # layers the drafting module adds to every dispatch (the pools'
-        # last; the load vector's last rows)
-        self._draft_layers = self.module.draft_expert_layers \
+        # last; the load vector's last rows), and a dispatch's in all
+        self._draft_layers = self.contract.draft_layers \
             if self.self_draft else 0
+        self._layers = self.contract.num_layers + self._draft_layers
         # scheduler counters (docs/serving.md) — made before the cache
         # manager, which counts prefix hits and evictions into them
         self.stats = {"iterations": 0, "decode_calls": 0,  # guarded-by: _lock
@@ -358,8 +354,8 @@ class ServingEngine:
         # run from position 0 anyway — a shared target prefix would
         # leave the draft side unfilled ----
         self._pages = SlotPages(                 # guarded-by: _lock
-            self.module, self.num_slots, cfg.max_cache_len, cfg.page_size,
-            cfg.num_pages, self.chunk,
+            self.module, self.contract, self.num_slots, cfg.max_cache_len,
+            cfg.page_size, cfg.num_pages, self.chunk,
             share_prefixes=cfg.prefix_cache and not self.speculative,
             stats=self.stats)
         if self.stats.get("prefix_sharing_refused"):
@@ -376,18 +372,17 @@ class ServingEngine:
         # pages) and the pools' constructor: geometry, fixed for good
         self.table_width = self._pages.table_width
         self._new_pools = self._pages.new_pools
+        # ... held against the cache the model builds
+        slot_contract.check(self.contract, self.module, self.page,
+                            self.chunk, self._layers)
         # a model that counts its own attention work names the span args
         # to sum into ``stats`` (``work_counters``); the names are its own
-        self._work_keys = tuple(getattr(self.module, "work_counters", ()))
-        if self.self_draft:
-            self._pages.work_layers = self.module.config.num_layers \
-                + self._draft_layers
+        self._work_keys = tuple(self.contract.work_counters)
         self.stats.update(dict.fromkeys(self._work_keys, 0))
         # the slot's virtual lane: max_cache_len in whole pages
         self.cache_len = self._pages.cache_len
-        max_seq = getattr(getattr(self.module, "config", None),
-                          "max_seq_len", None)
-        if max_seq is not None and self.cache_len > max_seq:
+        max_seq = self.contract.max_seq_len
+        if self.cache_len > max_seq:
             logger.warning(
                 f"serving.max_cache_len={self.cache_len} exceeds the "
                 f"model's max_seq_len={max_seq} — positions past it will "
@@ -434,14 +429,11 @@ class ServingEngine:
                     "no draft model is passed")
         elif self.speculative:
             draft_module, draft_params = self._resolve_draft(
-                engine, cfg, draft_module, draft_params)
+                engine, self.contract, cfg, draft_module, draft_params)
             self.draft_module = draft_module
-            dvocab = getattr(getattr(draft_module, "config", None),
-                             "vocab_size", None)
-            tvocab = getattr(getattr(self.module, "config", None),
-                             "vocab_size", None)
-            if dvocab is not None and tvocab is not None \
-                    and dvocab != tvocab:
+            dvocab = slot_contract.read(draft_module).vocab_size
+            tvocab = self.contract.vocab_size
+            if dvocab != tvocab:
                 raise ValueError(
                     f"draft model vocab_size={dvocab} != target "
                     f"vocab_size={tvocab} — speculative verification "
@@ -450,7 +442,7 @@ class ServingEngine:
         # ---- expert models (docs/serving.md "Expert models"): the slot
         # programs mask dead lanes and padded chunk tails out of the
         # dropless routing and return the expert load ----
-        self.routed = routes_experts(self.module)
+        self.routed = self.contract.routes_experts
         if self.routed:
             if self.speculative and not self.self_draft:
                 raise ValueError(
@@ -479,21 +471,19 @@ class ServingEngine:
         # prefill_plan reasons attribute the path that actually ran)
         from deepspeed_tpu.ops.transformer.registry import (
             kernel_modes as _registry_modes)
-        _pe = getattr(getattr(self.module, "config", None),
-                      "position_embedding", None)
-        self.kernel_modes = _registry_modes(paged=True,
-                                            has_bias=(_pe == "alibi"))
+        self.kernel_modes = _registry_modes(
+            paged=True, has_bias=self.contract.attention_bias)
         # ... and the form the chunk program's K/V write takes, by the
         # predicate the traced write asks.  A stat, not a third key of
         # kernel_modes: callers compare that dict whole
-        form = chunk_write_form(self.module, self.chunk, self.page)
+        form = chunk_write_form(self.contract, self.chunk, self.page)
         if form is not None:
             self.stats["chunk_write"] = form
         # chunk rows a prefill dispatch takes (docs/serving.md "Prefill
         # dispatches"): what the chunk kernel's bound holds of the chunk
         # the user set, 1 where rows would depend on each other through
         # more than the K/V pages — observed, not set
-        self.chunk_rows = chunk_rows(self.module, self.chunk, self.page,
+        self.chunk_rows = chunk_rows(self.contract, self.chunk, self.page,
                                      self.speculative)
         self._decode_fn = self._propose_fn = self._verify_fn = None
         self._draft_chunk_fn = self._draft_admit_fn = None
@@ -503,8 +493,8 @@ class ServingEngine:
         self._spec_fn = None
         if self.self_draft:
             self._spec_fn = make_spec_block_fn(
-                self.module, sample_fn, engine._deq, self.block,
-                self.cache_len)
+                self.module, self.contract, sample_fn, engine._deq,
+                self.block, self.cache_len)
             engine._tags[id(self._spec_fn)] = (
                 "serving_spec_block", self.num_slots, self.num_pages,
                 self.page, self.block, sampling_key)
@@ -517,8 +507,8 @@ class ServingEngine:
                 self.page, self.spec_k, sampling_key)
         else:
             self._decode_fn = make_decode_block_fn(
-                self.module, sample_fn, engine._deq, self.block,
-                self.cache_len)
+                self.module, self.contract, sample_fn, engine._deq,
+                self.block, self.cache_len)
             engine._tags[id(self._decode_fn)] = (
                 "serving_decode", self.num_slots, self.num_pages,
                 self.page, self.block, sampling_key)
@@ -564,8 +554,8 @@ class ServingEngine:
         # executables (tests/unit/test_serving_slo.py).
         # Prefill writes straight into the slot's pool pages (the pool
         # chains chunk -> decode by donation).
-        self._chunk_fn = make_chunk_fn(self.module, engine._deq,
-                                       self.self_draft)
+        self._chunk_fn = make_chunk_fn(self.module, self.contract,
+                                       engine._deq, self.self_draft)
         engine._tags[id(self._chunk_fn)] = (
             "serving_prefill", self.chunk, self.page, self.chunk_rows)
         for fn in (self._decode_fn, self._admit_fn, self._chunk_fn,
@@ -662,22 +652,19 @@ class ServingEngine:
             # expert's tokens summed likewise — and, outside ``stats``
             # (every value there is one /metrics gauge), the assignments
             # by expert layer and expert
-            from deepspeed_tpu.models.transformer import _is_moe_layer
-            mc = self.module.config
             self.stats.update({"moe_assignments": 0,
                                "moe_experts_touched": 0,
                                "moe_max_expert_tokens": 0})
             # a model that holds a share of the experts also reports the
             # choices that fell on the experts other chips hold
-            self._moe_share = holds_share(self.module)
+            self._moe_share = self.contract.holds_share
             if self._moe_share:
                 self.stats["moe_assignments_elsewhere"] = 0
             # the load vector's rows: the model's expert layers, then —
             # self-drafting — its drafting module's
             self.moe_expert_tokens = np.zeros(
-                (sum(_is_moe_layer(mc, i) for i in range(mc.num_layers))
-                 + self._draft_layers,
-                 mc.moe_num_experts), np.int64)  # guarded-by: _lock
+                (self.contract.expert_layers + self._draft_layers,
+                 self.contract.experts), np.int64)  # guarded-by: _lock
         # the slot-occupancy trace the correctness test asserts
         # EOS-mid-flight retirement against
         self.occupancy_trace = []        # (it, n_active)  # guarded-by: _lock
@@ -745,14 +732,15 @@ class ServingEngine:
             install_concurrency_checks(self)
 
     @staticmethod
-    def _resolve_draft(engine, cfg, draft_module, draft_params):
+    def _resolve_draft(engine, target, cfg, draft_module, draft_params):
         """The draft model behind ``serving.speculative``: an explicitly
         passed ``(draft_module, draft_params)`` pair wins;
         ``spec_draft_model="self"`` drafts with the target model itself
         (accept rate 1.0 under greedy — the dispatch/batched-verify
         ceiling, at the cost of a second full-size KV cache and a
         doubled decode forward); an OPT preset name builds the
-        architecture against the target's vocab and uses the given
+        architecture against the target's vocab (``target``: its
+        contract) and uses the given
         ``draft_params`` — or RANDOM weights with a loud warning
         (accept rate ~0; smoke/bench floor only).  Float draft params
         are cast to the engine's compute dtype like ``set_params``
@@ -776,13 +764,10 @@ class ServingEngine:
                     "target drafts for itself; docs/serving.md "
                     "'Speculative decoding')")
             from deepspeed_tpu.models.opt import opt_model
-            tcfg = getattr(engine.module, "config", None)
             draft_module = opt_model(
-                name,
-                vocab_size=getattr(tcfg, "vocab_size", 50272),
-                max_seq_len=max(getattr(tcfg, "max_seq_len", 2048),
-                                int(cfg.max_cache_len)),
-                dtype=getattr(tcfg, "dtype", "bfloat16"))
+                name, vocab_size=target.vocab_size,
+                max_seq_len=max(target.max_seq_len, int(cfg.max_cache_len)),
+                dtype=target.dtype)
             if draft_params is None:
                 logger.warning(
                     f"serving.spec_draft_model={name!r} with no "
@@ -2178,8 +2163,7 @@ class ServingEngine:
             starts[r] = p.start + ci * C
             last[r] = min(max(p.fill_len - 1 - starts[r], 0), C - 1)
             for key, val in self._pages.chunk_reach(
-                    self.module.config.num_layers + self._draft_layers,
-                    int(starts[r]) + C,
+                    self._layers, int(starts[r]) + C,
                     live_end=p.fill_len).items():
                 work[key] = work.get(key, 0) + val
         p0, ci0 = rows[0]
@@ -2443,7 +2427,7 @@ class ServingEngine:
             block *= 2
         return {"kv_positions": sum(steps * first + steps * (steps - 1) // 2
                                     for first, steps in work),
-                **self._pages.block_reach(work, block)}
+                **self._pages.block_reach(self._layers, work, block)}
 
     def _dispatch_spec_block(self, sub):  # lock-held: _lock
         """``decode_block`` self-drafted verify windows in ONE dispatch

@@ -291,12 +291,12 @@ class SlotPages:
     ``prefix_lookups`` / ``prefix_hits`` / ``prefix_tokens_reused`` /
     ``page_evictions`` entries the admissions are counted into."""
 
-    def __init__(self, module, num_slots, cache_len, page_size, num_pages,
-                 chunk, share_prefixes, stats):
+    def __init__(self, module, contract, num_slots, cache_len, page_size,
+                 num_pages, chunk, share_prefixes, stats):
         self.page = page_rows(page_size)
-        # positions a lane row stands for (1 for every model but one that
-        # names a ``lane_stride``): all page arithmetic below is in rows
-        self.stride = int(getattr(module, "lane_stride", 1))
+        # positions a lane row stands for: all page arithmetic below is in
+        # rows
+        self.stride = int(contract.lane_stride)
         self.pages_per_slot = self._lane_pages(cache_len)
         self.cache_len = self.pages_per_slot * self.page * self.stride
         self.num_slots = int(num_slots)
@@ -309,20 +309,16 @@ class SlotPages:
         self.share_prefixes = bool(share_prefixes)
         self._stats = stats
         self._module = module
-        # layers a model's own work counters sum over, where that is more
-        # than the model's (the engine sets it under self-drafting: the
-        # drafting module's layer rides every dispatch)
-        self.work_layers = None
+        self._contract = contract
         self._buffer = None              # the device pool, between uses
         self._pool = PagePool(self.num_pages)
         self._prefix = PrefixIndex()
-        # a model with window layers (``window_ring_pages``) keeps their
-        # rows in a pool of its own: every slot owns ``ring_pages`` pages
+        # a model with window layers (the contract's ``ring_pages``) keeps
+        # their rows in a pool of its own: every slot owns that many pages
         # there for good — a ring over the window's positions, whatever
         # the prompt's length — behind one trash page; the slot's table
         # row carries them after its lane pages
-        self.ring_pages = int(getattr(
-            module, "window_ring_pages", lambda page: 0)(self.page))
+        self.ring_pages = int(contract.ring_pages(self.page))
         self.window_pages = 1 + self.num_slots * self.ring_pages \
             if self.ring_pages else 0
         # a model whose layers keep a fixed-size state a slot names the
@@ -332,7 +328,7 @@ class SlotPages:
         # the row's index is the LAST entry of the slot's table row — so
         # whatever sends a dead lane's pages to the trash page sends its
         # state writes to the trash row
-        self.state_kinds = tuple(getattr(module, "state_kinds", ()))
+        self.state_kinds = tuple(contract.state_kinds)
         self.state_rows = 1 + self.num_slots if self.state_kinds else 0
         self.state_row_bytes = 0         # known once the pools are made
         self.page_bytes = 0
@@ -554,9 +550,7 @@ class SlotPages:
         if self.ring_pages:
             held = int((self._table[:, self.pages_per_slot]
                         != TRASH_PAGE).sum())
-            lane_rows, ring_rows = getattr(
-                self._module, "row_kinds",
-                ("latent + index rows", "window rows"))
+            lane_rows, ring_rows = self._contract.row_kinds
             text += (f"; by row kind: {lane_rows} "
                      f"{self._pool.in_use} pages, {ring_rows} "
                      f"{held * self.ring_pages}/{self.window_pages - 1} "
@@ -583,22 +577,24 @@ class SlotPages:
         chunk's furthest position, which is the paged chunk-prefill
         kernel's block loop — and ``kv_pages_table``, pages a slot x
         layers, what a walk over the whole table would take.  A model
-        that counts its own attention work (a ``chunk_work`` method, the
-        names its own) adds it, over the chunk's REAL positions — through
-        ``live_end - 1``, the padded tail left out."""
+        that counts its own attention work (the contract's ``chunk_work``,
+        the names its own) adds it, over the chunk's REAL positions —
+        through ``live_end - 1``, the padded tail left out."""
         reach = self._lane_pages(end)
-        work = getattr(self._module, "chunk_work", None)
+        work = self._contract.chunk_work
         return {"kv_pages": layers * min(reach, self.pages_per_slot),
                 "kv_pages_table": layers * self.pages_per_slot,
                 **({"state_rows": 1} if self.state_kinds else {}),
                 **(work(end - self.chunk, min(end, live_end or end),
-                        self.page, self.ring_pages, **self._work_kw())
+                        self.page, self.ring_pages, layers)
                    if work else {})}
 
-    def block_reach(self, live, block):
+    def block_reach(self, layers, live, block):
         """What a decode block of ``block`` steps walks, as its dispatch
         span's args, from ``live`` — ``(context, steps)`` per live slot,
-        the positions its first step attends and the steps it takes:
+        the positions its first step attends and the steps it takes
+        (``layers``: the layers the dispatch runs, what the model's own
+        ``block_work`` sums over):
         ``kv_pages``, ``ceil(context / page_size)`` a live slot and
         step, which is the paged-decode kernel's page loop;
         ``kv_folds``, the online-softmax updates that loop makes of
@@ -607,7 +603,7 @@ class SlotPages:
         update really carried); and ``kv_pages_table``, the slots x
         pages-a-slot x steps a walk over the whole table would take —
         ``kv_pages`` over it is the share of the table that is live."""
-        work = getattr(self._module, "block_work", None)
+        work = self._contract.block_work
         pages = [self._lane_pages(first + i)
                  for first, steps in live for i in range(steps)]
         return {"kv_pages": sum(pages),
@@ -615,11 +611,7 @@ class SlotPages:
                 "kv_pages_table":
                     self.num_slots * self.pages_per_slot * block,
                 **(self._state_reach(live) if self.state_kinds else {}),
-                **(work(live, self.ring_pages, **self._work_kw())
-                   if work else {})}
-
-    def _work_kw(self):
-        return {"layers": self.work_layers} if self.work_layers else {}
+                **(work(live, self.ring_pages, layers) if work else {})}
 
     def _state_reach(self, live):
         """A decode block's state work and the cache's split, as span

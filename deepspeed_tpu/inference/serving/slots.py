@@ -30,7 +30,7 @@ The programs:
   slot's state entries in-program — so the host scheduler never
   synchronizes inside the admission path.
 * :func:`make_spec_block_fn` — self-drafting (a model with a
-  multi-token-prediction module of its own, :func:`drafts_itself`):
+  multi-token-prediction module of its own: ``contract.drafts_itself``):
   ``block`` verify windows of two rows a lane in one program, the module
   drafting from the pools' own last layer; the chunk and admit programs
   carry the module's rows and the first draft.
@@ -74,32 +74,6 @@ def init_slot_state(num_slots, draft=False):
     return state
 
 
-def routes_experts(module):
-    """True for a model with DROPLESS expert layers
-    (``TransformerConfig.moe_capacity_factor=None``, ``moe/dropless.py``)
-    — the models whose slot programs mask dead tokens out of the routing
-    and return the expert load (docs/serving.md "Expert models")."""
-    cfg = getattr(module, "config", None)
-    return getattr(cfg, "moe_num_experts", 0) > 0 \
-        and getattr(cfg, "moe_capacity_factor", 1.0) is None
-
-
-def holds_share(module):
-    """True for a model that holds a SHARE of each expert layer's experts
-    (``held_experts``, ``moe/layer.py``): its counts carry one more column,
-    the live choices that fell on absent experts."""
-    return getattr(getattr(module, "config", None), "held_experts",
-                   None) is not None
-
-
-def drafts_itself(module):
-    """True for a model with a multi-token-prediction module of its own: a
-    ``draft`` method beside ``decode`` (``models/glm5.py``) — what
-    ``serving.spec_draft_model: "mtp"`` asks for."""
-    return hasattr(type(module), "draft") and getattr(
-        getattr(module, "config", None), "mtp_layers", 0) > 0
-
-
 def _sown_counts(sown, share):
     """``counts [expert layers, experts (+ 1)]`` from what the expert
     layers of one ``apply`` sowed, in layer order: every ``moe_mlp`` under
@@ -125,23 +99,23 @@ def _sown_counts(sown, share):
 
 
 def _decode(module, variables, ids, cache, pos, live=None, method=None,
-            **kw):
+            share=False, **kw):
     """``module.decode`` as the slot programs call it: ``(logits, cache,
     counts)`` — ``(logits, hidden, cache, counts)`` where ``hidden=True``
     asks the model for its rows' last hidden state too.  Dense model
     (``live`` None): the plain call, ``counts`` None.  Expert model: only
     ``live [B, S]`` tokens are routed, and ``counts [expert layers,
     experts]`` int32 are the (token, expert) assignments each expert layer
-    computed — what its ``MoE`` sows.  ``method``: another method of the
-    same call form (a self-drafting model's ``draft``, whose first
-    argument is the hidden state)."""
+    computed — what its ``MoE`` sows (``share``: :func:`_sown_counts`).
+    ``method``: another method of the same call form (a self-drafting
+    model's ``draft``, whose first argument is the hidden state)."""
     method = method or type(module).decode
     if live is None:
         return module.apply(variables, ids, cache, pos, method=method,
                             **kw) + (None,)
     out, sown = module.apply(variables, ids, cache, pos, method=method,
                              live=live, mutable=["moe_stats"], **kw)
-    return out + (_sown_counts(sown, holds_share(module)),)
+    return out + (_sown_counts(sown, share),)
 
 
 def _expert_load(counts, share=False):
@@ -152,7 +126,7 @@ def _expert_load(counts, share=False):
     calls), then ``touched`` (experts with a live token, summed over
     layers and calls — each is one expert's weights read) and
     ``max_tokens`` (the busiest expert's tokens, summed likewise).
-    ``share`` (:func:`holds_share`): the counts' last column is the
+    ``share`` (``contract.holds_share``): the counts' last column is the
     choices of absent experts — their sum goes between the held experts'
     tokens and ``touched``."""
     with jax.named_scope("slots.expert_load"):
@@ -164,8 +138,8 @@ def _expert_load(counts, share=False):
                jnp.sum(jnp.max(held, axis=-1))[None]])
 
 
-def make_decode_block_fn(module, sample_fn, param_transform, block,
-                         cache_len):
+def make_decode_block_fn(module, contract, sample_fn, param_transform,
+                         block, cache_len):
     """The single reusable decode-step program:
     ``fn(params, cache, state, pages, rng) -> (tokens [block, N], cache,
     state)`` with the page POOL and the slot state donated (argnums 1, 2)
@@ -184,13 +158,12 @@ def make_decode_block_fn(module, sample_fn, param_transform, block,
     as masked no-ops for at most ``block - 1`` steps until the host
     scheduler reclaims them.
 
-    For a model with dropless expert layers (:func:`routes_experts`) a
-    lane that is not ``active`` at a step is routed to no expert — it
+    For a model with dropless expert layers (``contract.routes_experts``)
+    a lane that is not ``active`` at a step is routed to no expert — it
     reads no expert's weights and is not counted — and the program
     returns a fourth output, the block's :func:`_expert_load`."""
     deq = param_transform if param_transform is not None else (lambda p: p)
-    routed = routes_experts(module)
-    share = holds_share(module)
+    routed, share = contract.routes_experts, contract.holds_share
 
     @hot_path("serving.decode_step")
     def decode_block(params, cache, state, pages, rng):
@@ -209,7 +182,7 @@ def make_decode_block_fn(module, sample_fn, param_transform, block,
             logits, cache, counts = _decode(
                 module, deq(params), tok[:, None],
                 {**cache, "pages": safe_pages},
-                pos, live=active[:, None] if routed else None)
+                pos, live=active[:, None] if routed else None, share=share)
             with jax.named_scope("head.sample"):
                 rng, sub = jax.random.split(rng)
                 nxt = sample_fn(logits[:, -1], sub).astype(jnp.int32)
@@ -237,34 +210,33 @@ def make_decode_block_fn(module, sample_fn, param_transform, block,
     return jax.jit(decode_block, donate_argnums=(1, 2))
 
 
-def admission_chunk(module, prefill_chunk):
+def admission_chunk(contract, prefill_chunk):
     """``serving.prefill_chunk`` as the server runs it: aligned like the
-    engine's ``prefill_chunk_size`` (multiple of 8, floor 8, cap 512 — the
-    chunk kernel's bounds; a model whose chunk path has other bounds
-    names its own cap, and one whose chunk must fit its cache's geometry —
-    ``models/evabyte.py``: no chunk straddles a window — says why a chunk
-    does not, ``prefill_chunk_fault``)."""
-    chunk = min(getattr(module, "prefill_chunk_cap", 512),
-                max(8, -(-int(prefill_chunk) // 8) * 8))
-    fault = getattr(module, "prefill_chunk_fault", lambda chunk: None)(chunk)
+    engine's ``prefill_chunk_size`` (multiple of 8, floor 8), capped at the
+    contract's ``chunk_cap``; a model whose chunk must fit its cache's
+    geometry — ``models/evabyte.py``: no chunk straddles a window — says
+    why a chunk does not (``chunk_fault``)."""
+    chunk = min(contract.chunk_cap, max(8, -(-int(prefill_chunk) // 8) * 8))
+    fault = contract.chunk_fault(chunk)
     if fault:
         raise ValueError(f"serving.prefill_chunk={prefill_chunk}: {fault}")
     return chunk
 
 
-def chunk_write_form(module, chunk, page):
+def chunk_write_form(contract, chunk, page):
     """The form in which :func:`make_chunk_fn`'s program writes a chunk's
     K/V into the pool: ``registry.paged_write_form`` at the server's
     chunk and page under the marker that program sets — what the traced
     write asks — or ``None`` for a model whose pools hold no K/V pages
-    (latent attention writes its own rows, ``models/dots3.py``)."""
+    (``kv_pages`` False: latent attention writes its own rows,
+    ``models/dots3.py``)."""
     from deepspeed_tpu.ops.transformer.registry import paged_write_form
-    if "k" not in jax.eval_shape(lambda: module.init_paged_cache(2, page)):
+    if not contract.kv_pages:
         return None
     return paged_write_form(chunk, page, page_runs=True)
 
 
-def chunk_rows(module, chunk, page, speculative=False):
+def chunk_rows(contract, chunk, page, speculative=False):
     """How many ``chunk``-token rows one dispatch of :func:`make_chunk_fn`'s
     program takes: as many as the chunk kernel's bound holds
     (``registry.MAX_CHUNK_S // chunk`` — 4 at a chunk of 128), so one pass
@@ -274,20 +246,17 @@ def chunk_rows(module, chunk, page, speculative=False):
     writes every row's K/V before any row attends.  ONE row — the
     scalar-``start`` program — where they depend through more: per-slot
     state (``state_kinds``) or a chunk geometry of the model's own
-    (``prefill_chunk_cap`` / ``prefill_chunk_fault``: windows, latent
-    lanes), dropless experts (the load vector is a request's,
-    :func:`_expert_load`), and under speculation (the draft lane mirrors
-    one chunk at a time)."""
+    (``own_chunk_path``: windows, latent lanes), dropless experts (the
+    load vector is a request's, :func:`_expert_load`), and under
+    speculation (the draft lane mirrors one chunk at a time)."""
     from deepspeed_tpu.ops.transformer.registry import MAX_CHUNK_S
-    own_path = (routes_experts(module) or speculative
-                or getattr(module, "state_kinds", ())
-                or hasattr(module, "prefill_chunk_cap")
-                or hasattr(module, "prefill_chunk_fault")
-                or chunk_write_form(module, chunk, page) != "page_runs")
+    own_path = (contract.routes_experts or speculative
+                or contract.state_kinds or contract.own_chunk_path
+                or chunk_write_form(contract, chunk, page) != "page_runs")
     return 1 if own_path else max(1, MAX_CHUNK_S // chunk)
 
 
-def make_chunk_fn(module, param_transform, self_draft=False):
+def make_chunk_fn(module, contract, param_transform, self_draft=False):
     """The admission-prefill chunk program:
     ``fn(params, cache, pages, chunk_ids, start, logits_at)`` — same
     body as the engine's per-chunk program (``generate()``'s split
@@ -309,11 +278,11 @@ def make_chunk_fn(module, param_transform, self_draft=False):
     ``logits_at`` is each chunk's LAST REAL row (the scheduler passes
     ``chunk - 1`` for a whole chunk and the prompt's last token for the
     final one), so the rows past it are the padded tail.  For a model
-    with dropless expert layers (:func:`routes_experts`) the tail is
+    with dropless expert layers (``contract.routes_experts``) the tail is
     routed to no expert, and the program returns ``(logits, cache,
     load)`` with the chunk's :func:`_expert_load`.
 
-    ``self_draft`` (:func:`drafts_itself`; ``R`` = 1): the chunk also
+    ``self_draft`` (the model ``drafts_itself``; ``R`` = 1): the chunk also
     fills the multi-token-prediction module's rows — row ``t`` from the
     main model's ``h_t`` and token ``t + 1``.  The token after the chunk's
     LAST real position is not in the chunk: a seventh argument, ``next_id
@@ -323,8 +292,7 @@ def make_chunk_fn(module, param_transform, self_draft=False):
     One more output, ``draft [1]``: the module's guess after that token,
     the slot's first pending draft (read of the last chunk only)."""
     deq = param_transform if param_transform is not None else (lambda p: p)
-    routed = routes_experts(module)
-    share = holds_share(module)
+    routed, share = contract.routes_experts, contract.holds_share
 
     @hot_path("serving.prefill_chunk")
     def chunk_step(params, cache, pages, chunk_ids, start, logits_at,
@@ -342,7 +310,7 @@ def make_chunk_fn(module, param_transform, self_draft=False):
             module, deq(params), chunk_ids,
             {**cache, "pages": pages,
              "page_runs": jnp.zeros((), jnp.int32)}, start, live=live,
-            logits_at=logits_at)
+            share=share, logits_at=logits_at)
         if routed:
             return logits, cache, _expert_load(counts[None], share)
         return logits, cache
@@ -354,7 +322,7 @@ def make_chunk_fn(module, param_transform, self_draft=False):
                                "page_runs": jnp.zeros((), jnp.int32)}
         logits, hidden, cache, counts = _decode(
             module, variables, chunk_ids, paged(cache), start, live=live,
-            logits_at=logits_at, hidden=True)
+            share=share, logits_at=logits_at, hidden=True)
         first = jnp.argmax(logits[:, 0].astype(jnp.float32), axis=-1)
         after = jnp.where(next_id >= 0, next_id, first.astype(jnp.int32))
         at_last = jnp.arange(chunk_ids.shape[1])[None, :] \
@@ -363,7 +331,8 @@ def make_chunk_fn(module, param_transform, self_draft=False):
                         jnp.roll(chunk_ids, -1, axis=1))
         guess, cache, drafted = _decode(
             module, variables, nxt, paged(cache), start, live=live,
-            method=type(module).draft, hidden=hidden, logits_at=logits_at)
+            method=type(module).draft, share=share, hidden=hidden,
+            logits_at=logits_at)
         draft = jnp.argmax(guess[:, 0].astype(jnp.float32),
                            axis=-1).astype(jnp.int32)
         if routed:
@@ -516,9 +485,9 @@ def make_spec_verify_fn(module, sample_fn, param_transform, k, cache_len):
     return jax.jit(verify, donate_argnums=(1, 2))
 
 
-def make_spec_block_fn(module, sample_fn, param_transform, block,
+def make_spec_block_fn(module, contract, sample_fn, param_transform, block,
                        cache_len):
-    """The self-drafting decode program (:func:`drafts_itself`):
+    """The self-drafting decode program (a model that ``drafts_itself``):
     ``fn(params, cache, state, pages, rng) -> (tokens [block, 2, N],
     accepted [block, N], cache, state[, load])`` — ``block`` verify
     WINDOWS in one program, as the decode block carries steps; the pool and
@@ -550,8 +519,7 @@ def make_spec_block_fn(module, sample_fn, param_transform, block,
     costs), and ``load`` is the block's :func:`_expert_load` over the main
     model's expert layers and then the module's."""
     deq = param_transform if param_transform is not None else (lambda p: p)
-    routed = routes_experts(module)
-    share = holds_share(module)
+    routed, share = contract.routes_experts, contract.holds_share
 
     @hot_path("serving.spec_block")
     def spec_block(params, cache, state, pages, rng):
@@ -568,7 +536,7 @@ def make_spec_block_fn(module, sample_fn, param_transform, block,
             ids = jnp.stack([state["token"], draft], axis=1)
             logits, hidden, cache, counts = _decode(
                 module, variables, ids, paged(cache), pos, live=live,
-                hidden=True)
+                share=share, hidden=True)
             with jax.named_scope("head.sample"):
                 rng, *subs = jax.random.split(rng, 3)
                 t = jnp.stack(
@@ -579,7 +547,7 @@ def make_spec_block_fn(module, sample_fn, param_transform, block,
                     t, draft[:, None], state, 1, cache_len)
             guess, cache, drafted = _decode(
                 module, variables, t, paged(cache), pos, live=live,
-                method=type(module).draft, hidden=hidden)
+                method=type(module).draft, share=share, hidden=hidden)
             with jax.named_scope("slots.state"):
                 guess = jnp.argmax(guess.astype(jnp.float32),
                                    axis=-1).astype(jnp.int32)

@@ -30,6 +30,7 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import flax.linen as nn
 
+from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.models.latent_attention import (LatentSpec,
                                                    causal_pairs,
                                                    live_block_rows, padded)
@@ -56,23 +57,10 @@ class Dots3Config:
     rms_norm_eps: float = 1e-5
     held_experts: Optional[Tuple[int, int]] = None
     dtype: str = "bfloat16"
-    # what the slot engine reads off a model's config
-    position_embedding: str = "rope"
-    moe_capacity_factor: Optional[float] = None      # dropless
-    moe_every: int = 1
 
     @property
     def num_layers(self):
         return len(self.layer_types)
-
-    @property
-    def moe_layer_offset(self):
-        return self.first_k_dense
-
-    @property
-    def moe_num_experts(self):
-        """Experts this model HOLDS a layer (the load it reports)."""
-        return (self.held_experts or (0, self.n_routed_experts))[1]
 
     @property
     def jnp_dtype(self):
@@ -149,10 +137,6 @@ def dots3_model(hf, held_experts=None, **overrides):
 class Dots3Model(nn.Module):
     config: Dots3Config
 
-    # the slot engine's prefill chunk may be this long for this model
-    # (the paged chunk kernel of ``models/transformer.py`` stops at 512)
-    prefill_chunk_cap = 2048
-
     def setup(self):
         cfg = self.config
         self.embed_tokens = nn.Embed(cfg.vocab_size, cfg.hidden_size,
@@ -180,19 +164,33 @@ class Dots3Model(nn.Module):
         return jnp.stack(rows)
 
     # ---- the serving path ---- #
-    # of ``chunk_work`` / ``block_work``'s span args, those the server
-    # sums into ``srv.stats`` (``window_pages`` is a level, not a count)
-    work_counters = ("dsa_keys_scored", "dsa_keys_kept", "latent_rows_read",
-                     "window_keys")
+    def slot_contract(self):
+        """For the slot engine (``models/contract.py``): pools by row kind,
+        a ring a slot in the window layers, the latent kernels' own chunk
+        (up to 2048), the load of the experts this model HOLDS a layer."""
+        cfg = self.config
+        return SlotContract(
+            vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
+            dtype=cfg.dtype, num_layers=cfg.num_layers,
+            kv_pages=False, ring_pages=self._ring_pages,
+            row_kinds=("latent + index rows", "window rows"),
+            chunk_cap=2048, own_chunk_path=True,
+            routes_experts=True, holds_share=cfg.held_experts is not None,
+            expert_layers=cfg.num_layers - cfg.first_k_dense,
+            experts=(cfg.held_experts or (0, cfg.n_routed_experts))[1],
+            chunk_work=self._chunk_work, block_work=self._block_work,
+            work_counters=("dsa_keys_scored", "dsa_keys_kept",
+                           "latent_rows_read", "window_keys"),
+            work_levels=("latent_rows_decompressed", "window_pages"))
 
-    def window_ring_pages(self, page_size):
+    def _ring_pages(self, page_size):
         """Pages a slot's ring holds in each window layer: the window's
         ``W - 1`` predecessors, wherever they start in a page."""
         if not self.config.layers_of("sliding_attention"):
             return 0
         return -(-(self.config.window.window - 1) // page_size) + 1
 
-    def chunk_work(self, start, end, page_size, ring_pages):
+    def _chunk_work(self, start, end, page_size, ring_pages, layers):
         """What a prefill chunk over positions ``start .. end - 1`` does
         in this model's attention, as its dispatch span's args (summed
         over the layers of each kind): ``dsa_keys_scored`` — (query, key)
@@ -216,7 +214,7 @@ class Dots3Model(nn.Module):
                 "window_pages": ring_pages * swa,
                 "window_keys": swa * pairs(cfg.window.window)}
 
-    def block_work(self, live, ring_pages):
+    def _block_work(self, live, ring_pages, layers):
         """The same for a decode block, from ``live`` — ``(context, steps)``
         a live slot: a step scores its context and READS the kept rows
         only."""
@@ -260,7 +258,7 @@ class Dots3Model(nn.Module):
         cfg = self.config
         per_row = jnp.ndim(start_pos) == 1
         pages = cache["pages"]
-        ring = self.window_ring_pages(cache["window"].shape[2])
+        ring = self._ring_pages(cache["window"].shape[2])
         lane = pages.shape[1] - ring
         full_pools, window_pool = (cache["latent"], cache["index"]), \
             cache["window"]

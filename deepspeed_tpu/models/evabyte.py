@@ -31,7 +31,7 @@ biases.
 
 **The cache** (``paging.SlotPages``): two pools a layer behind the slot's
 ONE table row.  ``k`` / ``v [layers, 1 + slots x W / page, page, H x D]`` is
-a RING the slot owns for good (``window_ring_pages``): position ``p``'s row
+a RING the slot owns for good (``ring_pages``): position ``p``'s row
 is ring row ``p % W``, and the rows of the window before go dead ALL AT
 ONCE when ``p`` crosses a multiple of ``W`` — by the position mask, never
 by what was written.  ``ksum`` / ``vsum [layers, num_pages, page, H x D]``
@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.models.dots3 import _Mlp
 from deepspeed_tpu.models.latent_attention import _rms
 from deepspeed_tpu.models.transformer import _paged_write, _rope
@@ -79,8 +80,7 @@ class EvaByteConfig:
     max_seq_len: int
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
-    # what the slot engine reads off a model's config
-    position_embedding: str = "rope"
+    # what the attention registry reads off a config
     kv_cache_quant: bool = False
     decode_int8_matmuls: bool = False
 
@@ -337,13 +337,6 @@ class _Head(nn.Module):
 class EvaByteModel(nn.Module):
     config: EvaByteConfig
 
-    # what ``SlotPages.describe()`` calls the lane's and the ring's rows
-    row_kinds = ("summary rows", "ring rows")
-    # of ``chunk_work`` / ``block_work``'s span args, those the server
-    # sums into ``srv.stats`` (the two ``*_bytes_*`` are levels)
-    work_counters = ("eva_ring_rows", "eva_summary_rows", "eva_local_pairs",
-                     "eva_remote_pairs", "eva_summaries_written")
-
     def setup(self):
         cfg = self.config
         self.embed_tokens = nn.Embed(cfg.vocab_size, cfg.hidden_size,
@@ -369,27 +362,33 @@ class EvaByteModel(nn.Module):
         return jnp.stack(rows)
 
     # ---- the serving path ---- #
-    @property
-    def prefill_chunk_cap(self):
-        """The slot engine's prefill chunk may be a whole window."""
-        return self.config.window_size
+    def slot_contract(self):
+        """For the slot engine (``models/contract.py``): one SUMMARY row a
+        chunk in the lane, one window's K/V ring a slot, a prefill chunk of
+        at most a window that straddles none."""
+        cfg = self.config
+        return SlotContract(
+            vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
+            dtype=cfg.dtype, num_layers=cfg.num_layers,
+            lane_stride=cfg.chunk_size, ring_pages=self._ring_pages,
+            row_kinds=("summary rows", "ring rows"),
+            chunk_cap=cfg.window_size, chunk_fault=self._chunk_fault,
+            own_chunk_path=True,
+            chunk_work=self._chunk_work, block_work=self._block_work,
+            work_counters=("eva_ring_rows", "eva_summary_rows",
+                           "eva_local_pairs", "eva_remote_pairs",
+                           "eva_summaries_written"),
+            work_levels=("ring_bytes_held", "summary_bytes_mapped"))
 
-    def prefill_chunk_fault(self, chunk):
-        """... and must not straddle one (``slots.admission_chunk``): why
-        ``chunk`` cannot be the prefill chunk, or None."""
+    def _chunk_fault(self, chunk):
+        """Why ``chunk`` cannot be the prefill chunk, or None."""
         c, W = self.config.chunk_size, self.config.window_size
         if W % chunk or chunk % c:
             return (f"an evabyte prefill chunk divides window_size={W} and "
                     f"is whole chunks of chunk_size={c}; got {chunk}")
         return None
 
-    @property
-    def lane_stride(self):
-        """Positions a row of the slot's lane pages stands for: the lane
-        holds one SUMMARY row a chunk (``paging.SlotPages``)."""
-        return self.config.chunk_size
-
-    def window_ring_pages(self, page_size):
+    def _ring_pages(self, page_size):
         """Pages of a slot's K/V ring in each layer: one window."""
         cfg = self.config
         if cfg.window_size % page_size or page_size % cfg.chunk_size:
@@ -415,7 +414,7 @@ class EvaByteModel(nn.Module):
             p = end
         return local, remote
 
-    def chunk_work(self, start, end, page_size, ring_pages):
+    def _chunk_work(self, start, end, page_size, ring_pages, layers):
         """What a prefill chunk over REAL positions ``start .. end - 1``
         does in EVA attention, as its dispatch span's args (summed over the
         layers): ``eva_ring_rows`` / ``eva_summary_rows`` — K/V rows the
@@ -423,7 +422,7 @@ class EvaByteModel(nn.Module):
         whole pages; the window's visible summaries) —, ``eva_local_pairs``
         / ``eva_remote_pairs`` — (query, key) pairs under the softmax —,
         ``eva_summaries_written`` — whole real chunks pooled."""
-        cfg, L = self.config, self.config.num_layers
+        cfg, L = self.config, layers
         c, W = cfg.chunk_size, cfg.window_size
         local, remote = self._pairs(start, end)
         return {"eva_ring_rows": L * ((end - 1) % W + 1),
@@ -432,14 +431,14 @@ class EvaByteModel(nn.Module):
                 "eva_remote_pairs": L * remote,
                 "eva_summaries_written": L * (end // c - start // c)}
 
-    def block_work(self, live, ring_pages):
+    def _block_work(self, live, ring_pages, layers):
         """The same for a decode block, from ``live`` — ``(context,
         steps)`` a live slot, ``context`` the positions the first step
         attends (its own among them) — plus the cache's split:
         ``ring_bytes_held`` — the rings of the live slots, held whole —
         and ``summary_bytes_mapped`` — the lane pages their summaries
         reach."""
-        cfg, L = self.config, self.config.num_layers
+        cfg, L = self.config, layers
         c, W = cfg.chunk_size, cfg.window_size
         page = W // max(ring_pages, 1)
         page_bytes = 2 * page * cfg.hidden_size * cfg.jnp_dtype.itemsize
@@ -485,7 +484,7 @@ class EvaByteModel(nn.Module):
                 "verify window would write ring rows and summaries for "
                 "positions it may reject (serving.speculative)")
         pages = cache["pages"]
-        n_ring = self.window_ring_pages(cache["k"].shape[2])
+        n_ring = self._ring_pages(cache["k"].shape[2])
         lane, ring = pages[:, :-n_ring], pages[:, -n_ring:]
         pools = {n: cache[n] for n in ("k", "v", "ksum", "vsum")}
         x = self.embed_tokens(input_ids[:, 0] if per_row else input_ids[0]) \

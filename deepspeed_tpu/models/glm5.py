@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.models.latent_attention import (LatentSpec,
                                                    causal_pairs,
                                                    live_block_rows, padded)
@@ -77,19 +78,6 @@ class Glm5Config:
     rms_norm_eps: float = 1e-5
     held_experts: Optional[Tuple[int, int]] = None
     dtype: str = "bfloat16"
-    # what the slot engine reads off a model's config
-    position_embedding: str = "rope"
-    moe_capacity_factor: Optional[float] = None      # dropless
-    moe_every: int = 1
-
-    @property
-    def moe_layer_offset(self):
-        return self.first_k_dense
-
-    @property
-    def moe_num_experts(self):
-        """Experts this model HOLDS a layer (the load it reports)."""
-        return (self.held_experts or (0, self.n_routed_experts))[1]
 
     @property
     def jnp_dtype(self):
@@ -175,12 +163,6 @@ class Glm5Mtp(nn.Module):
 class Glm5Model(nn.Module):
     config: Glm5Config
 
-    # the slot engine's prefill chunk may be this long for this model
-    prefill_chunk_cap = 2048
-    # of ``chunk_work`` / ``block_work``'s span args, those the server sums
-    # into ``srv.stats``
-    work_counters = ("dsa_keys_scored", "dsa_keys_kept", "latent_rows_read")
-
     def setup(self):
         cfg = self.config
         self.embed_tokens = nn.Embed(cfg.vocab_size, cfg.hidden_size,
@@ -193,12 +175,6 @@ class Glm5Model(nn.Module):
                                 dtype=cfg.jnp_dtype)
         if cfg.mtp_layers:
             self.mtp = Glm5Mtp(cfg)
-
-    @property
-    def draft_expert_layers(self):
-        """Expert layers :meth:`draft` runs (the slot programs' load
-        vector has their rows after the main model's)."""
-        return self.config.mtp_layers
 
     def __call__(self, batch, drafts=False):
         """Logits ``[B, S, V]`` of ``batch["input_ids"] [B, S]``: the plain
@@ -225,10 +201,29 @@ class Glm5Model(nn.Module):
         return jnp.stack(rows)
 
     # ---- the serving path ---- #
-    def chunk_work(self, start, end, page_size, ring_pages, layers=None):
+    def slot_contract(self):
+        """For the slot engine (``models/contract.py``): latent rows and no
+        K/V pages, the latent kernels' own chunk (up to 2048), the load of
+        the experts this model HOLDS a layer, and the multi-token-prediction
+        module's layers (expert layers, the pools' last)."""
+        cfg = self.config
+        return SlotContract(
+            vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
+            dtype=cfg.dtype, num_layers=cfg.num_layers,
+            kv_pages=False, chunk_cap=2048, own_chunk_path=True,
+            routes_experts=True, holds_share=cfg.held_experts is not None,
+            expert_layers=cfg.num_layers - cfg.first_k_dense,
+            experts=(cfg.held_experts or (0, cfg.n_routed_experts))[1],
+            draft_layers=cfg.mtp_layers,
+            chunk_work=self._chunk_work, block_work=self._block_work,
+            work_counters=("dsa_keys_scored", "dsa_keys_kept",
+                           "latent_rows_read"),
+            work_levels=("latent_rows_decompressed",))
+
+    def _chunk_work(self, start, end, page_size, ring_pages, layers):
         """What a prefill chunk over positions ``start .. end - 1`` does in
         this model's attention, as its dispatch span's args, summed over
-        ``layers`` (default the main model's): ``dsa_keys_scored`` —
+        the ``layers`` the dispatch ran: ``dsa_keys_scored`` —
         (query, key) pairs the indexer scores, the causal ones —,
         ``dsa_keys_kept`` — pairs the softmax runs over —,
         ``latent_rows_read`` — latent rows fetched from the pool (the
@@ -237,14 +232,13 @@ class Glm5Model(nn.Module):
         blocks, whole, not the lane (a padded last chunk's blocks past
         ``end`` run too and are not counted)."""
         cfg = self.config
-        layers = layers or cfg.num_layers
         pairs = lambda limit: causal_pairs(start, end, limit)
         return {"dsa_keys_scored": layers * pairs(end),
                 "dsa_keys_kept": layers * pairs(cfg.attn.index_topk),
                 "latent_rows_read": layers * -(-end // page_size) * page_size,
                 "latent_rows_decompressed": layers * live_block_rows(end)}
 
-    def block_work(self, live, ring_pages, layers=None):
+    def _block_work(self, live, ring_pages, layers):
         """The same for the rows of a decode dispatch, from ``live`` —
         ``(context, rows)`` a live slot, the rows at consecutive positions:
         a row scores its context and attends its kept rows
@@ -252,7 +246,6 @@ class Glm5Model(nn.Module):
         reads a lane's live rows once for all its rows, which the span's
         ``kv_pages`` counts — ``LatentAttention.window``)."""
         cfg = self.config
-        layers = layers or cfg.num_layers
         contexts = [first + i for first, rows in live for i in range(rows)]
         kept = sum(min(c, cfg.attn.index_topk) for c in contexts)
         return {"dsa_keys_scored": layers * sum(contexts),

@@ -27,12 +27,13 @@ have no VJP).
 """
 
 import dataclasses
-from typing import Any, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.models.dots3 import _Mlp, _Norm
 from deepspeed_tpu.models.latent_attention import _rms
 from deepspeed_tpu.models.transformer import _rope, reference_attention
@@ -60,10 +61,7 @@ class Lfm2Config:
     max_seq_len: int
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
-    # what the slot engine and the attention registry read off a config
-    position_embedding: str = "rope"
-    moe_capacity_factor: Any = None              # dropless
-    moe_every: int = 1
+    # what the attention registry reads off a config
     kv_cache_quant: bool = False
     decode_int8_matmuls: bool = False
 
@@ -74,14 +72,6 @@ class Lfm2Config:
     @property
     def head_dim(self):
         return self.hidden_size // self.num_heads
-
-    @property
-    def moe_layer_offset(self):
-        return self.num_dense_layers
-
-    @property
-    def moe_num_experts(self):
-        return self.num_experts
 
     @property
     def jnp_dtype(self):
@@ -256,10 +246,6 @@ class Lfm2Layer(nn.Module):
 class Lfm2Model(nn.Module):
     config: Lfm2Config
 
-    # the cache keys a slot's STATE ROW indexes (``paging.SlotPages``):
-    # every other pool is indexed by the page table
-    state_kinds = ("conv",)
-
     def setup(self):
         cfg = self.config
         self.embed_tokens = nn.Embed(cfg.vocab_size, cfg.hidden_size,
@@ -289,6 +275,19 @@ class Lfm2Model(nn.Module):
         return jnp.stack(rows)
 
     # ---- the serving path ---- #
+    def slot_contract(self):
+        """For the slot engine (``models/contract.py``): the conv layers'
+        state behind the slot's STATE ROW (``paging.SlotPages``) beside the
+        attention layers' K/V pages; dropless experts after the dense
+        layers."""
+        cfg = self.config
+        return SlotContract(
+            vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
+            dtype=cfg.dtype, num_layers=cfg.num_layers,
+            state_kinds=("conv",), routes_experts=True,
+            expert_layers=cfg.num_layers - cfg.num_dense_layers,
+            experts=cfg.num_experts)
+
     def init_paged_cache(self, num_pages, page_size, dtype=None,
                          state_rows=1):
         """``k`` / ``v [attention layers, num_pages, page, KV heads x

@@ -31,6 +31,7 @@ import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -1251,6 +1252,20 @@ class Transformer(nn.Module):
                     "k_scale": jnp.zeros(sshape, jnp.float32),
                     "v_scale": jnp.zeros(sshape, jnp.float32)}
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def slot_contract(self):
+        """For the slot engine (``models/contract.py``): every default, and
+        the expert load where the expert layers are dropless."""
+        cfg = self.config
+        return SlotContract(
+            vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
+            dtype=cfg.dtype, num_layers=cfg.num_layers,
+            attention_bias=cfg.position_embedding == "alibi",
+            routes_experts=cfg.moe_num_experts > 0
+            and cfg.moe_capacity_factor is None,
+            expert_layers=sum(_is_moe_layer(cfg, i)
+                              for i in range(cfg.num_layers)),
+            experts=cfg.moe_num_experts)
 
     def init_paged_cache(self, num_pages, page_size, dtype=None):
         """Zero PAGED KV pool: ``[L, num_pages, page_size, KVH*D]`` per

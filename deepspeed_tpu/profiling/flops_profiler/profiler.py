@@ -57,17 +57,6 @@ PEAK_TFLOPS = {
     "cpu": 0.1,
 }
 
-# Peak HBM GB/s per chip for bandwidth-utilization estimates (public figures).
-PEAK_HBM_GBPS = {
-    "tpu v4": 1228.0,
-    "tpu v5 lite": 819.0,   # v5e
-    "tpu v5e": 819.0,
-    "tpu v5": 2765.0,       # v5p
-    "tpu v6 lite": 1640.0,  # trillium
-    "cpu": 50.0,
-}
-
-
 def _device_peak(table):
     d = jax.devices()[0]
     kind = d.device_kind.lower()
@@ -84,10 +73,6 @@ def device_peak_tflops():
     return _device_peak(PEAK_TFLOPS)
 
 
-def device_peak_hbm_gbps():
-    return _device_peak(PEAK_HBM_GBPS)
-
-
 def device_hbm_bytes():
     """Device memory budget in bytes, via the accelerator's canonical
     ``memory_snapshot`` reader: the runtime's reported ``bytes_limit``
@@ -101,7 +86,7 @@ def cost_analysis_of(fn, *args, **kwargs):
     """Compile ``fn`` and return XLA's cost analysis dict (flops, bytes)
     — the compiled-program extraction itself is the shared cost model
     (``autotuning.cost_model.xla_cost_analysis``), the same code the
-    memory/FLOP contract layer and the bench roofline blocks read."""
+    memory/FLOP contract layer reads."""
     from deepspeed_tpu.autotuning.cost_model import xla_cost_analysis
     compiled = jax.jit(fn).lower(*args, **kwargs).compile()
     return xla_cost_analysis(compiled)

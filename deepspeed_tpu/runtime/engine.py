@@ -1441,8 +1441,8 @@ class DeepSpeedEngine:
     def hbm_watermark(self):
         """Per-run peak-HBM watermark: the accelerator's canonical
         per-device memory record (process-lifetime peak — one training
-        run owns its process in every bench phase), for callers stamping
-        records (``bench.py`` train phases read this at run end)."""
+        run owns its process in a benchmark run), for callers stamping
+        records at run end."""
         from deepspeed_tpu.monitor.memwatch import device_memory_record
         return device_memory_record()
 

@@ -166,7 +166,7 @@ def serving_decode_step():
     from deepspeed_tpu.inference.serving.slots import make_decode_block_fn
     engine = _tiny_inference_engine()
     N, NP, PG = 2, 9, 8                 # 9 pages of 8 (page 0 = trash)
-    fn = make_decode_block_fn(engine.module,
+    fn = make_decode_block_fn(engine.module, engine.module.slot_contract(),
                               build_sample_fn(False, 1.0, 0, 1.0),
                               None, 2, 4 * PG)
     pool = engine.module.init_paged_cache(NP, PG,
@@ -188,7 +188,8 @@ def serving_prefill_chunk():
     from deepspeed_tpu.inference.serving.slots import make_chunk_fn
     engine = _tiny_inference_engine()
     C, NP, PG = 8, 9, 8
-    chunk_fn = make_chunk_fn(engine.module, None)
+    chunk_fn = make_chunk_fn(engine.module, engine.module.slot_contract(),
+                             None)
     pool = engine.module.init_paged_cache(NP, PG,
                                           dtype=engine.compute_dtype)
     pages = jnp.asarray([[3, 5, 2, 7], [3, 5, 2, 7], [1, 4, 6, 8],
@@ -282,8 +283,9 @@ def serving_spec_block():
     params = model.init(jax.random.key(0),
                         {"input_ids": jnp.zeros((1, 8), jnp.int32)})
     N, NP, PG = 2, 9, 8
-    fn = make_spec_block_fn(model, build_sample_fn(False, 1.0, 0, 1.0),
-                            None, 2, 4 * PG)
+    fn = make_spec_block_fn(model, model.slot_contract(),
+                            build_sample_fn(False, 1.0, 0, 1.0), None, 2,
+                            4 * PG)
     pool = model.init_paged_cache(NP, PG, dtype=jnp.float32)
     pages = jnp.asarray([[3, 5, 2, 7], [1, 4, 0, 0]], jnp.int32)
     state = dict(_slot_state(N), draft=jnp.asarray([5, 0], jnp.int32))

@@ -12,7 +12,7 @@ device: for every hot-path program and sharding plan,
   tier-1 CPU backend the contracts are defined under; and
 * ``compiled.cost_analysis()`` — flops and bytes-accessed, the roofline
   numerators (``autotuning.cost_model`` is the shared extraction — the
-  flops profiler and the bench roofline blocks read the same code).
+  flops profiler reads the same code).
 
 The regression story the comm layer taught, applied to the resource
 that actually produced the BENCH_r04 cliff (decode collapsing 8,673 →

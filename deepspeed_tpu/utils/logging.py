@@ -24,8 +24,9 @@ def _create_logger(name="DeepSpeedTPU", level=logging.INFO):
     logger_.setLevel(level)
     logger_.propagate = False
     if not logger_.handlers:
-        # stderr: stdout belongs to the program's own result lines (bench.py's
-        # and chip_smoke.py's last-line contracts, `| tail -n 1` consumers).
+        # stderr: stdout belongs to the program's own result lines
+        # (benchmark/run.py's and chip_smoke.py's last-line contracts,
+        # `| tail -n 1` consumers).
         handler = logging.StreamHandler(stream=sys.stderr)
         handler.setFormatter(
             logging.Formatter("[%(asctime)s] [%(levelname)s] [%(name)s] %(message)s"))

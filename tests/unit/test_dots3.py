@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from benchmark import spec
 from deepspeed_tpu.inference.serving.paging import SlotPages
-from deepspeed_tpu.models.dots3 import dots3_config
+from deepspeed_tpu.models.dots3 import Dots3Model, dots3_config
 from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.ops.transformer import latent_attention as ops
 
@@ -67,32 +67,36 @@ def _serve_logits(module, params, tokens, prompt_len, chunk, page=8,
     chunks of ``chunk`` (the last one padded), the rest a token a decode
     step, teacher-forced, in lane ``slot`` of ``slots`` — the other lanes
     dead, as a retired slot is (table row on the trash page)."""
-    mgr = SlotPages(module, slots, cache_len, page, 0, chunk, True,
-                    {"prefix_lookups": 0})
+    mgr = SlotPages(module, module.slot_contract(), slots, cache_len, page,
+                    0, chunk, True, {"prefix_lookups": 0})
     pools = mgr.new_pools(jnp.float32)
     mgr.reserve(slot, tokens[:prompt_len], len(tokens) - prompt_len)
-    decode = type(module).decode
+    # one program a call form (a chunk of one slot, a token a lane), traced
+    # once: the loops below replay them
+    @jax.jit
+    def decode(pools, ids, pages, start, live):
+        (lg, pools), _ = module.apply(
+            params, ids, {**pools, "pages": pages}, start, live=live,
+            method=type(module).decode, mutable=["moe_stats"])
+        return lg, pools
+
     out = []
     for s0 in range(0, prompt_len, chunk):
         ids = np.zeros(chunk, np.int32)
         n = min(chunk, prompt_len - s0)
         ids[:n] = tokens[s0:s0 + n]
-        (lg, pools), _ = module.apply(
-            params, jnp.asarray(ids[None]),
-            {**pools, "pages": jnp.asarray(mgr.row(slot))}, jnp.int32(s0),
-            live=jnp.asarray((np.arange(chunk) < n)[None]), method=decode,
-            mutable=["moe_stats"])
+        lg, pools = decode(pools, jnp.asarray(ids[None]),
+                           jnp.asarray(mgr.row(slot)), jnp.int32(s0),
+                           jnp.asarray((np.arange(chunk) < n)[None]))
         out.append(np.asarray(lg[0, :n]))
     active = np.arange(slots) == slot
     table = np.where(active[:, None], mgr.table(), 0)
     for p in range(prompt_len, len(tokens)):
         ids = np.where(active, tokens[p], 0).astype(np.int32)
         pos = np.where(active, p, cache_len - 1).astype(np.int32)
-        (lg, pools), _ = module.apply(
-            params, jnp.asarray(ids[:, None]),
-            {**pools, "pages": jnp.asarray(table)}, jnp.asarray(pos),
-            live=jnp.asarray(active[:, None]), method=decode,
-            mutable=["moe_stats"])
+        lg, pools = decode(pools, jnp.asarray(ids[:, None]),
+                           jnp.asarray(table), jnp.asarray(pos),
+                           jnp.asarray(active[:, None]))
         out.append(np.asarray(lg[slot]))
     return np.concatenate(out), mgr
 
@@ -130,8 +134,9 @@ def test_index_topk_at_least_the_context_is_dense_attention(topk, same):
     model = dict(TOY, index_topk=topk)
     module, params = _program(model)
     tokens = np.random.default_rng(5).integers(0, 128, 64).astype(np.int32)
-    run = lambda p: np.asarray(module.apply(
-        p, {"input_ids": jnp.asarray(tokens[None])}))[0]
+    forward = jax.jit(lambda p: module.apply(
+        p, {"input_ids": jnp.asarray(tokens[None])}))   # traced once
+    run = lambda p: np.asarray(forward(p))[0]
     other = jax.tree_util.tree_map_with_path(
         lambda path, x: x[::-1] if "index" in path[-1].key else x, params)
     diff = np.abs(run(params) - run(other)).max()
@@ -269,7 +274,8 @@ def test_window_pools_hold_a_bounded_number_of_pages_a_slot(cache_len):
     reports the pages by row kind."""
     module = fam.program_model(TOY, dtype="float32")
     stats = {"prefix_lookups": 0}
-    mgr = SlotPages(module, 4, cache_len, 8, 0, 32, True, stats)
+    mgr = SlotPages(module, module.slot_contract(), 4, cache_len, 8, 0, 32,
+                    True, stats)
     assert mgr.ring_pages == 3 and mgr.window_pages == 1 + 4 * 3
     assert mgr.table_width == cache_len // 8 + 3
     pools = jax.eval_shape(lambda: mgr.new_pools(jnp.float32))
@@ -436,20 +442,22 @@ def test_a_cached_chunk_touches_its_live_blocks_only(chunks):
     (32, 64, 1552, 768, 544),
     (64, 80, 1160, 384, 272)])         # a last chunk's 16 real positions
 def test_chunk_work_counts_pairs(start, end, scored, kept, window):
-    module = fam.program_model(TOY)
-    work = module.chunk_work(start, end, 8, 3)
+    chunk_work = fam.program_model(TOY).slot_contract().chunk_work
+    work = chunk_work(start, end, 8, 3, 4)
     assert work == {"dsa_keys_scored": 2 * scored, "dsa_keys_kept": 2 * kept,
                     "latent_rows_read": 2 * end,
                     "latent_rows_decompressed": 2 * 512, "window_pages": 6,
                     "window_keys": 2 * window}
     # the live 512-key blocks, whole: what ``attn.mla_decompress`` runs
-    assert [module.chunk_work(e - 1024, e, 64, 9)["latent_rows_decompressed"]
+    assert [chunk_work(e - 1024, e, 64, 9, 4)["latent_rows_decompressed"]
             for e in (1024, 1500, 15360)] == [2 * 1024, 2 * 1536, 2 * 15360]
 
 
 def test_block_work_reads_the_kept_rows_only():
-    module = fam.program_model(TOY)
-    work = module.block_work([(10, 2), (100, 3)], 3)
+    declared = fam.program_model(TOY).slot_contract()
+    work = declared.block_work([(10, 2), (100, 3)], 3, 4)
+    # every name the two return is declared: summed into stats, or a level
+    assert set(work) <= set(declared.work_counters + declared.work_levels)
     assert work["dsa_keys_scored"] == 2 * (10 + 11 + 100 + 101 + 102)
     assert work["dsa_keys_kept"] == work["latent_rows_read"] \
         == 2 * (10 + 11 + 3 * 24)
@@ -473,8 +481,12 @@ def test_the_mapping_reads_both_layer_kinds():
     cfg = dots3_config(TOY, held_experts=(4, 4))
     assert (cfg.full.heads, cfg.full.row, cfg.full.index_topk) == (4, 40, 24)
     assert (cfg.window.heads, cfg.window.row, cfg.window.window) == (2, 48, 17)
-    assert cfg.n_routed_experts == 16 and cfg.moe_num_experts == 4
-    assert cfg.num_layers == 4 and cfg.moe_layer_offset == 1
+    assert cfg.n_routed_experts == 16 and cfg.num_layers == 4
+    # what the slot engine reads of it: the load's shape is the experts
+    # HELD, in the layers after the dense one
+    declared = Dots3Model(cfg).slot_contract()
+    assert declared.holds_share and declared.routes_experts
+    assert (declared.expert_layers, declared.experts) == (3, 4)
     assert cfg.full.scale == pytest.approx(24 ** -0.5)
 
 

@@ -170,7 +170,8 @@ def test_checkpoint_save_load(tmp_path):
 def test_memory_lean_optimizer_states(tmp_path):
     """The documented memory-lean deviation (bf16 master weights + bf16
     Adam moments, fp32 arithmetic) trains and stores what it claims —
-    the mode bench.py uses for the OPT-1.3B north star on one 16 GB chip."""
+    the mode the benchmark's ``opt13b-sft-1chip`` cell runs OPT-1.3B in on
+    one 16 GB chip."""
     engine, *_ = deepspeed_tpu.initialize(
         model=SimpleModel(hidden_dim=16),
         config=base_config(

@@ -68,6 +68,29 @@ def _prompt(n, seed=0):
         .astype(np.int32)
 
 
+# the rows of logits the programs' sample function saw since an Engine last
+# cleared it, and the programs by (model, lane): every Engine of a module is
+# the same three programs, traced and compiled ONCE a test session — an
+# Engine owns its pools, state and pages, not its executables
+_SEEN, _PROGRAMS = [], {}
+
+
+def _programs(module, contract, cache_len):
+    key = (type(module), module.config, cache_len)
+    if key not in _PROGRAMS:
+        def sample(logits, rng):
+            jax.debug.callback(lambda l: _SEEN.append(np.asarray(l)),
+                               logits, ordered=True)
+            return jnp.argmax(logits, axis=-1)
+
+        _PROGRAMS[key] = (
+            slots.make_chunk_fn(module, contract, None),
+            slots.make_admit_fn(sample),
+            slots.make_decode_block_fn(module, contract, sample, None, BLOCK,
+                                       cache_len))
+    return _PROGRAMS[key]
+
+
 class Engine:
     """The slot programs as ``ServingEngine`` builds and calls them, with a
     scheduler a test can read (``tests/unit/test_lfm2.py`` has the long
@@ -77,22 +100,15 @@ class Engine:
     def __init__(self, module, params, num_slots=2, cache_len=128):
         self.module, self.params = module, params
         self.stats = {}
-        self.pages = SlotPages(module, num_slots, cache_len, PAGE, 0, CHUNK,
-                               False, self.stats)
+        contract = module.slot_contract()
+        self.pages = SlotPages(module, contract, num_slots, cache_len, PAGE,
+                               0, CHUNK, False, self.stats)
         self.pools = self.pages.new_pools(jnp.float32)
         self.state = {k: jnp.asarray(v) for k, v in
                       slots.init_slot_state(num_slots).items()}
-        self._seen = []
-
-        def sample(logits, rng):
-            jax.debug.callback(lambda l: self._seen.append(np.asarray(l)),
-                               logits, ordered=True)
-            return jnp.argmax(logits, axis=-1)
-
-        self.chunk_fn = slots.make_chunk_fn(module, None)
-        self.admit_fn = slots.make_admit_fn(sample)
-        self.decode_fn = slots.make_decode_block_fn(
-            module, sample, None, BLOCK, self.pages.cache_len)
+        self._seen = _SEEN
+        self.chunk_fn, self.admit_fn, self.decode_fn = _programs(
+            module, contract, self.pages.cache_len)
         self.rng = jax.random.key(0)
         self.lanes = {}                  # slot -> [rid, tokens left]
         self.tokens, self.logits = {}, {}
@@ -240,7 +256,8 @@ def test_each_control_fails(program, control):
 def test_slot_pages_reckons_a_strided_lane_in_rows(program):
     module, _ = program
     stats = {}
-    sp = SlotPages(module, 3, 120, PAGE, 0, CHUNK, True, stats)
+    sp = SlotPages(module, module.slot_contract(), 3, 120, PAGE, 0, CHUNK,
+                   True, stats)
     # 120 positions = 30 rows = 4 lane pages; the lane in positions
     assert (sp.stride, sp.pages_per_slot, sp.cache_len) == (4, 4, 128)
     assert sp.ring_pages == W // PAGE and sp.window_pages == 1 + 3 * 4
@@ -274,7 +291,7 @@ def test_slot_pages_reckons_a_strided_lane_in_rows(program):
     assert reach["eva_summaries_written"] == 2 * 3   # 32..43; 44 is partial
     assert reach["eva_remote_pairs"] == 2 * 13 * 8
     assert reach["eva_local_pairs"] == 2 * sum(range(1, 14))
-    work = sp.block_reach([(46, 8), (31, 3)], BLOCK)
+    work = sp.block_reach(2, [(46, 8), (31, 3)], BLOCK)
     assert work["kv_pages"] == sum(-(-(-(-(46 + i) // 4)) // 8)
                                    for i in range(8)) + 1 + 1 + 2
     # lane one feeds 45..52: ring rows 14..21, 8 summaries each; lane two
@@ -288,13 +305,13 @@ def test_slot_pages_reckons_a_strided_lane_in_rows(program):
 
 
 def test_a_prefill_chunk_may_not_straddle_a_window(program):
-    module, _ = program
-    assert slots.admission_chunk(module, 16) == 16
-    assert slots.admission_chunk(module, 32) == 32
-    assert slots.admission_chunk(module, 4096) == 32    # the cap: a window
+    declared = program[0].slot_contract()
+    assert slots.admission_chunk(declared, 16) == 16
+    assert slots.admission_chunk(declared, 32) == 32
+    assert slots.admission_chunk(declared, 4096) == 32  # the cap: a window
     with pytest.raises(ValueError, match="divides window_size=32"):
-        slots.admission_chunk(module, 24)
-    assert slots.chunk_write_form(module, 16, PAGE) == "page_runs"
+        slots.admission_chunk(declared, 24)
+    assert slots.chunk_write_form(declared, 16, PAGE) == "page_runs"
 
 
 # ---- through init_inference -> serve() -> submit / drain ------------------ #

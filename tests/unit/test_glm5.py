@@ -9,6 +9,7 @@ order of their sums alone (``tests/unit/test_dots3.py`` has the sizes):
 2e-4 absolute on logits of ~1.
 """
 
+import functools
 import hashlib
 import json
 import os
@@ -94,22 +95,15 @@ def test_the_uncached_forward_is_the_reference(program, reference):
 
 
 # ---- (b) chunked prefill, then self-drafted windows, by logits --------- #
-def _windowed_logits(module, params, tokens, prompt_len, chunk, accepts,
-                     page=8, slots_n=3, slot=1, cache_len=192):
-    """Main and module logits at every position of ``tokens``: the prompt
-    through prefill chunks (the last padded), then verify windows of two
-    rows in lane ``slot``, teacher-forced — a window whose turn in
-    ``accepts`` is True holds the next token as its draft and moves two
-    positions, one whose turn is False holds a WRONG draft, moves one, and
-    leaves a rejected row behind for the next window to overwrite."""
-    mgr = SlotPages(module, slots_n, cache_len, page, 0, chunk, False, {})
-    pools = mgr.new_pools(jnp.float32)
-    mgr.reserve(slot, tokens[:prompt_len], len(tokens) - prompt_len)
+@functools.lru_cache(maxsize=None)
+def _both(module):
+    """The main forward and the module's over the same rows, ONE jitted
+    function a model: a case that repeats a call form (a chunk length, a
+    table shape) replays the program an earlier case compiled."""
     model = type(module)
-    main, guess = {}, {}
 
     @jax.jit
-    def both(ids, nxt, pools, pages, start, live):
+    def both(params, ids, nxt, pools, pages, start, live):
         (lg, h, pools), _ = module.apply(
             params, ids, {**pools, "pages": pages}, start, live=live,
             hidden=True, method=model.decode, mutable=["moe_stats"])
@@ -118,8 +112,26 @@ def _windowed_logits(module, params, tokens, prompt_len, chunk, accepts,
             live=live, method=model.draft, mutable=["moe_stats"])
         return lg, dl, pools
 
+    return both
+
+
+def _windowed_logits(module, params, tokens, prompt_len, chunk, accepts,
+                     page=8, slots_n=3, slot=1, cache_len=192):
+    """Main and module logits at every position of ``tokens``: the prompt
+    through prefill chunks (the last padded), then verify windows of two
+    rows in lane ``slot``, teacher-forced — a window whose turn in
+    ``accepts`` is True holds the next token as its draft and moves two
+    positions, one whose turn is False holds a WRONG draft, moves one, and
+    leaves a rejected row behind for the next window to overwrite."""
+    mgr = SlotPages(module, module.slot_contract(), slots_n, cache_len, page,
+                    0, chunk, False, {})
+    pools = mgr.new_pools(jnp.float32)
+    mgr.reserve(slot, tokens[:prompt_len], len(tokens) - prompt_len)
+    main, guess = {}, {}
+    both = _both(module)
+
     def run(*args):
-        lg, dl, pools = both(*map(jnp.asarray, args[:2]), args[2],
+        lg, dl, pools = both(params, *map(jnp.asarray, args[:2]), args[2],
                              *map(jnp.asarray, args[3:]))
         return np.asarray(lg), np.asarray(dl), pools
 
@@ -328,10 +340,11 @@ def test_the_modules_rows_do_not_depend_on_the_chunking(program, chunks):
     prompt = np.random.default_rng(9).integers(0, 128, 53).astype(np.int32)
 
     def prefill(chunk):
-        mgr = SlotPages(module, 2, 128, 8, 0, chunk, False, {})
+        declared = module.slot_contract()
+        mgr = SlotPages(module, declared, 2, 128, 8, 0, chunk, False, {})
         pools = mgr.new_pools(jnp.float32)
         mgr.reserve(1, prompt, 8)
-        fn = slots.make_chunk_fn(module, None, self_draft=True)
+        fn = slots.make_chunk_fn(module, declared, None, self_draft=True)
         n_chunks = -(-len(prompt) // chunk)
         padded = np.zeros(n_chunks * chunk, np.int32)
         padded[:len(prompt)] = prompt
@@ -470,12 +483,12 @@ def test_dots3s_parameter_tree_is_what_it_was():
 @pytest.mark.parametrize("layers", [None, "with_the_module"])
 def test_chunk_work_counts_the_live_blocks(program, layers):
     """A chunk's attention work over the main model's layers, or those and
-    the module's (the self-drafting server's ``work_layers``): the rows it
+    the module's (what a self-drafting server's dispatches run): the rows it
     decompresses are the live 512-key blocks, whole — not the slot's lane."""
-    module, _ = program
-    n = module.config.num_layers + (module.config.mtp_layers if layers else 0)
-    work = module.chunk_work(1024, 2500, 64, 0, **(
-        {"layers": n} if layers else {}))
+    declared = program[0].slot_contract()
+    assert declared.drafts_itself and declared.draft_layers == 1
+    n = declared.num_layers + (declared.draft_layers if layers else 0)
+    work = declared.chunk_work(1024, 2500, 64, 0, n)
     assert work["latent_rows_read"] == n * 40 * 64
     assert work["latent_rows_decompressed"] == n * 5 * 512
     assert work["dsa_keys_kept"] <= work["dsa_keys_scored"] \

@@ -14,6 +14,7 @@ what one stale state row, one wrong page or a missing expert moves them by
 (``test_a_stale_state_is_visible`` reads 0.1 and more).
 """
 
+import dataclasses
 import os
 
 import numpy as np
@@ -67,6 +68,29 @@ def _prompt(n, seed=0):
         .astype(np.int32)
 
 
+# the rows of logits the programs' sample function saw since an Engine last
+# cleared it, and the programs by (model, lane): every Engine of a module is
+# the same three programs, traced and compiled ONCE a test session — an
+# Engine owns its pools, state and pages, not its executables
+_SEEN, _PROGRAMS = [], {}
+
+
+def _programs(module, contract, cache_len):
+    key = (type(module), module.config, cache_len)
+    if key not in _PROGRAMS:
+        def sample(logits, rng):
+            jax.debug.callback(lambda l: _SEEN.append(np.asarray(l)),
+                               logits, ordered=True)
+            return jnp.argmax(logits, axis=-1)
+
+        _PROGRAMS[key] = (
+            slots.make_chunk_fn(module, contract, None),
+            slots.make_admit_fn(sample),
+            slots.make_decode_block_fn(module, contract, sample, None, BLOCK,
+                                       cache_len))
+    return _PROGRAMS[key]
+
+
 class Engine:
     """The slot programs as ``ServingEngine`` builds and calls them, with a
     scheduler a test can read: ``admit`` runs a request's chunks and the
@@ -79,22 +103,15 @@ class Engine:
     def __init__(self, module, params, num_slots=2, cache_len=64):
         self.module, self.params = module, params
         self.stats = {}
-        self.pages = SlotPages(module, num_slots, cache_len, PAGE, 0, CHUNK,
-                               False, self.stats)
+        contract = module.slot_contract()
+        self.pages = SlotPages(module, contract, num_slots, cache_len, PAGE,
+                               0, CHUNK, False, self.stats)
         self.pools = self.pages.new_pools(jnp.float32)
         self.state = {k: jnp.asarray(v) for k, v in
                       slots.init_slot_state(num_slots).items()}
-        self._seen = []
-
-        def sample(logits, rng):
-            jax.debug.callback(lambda l: self._seen.append(np.asarray(l)),
-                               logits, ordered=True)
-            return jnp.argmax(logits, axis=-1)
-
-        self.chunk_fn = slots.make_chunk_fn(module, None)
-        self.admit_fn = slots.make_admit_fn(sample)
-        self.decode_fn = slots.make_decode_block_fn(
-            module, sample, None, BLOCK, self.pages.cache_len)
+        self._seen = _SEEN
+        self.chunk_fn, self.admit_fn, self.decode_fn = _programs(
+            module, contract, self.pages.cache_len)
         self.rng = jax.random.key(0)
         self.lanes = {}                  # slot -> [rid, tokens left]
         self.tokens, self.logits = {}, {}
@@ -447,8 +464,8 @@ def test_the_grouped_form_is_the_dense_form(monkeypatch):
 # ---- the cache manager's state kind --------------------------------------- #
 def _manager(program, share=False, slots_=3, stats=None):
     stats = {"prefix_lookups": 0} if stats is None else stats
-    return SlotPages(program[0], slots_, 64, PAGE, 0, CHUNK, share,
-                     stats), stats
+    return SlotPages(program[0], program[0].slot_contract(), slots_, 64,
+                     PAGE, 0, CHUNK, share, stats), stats
 
 
 def test_slot_pages_ship_the_state_row_in_the_table(program):
@@ -499,13 +516,15 @@ def test_slot_pages_refuse_prefix_sharing_for_a_state_kind(program):
 def test_slot_pages_without_a_state_kind_are_as_they_were():
     stats = {"prefix_lookups": 0, "prefix_hits": 0,
              "prefix_tokens_reused": 0, "page_evictions": 0}
-    mgr = SlotPages(None, 3, 64, PAGE, 0, CHUNK, True, stats)
+    plain = dataclasses.replace(fam.program_model(TOY).slot_contract(),
+                                state_kinds=())
+    mgr = SlotPages(None, plain, 3, 64, PAGE, 0, CHUNK, True, stats)
     assert mgr.state_kinds == () and mgr.state_rows == 0
     assert mgr.table_width == mgr.pages_per_slot
     mgr.reserve(0, _prompt(20), 4)
     assert "state_rows_live" not in stats and "state" not in mgr.describe()
     assert "state_rows" not in mgr.chunk_reach(2, 16)
-    assert "state_rows" not in mgr.block_reach([(20, 4)], 4)
+    assert "state_rows" not in mgr.block_reach(2, [(20, 4)], 4)
 
 
 def test_dispatch_spans_carry_the_state_rows(program):
@@ -514,7 +533,7 @@ def test_dispatch_spans_carry_the_state_rows(program):
     mgr.reserve(0, _prompt(20), 10)
     mgr.reserve(2, _prompt(5), 3)
     assert mgr.chunk_reach(6, 16)["state_rows"] == 1
-    reach = mgr.block_reach([(21, 4), (6, 2)], 4)
+    reach = mgr.block_reach(6, [(21, 4), (6, 2)], 4)
     assert reach["state_rows"] == 6
     assert reach["state_bytes"] == 2 * mgr.state_row_bytes
     assert reach["kv_bytes_mapped"] == mgr.in_use * mgr.page_bytes > 0
@@ -525,10 +544,12 @@ def test_config_reads_the_hf_keys():
     cfg = lfm2_config(TOY)
     assert cfg.layers_of("conv") == [0, 1, 3, 4]
     assert cfg.layers_of("full_attention") == [2, 5]
-    assert (cfg.head_dim, cfg.num_kv_heads, cfg.moe_layer_offset,
-            cfg.moe_num_experts, cfg.rope_theta) == (16, 2, 2, 8, 1e6)
-    assert cfg.moe_capacity_factor is None and slots.routes_experts(
-        fam.program_model(TOY))
+    assert (cfg.head_dim, cfg.num_kv_heads, cfg.rope_theta) == (16, 2, 1e6)
+    # what the slot engine reads of it: dropless experts after the two
+    # dense layers, eight a layer
+    declared = fam.program_model(TOY).slot_contract()
+    assert declared.routes_experts and not declared.holds_share
+    assert (declared.expert_layers, declared.experts) == (4, 8)
 
 
 @pytest.mark.parametrize("key,value", [

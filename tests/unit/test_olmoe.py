@@ -107,7 +107,7 @@ def test_paged_chunks_then_decode_match_the_reference_logits(fam):
     prompt, n_new, C = _tokens((21,), seed=3), 6, 8
     pool, tables = _pool_and_tables(module, 1)
     row = jnp.asarray(tables[:1])
-    chunk_fn = slots.make_chunk_fn(module, None)
+    chunk_fn = slots.make_chunk_fn(module, module.slot_contract(), None)
     padded = np.zeros((1, 24), np.int32)
     padded[0, :21] = prompt
     chunk_logits = []
@@ -120,12 +120,13 @@ def test_paged_chunks_then_decode_match_the_reference_logits(fam):
         assert int(np.asarray(load)[:-2].sum()) == (last + 1) * TOP_K * LAYERS
     toks, step_logits = list(prompt), []
     nxt = int(np.argmax(chunk_logits[-1][1]))
+    step = jax.jit(lambda ids, pool, pos: slots._decode(    # traced once
+        module, params, ids, {**pool, "pages": row}, pos,
+        live=jnp.ones((1, 1), bool)))
     for i in range(n_new):
         toks.append(nxt)
-        logits, cache, counts = slots._decode(
-            module, params, jnp.asarray([[nxt]], jnp.int32),
-            {**pool, "pages": row}, jnp.asarray([21 + i], jnp.int32),
-            live=jnp.ones((1, 1), bool))
+        logits, cache, counts = step(jnp.asarray([[nxt]], jnp.int32), pool,
+                                     jnp.asarray([21 + i], jnp.int32))
         pool = {k: cache[k] for k in pool}
         step_logits.append(np.asarray(logits)[0, 0])
         nxt = int(np.argmax(step_logits[-1]))

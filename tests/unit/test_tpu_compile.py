@@ -423,8 +423,9 @@ def test_chunk_step_writes_page_runs_in_place(rows, one_chip, mosaic):
     pool = on_chip(jax.eval_shape(
         lambda: model.init_paged_cache(pages, page, BF16)))
     ints = lambda *shape: jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
-    assert chunk_rows(model, chunk, page) == 4
-    compiled = make_chunk_fn(model, None).lower(
+    declared = model.slot_contract()
+    assert chunk_rows(declared, chunk, page) == 4
+    compiled = make_chunk_fn(model, declared, None).lower(
         params, pool, ints(rows, slot_pages), ints(rows, chunk),
         ints(rows) if rows > 1 else ints(), ints(rows)).compile()
     text = compiled.as_text()
@@ -471,14 +472,15 @@ def _slot_programs_of(cell, family, one_chip):
     cell = bench.cell(cell)
     module = bench.family(family).program_model(cell["config"])
     s = cell["system"]["serving"]
-    chunk = slots.admission_chunk(module, s["prefill_chunk"])
-    pages = SlotPages(module, s["num_slots"], s["max_cache_len"],
+    declared = module.slot_contract()
+    chunk = slots.admission_chunk(declared, s["prefill_chunk"])
+    pages = SlotPages(module, declared, s["num_slots"], s["max_cache_len"],
                       s["page_size"], 0, chunk, False, {})
     on_chip = lambda tree, dtype=None: jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
                                        sharding=one_chip), tree)
     return types.SimpleNamespace(
-        module=module, serving=s, chunk=chunk, pages=pages, on_chip=on_chip,
+        module=module, declared=declared, serving=s, chunk=chunk, pages=pages, on_chip=on_chip,
         params=on_chip(jax.eval_shape(lambda: module.init(
             jax.random.key(0), {"input_ids": jnp.zeros((1, 8), I32)})), BF16),
         pool=on_chip(jax.eval_shape(lambda: pages.new_pools(BF16))),
@@ -501,7 +503,7 @@ def test_evabyte_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     params, pool, ints, on_chip = c.params, c.pool, c.ints, c.on_chip
     assert (pages.pages_per_slot, pages.ring_pages) == (14, 32)
     if program == "chunk_step":
-        compiled = slots.make_chunk_fn(module, None).lower(
+        compiled = slots.make_chunk_fn(module, c.declared, None).lower(
             params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
             ints(1)).compile()
     else:
@@ -511,8 +513,8 @@ def test_evabyte_slot_programs_compile_at_the_cells_sizes(program, one_chip,
         rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
                                    sharding=one_chip)
         compiled = slots.make_decode_block_fn(
-            module, lambda logits, rng: jnp.argmax(logits, -1), None,
-            s["decode_block"], pages.cache_len).lower(
+            module, c.declared, lambda logits, rng: jnp.argmax(logits, -1),
+            None, s["decode_block"], pages.cache_len).lower(
                 params, pool, state, ints(n, pages.table_width),
                 rng).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 8
@@ -548,7 +550,7 @@ def test_dots3_chunk_step_compiles_at_the_cells_sizes(one_chip, mosaic):
     module, s, chunk, pages = c.module, c.serving, c.chunk, c.pages
     params, pool, ints = c.params, c.pool, c.ints
     assert (pages.num_pages, s["page_size"]) == (4113, 64)
-    compiled = slots.make_chunk_fn(module, None).lower(
+    compiled = slots.make_chunk_fn(module, c.declared, None).lower(
         params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
         ints(1)).compile()
     text = compiled.as_text()
@@ -578,9 +580,10 @@ def test_glm5_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     assert {k: v.shape[0] for k, v in pool.items()} \
         == {"latent": 6, "index": 6}
     if program == "chunk_step":
-        compiled = slots.make_chunk_fn(module, None, self_draft=True).lower(
-            params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
-            ints(1), ints(1)).compile()
+        compiled = slots.make_chunk_fn(
+            module, c.declared, None, self_draft=True).lower(
+                params, pool, ints(1, pages.table_width), ints(1, chunk),
+                ints(), ints(1), ints(1)).compile()
         calls = 6 * 4         # index, top-k, decompress, flash a layer
         _no_pool_layer_is_sliced_out(compiled.as_text(), pages)
     else:
@@ -590,8 +593,8 @@ def test_glm5_slot_programs_compile_at_the_cells_sizes(program, one_chip,
         rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
                                    sharding=one_chip)
         compiled = slots.make_spec_block_fn(
-            module, lambda logits, rng: jnp.argmax(logits, -1), None,
-            s["decode_block"], pages.cache_len).lower(
+            module, c.declared, lambda logits, rng: jnp.argmax(logits, -1),
+            None, s["decode_block"], pages.cache_len).lower(
                 params, pool, state, ints(n, pages.table_width),
                 rng).compile()
         # the expert kernel a routed layer; lane index, top-k, lane

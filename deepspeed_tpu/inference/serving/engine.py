@@ -657,9 +657,15 @@ class ServingEngine:
                                "moe_max_expert_tokens": 0})
             # a model that holds a share of the experts also reports the
             # choices that fell on the experts other chips hold
-            self._moe_share = self.contract.holds_share
-            if self._moe_share:
-                self.stats["moe_assignments_elsewhere"] = 0
+            # ... and one whose router has zero-compute outputs, the
+            # choices that fell on those (the load vector's columns after
+            # the held experts', ``contract.load_columns``)
+            self._moe_columns = tuple(
+                {"elsewhere": "moe_assignments_elsewhere",
+                 "zero": "moe_zero_picks"}[c]
+                for c in self.contract.load_columns)
+            for key in self._moe_columns:
+                self.stats[key] = 0
             # the load vector's rows: the model's expert layers, then —
             # self-drafting — its drafting module's
             self.moe_expert_tokens = np.zeros(
@@ -2502,18 +2508,20 @@ class ServingEngine:
         with ``moe_calls``, the expert-layer calls they cover."""
         if not loads:
             return
-        assigned = touched = busiest = elsewhere = 0
+        assigned = touched = busiest = 0
         held = self.moe_expert_tokens.size
+        beside = dict.fromkeys(self._moe_columns, 0)
         for vec in map(np.asarray, loads):
             self.moe_expert_tokens += vec[:held].reshape(
                 self.moe_expert_tokens.shape)
             assigned += int(vec[:held].sum())
             touched += int(vec[-2])
             busiest += int(vec[-1])
-            elsewhere += int(vec[held]) if self._moe_share else 0
-        if self._moe_share:
-            self.stats["moe_assignments_elsewhere"] += elsewhere
-            sp.set(moe_assignments_elsewhere=elsewhere)
+            for i, key in enumerate(self._moe_columns):
+                beside[key] += int(vec[held + i])
+        for key, n in beside.items():
+            self.stats[key] += n
+        sp.set(**beside)
         self.stats["moe_assignments"] += assigned
         self.stats["moe_experts_touched"] += touched
         self.stats["moe_max_expert_tokens"] += busiest

@@ -75,10 +75,11 @@ def init_slot_state(num_slots, draft=False):
 
 
 def _sown_counts(sown, share):
-    """``counts [expert layers, experts (+ 1)]`` from what the expert
-    layers of one ``apply`` sowed, in layer order: every ``moe_mlp`` under
-    ``moe_stats``, whatever holds it (``layers_<i>``, a drafting module's
-    block), shorter names first."""
+    """``counts [expert layers, experts (+ len(share))]`` from what the
+    expert layers of one ``apply`` sowed, in layer order: every
+    ``moe_mlp`` under ``moe_stats``, whatever holds it (``layers_<i>``, a
+    drafting module's block), shorter names first.  ``share``: the sown
+    scalars that follow the experts' tokens (``contract.load_columns``)."""
     found = []
 
     def walk(tree):
@@ -91,15 +92,16 @@ def _sown_counts(sown, share):
     walk(sown["moe_stats"])
     counts = jnp.stack([f["expert_tokens"] for f in found])
     if share:
-        # the last column: choices that fell on experts held elsewhere
+        # the last columns: choices that fell on experts held elsewhere,
+        # and on zero-compute experts
         counts = jnp.concatenate(
-            [counts, jnp.stack([f["elsewhere"] for f in found])[:, None]],
-            axis=1)
+            [counts] + [jnp.stack([f[name] for f in found])[:, None]
+                        for name in share], axis=1)
     return counts
 
 
 def _decode(module, variables, ids, cache, pos, live=None, method=None,
-            share=False, **kw):
+            share=(), **kw):
     """``module.decode`` as the slot programs call it: ``(logits, cache,
     counts)`` — ``(logits, hidden, cache, counts)`` where ``hidden=True``
     asks the model for its rows' last hidden state too.  Dense model
@@ -118,7 +120,7 @@ def _decode(module, variables, ids, cache, pos, live=None, method=None,
     return out + (_sown_counts(sown, share),)
 
 
-def _expert_load(counts, share=False):
+def _expert_load(counts, share=()):
     """The load summary a slot program of an expert model returns beside
     its other outputs, from ``counts [calls, expert layers, experts]`` —
     ONE int32 vector (one device read for the scheduler):
@@ -126,14 +128,15 @@ def _expert_load(counts, share=False):
     calls), then ``touched`` (experts with a live token, summed over
     layers and calls — each is one expert's weights read) and
     ``max_tokens`` (the busiest expert's tokens, summed likewise).
-    ``share`` (``contract.holds_share``): the counts' last column is the
-    choices of absent experts — their sum goes between the held experts'
-    tokens and ``touched``."""
+    ``share`` (``contract.load_columns``): the counts' last columns are
+    the choices of absent experts and of zero-compute experts — each
+    column's sum goes between the held experts' tokens and ``touched``."""
     with jax.named_scope("slots.expert_load"):
-        held = counts[..., :-1] if share else counts
+        held = counts[..., :-len(share)] if share else counts
         return jnp.concatenate(
             [jnp.sum(held, axis=0).reshape(-1)]
-            + ([jnp.sum(counts[..., -1])[None]] if share else [])
+            + [jnp.sum(counts[..., i - len(share)])[None]
+               for i in range(len(share))]
             + [jnp.sum(held > 0).astype(jnp.int32)[None],
                jnp.sum(jnp.max(held, axis=-1))[None]])
 
@@ -163,7 +166,7 @@ def make_decode_block_fn(module, contract, sample_fn, param_transform,
     reads no expert's weights and is not counted — and the program
     returns a fourth output, the block's :func:`_expert_load`."""
     deq = param_transform if param_transform is not None else (lambda p: p)
-    routed, share = contract.routes_experts, contract.holds_share
+    routed, share = contract.routes_experts, contract.load_columns
 
     @hot_path("serving.decode_step")
     def decode_block(params, cache, state, pages, rng):
@@ -292,7 +295,7 @@ def make_chunk_fn(module, contract, param_transform, self_draft=False):
     One more output, ``draft [1]``: the module's guess after that token,
     the slot's first pending draft (read of the last chunk only)."""
     deq = param_transform if param_transform is not None else (lambda p: p)
-    routed, share = contract.routes_experts, contract.holds_share
+    routed, share = contract.routes_experts, contract.load_columns
 
     @hot_path("serving.prefill_chunk")
     def chunk_step(params, cache, pages, chunk_ids, start, logits_at,
@@ -519,7 +522,7 @@ def make_spec_block_fn(module, contract, sample_fn, param_transform, block,
     costs), and ``load`` is the block's :func:`_expert_load` over the main
     model's expert layers and then the module's."""
     deq = param_transform if param_transform is not None else (lambda p: p)
-    routed, share = contract.routes_experts, contract.holds_share
+    routed, share = contract.routes_experts, contract.load_columns
 
     @hot_path("serving.spec_block")
     def spec_block(params, cache, state, pages, rng):

@@ -54,6 +54,7 @@ class SlotContract:
     # ---- experts ---- #
     routes_experts: bool = False       # dropless: mask dead rows, return load
     holds_share: bool = False          # the load's column of absent experts
+    zero_experts: bool = False         # ... and of zero-compute choices
     expert_layers: int = 0             # the load vector is these ...
     experts: int = 0                   # ... x the experts held a layer
     # ---- self-drafting (``serving.spec_draft_model: "mtp"``) ---- #
@@ -74,6 +75,13 @@ class SlotContract:
     @property
     def drafts_itself(self):
         return self.draft_layers > 0
+
+    @property
+    def load_columns(self):
+        """What an expert layer sows beside ``expert_tokens``, in the
+        order of the load vector's columns after the held experts'."""
+        return ("elsewhere",) * self.holds_share \
+            + ("zero",) * self.zero_experts
 
 
 def read(module):

@@ -4,10 +4,16 @@ A layer caches ONE row a token, ``[c_kv | k_r]`` — the RMS-normed latent
 and the roped key part shared by all heads — instead of per-head keys and
 values; every head's key and value are up-projections of the latent.
 
-* A **full** layer (``window == 0``) carries the DSA indexer (DeepSeek-
-  V3.2): a second, small cached key a token, per-query index scores over
-  the slot's live positions, an exact top-``index_topk``, and a softmax
-  over the kept positions only.
+* A **full** layer (``window == 0``) with ``index_topk > 0`` carries the
+  DSA indexer (DeepSeek-V3.2): a second, small cached key a token,
+  per-query index scores over the slot's live positions, an exact
+  top-``index_topk``, and a softmax over the kept positions only.
+* A **dense** full layer (``window == 0``, ``index_topk == 0``) is plain
+  causal MLA (``models/longcat.py``): no indexer parameter, no index pool
+  — its cache is the latent pool alone —, a chunk under the causal mask,
+  a decode row over every row ``<= position`` of its lane.  The kernels
+  are the indexed layer's, handed a mask that keeps every visible key
+  (scopes ``attn.mla_dense_chunk`` / ``attn.mla_dense_decode``).
 * A **window** layer attends the token and its ``window - 1`` predecessors
   and keeps its rows in a bounded ring a slot (``paging.SlotPages``).
 
@@ -80,10 +86,10 @@ class LatentSpec:
     v: int
     theta: float
     eps: float = 1e-5
-    window: int = 0              # 0: full attention with the indexer
+    window: int = 0              # 0: full attention
     index_heads: int = 0
     index_dim: int = 0
-    index_topk: int = 0
+    index_topk: int = 0          # 0 (full): no indexer, plain causal
     rescale: bool = True
     gated: bool = True           # the headwise output gate and its W_g
     interleaved: bool = False    # rotary pairs (2i, 2i + 1), not (i, i + d/2)
@@ -273,8 +279,10 @@ class LatentAttention(nn.Module):
         q, row, c_q = self._project(x, positions)
         if z.window:
             out, cache = self._chunk_window(q, row, positions, live, cache)
-        else:
+        elif z.index_topk:
             out, cache = self._chunk_full(x, q, row, c_q, positions, cache)
+        else:
+            out, cache = self._chunk_dense(q, row, positions, cache)
         return self._out(x, out.transpose(1, 0, 2)), cache
 
     def _attend(self, q, keys, mask, name, live_keys=None):
@@ -318,6 +326,23 @@ class LatentAttention(nn.Module):
         return self._attend(q, keys, mask, "attn.mla_chunk_prefill",
                             None if cache is None else live_keys), pools
 
+    def _chunk_dense(self, q, row, positions, cache):
+        """A dense full layer's chunk: the slot's lane under the causal
+        mask, the live key blocks decompressed; ``cache = (latent pool,
+        layer, table_row)``."""
+        pool, keys = None, row
+        if cache is not None:
+            pool, layer, table = cache
+            with jax.named_scope("cache.write"):
+                pool = write_rows(pool, layer, table, positions, row)
+            keys = lane_rows(pool, layer, table,
+                             max(1, ops.KEY_BLOCK // pool.shape[2]))
+        with jax.named_scope("attn.mla_dense_chunk"):
+            mask = jnp.arange(keys.shape[0])[None, :] <= positions[:, None]
+            return self._attend(
+                q, keys, mask.astype(jnp.int8), "attn.mla_chunk_prefill",
+                None if cache is None else positions[-1] + 1), pool
+
     def _chunk_window(self, q, row, positions, live, cache):
         z = self.spec
         C, back = row.shape[0], z.window - 1
@@ -354,7 +379,9 @@ class LatentAttention(nn.Module):
         then each row's kept set is the exact top ``index_topk`` of its
         scores and the absorbed softmax runs over it, in the form the
         slot's table decides (:data:`LANE_FORM_KEPT_SETS`).  Returns
-        ``(out [N W, h], pools)``."""
+        ``(out [N W, h], pools)``.  A dense full layer (``cache``'s pool
+        the latent pool alone) takes the lane form with every row
+        ``<= position`` kept."""
         z = self.spec
         N, W = start.shape[0], x.shape[0] // start.shape[0]
         positions = start if W == 1 else (
@@ -365,6 +392,11 @@ class LatentAttention(nn.Module):
         q, row, c_q = q[:, :, 0], row[:, 0], c_q[:, 0]    # [T, H, D] ...
         w_k, w_v = self._kv_up()
         q_lat = jnp.einsum("thd,rhd->thr", q[..., :z.nope], w_k)
+        if not z.index_topk:
+            lat, pools = self._lanes_dense(q_lat, q[..., z.nope:], row,
+                                           start, positions, cache)
+            out = jnp.einsum("thr,rhd->thd", lat.astype(self.dtype), w_v)
+            return self._out(x, out), pools
         (latent, index), layer, table = cache
         qi, ki, w = jax.vmap(
             lambda xr, cr, p: self._index(xr[None], cr[None], p[None]))(
@@ -404,13 +436,40 @@ class LatentAttention(nn.Module):
                                               layer, lane, bp, ctx)
         kept = ops.kept_mask(scores.reshape(N * W, L), positions,
                              min(z.index_topk, L))
+        lat, latent = self._lane_attend(q_lat, q_rope, kept, latent, layer,
+                                        lane, bp, ctx)
+        return lat, latent, index
+
+    def _lane_attend(self, q_lat, q_rope, kept, latent, layer, lane, bp,
+                     ctx):
+        """``ops.lane_decode`` of ``[T, H, ..]`` query rows under ``kept
+        [T, L]``, the query laid out as a pool row."""
+        z = self.spec
+        N = lane.shape[0]
+        by_lane = lambda t: t.reshape((N, -1) + t.shape[1:])
         q_row = jnp.concatenate([q_lat, q_rope], axis=-1)
         q_row = jnp.pad(q_row, ((0, 0), (0, 0),
                                 (0, latent.shape[-1] - q_row.shape[-1])))
         lat, latent = ops.lane_decode(by_lane(q_row), by_lane(kept), latent,
                                       layer, lane, bp, ctx, z.kv_rank,
                                       z.scale)
-        return lat.reshape((N * W,) + lat.shape[2:]), latent, index
+        return lat.reshape((-1,) + lat.shape[2:]), latent
+
+    def _lanes_dense(self, q_lat, q_rope, row, start, positions, cache):
+        """A dense full layer's rows: written, then each lane's rows read
+        once for all its rows and heads, every row ``<= position`` kept.
+        Returns ``(attended latent, latent pool)``."""
+        latent, layer, table = cache
+        W = positions.shape[0] // start.shape[0]
+        per_row = table if W == 1 else jnp.repeat(table, W, axis=0)
+        with jax.named_scope("cache.write"):
+            latent = write_rows(latent, layer, per_row, positions, row)
+        with jax.named_scope("attn.mla_dense_decode"):
+            lane, bp = ops.lane_pages(table, latent.shape[2])
+            L = lane.shape[1] * latent.shape[2]
+            kept = jnp.arange(L)[None, :] <= positions[:, None]
+            return self._lane_attend(q_lat, q_rope, kept, latent, layer,
+                                     lane, bp, jnp.minimum(start + W, L))
 
     def _kept_rows(self, q_lat, q_rope, qi, w, latent, index, layer, table,
                    positions):

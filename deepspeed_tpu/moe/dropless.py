@@ -243,17 +243,19 @@ def experts(x, combine, counts, wg, wu, wd, act):
 # Sigmoid + bias routing, and the share of the experts a chip holds
 # --------------------------------------------------------------------- #
 def route_scored(x, gate_w, bias, k, renormalize=True, scaling=1.0,
-                 live=None, sum_eps=0.0):
+                 live=None, sum_eps=0.0, scoring="sigmoid"):
     """``noaux_tc`` routing of ``x [T, M]`` through ``gate_w [M, E]``:
-    float32 scores ``sigmoid(x @ gate_w)``, the ``k`` largest of
-    ``score + bias`` chosen (ties to the lower index), gates the chosen
-    SCORES (not the biased ones) over their sum where ``renormalize``
-    (plus ``sum_eps``, a family's guard in that denominator: LFM2's
-    ``1e-6``), times ``scaling``.  Returns ``(choice [T, k] int32, gate
-    [T, k] float32)``; a token that is not ``live`` has gates 0 and
-    choice -1."""
+    float32 scores ``sigmoid(x @ gate_w)`` (``scoring="softmax"``: a
+    softmax over the ``E`` outputs), the ``k`` largest of ``score + bias``
+    chosen (ties to the lower index), gates the chosen SCORES (not the
+    biased ones) over their sum where ``renormalize`` (plus ``sum_eps``, a
+    family's guard in that denominator: LFM2's ``1e-6``), times
+    ``scaling``.  Returns ``(choice [T, k] int32, gate [T, k] float32)``;
+    a token that is not ``live`` has gates 0 and choice -1."""
+    score = {"sigmoid": jax.nn.sigmoid,
+             "softmax": lambda t: jax.nn.softmax(t, axis=-1)}[scoring]
     with jax.named_scope("moe.route"):
-        scores = jax.nn.sigmoid(jnp.matmul(
+        scores = score(jnp.matmul(
             x.astype(jnp.float32), gate_w.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
         _, choice = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
@@ -269,18 +271,33 @@ def route_scored(x, gate_w, bias, k, renormalize=True, scaling=1.0,
         return choice.astype(jnp.int32), gate
 
 
-def held_load(choice, first, count):
+def held_load(choice, first, count, real=None):
     """What a chip that holds experts ``first .. first + count - 1`` sees
     of ``choice [T, k]``: ``(local [T, k]`` — the held expert's index or
     ``count`` for a choice that fell elsewhere or on a dead token —,
     ``counts [count]`` int32, ``elsewhere`` — live choices of absent
-    experts)``."""
+    experts)``.  ``real``: the router's outputs ``>= real`` are no expert
+    (zero-compute choices, :func:`zero_gate`) and are not counted as
+    absent ones."""
     held = (choice >= first) & (choice < first + count)
     local = jnp.where(held, choice - first, count)
     counts = jnp.sum(jax.nn.one_hot(local, count + 1, dtype=jnp.int32),
                      axis=(0, 1))
-    return local, counts[:count], \
-        jnp.sum((choice >= 0) & ~held).astype(jnp.int32)
+    absent = (choice >= 0) & ~held
+    if real is not None:
+        absent = absent & (choice < real)
+    return local, counts[:count], jnp.sum(absent).astype(jnp.int32)
+
+
+def zero_gate(choice, gate, real):
+    """The zero-compute choices of ``choice [T, k]`` — router outputs
+    ``>= real``, identity experts (LongCat-Flash: a chosen one returns its
+    input): ``(gates [T] float32`` — a token's chosen zero experts' gates
+    summed, what multiplies the layer's input —, ``picks`` int32 — live
+    choices that fell on them)``."""
+    zero = choice >= real
+    return jnp.sum(jnp.where(zero, gate, 0.0), axis=1), \
+        jnp.sum(zero).astype(jnp.int32)
 
 
 def combine_of(local, gate, count):

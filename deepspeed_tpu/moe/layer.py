@@ -114,12 +114,20 @@ class MoE(nn.Module):
     # ``num_experts``, this layer holds and computes ``count`` of them
     # and adds nothing for the absent ones (``moe/dropless.py``);
     # ``gate_sum_eps`` is what a family adds to the chosen scores' sum
-    # before dividing by it (LFM2: 1e-6; 0 leaves the division as it is)
+    # before dividing by it (LFM2: 1e-6; 0 leaves the division as it is).
+    # ``noaux_tc=True`` gives ``scoring="softmax"`` the same scored
+    # form (a softmax over the router's outputs, the top-k of score + a
+    # stored bias), and ``zero_experts`` widens the router by that many
+    # zero-compute IDENTITY experts (LongCat-Flash): outputs
+    # ``num_experts ..`` are no expert — a chosen one adds its gate times
+    # the layer's input, computed here for every token, on every chip
     scoring: str = "softmax"
     routed_scaling: float = 1.0
     gate_sum_eps: float = 0.0
     shared_ffn_hidden_size: int = 0
     held_experts: Optional[tuple] = None
+    noaux_tc: bool = False
+    zero_experts: int = 0
 
     @nn.compact
     def __call__(self, x, train=True, live=None):
@@ -128,22 +136,25 @@ class MoE(nn.Module):
         tokens = x.reshape(-1, M)
 
         gate_w = self.param("gate_kernel", nn.initializers.lecun_normal(),
-                            (M, self.num_experts), jnp.float32)
+                            (M, self.num_experts + self.zero_experts),
+                            jnp.float32)
         first, held = self.held_experts or (0, self.num_experts)
         experts = self.expert or ExpertsMLP(
             held, M, self.ffn_hidden_size or 4 * M,
             activation=self.activation, dtype=self.dtype,
             use_bias=self.expert_bias, gated=self.gated)
-        if self.scoring == "sigmoid":
+        if self.scoring == "sigmoid" or self.noaux_tc:
             if self.capacity_factor is not None or not self.gated:
-                raise ValueError("sigmoid + bias routing is the dropless "
+                raise ValueError("score + bias routing is the dropless "
                                  "layer's, over gated experts")
             return self._scored(tokens, gate_w, experts, first, held,
                                 live).reshape(orig_shape).astype(x.dtype), \
                 0.0, None
-        if self.held_experts is not None or self.shared_ffn_hidden_size:
-            raise ValueError("a share of the experts and a shared expert "
-                             "are implemented for scoring='sigmoid'")
+        if self.held_experts is not None or self.shared_ffn_hidden_size \
+                or self.zero_experts:
+            raise ValueError("a share of the experts, a shared expert and "
+                             "zero experts are implemented for the scored "
+                             "form (scoring='sigmoid', or noaux_tc)")
         if self.capacity_factor is None:
             combine, exp_counts = dropless.route(
                 tokens, gate_w, self.k,
@@ -177,18 +188,22 @@ class MoE(nn.Module):
 
     def _scored(self, tokens, gate_w, experts, first, held, live):
         """``shared(x) + sum over the chosen experts that are HELD of
-        gate_e * E_e(x)``; sows the held experts' tokens and the choices
-        that fell on absent experts."""
+        gate_e * E_e(x)`` (+ the chosen zero experts' gates times ``x``);
+        sows the held experts' tokens, the choices that fell on absent
+        experts and — with ``zero_experts`` — those that fell on zero
+        experts."""
         M, F = self.hidden_size, self.shared_ffn_hidden_size
+        real = self.num_experts if self.zero_experts else None
         bias = self.param("select_bias", nn.initializers.zeros,
-                          (self.num_experts,), jnp.float32)
+                          (gate_w.shape[1],), jnp.float32)
         choice, gate = dropless.route_scored(
             tokens, gate_w, bias, self.k,
             renormalize=self.norm_topk_prob and self.k > 1,
             scaling=self.routed_scaling,
             live=None if live is None else live.reshape(-1),
-            sum_eps=self.gate_sum_eps)
-        local, counts, elsewhere = dropless.held_load(choice, first, held)
+            sum_eps=self.gate_sum_eps, scoring=self.scoring)
+        local, counts, elsewhere = dropless.held_load(choice, first, held,
+                                                      real)
         if self.is_initializing() or tokens.shape[0] < dropless.GROUPED_MIN_ROWS:
             y = experts(tokens, routed=(
                 dropless.combine_of(local, gate, held), counts))
@@ -200,9 +215,15 @@ class MoE(nn.Module):
             y = y + dense(M, "shared_down")(
                 self.activation(dense(F, "shared_gate")(tokens))
                 * dense(F, "shared_up")(tokens))
+        sown = [("expert_tokens", counts), ("elsewhere", elsewhere)]
+        if self.zero_experts:
+            with jax.named_scope("moe.zero_experts"):
+                kept, picks = dropless.zero_gate(choice, gate, real)
+                y = (y.astype(jnp.float32) + kept[:, None]
+                     * tokens.astype(jnp.float32)).astype(y.dtype)
+            sown.append(("zero", picks))
         if not self.is_initializing():
-            for name, value in (("expert_tokens", counts),
-                                ("elsewhere", elsewhere)):
+            for name, value in sown:
                 self.sow("moe_stats", name, value,
                          reduce_fn=lambda _, new: new, init_fn=lambda: None)
         return y

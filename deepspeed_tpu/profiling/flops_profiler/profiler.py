@@ -332,6 +332,13 @@ SCOPE_PARTS = (
     (r"mtp\.combine|eh_proj", "mtp.combine"),
     (r"mtp\.block", "residual"),
     (r"mtp\.head", "head"),
+    # a shortcut-connected double layer (``models/longcat.py``): what its
+    # scopes hold outside a module or kernel the rows below know — the zero
+    # experts' identity part and the branch's adds; the dense latent
+    # layer's masks and query rows around its kernels
+    (r"scmoe\.experts|moe\.zero_experts", "moe.experts"),
+    (r"scmoe\.dense_ffn", "mlp"),
+    (r"attn\.mla_dense_(chunk|decode)", "attn.core"),
     # the layer scan's own operations (``scan_layers``): a layer's slice
     # out of the stacked parameters and saved residuals, the saves' and the
     # gradients' writes back into the stacks, the stacks' zeros and copies
@@ -354,18 +361,18 @@ SCOPE_PARTS = (
     (r"\w+\._(chunk_full|chunk_window|attend|lanes|kept_rows)", "attn.core"),
     (r"(q|k|v|qkv|o|out)_proj|(q|k)_(layer)?norm|\w+\._(project|out|index)",
      "attn.proj"),
-    (r"gate_proj|up_proj|down_proj|shared_(gate|up|down)|mlp|feed_forward",
-     "mlp"),
+    (r"gate_proj|up_proj|down_proj|shared_(gate|up|down)|mlp(_\d+)?"
+     r"|feed_forward", "mlp"),
     (r"moe_mlp(\.\w+)?|ExpertsMLP_\d+", "moe.experts"),
     (r"conv", "conv.short"),
     (r"final_norm|head_norm|embedding_norm|lm_head|project_out|\w+\._head",
      "head"),
     (r"embed_tokens|embed_positions|project_in", "embed"),
-    (r"\w+_norm", "norm"),
+    (r"\w+_norm(_\d+)?", "norm"),
     # what the attention module does outside its projections: the head
     # split's reshapes and copies around the kernels and, where no kernel
     # runs (the non-flash fallback), the score and value matmuls themselves
-    (r"attn(\.\w+)?|self_attn(\.\w+)?", "attn.core"),
+    (r"attn(_\d+)?(\.\w+)?|self_attn(\.\w+)?", "attn.core"),
     # what a block does between its modules: the residual adds
     (r"layers(_\d+)?", "residual"),
 )

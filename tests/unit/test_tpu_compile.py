@@ -609,6 +609,50 @@ def test_glm5_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     assert 12.3e9 < total < 14.5e9, f"{total / 1e9:.2f} GB"
 
 
+@pytest.mark.parametrize("program", ["chunk_step", "decode_block"])
+def test_longcat_slot_programs_compile_at_the_cells_sizes(program, one_chip,
+                                                          mosaic):
+    """The two programs ``longcat-serve-agentgen-batch`` runs, whole, as
+    ``serving/slots.py`` builds them at the cell's own settings: four
+    double layers — eight dense latent attentions over ONE pool of eight
+    layers (no index pool), four expert layers of 16 held experts —, the
+    pool aliased input -> output, 10.35 GB of weights + 2.77 GB of pool and
+    the programs' temporaries inside one chip."""
+    from deepspeed_tpu.inference.serving import slots
+    c = _slot_programs_of("longcat-serve-agentgen-batch", "longcat",
+                          one_chip)
+    module, s, chunk, pages = c.module, c.serving, c.chunk, c.pages
+    params, pool, ints, on_chip = c.params, c.pool, c.ints, c.on_chip
+    assert (pages.pages_per_slot, pages.num_pages) == (33, 128 * 33 + 1)
+    assert {k: v.shape for k, v in pool.items()} \
+        == {"latent": (8, 128 * 33 + 1, 64, 640)}
+    if program == "chunk_step":
+        compiled = slots.make_chunk_fn(module, c.declared, None).lower(
+            params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
+            ints(1)).compile()
+        calls = 8 * 2 + 4     # decompress, flash a sublayer; the experts
+        _no_pool_layer_is_sliced_out(compiled.as_text(), pages)
+    else:
+        n = s["num_slots"]
+        state = on_chip({k: jnp.asarray(v) for k, v in
+                         slots.init_slot_state(n).items()})
+        rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                   sharding=one_chip)
+        compiled = slots.make_decode_block_fn(
+            module, c.declared, lambda logits, rng: jnp.argmax(logits, -1),
+            None, s["decode_block"], pages.cache_len).lower(
+                params, pool, state, ints(n, pages.table_width),
+                rng).compile()
+        calls = 8 + 4         # lane decode a sublayer; the experts
+    assert compiled.as_text().count("tpu_custom_call") >= calls
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * 2 for x in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 13.0e9 < total < 15.5e9, f"{total / 1e9:.2f} GB"
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
     fn, shapes = CASES[case]()

@@ -31,12 +31,15 @@ Pallas kernels, each findable in a device trace by its own name:
 * ``attn.dsa_lane_index`` (:func:`lane_index_scores`) and
   ``attn.mla_lane_decode`` (:func:`lane_decode`) — a decode step's or a
   verify window's LANE form: one grid step a lane, the pool whole in HBM,
-  the lane's pages fetched through its table in blocks of ~512 keys
-  (double-buffered) ONCE for all the lane's rows and heads — the index
-  scores of its ``W`` rows, then the absorbed softmax of ``W x heads``
-  query rows under each row's kept mask, the latent row key and value at
-  once.  Blocks past the lane's last position, and a dead lane's, are not
-  fetched.
+  the lane's pages fetched through its table in blocks of ~512 keys ONCE
+  for all the lane's rows and heads — the index scores of its ``W`` rows,
+  then the absorbed softmax of ``W x heads`` query rows under each row's
+  kept mask, the latent row key and value at once.  A call walks its
+  (live lane, block) pairs as ONE pipeline through a two-block ring that
+  outlives the grid step: while a lane folds its last block the first
+  block of the next live lane is on its way, so the call's first block
+  alone is waited for with nothing folding.  Pages past the lane's last
+  position, and a dead lane's, are not fetched.
 
 and three parts left to XLA, each under a ``jax.named_scope`` of its name
 (the per-row form of a decode step, where the context is many times the
@@ -61,6 +64,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.transformer.flash_attention import _interpret
+from deepspeed_tpu.ops.transformer.paged_attention import (_live_pages,
+                                                           _zero_value_tail)
 
 NEG = -1e30
 # a chunk's keys are scored, decompressed and attended in blocks of this
@@ -140,51 +145,105 @@ def index_scores_rows(q, w, k):
                           w.astype(jnp.float32))
 
 
-def _lane_blocks(ctx_ref, pages_ref, n, bk):
-    """Key blocks lane ``n`` walks: those up to its last live position —
-    none for a DEAD lane, one whose table points at the trash page."""
-    return jnp.where(pages_ref[n, 0] == 0, 0, (ctx_ref[n] + bk - 1) // bk)
+def _lane_walk(ctx_ref, layer_ref, pages_ref, pool, buf, sem, slot_ref, fold,
+               *, page, bp):
+    """This grid step's part of the call's ONE pipeline over its (live
+    lane, block of ``bp`` pages) pairs: ``fold(i, slot)`` on each block
+    ``i`` of lane ``program_id(0)``, in ``buf[slot]``, while what follows
+    it in the call's sequence is on its way into the other buffer — this
+    lane's block ``i + 1`` or, behind its LAST block, the first block of
+    the next LIVE lane (a scalar look-ahead over the table; a DEAD lane,
+    its table at the trash page, is walked by nobody).  The buffer a
+    lane's first block lands in is carried from grid step to grid step in
+    ``slot_ref`` (SMEM), so only the call's very first block is waited for
+    with nothing folding.  A block copies its live pages alone — whole
+    pages up to the lane's last position; start and wait count the same
+    descriptors —: the other rows of ``buf[slot]`` keep what an earlier
+    block, an earlier lane or nobody wrote."""
+    n, lanes, width = pl.program_id(0), pages_ref.shape[0], pages_ref.shape[1]
 
+    def live_pages(r):
+        return _live_pages(ctx_ref, pages_ref, jnp.minimum(r, lanes - 1),
+                           page, width)
 
-def _lane_fetch(pages_ref, layer_ref, pool, buf, sem, n, page, bp):
-    """``(start, wait)`` of key block ``i`` of lane ``n``: its ``bp``
-    pages through the table into the rows of ``buf[i % 2]``."""
-    def each(i, fn):
-        slot = i % 2
-        for j in range(bp):
+    def next_live(r):
+        # the first live lane from ``r`` on, ``lanes`` where there is none
+        return jax.lax.while_loop(
+            lambda r: jnp.logical_and(r < lanes, live_pages(r) == 0),
+            lambda r: r + 1, r)
+
+    def each_page(fn, lane, first, count, slot):
+        # the copies of pages ``first .. first + count`` of ``lane``'s
+        # table into the rows of ``buf[slot]``.  A whole block — every
+        # block of a lane but its last — is straight-line code: the
+        # counted loop costs each descriptor a branch, more than the
+        # pages it leaves out save a lane of many blocks
+        def one(j, carry=None):
             fn(pltpu.make_async_copy(
-                pool.at[layer_ref[0], pages_ref[n, i * bp + j]],
-                buf.at[slot, pl.ds(j * page, page)], sem.at[slot]))
+                pool.at[layer_ref[0], pages_ref[lane, first + j]],
+                buf.at[slot, pl.ds(pl.multiple_of(j * page, page), page)],
+                sem.at[slot]))
 
-    return (lambda i: each(i, lambda cp: cp.start()),
-            lambda i: each(i, lambda cp: cp.wait()))
+        @pl.when(count == bp)
+        def _whole():
+            for j in range(bp):
+                one(j)
 
+        @pl.when(count < bp)
+        def _some():
+            jax.lax.fori_loop(0, count, one, None)
 
-def _lane_walk(n_blocks, start, wait, fold):
-    """Block ``i + 1`` arrives while ``fold(i, slot)`` runs on block
-    ``i``."""
-    @pl.when(n_blocks > 0)
-    def _first():
-        start(0)
+    def first_block(fn, lane, slot):
+        each_page(fn, jnp.minimum(lane, lanes - 1), 0,
+                  jnp.where(lane < lanes,
+                            jnp.minimum(live_pages(lane), bp), 0), slot)
 
-    def body(i, carry):
-        @pl.when(i + 1 < n_blocks)
-        def _next():
-            start(i + 1)
+    def start(cp):
+        cp.start()
 
-        wait(i)
-        fold(i, i % 2)
-        return carry
+    def wait(cp):
+        cp.wait()
 
-    jax.lax.fori_loop(0, n_blocks, body, None)
+    @pl.when(n == 0)
+    def _exposed():
+        slot_ref[0] = 0
+        first_block(start, next_live(0), 0)
+
+    n_pages = live_pages(n)
+
+    @pl.when(n_pages > 0)
+    def _live():
+        slot0 = slot_ref[0]
+        n_blocks = (n_pages + bp - 1) // bp
+        nxt = next_live(n + 1)
+
+        def body(i, carry):
+            slot = (slot0 + i) % 2
+
+            @pl.when(i + 1 < n_blocks)
+            def _next_block():
+                each_page(start, n, (i + 1) * bp,
+                          jnp.minimum(n_pages - (i + 1) * bp, bp), 1 - slot)
+
+            @pl.when(i + 1 == n_blocks)
+            def _hand_over():
+                first_block(start, nxt, 1 - slot)
+
+            each_page(wait, n, i * bp, jnp.minimum(n_pages - i * bp, bp),
+                      slot)
+            fold(i, slot)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, body, None)
+        slot_ref[0] = (slot0 + n_blocks) % 2
 
 
 def _lane_index_kernel(ctx_ref, layer_ref, pages_ref, q_ref, w_ref, pool,
-                       o_ref, _pool_out, buf, sem, *, page, bp, rows, heads):
-    n = pl.program_id(0)
+                       o_ref, _pool_out, buf, sem, slot_ref, *, page, bp,
+                       rows, heads):
     bk = bp * page
-    start, wait = _lane_fetch(pages_ref, layer_ref, pool, buf, sem, n, page,
-                              bp)
+    # positions in the lane's live pages: the rows past them are not fetched
+    live = (ctx_ref[pl.program_id(0)] + page - 1) // page * page
     o_ref[...] = jnp.full(o_ref.shape, NEG, o_ref.dtype)
 
     def fold(i, slot):
@@ -192,21 +251,30 @@ def _lane_index_kernel(ctx_ref, layer_ref, pages_ref, q_ref, w_ref, pool,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         part = jnp.maximum(s, 0.0) * w_ref[0][:, :1]
-        o_ref[0, :, pl.ds(pl.multiple_of(i * bk, bk), bk)] = jnp.concatenate(
+        scores = jnp.concatenate(
             [jnp.sum(part[r * heads:(r + 1) * heads], axis=0, keepdims=True)
              for r in range(rows)], axis=0)
+        pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        o_ref[0, :, pl.ds(pl.multiple_of(i * bk, bk), bk)] = jnp.where(
+            pos < live, scores, NEG)
 
-    _lane_walk(_lane_blocks(ctx_ref, pages_ref, n, bk), start, wait, fold)
+    _lane_walk(ctx_ref, layer_ref, pages_ref, pool, buf, sem, slot_ref, fold,
+               page=page, bp=bp)
 
 
 def _lane_call(kernel, name, ctx, layer, table, operands, pool, out_width,
                scratch, bp):
     """One grid step a lane, the pool whole in HBM, the table and the
-    lanes' contexts in SMEM; ``operands [N, ...]`` a lane's block each.
-    Returns ``(out [N, *out_width] float32, pool)`` — the pool handed
-    through as an aliased output nothing writes: the next cache write then
-    follows the kernel in the program's dataflow, and XLA has no earlier
-    value of the pool to keep or recompute beside it."""
+    lanes' contexts in SMEM; ``operands [N, ...]`` a lane's block each,
+    brought and taken by the grid's own pipeline while the kernel walks
+    the call's (live lane, block) pairs through a two-block ring that
+    outlives the grid step (:func:`_lane_walk`: the ring, its semaphores
+    and the buffer the next lane starts in are the last three scratch
+    operands).  Returns ``(out [N, *out_width] float32, pool)`` — the
+    pool handed through as an aliased output nothing writes: the next
+    cache write then follows the kernel in the program's dataflow, and
+    XLA has no earlier value of the pool to keep or recompute beside
+    it."""
     N = table.shape[0]
     page = pool.shape[2]
     lane = lambda n, *refs: (n, 0, 0)
@@ -220,7 +288,8 @@ def _lane_call(kernel, name, ctx, layer, table, operands, pool, out_width,
             out_specs=[pl.BlockSpec((1,) + out_width, lane), whole],
             scratch_shapes=scratch + [
                 pltpu.VMEM((2, bp * page, pool.shape[-1]), pool.dtype),
-                pltpu.SemaphoreType.DMA((2,))]),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32)]),
         out_shape=[jax.ShapeDtypeStruct((N,) + out_width, jnp.float32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         input_output_aliases={3 + len(operands): 1},
@@ -247,8 +316,8 @@ def lane_index_scores(q, w, pool, layer, table, bp, ctx):
     ``n`` a multiple of ``bp``; ``L = n x page``) from ``pool [layers,
     pages, page, D]``, each lane's keys fetched once for its rows: ``q
     [N, W, J, D]``, ``w [N, W, J]`` (the score's constant factors folded
-    in).  Key blocks past ``ctx[n]`` positions, and every block of a dead
-    lane (its table at the trash page), are not fetched: ``NEG``."""
+    in).  Pages past ``ctx[n]`` positions, and every page of a dead lane
+    (its table at the trash page), are not fetched: ``NEG``."""
     N, W, J, D = q.shape
     page = pool.shape[2]
     L = table.shape[1] * page
@@ -389,17 +458,19 @@ def sparse_decode(q_lat, q_rope, rows, valid, rank, scale):
 
 
 def _lane_decode_kernel(ctx_ref, layer_ref, pages_ref, q_ref, keep_ref, pool,
-                        o_ref, _pool_out, m_ref, l_ref, acc_ref, buf, sem, *,
-                        page, bp, rows, heads, rank, scale):
-    n = pl.program_id(0)
+                        o_ref, _pool_out, m_ref, l_ref, acc_ref, buf, sem,
+                        slot_ref, *, page, bp, rows, heads, rank, scale):
     bk = bp * page
-    start, wait = _lane_fetch(pages_ref, layer_ref, pool, buf, sem, n, page,
-                              bp)
+    ctx = ctx_ref[pl.program_id(0)]
     m_ref[...] = jnp.full(m_ref.shape, NEG, m_ref.dtype)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def fold(i, slot):
+        # a row is key AND value: the mask leaves a row past the lane's
+        # last position — stale where its page was not fetched — a
+        # probability of 0, and 0 against a NaN is a NaN
+        _zero_value_tail(buf, slot, i * bk, ctx, page=page, bp=bp)
         keys = buf[slot]
         s = jax.lax.dot_general(q_ref[0], keys, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -418,7 +489,8 @@ def _lane_decode_kernel(ctx_ref, layer_ref, pages_ref, q_ref, keep_ref, pool,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    _lane_walk(_lane_blocks(ctx_ref, pages_ref, n, bk), start, wait, fold)
+    _lane_walk(ctx_ref, layer_ref, pages_ref, pool, buf, sem, slot_ref, fold,
+               page=page, bp=bp)
     o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
@@ -433,7 +505,7 @@ def lane_decode(q, kept, pool, layer, table, bp, ctx, rank, scale):
     rope part | zeros]``, laid out as a pool row, so a score is ONE
     product —, ``kept [N, W, L]`` (nonzero: attended; ``L = n x page``,
     ``table [N, n]``, ``n`` a multiple of ``bp``), ``ctx [N]`` the
-    positions a lane has (blocks past them, and a dead lane's, are not
+    positions a lane has (pages past them, and a dead lane's, are not
     fetched).  Returns ``(the attended latent [N, W, H, rank] float32 —
     zeros for a row that keeps nothing —, pool)``."""
     N, W, H, width = q.shape

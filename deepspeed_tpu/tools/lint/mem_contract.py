@@ -97,6 +97,14 @@ DECLARED_GROWTH["serving.prefill_chunk"] = (
     "the locked entry point is a dispatch of FOUR chunk rows since PR 38 "
     "(one row before): 4x the activations for 4x the tokens, one pass of "
     "the weights — docs/serving.md 'Prefill dispatches'")
+DECLARED_GROWTH["serving.spec_block"] = (
+    "the lane kernels walk a call's (live lane, block) pairs as one "
+    "pipeline since PR 48: the CPU interpreter lowers the look-ahead over "
+    "the table, the hand-over's branches and the zeroed tail as XLA conds "
+    "and loops with temporaries of their own (+27 KB at the toy size); on "
+    "the chip they are scalar code of a Mosaic kernel whose scratch grew "
+    "by one SMEM word — tests/unit/test_tpu_compile.py holds the programs' "
+    "memory at the cells' sizes")
 
 
 # ------------------------------------------------------------------ #

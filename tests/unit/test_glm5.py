@@ -496,14 +496,38 @@ def test_chunk_work_counts_the_live_blocks(program, layers):
 
 
 # ---- (h) the lane kernels against plain math ---------------------------- #
-@pytest.mark.parametrize("rows,contexts", [
-    (1, (37, 64, 5)), (2, (38, 0, 64)), (2, (17, 33, 49)), (1, (1, 2, 3))])
-def test_the_lane_kernels_are_the_plain_math(rows, contexts):
+# page 8, blocks of 4 pages (32 keys), 8 pages a lane; context 0: a dead lane
+_LANE_CASES = {
+    "inside_a_page_a_block_and_on_the_tables_edge": (1, (37, 64, 5)),
+    "a_dead_lane_between_two_live": (2, (38, 0, 64)),
+    "two_rows_a_lane": (2, (17, 33, 49)),
+    "contexts_of_a_few_rows": (1, (1, 2, 3)),
+    "a_dead_lane_first": (1, (0, 41, 20)),
+    "a_dead_lane_last": (2, (41, 20, 0)),
+    "two_dead_lanes_in_a_row": (1, (9, 0, 0, 50, 0, 0, 33)),
+    "every_lane_dead": (2, (0, 0, 0)),
+    "a_lane_of_one_page": (1, (8, 7, 2)),
+    "on_a_page_edge_and_on_a_block_edge": (2, (24, 32, 40, 64)),
+    # one block a lane, then two: the ring's parity is carried
+    "odd_blocks_then_even": (1, (30, 64, 64, 12, 40, 0, 33, 64)),
+}
+
+
+@pytest.mark.parametrize("poison", [False, True],
+                         ids=["finite_pool", "nan_off_the_live_pages"])
+@pytest.mark.parametrize("case", sorted(_LANE_CASES))
+def test_the_lane_kernels_are_the_plain_math(case, poison):
     """``attn.dsa_lane_index`` and ``attn.mla_lane_decode`` over a pool
-    read THROUGH a shuffled table — contexts that end inside a page, inside
-    a block and on the table's edge, a dead lane (context 0: its table at
-    the trash page) — against the gathered lane in plain ``jnp``."""
+    read THROUGH a shuffled table — contexts that end inside a page, on a
+    page's edge, on a block's and on the table's, dead lanes (context 0:
+    the table at the trash page) wherever the hand-over from lane to lane
+    can meet them, block counts that flip the ring's parity — against the
+    gathered lane in plain ``jnp``.  A lane's pages past its last live one
+    are not fetched: with NaN in every such page, and in the trash page,
+    the results are the same and finite, and the scores past the last
+    live PAGE read ``NEG``."""
     from deepspeed_tpu.ops.transformer import latent_attention as ops
+    rows, contexts = _LANE_CASES[case]
     rng = np.random.default_rng(rows + sum(contexts))
     N, page, n, H, J, D, rank, rope = len(contexts), 8, 8, 4, 2, 16, 32, 8
     width, layers, pages = 128, 2, 1 + N * n
@@ -514,14 +538,26 @@ def test_the_lane_kernels_are_the_plain_math(rows, contexts):
     table = np.where(ctx[:, None] > 0, table, 0).astype(np.int32)
     lane, bp = ops.lane_pages(jnp.asarray(table), page, block_keys=32)
     L = lane.shape[1] * page
+    live_pages = -(-ctx // page)
+    # the reference reads the clean pools; the kernels, where ``poison``,
+    # pools that hold NaN wherever they have no business reading
+    clean_latent, clean_index = latent, index
+    if poison:
+        dead = np.ones((pages,), bool)
+        for row, k in zip(table, live_pages):
+            dead[row[:k]] = False
+        dead[0] = True
+        latent = latent.at[:, dead].set(jnp.nan)
+        index = index.at[:, dead].set(jnp.nan)
     q, w = f(N, rows, J, D), f(N, rows, J)
     got, same = ops.lane_index_scores(q, w, index, 1, lane, bp,
                                       jnp.asarray(ctx))
-    assert (same == index).all()
-    keys = index[1][lane].reshape(N, L, D)
+    assert np.array_equal(same, index, equal_nan=True)
+    keys = clean_index[1][lane].reshape(N, L, D)
     want = jnp.einsum("nwjl,nwj->nwl", jnp.maximum(
         jnp.einsum("nwjd,nld->nwjl", q, keys), 0.0), w)
-    scored = (np.arange(L)[None] < -(-ctx[:, None] // 32) * 32)[:, None]
+    scored = (np.arange(L)[None] < live_pages[:, None] * page)[:, None]
+    assert np.isfinite(np.asarray(got)).all()
     assert np.abs(np.where(scored, got - want, 0.0)).max() < 1e-4
     assert (np.where(scored, ops.NEG, got) <= ops.NEG / 2).all()
     # each row keeps a random half of the positions it may see
@@ -534,8 +570,8 @@ def test_the_lane_kernels_are_the_plain_math(rows, contexts):
                  ((0, 0), (0, 0), (0, 0), (0, width - rank - rope)))
     got, same = ops.lane_decode(qq, jnp.asarray(kept, jnp.int8), latent, 0,
                                 lane, bp, jnp.asarray(ctx), rank, 0.25)
-    assert (same == latent).all()
-    lat = latent[0][lane].reshape(N, L, width)
+    assert np.array_equal(same, latent, equal_nan=True)
+    lat = clean_latent[0][lane].reshape(N, L, width)
     s = jnp.einsum("nwhd,nld->nwhl", qq, lat) * 0.25
     on = jnp.asarray(kept)[:, :, None, :]
     p = jnp.where(on, jnp.exp(s - jnp.max(jnp.where(on, s, -1e30), -1,
@@ -543,5 +579,7 @@ def test_the_lane_kernels_are_the_plain_math(rows, contexts):
     want = jnp.einsum("nwhl,nlr->nwhr", p, lat[..., :rank]) \
         / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
     assert got.shape == (N, rows, H, rank)
+    assert np.isfinite(np.asarray(got)).all()
     assert np.abs(np.asarray(got - want)).max() < 1e-4
     assert np.abs(np.asarray(got[0, 0])).max() == 0.0
+    assert np.abs(np.asarray(got)[ctx == 0]).max(initial=0.0) == 0.0

@@ -540,6 +540,26 @@ def _no_pool_layer_is_sliced_out(text, pages):
     assert not found, found
 
 
+def _lane_calls_alias_their_pool(compiled, name, layers, pool):
+    """The program's Mosaic calls of lane kernel ``name``: one a pool layer,
+    each handing its pool through as an aliased output (the call's last
+    operand), and no pool-shaped value anywhere in the program that is a
+    COPY — no second pool stands beside the block."""
+    text = compiled.as_text()
+    calls = re.findall(rf"%{re.escape(name)}[.\d]* = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert len(calls) == layers, (name, len(calls))
+    for call in calls:
+        operands = call.split("operand_layout_constraints={")[1] \
+            .split("}, output_to_operand_aliasing")[0].count("[")
+        assert "output_to_operand_aliasing={{1}: (%d, {})}" % (operands - 1) \
+            in call, call[:400]
+    for leaf in jax.tree.leaves(pool):
+        shape = ",".join(str(d) for d in leaf.shape)
+        copied = re.findall(rf"= \w+\[{shape}\]\S* copy\([^\n]*", text)
+        assert not copied, [line[:160] for line in copied]
+
+
 def test_dots3_chunk_step_compiles_at_the_cells_sizes(one_chip, mosaic):
     """The chunk program ``dots3-serve-longdoc-batch`` runs, whole: index,
     top-k, decompress and flash a full layer (two), flash a window layer
@@ -600,6 +620,9 @@ def test_glm5_slot_programs_compile_at_the_cells_sizes(program, one_chip,
         # the expert kernel a routed layer; lane index, top-k, lane
         # decode a pool layer
         calls = 5 + 6 * 3
+        for name in ("attn.dsa_lane_index", "attn.mla_lane_decode"):
+            _lane_calls_alias_their_pool(compiled, name, 6, pool)
+        assert compiled.as_text().count("tpu_custom_call") == calls
     assert compiled.as_text().count("tpu_custom_call") >= calls
     mem = compiled.memory_analysis()
     pool_bytes = sum(x.size * 2 for x in jax.tree.leaves(pool))
@@ -644,6 +667,9 @@ def test_longcat_slot_programs_compile_at_the_cells_sizes(program, one_chip,
                 params, pool, state, ints(n, pages.table_width),
                 rng).compile()
         calls = 8 + 4         # lane decode a sublayer; the experts
+        _lane_calls_alias_their_pool(compiled, "attn.mla_lane_decode", 8,
+                                     pool)
+        assert compiled.as_text().count("tpu_custom_call") == calls
     assert compiled.as_text().count("tpu_custom_call") >= calls
     mem = compiled.memory_analysis()
     pool_bytes = sum(x.size * 2 for x in jax.tree.leaves(pool))

@@ -82,7 +82,7 @@ def bench_flash_attention(b=2, s=2048, h=32, d=64, iters=20, bwd=False):
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops.transformer.flash_attention import (
-        flash_attention, tile_plan)
+        backward_plans, flash_attention, tile_plan)
 
     rng = np.random.default_rng(0)
     q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.bfloat16)
@@ -106,9 +106,12 @@ def bench_flash_attention(b=2, s=2048, h=32, d=64, iters=20, bwd=False):
     dt = _timeit_chained(step, (q, k, v), iters)
     needed = s * (s + 1) / 2
     executed = {}
-    for kernel in ("fwd", "dq", "dkv") if bwd else ("fwd",):
-        c = tile_plan(kernel, s, s, d, q.dtype, True).counts()
-        executed[kernel] = round(
+    plans = [tile_plan("fwd", s, s, d, q.dtype, True)]
+    if bwd:
+        plans += backward_plans(s, s, d, q.dtype, True)
+    for plan in plans:
+        c = plan.counts()
+        executed[plan.kernel] = round(
             c["tiles_run"] * c["tile_q"] * c["tile_k"] / needed, 3)
     # causal attention flops: 2 gemms, half the square
     flops = (2 * 2 * b * h * s * s * d) / 2 * (3.5 if bwd else 1)
@@ -139,11 +142,16 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--ops", default="adam,flash_fwd,flash_bwd,quant")
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--flash-shape", default="2,2048,32,64",
+                    help="b,s,h,d of the flash ops (default: the "
+                         "opt13b-sft-1chip cell's)")
     args = ap.parse_args()
+    b, s, h, d = (int(x) for x in args.flash_shape.split(","))
+    flash = dict(b=b, s=s, h=h, d=d, iters=args.iters)
     runners = {
         "adam": lambda: bench_adam(iters=args.iters),
-        "flash_fwd": lambda: bench_flash_attention(iters=args.iters),
-        "flash_bwd": lambda: bench_flash_attention(iters=args.iters, bwd=True),
+        "flash_fwd": lambda: bench_flash_attention(**flash),
+        "flash_bwd": lambda: bench_flash_attention(**flash, bwd=True),
         "quant": lambda: bench_quantizer(iters=args.iters),
     }
     for name in args.ops.split(","):

@@ -16,9 +16,15 @@ axis itself: an in-kernel loop over square tiles that stops at the causal
 diagonal, masks only the tiles the diagonal crosses (and a ragged tail), and
 carries the running statistics as values (:func:`tile_plan` decides the
 tiles and is where the loop bounds come from).  The backward pass uses the
-saved LSE (log-sum-exp) rows, with two kernels: one accumulating dq over key
-tiles, one accumulating (dk, dv) over query tiles — the standard
-flash-attention-2 decomposition.
+saved LSE (log-sum-exp) rows and is ONE kernel, ``attn.flash_dq_dkv``: a key
+block resident, the scores, the probabilities and ``ds`` of its slab of
+queries computed once, and dq, dk and dv all taken from them — five matmuls
+and one pass of the VPU over the score slab, where the flash-attention-2
+decomposition (a kernel accumulating dq over key tiles, one accumulating
+dk and dv over query tiles) makes seven and two.  dq is summed over the key
+blocks in VMEM, so a head's whole query axis has to fit there
+(``_DQ_SUM_BYTES``, decided in :func:`tile_plan` from the shape); a longer
+head takes that pair, ``attn.flash_dq`` and ``attn.flash_dkv``.
 """
 
 import functools
@@ -41,9 +47,11 @@ def _env_block(name):
 
 
 # The GRID's blocks.  Each kernel keeps one block of its own axis resident
-# (a query block in ``attn.flash_fwd`` / ``attn.flash_dq``, a key block in
-# ``attn.flash_dkv``) and is handed the opposite axis in MAJOR blocks, inside
-# which it walks the causal triangle itself (:func:`tile_plan`).  Skipping
+# (a query block in ``attn.flash_fwd``, a key block in the backward's
+# ``attn.flash_dq_dkv``; of the pair a long head falls back to,
+# ``attn.flash_dq`` a query block and ``attn.flash_dkv`` a key block) and is
+# handed the opposite axis in MAJOR blocks, inside which it walks the causal
+# triangle itself (:func:`tile_plan`).  Skipping
 # the masked half with the GRID does not pay on a v5e: every step along the
 # walked axis is another softmax pass (two lane reductions a row, a rescale
 # of the accumulator, the statistics' stores), and at 512 x 512 grid blocks
@@ -81,6 +89,10 @@ def pallas_supported():
 # the walked axis one grid step holds in VMEM.
 _TILE = 512
 _MAJOR = 2048
+# The fused backward sums a head's whole dq in VMEM, float32, while it goes
+# through the key blocks: the most bytes of it.  2 MiB is 8192 x 64 or
+# 4096 x 128; a longer head takes the pair of kernels, dq from its own.
+_DQ_SUM_BYTES = 2 * 2 ** 20
 
 
 class TilePlan(NamedTuple):
@@ -88,7 +100,7 @@ class TilePlan(NamedTuple):
     resident block of its own axis against ``tile_q x tile_k`` tiles of the
     walked axis' major block.  The kernels take their walks' bounds from
     :meth:`segments`; :meth:`counts` sums the same bounds over the grid."""
-    kernel: str                 # "fwd" | "dq" | "dkv"
+    kernel: str                 # "fwd" | "dq" | "dkv" | "dq_dkv"
     tile_q: int
     tile_k: int
     block_q: int                # grid blocks: the resident one is its tile
@@ -99,7 +111,7 @@ class TilePlan(NamedTuple):
 
     @property
     def walks_q(self):
-        return self.kernel == "dkv"
+        return self.kernel in ("dkv", "dq_dkv")
 
     @property
     def _axes(self):
@@ -187,8 +199,14 @@ def tile_plan(kernel, q_len, kv_len, head_dim, dtype, causal,
     """The one place the tiling is decided, from what the call sees.
     ``block_q`` / ``block_k`` are the grid's blocks where the caller (or a
     ``DSTPU_FLASH_BLOCK_*`` variable) fixes them: the resident axis' block
-    is that kernel's tile, the walked axis' block the major block."""
-    walks_q = kernel == "dkv"
+    is that kernel's tile, the walked axis' block the major block.
+
+    The backward's FORM is decided here too: asked for ``"dq_dkv"``, the
+    fused kernel, the answer is the pair's ``"dkv"`` plan where a head's
+    float32 dq, in whole major blocks, is more than ``_DQ_SUM_BYTES`` —
+    by the query length, the head size and the dtype (it sets the major
+    block), and by nothing else (:func:`backward_plans`)."""
+    walks_q = kernel in ("dkv", "dq_dkv")
     res_blk, walk_blk = (block_k, block_q) if walks_q else (block_q, block_k)
     res_len, walk_len = (kv_len, q_len) if walks_q else (q_len, kv_len)
     # two walked operands, double-buffered, stay within ~4 MB of VMEM
@@ -201,6 +219,9 @@ def tile_plan(kernel, q_len, kv_len, head_dim, dtype, causal,
                  if major % t == 0), major)
     tile_q, tile_k = (tile, res) if walks_q else (res, tile)
     block_q, block_k = (major, res) if walks_q else (res, major)
+    if (kernel == "dq_dkv"
+            and pl.cdiv(q_len, major) * major * head_dim * 4 > _DQ_SUM_BYTES):
+        kernel = "dkv"      # no room for the head's dq: the pair's half
     return TilePlan(kernel, tile_q, tile_k, block_q, block_k,
                     q_len, kv_len, bool(causal))
 
@@ -208,10 +229,12 @@ def tile_plan(kernel, q_len, kv_len, head_dim, dtype, causal,
 def _planned_call(plan, name, kernel, **kw):
     """``pl.pallas_call`` under the plan's event: one
     ``dstpu.kernel.tile_plan`` span a traced call, the counts as its args."""
+    # the fused backward sums dq over the resident (key) axis too
+    resident = "arbitrary" if plan.kernel == "dq_dkv" else "parallel"
     call = pl.pallas_call(
         kernel, name=name, interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", resident, "arbitrary")),
         **kw)
 
     def run(*operands):
@@ -298,6 +321,7 @@ def _dot(a, b, contract):
 
 _NT = ((1,), (1,))          # a @ b.T
 _NN = ((1,), (0,))          # a @ b
+_TN = ((0,), (0,))          # a.T @ b
 
 
 def _scaled(x, scale):
@@ -454,17 +478,34 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *scr, scale, plan):
-    """The mirror: a key block against the query tiles from its diagonal
-    on.  Scores are held TRANSPOSED, ``[keys, queries]``: ``p.T @ do`` and
-    ``ds.T @ q`` are then plain matmuls (no transposed operand), and the
-    per-query ``lse`` / ``delta`` are row vectors that broadcast along
-    sublanes."""
+                    *rest, scale, plan):
+    """A key block against the query tiles from its diagonal on.  Scores
+    are held TRANSPOSED, ``[keys, queries]``: ``p.T @ do`` and ``ds.T @ q``
+    are then plain matmuls (no transposed operand), and the per-query
+    ``lse`` / ``delta`` are row vectors that broadcast along sublanes.
+
+    As ``attn.flash_dq_dkv`` (the plan's kernel ``dq_dkv``) the same pass
+    over the scores gives dq as well: ``k.T @ ds.T`` is the slab's share of
+    dq, transposed — a plain matmul again, what is transposed for it is the
+    key block ``[tile, D]`` and not the score slab — summed over the key
+    blocks in ``dq_sum`` ``[major blocks, D, queries]``, which covers the
+    head's whole query axis, and turned and stored once a head."""
     ik, iq = pl.program_id(1), pl.program_id(2)
     base = iq * plan.block_q
+    fused = plan.kernel == "dq_dkv"
+    if fused:
+        dq_ref, dk_ref, dv_ref, *scr, dq_sum = rest
+    else:
+        dk_ref, dv_ref, *scr = rest
 
     def fold(first, pieces):
         k, v = k_ref[0, 0], v_ref[0, 0]                   # [tk, d]
+        if fused and plan.kv_len % plan.tile_k:
+            # dq is summed over the keys: rows past the last key are
+            # out-of-bounds reads, and 0 x garbage must stay finite
+            live = ik * plan.tile_k + jax.lax.broadcasted_iota(
+                jnp.int32, (k.shape[0], 1), 0) < plan.kv_len
+            k, v = jnp.where(live, k, 0.0), jnp.where(live, v, 0.0)
         q = _slab_rows(plan, q_ref, base, first, pieces)  # [w, d]
         do = _slab_rows(plan, do_ref, base, first, pieces)
         lanes = slice(first * plan.tile_q,
@@ -480,10 +521,34 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         pt = _mask_runs(plan, jnp.exp(st - lse), pieces, ik * plan.tile_k,
                         base + first * plan.tile_q, 0.0)
         dv = _dot(pt.astype(do.dtype), do, _NN)
-        dst = _scaled(pt * (_dot(v, do, _NT) - delta), scale)
-        return [_dot(dst.astype(q.dtype), q, _NN), dv]
+        dst = _scaled(pt * (_dot(v, do, _NT) - delta), scale).astype(q.dtype)
+        if fused:
+            dq_sum[iq, :, lanes] += _dot(k, dst, _TN)     # [d, w]
+        return [_dot(dst, q, _NN), dv]
+
+    if fused:
+        @pl.when((ik == 0) & (iq == 0))
+        def _init():
+            dq_sum[:] = jnp.zeros_like(dq_sum)
 
     _accumulate(plan, ik, iq, [dk_ref, dv_ref], scr, fold)
+
+    if fused:
+        @pl.when((ik == plan.n_resident - 1) & (iq == plan.n_major - 1))
+        def _finish():
+            for im in range(plan.n_major):
+                rows = slice(im * plan.block_q, (im + 1) * plan.block_q)
+                dq_ref[0, 0, rows, :] = dq_sum[im].T.astype(dq_ref.dtype)
+
+
+def backward_plans(q_len, kv_len, head_dim, dtype, causal,
+                   block_q=None, block_k=None):
+    """The plans of the backward's kernels: the fused one where
+    :func:`tile_plan` has room for a head's dq, else the pair."""
+    shape = q_len, kv_len, head_dim, dtype, causal, block_q, block_k
+    plan = tile_plan("dq_dkv", *shape)
+    return [plan] if plan.kernel == "dq_dkv" else [tile_plan("dq", *shape),
+                                                   plan]
 
 
 def _bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd, res, do):
@@ -492,46 +557,57 @@ def _bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd, res, do):
     KVH, Sk = k.shape[1], k.shape[2]
 
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    # per query row, twice: a column for the kernel that holds queries
-    # resident, a lane-dense row for the one that walks them
-    cols = lse, jnp.broadcast_to(delta[..., None], lse.shape)
-    rows = lse[..., 0][:, :, None, :], delta[:, :, None, :]
 
-    def call(kind, name, kernel, n_out):
-        plan = tile_plan(kind, S, Sk, D, q.dtype, causal,
-                         block_q_bwd, block_k_bwd)
+    def call(plan):
         q_map, kv_map = _index_maps(H, KVH, plan.walks_q)
         q_spec = pl.BlockSpec((1, 1, plan.block_q, D), q_map)
         kv_spec = pl.BlockSpec((1, 1, plan.block_k, D), kv_map)
+        # per query row: a lane-dense row for the kernel that walks the
+        # queries, a column for the one that holds them resident
         if plan.walks_q:
-            # dk/dv per QUERY head (reduced over the group below): the
-            # key axis' map with KVH = H
-            out_map, res_rows = _index_maps(H, H, True)[1], plan.block_k
-            stats = rows
+            kernel = _bwd_dkv_kernel
+            stats = lse[..., 0][:, :, None, :], delta[:, :, None, :]
             stat_spec = pl.BlockSpec(
                 (1, 1, 1, plan.block_q),
                 lambda bh, i, j: (bh // H, bh % H, 0, j))
+            # dk/dv per QUERY head (reduced over the group below): the
+            # key axis' map with KVH = H
+            outs = [(Sk, plan.block_k, _index_maps(H, H, True)[1])] * 2
         else:
-            out_map, res_rows, stats = q_map, plan.block_q, cols
+            kernel = _bwd_dq_kernel
+            stats = lse, jnp.broadcast_to(delta[..., None], lse.shape)
             stat_spec = pl.BlockSpec((1, 1, plan.block_q, LSE_LANES), q_map)
+            outs = [(S, plan.block_q, q_map)]
+        sums = [] if plan.n_major == 1 else [
+            pltpu.VMEM((rows, D), jnp.float32) for _, rows, _ in outs]
+        if plan.kernel == "dq_dkv":
+            # the head's whole dq, in whole major blocks: one block of
+            # the output, written back once a head
+            head = plan.n_major * plan.block_q
+            outs = [(head, head, lambda bh, i, j: (bh // H, bh % H, 0, 0)),
+                    *outs]
+            sums.append(pltpu.VMEM((plan.n_major, D, plan.block_q),
+                                   jnp.float32))
         return _planned_call(
-            plan, name, functools.partial(kernel, scale=scale, plan=plan),
+            plan, f"attn.flash_{plan.kernel}",
+            functools.partial(kernel, scale=scale, plan=plan),
             grid=(B * H, plan.n_resident, plan.n_major),
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
-            out_specs=[pl.BlockSpec((1, 1, res_rows, D), out_map)] * n_out,
-            out_shape=[jax.ShapeDtypeStruct(
-                (B, H, Sk if plan.walks_q else S, D), q.dtype)] * n_out,
-            scratch_shapes=[] if plan.n_major == 1 else
-            [pltpu.VMEM((res_rows, D), jnp.float32)] * n_out,
+            out_specs=[pl.BlockSpec((1, 1, rows, D), out_map)
+                       for _, rows, out_map in outs],
+            out_shape=[jax.ShapeDtypeStruct((B, H, n, D), q.dtype)
+                       for n, _, _ in outs],
+            scratch_shapes=sums,
         )(q, k, v, do, *stats)
 
-    dq, = call("dq", "attn.flash_dq", _bwd_dq_kernel, 1)
-    dk, dv = call("dkv", "attn.flash_dkv", _bwd_dkv_kernel, 2)
+    dq, dk, dv = [x for plan in backward_plans(
+        S, Sk, D, q.dtype, causal, block_q_bwd, block_k_bwd)
+        for x in call(plan)]
     if KVH != H:
         rep = H // KVH
         dk = dk.reshape(B, KVH, rep, Sk, D).sum(axis=2)
         dv = dv.reshape(B, KVH, rep, Sk, D).sum(axis=2)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+    return dq[:, :, :S], dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 # --------------------------------------------------------------------- #

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.models.transformer import reference_attention
 from deepspeed_tpu.monitor import trace
 from deepspeed_tpu.ops.transformer import flash_attention as flash_mod
-from deepspeed_tpu.ops.transformer.flash_attention import (flash_attention,
+from deepspeed_tpu.ops.transformer.flash_attention import (backward_plans,
+                                                           flash_attention,
                                                            tile_plan)
 
 
@@ -107,6 +108,14 @@ BF16, F32 = jnp.bfloat16, jnp.float32
     ("fwd", (2048, 2048, 128, BF16, True), (), 512, (512, 512, 10, 4, 16)),
     ("dkv", (2048, 2048, 128, BF16, True), (), 512, (512, 512, 10, 4, 16)),
     ("dq", (2048, 2048, 64, BF16, True), (), 256, (256, 256, 36, 8, 64)),
+    # the fused backward walks what dkv walks
+    ("dq_dkv", (2048, 2048, 64, BF16, True), (), 512, (512, 512, 10, 4, 16)),
+    ("dq_dkv", (2048, 2048, 128, BF16, True), (), 512,
+     (512, 512, 10, 4, 16)),
+    ("dq_dkv", (4096, 4096, 128, BF16, True), (), 512,
+     (512, 512, 36, 8, 64)),
+    ("dq_dkv", (192, 192, 64, F32, True), (128, 128), 512,
+     (128, 128, 3, 3, 4)),
     # what the kernels ran before the walk: one [512, 2048] tile a query
     # block, all masked; three of four [1024, 1024] tiles backward
     ("fwd", (2048, 2048, 64, BF16, True), (512, 2048), 2048,
@@ -154,8 +163,7 @@ def test_plan_event_a_traced_call():
         trace.disable()
     events = {s[5]["kernel"]: s[5] for s in spans
               if s[0] == "dstpu.kernel.tile_plan"}
-    assert sorted(events) == ["attn.flash_dkv", "attn.flash_dq",
-                              "attn.flash_fwd"]
+    assert sorted(events) == ["attn.flash_dq_dkv", "attn.flash_fwd"]
     for name, args in events.items():
         assert (args["tile_q"], args["tile_k"]) == (512, 512), name
         assert (args["tiles_run"], args["tiles_masked"],
@@ -201,39 +209,110 @@ def test_walk_of_several_tiles_matches_reference(D, dtype, KVH, causal,
     _assert_parity(q, k, v, causal, 3e-2 if dtype == BF16 else 5e-5)
 
 
+@pytest.mark.parametrize("form", ["fused", "pair"])
 @pytest.mark.parametrize("causal", [True, False])
-def test_walk_across_major_blocks_with_a_ragged_tail(causal, monkeypatch):
+def test_walk_across_major_blocks_with_a_ragged_tail(causal, form,
+                                                     monkeypatch):
     """S=320 with the walked axis in 256-blocks of 128-tiles: the running
-    statistics cross a grid step, and the second block is a ragged tile."""
+    statistics cross a grid step, and the second block is a ragged tile.
+    Backward, the fused kernel sums dq over two key blocks (the second
+    ragged: its dead rows must add nothing) AND two major query blocks;
+    with no room for a head's dq the pair does the same work."""
     monkeypatch.setattr(flash_mod, "_TILE", 128)
+    if form == "pair":
+        monkeypatch.setattr(flash_mod, "_DQ_SUM_BYTES", 0)
     plan = tile_plan("fwd", 320, 320, 32, F32, causal, None, 256)
     assert (plan.n_major, plan.tile_k, plan.ragged) == (2, 128, True)
+    plans = backward_plans(320, 320, 32, F32, causal, 256, 256)
+    assert [p.kernel for p in plans] == (
+        ["dq_dkv"] if form == "fused" else ["dq", "dkv"])
+    assert (plans[-1].n_resident, plans[-1].n_major) == (2, 2)
     q, k, v = make_qkv(B=1, S=320, H=2, D=32)
     _assert_parity(q, k, v, causal, 5e-5, block_k=256, block_q_bwd=256,
                    block_k_bwd=256)
 
 
-def test_skipped_tiles_are_not_computed():
-    """Causal, 512 queries against 2,048 keys (top-left aligned): keys from
-    512 on are above every query's diagonal.  NaN there must not reach the
-    output or dq — a computed-then-masked tile turns 0 x NaN into NaN."""
-    q, k, v = make_qkv(B=1, S=512, Sk=2048, H=1, D=64)
-    poison = jnp.arange(2048)[None, :, None, None] >= 512
+@pytest.mark.parametrize("D,dtype,causal", [
+    (64, F32, True), (128, BF16, True), (64, F32, False)],
+    ids=["d64-f32", "d128-bf16", "d64-f32-full"])
+def test_fused_backward_sums_dq_over_key_and_major_blocks(D, dtype, causal,
+                                                          monkeypatch):
+    """S=1024 in 256-tiles with the queries in 512-row major blocks and
+    GROUPED heads: a head's dq is the sum of four key blocks' shares in
+    each of two major blocks — what ``attn.flash_dq``, a query block
+    resident, never had to add up."""
+    monkeypatch.setattr(flash_mod, "_TILE", 256)
+    plan, = backward_plans(1024, 1024, D, dtype, causal, 512, None)
+    assert (plan.kernel, plan.n_resident, plan.n_major) == ("dq_dkv", 4, 2)
+    assert plan.counts()["tiles_run"] == (10 if causal else 16)
+    q, k, v = make_qkv(B=1, S=1024, H=4, KVH=2, D=D, dtype=dtype, seed=3)
+    _assert_parity(q, k, v, causal, 3e-2 if dtype == BF16 else 5e-5,
+                   block_q_bwd=512)
+
+
+def test_form_of_the_backward_follows_the_shape():
+    """``tile_plan`` grants the fused kernel where a head's float32 dq fits
+    ``_DQ_SUM_BYTES`` of VMEM — every length a cell or a test trains at —
+    and hands back the pair's ``dkv`` plan where it does not; nothing else
+    decides."""
+    names = lambda *shape: [p.kernel for p in backward_plans(*shape, True)]
+    for s, d in [(2048, 64), (2048, 128), (4096, 128), (8192, 64)]:
+        assert names(s, s, d, BF16) == ["dq_dkv"], (s, d)
+    assert 8192 * 64 * 4 == flash_mod._DQ_SUM_BYTES
+    # one row past the budget: whole major blocks are what is held
+    assert names(8193, 8193, 64, BF16) == ["dq", "dkv"]
+    assert names(8192, 8192, 128, BF16) == ["dq", "dkv"]
+    assert names(4096, 4096, 256, F32) == ["dq", "dkv"]
+    # the keys' length is not in the rule
+    assert names(2048, 16384, 128, BF16) == ["dq_dkv"]
+    # the traced call says which form ran
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(F32).sum()
+    arg = jax.ShapeDtypeStruct((1, 8192, 1, 128), BF16)
+    ring = trace.enable()
+    try:
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), arg, arg, arg)
+        spans, _ = ring.span_snapshot()
+    finally:
+        trace.disable()
+    assert sorted(s[5]["kernel"] for s in spans
+                  if s[0] == "dstpu.kernel.tile_plan") == [
+        "attn.flash_dkv", "attn.flash_dq", "attn.flash_fwd"]
+
+
+@pytest.mark.parametrize("S,Sk,H,KVH,tile", [(512, 2048, 1, 1, 512),
+                                             (256, 1024, 2, 1, 256)],
+                         ids=["s512-k2048", "s256-k1024-gqa"])
+def test_skipped_tiles_are_not_computed(S, Sk, H, KVH, tile, monkeypatch):
+    """Causal, fewer queries than keys (top-left aligned): keys from ``S``
+    on are above every query's diagonal.  NaN there must not reach the
+    output or a gradient — a computed-then-masked tile turns 0 x NaN into
+    NaN, and the fused backward SUMS dq over all four key blocks, three of
+    them wholly unseen: every gradient is finite, the seen keys' match the
+    reference, the unseen keys' are exactly zero."""
+    monkeypatch.setattr(flash_mod, "_TILE", tile)
+    plan, = backward_plans(S, Sk, 64, F32, True)
+    assert (plan.kernel, plan.n_resident) == ("dq_dkv", 4)
+    q, k, v = make_qkv(B=1, S=S, Sk=Sk, H=H, KVH=KVH, D=64, seed=5)
+    poison = jnp.arange(Sk)[None, :, None, None] >= S
     k_bad = jnp.where(poison, jnp.nan, k)
     v_bad = jnp.where(poison, jnp.nan, v)
 
-    def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True) ** 2)
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=True) ** 2)
 
     out = flash_attention(q, k_bad, v_bad, causal=True)
-    ref = reference_attention(q, k[:, :512], v[:, :512], causal=True)
+    ref = reference_attention(q, k[:, :S], v[:, :S], causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
                                rtol=2e-5)
-    dq, dk, dv = jax.grad(loss, argnums=(0, 1, 2))(q, k_bad, v_bad)
-    dq_ref = jax.grad(lambda q: jnp.sum(reference_attention(
-        q, k[:, :512], v[:, :512], causal=True) ** 2))(q)
-    np.testing.assert_allclose(np.asarray(dq), np.asarray(dq_ref), atol=5e-4,
-                               rtol=5e-4)
+    got = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k_bad, v_bad)
+    want = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(
+        q, k[:, :S], v[:, :S])
+    for g, w, name in zip(got, want, "qkv"):
+        assert np.isfinite(np.asarray(g)).all(), f"d{name} not finite"
+        np.testing.assert_allclose(np.asarray(g[:, :S]), np.asarray(w),
+                                   atol=5e-4, rtol=5e-4,
+                                   err_msg=f"d{name} mismatch")
     # keys no query sees get a zero gradient, not a NaN
-    assert not np.asarray(dk[:, 512:]).any()
-    assert not np.asarray(dv[:, 512:]).any()
+    assert not np.asarray(got[1][:, S:]).any()
+    assert not np.asarray(got[2][:, S:]).any()

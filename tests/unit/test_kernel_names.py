@@ -20,11 +20,11 @@ HD = H * D
 F32, I32 = jnp.float32, jnp.int32
 
 
-def _flash(grad):
+def _flash(grad, seq=256, head_dim=D):
     def loss(q, k, v):
         return flash_mod.flash_attention(q, k, v, causal=True).sum()
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else loss
-    return fn, [((1, 256, H, D), F32)] * 3
+    return fn, [((1, seq, 1, head_dim), F32)] * 3
 
 
 def _paged_decode():
@@ -76,8 +76,10 @@ CASES = {
     "attn.paged_decode": _paged_decode,
     "attn.paged_chunk_prefill": _paged_chunk,
     "attn.flash_fwd": lambda: _flash(False),
-    "attn.flash_dq": lambda: _flash(True),
-    "attn.flash_dkv": lambda: _flash(True),
+    "attn.flash_dq_dkv": lambda: _flash(True),
+    # a head whose dq the fused backward has no room to sum: the pair
+    "attn.flash_dq": lambda: _flash(True, 8192, 128),
+    "attn.flash_dkv": lambda: _flash(True, 8192, 128),
     "attn.chunk_prefill": _mono_chunk,
     "attn.decode": _mono_decode,
     "attn.block_sparse_fwd": _block_sparse,

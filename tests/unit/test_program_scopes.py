@@ -136,7 +136,7 @@ D = "jit(decode_block)/while/body/closed_call/"
     (F + "layers_0/attn/shard_map/attn.flash_fwd/pallas_call",
      "attn.core", "fwd"),
     (T + "jvp(Transformer)/Transformer.hidden_states/checkpoint/layers_0/"
-     "attn/shard_map/attn.flash_dkv/pallas_call", "attn.core", "bwd"),
+     "attn/shard_map/attn.flash_dq_dkv/pallas_call", "attn.core", "bwd"),
     (D + "Transformer.decode/layers_0/attn/attn.paged_decode/pallas_call",
      "attn.core", "fwd"),
     ("jit(chunk_step)/Dots3Model.decode/layers_1/attn.chunk/"

@@ -320,6 +320,11 @@ CASES = {
 }
 
 
+# the training cells' shapes, and one across two major blocks
+_FLASH_TRAINED = ("flash_fwd_bwd_s2048", "flash_fwd_bwd_s2048_d128",
+                  "flash_fwd_bwd_s4096")
+
+
 def _compile(fn, shapes, one_chip):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     return jax.jit(fn).lower(*args).compile()
@@ -683,8 +688,16 @@ def test_longcat_slot_programs_compile_at_the_cells_sizes(program, one_chip,
 def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
     fn, shapes = CASES[case]()
     compiled = _compile(fn, shapes, one_chip)
-    assert "tpu_custom_call" in compiled.as_text(), \
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, \
         f"{case}: no Mosaic kernel in the compiled program"
+    if case in _FLASH_TRAINED:
+        # the backward of one call is ONE Mosaic kernel beside the
+        # forward's: the head's float32 dq sum fits the VMEM a kernel gets
+        # by default (the call asks for no more).  A silent fall back to
+        # the pair fails here and not in a benchmark
+        assert text.count("tpu_custom_call") == 2, case
+        assert "attn.flash_dq_dkv" in text and "attn.flash_fwd" in text
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)

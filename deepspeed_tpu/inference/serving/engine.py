@@ -332,7 +332,7 @@ class ServingEngine:
         # last; the load vector's last rows), and a dispatch's in all
         self._draft_layers = self.contract.draft_layers \
             if self.self_draft else 0
-        self._layers = self.contract.num_layers + self._draft_layers
+        self._layers = self.contract.paged_layers + self._draft_layers
         # scheduler counters (docs/serving.md) — made before the cache
         # manager, which counts prefix hits and evictions into them
         self.stats = {"iterations": 0, "decode_calls": 0,  # guarded-by: _lock
@@ -473,6 +473,12 @@ class ServingEngine:
             kernel_modes as _registry_modes)
         self.kernel_modes = _registry_modes(
             paged=True, has_bias=self.contract.attention_bias)
+        # ... and, of a model whose window layers keep K/V rings beside the
+        # K/V pages, those layers' own
+        self.ring_kernel_modes = _registry_modes(
+            paged=True, has_bias=self.contract.attention_bias,
+            has_window=True, ring=True) \
+            if self.contract.kv_pages and self._pages.ring_pages else None
         # ... and the form the chunk program's K/V write takes, by the
         # predicate the traced write asks.  A stat, not a third key of
         # kernel_modes: callers compare that dict whole
@@ -2388,7 +2394,9 @@ class ServingEngine:
                 self._slot_last_dispatch[s] = now
         self._events.append(ev)
         self.stats["decode_calls"] += 1
-        if self.kernel_modes["decode"] == "reference_fallback":
+        if "reference_fallback" in (
+                self.kernel_modes["decode"],
+                (self.ring_kernel_modes or {}).get("decode")):
             # this decode dispatch took the take_along_axis gather path
             # (no Pallas, or alibi) — the BENCH_r04 bs128 cliff,
             # surfaced instead of silent

@@ -332,6 +332,10 @@ class SlotPages:
         self.state_rows = 1 + self.num_slots if self.state_kinds else 0
         self.state_row_bytes = 0         # known once the pools are made
         self.page_bytes = 0
+        # ... and a model that names its ring pools (``ring_kinds``) has the
+        # rings' bytes counted beside the pages'
+        self.ring_kinds = tuple(contract.ring_kinds)
+        self.ring_slot_bytes = 0
         self.fold_pages = 1              # (their dtype decides it)
         self.table_width = self.pages_per_slot + self.ring_pages \
             + bool(self.state_kinds)
@@ -367,8 +371,10 @@ class SlotPages:
 
     def new_pools(self, dtype):
         """The model's zero pool(s) at this manager's sizes — one ``k`` /
-        ``v`` pair, pools by row kind (``models/dots3.py``), or page pools
-        beside state pools (``models/lfm2.py``)."""
+        ``v`` pair, pools by row kind (``models/dots3.py``), page pools
+        beside state pools (``models/lfm2.py``), or K/V page pools for some
+        layers beside K/V ring pools for the others
+        (``models/trinity.py``)."""
         kinds = {"window_pages": self.window_pages} if self.ring_pages \
             else {}
         if self.state_kinds:
@@ -377,11 +383,16 @@ class SlotPages:
                                               dtype=dtype, **kinds)
         self.fold_pages = _decode_block_pages(
             self.page, self.pages_per_slot, jax.numpy.dtype(dtype).itemsize)
+        nbytes = lambda keys: sum(pools[k].size * pools[k].dtype.itemsize
+                                  for k in keys)
         if self.state_kinds:
-            nbytes = lambda keys: sum(pools[k].size * pools[k].dtype.itemsize
-                                      for k in keys)
             self.state_row_bytes = nbytes(self.state_kinds) // self.state_rows
-            self.page_bytes = nbytes(set(pools) - set(self.state_kinds)) \
+        if self.ring_kinds:
+            self.ring_slot_bytes = nbytes(self.ring_kinds) \
+                // self.window_pages * self.ring_pages
+        if self.state_kinds or self.ring_kinds:
+            self.page_bytes = nbytes(
+                set(pools) - set(self.state_kinds) - set(self.ring_kinds)) \
                 // self.num_pages
         return pools
 
@@ -555,6 +566,10 @@ class SlotPages:
                      f"{self._pool.in_use} pages, {ring_rows} "
                      f"{held * self.ring_pages}/{self.window_pages - 1} "
                      f"pages ({self.ring_pages} a slot, a ring)")
+            if self.ring_kinds:
+                text += (f"; bytes held: {lane_rows} "
+                         f"{self._pool.in_use * self.page_bytes}, "
+                         f"{ring_rows} {held * self.ring_slot_bytes}")
         if self.stride > 1:
             text += f"; a lane row a {self.stride} positions"
         if self.state_kinds:
@@ -611,7 +626,16 @@ class SlotPages:
                 "kv_pages_table":
                     self.num_slots * self.pages_per_slot * block,
                 **(self._state_reach(live) if self.state_kinds else {}),
+                **(self._ring_reach() if self.ring_kinds else {}),
                 **(work(live, self.ring_pages, layers) if work else {})}
+
+    def _ring_reach(self):
+        """The cache's split by kind, as a decode block's span args:
+        ``ring_bytes_held`` — the rings of the slots that are reserved,
+        held whole whatever the context — and ``kv_bytes_mapped`` — the
+        lane pages slots hold."""
+        return {"ring_bytes_held": len(self._rows) * self.ring_slot_bytes,
+                "kv_bytes_mapped": self._pool.in_use * self.page_bytes}
 
     def _state_reach(self, live):
         """A decode block's state work and the cache's split, as span

@@ -33,15 +33,22 @@ class SlotContract:
     vocab_size: int
     max_seq_len: int
     dtype: str
-    num_layers: int        # a dispatch's ``kv_pages`` are a layer's x these
+    num_layers: int
     attention_bias: bool = False       # ALiBi: other registry kernel modes
     # ---- the cache ---- #
     kv_pages: bool = True              # the pools hold ``k`` / ``v`` pages
+    # layers whose rows lie in the LANE pages — a dispatch's ``kv_pages``
+    # are a layer's x these; None: all ``num_layers`` (a model whose other
+    # layers keep a ring says how many do not: ``models/trinity.py``)
+    lane_layers: Optional[int] = None
     lane_stride: int = 1               # positions a lane row stands for
     # ``ring_pages(page_size)``: pages of a ring the slot owns for good in a
     # pool of its own, behind the lane pages in its table row
     ring_pages: Callable[[int], int] = _quiet(_no_ring)
     row_kinds: Tuple[str, str] = ("lane rows", "ring rows")   # describe()'s
+    # cache keys whose pools hold the rings: named, the cache manager counts
+    # the rings' bytes beside the pages' (``ring_bytes_held``)
+    ring_kinds: Tuple[str, ...] = ()
     # cache keys indexed by the slot's STATE ROW, its table row's last entry
     state_kinds: Tuple[str, ...] = ()
     # ---- the chunk program ---- #
@@ -71,6 +78,11 @@ class SlotContract:
     block_work: Optional[Callable[..., dict]] = _quiet(None)
     work_counters: Tuple[str, ...] = ()    # names summed into ``srv.stats``
     work_levels: Tuple[str, ...] = ()      # names that are span args only
+
+    @property
+    def paged_layers(self):
+        return self.num_layers if self.lane_layers is None \
+            else self.lane_layers
 
     @property
     def drafts_itself(self):
@@ -122,14 +134,21 @@ def check(contract, module, page_size, chunk, layers):
             f"{type(module).__name__}.init_paged_cache() does not take "
             f"{sorted(sizes)}, the sizes of what its slot_contract() "
             f"declares (ring_pages, state_kinds): {e}") from None
-    missing = [k for k in contract.state_kinds if k not in pools]
-    if missing:
-        raise ValueError(
-            f"{said}: state_kinds names {missing}, no key of the cache "
-            f"init_paged_cache() returns ({sorted(pools)})")
+    for field in ("state_kinds", "ring_kinds"):
+        missing = [k for k in getattr(contract, field) if k not in pools]
+        if missing:
+            raise ValueError(
+                f"{said}: {field} names {missing}, no key of the cache "
+                f"init_paged_cache() returns ({sorted(pools)})")
     if contract.kv_pages != ("k" in pools):
         raise ValueError(f"{said}: kv_pages={contract.kv_pages} but "
                          f"init_paged_cache() returns {sorted(pools)}")
+    if contract.kv_pages and contract.lane_layers is not None \
+            and pools["k"].shape[0] != contract.lane_layers:
+        raise ValueError(
+            f"{said}: lane_layers={contract.lane_layers}, but the ``k`` "
+            f"pool init_paged_cache() returns holds {pools['k'].shape[0]} "
+            f"layers")
     declared = set(contract.work_counters) | set(contract.work_levels)
     returned = set()
     for field, work, args in (

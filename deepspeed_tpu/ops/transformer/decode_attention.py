@@ -404,7 +404,8 @@ def _init_chunk(st):
 
 
 def _chunk_block_update(st, ik, start, k_ref, v_ref, ks, vs, *, scale,
-                        block_k, c, kvh, g, d, masked=True, prefix=False):
+                        block_k, c, kvh, g, d, masked=True, prefix=False,
+                        pos0=None, window=None, limit=None):
     """Fold KV block ``ik`` (virtual positions ``ik*block_k ..``) into the
     chunk's online-softmax state: per head ONE ``[C, D] x [D, bk]`` score
     matmul, a ``[C, bk]`` float32 tile for max / exp / sum, ONE
@@ -417,9 +418,12 @@ def _chunk_block_update(st, ik, start, k_ref, v_ref, ks, vs, *, scale,
     (with ``masked``): the block's keys are no positions of the chunk's
     sequence but rows every query sees alike up to a bound — ``start`` is
     then that bound, and row ``r`` is live while ``ik*block_k + r <
-    start`` (EVA's chunk summaries, ``eva_attention.py``).  The one
-    per-block update of chunked prefill, whichever driver supplies the
-    block sequence.
+    start`` (EVA's chunk summaries, ``eva_attention.py``).  A BAND
+    (``paged_attention``'s window chunk kernel; with ``masked``): the
+    block's row 0 stands at position ``pos0`` (not ``ik*block_k``), a query
+    sees the ``window`` positions up to its own, and rows at ``limit`` and
+    past it hold no position of the sequence yet.  The one per-block update
+    of chunked prefill, whichever driver supplies the block sequence.
 
     Heads are walked in GROUPS of whole 128-lane tiles of the slabs (two
     kv heads of 64, one of 128, with the ``g`` query heads of each): a
@@ -433,14 +437,18 @@ def _chunk_block_update(st, ik, start, k_ref, v_ref, ks, vs, *, scale,
         kst = ks.astype(jnp.float32).T                   # [KVH, bk]
         vst = vs.astype(jnp.float32).T
     if masked:
-        pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)                  # [1, bk]
+        pos = (ik * block_k if pos0 is None else pos0) \
+            + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)  # [1, bk]
         if prefix:
             live = pos < start                           # [1, bk], all rows
         else:
             qpos = start + jax.lax.broadcasted_iota(
                 jnp.int32, (c, 1), 0)                    # [C, 1]
             live = pos <= qpos                           # [C, bk] causal+tail
+            if window is not None:
+                live = jnp.logical_and(live, pos > qpos - window)
+            if limit is not None:
+                live = jnp.logical_and(live, pos < limit)
     hpg = 128 // d if 128 % d == 0 and kvh % (128 // d) == 0 else 1
     lanes, qlanes = hpg * d, hpg * g * d
     tiled = lanes % 128 == 0 and not quant

@@ -111,6 +111,23 @@ REACHABLE pages of each row — those up to the chunk's furthest position,
   body ``pl.when``-gated off): the scale page cannot be fetched by hand
   (above).
 
+**A sliding window over a K/V ring** (:func:`window_chunk_attention`,
+``attn.gqa_window_chunk``): a windowed layer's K/V lie in a ring a slot of
+exactly the window's rows (``models/trinity.py``; position ``t`` in ring row
+``t % window``), read through the slot's RING table.  A prefill chunk's
+queries go ``_WINDOW_CHUNK_QUERIES`` a grid step; a step folds first the
+ring's rows its queries can see — the positions before the chunk from its
+first query's band on, in the chunk loop's 512-key blocks through the
+table, double-buffered — and then the chunk's own keys up to the diagonal,
+which are a VMEM input (the ring is read as the chunk FOUND it; the chunk's
+rows go in after).  Blocks wholly outside a query block's band are never
+walked, blocks wholly inside it take the unmasked update, the others
+``_chunk_block_update``'s band mask (``pos0`` / ``window`` / ``limit``).  A
+decode step over the ring needs no kernel of its own: every row of a full
+ring is in the band, so it is :func:`paged_decode_attention` over the ring's
+table at ``min(pos + 1, window)`` rows (``registry``'s
+``pallas_ring_decode``).
+
 How far the live-page walks engage in serving is on the dispatch spans:
 ``dstpu.sched.dispatch.decode`` carries ``kv_pages`` (pages the block's
 steps walk) against ``kv_pages_table`` (slots x pages a slot x steps)
@@ -779,3 +796,186 @@ def _paged_chunk_call(q, k_pool, v_pool, starts, layer_arr, pages_arr,
         name="attn.paged_chunk_prefill",
     )(starts, layer_arr, pages_arr, q.reshape(B, C, H * D), *operands)
     return out.reshape(B, C, H, D)
+
+
+# ---- a sliding window over a K/V ring ------------------------------------ #
+
+# queries one grid step of the window chunk kernel holds: the chunk kernel's
+# own bound (``registry.MAX_CHUNK_S``)
+_WINDOW_CHUNK_QUERIES = 512
+
+
+def _window_chunk_kernel(start_ref, layer_ref, ring_ref, q_ref, kn_ref,
+                         vn_ref, k_hbm, v_hbm, o_ref, m_scr, l_scr, acc_scr,
+                         kbuf, vbuf, sem, *, scale, page, n_ring, bp, cq,
+                         window, kvh, g, d):
+    """One block of ``cq`` queries of a prefill chunk under a sliding
+    window of ``window`` keys: first the ring's rows the block can see —
+    the positions before the chunk, ``q0 - window + 1 .. start - 1``,
+    fetched through the slot's ring table ``bp`` pages at a time as
+    ``_paged_chunk_kernel`` fetches a lane's —, then the chunk's own keys up
+    to the diagonal, from VMEM.  Blocks wholly outside the band are not
+    walked; blocks wholly inside it run unmasked."""
+    st = _ChunkState(q_ref, m_scr, l_scr, acc_scr)
+    li = layer_ref[0]
+    start = start_ref[0]
+    q0 = start + pl.program_id(0) * cq      # the block's first query
+    low1 = q0 + cq - window                 # its LAST query's first key
+    bk = bp * page
+    update = functools.partial(_chunk_block_update, st, 0, q0, ks=None,
+                               vs=None, scale=scale, c=cq, kvh=kvh, g=g, d=d)
+
+    # ---- the ring: positions a0 .. start - 1, a0 the page of the first
+    # query's first key; position t lies in ring page (t // page) % n_ring
+    a0 = jnp.maximum(q0 - window + 1, 0) // page * page
+    n_pages = jnp.maximum((start - a0 + page - 1) // page, 0)
+    n_blocks = (n_pages + bp - 1) // bp
+    # blocks under every query's band whole: from the last query's first
+    # key on, and before ``start``
+    first_in = jnp.clip((low1 - a0 + bk - 1) // bk, 0, n_blocks)
+    end_in = jnp.clip((start - a0) // bk, first_in, n_blocks)
+
+    def each_page(i, fn):
+        # block i of the ring walk -> buffer i % 2; pages past ``start``
+        # are not fetched
+        vp, slot = a0 // page + i * bp, i % 2
+
+        def one(j, carry):
+            pg = ring_ref[(vp + j) % n_ring]
+            rows = _page_rows(j, page, bp)
+            for n, (src, dst) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                fn(pltpu.make_async_copy(src.at[li, pg], dst.at[slot, rows],
+                                         sem.at[n, slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n_pages - i * bp, 0, bp), one, None)
+
+    def fold_ring(masked):
+        def body(i, carry):
+            each_page(i + 1, lambda cp: cp.start())
+            each_page(i, lambda cp: cp.wait())
+            slot = i % 2
+            if masked:
+                # rows at ``start`` and past it hold what the ring kept of
+                # a window ago, or nothing: masked on the score side by
+                # ``limit``, zeroed on the value side
+                @pl.when(a0 + (i + 1) * bk > start)
+                def _zero_tail():
+                    _zero_value_tail(vbuf, slot, a0 + i * bk, start,
+                                     page=page, bp=bp)
+            update(k_ref=kbuf.at[slot], v_ref=vbuf.at[slot], block_k=bk,
+                   masked=masked, pos0=a0 + i * bk, window=window,
+                   limit=start)
+            return carry
+        return body
+
+    _init_chunk(st)
+    each_page(0, lambda cp: cp.start())
+    jax.lax.fori_loop(0, first_in, fold_ring(True), None)
+    jax.lax.fori_loop(first_in, end_in, fold_ring(False), None)
+    jax.lax.fori_loop(end_in, n_blocks, fold_ring(True), None)
+
+    # ---- the chunk's own keys, ``cq`` rows a block, up to the diagonal
+    diag = pl.program_id(0)
+    first = jnp.maximum(q0 - window + 1 - start, 0) // cq
+    whole = jnp.clip((low1 - start + cq - 1) // cq, first, diag)
+
+    def fold_chunk(masked):
+        def body(t, carry):
+            rows = pl.ds(pl.multiple_of(t * cq, cq), cq)
+            update(k_ref=kn_ref.at[rows], v_ref=vn_ref.at[rows], block_k=cq,
+                   masked=masked, pos0=start + t * cq, window=window)
+            return carry
+        return body
+
+    jax.lax.fori_loop(first, whole, fold_chunk(True), None)
+    jax.lax.fori_loop(whole, diag, fold_chunk(False), None)
+    fold_chunk(True)(diag, None)
+    _finish_chunk(st, o_ref, heads=kvh * g, d=d)
+
+
+def window_chunk_queries(c):
+    """Queries a grid step of the window chunk kernel holds at a chunk of
+    ``c``, or ``None`` where ``c`` is no whole number of them."""
+    cq = min(c, _WINDOW_CHUNK_QUERIES)
+    return None if c % cq else cq
+
+
+def window_chunk_attention(q, k_new, v_new, k_ring, v_ring, start, ring, *,
+                           window, layer, scale=None):
+    """A prefill chunk's attention under a sliding window over a K/V RING
+    (``attn.gqa_window_chunk``): query ``i`` at position ``start + i`` sees
+    keys ``start + i - window + 1 .. start + i`` — those before ``start``
+    from the slot's ring, where position ``t`` lies in row ``t % page`` of
+    ring page ``(t // page) % n_ring`` (``n_ring * page == window``: the
+    ring holds exactly the window), those of the chunk from ``k_new`` /
+    ``v_new``.  The ring is read as the chunk FOUND it: the chunk's own
+    rows go in after (``registry``'s ring write).
+
+    q ``[C, H, D]``; k_new / v_new ``[C, KVH*D]`` (normed, roped: as
+    cached); rings ``[window layers, pages, page, KVH*D]``; ``ring``
+    ``[n_ring]`` int32, the slot's ring pages; ``start`` a scalar.
+    Returns ``[C, H, D]``."""
+    C, H, D = q.shape
+    page, KVHD, KVH = _pool_dims(q, k_ring)
+    n_ring = ring.shape[0]
+    if n_ring * page != window:
+        raise ValueError(f"a ring of {n_ring} pages of {page} rows holds "
+                         f"{n_ring * page} positions, not the window's "
+                         f"{window}")
+    cq = window_chunk_queries(C)
+    if cq is None or page % (32 // k_ring.dtype.itemsize):
+        raise ValueError(
+            f"the window chunk kernel takes a chunk of whole "
+            f"{_WINDOW_CHUNK_QUERIES}-query blocks (got {C}) and pages of "
+            f"whole sublane tiles (got {page})")
+    if scale is None:
+        scale = 1.0 / float(np.sqrt(D))
+    interpret = _interpret()                # a bool: static by value
+    return _window_chunk_call(
+        q.reshape(1, C, H * D), k_new, v_new, k_ring, v_ring,
+        jnp.asarray(start, jnp.int32).reshape(1),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.asarray(ring, jnp.int32), scale=float(scale), window=int(window),
+        cq=cq, heads=H, interpret=interpret).reshape(C, H, D)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "cq",
+                                             "heads", "interpret"))
+def _window_chunk_call(q, k_new, v_new, k_ring, v_ring, start, layer_arr,
+                       ring, *, scale, window, cq, heads, interpret):
+    """Jitted with the layer traced, like :func:`_paged_chunk_call`: the
+    window layers of an unrolled model lower the kernel once."""
+    _, C, HD = q.shape
+    D = HD // heads
+    page, KVHD = k_ring.shape[-2:]
+    KVH = KVHD // D
+    n_ring = ring.shape[0]
+    bp = _chunk_block_pages(page, n_ring)
+    itemsize = k_ring.dtype.itemsize
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    q_spec = pl.BlockSpec((1, cq, HD), lambda j, *refs: (0, j, 0))
+    new_spec = pl.BlockSpec((C, KVHD), lambda j, *refs: (0, 0))
+    kernel = functools.partial(
+        _window_chunk_kernel, scale=scale, page=page, n_ring=n_ring, bp=bp,
+        cq=cq, window=window, kvh=KVH, g=heads // KVH, d=D)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(C // cq,),
+            in_specs=[q_spec, new_spec, new_spec, pool_spec, pool_spec],
+            out_specs=q_spec,
+            scratch_shapes=_chunk_scratch(cq, heads, D) + [
+                pltpu.VMEM((2, bp * page, KVHD), k_ring.dtype),
+                pltpu.VMEM((2, bp * page, KVHD), v_ring.dtype),
+                pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_chunk_loop_vmem_bytes(
+                cq, heads, D, max(bp * page, cq), KVHD, itemsize,
+                q.dtype.itemsize) + 4 * C * KVHD * itemsize),
+        interpret=interpret,
+        name="attn.gqa_window_chunk",
+    )(start, layer_arr, ring, q, k_new, v_new, k_ring, v_ring)

@@ -26,9 +26,23 @@ Modes (the ``KERNEL_MODES`` table, probed in order, first hit wins):
                             across block-table pages, no gathered virtual view
 ``pallas_decode``           single-token decode over a monolithic cache
                             (``ops/transformer/decode_attention.py``)
+``pallas_ring_decode``      single-token decode of a SLIDING-WINDOW layer whose
+                            K/V lie in a ring a slot (the cache's ``ring``
+                            marker): the row goes to ring row ``pos %
+                            window``, then ``attn.paged_decode`` over the
+                            ring's table at ``min(pos + 1, window)`` rows —
+                            the ring holds exactly the window, so the band
+                            needs no mask
+``pallas_window_chunk``     a prefill chunk of such a layer
+                            (``attn.gqa_window_chunk``): the ring's rows
+                            before the chunk and the chunk's own keys under
+                            the band, blocks outside it skipped; the chunk's
+                            rows enter the ring after
 ``pallas_chunked_prefill``  multi-token block (chunked prefill, multi-token
                             decode, speculative verify) vs either cache
-                            layout, S <= MAX_CHUNK_S
+                            layout, S <= MAX_CHUNK_S — over a paged pool also
+                            whole multiples of it, as that many rows of
+                            MAX_CHUNK_S queries over the one table row
 ``reference_fallback``      the XLA reference path: paged caches first
                             materialize the ``take_along_axis`` gathered view
                             (``_paged_gather``) and then take whatever
@@ -56,6 +70,8 @@ from deepspeed_tpu.utils.logging import warning_once
 MAX_CHUNK_S = 512
 
 KERNEL_MODES = (
+    "pallas_ring_decode",
+    "pallas_window_chunk",
     "pallas_paged_decode",
     "pallas_decode",
     "pallas_chunked_prefill",
@@ -63,53 +79,69 @@ KERNEL_MODES = (
 )
 
 
-def _probe_paged_decode(s, paged, has_bias, has_window):
-    # the paged kernel has no sliding-window mode: windowed paged decode
+def _probe_ring_decode(s, paged, has_bias, has_window, ring):
+    return (paged and ring and has_window and s == 1 and not has_bias
+            and pallas_supported())
+
+
+def _probe_window_chunk(s, paged, has_bias, has_window, ring):
+    from deepspeed_tpu.ops.transformer.paged_attention import (
+        window_chunk_queries)
+    return (paged and ring and has_window and s > 1 and not has_bias
+            and window_chunk_queries(s) is not None and pallas_supported())
+
+
+def _probe_paged_decode(s, paged, has_bias, has_window, ring):
+    # a windowed layer over LANE pages (no ring) has no paged kernel: it
     # keeps the gather path (whose monolithic kernel masks the window)
     return (paged and s == 1 and not has_bias and not has_window
             and pallas_supported())
 
 
-def _probe_decode(s, paged, has_bias, has_window):
+def _probe_decode(s, paged, has_bias, has_window, ring):
     # monolithic decode masks sliding windows in-kernel
     return (not paged and s == 1 and not has_bias and pallas_supported())
 
 
-def _probe_chunk(s, paged, has_bias, has_window):
-    return (1 < s <= MAX_CHUNK_S and not has_bias and not has_window
-            and pallas_supported())
+def _probe_chunk(s, paged, has_bias, has_window, ring):
+    return ((1 < s <= MAX_CHUNK_S or (paged and s % MAX_CHUNK_S == 0))
+            and not has_bias and not has_window and pallas_supported())
 
 
 _REGISTRY = (
+    ("pallas_ring_decode", _probe_ring_decode),
+    ("pallas_window_chunk", _probe_window_chunk),
     ("pallas_paged_decode", _probe_paged_decode),
     ("pallas_decode", _probe_decode),
     ("pallas_chunked_prefill", _probe_chunk),
 )
 
 
-def select_kernel(*, s, paged=False, has_bias=False, has_window=False):
+def select_kernel(*, s, paged=False, has_bias=False, has_window=False,
+                  ring=False):
     """The attention-kernel dispatch decision for one cached-attention
     call.  All inputs are static: ``s`` (this block's token count),
     ``paged`` (block-table pool vs monolithic lanes), ``has_bias``
-    (alibi) and ``has_window`` (sliding-window layer).  Returns a
+    (alibi), ``has_window`` (sliding-window layer) and ``ring`` (the
+    window's K/V lie in a ring a slot, exactly the window long).  Returns a
     :data:`KERNEL_MODES` name; ``reference_fallback`` when no Pallas
     kernel applies."""
     for mode, probe in _REGISTRY:
-        if probe(s, paged, has_bias, has_window):
+        if probe(s, paged, has_bias, has_window, ring):
             return mode
     return "reference_fallback"
 
 
-def kernel_modes(*, paged, has_bias=False, has_window=False):
+def kernel_modes(*, paged, has_bias=False, has_window=False, ring=False):
     """Host-side attribution of which kernel mode each serving program
     class will take (what ``prefill_plan`` reasons and bench records
     report).  Probes the same table the traced programs dispatch
     through, so the attribution cannot drift from reality."""
     return {
         "decode": select_kernel(s=1, paged=paged, has_bias=has_bias,
-                                has_window=has_window),
+                                has_window=has_window, ring=ring),
         "prefill_chunk": select_kernel(s=2, paged=paged, has_bias=has_bias,
-                                       has_window=has_window),
+                                       has_window=has_window, ring=ring),
     }
 
 
@@ -139,7 +171,7 @@ def paged_write_form(block, page, *, page_runs):
 def _cache_markers(cache):
     """The bookkeeping keys a write must thread through unchanged."""
     return {kk: cache[kk] for kk in ("layer", "pages", "per_row",
-                                     "page_runs") if kk in cache}
+                                     "page_runs", "ring") if kk in cache}
 
 
 def _quant_rows(new, kvh):
@@ -294,10 +326,22 @@ def _attend(cfg, mode, q, cache, positions, bias, window):
             from deepspeed_tpu.ops.transformer.paged_attention import (
                 paged_chunk_prefill_attention)
             starts = positions[:, 0].astype(jnp.int32)
+            pages = cache["pages"]
+            B_, S_ = q.shape[:2]
+            rows = -(-S_ // MAX_CHUNK_S)
+            if rows > 1:
+                # past the kernel's bound: the block's K/V are in the pool,
+                # so it is ``rows`` rows of MAX_CHUNK_S queries, each from
+                # its own start over the same table row
+                q = q.reshape((B_ * rows, S_ // rows) + q.shape[2:])
+                starts = (starts[:, None] + (S_ // rows) * jnp.arange(
+                    rows, dtype=jnp.int32)).reshape(-1)
+                pages = jnp.repeat(pages, rows, axis=0)
             return paged_chunk_prefill_attention(
-                q, cache["k"], cache["v"], starts, cache["pages"],
+                q, cache["k"], cache["v"], starts, pages,
                 layer=cache["layer"], k_scale=cache.get("k_scale"),
-                v_scale=cache.get("v_scale"))
+                v_scale=cache.get("v_scale")).reshape(
+                    (B_, S_) + q.shape[2:])
         # reference/gather fallback — the pre-kernel paged path: one
         # take_along_axis virtual-view copy per layer, then whatever
         # cached_attention does on the monolithic view.  For DECODE this
@@ -319,6 +363,93 @@ def _attend(cfg, mode, q, cache, positions, bias, window):
         layer=layer, k_scale=cache.get("k_scale"),
         v_scale=cache.get("v_scale"),
         int8_matmuls=cfg.decode_int8_matmuls)
+
+
+def _band_attention(q, k, v, band):
+    """Softmax attention over the keys ``band [S, T]`` allows a query — the
+    ring paths' plain form.  q ``[B, S, H, D]``, k / v ``[B, T, KVH, D]``."""
+    rep = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    logits = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) \
+        / jnp.sqrt(jnp.float32(q.shape[-1]))
+    logits = jnp.where(band[None, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhst,bthd->bshd", probs, v)
+
+
+def _ring_write_and_attend(mode, q, k, v, positions, cache, window):
+    """A sliding-window layer over a K/V RING a slot (``cache["ring"]``):
+    ``cache["k"]`` / ``["v"]`` are ``[window layers, pages, page, KVH*D]``,
+    ``cache["pages"] [B, n]`` the slots' ring pages, ``n * page ==
+    window`` — position ``p`` lies in ring row ``p % window``, so the ring
+    holds exactly the window once it is full.
+
+    A decode step (one token a lane) writes its row, then attends the
+    ring's ``min(p + 1, window)`` rows: all of them are in the band, in
+    whatever order.  A chunk (one slot's, from ``positions[0, 0]``)
+    attends the ring as it FOUND it — the ``window - 1`` positions before
+    the chunk — and its own keys, then writes: of its ``cache["live"]``
+    rows (all, where absent) the last ``window``, so that a padded tail
+    pushes out nothing the next step attends."""
+    from deepspeed_tpu.models.latent_attention import write_rows
+    from deepspeed_tpu.models.transformer import (_paged_gather,
+                                                  cached_attention)
+    B_, S_, KVH, D = k.shape
+    li, table = cache["layer"], cache["pages"]
+    k_new = k.reshape(B_, S_, KVH * D)
+    v_new = v.reshape(B_, S_, KVH * D)
+    if table.shape[1] * cache["k"].shape[-2] != window:
+        raise ValueError(
+            f"a ring of {table.shape[1]} pages of {cache['k'].shape[-2]} "
+            f"rows does not hold the window's {window} positions exactly")
+
+    def write(tab, pos, kn, vn, keep=None):
+        with jax.named_scope("cache.write"):
+            return {"k": write_rows(cache["k"], li, tab, pos, kn, keep),
+                    "v": write_rows(cache["v"], li, tab, pos, vn, keep),
+                    **_cache_markers(cache)}
+
+    if S_ == 1:
+        pos = positions[:, 0].astype(jnp.int32)
+        new = write(table, pos, k_new[:, 0], v_new[:, 0])
+        lengths = jnp.minimum(pos + 1, window)
+        if mode == "pallas_ring_decode":
+            from deepspeed_tpu.ops.transformer.paged_attention import (
+                paged_decode_attention)
+            out = paged_decode_attention(q[:, 0], new["k"], new["v"],
+                                         lengths, table, layer=li)[:, None]
+        else:
+            g = _paged_gather(new)          # row r: the position r mod ring
+            out = cached_attention(q, g["k"], g["v"], (lengths - 1)[:, None])
+        return out, new
+    if B_ != 1:
+        raise ValueError("a ring's chunk is one slot's")
+    pos = positions[0].astype(jnp.int32)
+    start = pos[0]
+    if mode == "pallas_window_chunk":
+        from deepspeed_tpu.ops.transformer.paged_attention import (
+            window_chunk_attention)
+        out = window_chunk_attention(
+            q[0], k_new[0], v_new[0], cache["k"], cache["v"], start,
+            table[0], window=window, layer=li)[None]
+    else:
+        # the ring's rows in position order, then the chunk's: a masked
+        # dense band
+        before = start - window + jnp.arange(window, dtype=jnp.int32)
+        g = _paged_gather(cache)
+        keys = lambda held, new: jnp.concatenate(
+            [held[:, before % window], new], axis=1
+        ).reshape(1, window + S_, KVH, D)
+        key_pos = jnp.concatenate([before, pos])
+        band = (key_pos[None, :] >= 0) & (key_pos[None, :] <= pos[:, None]) \
+            & (key_pos[None, :] > pos[:, None] - window)
+        out = _band_attention(q, keys(g["k"], k_new), keys(g["v"], v_new),
+                              band)
+    live = cache.get("live")
+    keep = jnp.ones((S_,), bool) if live is None else live
+    last = jnp.max(jnp.where(keep, pos, -1))
+    return out, write(table[0], pos, k_new[0], v_new[0],
+                      keep & (pos > last - window))
 
 
 def _fallback_reason(bias, window):
@@ -350,7 +481,11 @@ def write_and_attend(cfg, q, k, v, positions, cache, *, bias=None,
     paged = "pages" in cache
     prefill_from_zero = bool(prefill) and S_ > 1 and bias is None
     mode = select_kernel(s=S_, paged=paged, has_bias=bias is not None,
-                         has_window=window is not None)
+                         has_window=window is not None,
+                         ring="ring" in cache)
+    if "ring" in cache:
+        return _ring_write_and_attend(mode, q, k, v, positions, cache,
+                                      window)
     if not prefill_from_zero:
         fused = _fused_decode(cfg, q, k, v, positions, cache, mode, window)
         if fused is not None:

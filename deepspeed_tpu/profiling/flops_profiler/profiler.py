@@ -339,6 +339,17 @@ SCOPE_PARTS = (
     (r"scmoe\.experts|moe\.zero_experts", "moe.experts"),
     (r"scmoe\.dense_ffn", "mlp"),
     (r"attn\.mla_dense_(chunk|decode)", "attn.core"),
+    # a sandwich-norm block with gated attention over two kinds of K/V cache
+    # (``models/trinity.py``): its norms' and the embedding multiplier's
+    # scopes, the head's, the output gate's projection (named like an MLP's)
+    # and what it does around its attention kernels
+    (r"norm\.(input|post_attn|pre_mlp|post_mlp)|\w+_layernorm", "norm"),
+    (r"embed\.scale", "embed"),
+    (r"head\.logits", "head"),
+    (r"slots\.tables", "slots"),
+    (r"self_attn/gate_proj", "attn.proj"),
+    (r"attn\.(qk_norm|out_gate)", "attn.proj"),
+    (r"attn\.(window|full)", "attn.core"),
     # the layer scan's own operations (``scan_layers``): a layer's slice
     # out of the stacked parameters and saved residuals, the saves' and the
     # gradients' writes back into the stacks, the stacks' zeros and copies
@@ -352,7 +363,8 @@ SCOPE_PARTS = (
     (r"\w*moe\.experts_(gmm|grouped)\w*", "moe.experts"),
     (r"\w*attn\.(flash_(fwd|dq|dkv)|block_sparse_fwd|chunk_prefill|decode"
      r"|paged_decode|paged_chunk_prefill|dsa_index|dsa_lane_index|dsa_topk"
-     r"|mla_chunk_prefill|mla_window|mla_sparse_decode|mla_lane_decode)\w*",
+     r"|mla_chunk_prefill|mla_window|mla_sparse_decode|mla_lane_decode"
+     r"|gqa_window_chunk)\w*",
      "attn.core"),
     (r"\w*attn\.eva_(decode|chunk)\w*", "attn.eva"),
     # flax modules and their methods

@@ -227,6 +227,32 @@ D = "jit(decode_block)/while/body/closed_call/"
      "fwd"),
     ("jit(chunk_step)/Glm5Model.draft/mtp.head/lm_head/dot_general", "head",
      "fwd"),
+    # a sandwich-norm block with gated attention over a ring and lane pages
+    (D + "TrinityModel.decode/embed.scale/mul", "embed", "fwd"),
+    (D + "TrinityModel.decode/layers_1/norm.post_attn/"
+     "post_attention_layernorm/mul", "norm", "fwd"),
+    (D + "TrinityModel.decode/layers_1/pre_mlp_layernorm/rsqrt", "norm",
+     "fwd"),
+    (D + "TrinityModel.decode/layers_1/self_attn/gate_proj/dot_general",
+     "attn.proj", "fwd"),
+    (D + "TrinityModel.decode/layers_1/self_attn/attn.qk_norm/mul",
+     "attn.proj", "fwd"),
+    (D + "TrinityModel.decode/layers_1/self_attn/attn.out_gate/logistic",
+     "attn.proj", "fwd"),
+    (D + "TrinityModel.decode/layers_1/self_attn/attn.window/cache.write/"
+     "scatter", "cache.write", "fwd"),
+    (D + "TrinityModel.decode/layers_1/self_attn/attn.window/"
+     "attn.paged_decode", "attn.core", "fwd"),
+    ("jit(chunk_step)/TrinityModel.decode/layers_2/self_attn/attn.window/"
+     "attn.gqa_window_chunk", "attn.core", "fwd"),
+    (D + "TrinityModel.decode/layers_4/self_attn/attn.full/reshape",
+     "attn.core", "fwd"),
+    (D + "TrinityModel.decode/layers_0/mlp/gate_proj/dot_general", "mlp",
+     "fwd"),
+    (D + "TrinityModel.decode/slots.tables/slice", "slots", "fwd"),
+    (D + "TrinityModel.decode/head.logits/norm/mul", "head", "fwd"),
+    (D + "TrinityModel.decode/head.logits/lm_head/dot_general", "head",
+     "fwd"),
     # nothing the table knows: unattributed
     ("jit(train_step)/mul", None, "fwd"),
     ("jit(train_step)/transpose(jvp(Transformer))/broadcast_in_dim", None,
@@ -244,7 +270,7 @@ def test_part_and_phase_of_an_op_name(op_name, part, phase):
 def test_the_table_is_a_fixed_literal_set_of_known_parts():
     assert {part for _, part in profiler.SCOPE_PARTS} <= set(profiler.PARTS)
     # never a size or an index in a scope name the programs add
-    for rx, _ in profiler.SCOPE_PARTS[:16]:
+    for rx, _ in profiler.SCOPE_PARTS[:23]:
         assert not any(ch.isdigit() for ch in rx)
 
 

@@ -684,6 +684,65 @@ def test_longcat_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     assert 13.0e9 < total < 15.5e9, f"{total / 1e9:.2f} GB"
 
 
+@pytest.mark.parametrize("program", ["chunk_step", "decode_block"])
+def test_trinity_slot_programs_compile_at_the_cells_sizes(program, one_chip,
+                                                          mosaic):
+    """The two programs ``trinity-serve-mixedlen-batch`` runs, whole, as
+    ``serving/slots.py`` builds them at the cell's own settings: four
+    sliding layers over K/V rings of 32 pages a slot and one full layer over
+    K/V lane pages — ``attn.gqa_window_chunk`` / ``attn.paged_decode`` over
+    the ring's table and ``attn.paged_chunk_prefill`` / the fused paged
+    decode —, four expert layers of 128 experts, a 200,192-wide head; every
+    pool aliased input -> output, 8.48 GB of weights + 2.15 GB of lane pool
+    + 2.15 GB of rings and the programs' temporaries inside one chip; and
+    every instruction under the model's call lies in a part of the
+    profiler's table."""
+    import re
+    from deepspeed_tpu.inference.serving import slots
+    from deepspeed_tpu.profiling.flops_profiler import profiler
+    c = _slot_programs_of("trinity-serve-mixedlen-batch", "trinity",
+                          one_chip)
+    module, s, chunk = c.module, c.serving, c.chunk
+    params, ints, on_chip = c.params, c.ints, c.on_chip
+    from deepspeed_tpu.inference.serving.paging import SlotPages
+    pages = SlotPages(module, c.declared, s["num_slots"], s["max_cache_len"],
+                      s["page_size"], s["num_pages"], chunk, False, {})
+    pool = on_chip(jax.eval_shape(lambda: pages.new_pools(BF16)))
+    assert (pages.pages_per_slot, pages.ring_pages, pages.window_pages) \
+        == (273, 32, 128 * 32 + 1)
+    assert {k: v.shape for k, v in pool.items()} == {
+        "k": (1, s["num_pages"], 64, 512), "v": (1, s["num_pages"], 64, 512),
+        "k_ring": (4, 4097, 64, 512), "v_ring": (4, 4097, 64, 512)}
+    if program == "chunk_step":
+        compiled = slots.make_chunk_fn(module, c.declared, None).lower(
+            params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
+            ints(1)).compile()
+        calls = 4 + 1 + 4     # a window chunk, a paged chunk; the experts
+    else:
+        n = s["num_slots"]
+        state = on_chip({k: jnp.asarray(v) for k, v in
+                         slots.init_slot_state(n).items()})
+        rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                   sharding=one_chip)
+        compiled = slots.make_decode_block_fn(
+            module, c.declared, lambda logits, rng: jnp.argmax(logits, -1),
+            None, s["decode_block"], pages.cache_len).lower(
+                params, pool, state, ints(n, pages.table_width),
+                rng).compile()
+        calls = 5 + 4         # paged decode a layer; the experts
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= calls
+    named = set(re.findall(r'op_name="([^"]*TrinityModel\.decode[^"]*)"',
+                           text))
+    assert named and not [n for n in named if profiler.part_of(n)[0] is None]
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * 2 for x in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 12.5e9 < total < 15.5e9, f"{total / 1e9:.2f} GB"
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
     fn, shapes = CASES[case]()

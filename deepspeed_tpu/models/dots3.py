@@ -32,7 +32,7 @@ import flax.linen as nn
 
 from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.models.latent_attention import (LatentSpec,
-                                                   causal_pairs,
+                                                   causal_pairs, flash_tiles,
                                                    live_block_rows, padded)
 from deepspeed_tpu.models.latent_block import (LatentBlock,  # noqa: F401
                                                _Mlp, _Norm)
@@ -180,7 +180,8 @@ class Dots3Model(nn.Module):
             experts=(cfg.held_experts or (0, cfg.n_routed_experts))[1],
             chunk_work=self._chunk_work, block_work=self._block_work,
             work_counters=("dsa_keys_scored", "dsa_keys_kept",
-                           "latent_rows_read", "window_keys"),
+                           "latent_rows_read", "window_keys",
+                           "flash_tiles_live", "flash_tiles_whole"),
             work_levels=("latent_rows_decompressed", "window_pages"))
 
     def _ring_pages(self, page_size):
@@ -202,17 +203,25 @@ class Dots3Model(nn.Module):
         the lane (a padded last chunk's blocks past ``end`` run too and
         are not counted) —, ``window_pages`` — ring pages the
         window layers hold for the slot —, ``window_keys`` — pairs the
-        window layers attend."""
+        window layers attend —, ``flash_tiles_live`` / ``flash_tiles_whole``
+        — (query, key) tiles the chunk flash kernel walks in the layers of
+        both kinds, and those of them its mask keeps whole
+        (``latent_attention.flash_tiles``: past ``index_topk`` a lower
+        bound)."""
         cfg = self.config
         full = len(cfg.layers_of("full_attention"))
         swa = len(cfg.layers_of("sliding_attention"))
         pairs = lambda limit: causal_pairs(start, end, limit)
+        lane = flash_tiles(start, end, limit=cfg.full.index_topk)
+        band = flash_tiles(start, end, window=cfg.window.window)
         return {"dsa_keys_scored": full * pairs(end),
                 "dsa_keys_kept": full * pairs(cfg.full.index_topk),
                 "latent_rows_read": full * -(-end // page_size) * page_size,
                 "latent_rows_decompressed": full * live_block_rows(end),
                 "window_pages": ring_pages * swa,
-                "window_keys": swa * pairs(cfg.window.window)}
+                "window_keys": swa * pairs(cfg.window.window),
+                "flash_tiles_live": full * lane[0] + swa * band[0],
+                "flash_tiles_whole": full * lane[1] + swa * band[1]}
 
     def _block_work(self, live, ring_pages, layers):
         """The same for a decode block, from ``live`` — ``(context, steps)``

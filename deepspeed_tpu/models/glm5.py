@@ -54,7 +54,7 @@ import flax.linen as nn
 
 from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.models.latent_attention import (LatentSpec,
-                                                   causal_pairs,
+                                                   causal_pairs, flash_tiles,
                                                    live_block_rows, padded)
 from deepspeed_tpu.models.latent_block import LatentBlock, _Norm
 
@@ -217,7 +217,8 @@ class Glm5Model(nn.Module):
             draft_layers=cfg.mtp_layers,
             chunk_work=self._chunk_work, block_work=self._block_work,
             work_counters=("dsa_keys_scored", "dsa_keys_kept",
-                           "latent_rows_read"),
+                           "latent_rows_read", "flash_tiles_live",
+                           "flash_tiles_whole"),
             work_levels=("latent_rows_decompressed",))
 
     def _chunk_work(self, start, end, page_size, ring_pages, layers):
@@ -230,13 +231,20 @@ class Glm5Model(nn.Module):
         slot's live rows, once a layer) —, ``latent_rows_decompressed`` —
         rows up-projected into every head's keys and values: the live key
         blocks, whole, not the lane (a padded last chunk's blocks past
-        ``end`` run too and are not counted)."""
+        ``end`` run too and are not counted) —, ``flash_tiles_live`` /
+        ``flash_tiles_whole`` — (query, key) tiles the chunk flash kernel
+        walks, and those of them its mask keeps whole
+        (``latent_attention.flash_tiles``: past ``index_topk`` a lower
+        bound)."""
         cfg = self.config
         pairs = lambda limit: causal_pairs(start, end, limit)
+        live, whole = flash_tiles(start, end, limit=cfg.attn.index_topk)
         return {"dsa_keys_scored": layers * pairs(end),
                 "dsa_keys_kept": layers * pairs(cfg.attn.index_topk),
                 "latent_rows_read": layers * -(-end // page_size) * page_size,
-                "latent_rows_decompressed": layers * live_block_rows(end)}
+                "latent_rows_decompressed": layers * live_block_rows(end),
+                "flash_tiles_live": layers * live,
+                "flash_tiles_whole": layers * whole}
 
     def _block_work(self, live, ring_pages, layers):
         """The same for the rows of a decode dispatch, from ``live`` —

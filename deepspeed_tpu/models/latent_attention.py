@@ -56,7 +56,7 @@ import flax.linen as nn
 
 from deepspeed_tpu.ops.transformer import latent_attention as ops
 
-LANES = 128
+LANES = ops.LANES
 # A full layer's decode takes the LANE form — a lane's rows read once for
 # all its rows and heads, a row's kept set a mask over them — while the
 # slot's table spans at most this many kept sets (``index_topk``), and
@@ -154,6 +154,36 @@ def live_block_rows(end):
     position is ``end - 1``: the live key blocks, whole — the host-side
     count behind ``chunk_work``'s ``latent_rows_decompressed``."""
     return -(-end // ops.KEY_BLOCK) * ops.KEY_BLOCK
+
+
+def flash_tiles(start, end, limit=None, window=0):
+    """``(live, whole)``: how many of the chunk flash kernel's ``KEY_BLOCK
+    x KEY_BLOCK`` (query, key) tiles one layer walks for a chunk's real
+    positions ``start .. end - 1`` (every head block walks each), and how
+    many of those its mask keeps WHOLE — the host-side count behind
+    ``chunk_work``'s ``flash_tiles_live`` / ``flash_tiles_whole``.  Query
+    tiles lie from ``start``; key tiles from position 0 (a full layer's
+    lane) or, ``window`` set, from the band's first key ``start - window +
+    1`` — the kernel's own tiles wherever the chunk and the keys are whole
+    512-row tiles, or fewer than 512 rows.  A tile is live where a pair is
+    visible.  ``limit`` (a selecting layer's ``index_topk``): a query past
+    it keeps a subset nobody knows here, so a tile counts as whole only
+    where every query keeps all it sees — a LOWER bound on the whole tiles
+    of the contexts past ``limit``, exact before it."""
+    tile = ops.KEY_BLOCK
+    first = start - window + 1 if window else 0
+    live = whole = 0
+    for a in range(start, end, tile):
+        b = min(a + tile, end)                 # queries a .. b - 1
+        for c in range(first, b, tile):        # keys c .. d - 1, some <= b - 1
+            d = c + tile
+            if d <= 0 or (window and d - 1 <= a - window):
+                continue
+            live += 1
+            whole += c >= 0 and d - 1 <= a \
+                and (not window or c > b - 1 - window) \
+                and (limit is None or b <= limit)
+    return live, whole
 
 
 def lane_rows(pool, layer, table_row, multiple):

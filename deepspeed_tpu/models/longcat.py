@@ -48,7 +48,8 @@ import flax.linen as nn
 from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.models.latent_attention import (LatentAttention,
                                                    LatentSpec,
-                                                   causal_pairs, padded)
+                                                   causal_pairs, flash_tiles,
+                                                   padded)
 from deepspeed_tpu.models.latent_block import _Mlp, _Norm
 from deepspeed_tpu.moe.layer import MoE
 
@@ -195,7 +196,8 @@ class LongcatModel(nn.Module):
             expert_layers=cfg.num_layers,
             experts=(cfg.held_experts or (0, cfg.n_routed_experts))[1],
             chunk_work=self._chunk_work, block_work=self._block_work,
-            work_counters=("latent_rows_read", "causal_pairs"))
+            work_counters=("latent_rows_read", "causal_pairs",
+                           "flash_tiles_live", "flash_tiles_whole"))
 
     @staticmethod
     def _chunk_work(start, end, page_size, ring_pages, layers):
@@ -203,9 +205,15 @@ class LongcatModel(nn.Module):
         this model's attention, as its dispatch span's args, summed over
         the pool ``layers``: ``causal_pairs`` — (query, key) pairs under
         the causal mask —, ``latent_rows_read`` — latent rows fetched from
-        the pool (the slot's live rows, once a layer)."""
+        the pool (the slot's live rows, once a layer) —,
+        ``flash_tiles_live`` / ``flash_tiles_whole`` — (query, key) tiles
+        the chunk flash kernel walks, and those of them wholly under the
+        diagonal (``latent_attention.flash_tiles``)."""
+        live, whole = flash_tiles(start, end)
         return {"causal_pairs": layers * causal_pairs(start, end, end),
-                "latent_rows_read": layers * -(-end // page_size) * page_size}
+                "latent_rows_read": layers * -(-end // page_size) * page_size,
+                "flash_tiles_live": layers * live,
+                "flash_tiles_whole": layers * whole}
 
     @staticmethod
     def _block_work(live, ring_pages, layers):

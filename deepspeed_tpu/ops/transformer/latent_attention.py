@@ -15,7 +15,10 @@ Pallas kernels, each findable in a device trace by its own name:
   of a full layer, the band of a window layer).  The key has two parts —
   a per-head ``nope`` part and ONE ``rope`` part shared by all heads — so
   the shared part is never copied per head.  Tiles in which the mask
-  keeps nothing are skipped, DMA and body.
+  keeps nothing are skipped, DMA and body.  A grid step holds eight
+  heads; the running max and sum live lane-replicated (no lane broadcast
+  a vreg of the score tile), and a masked score is selected ONCE: the
+  running max starts at a floor above the masked value.
 
 * ``attn.mla_decompress`` (:func:`decompress`) — a cached full layer's
   ``c_kv W_kvb``: every head's keys and values of the lane's key blocks up
@@ -68,6 +71,17 @@ from deepspeed_tpu.ops.transformer.paged_attention import (_live_pages,
                                                            _zero_value_tail)
 
 NEG = -1e30
+# where a flash kernel's running max starts: finite and ABOVE the masked
+# score, so exp(NEG - max) is 0 from a row's first tile on and a masked
+# weight needs no second select; a row that keeps nothing ends with a sum
+# of 0 and returns zeros
+FLOOR = -1e29
+LANES = 128
+# what this file's grid kernels ask of VMEM, flat (ROADMAP S26)
+VMEM_ASK = 64 * 1024 * 1024
+# heads of a flash grid step unrolled into one block of code (the rest of
+# the step's heads loop over such groups)
+HEAD_GROUP = 2
 # a chunk's keys are scored, decompressed and attended in blocks of this
 # many rows: a lane is whole blocks, and what one kernel leaves out past
 # the live ones (``decompress``) no other fetches (``masked_flash``)
@@ -127,7 +141,7 @@ def index_scores(q, w, k, live_keys, block_q=32, block_k=KEY_BLOCK):
         out_shape=jax.ShapeDtypeStruct((C, L), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
+            vmem_limit_bytes=VMEM_ASK),
         interpret=_interpret(),
         name="attn.dsa_index",
     )(live, q.reshape(C * J, D),
@@ -295,7 +309,7 @@ def _lane_call(kernel, name, ctx, layer, table, operands, pool, out_width,
         input_output_aliases={3 + len(operands): 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=64 * 1024 * 1024),
+            vmem_limit_bytes=VMEM_ASK),
         interpret=_interpret(),
         name=name,
     )(ctx.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
@@ -569,7 +583,7 @@ def decompress(rows, w, heads, nope, live_keys, block_k=KEY_BLOCK,
                    jax.ShapeDtypeStruct((heads, L, per - nope), rows.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
+            vmem_limit_bytes=VMEM_ASK),
         interpret=_interpret(),
         name="attn.mla_decompress",
     )(live, rows, w)
@@ -578,14 +592,24 @@ def decompress(rows, w, heads, nope, live_keys, block_k=KEY_BLOCK,
 # --------------------------------------------------------------------- #
 # attn.mla_chunk_prefill / attn.mla_window
 # --------------------------------------------------------------------- #
+def _across(x, n):
+    """``x [rows, LANES]``, every lane its row's value, as ``[rows, n]``:
+    whole vregs side by side where ``n`` is whole lane tiles — no lane
+    broadcast a vreg, which a ``[rows, 1]`` column costs."""
+    if n % LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if n == LANES else jnp.tile(x, (1, n // LANES))
+
+
 def _flash_kernel(live_ref, fetch_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
-                  mask_ref, o_ref, m_ref, l_ref, acc_ref, *, scale):
+                  mask_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, group):
     j = pl.program_id(2)
     tile = pl.program_id(1) * pl.num_programs(2) + j
+    heads, dv = o_ref.shape[0], o_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full(m_ref.shape, NEG, m_ref.dtype)
+        m_ref[...] = jnp.full(m_ref.shape, FLOOR, m_ref.dtype)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -594,26 +618,43 @@ def _flash_kernel(live_ref, fetch_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
         keep = mask_ref[...] != 0
         contract = (((1,), (1,)), ((), ()))
         kr = kr_ref[...]
-        for h in range(qn_ref.shape[0]):
+
+        def head(h):
             s = jax.lax.dot_general(qn_ref[h], kn_ref[h], contract,
                                     preferred_element_type=jnp.float32) \
                 + jax.lax.dot_general(qr_ref[h], kr, contract,
                                       preferred_element_type=jnp.float32)
+            # ONE select: the running max never falls under FLOOR, so a
+            # masked score's exp(NEG - m) is 0 whatever its row has kept
             s = jnp.where(keep, s * scale, NEG)
             m_old = m_ref[h]
             m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+            p = jnp.exp(s - _across(m_new, s.shape[1]))
             alpha = jnp.exp(m_old - m_new)
             l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+            acc_ref[h] = _across(alpha, dv) * acc_ref[h] + jnp.dot(
                 p.astype(v_ref.dtype), v_ref[h],
                 preferred_element_type=jnp.float32)
             m_ref[h] = m_new
 
+        # ``group`` heads are one block of code — one head's matmuls fill
+        # the MXU while its neighbour's softmax walks the VPU — and the
+        # groups a loop: the compile time is the group's, not the block's
+        def heads_of(g, carry):
+            for u in range(group):
+                head(g * group + u)
+            return carry
+
+        if heads == group:
+            heads_of(0, None)
+        else:
+            jax.lax.fori_loop(0, heads // group, heads_of, None)
+
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                      ).astype(o_ref.dtype)
+        for h in range(heads):
+            o_ref[h] = (acc_ref[h] / jnp.maximum(_across(l_ref[h], dv), 1e-30)
+                        ).astype(o_ref.dtype)
 
 
 def _tile_plan(mask, bq, bk):
@@ -631,8 +672,21 @@ def _tile_plan(mask, bq, bk):
     return live.astype(jnp.int32).reshape(-1), fetch.reshape(-1)
 
 
+def _flash_vmem_bytes(bh, bq, bk, dn, dr, dv, itemsize):
+    """The VMEM a grid step of :func:`masked_flash` needs: its blocks —
+    queries, keys, values, mask, output — double-buffered by the pipeline,
+    the running max and sum (a 128-lane float32 tile a head each) and the
+    accumulator, and a group of heads' score tiles (float32 scores and
+    weights, the weights again in the pool's dtype)."""
+    blocks = bh * bq * (dn + dr) + bh * bk * (dn + dv) + bk * dr \
+        + bh * bq * dv
+    scratch = bh * bq * (2 * LANES + dv) * 4
+    return 2 * (blocks * itemsize + bq * bk) + scratch \
+        + HEAD_GROUP * bq * bk * (4 + 4 + itemsize)
+
+
 def masked_flash(q_nope, q_rope, k_nope, k_rope, v, mask, scale, name,
-                 block_q=512, block_k=KEY_BLOCK, block_h=2):
+                 block_q=512, block_k=KEY_BLOCK, block_h=8):
     """``out [H, C, Dv]``: softmax over the keys ``mask [C, L]`` keeps of
     ``(q_nope . k_nope + q_rope . k_rope) * scale``, times ``v``.
     ``q_nope [H, C, Dn]``, ``q_rope [H, C, Dr]``, ``k_nope [H, L, Dn]``,
@@ -640,7 +694,10 @@ def masked_flash(q_nope, q_rope, k_nope, k_rope, v, mask, scale, name,
     ``block_q`` queries by ``block_k`` keys in which the mask keeps nothing
     is skipped, DMA and body — the keys past a chunk's last position, the
     chunk's own upper triangle, everything off a window layer's band.  A
-    query whose mask keeps nothing gets zeros."""
+    query whose mask keeps nothing gets zeros.  A grid step holds
+    ``block_h`` heads (one mask tile unpacked for all of them) and walks
+    them in groups of :data:`HEAD_GROUP`; the running max and sum live
+    lane-replicated, ``[.., LANES]`` wide."""
     H, C, Dn = q_nope.shape
     L, Dr = k_rope.shape
     Dv = v.shape[-1]
@@ -649,7 +706,8 @@ def masked_flash(q_nope, q_rope, k_nope, k_rope, v, mask, scale, name,
     live, fetch = _tile_plan(mask, bq, bk)
     kb = lambda i, j, fetch: fetch[i * nk + j]
     return pl.pallas_call(
-        functools.partial(_flash_kernel, scale=scale),
+        functools.partial(_flash_kernel, scale=scale,
+                          group=_block(bh, HEAD_GROUP)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(H // bh, C // bq, nk),
             in_specs=[
@@ -665,13 +723,13 @@ def masked_flash(q_nope, q_rope, k_nope, k_rope, v, mask, scale, name,
                              lambda h, i, j, lv, f: (i, kb(i, j, f)))],
             out_specs=pl.BlockSpec((bh, bq, Dv),
                                    lambda h, i, j, lv, f: (h, i, 0)),
-            scratch_shapes=[pltpu.VMEM((bh, bq, 1), jnp.float32),
-                            pltpu.VMEM((bh, bq, 1), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((bh, bq, LANES), jnp.float32),
+                            pltpu.VMEM((bh, bq, LANES), jnp.float32),
                             pltpu.VMEM((bh, bq, Dv), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((H, C, Dv), q_nope.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
+            vmem_limit_bytes=VMEM_ASK),
         interpret=_interpret(),
         name=name,
     )(live, fetch, q_nope, q_rope, k_nope, k_rope, v, mask)

@@ -339,32 +339,124 @@ def test_kept_mask_is_the_exact_top_k(k, ties):
             == set(np.nonzero(mask[t])[0])
 
 
-@pytest.mark.parametrize("live", [64, 128])
-def test_masked_flash_kernel(live):
-    rng = np.random.default_rng(live)
-    H, C, L, Dn, Dr, Dv = 4, 32, 128, 16, 8, 16
+def _flash_case(case, rng):
+    """``(sizes (C, L, Dv), blocks (q, k), mask [C, L])`` of one case of
+    :func:`test_masked_flash_kernel`."""
+    sizes, blocks = (32, 128, 16), (8, 32)
+    if case in ("live64", "live128"):
+        live = int(case[4:])
+        mask = rng.uniform(size=(32, 128)) < 0.3
+        mask[:, live:] = False
+        mask[5] = False                # a query that keeps nothing: zeros
+        mask[8:16, :64] = False        # tiles that keep nothing: skipped
+    elif case == "whole_tiles":
+        # a chunk at positions 96 .. 127 under the causal mask: every tile
+        # left of the diagonal's is kept whole
+        mask = np.arange(128)[None, :] <= 96 + np.arange(32)[:, None]
+    elif case == "keeps_late":
+        # a row whose first live tiles keep nothing (its neighbours' do):
+        # its running max waits at the floor, its weights there are 0
+        mask = rng.uniform(size=(32, 128)) < 0.4
+        mask[3, :96] = False
+        mask[3, 100] = True
+    elif case == "keeps_early":
+        # ... and one that keeps something early and nothing after
+        mask = rng.uniform(size=(32, 128)) < 0.4
+        mask[4, 32:] = False
+        mask[4, 7] = True
+    elif case == "band":
+        # a window layer's: 16 predecessors (the first 6 before position 0)
+        # and the chunk, each query its 17 last keys
+        sizes, blocks = (32, 48, 16), (8, 16)
+        positions = 10 + np.arange(32)
+        key_pos = np.concatenate([10 - 16 + np.arange(16), positions])
+        mask = (key_pos[None, :] >= 0) \
+            & (key_pos[None, :] <= positions[:, None]) \
+            & (key_pos[None, :] > positions[:, None] - 17)
+    else:
+        # whole lane tiles of keys and values, as on the chip: the running
+        # max and sum are laid side by side across them, not broadcast
+        sizes, blocks = (16, 256, 256), (8, 128)
+        mask = rng.uniform(size=(16, 256)) < 0.3
+        mask[2] = False
+    return sizes, blocks, mask
+
+
+@pytest.mark.parametrize("block_h", [1, 2, 4])
+@pytest.mark.parametrize("case", ["live64", "live128", "whole_tiles",
+                                  "keeps_late", "keeps_early", "band",
+                                  "lane_tiles"])
+def test_masked_flash_kernel(case, block_h):
+    """The chunk flash kernel against the plain softmax under the same
+    mask, a head, a group of two and two groups a grid step."""
+    rng = np.random.default_rng(len(case))
+    (C, L, Dv), (bq, bk), mask = _flash_case(case, rng)
+    H, Dn, Dr = 4, 16, 8
     r = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     qn, qr, kn, kr, v = r(H, C, Dn), r(H, C, Dr), r(H, L, Dn), r(L, Dr), \
         r(H, L, Dv)
-    mask = rng.uniform(size=(C, L)) < 0.3
-    mask[:, live:] = False
-    mask[5] = False                    # a query that keeps nothing: zeros
-    mask[8:16, :64] = False            # tiles that keep nothing: skipped
     got = np.asarray(ops.masked_flash(
         qn, qr, kn, kr, v, jnp.asarray(mask, jnp.int8), 0.2,
-        "attn.mla_chunk_prefill", block_q=8, block_k=32, block_h=2))
+        "attn.mla_chunk_prefill", block_q=bq, block_k=bk, block_h=block_h))
     s = (np.einsum("hcd,hld->hcl", qn, kn)
          + np.einsum("hcd,ld->hcl", qr, kr)) * 0.2
-    p = np.where(mask[None], np.exp(s - s.max(-1, keepdims=True)), 0)
+    s = np.where(mask[None], s, -np.inf)
+    p = np.exp(s - np.maximum(s.max(-1, keepdims=True), -1e30))
     want = np.einsum("hcl,hld->hcd", p / np.maximum(
         p.sum(-1, keepdims=True), 1e-30), v)
-    assert np.abs(got - want).max() < 1e-4 and not got[:, 5].any()
-    tiles, fetch = ops._tile_plan(jnp.asarray(mask, jnp.int8), 8, 32)
-    tiles, fetch = np.asarray(tiles).reshape(4, 4), \
-        np.asarray(fetch).reshape(4, 4)
-    assert tiles[1].tolist() == [0, 0, live > 64, live > 64]
-    assert fetch[1].tolist() == ([2, 2, 2, 3] if live > 64 else [0] * 4)
-    assert (tiles[:, live // 32:] == 0).all()
+    assert np.abs(got - want).max() < 1e-4
+    empty = ~mask.any(axis=1)
+    assert not got[:, empty].any()
+    assert empty.any() == (case.startswith("live") or case == "lane_tiles")
+    tiles, fetch = ops._tile_plan(jnp.asarray(mask, jnp.int8), bq, bk)
+    tiles = np.asarray(tiles).reshape(C // bq, L // bk)
+    fetch = np.asarray(fetch).reshape(tiles.shape)
+    by_tile = mask.reshape(C // bq, bq, L // bk, bk)
+    assert np.array_equal(tiles, by_tile.any(axis=(1, 3)))
+    if case.startswith("live"):
+        live = int(case[4:])
+        assert tiles[1].tolist() == [0, 0, live > 64, live > 64]
+        assert fetch[1].tolist() == ([2, 2, 2, 3] if live > 64 else [0] * 4)
+        assert (tiles[:, live // 32:] == 0).all()
+    if case == "whole_tiles":
+        assert by_tile.all(axis=(1, 3)).sum() == 4 * 3 and tiles.all()
+    if case == "band":
+        assert tiles.tolist() == [[1, 1, 0], [1, 1, 0], [0, 1, 1],
+                                  [0, 1, 1]]   # two tiles a query tile
+
+
+@pytest.mark.parametrize("start,end,limit,window", [
+    (0, 32, 64, 0), (32, 64, 64, 0), (64, 96, 64, 0), (96, 120, 64, 0),
+    (64, 128, None, 0), (0, 64, None, 33), (128, 192, None, 33),
+    (128, 170, None, 33), (128, 192, None, 97), (64, 128, None, 65)])
+def test_flash_tiles_counts_the_masks_tiles(start, end, limit, window,
+                                            monkeypatch):
+    """The host's count behind ``flash_tiles_live`` / ``flash_tiles_whole``
+    against the tiles of the masks the layers build (tiles of 32 here, the
+    chunk two of them): a dense causal lane, a band, and a selecting
+    layer's, whose tiles are known whole only under ``limit``."""
+    from deepspeed_tpu.models import latent_attention as model
+    monkeypatch.setattr(ops, "KEY_BLOCK", 32)
+    positions = start + np.arange(64)
+    if window:
+        key_pos = np.concatenate([start - window + 1 + np.arange(window - 1),
+                                  positions])
+        mask = (key_pos[None, :] >= 0) \
+            & (key_pos[None, :] <= positions[:, None]) \
+            & (key_pos[None, :] > positions[:, None] - window)
+    else:
+        mask = np.arange(start + 64)[None, :] <= positions[:, None]
+    real = mask[:end - start]
+    real = np.pad(real, ((0, -real.shape[0] % 32), (0, 0)))
+    by_tile = real.reshape(-1, 32, mask.shape[1] // 32, 32)
+    live = by_tile.any(axis=(1, 3))
+    rows = np.minimum(start + 32 * (1 + np.arange(live.shape[0])), end)
+    whole = mask[:live.shape[0] * 32].reshape(by_tile.shape).all(
+        axis=(1, 3)) & live
+    if limit is not None:
+        whole &= (rows <= limit)[:, None]
+    assert model.flash_tiles(start, end, limit, window) \
+        == (live.sum(), whole.sum())
 
 
 @pytest.mark.parametrize("live", [20, 70, 128],
@@ -447,7 +539,15 @@ def test_chunk_work_counts_pairs(start, end, scored, kept, window):
     assert work == {"dsa_keys_scored": 2 * scored, "dsa_keys_kept": 2 * kept,
                     "latent_rows_read": 2 * end,
                     "latent_rows_decompressed": 2 * 512, "window_pages": 6,
-                    "window_keys": 2 * window}
+                    "window_keys": 2 * window,
+                    # one tile a layer: a toy chunk lies inside a key block
+                    "flash_tiles_live": 4, "flash_tiles_whole": 0}
+    # the cell's second chunk: two full layers' 3 + 4 tiles — none known
+    # whole past the toy ``index_topk`` (24) — and two window layers' band
+    # of 2 tiles a query tile
+    tiles = chunk_work(1024, 2048, 64, 9, 4)
+    assert (tiles["flash_tiles_live"], tiles["flash_tiles_whole"]) \
+        == (2 * 7 + 2 * 4, 0)
     # the live 512-key blocks, whole: what ``attn.mla_decompress`` runs
     assert [chunk_work(e - 1024, e, 64, 9, 4)["latent_rows_decompressed"]
             for e in (1024, 1500, 15360)] == [2 * 1024, 2 * 1536, 2 * 15360]

@@ -493,6 +493,10 @@ def test_chunk_work_counts_the_live_blocks(program, layers):
     assert work["latent_rows_decompressed"] == n * 5 * 512
     assert work["dsa_keys_kept"] <= work["dsa_keys_scored"] \
         == n * sum(range(1025, 2501))
+    # query tiles at 1024, 1536 and 2048 over 3, 4 and 5 key tiles; past
+    # the toy ``index_topk`` (24) no tile is known to be kept whole
+    assert (work["flash_tiles_live"], work["flash_tiles_whole"]) \
+        == (n * 12, 0)
 
 
 # ---- (h) the lane kernels against plain math ---------------------------- #

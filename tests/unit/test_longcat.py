@@ -317,11 +317,17 @@ def test_the_contract_and_its_work_counters():
     assert (c.num_layers, c.expert_layers, c.experts) == (4, 2, 8)
     assert c.own_chunk_path and not c.kv_pages and c.routes_experts
     assert c.holds_share and c.zero_experts and c.chunk_cap == 2048
-    assert c.work_counters == ("latent_rows_read", "causal_pairs")
+    assert c.work_counters == ("latent_rows_read", "causal_pairs",
+                               "flash_tiles_live", "flash_tiles_whole")
     # a chunk over positions 32 .. 47 of a slot, pages of 8, 4 pool layers
     assert c.chunk_work(32, 48, 8, 0, 4) == {
         "causal_pairs": 4 * (16 * 32 + 16 * 17 // 2),
-        "latent_rows_read": 4 * 48}
+        "latent_rows_read": 4 * 48,
+        "flash_tiles_live": 4, "flash_tiles_whole": 0}
+    # the cell's chunk of 512 at 512 .. 1023, eight pool layers: the tile
+    # under the diagonal is kept whole, the diagonal's is not
+    tiles = c.chunk_work(512, 1024, 64, 0, 8)
+    assert (tiles["flash_tiles_live"], tiles["flash_tiles_whole"]) == (16, 8)
     # two live slots, 3 and 2 rows: contexts 10, 11, 12 and 20, 21
     assert c.block_work([(10, 3), (20, 2)], 0, 4) == {
         "causal_pairs": 4 * 74, "latent_rows_read": 4 * 74}

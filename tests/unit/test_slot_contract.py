@@ -27,6 +27,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 SERVING = os.path.join(ROOT, "deepspeed_tpu", "inference", "serving")
 
 DSA = ("dsa_keys_scored", "dsa_keys_kept", "latent_rows_read")
+TILES = ("flash_tiles_live", "flash_tiles_whole")
 EVA = ("eva_ring_rows", "eva_summary_rows", "eva_local_pairs",
        "eva_remote_pairs", "eva_summaries_written")
 
@@ -43,7 +44,7 @@ FAMILIES = [
         vocab_size=19008, max_seq_len=524288, num_layers=5, kv_pages=False,
         row_kinds=("latent + index rows", "window rows"), chunk_cap=2048,
         own_chunk_path=True, routes_experts=True, holds_share=True,
-        expert_layers=4, experts=32, work_counters=DSA + ("window_keys",),
+        expert_layers=4, experts=32, work_counters=DSA + ("window_keys",) + TILES,
         work_levels=("latent_rows_decompressed", "window_pages")), 9),
     ("lfm2", "lfm2-serve-widegen-batch", {}, dict(
         vocab_size=65536, max_seq_len=128000, num_layers=10,
@@ -57,13 +58,15 @@ FAMILIES = [
     ("glm5", "glm5-serve-reasongen-batch", {"num_nextn_predict_layers": 0},
      dict(vocab_size=19360, max_seq_len=202752, num_layers=5, kv_pages=False,
           chunk_cap=2048, own_chunk_path=True, routes_experts=True,
-          holds_share=True, expert_layers=4, experts=16, work_counters=DSA,
+          holds_share=True, expert_layers=4, experts=16,
+          work_counters=DSA + TILES,
           work_levels=("latent_rows_decompressed",)), 0),
     ("glm5", "glm5-serve-reasongen-batch", {}, dict(
         vocab_size=19360, max_seq_len=202752, num_layers=5, kv_pages=False,
         chunk_cap=2048, own_chunk_path=True, routes_experts=True,
         holds_share=True, expert_layers=4, experts=16, draft_layers=1,
-        work_counters=DSA, work_levels=("latent_rows_decompressed",)), 0),
+        work_counters=DSA + TILES,
+        work_levels=("latent_rows_decompressed",)), 0),
 ]
 CALLABLES = ("ring_pages", "chunk_fault", "chunk_work", "block_work")
 

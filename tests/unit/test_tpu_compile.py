@@ -164,15 +164,17 @@ def _dsa_topk():
     return fn, [((DOTS3_CHUNK, DOTS3_LANE), F32), ((DOTS3_CHUNK,), I32)]
 
 
-def _mla_flash(name, heads, nope, keys):
-    """Full layers: 128 heads of 128 + 64 over the lane; window layers: 64
-    heads of 192 + 64 over the chunk and its 512 predecessors."""
+def _mla_flash(name, heads, nope, keys, chunk=DOTS3_CHUNK, v=128):
+    """dots3's full layers: 128 heads of 128 + 64 over the lane; its window
+    layers: 64 heads of 192 + 64 over the chunk and its 512 predecessors;
+    LongCat's dense causal layers: 64 heads of 128 + 64, a chunk of 512 over
+    a 2,112-position lane in 512-key blocks; GLM-5's: 64 heads of 192 + 64
+    with 256-wide values, the widest blocks the kernel holds."""
     def fn(qn, qr, kn, kr, v, mask):
         return latent_mod.masked_flash(qn, qr, kn, kr, v, mask, 0.07, name)
-    return fn, [((heads, DOTS3_CHUNK, nope), BF16),
-                ((heads, DOTS3_CHUNK, 64), BF16), ((heads, keys, nope), BF16),
-                ((keys, 64), BF16), ((heads, keys, 128), BF16),
-                ((DOTS3_CHUNK, keys), I8)]
+    return fn, [((heads, chunk, nope), BF16), ((heads, chunk, 64), BF16),
+                ((heads, keys, nope), BF16), ((keys, 64), BF16),
+                ((heads, keys, v), BF16), ((chunk, keys), I8)]
 
 
 def _mla_decompress(heads, nope, v, lane):
@@ -287,6 +289,10 @@ CASES = {
         "attn.mla_chunk_prefill", 128, 128, DOTS3_LANE),
     "dots3_mla_window_c2048": lambda: _mla_flash(
         "attn.mla_window", 64, 192, DOTS3_CHUNK + 512),
+    "longcat_mla_causal_prefill_c512": lambda: _mla_flash(
+        "attn.mla_chunk_prefill", 64, 128, 2560, chunk=512),
+    "glm5_mla_chunk_prefill_c1024": lambda: _mla_flash(
+        "attn.mla_chunk_prefill", 64, 192, 5120, chunk=1024, v=256),
     "dots3_mla_decompress_l16896": lambda: _mla_decompress(
         128, 128, 128, DOTS3_LANE),
     "glm5_mla_decompress_l5120": lambda: _mla_decompress(64, 192, 256, 5120),
@@ -323,6 +329,12 @@ CASES = {
 # the training cells' shapes, and one across two major blocks
 _FLASH_TRAINED = ("flash_fwd_bwd_s2048", "flash_fwd_bwd_s2048_d128",
                   "flash_fwd_bwd_s4096")
+
+
+# the four shapes ``latent_attention.masked_flash`` runs at in the cells
+_MLA_FLASH = ("dots3_mla_chunk_prefill_c2048", "dots3_mla_window_c2048",
+              "longcat_mla_causal_prefill_c512",
+              "glm5_mla_chunk_prefill_c1024")
 
 
 def _compile(fn, shapes, one_chip):
@@ -757,6 +769,15 @@ def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
         # the pair fails here and not in a benchmark
         assert text.count("tpu_custom_call") == 2, case
         assert "attn.flash_dq_dkv" in text and "attn.flash_fwd" in text
+    if case in _MLA_FLASH:
+        # the flash kernel's blocks at its own block sizes — eight heads a
+        # grid step — take under half the flat 64 MiB it asks for (a
+        # kernel over its ask does not compile: this says by how much it
+        # is under)
+        nope, v = shapes[0][0][2], shapes[4][0][2]
+        need = latent_mod._flash_vmem_bytes(8, 512, 512, nope, 64, v, 2)
+        assert need <= latent_mod.VMEM_ASK // 2, \
+            f"{case}: {need / 2 ** 20:.1f} MiB"
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)

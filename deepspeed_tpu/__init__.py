@@ -10,6 +10,10 @@ Top-level API parity:
 * ``add_config_arguments()``(reference ``__init__.py:237``)
 """
 
+import functools as _functools
+import time as _time
+_T_IMPORT = _time.monotonic()            # dstpu.setup.import opens here
+
 __version__ = "0.1.0"
 __git_hash__ = None
 __git_branch__ = None
@@ -27,11 +31,27 @@ from deepspeed_tpu.ops.transformer.transformer import (  # noqa: F401
     DeepSpeedTransformerConfig, DeepSpeedTransformerLayer)
 from deepspeed_tpu.runtime.activation_checkpointing import checkpointing  # noqa: F401
 from deepspeed_tpu.utils.logging import logger, log_dist  # noqa: F401
+from deepspeed_tpu.monitor.trace import span as _span
 
 from deepspeed_tpu.ops.adam.fused_adam import FusedAdam, FusedAdamW  # noqa: F401
 from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb  # noqa: F401
 
 
+def _setup_engine_span(fn):
+    """Run an entry point under ``dstpu.setup.engine`` (config, topology
+    and mesh, the engine object; ``docs/observability.md`` "Start-up")."""
+    @_functools.wraps(fn)
+    def entry(*args, **kwargs):
+        with _span("dstpu.setup.engine", cat="setup",
+                   entry=fn.__name__) as sp:
+            out = fn(*args, **kwargs)
+            engine = out[0] if isinstance(out, tuple) else out
+            sp.set(chips=engine.mesh.size)
+        return out
+    return entry
+
+
+@_setup_engine_span
 def initialize(args=None,
                model=None,
                optimizer=None,
@@ -117,6 +137,7 @@ def initialize(args=None,
     return engine, engine.optimizer, engine.training_dataloader, engine.lr_scheduler
 
 
+@_setup_engine_span
 def init_inference(model=None, config=None, **kwargs):
     """Initialize the inference engine (reference ``__init__.py:260``).
 
@@ -136,7 +157,11 @@ def init_inference(model=None, config=None, **kwargs):
         is_torch = True
     else:
         try:
-            import torch
+            # seconds where the process has not imported torch yet (a named
+            # part of the engine's set-up)
+            with _span("dstpu.setup.lazy_import", cat="setup",
+                       module="torch"):
+                import torch
             is_torch = isinstance(model, torch.nn.Module)
         except ImportError:
             pass
@@ -174,3 +199,7 @@ def add_config_arguments(parser):
     group.add_argument("--local_rank", type=int, default=-1,
                        help="local rank passed by the launcher")
     return parser
+
+
+with _span("dstpu.setup.import", cat="setup", start=_T_IMPORT):
+    pass                                 # first line of this file -> here

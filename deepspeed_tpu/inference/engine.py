@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+from deepspeed_tpu.monitor.trace import ready_line, span
 from deepspeed_tpu.parallel import topology as topo_mod
 from deepspeed_tpu.runtime import compile_cache as compile_cache_mod
 from deepspeed_tpu.runtime.zero.partition import build_sharding_plan
@@ -74,6 +75,9 @@ class InferenceEngine:
         self._workspace = KVCacheWorkspace(model)
         self._aot = {}
         self._tags = {}          # id(jit fn) -> stable program tag
+        # id(jit fn) -> the short kind its dispatch span uses ("decode",
+        # "prefill_chunk", ...): the compile span's ``program``
+        self._programs = {}
         # ids of jitted fns that must NOT touch the persistent caches —
         # neither the serialized-executable store nor the XLA disk cache.
         # The serving slot programs register here: reloading any of them
@@ -107,6 +111,16 @@ class InferenceEngine:
         return build_sharding_plan(abstract, self.topology, ZeroConfig(stage=0))
 
     def set_params(self, params):
+        """Cast (or quantize) ``params`` and place them on the mesh by the
+        tensor-parallel plan, under ``dstpu.setup.weights``."""
+        with span("dstpu.setup.weights", cat="setup") as sp:
+            self._place_params(params)
+            leaves = jax.tree.leaves(self._params)
+            sp.set(bytes=sum(l.nbytes for l in leaves), leaves=len(leaves),
+                   sharded=int(self.topology.tp > 1
+                               and self._quantizer is None))
+
+    def _place_params(self, params):
         if self._quantizer is not None:
             # INT8/INT4-at-rest (reference WeightQuantization at checkpoint
             # load): payload+scales live in HBM; dequant runs inside the
@@ -559,10 +573,13 @@ class InferenceEngine:
         ``engine.serve(speculative=True, draft_module=...,
         draft_params=...)`` or set ``serving.spec_draft_model``
         (``docs/serving.md`` "Speculative decoding")."""
-        from deepspeed_tpu.inference.serving.engine import ServingEngine
-        return ServingEngine(self, monitor=monitor,
-                             draft_module=draft_module,
-                             draft_params=draft_params, **overrides)
+        with span("dstpu.setup.serve", cat="setup") as sp:
+            from deepspeed_tpu.inference.serving.engine import ServingEngine
+            srv = ServingEngine(self, monitor=monitor,
+                                draft_module=draft_module,
+                                draft_params=draft_params, **overrides)
+            sp.set(num_slots=srv.num_slots, num_pages=srv.num_pages)
+        return srv
 
     def _run_guarded(self, fn, args):
         """Compile-and-check-then-execute: the generation program is
@@ -656,20 +673,21 @@ class InferenceEngine:
         from deepspeed_tpu.runtime.fault import inject as fault_inject
         fault_inject.fire("infer.executable_load")
         tag = self._tags.get(id(fn))
+        kind = tag[0] if tag else "untagged"
+        program = self._programs.get(id(fn), kind)
         if id(fn) in self._persist_opt_out:
             # fresh compile with BOTH persistent layers detached (see
             # _persist_opt_out above) — once per process per signature
             with compile_cache_mod.suspended_persistent_cache():
                 compiled, dt, hit = compile_cache_mod.aot_compile_with_store(
-                    None, f"infer:{tag[0] if tag else 'untagged'}",
-                    (), fn, args)
+                    None, f"infer:{kind}", (), fn, args, program=program)
         else:
             compiled, dt, hit = compile_cache_mod.aot_compile_with_store(
                 self._program_cache if tag is not None else None,
-                f"infer:{tag[0] if tag else 'untagged'}",
+                f"infer:{kind}",
                 (tag, compile_cache_mod.abstract_signature(args),
                  self._cache_context()),
-                fn, args)
+                fn, args, program=program)
         if compiled is None:
             return None, 0.0, False
         # guard BEFORE caching: under strict_memory every retry with
@@ -729,17 +747,20 @@ class InferenceEngine:
         assert self._params is not None, \
             "no parameters: set_params/init_params first"
         report = {}
-        for B in batch_sizes:
-            report.update(self._warmup_one(
-                int(B), int(prompt_len), int(max_new_tokens),
-                bool(do_sample), float(temperature), int(top_k),
-                float(top_p), bool(with_mask)))
+        with span("dstpu.setup.warmup", cat="setup") as sp:
+            for B in batch_sizes:
+                report.update(self._warmup_one(
+                    int(B), int(prompt_len), int(max_new_tokens),
+                    bool(do_sample), float(temperature), int(top_k),
+                    float(top_p), bool(with_mask)))
+            sp.set(programs=len(report))
         for name, dt in report.items():
             log_dist(f"warmup[{name}]: "
                      + ("cached" if dt == 0.0 else f"{dt:.1f}s"), ranks=[0])
         if monitor is not None and getattr(monitor, "enabled", True):
             monitor.write_events([(f"Compile/{name}_secs", dt, 0)
                                   for name, dt in report.items()])
+        log_dist(ready_line("inference"), ranks=[0])
         return report
 
     precompile = warmup

@@ -564,11 +564,19 @@ class ServingEngine:
                                        engine._deq, self.self_draft)
         engine._tags[id(self._chunk_fn)] = (
             "serving_prefill", self.chunk, self.page, self.chunk_rows)
-        for fn in (self._decode_fn, self._admit_fn, self._chunk_fn,
-                   self._verify_fn, self._spec_fn, self._propose_fn,
-                   self._draft_chunk_fn, self._draft_admit_fn):
+        # ... each under the name its dispatch span carries, which its
+        # compile span (dstpu.setup.compile) carries as ``program``
+        for fn, program in ((self._decode_fn, "decode"),
+                            (self._admit_fn, "admit"),
+                            (self._chunk_fn, "prefill_chunk"),
+                            (self._verify_fn, "spec_verify"),
+                            (self._spec_fn, "spec_block"),
+                            (self._propose_fn, "spec_propose"),
+                            (self._draft_chunk_fn, "draft_prefill_chunk"),
+                            (self._draft_admit_fn, "draft_admit")):
             if fn is not None:
                 engine._persist_opt_out.add(id(fn))
+                engine._programs[id(fn)] = program
 
         if self.separate_draft:
             self._draft_params = draft_params
@@ -623,6 +631,10 @@ class ServingEngine:
                                        cfg.breaker_cooldown_s)
         self._closed = False             # guarded-by: _lock
         self._close_report = []          # undrained rids  # guarded-by: _lock
+        # what a PREEMPTED request's stream ends with: such a request has no
+        # result record, and a stream subscribed after the preemption (a
+        # handler that lost the race under load) replays its end from here
+        self._preempt_detail = ""
         self._snap_seq = 0               # snapshot lineage  # guarded-by: _lock
         self._slot_last_dispatch = {}    # slot -> mono t  # guarded-by: _lock
         if self.speculative:
@@ -1295,7 +1307,7 @@ class ServingEngine:
                 stream.push({"event": "end", "rid": rid,
                              "status": req.status,
                              "detail": res.detail if res is not None
-                             else ""})
+                             else self._preempt_detail})
             else:
                 self._streams.setdefault(rid, []).append(stream)
             return stream
@@ -1859,79 +1871,81 @@ class ServingEngine:
         pin it to single-device input shardings while its runtime inputs
         (chunk-program outputs) carry the mesh's replicated sharding —
         first-use compilation sees the real shardings."""
-        eng = self.engine
-        N, S, C = self.num_slots, self.cache_len, self.chunk
-        dtype = eng.compute_dtype
-        cache = jax.eval_shape(lambda: self._new_pools(dtype))
-        state = {
-            "token": jax.ShapeDtypeStruct((N,), jnp.int32),
-            "pos": jax.ShapeDtypeStruct((N,), jnp.int32),
-            "active": jax.ShapeDtypeStruct((N,), jnp.bool_),
-            "remaining": jax.ShapeDtypeStruct((N,), jnp.int32),
-            "eos": jax.ShapeDtypeStruct((N,), jnp.int32),
-        }
-        if self.self_draft:
-            state["draft"] = jax.ShapeDtypeStruct((N,), jnp.int32)
-        rng = jax.eval_shape(lambda: jax.random.key(0))
-        report = {}
+        with span("dstpu.setup.warmup", cat="setup") as sp:
+            eng = self.engine
+            N, S, C = self.num_slots, self.cache_len, self.chunk
+            dtype = eng.compute_dtype
+            cache = jax.eval_shape(lambda: self._new_pools(dtype))
+            state = {
+                "token": jax.ShapeDtypeStruct((N,), jnp.int32),
+                "pos": jax.ShapeDtypeStruct((N,), jnp.int32),
+                "active": jax.ShapeDtypeStruct((N,), jnp.bool_),
+                "remaining": jax.ShapeDtypeStruct((N,), jnp.int32),
+                "eos": jax.ShapeDtypeStruct((N,), jnp.int32),
+            }
+            if self.self_draft:
+                state["draft"] = jax.ShapeDtypeStruct((N,), jnp.int32)
+            rng = jax.eval_shape(lambda: jax.random.key(0))
+            report = {}
 
-        def warm(fn, args, name):
-            from deepspeed_tpu.runtime import compile_cache as cc
-            sig = (id(fn),) + cc.abstract_signature(args)
-            if sig in eng._aot:
-                return {name: 0.0}
-            compiled, dt, hit = eng._aot_compile(fn, args)
-            if compiled is None:
-                logger.warning(f"serving warmup: {name} failed to "
-                               f"AOT-compile — it compiles on first use")
-                return {}
-            eng._aot[sig] = compiled
-            return {name: 0.0 if hit else dt}
+            def warm(fn, args, name):
+                from deepspeed_tpu.runtime import compile_cache as cc
+                sig = (id(fn),) + cc.abstract_signature(args)
+                if sig in eng._aot:
+                    return {name: 0.0}
+                compiled, dt, hit = eng._aot_compile(fn, args)
+                if compiled is None:
+                    logger.warning(f"serving warmup: {name} failed to "
+                                   f"AOT-compile — it compiles on first use")
+                    return {}
+                eng._aot[sig] = compiled
+                return {name: 0.0 if hit else dt}
 
-        R = self.chunk_rows
-        rows = jax.ShapeDtypeStruct((R, self.table_width), jnp.int32)
-        tables = jax.ShapeDtypeStruct((N, self.table_width), jnp.int32)
-        cargs = (eng._params, cache, rows,
-                 jax.ShapeDtypeStruct((R, C), jnp.int32),
-                 jax.ShapeDtypeStruct((R,) if R > 1 else (), jnp.int32),
-                 jax.ShapeDtypeStruct((R,), jnp.int32)) \
-            + ((jax.ShapeDtypeStruct((R,), jnp.int32),)
-               if self.self_draft else ())
-        report.update(warm(self._chunk_fn, cargs,
-                           f"serving_prefill:c{C}p{self.page}"
-                           + (f"r{R}" if R > 1 else "")))
-        if self.self_draft:
-            report.update(warm(
-                self._spec_fn,
-                (eng._params, cache, state, tables, rng),
-                f"serving_spec_block:n{N}s{S}b{self.block}p{self.page}"))
-        elif self.speculative:
-            draft = jax.ShapeDtypeStruct((N, self.spec_k), jnp.int32)
-            report.update(warm(
-                self._verify_fn,
-                (eng._params, cache, state, tables, draft, rng),
-                f"serving_spec_verify:n{N}s{S}k{self.spec_k}"
-                f"p{self.page}"))
-        else:
-            report.update(warm(
-                self._decode_fn,
-                (eng._params, cache, state, tables, rng),
-                f"serving_decode:n{N}s{S}b{self.block}p{self.page}"))
-        if self.separate_draft:
-            dcache = jax.eval_shape(
-                lambda: self.draft_module.init_cache(N, S, dtype=dtype))
-            dlane = jax.eval_shape(
-                lambda: self.draft_module.init_cache(1, S, dtype=dtype))
-            report.update(warm(
-                self._propose_fn, (self._draft_params, dcache, state),
-                f"serving_spec_propose:n{N}s{S}k{self.spec_k}"))
-            report.update(warm(
-                self._draft_chunk_fn,
-                (self._draft_params, dlane,
-                 jax.ShapeDtypeStruct((1, C), jnp.int32),
-                 jax.ShapeDtypeStruct((), jnp.int32),
-                 jax.ShapeDtypeStruct((1,), jnp.int32)),
-                f"serving_spec_draft_prefill:c{C}"))
+            R = self.chunk_rows
+            rows = jax.ShapeDtypeStruct((R, self.table_width), jnp.int32)
+            tables = jax.ShapeDtypeStruct((N, self.table_width), jnp.int32)
+            cargs = (eng._params, cache, rows,
+                     jax.ShapeDtypeStruct((R, C), jnp.int32),
+                     jax.ShapeDtypeStruct((R,) if R > 1 else (), jnp.int32),
+                     jax.ShapeDtypeStruct((R,), jnp.int32)) \
+                + ((jax.ShapeDtypeStruct((R,), jnp.int32),)
+                   if self.self_draft else ())
+            report.update(warm(self._chunk_fn, cargs,
+                               f"serving_prefill:c{C}p{self.page}"
+                               + (f"r{R}" if R > 1 else "")))
+            if self.self_draft:
+                report.update(warm(
+                    self._spec_fn,
+                    (eng._params, cache, state, tables, rng),
+                    f"serving_spec_block:n{N}s{S}b{self.block}p{self.page}"))
+            elif self.speculative:
+                draft = jax.ShapeDtypeStruct((N, self.spec_k), jnp.int32)
+                report.update(warm(
+                    self._verify_fn,
+                    (eng._params, cache, state, tables, draft, rng),
+                    f"serving_spec_verify:n{N}s{S}k{self.spec_k}"
+                    f"p{self.page}"))
+            else:
+                report.update(warm(
+                    self._decode_fn,
+                    (eng._params, cache, state, tables, rng),
+                    f"serving_decode:n{N}s{S}b{self.block}p{self.page}"))
+            if self.separate_draft:
+                dcache = jax.eval_shape(
+                    lambda: self.draft_module.init_cache(N, S, dtype=dtype))
+                dlane = jax.eval_shape(
+                    lambda: self.draft_module.init_cache(1, S, dtype=dtype))
+                report.update(warm(
+                    self._propose_fn, (self._draft_params, dcache, state),
+                    f"serving_spec_propose:n{N}s{S}k{self.spec_k}"))
+                report.update(warm(
+                    self._draft_chunk_fn,
+                    (self._draft_params, dlane,
+                     jax.ShapeDtypeStruct((1, C), jnp.int32),
+                     jax.ShapeDtypeStruct((), jnp.int32),
+                     jax.ShapeDtypeStruct((1,), jnp.int32)),
+                    f"serving_spec_draft_prefill:c{C}"))
+            sp.set(programs=len(report))
         for name, dt in report.items():
             log_dist(f"serving warmup[{name}]: "
                      + ("cached" if dt == 0.0 else f"{dt:.1f}s"), ranks=[0])
@@ -1939,6 +1953,7 @@ class ServingEngine:
         if mon is not None and getattr(mon, "enabled", True):
             mon.write_events([(f"Compile/{name}_secs", dt, 0)
                               for name, dt in report.items()])
+        log_dist(span_trace.ready_line("serving"), ranks=[0])
         return report
 
     # ------------------------------------------------------------------ #
@@ -2798,6 +2813,8 @@ class ServingEngine:
         self._shed_expired()                 # don't snapshot expired work
         undrained = self._undrained_requests()
         tag = self.snapshot(checkpoint_dir, tag=tag)
+        self._preempt_detail = (f"preempted — snapshotted for resume "
+                                f"(tag {tag!r})")
         for req in undrained:
             req.status = RequestStatus.PREEMPTED
             # active HTTP/token streams end with the TYPED event — the
@@ -2805,8 +2822,7 @@ class ServingEngine:
             # (reconnect and re-subscribe) instead of seeing a dead
             # socket with no verdict
             self._publish_end(req, RequestStatus.PREEMPTED,
-                              f"preempted — snapshotted for resume "
-                              f"(tag {tag!r})")
+                              self._preempt_detail)
         snapped = [r.rid for r in undrained]
         # retire the engine without ABORTED accounting: the snapshotted
         # requests are not lost, they resume elsewhere
@@ -3027,7 +3043,11 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
     def _ensure_workspace(self):  # lock-held: _lock
         if self._cache is None:
-            self._cache = self._pages.take(self.engine.compute_dtype)
+            # the pools' allocation: once a server (again only after a
+            # failed dispatch left the donated buffers dead)
+            with span("dstpu.setup.pools", cat="setup") as sp:
+                self._cache = self._pages.take(self.engine.compute_dtype)
+                sp.set(**self._pages.pool_bytes(self._cache))
         if self.separate_draft and self._draft_cache is None:
             self._draft_cache = self._draft_ws.take(
                 self.num_slots, self.cache_len, self.engine.compute_dtype)

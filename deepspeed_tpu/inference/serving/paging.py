@@ -396,6 +396,20 @@ class SlotPages:
                 // self.num_pages
         return pools
 
+    def pool_bytes(self, pools):
+        """``pools``' size on the device: ``{"bytes": all of it}`` and, of
+        a model whose pools are of several row kinds, ``bytes_pages`` /
+        ``bytes_ring`` / ``bytes_state`` beside it (what
+        ``dstpu.setup.pools`` carries)."""
+        nbytes = lambda keys: sum(int(pools[k].nbytes) for k in keys)
+        out = {"bytes": nbytes(pools)}
+        if self.state_kinds or self.ring_kinds:
+            out["bytes_ring"] = nbytes(self.ring_kinds) or None
+            out["bytes_state"] = nbytes(self.state_kinds) or None
+            out["bytes_pages"] = out["bytes"] - nbytes(
+                self.ring_kinds + self.state_kinds)
+        return out
+
     def give_back(self, pool):
         self._buffer = pool
 

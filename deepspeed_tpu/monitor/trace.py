@@ -14,7 +14,10 @@ zero-new-executables proof extends over them):
   (:func:`enable`; ``serving.tracing`` turns it on), also appends the
   span to the :class:`SpanTracer` ring.  Names are the fixed literal
   set ``dstpu.<layer>.<what>`` tabled in ``docs/observability.md`` —
-  never an id or a size in a name; those are args.
+  never an id or a size in a name; those are args.  Spans of category
+  ``"setup"`` (the entry points' ``dstpu.setup.*``: a few dozen a
+  process, over before anyone could turn the ring on) are ALWAYS kept,
+  in a small list of this module's own that :func:`setup_spans` reads.
 * :class:`SpanTracer` — a bounded ring of finished spans recorded at the
   serving scheduler's existing seams (submit → queue wait → prefill
   chunks → admit dispatch → decode / spec-propose / spec-verify
@@ -301,6 +304,14 @@ class SpanTracer:
 # ---------------------------------------------------------------------- #
 _TRACER = None                           # None = the ring is off
 
+# Finished spans of category "setup", kept whether or not the ring is on:
+# an engine's start-up is ~20 of them, so a few hundred hold every engine
+# a process builds (the oldest fall off)
+SETUP_SPANS_KEPT = 512
+_SETUP = deque(maxlen=SETUP_SPANS_KEPT)
+_SETUP_LOCK = threading.Lock()
+_SETUP_OPEN = threading.local()          # .names: this thread's open ones
+
 
 def enable(max_spans=DEFAULT_MAX_SPANS, **clocks):
     """Turn the span ring on: install a FRESH :class:`SpanTracer` as the
@@ -330,6 +341,60 @@ def now():
     return tr.now() if tr is not None else time.monotonic()
 
 
+def setup_spans():
+    """The finished ``cat="setup"`` spans of this process, oldest first,
+    as ``[(name, t0, t1, track, args)]`` — a copy.  They are kept with
+    the ring off (``time.monotonic`` stamps then), the newest
+    :data:`SETUP_SPANS_KEPT` of them."""
+    with _SETUP_LOCK:
+        return list(_SETUP)
+
+
+def ready_line(what):
+    """The one line an engine logs when a warm-up ends (``ready[<what>]:
+    ...``; ``docs/observability.md`` "Start-up"): of the set-up spans since
+    the newest ``dstpu.setup.engine`` opened (and the package's import),
+    each phase's OWN seconds — its spans' time less the spans nested in
+    them — and each program's compile seconds with where the executable
+    came from: ``store`` (the executable store), ``cache`` (every request
+    to the persistent cache hit) or ``miss`` (compiled)."""
+    spans = setup_spans()
+    built = [t0 for name, t0, *_ in spans if name == "dstpu.setup.engine"]
+    since = built[-1] if built else float("-inf")
+    spans = sorted((s for s in spans
+                    if s[1] >= since or s[0] == "dstpu.setup.import"),
+                   key=lambda s: (s[1], -s[2]))
+    own, programs, open_ = {}, {}, {}    # open_: track -> [(t1, name)]
+    for name, t0, t1, track, args in spans:
+        stack = open_.setdefault(track, [])
+        while stack and stack[-1][0] <= t0:
+            stack.pop()
+        if stack:
+            own[stack[-1][1]] -= t1 - t0
+        own[name] = own.get(name, 0.0) + t1 - t0
+        stack.append((t1, name))
+        if name == "dstpu.setup.compile":
+            asked, hits = args["persistent_requests"], args["persistent_hits"]
+            how = "store" if args["store_hit"] else \
+                "cache" if asked and hits == asked else "miss"
+            seconds, hows = programs.get(args["program"], (0.0, ()))
+            programs[args["program"]] = (
+                seconds + t1 - t0, hows if how in hows else hows + (how,))
+    phases = ", ".join(f"{name.rpartition('.')[2]} {seconds:.1f}s"
+                       for name, seconds in own.items()
+                       if name != "dstpu.setup.compile")
+    compiles = ", ".join(f"{p} {seconds:.1f}s ({'/'.join(hows)})"
+                         for p, (seconds, hows) in programs.items())
+    return f"ready[{what}]: {phases}; compiled: {compiles or 'nothing'}"
+
+
+def setup_open(name):
+    """Whether the calling thread is inside a ``cat="setup"`` span called
+    ``name`` (a compile asks whether its engine's warm-up is what runs
+    it)."""
+    return name in getattr(_SETUP_OPEN, "names", ())
+
+
 class span:
     """``with span("dstpu.sched.step", it=3):`` — one host span, on the
     profiler's clock always and in the ring when it is on (module
@@ -342,7 +407,9 @@ class span:
     a histogram or the flight recorder.  ``start`` (a :func:`now`
     stamp made earlier, e.g. on another thread at a hand-off) moves
     ``t0`` — and so the ring span's start — back to that stamp; the
-    annotation still covers only the ``with`` block."""
+    annotation still covers only the ``with`` block.  ``cat="setup"``
+    keeps the finished span for :func:`setup_spans` too, ring or no ring:
+    never on a path that runs per iteration, step, layer or request."""
 
     __slots__ = ("name", "track", "cat", "args", "t0", "t1", "_ann", "_tr")
 
@@ -357,6 +424,10 @@ class span:
         tr = self._tr = _TRACER          # one tracer, one clock, per span
         if self.t0 is None:
             self.t0 = tr.now() if tr is not None else time.monotonic()
+        if self.cat == "setup":
+            if not hasattr(_SETUP_OPEN, "names"):
+                _SETUP_OPEN.names = []
+            _SETUP_OPEN.names.append(self.name)
         return self
 
     def set(self, **args):
@@ -372,14 +443,27 @@ class span:
         tr = self._tr
         self.t1 = tr.now() if tr is not None else time.monotonic()
         self._ann.__exit__(*exc)
+        if self.cat == "setup":
+            _SETUP_OPEN.names.pop()
+            # a serving engine turns the ring on inside its own set-up
+            # span: what opened before lands in the ring it finds at exit
+            tr = tr if tr is not None else _TRACER
+        elif tr is None:
+            return False
+        track = self.track if self.track is not None \
+            else threading.current_thread().name
+        if self.cat == "setup":
+            with _SETUP_LOCK:
+                _SETUP.append((self.name, self.t0, self.t1, track,
+                               dict(self.args)))
         if tr is not None:
-            tr.add(self.name, self.cat, self.t0, self.t1,
-                   track=self.track if self.track is not None
-                   else threading.current_thread().name, **self.args)
+            tr.add(self.name, self.cat, self.t0, self.t1, track=track,
+                   **self.args)
         return False
 
 
 __all__ = ["SpanTracer", "span", "now", "tracer", "enable", "disable",
+           "setup_spans", "setup_open", "ready_line", "SETUP_SPANS_KEPT",
            "Histogram", "HistogramFamily",
            "ServingHistograms", "LATENCY_BUCKETS_S",
            "LOCK_WAIT_BUCKETS_S", "HISTOGRAM_SERIES",

@@ -10,6 +10,7 @@ import os
 import pickle
 from abc import ABC, abstractmethod
 
+from deepspeed_tpu.monitor.trace import span
 from deepspeed_tpu.runtime.fault import inject
 from deepspeed_tpu.runtime.fault.atomic import atomic_write_bytes
 from deepspeed_tpu.utils.logging import logger
@@ -52,7 +53,11 @@ class OrbaxCheckpointEngine(CheckpointEngine):
 
     def __init__(self, config_params=None, use_async=False):
         super().__init__(config_params)
-        import orbax.checkpoint as ocp
+        # seconds on a process's first engine: orbax pulls in the cloud
+        # logging stack (a named part of the engine's set-up)
+        with span("dstpu.setup.lazy_import", cat="setup",
+                  module="orbax.checkpoint"):
+            import orbax.checkpoint as ocp
         self._ocp = ocp
         self.use_async = use_async
         self._ckptr = None

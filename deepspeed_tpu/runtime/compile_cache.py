@@ -41,6 +41,7 @@ import pickle
 import time
 from typing import Any, Dict, Optional
 
+from deepspeed_tpu.monitor.trace import setup_open, span
 from deepspeed_tpu.runtime.config_utils import DeepSpeedConfigModel
 from deepspeed_tpu.utils.logging import logger, log_dist, warning_once
 
@@ -253,6 +254,9 @@ def _reset_jax_cache_state():
     jcc.reset_cache()
 
 
+_suspended = 0                           # open suspensions (opt-out compiles)
+
+
 @contextlib.contextmanager
 def suspended_persistent_cache():
     """Temporarily detach the process from the XLA persistent cache for
@@ -265,20 +269,25 @@ def suspended_persistent_cache():
     and whole-batch generate paths show no such failures and keep both
     layers).  Compiles are synchronous on the calling thread, so the
     process-global config flip is safe."""
+    global _suspended
     import jax
     prev = jax.config.jax_compilation_cache_dir
-    if prev is None:
-        yield
-        return
+    _suspended += 1
     try:
+        if prev is None:
+            yield
+            return
         jax.config.update("jax_compilation_cache_dir", None)
         _reset_jax_cache_state()
-        yield
+        try:
+            yield
+        finally:
+            jax.config.update("jax_compilation_cache_dir", prev)
+            # re-attach lazily: the next ordinary compile re-initializes
+            # from the restored config
+            _reset_jax_cache_state()
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        # re-attach lazily: the next ordinary compile re-initializes
-        # from the restored config
-        _reset_jax_cache_state()
+        _suspended -= 1
 
 
 def deconfigure_persistent_cache():
@@ -480,39 +489,75 @@ class ProgramCache:
         except OSError as e:
             logger.debug(f"compile cache note {tag} not saved: {e}")
 
-    def get_or_compile(self, tag, key_parts, compile_fn):
+    def get_or_compile(self, tag, key_parts, compile_fn, program=None):
         """Returns ``(compiled, seconds, hit)``.  ``compile_fn`` runs only
-        on a store miss; its wall time is recorded under ``tag`` in
+        on a store miss; the seconds — the whole look-up, compile and save,
+        :func:`compile_span`'s one timing — are recorded under ``tag`` in
         :func:`stats` and the fresh executable is persisted."""
         key = cache_key(tag, *key_parts)
-        if self.store is not None:
-            exe = self.store.load(key)
-            if exe is not None:
-                log_dist(f"compile cache hit: {tag}", ranks=[0])
-                return exe, 0.0, True
-        t0 = time.perf_counter()
-        exe = compile_fn()
-        dt = time.perf_counter() - t0
-        _STATS.compile_seconds[str(tag)] = dt
-        if self.store is not None:
-            self.store.save(key, exe)
-        log_dist(f"compiled {tag} in {dt:.1f}s", ranks=[0])
-        return exe, dt, False
+        with compile_span(tag, program) as sp:
+            exe = self.store.load(key) if self.store is not None else None
+            hit = exe is not None
+            if not hit:
+                exe = compile_fn()
+                if self.store is not None:
+                    self.store.save(key, exe)
+            sp.set(store_hit=int(hit))
+        if hit:
+            log_dist(f"compile cache hit: {tag}", ranks=[0])
+            return exe, 0.0, True
+        _STATS.compile_seconds[str(tag)] = sp.dur_s
+        log_dist(f"compiled {tag} in {sp.dur_s:.1f}s", ranks=[0])
+        return exe, sp.dur_s, False
 
 
-def aot_compile_with_store(program_cache, tag, key_parts, fn, args):
+@contextlib.contextmanager
+def compile_span(tag, program=None):
+    """ONE ``dstpu.setup.compile`` span (``docs/observability.md``
+    "Start-up") around a program's compile, whoever asks for it — a
+    warm-up or a first use (``after_warmup`` 1: no ``dstpu.setup.warmup``
+    span is open on this thread).  ``program`` is the short kind the
+    dispatch spans use (``prefill_chunk``, ``decode``, ``admit``,
+    ``train_step``; default: the tag).  At its close the span carries what
+    the process's counters moved by inside it: the persistent cache's
+    requests and hits, and JAX's three compile phases (``trace_s`` /
+    ``lower_s`` / ``backend_s``; all 0 where a stored executable was
+    loaded).  Its ``dur_s`` is THE compile time every report takes."""
+    _register_jax_listener()
+    st = _STATS
+    counters = lambda: {
+        "persistent_requests": st.persistent_requests,
+        "persistent_hits": st.persistent_hits,
+        "trace_s": st.trace_seconds, "lower_s": st.lower_seconds,
+        "backend_s": st.backend_compile_seconds}
+    before = counters()
+    with span("dstpu.setup.compile", cat="setup",
+              program=str(program or tag), tag=str(tag), store_hit=0,
+              opt_out=int(_suspended > 0),
+              after_warmup=int(not setup_open("dstpu.setup.warmup"))) as sp:
+        try:
+            yield sp
+        finally:
+            sp.set(**{k: v - before[k] for k, v in counters().items()})
+
+
+def aot_compile_with_store(program_cache, tag, key_parts, fn, args,
+                           program=None):
     """Lower+compile ``fn`` for ``args`` through ``program_cache``'s
     executable store (or inline when it is None) — the one copy of the
-    AOT-with-jit-fallback block all three engines share.  Returns
+    AOT-with-jit-fallback block all three engines share, under ONE
+    :func:`compile_span` named by ``program``.  Returns
     ``(exe, seconds, hit)``; exe is None on any failure (warned — the
     caller runs the plain jit call, which recompiles on its own clock, so
     a failure must never masquerade as a 0.0s compile or a store hit)."""
-    t0 = time.perf_counter()
     try:
         if program_cache is not None:
             return program_cache.get_or_compile(
-                tag, key_parts, lambda: fn.lower(*args).compile())
-        return fn.lower(*args).compile(), time.perf_counter() - t0, False
+                tag, key_parts, lambda: fn.lower(*args).compile(),
+                program=program)
+        with compile_span(tag, program) as sp:
+            exe = fn.lower(*args).compile()
+        return exe, sp.dur_s, False
     except Exception as e:
         _STATS.aot_fallbacks += 1
         logger.warning(f"AOT compile of {tag} failed ({e}); falling back "

@@ -33,6 +33,7 @@ monkey-patching.
 
 import os
 import inspect
+from contextlib import contextmanager
 from typing import Any, Optional
 
 import numpy as np
@@ -44,7 +45,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from deepspeed_tpu import comm as dist
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.monitor.monitor import MonitorMaster
-from deepspeed_tpu.monitor.trace import span
+from deepspeed_tpu.monitor.trace import ready_line, span
 from deepspeed_tpu.parallel import topology as topo_mod
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.checkpoint_engine.checkpoint_engine import OrbaxCheckpointEngine
@@ -402,18 +403,30 @@ class DeepSpeedEngine:
         """Place user-provided params: cast to fp32 master, shard per plan.
         ``materialize_opt=False`` computes optimizer shardings only (the
         caller will install loaded state) — no fresh m/v allocation."""
-        abstract = jax.eval_shape(lambda t: jax.tree.map(
-            lambda p: p.astype(self._master_dtype)
-            if jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating) else jnp.asarray(p),
-            t), params)
-        self._build_plan(abstract)
-        put = jax.jit(
-            lambda t: jax.tree.map(
+        with self._weights_span():
+            abstract = jax.eval_shape(lambda t: jax.tree.map(
                 lambda p: p.astype(self._master_dtype)
-                if jnp.issubdtype(p.dtype, jnp.floating) else p, t),
-            out_shardings=self._plan.param_shardings)
-        self._params = put(params)
+                if jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating) else jnp.asarray(p),
+                t), params)
+            self._build_plan(abstract)
+            put = jax.jit(
+                lambda t: jax.tree.map(
+                    lambda p: p.astype(self._master_dtype)
+                    if jnp.issubdtype(p.dtype, jnp.floating) else p, t),
+                out_shardings=self._plan.param_shardings)
+            self._params = put(params)
         self._init_opt_state(materialize=materialize_opt)
+
+    @contextmanager
+    def _weights_span(self):
+        """``dstpu.setup.weights`` around the plan and the parameters'
+        sharded init or placement; sized when they are there."""
+        with span("dstpu.setup.weights", cat="setup") as sp:
+            yield
+            leaves = jax.tree.leaves(self._params)
+            split = any(not l.sharding.is_fully_replicated for l in leaves)
+            sp.set(bytes=sum(l.nbytes for l in leaves), leaves=len(leaves),
+                   sharded=1 if split else 0)
 
     def _build_plan(self, abstract_params):
         self._plan = build_sharding_plan(abstract_params, self.topology,
@@ -451,8 +464,12 @@ class DeepSpeedEngine:
         if not materialize:        # caller installs loaded state itself
             self._abstract_opt = abstract_opt
             return
-        init_jit = jax.jit(self.optimizer.init, out_shardings=self._opt_shardings)
-        self._opt_state = init_jit(self._params)
+        with span("dstpu.setup.optimizer_state", cat="setup") as sp:
+            init_jit = jax.jit(self.optimizer.init,
+                               out_shardings=self._opt_shardings)
+            self._opt_state = init_jit(self._params)
+            sp.set(bytes=sum(l.nbytes
+                             for l in jax.tree.leaves(self._opt_state)))
 
     def _lazy_init(self, args, kwargs):
         """First-forward param init, jitted with sharded out_shardings so
@@ -462,21 +479,22 @@ class DeepSpeedEngine:
             return
         if self._init_fn is None:
             raise RuntimeError("no parameters: pass model_parameters or use a flax module")
-        self._rng, init_rng = jax.random.split(self._rng)
-        abstract = jax.eval_shape(lambda r: self._init_fn(r, *args, **kwargs), init_rng)
-        abstract = jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(
-                s.shape, self._master_dtype
-                if jnp.issubdtype(s.dtype, jnp.floating) else s.dtype),
-            abstract)
-        self._build_plan(abstract)
-        init_jit = jax.jit(
-            lambda r, a, kw: jax.tree.map(
-                lambda p: p.astype(self._master_dtype)
-                if jnp.issubdtype(p.dtype, jnp.floating) else p,
-                self._init_fn(r, *a, **kw)),
-            out_shardings=self._plan.param_shardings)
-        self._params = init_jit(init_rng, args, kwargs)
+        with self._weights_span():
+            self._rng, init_rng = jax.random.split(self._rng)
+            abstract = jax.eval_shape(lambda r: self._init_fn(r, *args, **kwargs), init_rng)
+            abstract = jax.tree.map(
+                lambda s: jax.ShapeDtypeStruct(
+                    s.shape, self._master_dtype
+                    if jnp.issubdtype(s.dtype, jnp.floating) else s.dtype),
+                abstract)
+            self._build_plan(abstract)
+            init_jit = jax.jit(
+                lambda r, a, kw: jax.tree.map(
+                    lambda p: p.astype(self._master_dtype)
+                    if jnp.issubdtype(p.dtype, jnp.floating) else p,
+                    self._init_fn(r, *a, **kw)),
+                out_shardings=self._plan.param_shardings)
+            self._params = init_jit(init_rng, args, kwargs)
         n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(self._params))
         log_dist(f"initialized {n_params/1e6:.2f}M parameters (sharded at birth)", ranks=[0])
         self._init_opt_state()
@@ -1320,37 +1338,45 @@ class DeepSpeedEngine:
         gas = self.gradient_accumulation_steps()
         n_groups = int(getattr(self._config.zero_config,
                                "grad_partition_groups", 1) or 1)
-        if self._offload_cfg is not None or n_groups > 1:
-            # before touching data_iter: an engine this cannot warm must
-            # not eat a global batch of real training data on the way out
-            logger.warning("warmup(): offload/grouped engines run the "
-                           "3-call path — no fused step to precompile")
-            return {}
-        if batch is None:
-            mbs = [next(data_iter) for _ in range(gas)]
-            batch = jax.tree.map(lambda *xs: jnp.stack(xs), *mbs)
-        self._lazy_init((jax.tree.map(lambda x: x[0], batch),), {})
-        # same curriculum slice train_batch applies — without it the
-        # warmed signature would never match the sliced batch's and the
-        # first real step would recompile anyway
-        batch = self._curriculum_slice(batch, 2)
-        batch = jax.tree.map(
-            lambda x: jax.device_put(
-                jnp.asarray(x),
-                NamedSharding(self.mesh,
-                              P(None, *(self._data_sharding(x.ndim - 1)
-                                        .spec)))),
-            batch)
-        lr = jnp.asarray(self.get_lr()[0], jnp.float32)
-        step_no = jnp.asarray(self.global_steps + 1, jnp.int32)
-        args = (self._params, self._opt_state, self._scaler_state,
-                lr, step_no, self._rng, batch)
-        from deepspeed_tpu.runtime import compile_cache as cc
-        sig = cc.abstract_signature(args)
-        if sig in self._train_aot:
-            return {"train_step": 0.0}
-        _, dt, hit = self._train_exe_for(self._get_fused_step(), args, sig)
-        return {"train_step": 0.0 if hit else dt}
+        fused = self._offload_cfg is None and n_groups <= 1
+        with span("dstpu.setup.warmup", cat="setup", programs=int(fused)):
+            if not fused:
+                # before touching data_iter: an engine this cannot warm
+                # must not eat a global batch of real training data on the
+                # way out
+                logger.warning("warmup(): offload/grouped engines run the "
+                               "3-call path — no fused step to precompile")
+                report = {}
+            else:
+                if batch is None:
+                    mbs = [next(data_iter) for _ in range(gas)]
+                    batch = jax.tree.map(lambda *xs: jnp.stack(xs), *mbs)
+                self._lazy_init((jax.tree.map(lambda x: x[0], batch),), {})
+                # same curriculum slice train_batch applies — without it
+                # the warmed signature would never match the sliced batch's
+                # and the first real step would recompile anyway
+                batch = self._curriculum_slice(batch, 2)
+                batch = jax.tree.map(
+                    lambda x: jax.device_put(
+                        jnp.asarray(x),
+                        NamedSharding(self.mesh,
+                                      P(None, *(self._data_sharding(x.ndim - 1)
+                                                .spec)))),
+                    batch)
+                lr = jnp.asarray(self.get_lr()[0], jnp.float32)
+                step_no = jnp.asarray(self.global_steps + 1, jnp.int32)
+                args = (self._params, self._opt_state, self._scaler_state,
+                        lr, step_no, self._rng, batch)
+                from deepspeed_tpu.runtime import compile_cache as cc
+                sig = cc.abstract_signature(args)
+                if sig in self._train_aot:
+                    report = {"train_step": 0.0}
+                else:
+                    _, dt, hit = self._train_exe_for(
+                        self._get_fused_step(), args, sig)
+                    report = {"train_step": 0.0 if hit else dt}
+        log_dist(ready_line("training"), ranks=[0])
+        return report
 
     precompile = warmup
 

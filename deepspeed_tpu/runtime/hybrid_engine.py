@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from deepspeed_tpu.monitor.trace import ready_line, span
 from deepspeed_tpu.runtime.engine import DeepSpeedEngine
 from deepspeed_tpu.tools.lint.hotpath import hot_path
 from deepspeed_tpu.utils.logging import log_dist, logger
@@ -397,25 +398,28 @@ class DeepSpeedHybridEngine(DeepSpeedEngine):
                self._rollout_early_exit)
         fn = self._get_rollout_fn(key)
         report = {}
-        for B in batch_sizes:
-            B = int(B)
-            cache = jax.eval_shape(
-                lambda: self.module.init_cache(
-                    B, required_cache_len(P, new, None),
-                    dtype=self.compute_dtype))
-            args = (params, cache,
-                    jax.ShapeDtypeStruct((B, P), jnp.int32),
-                    jax.eval_shape(lambda: jax.random.key(0)),
-                    jnp.asarray(-1))
-            if with_mask:
-                args += (jax.ShapeDtypeStruct((B, P), jnp.int32),)
-            sig = (id(fn),) + cc.abstract_signature(args)
-            name = f"rollout:b{B}p{P}n{new}"
-            if sig in self._gen_aot:
-                report[name] = 0.0
-                continue
-            _, dt, hit = self._rollout_aot_compile(fn, args, key, sig)
-            report[name] = 0.0 if hit else dt
+        with span("dstpu.setup.warmup", cat="setup") as sp:
+            for B in batch_sizes:
+                B = int(B)
+                cache = jax.eval_shape(
+                    lambda: self.module.init_cache(
+                        B, required_cache_len(P, new, None),
+                        dtype=self.compute_dtype))
+                args = (params, cache,
+                        jax.ShapeDtypeStruct((B, P), jnp.int32),
+                        jax.eval_shape(lambda: jax.random.key(0)),
+                        jnp.asarray(-1))
+                if with_mask:
+                    args += (jax.ShapeDtypeStruct((B, P), jnp.int32),)
+                sig = (id(fn),) + cc.abstract_signature(args)
+                name = f"rollout:b{B}p{P}n{new}"
+                if sig in self._gen_aot:
+                    report[name] = 0.0
+                    continue
+                _, dt, hit = self._rollout_aot_compile(fn, args, key, sig)
+                report[name] = 0.0 if hit else dt
+            sp.set(programs=len(report))
+        log_dist(ready_line("rollout"), ranks=[0])
         return report
 
 

@@ -304,13 +304,14 @@ class PipelineEngine(DeepSpeedEngine):
                     f"checkpoint param shapes do not match the pipeline "
                     f"module: {mismatch[:3]}")
             return
-        self._plan = self._build_pipe_plan(abstract)
-        self._abstract_params = abstract
-        put = jax.jit(lambda t: jax.tree.map(
-            lambda p: p.astype(jnp.float32)
-            if jnp.issubdtype(p.dtype, jnp.floating) else p, t),
-            out_shardings=self._plan.param_shardings)
-        self._params = put(raw)
+        with self._weights_span():
+            self._plan = self._build_pipe_plan(abstract)
+            self._abstract_params = abstract
+            put = jax.jit(lambda t: jax.tree.map(
+                lambda p: p.astype(jnp.float32)
+                if jnp.issubdtype(p.dtype, jnp.floating) else p, t),
+                out_shardings=self._plan.param_shardings)
+            self._params = put(raw)
         n = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(self._params))
         log_dist(f"pipeline params initialized: {n/1e6:.2f}M "
                  f"across {self.topology.pp} stages", ranks=[0])

@@ -52,7 +52,7 @@ class FakeStore:
     def __init__(self, exe):
         self.exe = exe
 
-    def get_or_compile(self, tag, key_parts, compile_fn):
+    def get_or_compile(self, tag, key_parts, compile_fn, program=None):
         return self.exe, 0.0, True
 
 

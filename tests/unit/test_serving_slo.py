@@ -405,6 +405,14 @@ def test_preempt_snapshot_resume_bitwise(served_engine, tmp_path, pool):
     assert {r["rid"] for r in state["requests"]} == set(snapped)
     with pytest.raises(RuntimeError, match="closed"):
         srv.submit(prompts[0], max_new_tokens=2)
+    # a stream subscribed AFTER the preemption (an HTTP handler that lost
+    # the race under load) still ends with the typed event and its reason
+    late, end = srv.token_events(snapped[0]), None
+    while end is None:
+        ev = late.get(timeout=5)
+        end = ev if ev["event"] == "end" else None
+    assert end["status"] == RequestStatus.PREEMPTED \
+        and "resume" in end["detail"] and tag in end["detail"], end
 
     srv2 = eng.serve(**pool)
     restored = srv2.restore(str(tmp_path))

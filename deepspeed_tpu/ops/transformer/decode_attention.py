@@ -43,7 +43,11 @@ in-kernel loop over the row's live pages.  Chunked prefill is split the
 same way (``_init_chunk`` / ``_chunk_block_update`` / ``_finish_chunk``):
 ``_chunk_prefill_kernel`` walks a contiguous cache on a ``(B, nk)`` grid,
 ``paged_attention._paged_chunk_kernel`` folds the chunk's reachable pages
-in blocks of ~512 keys inside one grid step.
+in blocks of ~512 keys inside one grid step, and the sliding-window and EVA
+chunk kernels (``paged_attention._window_chunk_kernel``,
+``eva_attention._eva_chunk_kernel``) their rings.  The chunk fold keeps its
+running max and sum LANE-REPLICATED — ``[C, STAT_LANES]`` float32 tiles a
+head — and selects a masked score once (``_ChunkState``, ``STAT_FLOOR``).
 """
 
 import functools
@@ -362,30 +366,48 @@ def _decode_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
 _CHUNK_GROUP_UNROLL = 2
 
 
+# Lanes of the chunk fold's running max and sum: one whole lane tile, every
+# lane its row's value.  The fold's own width — ``flash_attention.LSE_LANES``
+# (8) stays the flash kernels' and the decode kernels' ``[H, LSE_LANES]``.
+STAT_LANES = 128
+
+# The chunk fold's running max starts at the FLOOR and a masked score is
+# NEG_INF, a decade under it: the max never falls under the floor, so a masked
+# score's ``exp(NEG_INF - m)`` is 0 whatever its row has kept — ONE select a
+# masked score, none on the probabilities (``latent_attention``'s ``FLOOR`` /
+# ``NEG`` pair).  A row that has kept nothing keeps ``l == 0``.
+STAT_FLOOR = -1e29
+
+
 class _ChunkState(NamedTuple):
     """One batch row's chunk-prefill state: the refs both chunk drivers
     (the grid walk over a contiguous cache here, the in-kernel block loop
     of ``paged_attention``) hand to :func:`_init_chunk`,
     :func:`_chunk_block_update` and :func:`_finish_chunk`.  The running
-    max and sum are kept per head as lane-dense ``[C, LSE_LANES]`` tiles
-    (``[H, C, LSE_LANES]`` scratch, the flash kernels' layout) — a head's
-    update reads and writes whole tiles, never one lane of a ``[C, H]``
-    one."""
+    max and sum are kept per head LANE-REPLICATED, as whole ``[C,
+    STAT_LANES]`` float32 tiles every lane of which holds its row's value
+    (``[H, C, STAT_LANES]`` scratch): a head's update reads, computes and
+    writes whole tiles, and ``s - m`` / ``acc * corr`` take the tile laid
+    side by side (:func:`_across`) — kept as ``[C, 1]`` columns the two
+    cost a lane broadcast for every vreg of the ``[C, bk]`` score tile and
+    of the accumulator slice (PERF.md PR 51, PR 53)."""
     q_ref: Any                               # [1, C, H*D]
-    m_scr: Any                               # [H, C, LSE_LANES]
-    l_scr: Any                               # [H, C, LSE_LANES]
+    m_scr: Any                               # [H, C, STAT_LANES]
+    l_scr: Any                               # [H, C, STAT_LANES]
     acc_scr: Any                             # [C, H*D]
 
 
 def _chunk_scratch(c, h, d):
-    return [pltpu.VMEM((h, c, LSE_LANES), jnp.float32),   # running max
-            pltpu.VMEM((h, c, LSE_LANES), jnp.float32),   # running sum
+    return [pltpu.VMEM((h, c, STAT_LANES), jnp.float32),  # running max
+            pltpu.VMEM((h, c, STAT_LANES), jnp.float32),  # running sum
             pltpu.VMEM((c, h * d), jnp.float32)]          # per-head acc
 
 
 def _chunk_scratch_bytes(c, h, d):
-    # a [C, LSE_LANES] float32 tile pads to 128 lanes in VMEM
-    return 2 * h * c * 128 * 4 + c * h * d * 4
+    # the running max and sum are whole 128-lane float32 tiles — what the
+    # [C, LSE_LANES] tiles they replaced (PR 53) padded to in VMEM: the
+    # reckoning did not change with the layout
+    return 2 * h * c * STAT_LANES * 4 + c * h * d * 4
 
 
 def _chunk_grid_vmem_bytes(c, h, d, block_k, kvhd, itemsize):
@@ -397,8 +419,52 @@ def _chunk_grid_vmem_bytes(c, h, d, block_k, kvhd, itemsize):
                + _chunk_scratch_bytes(c, h, d) + 16 * 1024 * 1024)
 
 
+def _across(x, n):
+    """``x [rows, STAT_LANES]``, every lane its row's value, as ``[rows,
+    n]``: whole vregs side by side where ``n`` is whole lane tiles — no
+    lane broadcast a vreg, which a ``[rows, 1]`` column costs; the column
+    form where it is not (the tiny test models), decided from the static
+    shape (``latent_attention._across``, whose module imports this one)."""
+    if n % STAT_LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if n == STAT_LANES else jnp.tile(x, (1, n // STAT_LANES))
+
+
+def _side_by_side(parts):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _heads_a_tile(heads, d):
+    """How many of ``heads`` consecutive heads of size ``d`` share one lane
+    tile whole (two of 64), or 1."""
+    per = STAT_LANES // d if d < STAT_LANES and STAT_LANES % d == 0 else 1
+    return per if heads % per == 0 else 1
+
+
+def _per_head(tiles, d):
+    """One lane-replicated statistic a head (``[rows, STAT_LANES]`` each, of
+    consecutive heads) as ``[rows, len(tiles) * d]``, head ``t``'s value in
+    its ``d`` columns — what rescales the heads' slice of the accumulator
+    in one multiply.  By the static head size: whole lane tiles (D = 128)
+    are the tiles side by side; heads that share a lane tile (D = 64: two)
+    are ONE tile built by a lane select, where the columns' form took the
+    slice apart into half-filled vregs and put it together again; a size
+    that tiles no lane takes the column form."""
+    per = _heads_a_tile(len(tiles), d)
+    if per == 1:
+        return _side_by_side([_across(t, d) for t in tiles])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, STAT_LANES), 1)
+    parts = []
+    for i in range(0, len(tiles), per):
+        tile = tiles[i]
+        for u in range(1, per):
+            tile = jnp.where(lane >= u * d, tiles[i + u], tile)
+        parts.append(tile)
+    return _side_by_side(parts)
+
+
 def _init_chunk(st):
-    st.m_scr[...] = jnp.full_like(st.m_scr, NEG_INF)
+    st.m_scr[...] = jnp.full_like(st.m_scr, STAT_FLOOR)
     st.l_scr[...] = jnp.zeros_like(st.l_scr)
     st.acc_scr[...] = jnp.zeros_like(st.acc_scr)
 
@@ -464,7 +530,7 @@ def _chunk_block_update(st, ik, start, k_ref, v_ref, ks, vs, *, scale,
         kg = k_ref[:, kcols]                             # [bk, hpg*D]
         vg = v_ref[:, kcols]
         accg = st.acc_scr[:, qcols]
-        parts = []
+        corrs, outs = [], []
         for t in range(hpg * g):
             h, hk = j * hpg * g + t, j * hpg + t // g
             cols = slice(t * d, (t + 1) * d)
@@ -479,26 +545,25 @@ def _chunk_block_update(st, ik, start, k_ref, v_ref, ks, vs, *, scale,
             if quant:
                 s = s * kst[hk:hk + 1]                   # [1, bk] k-scales
             if masked:
+                # ONE select: the running max never falls under
+                # STAT_FLOOR, so exp(NEG_INF - m) is 0 without a second
                 s = jnp.where(live, s, NEG_INF)
-            m_prev = st.m_scr[h, :, 0:1]                 # [C, 1]
+            m_prev = st.m_scr[h]                         # [C, STAT_LANES]
             m_new = jnp.maximum(m_prev,
                                 jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            if masked:
-                p = jnp.where(live, p, 0.0)
+            p = jnp.exp(s - _across(m_new, block_k))
             corr = jnp.exp(m_prev - m_new)
-            l_new = st.l_scr[h, :, 0:1] * corr + jnp.sum(p, axis=1,
-                                                          keepdims=True)
-            st.l_scr[h] = jnp.broadcast_to(l_new, (c, LSE_LANES))
-            st.m_scr[h] = jnp.broadcast_to(m_new, (c, LSE_LANES))
+            st.l_scr[h] = st.l_scr[h] * corr + jnp.sum(p, axis=1,
+                                                       keepdims=True)
+            st.m_scr[h] = m_new
             if quant:
                 p = p * vst[hk:hk + 1]                   # v-scales on P
-            o = jax.lax.dot_general(
+            outs.append(jax.lax.dot_general(
                 p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [C, D]
-            parts.append(accg[:, cols] * corr + o)
-        st.acc_scr[:, qcols] = parts[0] if len(parts) == 1 \
-            else jnp.concatenate(parts, axis=1)
+                preferred_element_type=jnp.float32))     # [C, D]
+            corrs.append(corr)
+        st.acc_scr[:, qcols] = accg * _per_head(corrs, d) \
+            + _side_by_side(outs)
 
     if tiled:
         n_groups = kvh // hpg
@@ -516,9 +581,12 @@ def _chunk_block_update(st, ik, start, k_ref, v_ref, ks, vs, *, scale,
 
 
 def _finish_chunk(st, o_ref, *, heads, d):
-    for h in range(heads):
-        cols = slice(h * d, (h + 1) * d)
-        l = st.l_scr[h, :, 0:1]
+    # the heads of one lane tile together (two of 64), as the update
+    # rescales them
+    per = _heads_a_tile(heads, d)
+    for h in range(0, heads, per):
+        cols = slice(h * d, (h + per) * d)
+        l = _per_head([st.l_scr[h + u] for u in range(per)], d)
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, :, cols] = (st.acc_scr[:, cols] / safe_l).astype(o_ref.dtype)
 

@@ -85,8 +85,11 @@ REACHABLE pages of each row — those up to the chunk's furthest position,
   ``[C, 512] x [512, D]`` value matmul and one rescale of the head's
   accumulator slice (``decode_attention._chunk_block_update``, the
   monolithic chunk kernel's own update); the running max and sum are
-  ``[C, LSE_LANES]`` tiles a head.  Heads are walked by a ``fori_loop``
-  over 128-lane groups of the slab, so the body compiles once.  (The
+  lane-replicated ``[C, 128]`` tiles a head (``STAT_LANES``: every lane
+  its row's value, so ``s - m`` and the accumulator's rescale take whole
+  vregs and no lane broadcast — PERF.md PR 53).  Heads are walked by a
+  ``fori_loop`` over 128-lane groups of the slab, so the body compiles
+  once.  (The
   grid-per-page form this replaces did two 128x64x64 matmuls, a
   half-vreg score tile, two single-lane column updates and a rescale for
   every (head, 64-key page): ~20 us a reachable page, 3% of the chip's

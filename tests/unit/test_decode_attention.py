@@ -547,3 +547,242 @@ def test_cached_attention_chunk_branch_matches_dense(starts):
     want = xla_cached_attention(q, ks, vs, q_pos)     # dense fallback
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---- the chunk fold's layout (PR 53) -------------------------------------- #
+# ``_chunk_block_update`` keeps its running max and sum lane-replicated and
+# selects a masked score once.  Held, bitwise, to the form it replaced: the
+# statistics as ``[C, 1]`` columns of ``[H, C, LSE_LANES]`` tiles, the max
+# started at NEG_INF, the masked probabilities selected to 0 a second time —
+# transcribed below in plain ``jnp`` and folded through the SAME driver in
+# the same block order.
+
+def _column_fold():
+    """The parent's ``_chunk_scratch`` / ``_init_chunk`` /
+    ``_chunk_block_update`` / ``_finish_chunk``, heads walked statically."""
+    from jax.experimental.pallas import tpu as pltpu
+    from deepspeed_tpu.ops.transformer.flash_attention import (LSE_LANES,
+                                                               NEG_INF)
+
+    def scratch(c, h, d):
+        return [pltpu.VMEM((h, c, LSE_LANES), jnp.float32),
+                pltpu.VMEM((h, c, LSE_LANES), jnp.float32),
+                pltpu.VMEM((c, h * d), jnp.float32)]
+
+    def init(st):
+        st.m_scr[...] = jnp.full_like(st.m_scr, NEG_INF)
+        st.l_scr[...] = jnp.zeros_like(st.l_scr)
+        st.acc_scr[...] = jnp.zeros_like(st.acc_scr)
+
+    def update(st, ik, start, k_ref, v_ref, ks, vs, *, scale, block_k, c,
+               kvh, g, d, masked=True, prefix=False, pos0=None, window=None,
+               limit=None):
+        quant = ks is not None
+        if quant:
+            kst, vst = ks.astype(jnp.float32).T, vs.astype(jnp.float32).T
+        if masked:
+            pos = (ik * block_k if pos0 is None else pos0) \
+                + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+            if prefix:
+                live = pos < start
+            else:
+                qpos = start + jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+                live = pos <= qpos
+                if window is not None:
+                    live = jnp.logical_and(live, pos > qpos - window)
+                if limit is not None:
+                    live = jnp.logical_and(live, pos < limit)
+        for h in range(kvh * g):
+            hk = h // g
+            cols, kvcols = slice(h * d, (h + 1) * d), \
+                slice(hk * d, (hk + 1) * d)
+            qh, kh, vh = st.q_ref[0, :, cols], k_ref[:, kvcols], \
+                v_ref[:, kvcols]
+            if quant:
+                kh, vh = kh.astype(qh.dtype), vh.astype(qh.dtype)
+            s = jax.lax.dot_general(
+                qh, kh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if quant:
+                s = s * kst[hk:hk + 1]
+            if masked:
+                s = jnp.where(live, s, NEG_INF)
+            m_prev = st.m_scr[h, :, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if masked:
+                p = jnp.where(live, p, 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = st.l_scr[h, :, 0:1] * corr + jnp.sum(p, axis=1,
+                                                          keepdims=True)
+            st.l_scr[h] = jnp.broadcast_to(l_new, (c, LSE_LANES))
+            st.m_scr[h] = jnp.broadcast_to(m_new, (c, LSE_LANES))
+            if quant:
+                p = p * vst[hk:hk + 1]
+            o = jax.lax.dot_general(
+                p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            st.acc_scr[:, cols] = st.acc_scr[:, cols] * corr + o
+
+    def finish(st, o_ref, *, heads, d):
+        for h in range(heads):
+            cols = slice(h * d, (h + 1) * d)
+            l = st.l_scr[h, :, 0:1]
+            safe_l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, :, cols] = (st.acc_scr[:, cols] / safe_l
+                                 ).astype(o_ref.dtype)
+
+    return scratch, init, update, finish
+
+
+def _fold_blocks(fold, q, k, v, plan, *, kvh, g, d, scales=None):
+    """``plan``'s blocks (one dict of ``_chunk_block_update``'s block
+    arguments each; block ``i`` is ``k[i]`` / ``v[i]``) folded in order by
+    ``fold`` = (scratch, init, update, finish) inside ONE interpreted
+    kernel; the chunk's output ``[C, H*D]``."""
+    from jax.experimental import pallas as pl
+    from deepspeed_tpu.ops.transformer.decode_attention import _ChunkState
+    scratch, init, update, finish = fold
+    c, bk, heads = q.shape[0], k.shape[1], kvh * g
+
+    def kernel(q_ref, k_ref, v_ref, *rest):
+        ks_ref, vs_ref = rest[:2] if scales else (None, None)
+        o_ref, m_scr, l_scr, acc_scr = rest[2 if scales else 0:]
+        st = _ChunkState(q_ref, m_scr, l_scr, acc_scr)
+        init(st)
+        for i, block in enumerate(plan):
+            update(st, block["ik"], block["start"], k_ref.at[i], v_ref.at[i],
+                   ks_ref[i] if scales else None,
+                   vs_ref[i] if scales else None,
+                   scale=float(1.0 / np.sqrt(d)), block_k=bk, c=c,
+                   kvh=kvh, g=g, d=d,
+                   **{n: x for n, x in block.items()
+                      if n not in ("ik", "start")})
+        finish(st, o_ref, heads=heads, d=d)
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((1,) + q.shape, q.dtype),
+        scratch_shapes=scratch(c, heads, d), interpret=True,
+    )(q[None], k, v, *(scales or ()))[0]
+
+
+_C, _BK = 16, 128
+_FOLD_PLANS = {
+    # a causal chunk at ``start``: blocks wholly under the diagonal
+    # unmasked, then the blocks that reach it
+    "causal": [dict(ik=0, start=280, masked=False),
+               dict(ik=1, start=280, masked=False),
+               dict(ik=2, start=280)],
+    "causal_all_masked": [dict(ik=0, start=5), dict(ik=1, start=120)],
+    # EVA: summary rows up to a bound every query sees alike, then the ring
+    "prefix": [dict(ik=0, start=200, prefix=True),
+               dict(ik=1, start=200, prefix=True),
+               dict(ik=0, start=130, masked=False),
+               dict(ik=1, start=130)],
+    # a bound of 0 keeps nothing: every row ends at l == 0 and gets zeros
+    "prefix_nothing_kept": [dict(ik=0, start=0, prefix=True)],
+    # Trinity's ring walk: the first block (the late queries' band starts
+    # past it: rows that have kept nothing yet), an inner block wholly in
+    # the band, the last with rows at ``limit`` and past it; then the
+    # chunk's own keys up to the diagonal
+    "band": [dict(ik=0, start=300, pos0=0, window=296, limit=300),
+             dict(ik=0, start=300, masked=False),
+             dict(ik=0, start=300, pos0=256, window=296, limit=300),
+             dict(ik=0, start=300, pos0=300, window=296)],
+    # the band's first block alone: the late queries keep nothing, and
+    # stay so
+    "band_first_only": [dict(ik=0, start=300, pos0=0, window=180,
+                             limit=300)],
+}
+
+
+@pytest.mark.parametrize("plan", sorted(_FOLD_PLANS))
+@pytest.mark.parametrize("kvh,g,d,bk,quant", [
+    (2, 1, 64, _BK, False),      # OPT: two heads a lane tile
+    (2, 4, 64, _BK, False),      # LFM2
+    (4, 1, 64, _BK, True),       # the int8 pool's branch (heads unrolled)
+    (1, 1, 128, _BK, False),     # EvaByte / OLMoE
+    (2, 4, 128, 2 * _BK, False),   # Trinity; the tile laid side by side
+    (1, 8, 128, _BK, False),
+    (2, 2, 128, _BK, True),
+    (3, 1, 48, _BK, False),      # a head that tiles no lane: columns
+    (2, 2, 32, 48, False),       # a block that is no lane tile: columns
+    (4, 1, 32, _BK, False),      # four heads a lane tile
+], ids=lambda x: str(x))
+def test_chunk_fold_is_bitwise_the_column_form(kvh, g, d, bk, quant, plan):
+    """The lane-replicated, one-select fold gives the column form's bits —
+    every block class a driver hands it, every head layout its static
+    shapes choose between — and a row that kept nothing comes out zero."""
+    from deepspeed_tpu.ops.transformer import decode_attention as da
+    blocks = _FOLD_PLANS[plan]
+    if bk != _BK:
+        # the same positions in blocks of ``bk``
+        blocks = [dict(b, **{n: b[n] * bk // _BK for n in
+                             ("start", "pos0", "window", "limit") if n in b})
+                  for b in blocks]
+    rng = np.random.default_rng(kvh * 1000 + g * 100 + d)
+    heads, n = kvh * g, len(blocks)
+    dtype = jnp.bfloat16
+    q = jnp.asarray(rng.standard_normal((_C, heads * d)), dtype)
+    k = jnp.asarray(rng.standard_normal((n, bk, kvh * d)), dtype)
+    v = jnp.asarray(rng.standard_normal((n, bk, kvh * d)), dtype)
+    scales = None
+    if quant:
+        k = jnp.asarray(rng.integers(-127, 128, k.shape), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, v.shape), jnp.int8)
+        scales = tuple(jnp.asarray(rng.uniform(0.004, 0.02, (n, bk, kvh)),
+                                   jnp.float32) for _ in range(2))
+    new = _fold_blocks((da._chunk_scratch, da._init_chunk,
+                        da._chunk_block_update, da._finish_chunk),
+                       q, k, v, blocks, kvh=kvh, g=g, d=d, scales=scales)
+    old = _fold_blocks(_column_fold(), q, k, v, blocks, kvh=kvh, g=g, d=d,
+                       scales=scales)
+    new, old = np.asarray(new, np.float32), np.asarray(old, np.float32)
+    assert np.isfinite(new).all()
+    np.testing.assert_array_equal(new, old)
+    if plan == "prefix_nothing_kept":
+        assert not new.any()
+    if plan == "band_first_only":
+        # query r sees positions > start + r - window of the block's bk
+        kept = blocks[0]["window"] - blocks[0]["start"] + bk - 1
+        assert 0 < kept < _C
+        assert not new[kept:].any() and new[:kept].any(axis=1).all()
+
+
+@pytest.mark.parametrize("d,block_k,rescale,spread", [
+    (128, 512, "tiles", "tiles"),      # Trinity, EvaByte, OLMoE
+    (64, 512, "select", "tiles"),      # OPT, LFM2: two heads a lane tile
+    (256, 128, "tiles", "tiles"),
+    (48, 512, "columns", "tiles"),     # a head that tiles no lane
+    (64, 64, "select", "columns"),     # a page of 64 keys a block (int8)
+    (96, 48, "columns", "columns"),
+])
+def test_chunk_fold_lays_statistics_out_by_shape(d, block_k, rescale,
+                                                 spread):
+    """Which form a shape takes, from its static widths alone: whole lane
+    tiles side by side, a lane select between the heads that share a tile,
+    or the ``[:, :1]`` column's broadcast where a width tiles no lane."""
+    from deepspeed_tpu.ops.transformer import decode_attention as da
+
+    def primitives(jaxpr):
+        for e in jaxpr.eqns:
+            yield str(e.primitive)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from primitives(sub)
+
+    def form(fn, *shapes):
+        prims = set(primitives(jax.make_jaxpr(fn)(
+            *[jnp.zeros(s, jnp.float32) for s in shapes]).jaxpr))
+        if "slice" in prims:
+            return "columns"
+        return "select" if "select_n" in prims else "tiles"
+
+    tile = (8, da.STAT_LANES)
+    heads = 2 * max(1, da.STAT_LANES // d)
+    assert form(lambda *t: da._per_head(list(t), d),
+                *[tile] * heads) == rescale
+    assert form(lambda x: da._across(x, block_k), tile) == spread
+    out = da._per_head([jnp.full(tile, float(h)) for h in range(heads)], d)
+    assert out.shape == (8, heads * d)
+    np.testing.assert_array_equal(
+        np.asarray(out[0]), np.repeat(np.arange(heads, dtype=np.float32), d))

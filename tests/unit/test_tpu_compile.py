@@ -71,6 +71,25 @@ def mosaic(monkeypatch, no_persistent_cache):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
+@pytest.fixture
+def vmem_asks(monkeypatch):
+    """The ``vmem_limit_bytes`` of every Pallas call traced while the test
+    runs, read off the calls themselves (the chunk kernels' calls are
+    jitted: their own caches are dropped so that each is traced anew)."""
+    from jax.experimental.pallas import tpu as pltpu
+    asked, params = [], pltpu.CompilerParams
+
+    def recording(*args, **kw):
+        asked.append(kw.get("vmem_limit_bytes"))
+        return params(*args, **kw)
+
+    monkeypatch.setattr(pltpu, "CompilerParams", recording)
+    for call in (paged_mod._paged_chunk_call, paged_mod._window_chunk_call,
+                 eva_mod._eva_chunk_call):
+        call.clear_cache()
+    return asked
+
+
 def _flash_fwd_bwd(batch=2, seq=2048, heads=H, head_dim=D):
     def loss(q, k, v):
         out = flash_mod.flash_attention(q, k, v, causal=True)
@@ -366,6 +385,35 @@ def test_paged_chunk_block_loop_fits_vmem(heads, head_dim, pages_per_slot,
     assert asked <= 100 * 2 ** 20, f"asks for {asked / 2 ** 20:.0f} MiB"
     compiled = _compile(fn, shapes, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the chunk fold's kernels at the cells' shapes, and the VMEM each asked
+# for BEFORE its running max and sum became whole 128-lane tiles (PR 53):
+# the [C, LSE_LANES] tiles they replaced padded to 128 lanes, so the layout
+# may not cost a byte.  (Trinity's two are held in its chunk step, below.)
+_CHUNK_FOLD_ASKS = {
+    # two kv heads a 128-lane group, taken at a dynamic lane offset
+    "opt13b_paged_chunk_c128": (lambda: _paged_chunk_prefill(
+        cache_len=29 * 64), 34078720),
+    "olmoe_paged_chunk_c512": (lambda: _paged_chunk_prefill(
+        chunk=512, cache_len=17 * 64, heads=16, head_dim=128), 52428800),
+    "evabyte_eva_chunk_c512": (lambda: _eva_chunk(512), 81788928),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHUNK_FOLD_ASKS))
+def test_chunk_fold_compiles_under_its_old_vmem_ask(case, one_chip, mosaic,
+                                                    vmem_asks):
+    """Every kernel that folds through ``_chunk_block_update`` lowers
+    through Mosaic with the lane-replicated statistics under the SAME
+    ``vmem_limit_bytes`` it asked for with the columns (a kernel over its
+    ask does not compile)."""
+    build, old_ask = _CHUNK_FOLD_ASKS[case]
+    fn, shapes = build()
+    compiled = _compile(fn, shapes, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert vmem_asks == [old_ask], vmem_asks
+    assert decode_mod._chunk_scratch_bytes(512, 32, 128) == 25165824
 
 
 # the four cells that run ``attn.paged_decode``: slots, query heads, KV
@@ -698,7 +746,7 @@ def test_longcat_slot_programs_compile_at_the_cells_sizes(program, one_chip,
 
 @pytest.mark.parametrize("program", ["chunk_step", "decode_block"])
 def test_trinity_slot_programs_compile_at_the_cells_sizes(program, one_chip,
-                                                          mosaic):
+                                                          mosaic, vmem_asks):
     """The two programs ``trinity-serve-mixedlen-batch`` runs, whole, as
     ``serving/slots.py`` builds them at the cell's own settings: four
     sliding layers over K/V rings of 32 pages a slot and one full layer over
@@ -730,6 +778,10 @@ def test_trinity_slot_programs_compile_at_the_cells_sizes(program, one_chip,
             params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
             ints(1)).compile()
         calls = 4 + 1 + 4     # a window chunk, a paged chunk; the experts
+        # the chunk fold's two kernels — 512 queries a grid step, GQA 32 / 4
+        # at D = 128 — ask for what they asked before their statistics
+        # became whole lane tiles (PR 53)
+        assert {75497472, 67108864} <= set(vmem_asks), vmem_asks
     else:
         n = s["num_slots"]
         state = on_chip({k: jnp.asarray(v) for k, v in

@@ -331,6 +331,7 @@ class SlotPages:
         self.state_kinds = tuple(contract.state_kinds)
         self.state_rows = 1 + self.num_slots if self.state_kinds else 0
         self.state_row_bytes = 0         # known once the pools are made
+        self.state_kind_bytes = {}       # ... a row's bytes in each kind
         self.page_bytes = 0
         # ... and a model that names its ring pools (``ring_kinds``) has the
         # rings' bytes counted beside the pages'
@@ -386,7 +387,12 @@ class SlotPages:
         nbytes = lambda keys: sum(pools[k].size * pools[k].dtype.itemsize
                                   for k in keys)
         if self.state_kinds:
-            self.state_row_bytes = nbytes(self.state_kinds) // self.state_rows
+            # a kind's pool has its own dtype and shape (``models/
+            # solar_open2.py``: bfloat16 conv rows beside a float32 matrix
+            # state whatever ``dtype`` is): a row is counted kind by kind
+            self.state_kind_bytes = {k: nbytes([k]) // self.state_rows
+                                     for k in self.state_kinds}
+            self.state_row_bytes = sum(self.state_kind_bytes.values())
         if self.ring_kinds:
             self.ring_slot_bytes = nbytes(self.ring_kinds) \
                 // self.window_pages * self.ring_pages
@@ -591,6 +597,10 @@ class SlotPages:
                      f"state_rows_live {len(self._rows)}/"
                      f"{self.state_rows - 1} (one a slot, row 0 trash), "
                      f"state_bytes {self._state_bytes()}")
+            if len(self.state_kinds) > 1:
+                text += " (" + ", ".join(
+                    f"{k} {len(self._rows) * n}"
+                    for k, n in self.state_kind_bytes.items()) + ")"
         return text
 
     def slot_pages_str(self, slot):
@@ -654,9 +664,13 @@ class SlotPages:
     def _state_reach(self, live):
         """A decode block's state work and the cache's split, as span
         args: ``state_rows`` — state rows read and written, one a live
-        slot and step —, ``state_bytes`` — the state rows slots hold — and
-        ``kv_bytes_mapped`` — the pages slots hold, over every pool the
-        page table indexes."""
+        slot and step —, ``state_bytes`` — the state rows slots hold, every
+        kind's summed, and of a model with several kinds
+        ``state_bytes_<kind>`` each — and ``kv_bytes_mapped`` — the pages
+        slots hold, over every pool the page table indexes."""
+        by_kind = {f"state_bytes_{k}": len(self._rows) * n
+                   for k, n in self.state_kind_bytes.items()} \
+            if len(self.state_kinds) > 1 else {}
         return {"state_rows": sum(steps for _, steps in live),
-                "state_bytes": self._state_bytes(),
+                "state_bytes": self._state_bytes(), **by_kind,
                 "kv_bytes_mapped": self._pool.in_use * self.page_bytes}

@@ -140,6 +140,15 @@ def check(contract, module, page_size, chunk, layers):
             raise ValueError(
                 f"{said}: {field} names {missing}, no key of the cache "
                 f"init_paged_cache() returns ({sorted(pools)})")
+    for kind in contract.state_kinds:
+        # whatever its dtype and its row's shape, a kind's pool is one row a
+        # state row: ``paging.SlotPages`` counts a row's bytes kind by kind
+        if pools[kind].ndim < 2 \
+                or pools[kind].shape[1] != sizes["state_rows"]:
+            raise ValueError(
+                f"{said}: state kind {kind!r} is a pool of shape "
+                f"{pools[kind].shape} at state_rows="
+                f"{sizes['state_rows']}: its second axis is the state row")
     if contract.kv_pages != ("k" in pools):
         raise ValueError(f"{said}: kv_pages={contract.kv_pages} but "
                          f"init_paged_cache() returns {sorted(pools)}")

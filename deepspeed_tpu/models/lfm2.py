@@ -120,6 +120,35 @@ def lfm2_model(hf, **overrides):
     return Lfm2Model(lfm2_config(hf, **overrides))
 
 
+def step_taps(before, z, w):
+    """A causal depthwise convolution's step, one token a lane: ``before
+    [N, (K - 1) x h]`` each lane's last rows, ``z [N, h]`` its token's,
+    ``w [K, h]`` float32 (tap ``j`` weighs row ``t - K + 1 + j``).  Returns
+    ``(conv [N, h]`` float32``, the rows to keep [N, (K - 1) x h])``."""
+    K, h = w.shape
+    taps = jnp.concatenate([before, z], axis=-1).reshape(-1, K, h)
+    conv = jnp.sum(taps.astype(jnp.float32) * w, axis=1)
+    return conv, taps[:, 1:].reshape(-1, (K - 1) * h)
+
+
+def chunk_taps(before, z, w, start, last=None):
+    """The same over ``T`` consecutive rows ``z [T, h]`` of ONE sequence
+    from position ``start``: a request's first chunk starts from zeros,
+    whatever its slot's last occupant left in ``before [(K - 1) x h]``, and
+    the rows to keep are those that end at ``last``, the chunk's last real
+    row (the padded tail's never reach the state).  Returns ``(conv [T,
+    h]`` float32``, kept [(K - 1) x h])``."""
+    K, h = w.shape
+    before = jnp.where(start == 0, 0, before.reshape(K - 1, h))
+    zz = jnp.concatenate([before.astype(z.dtype), z])          # [T+K-1, h]
+    T = z.shape[0]
+    conv = sum(zz[j:j + T].astype(jnp.float32) * w[j] for j in range(K))
+    # rows (last - K + 2 .. last) of z: zz is ahead by K - 1
+    last = T - 1 if last is None else last
+    keep = jax.lax.dynamic_slice_in_dim(zz, last + 1, K - 1)
+    return conv, keep.reshape(-1)
+
+
 class ShortConv(nn.Module):
     """The gated short convolution.  ``state`` is ``None`` (a sequence
     from its start, nothing kept) or ``(pool [conv layers, rows, (K - 1)
@@ -150,25 +179,12 @@ class ShortConv(nn.Module):
                 pool, at, rows = state
                 before = pool[at, rows]                # [N, (K-1) h] | [(K-1) h]
             if start is None:
-                # one token a lane: ``before`` holds each lane's last rows
-                taps = jnp.concatenate([before, z], axis=-1) \
-                    .reshape(-1, K, h)                         # [N, K, h]
-                conv = jnp.sum(taps.astype(jnp.float32) * w, axis=1)
-                pool = pool.at[at, rows].set(taps[:, 1:].reshape(-1,
-                                                                 (K - 1) * h))
+                conv, kept = step_taps(before, z, w)
+                pool = pool.at[at, rows].set(kept)
             else:
-                # a request's first chunk starts from zeros, whatever its
-                # slot's last occupant left in the row
-                before = jnp.where(start == 0, 0, before.reshape(K - 1, h))
-                zz = jnp.concatenate([before.astype(z.dtype), z])  # [T+K-1, h]
-                T = z.shape[0]
-                conv = sum(zz[j:j + T].astype(jnp.float32) * w[j]
-                           for j in range(K))
+                conv, kept = chunk_taps(before, z, w, start, last)
                 if pool is not None:
-                    # rows (last - K + 2 .. last) of z: zz is ahead by K - 1
-                    last = T - 1 if last is None else last
-                    keep = jax.lax.dynamic_slice_in_dim(zz, last + 1, K - 1)
-                    pool = pool.at[at, rows].set(keep.reshape(-1))
+                    pool = pool.at[at, rows].set(kept)
             y = c * conv.astype(z.dtype)
         return dense(h, "out_proj")(y), pool
 

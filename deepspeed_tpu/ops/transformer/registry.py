@@ -508,3 +508,25 @@ def write_and_attend(cfg, q, k, v, positions, cache, *, bias=None,
     else:
         out = _attend(cfg, mode, q, new_cache, positions, bias, window)
     return out, new_cache
+
+
+# ---- a matrix state a slot: the gated delta rule ------------------------- #
+def delta_state_update(q, k, v, g, beta, state, *, start=None, real=None,
+                       live=None):
+    """Run a gated delta-rule layer's positions through its MATRIX STATE and
+    leave the state in the pool: ``state = (pool [layers, rows, H, d, d]``
+    float32``, layer, rows)``.  A chunk (``start`` a scalar): ``q`` / ``k`` /
+    ``v`` / ``g [T, H, d]``, ``beta [T, H]`` consecutive positions of ONE
+    slot, ``rows`` its state row, from zeros where ``start == 0`` and
+    through the chunk's ``real`` rows (None: all).  A step (``start``
+    None): row ``n`` is lane ``n``'s one token, ``rows [N]``, dead lanes
+    (``live [N]``) write nothing.  Returns ``(o, pool)``."""
+    from deepspeed_tpu.ops.transformer import delta_attention
+    pool, layer, rows = state
+    pallas = pallas_supported()
+    if start is None:
+        return delta_attention.decode_step(q, k, v, g, beta, pool, layer,
+                                           rows, live, pallas=pallas)
+    return delta_attention.chunk_scan(
+        q, k, v, g, beta, pool, layer, rows, fresh=start == 0,
+        real=q.shape[0] if real is None else real, pallas=pallas)

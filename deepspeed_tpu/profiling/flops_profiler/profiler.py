@@ -290,7 +290,7 @@ def _scope_to_path(op_name):
 # Device time by part (docs/observability.md "Device time by part")
 # --------------------------------------------------------------------- #
 PARTS = ("embed", "attn.proj", "attn.core", "attn.mla_decompress",
-         "attn.eva", "eva.summarise", "cache.write", "mlp", "moe.route",
+         "attn.eva", "attn.kda", "eva.summarise", "cache.write", "mlp", "moe.route",
          "moe.experts", "conv.short", "norm", "residual", "head", "loss",
          "optim", "scan.stack", "slots", "mtp.combine", "comm",
          "xla.prefetch")
@@ -350,6 +350,18 @@ SCOPE_PARTS = (
     (r"self_attn/gate_proj", "attn.proj"),
     (r"attn\.(qk_norm|out_gate)", "attn.proj"),
     (r"attn\.(window|full)", "attn.core"),
+    # gated delta-rule linear attention on a matrix state a slot
+    # (``models/solar_open2.py``): the mixer's scopes, its two state kernels
+    # by their ``name=``, and what its module holds that no row below knows
+    # — the decay's, the step size's and the gate's projections, the
+    # per-head output norm.  (Its q / k / v / o projections are
+    # ``attn.proj`` and its convolutions ``conv.short`` like any other's;
+    # the scope ``attn.kda`` covers them all for a reader that wants the
+    # mixer whole)
+    (r"attn\.kda/conv\.short", "conv.short"),
+    (r"attn\.kda|kda\.(scan|out_gate)|\w*kda\.(chunk_scan|decode_step)\w*",
+     "attn.kda"),
+    (r"(f|g)_(a|b)_proj|b_proj|o_norm|linear_attn(\.\w+)?", "attn.kda"),
     # the layer scan's own operations (``scan_layers``): a layer's slice
     # out of the stacked parameters and saved residuals, the saves' and the
     # gradients' writes back into the stacks, the stacks' zeros and copies

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.ops.sparse_attention import block_sparse
 from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
+                                           delta_attention as delta_mod,
                                            flash_attention as flash_mod,
                                            paged_attention as paged_mod)
 
@@ -72,7 +73,23 @@ def _block_sparse():
     return fn, [((1, 256, H, D), F32)] * 3
 
 
+def _kda(chunk):
+    """The two state kernels of the gated delta rule over a pool ``[layers,
+    rows, heads, d, d]``."""
+    pool, n = ((L, 3, H, 16, 16), F32), 64 if chunk else 2
+    rows = ((n, H, 16), F32)
+
+    def fn(q, k, v, g, beta, pool, at):
+        if chunk:
+            return delta_mod.chunk_scan(q, k, v, g, beta, pool, 1, at[0],
+                                        fresh=False, real=n)
+        return delta_mod.decode_step(q, k, v, g, beta, pool, 1, at)
+    return fn, [rows] * 4 + [((n, H), F32), pool, ((n,), I32)]
+
+
 CASES = {
+    "kda.chunk_scan": lambda: _kda(True),
+    "kda.decode_step": lambda: _kda(False),
     "attn.paged_decode": _paged_decode,
     "attn.paged_chunk_prefill": _paged_chunk,
     "attn.flash_fwd": lambda: _flash(False),

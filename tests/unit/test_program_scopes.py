@@ -253,6 +253,34 @@ D = "jit(decode_block)/while/body/closed_call/"
     (D + "TrinityModel.decode/head.logits/norm/mul", "head", "fwd"),
     (D + "TrinityModel.decode/head.logits/lm_head/dot_general", "head",
      "fwd"),
+    # gated delta-rule linear attention on a matrix state a slot: the
+    # mixer's own rows, its two kernels, and what stays where it always was
+    (D + "SolarOpen2Model.decode/layers_1/linear_attn/attn.kda/kda.scan/"
+     "rsqrt", "attn.kda", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_1/linear_attn/attn.kda/kda.scan/"
+     "kda.decode_step", "attn.kda", "fwd"),
+    ("jit(chunk_step)/SolarOpen2Model.decode/layers_2/linear_attn/attn.kda/"
+     "kda.scan/kda.chunk_scan", "attn.kda", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_1/linear_attn/attn.kda/f_b_proj/"
+     "dot_general", "attn.kda", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_1/linear_attn/attn.kda/b_proj/"
+     "dot_general", "attn.kda", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_1/linear_attn/attn.kda/kda.out_gate/"
+     "logistic", "attn.kda", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_1/linear_attn/attn.kda/concatenate",
+     "attn.kda", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_1/linear_attn/attn.kda/conv.short/"
+     "mul", "conv.short", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_1/linear_attn/attn.kda/q_proj/"
+     "dot_general", "attn.proj", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_0/self_attn/attn.full/"
+     "attn.paged_decode", "attn.core", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_0/self_attn/gate_proj/dot_general",
+     "attn.proj", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_3/moe_mlp/moe.experts_gmm",
+     "moe.experts", "fwd"),
+    (D + "SolarOpen2Model.decode/SolarOpen2Model._head/head.logits/lm_head/"
+     "dot_general", "head", "fwd"),
     # nothing the table knows: unattributed
     ("jit(train_step)/mul", None, "fwd"),
     ("jit(train_step)/transpose(jvp(Transformer))/broadcast_in_dim", None,
@@ -270,7 +298,7 @@ def test_part_and_phase_of_an_op_name(op_name, part, phase):
 def test_the_table_is_a_fixed_literal_set_of_known_parts():
     assert {part for _, part in profiler.SCOPE_PARTS} <= set(profiler.PARTS)
     # never a size or an index in a scope name the programs add
-    for rx, _ in profiler.SCOPE_PARTS[:23]:
+    for rx, _ in profiler.SCOPE_PARTS[:26]:
         assert not any(ch.isdigit() for ch in rx)
 
 

@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from deepspeed_tpu.moe import dropless as moe_mod
 from deepspeed_tpu.ops.sparse_attention import block_sparse
 from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
+                                           delta_attention as delta_mod,
                                            eva_attention as eva_mod,
                                            flash_attention as flash_mod,
                                            latent_attention as latent_mod,
@@ -67,7 +68,7 @@ def mosaic(monkeypatch, no_persistent_cache):
     """``_interpret()`` asks ``jax.default_backend()``, which is the CPU
     here: steer it in the test, not through an option of the program."""
     for mod in (flash_mod, decode_mod, paged_mod, block_sparse, moe_mod,
-                latent_mod, eva_mod):
+                latent_mod, eva_mod, delta_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -298,7 +299,23 @@ def _eva_chunk(chunk):
     return fn, args
 
 
+def _kda_chunk_scan(chunk=2048, heads=64, dim=128, slots=64, layers=3):
+    """One layer-chunk of ``solar-serve-longctx-batch``'s chunk step: 2,048
+    rows of 64 heads of 128 through ``kda.chunk_scan`` over the cell's
+    float32 state pool (65 rows x 3 layers x 4 MiB), a state row in and out."""
+    rows = ((chunk, heads, dim), BF16)
+    args = [rows] * 3 + [((chunk, heads, dim), F32), ((chunk, heads), F32),
+                         ((layers, 1 + slots, heads, dim, dim), F32),
+                         ((), I32), ((), I32)]
+
+    def fn(q, k, v, g, beta, pool, row, start):
+        return delta_mod.chunk_scan(q, k, v, g, beta, pool, layers - 1, row,
+                                    fresh=start == 0, real=chunk - 5)
+    return fn, args
+
+
 CASES = {
+    "solar_kda_chunk_scan_c2048": _kda_chunk_scan,
     "evabyte_eva_decode_24x46": _eva_decode,
     "evabyte_eva_chunk_c512": lambda: _eva_chunk(512),
     "evabyte_eva_chunk_c2048": lambda: _eva_chunk(2048),
@@ -805,6 +822,73 @@ def test_trinity_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 12.5e9 < total < 15.5e9, f"{total / 1e9:.2f} GB"
+
+
+@pytest.mark.parametrize("program", ["decode_block"])
+def test_solar_slot_programs_compile_at_the_cells_sizes(program, one_chip,
+                                                        mosaic):
+    """``program``: the decode block only — the chunk step compiles the same
+    way (PR 54 ran both, 60 s the chunk step) and its one new kernel is a
+    case of ``test_kernel_compiles_to_mosaic`` at the cell's widths.
+    The two programs ``solar-serve-longctx-batch`` runs, whole, as
+    ``serving/slots.py`` builds them at the cell's own settings: one softmax
+    layer over K/V lane pages (529 a slot), three gated delta-rule layers
+    over a float32 matrix state and a bfloat16 conv state a slot —
+    ``kda.chunk_scan`` / ``kda.decode_step`` with the state pool aliased in
+    and out —, four expert layers of 40 held experts under a 320-wide
+    router, a 24,576-wide head; every pool aliased input -> output, 6.62 GB
+    of weights + 4.3 GB of lane pool + 0.85 GB of state and the programs'
+    temporaries inside one chip; and every instruction under the model's
+    call lies in a part of the profiler's table."""
+    from deepspeed_tpu.inference.serving import slots
+    from deepspeed_tpu.inference.serving.paging import SlotPages
+    from deepspeed_tpu.profiling.flops_profiler import profiler
+    c = _slot_programs_of("solar-serve-longctx-batch", "solar_open2",
+                          one_chip)
+    module, s, chunk = c.module, c.serving, c.chunk
+    params, ints, on_chip = c.params, c.ints, c.on_chip
+    pages = SlotPages(module, c.declared, s["num_slots"], s["max_cache_len"],
+                      s["page_size"], s["num_pages"], chunk, False, {})
+    pool = on_chip(jax.eval_shape(lambda: pages.new_pools(BF16)))
+    assert (pages.pages_per_slot, pages.state_rows, pages.table_width) \
+        == (529, 65, 530)
+    assert {k: (v.shape, str(v.dtype)) for k, v in pool.items()} == {
+        "k": ((1, s["num_pages"], 64, 1024), "bfloat16"),
+        "v": ((1, s["num_pages"], 64, 1024), "bfloat16"),
+        "conv": ((3, 65, 3 * 24576), "bfloat16"),
+        "kda": ((3, 65, 64, 128, 128), "float32")}
+    assert pages.state_kind_bytes == {"conv": 3 * 147456,
+                                      "kda": 3 * 4 * 2 ** 20}
+    if program == "chunk_step":
+        compiled = slots.make_chunk_fn(module, c.declared, None).lower(
+            params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
+            ints(1)).compile()
+        calls = 1 + 3 + 4     # a paged chunk, a state scan; the experts
+    else:
+        n = s["num_slots"]
+        state = on_chip({k: jnp.asarray(v) for k, v in
+                         slots.init_slot_state(n).items()})
+        rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                   sharding=one_chip)
+        compiled = slots.make_decode_block_fn(
+            module, c.declared, lambda logits, rng: jnp.argmax(logits, -1),
+            None, s["decode_block"], pages.cache_len).lower(
+                params, pool, state, ints(n, pages.table_width),
+                rng).compile()
+        calls = 1 + 3 + 4     # paged decode, a state step; the experts
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= calls
+    assert ("kda.chunk_scan" if program == "chunk_step"
+            else "kda.decode_step") in text
+    named = set(re.findall(r'op_name="([^"]*SolarOpen2Model\.decode[^"]*)"',
+                           text))
+    assert named and not [n for n in named if profiler.part_of(n)[0] is None]
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 11.5e9 < total < 15.5e9, f"{total / 1e9:.2f} GB"
 
 
 @pytest.mark.parametrize("case", sorted(CASES))

@@ -18,9 +18,11 @@ to.  The pool is ``[KDA layers, state rows, heads, d_k, d_v]`` float32 — row
 ALIASED: a dispatch touches its own rows' blocks and copies nothing else.
 
 **A prefill chunk** (:func:`chunk_scan`, ``kda.chunk_scan``): ``T``
-consecutive positions of one slot, ``grid = (heads, T / 64)``, the state a
-head carried in VMEM across its 64-row blocks.  A block is the chunked
-form: with ``G_i = sum_{j <= i} g_j`` inside the block,
+consecutive positions of one slot, ``grid = (heads / 4, T / 64)``: a grid
+step takes FOUR heads' 64-row blocks (:func:`_chunk_heads`: of the shapes
+alone) and emits their work stage by stage in one body, the states the
+four carry in VMEM across their blocks.  A block is the chunked form: with
+``G_i = sum_{j <= i} g_j`` inside the block,
 
     A_ij = beta_i sum_c k_ic k_jc e^{G_ic - G_jc}    (j < i)
     (I + A) V' = beta (v - (k e^G) S_in)
@@ -57,6 +59,7 @@ from deepspeed_tpu.ops.transformer.flash_attention import _interpret
 HIGHEST = jax.lax.Precision.HIGHEST
 BLOCK = 64               # rows of a head a grid step of the chunk kernel takes
 SUB = 16                 # ... in sub-blocks of these
+_CHUNK_HEADS = (4, 2, 1)  # ... and heads: the most of these that divide them
 _STEP_HEADS = 8          # heads a grid step of the decode kernel takes
 
 
@@ -106,16 +109,39 @@ _NT = (((1,), (1,)), ((), ()))       # a @ b.T
 _TN = (((0,), (0,)), ((), ()))       # a.T @ b
 
 
+def _chunk_heads(H, D):
+    """The heads a grid step of the chunk kernel takes: the most of the list
+    that divide ``H``, where a head's lanes of a row are whole lane tiles —
+    of the shapes alone."""
+    return max(n for n in _CHUNK_HEADS
+               if n == 1 or (H % n == 0 and D % 128 == 0))
+
+
 def _chunk_kernel(meta, q_ref, k_ref, v_ref, g_ref, b_ref, s_in, o_ref,
-                  s_out, s_scr, g_scr, k_scr, x_scr):
+                  s_out, s_scr, g_scr, k_scr, x_scr, o_scr, p_scr):
     """``meta``: layer, state row, fresh (the state starts at zero), real
-    rows.  ``s_scr [d_k, d_v]``: the head's state between its blocks;
-    ``g_scr`` / ``k_scr [BLOCK, d_k]``: the block's ``G`` and keys, read a
-    row at a time; ``x_scr [BLOCK, d_v]``: the right-hand side that forward
-    substitution turns into ``V'``."""
-    h, c = pl.program_id(0), pl.program_id(1)
+    rows.  The row blocks hold ``heads`` heads side by side (``[BLOCK,
+    heads * d]``); inside, the heads are the LEADING axis of every value
+    (``[heads, rows, d]``), so each line below is every head's work at that
+    point of the block: heads share nothing, and one head's waits — a
+    product's six passes, a key row's lane sums, the substitution's chain —
+    are filled with another's work.  Only the products go a head at a time.
+    Scratch, ``[heads, ...]``: ``s_scr [d_k, d_v]`` the state between the
+    blocks; ``g_scr`` / ``k_scr [BLOCK, d_k]`` the block's ``G`` and keys,
+    read a row at a time; ``x_scr [BLOCK, d_v]`` the right-hand side that
+    forward substitution turns into ``V'``; ``o_scr [BLOCK, d_v]`` ``(q e^G)
+    S_in``; ``p_scr [BLOCK, BLOCK]`` the scores ``V'`` is read out through.
+    Values are re-read from the scratch where they are used: nothing
+    ``[BLOCK, d]`` is held across the body."""
+    n, c = pl.program_id(0), pl.program_id(1)
     C = q_ref.shape[0]
-    f32 = jnp.float32
+    heads, D = s_scr.shape[:2]
+    hs = range(heads)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    # rows of a [BLOCK, heads * d] block, the heads in front
+    of_heads = lambda ref, rows=slice(None): jnp.stack(
+        [ref[rows, h * D:(h + 1) * D] for h in hs])
+    a_head = lambda f, *xs: jnp.stack([f(*(x[h] for x in xs)) for h in hs])
 
     @pl.when(c == 0)
     def _():
@@ -127,55 +153,101 @@ def _chunk_kernel(meta, q_ref, k_ref, v_ref, g_ref, b_ref, s_in, o_ref,
 
     @pl.when(c * C < meta[3])
     def _():
-        rows = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-        real = c * C + rows < meta[3]
-        lanes = jax.lax.broadcasted_iota(jnp.int32, b_ref.shape, 1)
-        beta = jnp.sum(jnp.where(lanes == h, b_ref[...], 0.0), axis=1,
-                       keepdims=True)
-        beta = jnp.where(real, beta, 0.0)                       # [C, 1]
+        real = c * C + jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0) \
+            < meta[3]
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (SUB, b_ref.shape[1]), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (heads, 1, 1), 0)
         tri = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-               <= jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)).astype(f32)
-        G = _mm(tri, jnp.where(real, g_ref[...], 0.0))          # [C, d_k]
-        q, k, v = _f32(q_ref[...], k_ref[...], v_ref[...])
-        S = s_scr[...]
-        g_scr[...] = G
-        k_scr[...] = k
-        decay = jnp.exp(G)
-        x_scr[...] = beta * (v - _mm(k * decay, S))
+               <= jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)).astype(bf16)
         col = jax.lax.broadcasted_iota(jnp.int32, (SUB, C), 1)
         row = jax.lax.broadcasted_iota(jnp.int32, (SUB, 1), 0)
-        attend = []
-        for lo in range(0, C, SUB):
+
+        # G = tri @ g, float32: ``tri`` is exact in bfloat16, so of the six
+        # passes of the product the three over g's pieces are all there is
+        g, pieces = jnp.where(real, of_heads(g_ref), 0.0), []
+        for _ in range(3):
+            pieces.append(g.astype(bf16))
+            g = g - pieces[-1].astype(f32)
+        g_scr[...] = a_head(lambda *xs: sum(
+            jnp.dot(tri, x, preferred_element_type=f32)
+            for x in reversed(xs)), *pieces)                     # [C, d_k]
+        k_scr[...] = of_heads(k_ref).astype(f32)
+        decay = jnp.exp(g_scr[...])
+        both = a_head(_mm, jnp.concatenate(
+            [k_scr[...] * decay, of_heads(q_ref).astype(f32) * decay],
+            axis=1), s_scr)                      # one latch of S for the two
+        x_scr[...] = of_heads(v_ref).astype(f32) - both[:, :C]
+        o_scr[...] = both[:, C:]
+
+        def scores(lo):
+            """Sub-block ``lo`` against the keys up to its own, which needs
+            no ``V'``: its rows of ``p_scr``, and the coefficients its solve
+            takes — ``beta``, ``beta A`` over the earlier rows, the columns
+            of ``beta A`` inside the sub-block."""
             part = slice(lo, lo + SUB)
-            GI, kI, qI, bI = G[part], k[part], q[part], beta[part]
-            P = jnp.zeros((SUB, C), f32)
+            GI, kI = g_scr[:, part], k_scr[:, part]
+            qI = of_heads(q_ref, part).astype(f32)
+            bI = jnp.sum(jnp.where(lanes == n * heads + head,
+                                   jnp.where(real[part], b_ref[part], 0.0),
+                                   0.0), axis=2, keepdims=True)
+            # [heads, SUB, 1], lane-replicated as the sums below are
+            P, before = jnp.zeros((heads, SUB, C), f32), None
             if lo:
-                # the earlier sub-blocks' keys, through this one's first row
-                ref = g_scr[lo - 1:lo]
+                # the earlier sub-blocks' keys (rows [:lo]: the later ones
+                # would be masked), through this one's first row
+                ref = g_scr[:, lo - 1:lo]
                 since = jnp.exp(GI - ref)
-                off = _mm(jnp.concatenate([kI * since, qI * since]),
-                          k * jnp.exp(jnp.minimum(ref - G, 0.0)), _NT)
-                before = col < lo
-                x_scr[part] = x_scr[part] - _mm(
-                    jnp.where(before, bI * off[:SUB], 0.0), x_scr[...])
-                P = jnp.where(before, off[SUB:], 0.0)
+                off = a_head(
+                    functools.partial(_mm, dims=_NT),
+                    jnp.concatenate([kI * since, qI * since], axis=1),
+                    k_scr[:, :lo] * jnp.exp(jnp.minimum(
+                        ref - g_scr[:, :lo], 0.0)))
+                before = bI * off[:, :SUB]
+                P = jnp.concatenate(
+                    [off[:, SUB:], jnp.zeros((heads, SUB, C - lo), f32)],
+                    axis=2)
+            within = []
             for j in range(SUB):
                 # this sub-block's key row j against its rows i >= j
-                E = jnp.exp(jnp.minimum(GI - g_scr[lo + j:lo + j + 1], 0.0)) \
-                    * k_scr[lo + j:lo + j + 1]
+                at_j = slice(lo + j, lo + j + 1)
+                E = jnp.exp(jnp.minimum(GI - g_scr[:, at_j], 0.0)) \
+                    * k_scr[:, at_j]
                 P = jnp.where((col == lo + j) & (row >= j),
-                              jnp.sum(qI * E, axis=1, keepdims=True), P)
+                              jnp.sum(qI * E, axis=2, keepdims=True), P)
                 if j < SUB - 1:
-                    a = jnp.where(row > j, bI * jnp.sum(kI * E, axis=1,
-                                                        keepdims=True), 0.0)
-                    x_scr[part] = x_scr[part] - a * x_scr[lo + j:lo + j + 1]
-            attend.append(P)
-        Vp = x_scr[...]
-        o_ref[...] = (_mm(q * decay, S)
-                      + _mm(jnp.concatenate(attend), Vp)).astype(o_ref.dtype)
-        last = g_scr[C - 1:C]
-        s_scr[...] = _column(jnp.exp(last)) * S \
-            + _mm(k * jnp.exp(last - G), Vp, _TN)
+                    within.append(jnp.where(row > j, bI * jnp.sum(
+                        kI * E, axis=2, keepdims=True), 0.0))
+            p_scr[:, part] = P
+            return bI, before, within
+
+        def solve(lo, bI, before, within):
+            """Forward substitution over sub-block ``lo``: one product with
+            the rows before it, then a column at a time in registers."""
+            part = slice(lo, lo + SUB)
+            xI = bI * x_scr[:, part]
+            if lo:
+                xI = xI - a_head(_mm, before, x_scr[:, :lo])
+            for j, a in enumerate(within):
+                xI = xI - a * xI[:, j:j + 1]
+            x_scr[:, part] = xI
+
+        # a sub-block's scores go out with the solve BEFORE its own: they
+        # are lane sums and exponentials (XLU, EUP), the solves a chain of
+        # small products (MXU), and the schedule overlaps what it is handed
+        # side by side
+        coef = scores(0)
+        for lo in range(0, C, SUB):
+            solve(lo, *coef)
+            if lo + SUB < C:
+                coef = scores(lo + SUB)
+        last = g_scr[:, C - 1:C]
+        fade, keys = jnp.exp(last), k_scr[...] * jnp.exp(last - g_scr[...])
+        for h in hs:
+            o_ref[:, h * D:(h + 1) * D] = (
+                o_scr[h] + _mm(p_scr[h], x_scr[h])).astype(o_ref.dtype)
+        for h in hs:
+            s_scr[h] = _column(fade[h]) * s_scr[h] \
+                + _mm(keys[h], x_scr[h], _TN)
 
     @pl.when(c == pl.num_programs(1) - 1)
     def _():
@@ -185,22 +257,23 @@ def _chunk_kernel(meta, q_ref, k_ref, v_ref, g_ref, b_ref, s_in, o_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _chunk_pallas(q, k, v, g, beta, pool, meta, *, interpret):
     T, H, D = q.shape
+    heads = _chunk_heads(H, D)
     flat = lambda x: x.reshape(T, H * D)
-    head = pl.BlockSpec((BLOCK, D), lambda h, c, m: (c, h))
-    state = pl.BlockSpec((None, None, None, D, D),
-                         lambda h, c, m: (m[0], m[1], h, 0, 0))
+    head = pl.BlockSpec((BLOCK, heads * D), lambda n, c, m: (c, n))
+    state = pl.BlockSpec((None, None, heads, D, D),
+                         lambda n, c, m: (m[0], m[1], n, 0, 0))
+    block = lambda width: pltpu.VMEM((heads, BLOCK, width), jnp.float32)
     out, pool = pl.pallas_call(
         _chunk_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(H, T // BLOCK),
+            num_scalar_prefetch=1, grid=(H // heads, T // BLOCK),
             in_specs=[head, head, head, head,
-                      pl.BlockSpec((BLOCK, H), lambda h, c, m: (c, 0)),
+                      pl.BlockSpec((BLOCK, H), lambda n, c, m: (c, 0)),
                       state],
             out_specs=[head, state],
-            scratch_shapes=[pltpu.VMEM((D, D), jnp.float32),
-                            pltpu.VMEM((BLOCK, D), jnp.float32),
-                            pltpu.VMEM((BLOCK, D), jnp.float32),
-                            pltpu.VMEM((BLOCK, D), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((heads, D, D), jnp.float32),
+                            block(D), block(D), block(D), block(D),
+                            block(BLOCK)]),
         out_shape=[jax.ShapeDtypeStruct((T, H * D), v.dtype),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         input_output_aliases={6: 1},

@@ -100,28 +100,44 @@ def _recurrence(state, q, k, v, g, beta):
     return np.stack(out), S
 
 
+def _pool(heads=HEADS, d=D):
+    return jax.random.normal(jax.random.key(9), (2, 3, heads, d, d))
+
+
 @pytest.fixture(scope="module")
 def pool():
-    return jax.random.normal(jax.random.key(9), (2, 3, HEADS, D, D))
+    return _pool()
+
+
+# heads x width: the heads of it a grid step of the chunk kernel takes — the
+# toy's go one a step, 128-wide heads four, or two where four do not divide
+# them
+GROUPS = {"4x16": 1, "8x128": 4, "6x128": 2}
 
 
 @pytest.mark.parametrize("pallas", [True, False],
                          ids=["interpreted", "xla"])
-@pytest.mark.parametrize("T,real,fresh,strong", [
-    (150, 150, False, False),    # three blocks, the last a padded one
-    (150, 150, True, False),     # the row's old contents must not be read
-    (150, 70, False, False),     # a padded tail past the second block
-    (150, 3, False, False),      # a tail inside the first sub-block
-    (150, 150, False, True),     # decays no exp(-G) survives
+@pytest.mark.parametrize("T,real,fresh,strong,shape", [
+    (150, 150, False, False, "4x16"),  # three blocks, the last a padded one
+    (150, 150, True, False, "4x16"),   # the row's old contents are not read
+    (150, 70, False, False, "4x16"),   # a padded tail past the second block
+    (150, 3, False, False, "4x16"),    # a tail inside the first sub-block
+    (150, 150, False, True, "4x16"),   # decays no exp(-G) survives
+    (150, 150, False, False, "8x128"),   # four heads a grid step, two steps
+    (150, 70, True, True, "8x128"),
+    (150, 150, False, True, "6x128"),    # two a step: four do not divide six
 ])
-def test_chunk_scan_is_the_recurrence(pool, pallas, T, real, fresh, strong):
+def test_chunk_scan_is_the_recurrence(pallas, T, real, fresh, strong, shape):
     """A non-zero incoming state (or a fresh one over a dirty row), ``beta``
     past 1, blocks and sub-blocks crossed, a padded tail that leaves the
     state alone; only the call's own row of its own layer is written."""
-    q, k, v, g, beta = _draw(T, strong=strong)
+    heads, d = map(int, shape.split("x"))
+    assert delta._chunk_heads(heads, d) == GROUPS[shape]
+    pool = _pool(heads, d)
+    q, k, v, g, beta = _draw(T, strong=strong, heads=heads, d=d)
     out, new = delta.chunk_scan(q, k, v, g, beta, pool, 1, 2, fresh=fresh,
                                 real=real, pallas=pallas)
-    start = np.zeros((HEADS, D, D)) if fresh else np.asarray(pool[1, 2])
+    start = np.zeros((heads, d, d)) if fresh else np.asarray(pool[1, 2])
     want_o, want_s = _recurrence(start, *(x[:real] for x in (q, k, v, g,
                                                              beta)))
     assert np.abs(np.asarray(new[1, 2]) - want_s).max() < 2e-5
@@ -130,7 +146,30 @@ def test_chunk_scan_is_the_recurrence(pool, pallas, T, real, fresh, strong):
     untouched = np.ones(pool.shape[:2], bool)
     untouched[1, 2] = False
     assert (np.asarray(new)[untouched] == np.asarray(pool)[untouched]).all()
-    assert out.shape == (T, HEADS, D) and out.dtype == jnp.bfloat16
+    assert out.shape == (T, heads, d) and out.dtype == jnp.bfloat16
+
+
+def test_a_head_is_the_same_wherever_its_group_puts_it():
+    """Eight heads of 128 go four a grid step.  Shuffled — every head in
+    another group or another place of its group — and shuffled back, each
+    head's output rows and state are BITWISE what they were: the heads of
+    a step share its masks and nothing else."""
+    heads, d = 8, 128
+    assert delta._chunk_heads(heads, d) == 4
+    pool = _pool(heads, d)
+    q, k, v, g, beta = _draw(100, seed=5, heads=heads, d=d)
+    order = np.asarray([5, 2, 7, 0, 3, 6, 1, 4])    # head order[i] in place i
+    assert all(i // 4 != j // 4 or i % 4 != j % 4
+               for i, j in enumerate(order))
+    out, new = delta.chunk_scan(q, k, v, g, beta, pool, 0, 1, fresh=False,
+                                real=90)
+    shuffled = lambda x, axis: jnp.take(x, order, axis=axis)
+    out_s, new_s = delta.chunk_scan(
+        *(shuffled(x, 1) for x in (q, k, v, g, beta)), shuffled(pool, 2),
+        0, 1, fresh=False, real=90)
+    assert (np.asarray(out_s, np.float32)
+            == np.asarray(shuffled(out, 1), np.float32)).all()
+    assert (np.asarray(new_s[0, 1]) == np.asarray(new[0, 1])[order]).all()
 
 
 def test_unequal_chunks_hand_the_state_on(pool):

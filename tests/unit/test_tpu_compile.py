@@ -373,6 +373,20 @@ _MLA_FLASH = ("dots3_mla_chunk_prefill_c2048", "dots3_mla_window_c2048",
               "glm5_mla_chunk_prefill_c1024")
 
 
+def _pallas_calls(fn, shapes):
+    """The ``pallas_call`` equations of ``fn`` traced at ``shapes``."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for value in eqn.params.values():
+                inner = getattr(value, "jaxpr", value)
+                if hasattr(inner, "eqns"):
+                    yield from walk(inner)
+    return list(walk(jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(s, d) for s, d in shapes)).jaxpr))
+
+
 def _compile(fn, shapes, one_chip):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     return jax.jit(fn).lower(*args).compile()
@@ -898,6 +912,24 @@ def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
     text = compiled.as_text()
     assert "tpu_custom_call" in text, \
         f"{case}: no Mosaic kernel in the compiled program"
+    if case == "solar_kda_chunk_scan_c2048":
+        # what a grid step takes: FOUR of the 64 heads' 64-row blocks, their
+        # rows side by side (a quarter of the grid steps a head a step
+        # made), in ONE kernel of this name; the pool aliased in -> out
+        call, = _pallas_calls(fn, shapes)
+        grid, heads = call.params["grid_mapping"], delta_mod._chunk_heads(
+            64, 128)
+        blocks = [tuple(x.block_size for x in b.block_shape
+                        if hasattr(x, "block_size"))
+                  for b in grid.block_mappings]
+        assert heads == 4 and grid.grid == (64 // heads, 2048 // 64)
+        assert blocks == [(64, heads * 128)] * 4 + [
+            (64, 64), (heads, 128, 128), (64, heads * 128),
+            (heads, 128, 128)], blocks
+        assert call.params["name"] == "kda.chunk_scan" in text
+        # operand 6 (after the scalars and the five row arrays): the pool
+        assert call.params["input_output_aliases"] == ((6, 1),)
+        assert "output_to_operand_aliasing={{1}: (6, {})}" in text
     if case in _FLASH_TRAINED:
         # the backward of one call is ONE Mosaic kernel beside the
         # forward's: the head's float32 dq sum fits the VMEM a kernel gets

@@ -363,19 +363,20 @@ def alibi_bias(n_heads, kv_len):
 
 
 def reference_attention(q, k, v, causal=True, mask=None, bias=None,
-                        window=None):
+                        window=None, scale=None):
     """jnp attention used as the CPU fallback and the golden reference for
     the Pallas kernel tests.  q,k,v: [B, S, H, D] / [B, S, KVH, D];
     ``bias``: optional [H, T] additive logit bias (ALiBi); ``window``:
     optional band width (gpt-neo local attention — attend to the trailing
-    ``window`` positions only)."""
+    ``window`` positions only); ``scale``: what the scores are multiplied
+    by (default ``D ** -0.5``)."""
     B, S, H, D = q.shape
     KVH = k.shape[2]
     if KVH != H:
         rep = H // KVH
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    scale = 1.0 / np.sqrt(D)
+    scale = 1.0 / np.sqrt(D) if scale is None else scale
     logits = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
     if bias is not None:
         logits = logits + bias[None, :, None, :].astype(jnp.float32)

@@ -283,9 +283,10 @@ def _fused_decode(cfg, q, k, v, positions, cache, mode, window):
         res = paged_decode_attention(
             q[:, 0], cache["k"], cache["v"], lengths, cache["pages"],
             layer=cache["layer"], k_scale=cache.get("k_scale"),
-            v_scale=cache.get("v_scale"), new_k=k[:, 0], new_v=v[:, 0])
+            v_scale=cache.get("v_scale"), new_k=k[:, 0], new_v=v[:, 0],
+            **_stated_scale(cfg))
     elif mode == "pallas_decode":
-        if cache["k"].shape[-2] % 8 != 0:
+        if cache["k"].shape[-2] % 8 != 0 or _stated_scale(cfg):
             # odd cache lengths (hand-allocated test caches) take the
             # unfused path (required_cache_len rounds engine workspaces
             # to a multiple of 8)
@@ -308,10 +309,25 @@ def _fused_decode(cfg, q, k, v, positions, cache, mode, window):
     return out_f[:, None], {**data, **_cache_markers(cache)}
 
 
+def _stated_scale(cfg):
+    """``{"scale": s}`` where the config STATES the softmax's scale
+    (``attention_scale``: a model whose scores are not over ``sqrt(d)``),
+    else nothing: the kernels' own default, and the programs of every
+    config without the field, stay as they are."""
+    scale = getattr(cfg, "attention_scale", None)
+    return {} if scale is None else {"scale": scale}
+
+
 def _attend(cfg, mode, q, cache, positions, bias, window):
     """The attend half, through the selected kernel mode."""
     from deepspeed_tpu.models.transformer import (_paged_gather,
                                                   cached_attention)
+    if _stated_scale(cfg) and not (
+            "pages" in cache and mode in ("pallas_paged_decode",
+                                          "pallas_chunked_prefill")):
+        # the plain paths divide by sqrt(d): hand them q times what is left
+        q = (q.astype(jnp.float32) * (cfg.attention_scale
+                                      * q.shape[-1] ** 0.5)).astype(q.dtype)
     if "pages" in cache:
         if mode == "pallas_paged_decode":
             from deepspeed_tpu.ops.transformer.paged_attention import (
@@ -321,7 +337,8 @@ def _attend(cfg, mode, q, cache, positions, bias, window):
                 q[:, 0], cache["k"], cache["v"], lengths, cache["pages"],
                 layer=cache["layer"], k_scale=cache.get("k_scale"),
                 v_scale=cache.get("v_scale"),
-                int8_matmuls=cfg.decode_int8_matmuls)[:, None]
+                int8_matmuls=cfg.decode_int8_matmuls,
+                **_stated_scale(cfg))[:, None]
         if mode == "pallas_chunked_prefill":
             from deepspeed_tpu.ops.transformer.paged_attention import (
                 paged_chunk_prefill_attention)
@@ -340,7 +357,7 @@ def _attend(cfg, mode, q, cache, positions, bias, window):
             return paged_chunk_prefill_attention(
                 q, cache["k"], cache["v"], starts, pages,
                 layer=cache["layer"], k_scale=cache.get("k_scale"),
-                v_scale=cache.get("v_scale")).reshape(
+                v_scale=cache.get("v_scale"), **_stated_scale(cfg)).reshape(
                     (B_, S_) + q.shape[2:])
         # reference/gather fallback — the pre-kernel paged path: one
         # take_along_axis virtual-view copy per layer, then whatever
@@ -530,3 +547,26 @@ def delta_state_update(q, k, v, g, beta, state, *, start=None, real=None,
     return delta_attention.chunk_scan(
         q, k, v, g, beta, pool, layer, rows, fresh=start == 0,
         real=q.shape[0] if real is None else real, pallas=pallas)
+
+
+# ---- a matrix state a slot: a state-space layer's scan ------------------- #
+def ssm_state_update(x, dt, a, b, c, state, *, start=None, real=None,
+                     live=None):
+    """Run a Mamba-2 layer's positions through its MATRIX STATE and leave
+    the state in the pool: ``state = (pool [layers, rows, H, P, N]``
+    float32``, layer, rows)``.  A chunk (``start`` a scalar): ``x [T, H,
+    P]``, ``dt`` / ``a [T, H]`` (step size, log-decay), ``b`` / ``c [T, N]``
+    consecutive positions of ONE slot, ``rows`` its state row, from zeros
+    where ``start == 0`` and through the chunk's ``real`` rows (None: all).
+    A step (``start`` None): row ``n`` is lane ``n``'s one token, ``rows
+    [N]``, dead lanes (``live [N]``) write nothing.  Returns ``(y, pool)``
+    — ``y = S C``, without the layer's skip."""
+    from deepspeed_tpu.ops.transformer import ssd
+    pool, layer, rows = state
+    pallas = pallas_supported()
+    if start is None:
+        return ssd.decode_step(x, dt, a, b, c, pool, layer, rows, live,
+                               pallas=pallas)
+    return ssd.chunk_scan(
+        x, dt, a, b, c, pool, layer, rows, fresh=start == 0,
+        real=x.shape[0] if real is None else real, pallas=pallas)

@@ -290,7 +290,7 @@ def _scope_to_path(op_name):
 # Device time by part (docs/observability.md "Device time by part")
 # --------------------------------------------------------------------- #
 PARTS = ("embed", "attn.proj", "attn.core", "attn.mla_decompress",
-         "attn.eva", "attn.kda", "eva.summarise", "cache.write", "mlp", "moe.route",
+         "attn.eva", "attn.kda", "attn.ssd", "eva.summarise", "cache.write", "mlp", "moe.route",
          "moe.experts", "conv.short", "norm", "residual", "head", "loss",
          "optim", "scan.stack", "slots", "mtp.combine", "comm",
          "xla.prefetch")
@@ -362,6 +362,18 @@ SCOPE_PARTS = (
     (r"attn\.kda|kda\.(scan|out_gate)|\w*kda\.(chunk_scan|decode_step)\w*",
      "attn.kda"),
     (r"(f|g)_(a|b)_proj|b_proj|o_norm|linear_attn(\.\w+)?", "attn.kda"),
+    # Mamba-2 state-space layers on a matrix state a slot
+    # (``models/granite_hybrid.py``): the mixer's scopes, its two state
+    # kernels by their ``name=``, and what its module holds outside them —
+    # the discretisation, the skip, the gate and its one norm.  (``in_proj``
+    # / ``out_proj`` are ``attn.proj`` and its convolution ``conv.short``
+    # like any other's; the scope ``attn.ssd`` covers them all for a reader
+    # that wants the mixer whole)
+    (r"attn\.ssd/conv\.short", "conv.short"),
+    (r"attn\.ssd/in_proj", "attn.proj"),
+    (r"attn\.ssd|ssd\.(scan|gate_norm)|\w*ssd\.(chunk_scan|decode_step)\w*",
+     "attn.ssd"),
+    (r"mamba(\.\w+)?", "attn.ssd"),
     # the layer scan's own operations (``scan_layers``): a layer's slice
     # out of the stacked parameters and saved residuals, the saves' and the
     # gradients' writes back into the stacks, the stacks' zeros and copies

@@ -14,7 +14,8 @@ from deepspeed_tpu.ops.sparse_attention import block_sparse
 from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
                                            delta_attention as delta_mod,
                                            flash_attention as flash_mod,
-                                           paged_attention as paged_mod)
+                                           paged_attention as paged_mod,
+                                           ssd as ssd_mod)
 
 H, D, L = 2, 64, 2
 HD = H * D
@@ -87,7 +88,25 @@ def _kda(chunk):
     return fn, [rows] * 4 + [((n, H), F32), pool, ((n,), I32)]
 
 
+def _ssd(chunk):
+    """The two state kernels of the Mamba-2 scan over a pool ``[layers,
+    rows, ...state_shape]``."""
+    pool, n = ((L, 3) + ssd_mod.state_shape(H, 64, 16), F32), \
+        128 if chunk else 2
+    rows = [((n, H, 64), F32), ((n, H), F32), ((n, H), F32),
+            ((n, 16), F32), ((n, 16), F32)]
+
+    def fn(x, dt, a, b, c, pool, at):
+        if chunk:
+            return ssd_mod.chunk_scan(x, dt, a, b, c, pool, 1, at[0],
+                                      fresh=False, real=n)
+        return ssd_mod.decode_step(x, dt, a, b, c, pool, 1, at)
+    return fn, rows + [pool, ((n,), I32)]
+
+
 CASES = {
+    "ssd.chunk_scan": lambda: _ssd(True),
+    "ssd.decode_step": lambda: _ssd(False),
     "kda.chunk_scan": lambda: _kda(True),
     "kda.decode_step": lambda: _kda(False),
     "attn.paged_decode": _paged_decode,

@@ -281,6 +281,36 @@ D = "jit(decode_block)/while/body/closed_call/"
      "moe.experts", "fwd"),
     (D + "SolarOpen2Model.decode/SolarOpen2Model._head/head.logits/lm_head/"
      "dot_general", "head", "fwd"),
+    # Mamba-2 state-space layers on a matrix state a slot: the mixer's own
+    # rows, its two kernels, and what stays where it always was
+    (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/ssd.scan/"
+     "softplus", "attn.ssd", "fwd"),
+    (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/ssd.scan/"
+     "ssd.decode_step", "attn.ssd", "fwd"),
+    ("jit(chunk_step)/GraniteHybridModel.decode/layers_2/mamba/attn.ssd/"
+     "ssd.scan/ssd.chunk_scan", "attn.ssd", "fwd"),
+    (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/ssd.gate_norm/"
+     "mul", "attn.ssd", "fwd"),
+    (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/split",
+     "attn.ssd", "fwd"),
+    (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/conv.short/"
+     "mul", "conv.short", "fwd"),
+    (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/in_proj/"
+     "dot_general", "attn.proj", "fwd"),
+    (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/out_proj/"
+     "dot_general", "attn.proj", "fwd"),
+    (D + "GraniteHybridModel.decode/layers_5/self_attn/attn.full/"
+     "attn.paged_decode", "attn.core", "fwd"),
+    (D + "GraniteHybridModel.decode/layers_3/moe_mlp/moe.experts_gmm",
+     "moe.experts", "fwd"),
+    (D + "GraniteHybridModel.decode/layers_3/mul", "residual", "fwd"),
+    (D + "GraniteHybridModel.decode/GraniteHybridModel._embed/embed.scale/"
+     "mul", "embed", "fwd"),
+    (D + "GraniteHybridModel.decode/GraniteHybridModel._head/head.logits/"
+     "div", "head", "fwd"),
+    # LFM2's projections beside its convolution keep their row
+    (D + "Lfm2Model.decode/layers_0/conv/in_proj/dot_general", "conv.short",
+     "fwd"),
     # nothing the table knows: unattributed
     ("jit(train_step)/mul", None, "fwd"),
     ("jit(train_step)/transpose(jvp(Transformer))/broadcast_in_dim", None,
@@ -298,7 +328,7 @@ def test_part_and_phase_of_an_op_name(op_name, part, phase):
 def test_the_table_is_a_fixed_literal_set_of_known_parts():
     assert {part for _, part in profiler.SCOPE_PARTS} <= set(profiler.PARTS)
     # never a size or an index in a scope name the programs add
-    for rx, _ in profiler.SCOPE_PARTS[:26]:
+    for rx, _ in profiler.SCOPE_PARTS[:30]:
         assert not any(ch.isdigit() for ch in rx)
 
 

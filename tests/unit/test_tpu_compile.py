@@ -28,7 +28,8 @@ from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
                                            eva_attention as eva_mod,
                                            flash_attention as flash_mod,
                                            latent_attention as latent_mod,
-                                           paged_attention as paged_mod)
+                                           paged_attention as paged_mod,
+                                           ssd as ssd_mod)
 
 H, D, L = 32, 64, 24            # OPT-1.3B (models/opt.py)
 HD = H * D
@@ -68,7 +69,7 @@ def mosaic(monkeypatch, no_persistent_cache):
     """``_interpret()`` asks ``jax.default_backend()``, which is the CPU
     here: steer it in the test, not through an option of the program."""
     for mod in (flash_mod, decode_mod, paged_mod, block_sparse, moe_mod,
-                latent_mod, eva_mod, delta_mod):
+                latent_mod, eva_mod, delta_mod, ssd_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -314,7 +315,31 @@ def _kda_chunk_scan(chunk=2048, heads=64, dim=128, slots=64, layers=3):
     return fn, args
 
 
+def _ssd(chunk, heads=128, dim=64, states=128, slots=176, layers=9):
+    """One Mamba-2 layer of ``granite-serve-chatgen-batch``: a 512-row chunk
+    through ``ssd.chunk_scan``, or one token of each of the 176 lanes
+    through ``ssd.decode_step``, over the cell's float32 state pool (177
+    rows x 9 layers x 4 MiB), the state rows in and out."""
+    n = chunk or slots
+    pool = ((layers, 1 + slots) + ssd_mod.state_shape(heads, dim, states),
+            F32)
+    args = [((n, heads, dim), BF16), ((n, heads), F32), ((n, heads), F32),
+            ((n, states), BF16), ((n, states), BF16), pool,
+            ((n,), I32), ((), I32)]
+
+    def fn(x, dt, a, b, c, pool, rows, start):
+        if chunk:
+            return ssd_mod.chunk_scan(x, dt, a, b, c, pool, layers - 1,
+                                      rows[0], fresh=start == 0,
+                                      real=chunk - 5)
+        return ssd_mod.decode_step(x, dt, a, b, c, pool, layers - 1, rows,
+                                   rows > 0)
+    return fn, args
+
+
 CASES = {
+    "granite_ssd_chunk_scan_c512": lambda: _ssd(512),
+    "granite_ssd_decode_step_176": lambda: _ssd(0),
     "solar_kda_chunk_scan_c2048": _kda_chunk_scan,
     "evabyte_eva_decode_24x46": _eva_decode,
     "evabyte_eva_chunk_c512": lambda: _eva_chunk(512),
@@ -905,6 +930,74 @@ def test_solar_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     assert 11.5e9 < total < 15.5e9, f"{total / 1e9:.2f} GB"
 
 
+@pytest.mark.parametrize("program", ["chunk_step", "decode_block"])
+def test_granite_slot_programs_compile_at_the_cells_sizes(program, one_chip,
+                                                          mosaic):
+    """The two programs ``granite-serve-chatgen-batch`` runs, whole, as
+    ``serving/slots.py`` builds them at the cell's own settings: nine
+    Mamba-2 layers over a float32 matrix state and a bfloat16 conv state a
+    slot — ``ssd.chunk_scan`` / ``ssd.decode_step`` with the state pool
+    aliased in and out —, one NoPE softmax layer over K/V lane pages at
+    scale 1/128, ten expert layers of 18 held experts under a 72-wide
+    router, a tied 25,088-wide head.  The state pool (6.8 GB at 176 slots)
+    is larger than the weights (5.9 GB): ONE un-aliased copy of it in either
+    program and the cell does not fit — every pool aliased input -> output,
+    weights + pools + the programs' temporaries inside one chip; and every
+    instruction under the model's call lies in a part of the profiler's
+    table."""
+    from deepspeed_tpu.inference.serving import slots
+    from deepspeed_tpu.inference.serving.paging import SlotPages
+    from deepspeed_tpu.profiling.flops_profiler import profiler
+    c = _slot_programs_of("granite-serve-chatgen-batch", "granite_hybrid",
+                          one_chip)
+    module, s, chunk = c.module, c.serving, c.chunk
+    params, ints, on_chip = c.params, c.ints, c.on_chip
+    pages = SlotPages(module, c.declared, s["num_slots"], s["max_cache_len"],
+                      s["page_size"], s["num_pages"], chunk, False, {})
+    pool = on_chip(jax.eval_shape(lambda: pages.new_pools(BF16)))
+    assert (pages.pages_per_slot, pages.state_rows, pages.table_width) \
+        == (45, 177, 46)
+    assert {k: (v.shape, str(v.dtype)) for k, v in pool.items()} == {
+        "k": ((1, s["num_pages"], 64, 1024), "bfloat16"),
+        "v": ((1, s["num_pages"], 64, 1024), "bfloat16"),
+        "conv": ((9, 177, 3 * 8448), "bfloat16"),
+        "ssm": ((9, 177, 64, 128, 128), "float32")}
+    assert pages.state_kind_bytes == {"conv": 9 * 50688,
+                                      "ssm": 9 * 4 * 2 ** 20}
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    weights = sum(x.size * 2 for x in jax.tree.leaves(params))
+    assert pool["ssm"].size * 4 > weights > 5.9e9       # state over weights
+    if program == "chunk_step":
+        compiled = slots.make_chunk_fn(module, c.declared, None).lower(
+            params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
+            ints(1)).compile()
+    else:
+        n = s["num_slots"]
+        state = on_chip({k: jnp.asarray(v) for k, v in
+                         slots.init_slot_state(n).items()})
+        rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                   sharding=one_chip)
+        compiled = slots.make_decode_block_fn(
+            module, c.declared, lambda logits, rng: jnp.argmax(logits, -1),
+            None, s["decode_block"], pages.cache_len).lower(
+                params, pool, state, ints(n, pages.table_width),
+                rng).compile()
+    text = compiled.as_text()
+    # a paged chunk / paged decode, a state scan / step a Mamba layer, the
+    # experts of ten layers
+    assert text.count("tpu_custom_call") >= 1 + 9 + 10
+    assert ("ssd.chunk_scan" if program == "chunk_step"
+            else "ssd.decode_step") in text
+    named = set(re.findall(
+        r'op_name="([^"]*GraniteHybridModel\.decode[^"]*)"', text))
+    assert named and not [n for n in named if profiler.part_of(n)[0] is None]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 13.4e9 < total < 15.5e9, f"{total / 1e9:.2f} GB"
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
     fn, shapes = CASES[case]()
@@ -930,6 +1023,17 @@ def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
         # operand 6 (after the scalars and the five row arrays): the pool
         assert call.params["input_output_aliases"] == ((6, 1),)
         assert "output_to_operand_aliasing={{1}: (6, {})}" in text
+    if case.startswith("granite_ssd_"):
+        # ONE kernel of the name, the pool (operand 7, after the scalars and
+        # the row arrays) aliased in -> out: no copy of its 6.8 GB
+        call, = _pallas_calls(fn, shapes)
+        name = "ssd.chunk_scan" if "chunk" in case else "ssd.decode_step"
+        assert call.params["name"] == name in text
+        assert call.params["input_output_aliases"] == ((7, 1),)
+        assert "output_to_operand_aliasing={{1}: (7, {})}" in text
+        grid = call.params["grid_mapping"].grid
+        assert grid == ((128 // 8, 512 // 128) if "chunk" in case
+                        else (176, 64 // 16))
     if case in _FLASH_TRAINED:
         # the backward of one call is ONE Mosaic kernel beside the
         # forward's: the head's float32 dq sum fits the VMEM a kernel gets

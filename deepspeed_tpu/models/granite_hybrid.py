@@ -55,7 +55,6 @@ import flax.linen as nn
 from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.models.latent_attention import _rms, causal_pairs
 from deepspeed_tpu.models.latent_block import _Norm
-from deepspeed_tpu.models.lfm2 import chunk_taps, step_taps
 from deepspeed_tpu.models.transformer import reference_attention
 from deepspeed_tpu.moe.layer import MoE
 
@@ -177,10 +176,10 @@ def granite_hybrid_model(hf, held_experts=None, **overrides):
 
 class Mamba2Mixer(nn.Module):
     """The state-space mixer.  ``state`` is ``None`` (a sequence from its
-    start, nothing kept) or ``(conv pool [SSM layers, rows, (taps - 1) x conv
-    width], ssm pool [SSM layers, rows, ...state_shape], layer index in the
-    pools, rows)`` — ``rows [N]`` for one token a lane, a scalar row
-    for a chunk of one slot."""
+    start, nothing kept) or ``(conv pool [SSM layers, rows,
+    ...short_conv.rows_shape], ssm pool [SSM layers, rows, ...state_shape],
+    layer index in the pools, rows)`` — ``rows [N]`` for one token a lane, a
+    scalar row for a chunk of one slot."""
     config: GraniteHybridConfig
 
     @nn.compact
@@ -191,7 +190,8 @@ class Mamba2Mixer(nn.Module):
         A step (``start`` None, ``state`` given): row ``n`` is lane ``n``'s
         one token, ``live [N]`` the lanes that are.  Returns ``(out, conv
         pool, ssm pool)``."""
-        from deepspeed_tpu.ops.transformer.registry import ssm_state_update
+        from deepspeed_tpu.ops.transformer.registry import (
+            conv_state_update, ssm_state_update)
         from deepspeed_tpu.ops.transformer.ssd import state_shape
         cfg = self.config
         H, P, N, K = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state, \
@@ -212,14 +212,10 @@ class Mamba2Mixer(nn.Module):
             if state is not None:
                 conv_pool, ssm_pool, at, rows = state
             with jax.named_scope("conv.short"):
-                before = jnp.zeros(((K - 1) * CW,), xbc.dtype) \
-                    if state is None else conv_pool[at, rows]
-                if start is None:
-                    conv, kept = step_taps(before, xbc, w)
-                else:
-                    conv, kept = chunk_taps(before, xbc, w, start, last)
-                if state is not None:
-                    conv_pool = conv_pool.at[at, rows].set(kept)
+                conv, conv_pool = conv_state_update(
+                    xbc, w,
+                    None if state is None else (conv_pool, at, rows),
+                    start=start, last=last)
                 xbc = nn.silu(conv + conv_bias).astype(cfg.jnp_dtype)
             dt_bias, a_log = vector("dt_bias", H), vector("A_log", H)
             skip = vector("D", H, nn.initializers.ones)
@@ -414,11 +410,17 @@ class GraniteHybridModel(nn.Module):
         """``k`` / ``v [attention layers, num_pages, page, KV heads x
         head_dim]`` behind the slot's page table, and behind its state row
         (``paging.SlotPages`` sizes both: trash + one row a slot) ``conv
-        [SSM layers, state_rows, (taps - 1) x conv width]`` in ``dtype`` and
-        ``ssm [SSM layers, state_rows, ...]`` — a row the heads' ``[d_head,
-        d_state]`` states as ``ops/transformer/ssd.py::state_shape`` lays
-        them — in FLOAT32 whatever ``dtype`` is: the state is summed into
-        over the whole context."""
+        [SSM layers, state_rows, R, 128]`` in ``dtype`` — a row's ``(taps -
+        1) x conv width`` values as whole tiles under the row's index
+        (``ops/transformer/short_conv.py::rows_shape``: 198 x 128 values on
+        208 sublanes here) — and ``ssm [SSM layers, state_rows, ...]`` — a
+        row the heads' ``[d_head, d_state]`` states as
+        ``ops/transformer/ssd.py::state_shape`` lays them — in FLOAT32
+        whatever ``dtype`` is: the state is summed into over the whole
+        context.  In both the row's index is a LEADING dimension: XLA tiles
+        the last two, and a row that is a sublane of its tiles is written
+        back a masked store a tile."""
+        from deepspeed_tpu.ops.transformer.short_conv import rows_shape
         from deepspeed_tpu.ops.transformer.ssd import state_shape
         cfg = self.config
         dtype = dtype or cfg.jnp_dtype
@@ -426,9 +428,8 @@ class GraniteHybridModel(nn.Module):
         kv = (len(cfg.layers_of("attention")), int(num_pages),
               int(page_size), cfg.num_kv_heads * cfg.head_dim)
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
-                "conv": jnp.zeros((mamba, int(state_rows),
-                                   (cfg.conv_size - 1) * cfg.conv_width),
-                                  dtype),
+                "conv": jnp.zeros((mamba, int(state_rows)) + rows_shape(
+                    cfg.conv_size, cfg.conv_width, dtype), dtype),
                 "ssm": jnp.zeros((mamba, int(state_rows)) + state_shape(
                     cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state),
                     jnp.float32)}

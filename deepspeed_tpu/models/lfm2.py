@@ -120,40 +120,11 @@ def lfm2_model(hf, **overrides):
     return Lfm2Model(lfm2_config(hf, **overrides))
 
 
-def step_taps(before, z, w):
-    """A causal depthwise convolution's step, one token a lane: ``before
-    [N, (K - 1) x h]`` each lane's last rows, ``z [N, h]`` its token's,
-    ``w [K, h]`` float32 (tap ``j`` weighs row ``t - K + 1 + j``).  Returns
-    ``(conv [N, h]`` float32``, the rows to keep [N, (K - 1) x h])``."""
-    K, h = w.shape
-    taps = jnp.concatenate([before, z], axis=-1).reshape(-1, K, h)
-    conv = jnp.sum(taps.astype(jnp.float32) * w, axis=1)
-    return conv, taps[:, 1:].reshape(-1, (K - 1) * h)
-
-
-def chunk_taps(before, z, w, start, last=None):
-    """The same over ``T`` consecutive rows ``z [T, h]`` of ONE sequence
-    from position ``start``: a request's first chunk starts from zeros,
-    whatever its slot's last occupant left in ``before [(K - 1) x h]``, and
-    the rows to keep are those that end at ``last``, the chunk's last real
-    row (the padded tail's never reach the state).  Returns ``(conv [T,
-    h]`` float32``, kept [(K - 1) x h])``."""
-    K, h = w.shape
-    before = jnp.where(start == 0, 0, before.reshape(K - 1, h))
-    zz = jnp.concatenate([before.astype(z.dtype), z])          # [T+K-1, h]
-    T = z.shape[0]
-    conv = sum(zz[j:j + T].astype(jnp.float32) * w[j] for j in range(K))
-    # rows (last - K + 2 .. last) of z: zz is ahead by K - 1
-    last = T - 1 if last is None else last
-    keep = jax.lax.dynamic_slice_in_dim(zz, last + 1, K - 1)
-    return conv, keep.reshape(-1)
-
-
 class ShortConv(nn.Module):
     """The gated short convolution.  ``state`` is ``None`` (a sequence
-    from its start, nothing kept) or ``(pool [conv layers, rows, (K - 1)
-    x hidden], layer index in the pool, rows)`` — ``rows [N]`` for one
-    token a lane, a scalar row for a chunk of one slot."""
+    from its start, nothing kept) or ``(pool [conv layers, rows,
+    ...short_conv.rows_shape], layer index in the pool, rows)`` — ``rows
+    [N]`` for one token a lane, a scalar row for a chunk of one slot."""
     config: Lfm2Config
 
     @nn.compact
@@ -163,6 +134,7 @@ class ShortConv(nn.Module):
         ``last`` is its last real row (the padded tail's ``z`` never
         reaches the state).  A step (``start`` None, ``state`` given): row
         ``n`` is lane ``n``'s one token.  Returns ``(out, pool)``."""
+        from deepspeed_tpu.ops.transformer.registry import conv_state_update
         cfg = self.config
         h, K = cfg.hidden_size, cfg.conv_L_cache
         dense = lambda n, name: nn.Dense(n, use_bias=False,
@@ -171,21 +143,9 @@ class ShortConv(nn.Module):
                        (K, h), jnp.float32)       # tap j weighs z_{t-K+1+j}
         b, c, x = jnp.split(dense(3 * h, "in_proj")(u), 3, axis=-1)
         with jax.named_scope("conv.short"):
-            z = b * x                                          # [T, h]
-            pool = None
-            if state is None:
-                before = jnp.zeros((K - 1, h), z.dtype)
-            else:
-                pool, at, rows = state
-                before = pool[at, rows]                # [N, (K-1) h] | [(K-1) h]
-            if start is None:
-                conv, kept = step_taps(before, z, w)
-                pool = pool.at[at, rows].set(kept)
-            else:
-                conv, kept = chunk_taps(before, z, w, start, last)
-                if pool is not None:
-                    pool = pool.at[at, rows].set(kept)
-            y = c * conv.astype(z.dtype)
+            conv, pool = conv_state_update(b * x, w, state, start=start,
+                                           last=last)
+            y = c * conv.astype(x.dtype)
         return dense(h, "out_proj")(y), pool
 
 
@@ -308,16 +268,23 @@ class Lfm2Model(nn.Module):
                          state_rows=1):
         """``k`` / ``v [attention layers, num_pages, page, KV heads x
         head_dim]`` behind the slot's page table, and ``conv [conv layers,
-        state_rows, (conv_L_cache - 1) x hidden]`` behind its state row
-        (``paging.SlotPages`` sizes it: trash + one row a slot)."""
+        state_rows, R, 128]`` behind its state row (``paging.SlotPages``
+        sizes it: trash + one row a slot): a row's ``(conv_L_cache - 1) x
+        hidden`` values as whole tiles under the row's index
+        (``ops/transformer/short_conv.py::rows_shape``).  The index is a
+        LEADING dimension because XLA tiles the last two: were it one of
+        them, a slot's row would be a sublane of every tile it touches and
+        a step's write-back a masked store a tile."""
+        from deepspeed_tpu.ops.transformer.short_conv import rows_shape
         cfg = self.config
         dtype = dtype or cfg.jnp_dtype
         kv = (len(cfg.layers_of("full_attention")), int(num_pages),
               int(page_size), cfg.num_kv_heads * cfg.head_dim)
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
                 "conv": jnp.zeros(
-                    (len(cfg.layers_of("conv")), int(state_rows),
-                     (cfg.conv_L_cache - 1) * cfg.hidden_size), dtype)}
+                    (len(cfg.layers_of("conv")), int(state_rows))
+                    + rows_shape(cfg.conv_L_cache, cfg.hidden_size, dtype),
+                    dtype)}
 
     def decode(self, input_ids, cache, start_pos, logits_at=None, live=None):
         """The slot programs' call: a prefill chunk of one slot

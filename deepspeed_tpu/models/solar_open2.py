@@ -50,7 +50,6 @@ import flax.linen as nn
 from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.models.latent_attention import _rms, causal_pairs
 from deepspeed_tpu.models.latent_block import _Norm
-from deepspeed_tpu.models.lfm2 import chunk_taps, step_taps
 from deepspeed_tpu.models.transformer import reference_attention
 from deepspeed_tpu.moe.layer import MoE
 
@@ -155,10 +154,10 @@ def solar_open2_model(hf, held_experts=None, **overrides):
 
 class KimiDeltaAttention(nn.Module):
     """The gated delta-rule mixer.  ``state`` is ``None`` (a sequence from
-    its start, nothing kept) or ``(conv pool [KDA layers, rows, (taps - 1)
-    x 3 x width], kda pool [KDA layers, rows, heads, d, d], layer index in
-    the pools, rows)`` — ``rows [N]`` for one token a lane, a scalar row for
-    a chunk of one slot."""
+    its start, nothing kept) or ``(conv pool [KDA layers, rows,
+    ...short_conv.rows_shape], kda pool [KDA layers, rows, heads, d, d],
+    layer index in the pools, rows)`` — ``rows [N]`` for one token a lane, a
+    scalar row for a chunk of one slot."""
     config: SolarOpen2Config
 
     @nn.compact
@@ -169,7 +168,8 @@ class KimiDeltaAttention(nn.Module):
         A step (``start`` None, ``state`` given): row ``n`` is lane ``n``'s
         one token, ``live [N]`` the lanes that are.  Returns ``(out, conv
         pool, kda pool)``."""
-        from deepspeed_tpu.ops.transformer.registry import delta_state_update
+        from deepspeed_tpu.ops.transformer.registry import (
+            conv_state_update, delta_state_update)
         cfg = self.config
         H, D, K, W = cfg.kda_heads, cfg.kda_head_dim, cfg.conv_size, \
             cfg.kda_width
@@ -185,14 +185,10 @@ class KimiDeltaAttention(nn.Module):
             if state is not None:
                 conv_pool, kda_pool, at, rows = state
             with jax.named_scope("conv.short"):
-                before = jnp.zeros(((K - 1) * 3 * W,), z.dtype) \
-                    if state is None else conv_pool[at, rows]
-                if start is None:
-                    conv, kept = step_taps(before, z, w)
-                else:
-                    conv, kept = chunk_taps(before, z, w, start, last)
-                if state is not None:
-                    conv_pool = conv_pool.at[at, rows].set(kept)
+                conv, conv_pool = conv_state_update(
+                    z, w,
+                    None if state is None else (conv_pool, at, rows),
+                    start=start, last=last)
                 q, k, v = jnp.split(nn.silu(conv), 3, axis=-1)
             decay = dense(W, "f_b_proj")(dense(cfg.kda_rank, "f_a_proj")(u))
             step = dense(H, "b_proj")(u)
@@ -388,19 +384,22 @@ class SolarOpen2Model(nn.Module):
         """``k`` / ``v [softmax layers, num_pages, page, KV heads x
         head_dim]`` behind the slot's page table, and behind its state row
         (``paging.SlotPages`` sizes both: trash + one row a slot) ``conv
-        [linear layers, state_rows, (taps - 1) x 3 x width]`` in ``dtype``
-        and ``kda [linear layers, state_rows, heads, d, d]`` in FLOAT32
-        whatever ``dtype`` is: the state is summed into over the whole
-        context."""
+        [linear layers, state_rows, R, 128]`` in ``dtype`` — a row's ``(taps
+        - 1) x 3 x width`` values as whole tiles under the row's index
+        (``ops/transformer/short_conv.py::rows_shape``) — and ``kda [linear
+        layers, state_rows, heads, d, d]`` in FLOAT32 whatever ``dtype`` is:
+        the state is summed into over the whole context.  In both the row's
+        index is a LEADING dimension: XLA tiles the last two, and a row that
+        is a sublane of its tiles is written back a masked store a tile."""
+        from deepspeed_tpu.ops.transformer.short_conv import rows_shape
         cfg = self.config
         dtype = dtype or cfg.jnp_dtype
         linear = len(cfg.kda_layers)
         kv = (len(cfg.gqa_layers), int(num_pages), int(page_size),
               cfg.num_kv_heads * cfg.head_dim)
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
-                "conv": jnp.zeros((linear, int(state_rows),
-                                   (cfg.conv_size - 1) * 3 * cfg.kda_width),
-                                  dtype),
+                "conv": jnp.zeros((linear, int(state_rows)) + rows_shape(
+                    cfg.conv_size, 3 * cfg.kda_width, dtype), dtype),
                 "kda": jnp.zeros((linear, int(state_rows), cfg.kda_heads,
                                   cfg.kda_head_dim, cfg.kda_head_dim),
                                  jnp.float32)}

@@ -527,6 +527,26 @@ def write_and_attend(cfg, q, k, v, positions, cache, *, bias=None,
     return out, new_cache
 
 
+# ---- a convolution's rows a slot ----------------------------------------- #
+def conv_state_update(z, w, state, *, start=None, last=None):
+    """Run a short causal depthwise convolution's positions over its ROWS a
+    slot — the last ``K - 1`` inputs — and leave the new ones in the pool:
+    ``state = (pool [layers, rows, ...short_conv.rows_shape], layer, rows)``
+    or None (a sequence from its start, nothing kept).  ``w [K, h]`` float32.
+    A chunk (``start`` a scalar): ``z [T, h]`` consecutive positions of ONE
+    slot, ``rows`` its state row, from zeros where ``start == 0``, the rows
+    kept those that end at ``last`` (None: the chunk's last).  A step
+    (``start`` None): row ``n`` is lane ``n``'s one token, ``rows [N]`` by
+    the lanes' tables — a lane on the trash row writes there.  Returns
+    ``(conv`` float32``, pool)``."""
+    from deepspeed_tpu.ops.transformer import short_conv
+    pool, layer, rows = (None, None, None) if state is None else state
+    if start is None:
+        return short_conv.decode_step(z, w, pool, layer, rows,
+                                      pallas=pallas_supported())
+    return short_conv.chunk(z, w, pool, layer, rows, start, last)
+
+
 # ---- a matrix state a slot: the gated delta rule ------------------------- #
 def delta_state_update(q, k, v, g, beta, state, *, start=None, real=None,
                        live=None):

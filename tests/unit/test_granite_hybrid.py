@@ -659,6 +659,9 @@ def test_slot_pages_size_a_row_kind_by_kind(program, dtype, conv_bytes):
     assert mgr.state_kinds == ("conv", "ssm") and mgr.state_rows == 4
     assert mgr.table_width == mgr.pages_per_slot + 1 == 9
     pools = mgr.new_pools(dtype)
+    # 128 does not divide the toy's 320-wide stream: a flat row, where the
+    # cell's 3 x 8,448 are whole tiles under the row's index
+    # (``short_conv.rows_shape``; test_tpu_compile.py holds the cell's)
     assert pools["conv"].shape == (SSM_LAYERS, 4, 3 * CONV)
     assert pools["ssm"].shape == (SSM_LAYERS, 4, HEADS // 2, N, 2 * P)
     assert (pools["conv"].dtype, pools["ssm"].dtype) == (dtype, jnp.float32)

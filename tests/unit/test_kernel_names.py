@@ -15,6 +15,7 @@ from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
                                            delta_attention as delta_mod,
                                            flash_attention as flash_mod,
                                            paged_attention as paged_mod,
+                                           short_conv as conv_mod,
                                            ssd as ssd_mod)
 
 H, D, L = 2, 64, 2
@@ -104,7 +105,20 @@ def _ssd(chunk):
     return fn, rows + [pool, ((n,), I32)]
 
 
+def _conv_step():
+    """The two row kernels of a short convolution's decode step over a pool
+    ``[layers, rows, ...rows_shape]``: the lanes' rows out, the rows to keep
+    back in."""
+    pool = ((L, 3) + conv_mod.rows_shape(3, 128, F32), F32)
+
+    def fn(z, w, pool, at):
+        return conv_mod.decode_step(z, w, pool, 1, at)
+    return fn, [((2, 128), F32), ((3, 128), F32), pool, ((2,), I32)]
+
+
 CASES = {
+    "conv.rows_read": _conv_step,
+    "conv.rows_write": _conv_step,
     "ssd.chunk_scan": lambda: _ssd(True),
     "ssd.decode_step": lambda: _ssd(False),
     "kda.chunk_scan": lambda: _kda(True),

@@ -253,6 +253,55 @@ def test_state_rows_after_a_padded_last_chunk(program, prompt_len):
     assert (np.asarray(eng.pools["conv"][:, 1]) == 0).all()   # slot 0's row
 
 
+def test_rows_in_tiles_through_the_slot_programs(monkeypatch):
+    """At a width 128 divides a slot's row is whole tiles under the row's
+    index — the cells' form; the 64-wide toy's rows above are flat — and a
+    decode step reads and writes it through ``conv.rows_read`` /
+    ``conv.rows_write`` (``ops/transformer/short_conv.py``, interpreted
+    here).  Three requests on two slots: one retires mid-block (its dead
+    steps land on the trash row), the third takes its slot and the stale
+    row; logits and the rows against the reference."""
+    toy = {**TOY, "hidden_size": 128}
+    for name in ("_W", "_OUT", "_DOWN"):      # sqrt(hidden) x std, as at 64
+        monkeypatch.setattr(fam, name, getattr(fam, name) / 2 ** 0.5)
+    sizes = fam.sizes_of(toy)
+    module = fam.program_model(toy, dtype="float32")
+    params = jax.tree.map(lambda x: x.astype(jnp.float32),
+                          fam.program_params(module, toy, SEED))
+    eng = Engine(module, params)
+    # 2 x 128 values a row on one float32 tile of 8 sublanes
+    assert eng.pools["conv"].shape == (4, 3, 8, 128)
+
+    def rows(slot, like):
+        got = np.asarray(eng.pools["conv"][:, 1 + slot]).reshape(4, -1)
+        assert (got[:, like[0].size:] == 0).all()         # the pad
+        return got[:, :like[0].size].reshape(like.shape)
+
+    def reference(prompt, generated):
+        full = np.concatenate([prompt, generated]).astype(np.int32)
+        lg = np.asarray(fam.logits(sizes, SEED, full))
+        return lg[len(prompt) - 1:len(full) - 1]
+
+    reqs = {"a": (_prompt(6, 4), 3), "b": (_prompt(13, 5), 9),
+            "c": (_prompt(2 * CHUNK + 3, 3), 6)}
+    eng.admit("a", 0, *reqs["a"])
+    want = np.asarray(fam.conv_states(sizes, SEED, reqs["a"][0]))
+    assert np.abs(rows(0, want) - want).max() < TOL       # a padded chunk
+    eng.admit("b", 1, *reqs["b"])
+    eng.block()                               # a retires inside this block
+    assert not bool(eng.state["active"][0]) and bool(eng.state["active"][1])
+    ta = np.asarray(eng.tokens["a"])
+    want = np.asarray(fam.conv_states(
+        sizes, SEED, np.concatenate([reqs["a"][0], ta[:2]])))
+    assert np.abs(rows(0, want) - want).max() < TOL       # its LAST LIVE step
+    eng.retire(0)
+    eng.admit("c", 0, *reqs["c"])             # over a's stale row
+    for rid, (prompt, n_new) in reqs.items():
+        tokens, logits = eng.run(rid)
+        assert len(tokens) == n_new
+        assert np.abs(logits - reference(prompt, tokens)).max() < TOL
+
+
 def test_a_stale_state_is_visible(program):
     """What the tolerance stands against: the same request with its state
     row zeroed between prefill and decode leaves the reference by a
@@ -474,7 +523,9 @@ def test_slot_pages_ship_the_state_row_in_the_table(program):
     assert mgr.table_width == mgr.pages_per_slot + 1 == 9
     assert mgr.table().shape == (3, 9) and (mgr.table() == 0).all()
     pools = jax.eval_shape(lambda: mgr.new_pools(jnp.float32))
-    assert pools["conv"].shape == (4, 4, 2 * 64)      # 4 conv layers
+    # 4 conv layers; 128 does not divide the toy's 64: a flat row, where the
+    # cell's 2 x 2,048 are whole tiles (``short_conv.rows_shape``)
+    assert pools["conv"].shape == (4, 4, 2 * 64)
     assert pools["k"].shape == pools["v"].shape == (2, 25, 8, 2 * 16)
     assert mgr.state_row_bytes == 4 * 2 * 64 * 4
     assert mgr.page_bytes == 2 * 2 * 8 * 32 * 4

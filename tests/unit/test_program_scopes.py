@@ -295,6 +295,14 @@ D = "jit(decode_block)/while/body/closed_call/"
      "attn.ssd", "fwd"),
     (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/conv.short/"
      "mul", "conv.short", "fwd"),
+    # the convolution rows' two kernels (``ops/transformer/short_conv.py``)
+    # lie under the scope of the mixer that calls them, whichever it is
+    (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/conv.short/"
+     "jit(_rows_pallas)/conv.rows_write/pallas_call", "conv.short", "fwd"),
+    (D + "SolarOpen2Model.decode/layers_1/linear_attn/attn.kda/conv.short/"
+     "jit(_rows_pallas)/conv.rows_read/pallas_call", "conv.short", "fwd"),
+    (D + "Lfm2Model.decode/layers_0/conv/conv.short/jit(_rows_pallas)/"
+     "conv.rows_write/pallas_call", "conv.short", "fwd"),
     (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/in_proj/"
      "dot_general", "attn.proj", "fwd"),
     (D + "GraniteHybridModel.decode/layers_1/mamba/attn.ssd/out_proj/"

@@ -561,6 +561,9 @@ def test_slot_pages_size_a_row_kind_by_kind(program, dtype, conv_bytes):
     assert mgr.state_kinds == ("conv", "kda") and mgr.state_rows == 4
     assert mgr.table_width == mgr.pages_per_slot + 1 == 9
     pools = mgr.new_pools(dtype)
+    # 128 does not divide the toy's 192-wide q | k | v: a flat row, where
+    # the cell's 3 x 24,576 are whole tiles under the row's index
+    # (``short_conv.rows_shape``; test_tpu_compile.py holds the cell's)
     assert pools["conv"].shape == (KDA_LAYERS, 4, 3 * 3 * HEADS * D)
     assert pools["kda"].shape == (KDA_LAYERS, 4, HEADS, D, D)
     assert (pools["conv"].dtype, pools["kda"].dtype) == (dtype, jnp.float32)

@@ -29,6 +29,7 @@ from deepspeed_tpu.ops.transformer import (decode_attention as decode_mod,
                                            flash_attention as flash_mod,
                                            latent_attention as latent_mod,
                                            paged_attention as paged_mod,
+                                           short_conv as conv_mod,
                                            ssd as ssd_mod)
 
 H, D, L = 32, 64, 24            # OPT-1.3B (models/opt.py)
@@ -69,7 +70,7 @@ def mosaic(monkeypatch, no_persistent_cache):
     """``_interpret()`` asks ``jax.default_backend()``, which is the CPU
     here: steer it in the test, not through an option of the program."""
     for mod in (flash_mod, decode_mod, paged_mod, block_sparse, moe_mod,
-                latent_mod, eva_mod, delta_mod, ssd_mod):
+                latent_mod, eva_mod, delta_mod, ssd_mod, conv_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -337,9 +338,28 @@ def _ssd(chunk, heads=128, dim=64, states=128, slots=176, layers=9):
     return fn, args
 
 
+def _conv_step(slots, taps, width, layers):
+    """One short-convolution layer's decode step of a cell — Granite's 176
+    lanes of 3 x 8,448 values a row, Solar's 64 of 3 x 24,576, LFM2's 256 of
+    2 x 2,048 — over the cell's bfloat16 row pool: ``conv.rows_read``, the
+    taps on the rows as they lie, ``conv.rows_write`` with the pool aliased
+    in and out."""
+    pool = ((layers, 1 + slots) + conv_mod.rows_shape(taps, width, BF16),
+            BF16)
+    args = [((slots, width), BF16), ((taps, width), F32), pool,
+            ((slots,), I32)]
+
+    def fn(z, w, pool, rows):
+        return conv_mod.decode_step(z, w, pool, layers - 1, rows)
+    return fn, args
+
+
 CASES = {
     "granite_ssd_chunk_scan_c512": lambda: _ssd(512),
     "granite_ssd_decode_step_176": lambda: _ssd(0),
+    "granite_conv_step_176": lambda: _conv_step(176, 4, 8448, 9),
+    "solar_conv_step_64": lambda: _conv_step(64, 4, 3 * 8192, 3),
+    "lfm2_conv_step_256": lambda: _conv_step(256, 3, 2048, 8),
     "solar_kda_chunk_scan_c2048": _kda_chunk_scan,
     "evabyte_eva_decode_24x46": _eva_decode,
     "evabyte_eva_chunk_c512": lambda: _eva_chunk(512),
@@ -894,7 +914,7 @@ def test_solar_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     assert {k: (v.shape, str(v.dtype)) for k, v in pool.items()} == {
         "k": ((1, s["num_pages"], 64, 1024), "bfloat16"),
         "v": ((1, s["num_pages"], 64, 1024), "bfloat16"),
-        "conv": ((3, 65, 3 * 24576), "bfloat16"),
+        "conv": ((3, 65, 576, 128), "bfloat16"),     # 3 x 24,576 a row
         "kda": ((3, 65, 64, 128, 128), "float32")}
     assert pages.state_kind_bytes == {"conv": 3 * 147456,
                                       "kda": 3 * 4 * 2 ** 20}
@@ -960,9 +980,10 @@ def test_granite_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     assert {k: (v.shape, str(v.dtype)) for k, v in pool.items()} == {
         "k": ((1, s["num_pages"], 64, 1024), "bfloat16"),
         "v": ((1, s["num_pages"], 64, 1024), "bfloat16"),
-        "conv": ((9, 177, 3 * 8448), "bfloat16"),
+        # 3 x 8,448 a row: 198 sublanes on 208, whole bfloat16 tiles
+        "conv": ((9, 177, 208, 128), "bfloat16"),
         "ssm": ((9, 177, 64, 128, 128), "float32")}
-    assert pages.state_kind_bytes == {"conv": 9 * 50688,
+    assert pages.state_kind_bytes == {"conv": 9 * 208 * 128 * 2,
                                       "ssm": 9 * 4 * 2 ** 20}
     pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
     weights = sum(x.size * 2 for x in jax.tree.leaves(params))
@@ -1034,6 +1055,24 @@ def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
         grid = call.params["grid_mapping"].grid
         assert grid == ((128 // 8, 512 // 128) if "chunk" in case
                         else (176, 64 // 16))
+    if "_conv_step_" in case:
+        # the rows' read and their write-back: one kernel each, the pool
+        # whole in HBM (no block of it in VMEM), the write's aliased in ->
+        # out (operand 3, after the two scalars and the rows to keep) — no
+        # copy of the pool, and no XLA gather or scatter left beside them
+        read, write = _pallas_calls(fn, shapes)
+        assert (read.params["name"], write.params["name"]) \
+            == ("conv.rows_read", "conv.rows_write")
+        assert read.params["input_output_aliases"] == ()
+        assert write.params["input_output_aliases"] == ((3, 0),)
+        assert "output_to_operand_aliasing={{}: (3, {})}" in text
+        assert all("any" in str(x.transformed_block_aval)
+                   for call in (read, write)
+                   for x in call.params["grid_mapping"].block_mappings)
+        assert " gather(" not in text and " scatter(" not in text
+        rows = shapes[2][0][2:]
+        assert rows == {"granite": (208, 128), "solar": (576, 128),
+                        "lfm2": (32, 128)}[case.split("_")[0]]
     if case in _FLASH_TRAINED:
         # the backward of one call is ONE Mosaic kernel beside the
         # forward's: the head's float32 dq sum fits the VMEM a kernel gets

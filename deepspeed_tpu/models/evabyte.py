@@ -58,8 +58,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from deepspeed_tpu.models.contract import SlotContract
-from deepspeed_tpu.models.dots3 import _Mlp
-from deepspeed_tpu.models.latent_attention import _rms
+from deepspeed_tpu.models.parts import _Mlp, _rms
 from deepspeed_tpu.models.transformer import _paged_write, _rope
 from deepspeed_tpu.ops.transformer.eva_attention import (
     eva_chunk_attention, eva_decode_attention)
@@ -80,9 +79,6 @@ class EvaByteConfig:
     max_seq_len: int
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
-    # what the attention registry reads off a config
-    kv_cache_quant: bool = False
-    decode_int8_matmuls: bool = False
 
     @property
     def head_dim(self):

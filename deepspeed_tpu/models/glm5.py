@@ -53,10 +53,10 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from deepspeed_tpu.models.contract import SlotContract
-from deepspeed_tpu.models.latent_attention import (LatentSpec,
-                                                   causal_pairs, flash_tiles,
+from deepspeed_tpu.models.latent_attention import (LatentSpec, flash_tiles,
                                                    live_block_rows, padded)
-from deepspeed_tpu.models.latent_block import LatentBlock, _Norm
+from deepspeed_tpu.models.latent_block import LatentBlock
+from deepspeed_tpu.models.parts import _Norm, causal_pairs
 
 
 @dataclasses.dataclass(frozen=True)

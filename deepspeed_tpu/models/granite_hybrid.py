@@ -30,19 +30,16 @@ expert layer; the router keeps its published width.  The embedding times
 ``logits_scaling``.
 
 THREE kinds of cache in the slot engine's one manager
-(``paging.SlotPages``): the attention layers' K/V rows in LANE pages under
-the slot's page table, growing with the context, and TWO fixed-size states a
-slot behind its STATE ROW — ``conv``, the last ``taps - 1`` rows of ``xBC``
-in the cache's dtype, and ``ssm``, the scan's state, FLOAT32 whatever dtype
-the server passes (4 MiB a layer at 128 heads of 64 x 128: nine to one over
-the K/V, and at many slots larger than the weights).  A request's first
-chunk starts both from zeros, a chunk leaves both as they stand after its
-last REAL row, and a dead lane of a decode block writes the trash row.
-
-This is a serving model: :meth:`GraniteHybridModel.decode` over the slot
-engine's pools and a plain uncached forward (``__call__``).  It has no
-``generate()`` cache and no training step (the scan and the dropless expert
-kernels have no VJP).
+(``paging.SlotPages``): the attention layers' K/V rows in LANE pages, and
+TWO state kinds this family declares to the skeleton it is built on
+(``models/hybrid.py``: the layer, the serving methods and the slot contract)
+— ``conv``, the last ``taps - 1`` rows of ``xBC`` in the cache's dtype, as
+whole tiles under the row's index (``ops/transformer/short_conv.py::
+rows_shape``: 198 x 128 values on 208 sublanes at the published widths), and
+``ssm``, the heads' ``[d_head, d_state]`` states as
+``ops/transformer/ssd.py::state_shape`` lays them, FLOAT32 whatever dtype the
+server passes (4 MiB a layer at 128 heads of 64 x 128: nine to one over the
+K/V, and at many slots larger than the weights).
 """
 
 import dataclasses
@@ -52,13 +49,9 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.contract import SlotContract
-from deepspeed_tpu.models.latent_attention import _rms, causal_pairs
-from deepspeed_tpu.models.latent_block import _Norm
-from deepspeed_tpu.models.transformer import reference_attention
-from deepspeed_tpu.moe.layer import MoE
-
-CHUNK_CAP = 2048             # whole 512-query blocks of the paged chunk kernel
+from deepspeed_tpu.models.hybrid import (Attention, Hybrid, HybridModel,
+                                         StateKind)
+from deepspeed_tpu.models.parts import _rms
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,7 +61,7 @@ class GraniteHybridConfig:
     layer_types: Tuple[str, ...]
     num_heads: int
     num_kv_heads: int
-    attention_scale: float       # what the attention registry's kernels take
+    attention_scale: float       # the scores' multiplier, not head_dim ** -0.5
     mamba_heads: int
     mamba_head_dim: int
     mamba_state: int
@@ -84,9 +77,6 @@ class GraniteHybridConfig:
     rms_norm_eps: float = 1e-5
     held_experts: Optional[Tuple[int, int]] = None
     dtype: str = "bfloat16"
-    # what the attention registry reads off a config
-    kv_cache_quant: bool = False
-    decode_int8_matmuls: bool = False
 
     @property
     def jnp_dtype(self):
@@ -175,21 +165,14 @@ def granite_hybrid_model(hf, held_experts=None, **overrides):
 
 
 class Mamba2Mixer(nn.Module):
-    """The state-space mixer.  ``state`` is ``None`` (a sequence from its
-    start, nothing kept) or ``(conv pool [SSM layers, rows,
-    ...short_conv.rows_shape], ssm pool [SSM layers, rows, ...state_shape],
-    layer index in the pools, rows)`` — ``rows [N]`` for one token a lane, a
-    scalar row for a chunk of one slot."""
+    """The state-space mixer, a state mixer of ``models/hybrid.py`` over
+    two pools: ``conv [SSM layers, rows, ...short_conv.rows_shape]`` and
+    ``ssm [SSM layers, rows, ...ssd.state_shape]``."""
     config: GraniteHybridConfig
 
     @nn.compact
     def __call__(self, u, state=None, start=None, last=None, live=None):
-        """``u [T, hidden]``.  A chunk (``start`` a scalar, or ``state``
-        None): ``T`` consecutive positions of ONE sequence from ``start``,
-        ``last`` its last real row (the padded tail reaches neither state).
-        A step (``start`` None, ``state`` given): row ``n`` is lane ``n``'s
-        one token, ``live [N]`` the lanes that are.  Returns ``(out, conv
-        pool, ssm pool)``."""
+        """``u [T, hidden]``.  Returns ``(out, (conv pool, ssm pool))``."""
         from deepspeed_tpu.ops.transformer.registry import (
             conv_state_update, ssm_state_update)
         from deepspeed_tpu.ops.transformer.ssd import state_shape
@@ -238,88 +221,44 @@ class Mamba2Mixer(nn.Module):
                 y = _rms((y.astype(f32) * nn.silu(z.astype(f32)))
                          .astype(cfg.jnp_dtype), gain, cfg.rms_norm_eps)
             # no state given: nothing is kept (the one-row pool was scratch)
-            return dense(cfg.hidden_size, "out_proj")(y), conv_pool, \
-                ssm_pool if state is not None else None
+            return dense(cfg.hidden_size, "out_proj")(y), (
+                conv_pool, ssm_pool if state is not None else None)
 
 
-class NopeAttention(nn.Module):
-    """Grouped-query softmax attention with no positional encoding at the
-    config's own scale, no biases, no QK-norm, no gate."""
-    config: GraniteHybridConfig
+class GraniteHybridModel(HybridModel):
 
-    @nn.compact
-    def __call__(self, u, positions, cache=None):
-        """``u [B, S, hidden]``, ``positions [B, S]``; ``cache``: what
-        ``ops/transformer/registry.py::write_and_attend`` takes (the K/V
-        pools, this layer's index in them, the page table) or None for
-        plain causal attention over ``u`` alone."""
-        cfg = self.config
-        H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        dense = lambda n, name: nn.DenseGeneral(
-            (n, D), use_bias=False, dtype=cfg.jnp_dtype, name=name)
-        q, k = dense(H, "q_proj")(u), dense(KVH, "k_proj")(u)
-        v = dense(KVH, "v_proj")(u)
-        if cache is None:
-            out = reference_attention(q, k, v, causal=True,
-                                      scale=cfg.attention_scale)
-        else:
-            from deepspeed_tpu.ops.transformer.registry import (
-                write_and_attend)
-            with jax.named_scope("attn.full"):
-                out, cache = write_and_attend(cfg, q, k, v, positions, cache)
-        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.jnp_dtype,
-                        name="o_proj")(out.reshape(out.shape[:2] + (H * D,))), \
-            cache
+    @staticmethod
+    def declare(cfg):
+        from deepspeed_tpu.ops.transformer.short_conv import rows_shape
+        from deepspeed_tpu.ops.transformer.ssd import state_shape
+        return Hybrid(
+            norm_eps=cfg.rms_norm_eps, tied=True,
+            attention_layers=cfg.layers_of("attention"),
+            attention=Attention(
+                cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+                cfg.head_dim, cfg.jnp_dtype,
+                attention_scale=cfg.attention_scale),
+            mixer=("mamba", Mamba2Mixer),
+            state=(StateKind("conv", lambda dtype: rows_shape(
+                       cfg.conv_size, cfg.conv_width, dtype)),
+                   StateKind("ssm", state_shape(
+                       cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state),
+                       jnp.float32)),
+            work="ssd",
+            # a softmax over the chosen logits IS the scored form at a zero
+            # bias: softmax over all, the top-k, renormalised
+            moe=dict(
+                num_experts=cfg.num_experts, k=cfg.moe_top_k,
+                norm_topk_prob=True, ffn_hidden_size=cfg.intermediate_size,
+                scoring="softmax", noaux_tc=True,
+                shared_ffn_hidden_size=cfg.shared_intermediate_size,
+                held_experts=cfg.held_experts))
 
-
-class GraniteHybridLayer(nn.Module):
-    config: GraniteHybridConfig
-    layer_idx: int
-
-    def setup(self):
-        cfg = self.config
-        self.input_layernorm = _Norm(cfg.rms_norm_eps)
-        self.post_attention_layernorm = _Norm(cfg.rms_norm_eps)
-        if cfg.layer_types[self.layer_idx] == "attention":
-            self.self_attn = NopeAttention(cfg)
-        else:
-            self.mamba = Mamba2Mixer(cfg)
-        # a softmax over the chosen logits IS the scored form at a zero
-        # bias: softmax over all, the top-k, renormalised
-        self.moe_mlp = MoE(
-            hidden_size=cfg.hidden_size, num_experts=cfg.num_experts,
-            k=cfg.moe_top_k, capacity_factor=None, norm_topk_prob=True,
-            ffn_hidden_size=cfg.intermediate_size, dtype=cfg.jnp_dtype,
-            gated=True, activation=nn.silu, scoring="softmax", noaux_tc=True,
-            shared_ffn_hidden_size=cfg.shared_intermediate_size,
-            held_experts=cfg.held_experts)
-
-    def __call__(self, x, mix, live=None):
-        """``mix(mixer, normed x) -> (out, cache)``: the call form the model
-        chose (chunk or step) with this layer's cache."""
-        cfg = self.config
-        mixer = self.self_attn if cfg.layer_types[self.layer_idx] \
-            == "attention" else self.mamba
+    @staticmethod
+    def residual(cfg, x, t):
         # one rounding a residual add: the multiplier is no bfloat16 number
-        add = lambda x, t: (x.astype(jnp.float32) + cfg.residual_multiplier
-                            * t.astype(jnp.float32)).astype(x.dtype)
-        a, cache = mix(mixer, self.input_layernorm(x))
-        x = add(x, a)
-        y, _, _ = self.moe_mlp(self.post_attention_layernorm(x), train=False,
-                               live=live)
-        return add(x, y), cache
-
-
-class GraniteHybridModel(nn.Module):
-    config: GraniteHybridConfig
-
-    def setup(self):
-        cfg = self.config
-        self.embed_tokens = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                                     dtype=cfg.jnp_dtype)
-        self.layers = [GraniteHybridLayer(cfg, i)
-                       for i in range(cfg.num_layers)]
-        self.norm = _Norm(cfg.rms_norm_eps)
+        return (x.astype(jnp.float32) + cfg.residual_multiplier
+                * t.astype(jnp.float32)).astype(x.dtype)
 
     def _embed(self, ids):
         x = self.embed_tokens(ids)
@@ -336,157 +275,3 @@ class GraniteHybridModel(nn.Module):
             logits = self.embed_tokens.attend(self.norm(h))
             return logits / jnp.asarray(self.config.logits_scaling,
                                         logits.dtype)
-
-    def __call__(self, batch):
-        """Logits ``[B, S, V]`` of ``batch["input_ids"] [B, S]``: the plain
-        causal forward, a row at a time, no cache."""
-        cfg, rows = self.config, []
-        for ids in batch["input_ids"]:
-            x = self._embed(ids)
-            positions = jnp.arange(ids.shape[0])[None]
-            for i, layer in enumerate(self.layers):
-                if cfg.layer_types[i] == "attention":
-                    mix = lambda op, u: (op(u[None], positions)[0][0], None)
-                else:
-                    mix = lambda op, u: (op(u, start=0)[0], None)
-                x, _ = layer(x, mix)
-            rows.append(self._head(x[None])[0])
-        return jnp.stack(rows)
-
-    # ---- the serving path ---- #
-    def slot_contract(self):
-        """For the slot engine (``models/contract.py``): K/V pages under the
-        slot's table for the attention layers; behind its STATE ROW the
-        state-space layers' two states, ``conv`` and the float32 ``ssm``;
-        one chunk a dispatch (the state is a slot's); dropless experts in
-        every layer, a share of them held."""
-        cfg = self.config
-        return SlotContract(
-            vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
-            dtype=cfg.dtype, num_layers=cfg.num_layers,
-            lane_layers=len(cfg.layers_of("attention")), kv_pages=True,
-            state_kinds=("conv", "ssm"), chunk_cap=CHUNK_CAP,
-            chunk_fault=self._chunk_fault, own_chunk_path=True,
-            routes_experts=True, holds_share=cfg.held_experts is not None,
-            expert_layers=cfg.num_layers,
-            experts=(cfg.held_experts or (0, cfg.num_experts))[1],
-            chunk_work=self._chunk_work, block_work=self._block_work,
-            work_counters=("ssd_scan_rows", "ssd_state_rows", "full_keys"))
-
-    @staticmethod
-    def _chunk_fault(chunk):
-        from deepspeed_tpu.ops.transformer.registry import MAX_CHUNK_S
-        if chunk > MAX_CHUNK_S and chunk % MAX_CHUNK_S:
-            return (f"a chunk over {MAX_CHUNK_S} is whole {MAX_CHUNK_S}-query "
-                    f"blocks of the paged chunk kernel; {chunk} is not")
-        return None
-
-    def _chunk_work(self, start, end, page_size, ring_pages, layers):
-        """What a prefill chunk over REAL positions ``start .. end - 1``
-        does, as its dispatch span's args: ``ssd_scan_rows`` — positions x
-        state-space layers the scan advanced over —, ``ssd_state_rows`` —
-        state rows read and written, one a state-space layer — and
-        ``full_keys``, (query, key) pairs the attention layers attend."""
-        cfg = self.config
-        mamba = len(cfg.layers_of("mamba"))
-        return {"ssd_scan_rows": mamba * (end - start),
-                "ssd_state_rows": mamba,
-                "full_keys": len(cfg.layers_of("attention"))
-                * causal_pairs(start, end, end)}
-
-    def _block_work(self, live, ring_pages, layers):
-        """The same for a decode block, from ``live`` — ``(context, steps)``
-        a live slot: a step scans one position and moves one state row a
-        live lane and state-space layer."""
-        cfg = self.config
-        mamba, steps = len(cfg.layers_of("mamba")), sum(n for _, n in live)
-        return {"ssd_scan_rows": mamba * steps,
-                "ssd_state_rows": mamba * steps,
-                "full_keys": len(cfg.layers_of("attention"))
-                * sum(first + i for first, n in live for i in range(n))}
-
-    def init_paged_cache(self, num_pages, page_size, dtype=None,
-                         state_rows=1):
-        """``k`` / ``v [attention layers, num_pages, page, KV heads x
-        head_dim]`` behind the slot's page table, and behind its state row
-        (``paging.SlotPages`` sizes both: trash + one row a slot) ``conv
-        [SSM layers, state_rows, R, 128]`` in ``dtype`` — a row's ``(taps -
-        1) x conv width`` values as whole tiles under the row's index
-        (``ops/transformer/short_conv.py::rows_shape``: 198 x 128 values on
-        208 sublanes here) — and ``ssm [SSM layers, state_rows, ...]`` — a
-        row the heads' ``[d_head, d_state]`` states as
-        ``ops/transformer/ssd.py::state_shape`` lays them — in FLOAT32
-        whatever ``dtype`` is: the state is summed into over the whole
-        context.  In both the row's index is a LEADING dimension: XLA tiles
-        the last two, and a row that is a sublane of its tiles is written
-        back a masked store a tile."""
-        from deepspeed_tpu.ops.transformer.short_conv import rows_shape
-        from deepspeed_tpu.ops.transformer.ssd import state_shape
-        cfg = self.config
-        dtype = dtype or cfg.jnp_dtype
-        mamba = len(cfg.layers_of("mamba"))
-        kv = (len(cfg.layers_of("attention")), int(num_pages),
-              int(page_size), cfg.num_kv_heads * cfg.head_dim)
-        return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
-                "conv": jnp.zeros((mamba, int(state_rows)) + rows_shape(
-                    cfg.conv_size, cfg.conv_width, dtype), dtype),
-                "ssm": jnp.zeros((mamba, int(state_rows)) + state_shape(
-                    cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state),
-                    jnp.float32)}
-
-    def decode(self, input_ids, cache, start_pos, logits_at=None, live=None):
-        """The slot programs' call: a prefill chunk of one slot
-        (``input_ids [1, C]``, scalar ``start_pos``) or one token a lane
-        (``[N, 1]``, ``start_pos [N]``).  ``cache["pages"]`` is the table
-        row(s): the slot's pages, then its state row."""
-        cfg = self.config
-        per_row = jnp.ndim(start_pos) == 1
-        kv = {"k": cache["k"], "v": cache["v"]}
-        conv_pool, ssm_pool = cache["conv"], cache["ssm"]
-        attention, mamba = cfg.layers_of("attention"), cfg.layers_of("mamba")
-        flat_live = None if live is None else live.reshape(-1)
-        with jax.named_scope("slots.tables"):
-            table, rows = cache["pages"][:, :-1], cache["pages"][:, -1]
-            ids = input_ids[:, 0] if per_row else input_ids[0]
-            if per_row:
-                positions = start_pos[:, None]
-                marker = {"per_row": jnp.zeros((), jnp.int32)}
-            else:
-                positions = (start_pos
-                             + jnp.arange(input_ids.shape[1]))[None]
-                marker = {"page_runs": cache["page_runs"]} \
-                    if "page_runs" in cache else {}
-                row = rows[0]
-            last = None if logits_at is None \
-                else logits_at[0].astype(jnp.int32)
-        x = self._embed(ids)
-        for i, layer in enumerate(self.layers):
-            if i in attention:
-                layer_cache = {**kv, "pages": table, **marker,
-                               "layer": jnp.asarray(attention.index(i),
-                                                    jnp.int32)}
-
-                def mix(op, u, layer_cache=layer_cache):
-                    u = u[:, None] if per_row else u[None]
-                    out, new = op(u, positions, layer_cache)
-                    return (out[:, 0] if per_row else out[0]), new
-
-                x, new = layer(x, mix, live=flat_live)
-                kv = {"k": new["k"], "v": new["v"]}
-            else:
-                at = mamba.index(i)
-
-                def mix(op, u, at=at):
-                    if per_row:
-                        out, *pools = op(u, (conv_pool, ssm_pool, at, rows),
-                                         live=flat_live)
-                    else:
-                        out, *pools = op(u, (conv_pool, ssm_pool, at, row),
-                                         start_pos, last)
-                    return out, pools
-
-                x, (conv_pool, ssm_pool) = layer(x, mix, live=flat_live)
-        with jax.named_scope("slots.tables"):
-            h = x[:, None] if per_row else x[None]
-        return self._head(h, logits_at), {**kv, "conv": conv_pool,
-                                          "ssm": ssm_pool}

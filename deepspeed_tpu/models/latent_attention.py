@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 import flax.linen as nn
 
+from deepspeed_tpu.models.parts import _rms
 from deepspeed_tpu.ops.transformer import latent_attention as ops
 
 LANES = ops.LANES
@@ -127,26 +128,12 @@ def rope(x, positions, theta, dims=None, interleaved=False):
     return out.astype(x.dtype)
 
 
-def _rms(x, scale, eps, factor=1.0):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32) * factor).astype(x.dtype)
-
-
 def _layer_norm(x, scale, bias, eps):
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, -1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mu), -1, keepdims=True)
     return ((xf - mu) * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
             + bias.astype(jnp.float32)).astype(x.dtype)
-
-
-def causal_pairs(start, end, limit):
-    """(query, key) pairs of a chunk's queries ``start .. end - 1`` when
-    query ``t`` sees ``min(t + 1, limit)`` keys — the host-side count
-    behind a latent model's ``chunk_work``."""
-    low = max(min(limit, end) - start, 0)      # queries under the limit
-    return low * start + low * (low + 1) // 2 + (end - start - low) * limit
 
 
 def live_block_rows(end):

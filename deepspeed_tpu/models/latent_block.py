@@ -15,35 +15,12 @@ What a block reads of its model's config: ``hidden_size``,
 
 from typing import Any
 
-import jax.numpy as jnp
 import flax.linen as nn
 
 from deepspeed_tpu.models.latent_attention import (LatentAttention,
-                                                   LatentSpec, _rms)
+                                                   LatentSpec)
+from deepspeed_tpu.models.parts import _Mlp, _Norm
 from deepspeed_tpu.moe.layer import MoE
-
-
-class _Norm(nn.Module):
-    eps: float
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        return _rms(x, scale, self.eps)
-
-
-class _Mlp(nn.Module):
-    width: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
-                                         name=name)
-        return dense(x.shape[-1], "down_proj")(
-            nn.silu(dense(self.width, "gate_proj")(x))
-            * dense(self.width, "up_proj")(x))
 
 
 class LatentBlock(nn.Module):

@@ -47,10 +47,9 @@ import flax.linen as nn
 
 from deepspeed_tpu.models.contract import SlotContract
 from deepspeed_tpu.models.latent_attention import (LatentAttention,
-                                                   LatentSpec,
-                                                   causal_pairs, flash_tiles,
+                                                   LatentSpec, flash_tiles,
                                                    padded)
-from deepspeed_tpu.models.latent_block import _Mlp, _Norm
+from deepspeed_tpu.models.parts import _Mlp, _Norm, causal_pairs
 from deepspeed_tpu.moe.layer import MoE
 
 

@@ -26,18 +26,13 @@ gives the model one chip's share of each expert layer (``moe/layer.py``);
 the router keeps its published width.  Final RMSNorm, untied head.
 
 THREE kinds of cache in the slot engine's one manager
-(``paging.SlotPages``): the softmax layers' K/V rows in LANE pages under the
-slot's page table, growing with the context, and TWO fixed-size states a
-slot behind its STATE ROW — ``conv``, the last ``taps - 1`` rows of the
-``[q | k | v]`` projections in the cache's dtype, and ``kda``, the
-delta-rule state, FLOAT32 whatever dtype the server passes.  A request's
-first chunk starts both from zeros, a chunk leaves both as they stand after
-its last REAL row, and a dead lane of a decode block writes the trash row.
-
-This is a serving model: :meth:`SolarOpen2Model.decode` over the slot
-engine's pools and a plain uncached forward (``__call__``).  It has no
-``generate()`` cache and no training step (the state scan and the dropless
-expert kernels have no VJP).
+(``paging.SlotPages``): the softmax layers' K/V rows in LANE pages, and TWO
+state kinds this family declares to the skeleton it is built on
+(``models/hybrid.py``: the layer, the serving methods and the slot contract)
+— ``conv``, the last ``taps - 1`` rows of the ``[q | k | v]`` projections in
+the cache's dtype, as whole tiles under the row's index
+(``ops/transformer/short_conv.py::rows_shape``), and ``kda``, the delta-rule
+state, FLOAT32 whatever dtype the server passes.
 """
 
 import dataclasses
@@ -47,13 +42,10 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.contract import SlotContract
-from deepspeed_tpu.models.latent_attention import _rms, causal_pairs
-from deepspeed_tpu.models.latent_block import _Norm
-from deepspeed_tpu.models.transformer import reference_attention
-from deepspeed_tpu.moe.layer import MoE
+from deepspeed_tpu.models.hybrid import (Attention, Hybrid, HybridModel,
+                                         StateKind)
+from deepspeed_tpu.models.parts import _rms
 
-CHUNK_CAP = 2048             # whole 512-query blocks of the paged chunk kernel
 L2_EPS = 1e-6                # under the square root of a head's q / k norm
 
 
@@ -82,9 +74,6 @@ class SolarOpen2Config:
     rms_norm_eps: float = 1e-5
     held_experts: Optional[Tuple[int, int]] = None
     dtype: str = "bfloat16"
-    # what the attention registry reads off a config
-    kv_cache_quant: bool = False
-    decode_int8_matmuls: bool = False
 
     @property
     def jnp_dtype(self):
@@ -153,21 +142,14 @@ def solar_open2_model(hf, held_experts=None, **overrides):
 
 
 class KimiDeltaAttention(nn.Module):
-    """The gated delta-rule mixer.  ``state`` is ``None`` (a sequence from
-    its start, nothing kept) or ``(conv pool [KDA layers, rows,
-    ...short_conv.rows_shape], kda pool [KDA layers, rows, heads, d, d],
-    layer index in the pools, rows)`` — ``rows [N]`` for one token a lane, a
-    scalar row for a chunk of one slot."""
+    """The gated delta-rule mixer, a state mixer of ``models/hybrid.py``
+    over two pools: ``conv [KDA layers, rows, ...short_conv.rows_shape]`` and
+    ``kda [KDA layers, rows, heads, d, d]``."""
     config: SolarOpen2Config
 
     @nn.compact
     def __call__(self, u, state=None, start=None, last=None, live=None):
-        """``u [T, hidden]``.  A chunk (``start`` a scalar, or ``state``
-        None): ``T`` consecutive positions of ONE sequence from ``start``,
-        ``last`` its last real row (the padded tail reaches neither state).
-        A step (``start`` None, ``state`` given): row ``n`` is lane ``n``'s
-        one token, ``live [N]`` the lanes that are.  Returns ``(out, conv
-        pool, kda pool)``."""
+        """``u [T, hidden]``.  Returns ``(out, (conv pool, kda pool))``."""
         from deepspeed_tpu.ops.transformer.registry import (
             conv_state_update, delta_state_update)
         cfg = self.config
@@ -217,245 +199,30 @@ class KimiDeltaAttention(nn.Module):
                 out = _rms(out, gain, cfg.rms_norm_eps).reshape(-1, W) \
                     * jax.nn.sigmoid(gate.astype(f32)).astype(out.dtype)
             # no state given: nothing is kept (the one-row pool was scratch)
-            return dense(cfg.hidden_size, "o_proj")(out), conv_pool, \
-                kda_pool if state is not None else None
+            return dense(cfg.hidden_size, "o_proj")(out), (
+                conv_pool, kda_pool if state is not None else None)
 
 
-class GatedAttention(nn.Module):
-    """Grouped-query softmax attention with no positional encoding and a
-    sigmoid gate on the heads' outputs, no biases, no QK-norm."""
-    config: SolarOpen2Config
-
-    @nn.compact
-    def __call__(self, u, positions, cache=None):
-        """``u [B, S, hidden]``, ``positions [B, S]``; ``cache``: what
-        ``ops/transformer/registry.py::write_and_attend`` takes (the K/V
-        pools, this layer's index in them, the page table) or None for
-        plain causal attention over ``u`` alone."""
-        cfg = self.config
-        H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        dense = lambda n, name: nn.DenseGeneral(
-            (n, D), use_bias=False, dtype=cfg.jnp_dtype, name=name)
-        q, k = dense(H, "q_proj")(u), dense(KVH, "k_proj")(u)
-        v = dense(KVH, "v_proj")(u)
-        if cache is None:
-            out = reference_attention(q, k, v, causal=True)
-        else:
-            from deepspeed_tpu.ops.transformer.registry import (
-                write_and_attend)
-            with jax.named_scope("attn.full"):
-                out, cache = write_and_attend(cfg, q, k, v, positions, cache)
-        out = out.reshape(out.shape[:2] + (H * D,))
-        if cfg.gqa_gate:
-            gate = nn.Dense(H * D, use_bias=False, dtype=cfg.jnp_dtype,
-                            name="gate_proj")(u)
-            with jax.named_scope("attn.out_gate"):
-                out = out * jax.nn.sigmoid(
-                    gate.astype(jnp.float32)).astype(out.dtype)
-        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.jnp_dtype,
-                        name="o_proj")(out), cache
-
-
-class SolarOpen2Layer(nn.Module):
-    config: SolarOpen2Config
-    layer_idx: int
-
-    def setup(self):
-        cfg = self.config
-        self.input_layernorm = _Norm(cfg.rms_norm_eps)
-        self.post_attention_layernorm = _Norm(cfg.rms_norm_eps)
-        if self.layer_idx in cfg.gqa_layers:
-            self.self_attn = GatedAttention(cfg)
-        else:
-            self.linear_attn = KimiDeltaAttention(cfg)
-        self.moe_mlp = MoE(
-            hidden_size=cfg.hidden_size, num_experts=cfg.n_routed_experts,
-            k=cfg.moe_top_k, capacity_factor=None,
-            norm_topk_prob=cfg.norm_topk_prob,
-            ffn_hidden_size=cfg.moe_intermediate_size, dtype=cfg.jnp_dtype,
-            gated=True, activation=nn.silu, scoring="sigmoid",
-            routed_scaling=cfg.routed_scaling_factor,
-            shared_ffn_hidden_size=cfg.n_shared_experts
-            * cfg.moe_intermediate_size,
-            held_experts=cfg.held_experts)
-
-    def __call__(self, x, mix, live=None):
-        """``mix(mixer, normed x) -> (out, cache)``: the call form the model
-        chose (chunk or step) with this layer's cache."""
-        mixer = self.self_attn if self.layer_idx in self.config.gqa_layers \
-            else self.linear_attn
-        a, cache = mix(mixer, self.input_layernorm(x))
-        x = x + a
-        y, _, _ = self.moe_mlp(self.post_attention_layernorm(x), train=False,
-                               live=live)
-        return x + y, cache
-
-
-class SolarOpen2Model(nn.Module):
-    config: SolarOpen2Config
-
-    def setup(self):
-        cfg = self.config
-        self.embed_tokens = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                                     dtype=cfg.jnp_dtype)
-        self.layers = [SolarOpen2Layer(cfg, i) for i in range(cfg.num_layers)]
-        self.norm = _Norm(cfg.rms_norm_eps)
-        self.lm_head = nn.Dense(cfg.vocab_size, use_bias=False,
-                                dtype=cfg.jnp_dtype)
-
-    def _head(self, h, at=None):
-        """Logits of ``h [B, S, hidden]``, or of row ``at[b]`` of each."""
-        with jax.named_scope("head.logits"):
-            if at is not None:
-                h = jnp.take_along_axis(
-                    h, at.astype(jnp.int32)[:, None, None], axis=1)
-            return self.lm_head(self.norm(h))
-
-    def __call__(self, batch):
-        """Logits ``[B, S, V]`` of ``batch["input_ids"] [B, S]``: the plain
-        causal forward, a row at a time, no cache."""
-        cfg, rows = self.config, []
-        for ids in batch["input_ids"]:
-            x = self.embed_tokens(ids)
-            positions = jnp.arange(ids.shape[0])[None]
-            for i, layer in enumerate(self.layers):
-                if i in cfg.gqa_layers:
-                    mix = lambda op, u: (op(u[None], positions)[0][0], None)
-                else:
-                    mix = lambda op, u: (op(u, start=0)[0], None)
-                x, _ = layer(x, mix)
-            rows.append(self._head(x[None])[0])
-        return jnp.stack(rows)
-
-    # ---- the serving path ---- #
-    def slot_contract(self):
-        """For the slot engine (``models/contract.py``): K/V pages under the
-        slot's table for the softmax layers; behind its STATE ROW the linear
-        layers' two states, ``conv`` and the float32 ``kda``; one chunk a
-        dispatch (the state is a slot's); dropless experts in every layer, a
-        share of them held."""
-        cfg = self.config
-        return SlotContract(
-            vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
-            dtype=cfg.dtype, num_layers=cfg.num_layers,
-            lane_layers=len(cfg.gqa_layers), kv_pages=True,
-            state_kinds=("conv", "kda"), chunk_cap=CHUNK_CAP,
-            chunk_fault=self._chunk_fault, own_chunk_path=True,
-            routes_experts=True, holds_share=cfg.held_experts is not None,
-            expert_layers=cfg.num_layers,
-            experts=(cfg.held_experts or (0, cfg.n_routed_experts))[1],
-            chunk_work=self._chunk_work, block_work=self._block_work,
-            work_counters=("kda_scan_rows", "kda_state_rows", "full_keys"))
+class SolarOpen2Model(HybridModel):
 
     @staticmethod
-    def _chunk_fault(chunk):
-        from deepspeed_tpu.ops.transformer.registry import MAX_CHUNK_S
-        if chunk > MAX_CHUNK_S and chunk % MAX_CHUNK_S:
-            return (f"a chunk over {MAX_CHUNK_S} is whole {MAX_CHUNK_S}-query "
-                    f"blocks of the paged chunk kernel; {chunk} is not")
-        return None
-
-    def _chunk_work(self, start, end, page_size, ring_pages, layers):
-        """What a prefill chunk over REAL positions ``start .. end - 1``
-        does, as its dispatch span's args: ``kda_scan_rows`` — positions x
-        linear layers the state scan advanced over —, ``kda_state_rows`` —
-        state rows read and written, one a linear layer — and ``full_keys``,
-        (query, key) pairs the softmax layers attend."""
-        cfg = self.config
-        linear = len(cfg.kda_layers)
-        return {"kda_scan_rows": linear * (end - start),
-                "kda_state_rows": linear,
-                "full_keys": len(cfg.gqa_layers)
-                * causal_pairs(start, end, end)}
-
-    def _block_work(self, live, ring_pages, layers):
-        """The same for a decode block, from ``live`` — ``(context, steps)``
-        a live slot: a step scans one position and moves one state row a
-        live lane and linear layer."""
-        cfg = self.config
-        steps = sum(n for _, n in live)
-        return {"kda_scan_rows": len(cfg.kda_layers) * steps,
-                "kda_state_rows": len(cfg.kda_layers) * steps,
-                "full_keys": len(cfg.gqa_layers)
-                * sum(first + i for first, n in live for i in range(n))}
-
-    def init_paged_cache(self, num_pages, page_size, dtype=None,
-                         state_rows=1):
-        """``k`` / ``v [softmax layers, num_pages, page, KV heads x
-        head_dim]`` behind the slot's page table, and behind its state row
-        (``paging.SlotPages`` sizes both: trash + one row a slot) ``conv
-        [linear layers, state_rows, R, 128]`` in ``dtype`` — a row's ``(taps
-        - 1) x 3 x width`` values as whole tiles under the row's index
-        (``ops/transformer/short_conv.py::rows_shape``) — and ``kda [linear
-        layers, state_rows, heads, d, d]`` in FLOAT32 whatever ``dtype`` is:
-        the state is summed into over the whole context.  In both the row's
-        index is a LEADING dimension: XLA tiles the last two, and a row that
-        is a sublane of its tiles is written back a masked store a tile."""
+    def declare(cfg):
         from deepspeed_tpu.ops.transformer.short_conv import rows_shape
-        cfg = self.config
-        dtype = dtype or cfg.jnp_dtype
-        linear = len(cfg.kda_layers)
-        kv = (len(cfg.gqa_layers), int(num_pages), int(page_size),
-              cfg.num_kv_heads * cfg.head_dim)
-        return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
-                "conv": jnp.zeros((linear, int(state_rows)) + rows_shape(
-                    cfg.conv_size, 3 * cfg.kda_width, dtype), dtype),
-                "kda": jnp.zeros((linear, int(state_rows), cfg.kda_heads,
-                                  cfg.kda_head_dim, cfg.kda_head_dim),
-                                 jnp.float32)}
-
-    def decode(self, input_ids, cache, start_pos, logits_at=None, live=None):
-        """The slot programs' call: a prefill chunk of one slot
-        (``input_ids [1, C]``, scalar ``start_pos``) or one token a lane
-        (``[N, 1]``, ``start_pos [N]``).  ``cache["pages"]`` is the table
-        row(s): the slot's pages, then its state row."""
-        cfg = self.config
-        per_row = jnp.ndim(start_pos) == 1
-        kv = {"k": cache["k"], "v": cache["v"]}
-        conv_pool, kda_pool = cache["conv"], cache["kda"]
-        flat_live = None if live is None else live.reshape(-1)
-        with jax.named_scope("slots.tables"):
-            table, rows = cache["pages"][:, :-1], cache["pages"][:, -1]
-            ids = input_ids[:, 0] if per_row else input_ids[0]
-            if per_row:
-                positions = start_pos[:, None]
-                marker = {"per_row": jnp.zeros((), jnp.int32)}
-            else:
-                positions = (start_pos
-                             + jnp.arange(input_ids.shape[1]))[None]
-                marker = {"page_runs": cache["page_runs"]} \
-                    if "page_runs" in cache else {}
-                row = rows[0]
-            last = None if logits_at is None \
-                else logits_at[0].astype(jnp.int32)
-        x = self.embed_tokens(ids)
-        for i, layer in enumerate(self.layers):
-            if i in cfg.gqa_layers:
-                layer_cache = {**kv, "pages": table, **marker,
-                               "layer": jnp.asarray(cfg.gqa_layers.index(i),
-                                                    jnp.int32)}
-
-                def mix(op, u, layer_cache=layer_cache):
-                    u = u[:, None] if per_row else u[None]
-                    out, new = op(u, positions, layer_cache)
-                    return (out[:, 0] if per_row else out[0]), new
-
-                x, new = layer(x, mix, live=flat_live)
-                kv = {"k": new["k"], "v": new["v"]}
-            else:
-                at = cfg.kda_layers.index(i)
-
-                def mix(op, u, at=at):
-                    if per_row:
-                        out, *pools = op(u, (conv_pool, kda_pool, at, rows),
-                                         live=flat_live)
-                    else:
-                        out, *pools = op(u, (conv_pool, kda_pool, at, row),
-                                         start_pos, last)
-                    return out, pools
-
-                x, (conv_pool, kda_pool) = layer(x, mix, live=flat_live)
-        with jax.named_scope("slots.tables"):
-            h = x[:, None] if per_row else x[None]
-        return self._head(h, logits_at), {**kv, "conv": conv_pool,
-                                          "kda": kda_pool}
+        return Hybrid(
+            norm_eps=cfg.rms_norm_eps, attention_layers=cfg.gqa_layers,
+            attention=Attention(
+                cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+                cfg.head_dim, cfg.jnp_dtype, out_gate=cfg.gqa_gate),
+            mixer=("linear_attn", KimiDeltaAttention),
+            state=(StateKind("conv", lambda dtype: rows_shape(
+                       cfg.conv_size, 3 * cfg.kda_width, dtype)),
+                   StateKind("kda", (cfg.kda_heads, cfg.kda_head_dim,
+                                     cfg.kda_head_dim), jnp.float32)),
+            work="kda", moe=dict(
+                num_experts=cfg.n_routed_experts, k=cfg.moe_top_k,
+                norm_topk_prob=cfg.norm_topk_prob,
+                ffn_hidden_size=cfg.moe_intermediate_size, scoring="sigmoid",
+                routed_scaling=cfg.routed_scaling_factor,
+                shared_ffn_hidden_size=cfg.n_shared_experts
+                * cfg.moe_intermediate_size,
+                held_experts=cfg.held_experts))

@@ -20,7 +20,8 @@ TWO kinds of K/V cache in the slot engine's one manager
 (``paging.SlotPages``): the full layers' rows in LANE pages under the
 slot's page table, growing with the context, and the sliding layers' in a
 RING the slot owns for good — ``sliding_window`` rows a layer, position
-``p`` in ring row ``p % sliding_window``.  Both go through
+``p`` in ring row ``p % sliding_window``.  Both go through the attention
+module it shares with ``models/hybrid.py``'s families and
 ``ops/transformer/registry.py::write_and_attend``, which picks the paged
 kernels for the lane pages and the ring modes for the ring.
 
@@ -39,9 +40,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from deepspeed_tpu.models.contract import SlotContract
-from deepspeed_tpu.models.latent_attention import _rms, causal_pairs
-from deepspeed_tpu.models.latent_block import _Mlp, _Norm
-from deepspeed_tpu.models.transformer import _rope, reference_attention
+from deepspeed_tpu.models.hybrid import Attention, GroupedQueryAttention
+from deepspeed_tpu.models.parts import _Mlp, _Norm, causal_pairs
 from deepspeed_tpu.moe.layer import MoE
 
 GATE_SUM_EPS = 1e-20         # the public router's guard under the division
@@ -70,9 +70,6 @@ class TrinityConfig:
     mup_enabled: bool = True
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
-    # what the attention registry reads off a config
-    kv_cache_quant: bool = False
-    decode_int8_matmuls: bool = False
 
     @property
     def num_layers(self):
@@ -130,50 +127,15 @@ def trinity_model(hf, **overrides):
     return TrinityModel(trinity_config(hf, **overrides))
 
 
-class TrinityAttention(nn.Module):
-    """Grouped-query attention, per-head RMSNorm on q and k, rope on a
-    SLIDING layer only, a sigmoid gate on the heads' outputs, no biases."""
-    config: TrinityConfig
-    sliding: bool
-
-    @nn.compact
-    def __call__(self, u, positions, cache=None):
-        """``u [B, S, hidden]``, ``positions [B, S]``; ``cache``: what
-        ``ops/transformer/registry.py::write_and_attend`` takes — a full
-        layer's lane pools and page table, or a sliding layer's rings and
-        ring table under the ``ring`` marker — or None for plain causal
-        attention over ``u`` alone."""
-        cfg = self.config
-        H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        window = cfg.sliding_window if self.sliding else None
-        dense = lambda n, name: nn.DenseGeneral(
-            (n, D), use_bias=False, dtype=cfg.jnp_dtype, name=name)
-        gain = lambda name: self.param(name, nn.initializers.ones, (D,),
-                                       jnp.float32)
-        q, k = dense(H, "q_proj")(u), dense(KVH, "k_proj")(u)
-        v = dense(KVH, "v_proj")(u)
-        with jax.named_scope("attn.qk_norm"):
-            q = _rms(q, gain("q_norm"), cfg.norm_eps)
-            k = _rms(k, gain("k_norm"), cfg.norm_eps)
-        if self.sliding:
-            with jax.named_scope("attn.rope"):
-                q, k = _rope(q, k, positions, D, cfg.rope_theta)
-        if cache is None:
-            out = reference_attention(q, k, v, causal=True, window=window)
-        else:
-            from deepspeed_tpu.ops.transformer.registry import (
-                write_and_attend)
-            with jax.named_scope("attn.window" if self.sliding
-                                 else "attn.full"):
-                out, cache = write_and_attend(cfg, q, k, v, positions, cache,
-                                              window=window)
-        gate = nn.Dense(H * D, use_bias=False, dtype=cfg.jnp_dtype,
-                        name="gate_proj")(u)
-        with jax.named_scope("attn.out_gate"):
-            out = out.reshape(out.shape[:2] + (H * D,)) \
-                * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
-        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.jnp_dtype,
-                        name="o_proj")(out), cache
+def attention_of(cfg, sliding):
+    """A layer's attention: per-head RMSNorm on q and k and a sigmoid gate
+    on the heads' outputs in both kinds, rope and the window on a SLIDING
+    layer only."""
+    return Attention(
+        cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+        cfg.jnp_dtype, qk_norm_eps=cfg.norm_eps, out_gate=True,
+        rope_theta=cfg.rope_theta if sliding else None,
+        window=cfg.sliding_window if sliding else None)
 
 
 class TrinityLayer(nn.Module):
@@ -186,8 +148,8 @@ class TrinityLayer(nn.Module):
         self.post_attention_layernorm = _Norm(cfg.norm_eps)
         self.pre_mlp_layernorm = _Norm(cfg.norm_eps)
         self.post_mlp_layernorm = _Norm(cfg.norm_eps)
-        self.self_attn = TrinityAttention(
-            cfg, cfg.layer_types[i] == "sliding_attention")
+        self.self_attn = GroupedQueryAttention(attention_of(
+            cfg, cfg.layer_types[i] == "sliding_attention"))
         if i < cfg.num_dense_layers:
             self.mlp = _Mlp(cfg.intermediate_size, cfg.jnp_dtype)
         else:

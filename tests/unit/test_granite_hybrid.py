@@ -34,7 +34,8 @@ from benchmark import spec
 from deepspeed_tpu.inference.serving import slots
 from deepspeed_tpu.inference.serving.paging import SlotPages
 from deepspeed_tpu.models import contract as contract_mod
-from deepspeed_tpu.models.granite_hybrid import granite_hybrid_config
+from deepspeed_tpu.models.granite_hybrid import (GraniteHybridModel,
+                                                 granite_hybrid_config)
 from deepspeed_tpu.ops.transformer import registry, ssd
 
 TOL = 2e-4
@@ -229,7 +230,9 @@ def test_a_stated_attention_scale_reaches_kernels_and_plain_paths(
     kernels (their ``scale=``) and, with the kernels switched off, from the
     gather path (``q`` handed over times what is left): both are softmax
     attention at that scale by hand, and neither is the default's."""
-    cfg = granite_hybrid_config(TOY, held_experts=(4, 4), dtype="float32")
+    # what the model hands the registry: its attention layers' declaration
+    cfg = GraniteHybridModel.declare(granite_hybrid_config(
+        TOY, held_experts=(4, 4), dtype="float32")).attention
     rng = np.random.default_rng(rows)
     shape = (1, 9, PAGE, 2 * 32)
     draw = lambda *dims: jnp.asarray(rng.normal(size=dims), jnp.float32)

@@ -302,7 +302,8 @@ def test_a_long_chunk_over_lane_pages_is_rows_of_the_kernels_bound(
     """A full layer's chunk of two ``MAX_CHUNK_S`` (shrunk to 16 here) runs
     as two rows over the one table row: the same numbers as the chunk in
     one call."""
-    cfg = trinity.trinity_config(TOY)
+    # what a full layer hands the registry: its attention's declaration
+    cfg = trinity.attention_of(trinity.trinity_config(TOY), sliding=False)
     rng = np.random.default_rng(1)
     shape = (1, 9, PAGE, 32)
     cache = {"k": jnp.zeros(shape, jnp.float32),

@@ -235,8 +235,9 @@ class _PendingPrefill:
         # self-drafting: the last chunk's guess after the first sampled
         # token (device-side), the slot's first pending draft
         self.draft0 = None
-        # expert models: each chunk's device-side load vector
-        # (slots._expert_load), read when the admit event is processed
+        # expert models: the device-side load vector (slots._expert_load)
+        # of each dispatch whose FIRST row was this prompt's, read when
+        # the admit event is processed
         self.expert_loads = []
 
 
@@ -2174,13 +2175,14 @@ class ServingEngine:
     def _run_prefill_dispatch(self, rows):  # lock-held: _lock
         """ONE dispatch of the chunk program over ``rows`` (``[(admission,
         chunk index)]``, row order; fewer than ``chunk_rows`` leaves the
-        rest dead: an all-trash table row, start 0, logits never read),
+        rest dead: an all-trash table row, start 0, last real position -1
+        — no token of it is routed to an expert —, logits never read),
         then the fused admit of every prompt whose last chunk rode it."""
         C, R, n = self.chunk, self.chunk_rows, len(rows)
         tables = np.zeros((R, self.table_width), np.int32)   # trash rows
         ids = np.zeros((R, C), np.int32)
         starts = np.zeros((R,), np.int32)
-        last = np.zeros((R,), np.int32)
+        last = np.full((R,), -1, np.int32)
         work = {}
         for r, (p, ci) in enumerate(rows):
             # chunk ci covers absolute positions [start + ci*C, start +
@@ -2221,7 +2223,9 @@ class ServingEngine:
                     # the slot's first pending draft, once this was the
                     # prompt's last chunk
                     p0.draft0 = load.pop()
-                p0.expert_loads += load     # expert models only (R = 1)
+                # expert models only: the dispatch's ONE load vector, summed
+                # into the statistics when the first row's prompt is admitted
+                p0.expert_loads += load
         except BaseException as e:
             # the donated POOL may be dead — this is a decode-grade
             # failure: every in-flight request's KV lived in it

@@ -246,14 +246,17 @@ def chunk_rows(contract, chunk, page, speculative=False):
     of the weights serves up to 512 prompt tokens — where rows depend on
     each other through the K/V pages alone, written as page runs
     (:func:`chunk_write_form`), which ``write_and_attend`` orders: a layer
-    writes every row's K/V before any row attends.  ONE row — the
-    scalar-``start`` program — where they depend through more: per-slot
-    state (``state_kinds``) or a chunk geometry of the model's own
-    (``own_chunk_path``: windows, latent lanes), dropless experts (the
-    load vector is a request's, :func:`_expert_load`), and under
-    speculation (the draft lane mirrors one chunk at a time)."""
+    writes every row's K/V before any row attends.  Dropless experts
+    (``routes_experts``) take rows like a dense model: an expert layer
+    flattens the rows to tokens, a dead row's tokens are routed nowhere,
+    and the dispatch returns ONE load vector (:func:`_expert_load`), which
+    the server only ever sums.  ONE row — the scalar-``start`` program —
+    where rows depend through more: per-slot state (``state_kinds``) or a
+    chunk geometry of the model's own (``own_chunk_path``: windows, latent
+    lanes), and under speculation (the draft lane mirrors one chunk at a
+    time)."""
     from deepspeed_tpu.ops.transformer.registry import MAX_CHUNK_S
-    own_path = (contract.routes_experts or speculative
+    own_path = (speculative
                 or contract.state_kinds or contract.own_chunk_path
                 or chunk_write_form(contract, chunk, page) != "page_runs")
     return 1 if own_path else max(1, MAX_CHUNK_S // chunk)
@@ -274,16 +277,18 @@ def make_chunk_fn(module, contract, param_transform, self_draft=False):
     own table row, start and last real position.  Rows may be consecutive
     chunks of ONE prompt (row r+1 attends what row r wrote: a layer's K/V
     write precedes its attention call) or chunks of different prompts; a
-    dead row carries an all-trash table row and start 0, and its logits
-    are never read.  At ``R`` = 1 ``start`` is a SCALAR (the row-uniform
-    program, no ``per_row`` marker).
+    dead row carries an all-trash table row, start 0 and ``logits_at``
+    -1, and its logits are never read.  At ``R`` = 1 ``start`` is a SCALAR
+    (the row-uniform program, no ``per_row`` marker).
 
     ``logits_at`` is each chunk's LAST REAL row (the scheduler passes
-    ``chunk - 1`` for a whole chunk and the prompt's last token for the
-    final one), so the rows past it are the padded tail.  For a model
-    with dropless expert layers (``contract.routes_experts``) the tail is
-    routed to no expert, and the program returns ``(logits, cache,
-    load)`` with the chunk's :func:`_expert_load`.
+    ``chunk - 1`` for a whole chunk, the prompt's last token for the
+    final one and -1 for a dead row), so the rows past it are the padded
+    tail.  For a model with dropless expert layers
+    (``contract.routes_experts``) the tail — all of a dead row — is routed
+    to no expert and counted nowhere, and the program returns ``(logits,
+    cache, load)`` with the DISPATCH's :func:`_expert_load`: its ``R x C``
+    tokens are one call of every expert layer.
 
     ``self_draft`` (the model ``drafts_itself``; ``R`` = 1): the chunk also
     fills the multi-token-prediction module's rows — row ``t`` from the

@@ -25,14 +25,13 @@ findable in a device trace by its own name:
   the block that is already there (the last touched expert's), so Mosaic
   elides the DMA, and the body is ``pl.when``-gated off.
 
-At the token counts the serving programs produce (a decode step over the
-slots, one prefill chunk of <= 128 tokens) the layer is bound by
-streaming the touched experts' weights, not by the arithmetic — on a v5e
-64 rows through an expert's three matrices take about the 15 us their
-12.6 MB take to arrive — so the zero-weight rows ride for free, and no
-sort, gather or scatter stands around the matmuls: the weighted combine
-is the kernel's own accumulation.  Rows are independent: what a dead lane
-holds changes no live lane's bits.
+At a decode step's token count (one a slot) or a lone chunk's (<= 128)
+the layer is bound by streaming the touched experts' weights, not by the
+arithmetic — on a v5e 64 rows through an expert's three matrices take
+about the 15 us their 12.6 MB take to arrive — so the zero-weight rows
+ride for free, and no sort, gather or scatter stands around the matmuls:
+the weighted combine is the kernel's own accumulation.  Rows are
+independent: what a dead lane holds changes no live lane's bits.
 
 Two more pieces serve a model whose router scores sigmoid + selection
 bias and whose chip holds a SHARE of the experts (``models/dots3.py``):
@@ -46,7 +45,9 @@ bias and whose chip holds a SHARE of the experts (``models/dots3.py``):
   whole row tile, and one kernel over the live tiles: a tile reads its
   expert's matrices and computes its own rows only.  At 8 of 256 experts
   a token, a 2,048-token chunk hands each of 32 held experts ~64 rows;
-  the dense form above would compute 32 x 2,048.
+  the dense form above would compute 32 x 2,048.  The softmax layer's
+  chunk DISPATCH (4 rows of 128: one 512-token call) takes it too, its
+  picks read back off the combine matrix (:func:`picks_of`).
 
 No VJP: a model that trains through expert layers gives its gate a
 capacity (``moe_capacity_factor``) and takes the GShard path.
@@ -63,14 +64,17 @@ from deepspeed_tpu.ops.transformer.flash_attention import _interpret
 
 # rows are padded to the bf16 sublane tile
 _ROW_TILE = 16
-# rows from which a call of the scored layer sorts by expert
+# rows from which a call of the dropless layer — the scored form and the
+# softmax form alike, ``MoE._sorted`` — sorts by expert
 # (``moe.experts_grouped``) instead of computing every touched expert over
 # every row (``moe.experts_gmm``).  On a v5e, 32 held experts of 5120 x
 # 1536, 8 of 256 a token (PR 31, ms a call, gmm / grouped): 64 rows 1.82 /
 # 2.07, 128 2.00 / 2.32, 256 2.09 / 2.55, 512 4.05 / 2.86, 1024 9.17 /
 # 3.57 — the sort and the gathers cost ~0.4 ms, every row past ~256 costs
 # the dense form another expert-row product.  OLMoE's sizes (64 of 64,
-# 2048 x 1024): 128 rows 1.11 / 1.35, 512 rows 2.18 / 1.53
+# 2048 x 1024): 128 rows 1.11 / 1.35, 512 rows 2.18 / 1.53 — and with the
+# softmax router before either, as a 4-row chunk dispatch of its cell runs
+# them (PR 59): 512 rows 2.20 / 1.55, a row of them dead 2.20 / 1.57
 GROUPED_MIN_ROWS = 512
 
 
@@ -307,6 +311,17 @@ def combine_of(local, gate, count):
                    * gate[..., None], axis=1)[:, :count]
 
 
+def picks_of(combine, k):
+    """:func:`route`'s dense ``combine [T, E]`` as the sorted form takes a
+    call: ``(local [T, k] int32, gate [T, k] float32)`` — a row's ``k``
+    largest entries, the chosen experts' gates, and their indices.  An
+    entry of 0 is no choice (a dead token's whole row) and names no expert:
+    ``E``, as :func:`held_load` writes ``count``."""
+    gate, local = jax.lax.top_k(combine, k)
+    return jnp.where(gate > 0, local, combine.shape[1]).astype(jnp.int32), \
+        gate
+
+
 def _grouped_kernel(tiles_ref, expert_ref, x_ref, wg_ref, wu_ref, wd_ref,
                     o_ref, acc_ref, *, act):
     t, f = pl.program_id(0), pl.program_id(1)
@@ -346,10 +361,13 @@ def grouped_layout(local, count, tile):
     order = jnp.argsort(flat, stable=True)
     start = jnp.cumsum(counts) - counts                   # in sorted order
     sorted_e = flat[order]
-    safe_e = jnp.minimum(sorted_e, count - 1)
-    rank = jnp.arange(T * k, dtype=jnp.int32) - start[safe_e]
+    # an expert's first row less its first place in the sorted order, looked
+    # up as a one-hot sum: a gather of T x k indices into a table of
+    # ``count`` costs XLA's TPU compiler ~0.4 s apiece (two a layer)
+    shift = jnp.sum(jax.nn.one_hot(sorted_e, count, dtype=jnp.int32)
+                    * (first_tile * tile - start), axis=1)
     row = jnp.where(sorted_e < count,
-                    first_tile[safe_e] * tile + rank, rows)
+                    jnp.arange(T * k, dtype=jnp.int32) + shift, rows)
     dest = jnp.zeros((T * k,), jnp.int32).at[order].set(row)
     source = jnp.zeros((rows,), jnp.int32).at[row].set(
         (order // k).astype(jnp.int32), mode="drop")

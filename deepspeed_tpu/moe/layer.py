@@ -49,14 +49,14 @@ class ExpertsMLP(nn.Module):
         wg = self.param("experts_wg", nn.initializers.lecun_normal(),
                         (E, M, F), jnp.float32).astype(x.dtype) \
             if self.gated else None
+        if self.use_bias and (grouped is not None or routed is not None):
+            raise ValueError("the dropless expert kernels carry no "
+                             "per-expert biases")
         if grouped is not None:
             # a chunk's rows sorted by expert: ``(local, gate)``
             return dropless.experts_grouped(x, *grouped, wg, wi, wo,
                                             self.activation)
         if routed is not None:
-            if self.use_bias:
-                raise ValueError("the dropless expert kernel carries no "
-                                 "per-expert biases")
             return dropless.experts(x, *routed, wg, wi, wo, self.activation)
         # x: [E, C, M]
         h = jnp.einsum("ecm,emf->ecf", x, wi)
@@ -160,7 +160,11 @@ class MoE(nn.Module):
                 tokens, gate_w, self.k,
                 renormalize=self.norm_topk_prob and self.k > 1,
                 live=None if live is None else live.reshape(-1))
-            y = experts(tokens, routed=(combine, exp_counts))
+            if self._sorted(tokens):
+                y = experts(tokens, grouped=dropless.picks_of(
+                    combine[:tokens.shape[0]], self.k))
+            else:
+                y = experts(tokens, routed=(combine, exp_counts))
             if not self.is_initializing():
                 self.sow("moe_stats", "expert_tokens", exp_counts,
                          reduce_fn=lambda _, new: new, init_fn=lambda: None)
@@ -186,6 +190,15 @@ class MoE(nn.Module):
 
         return y.reshape(orig_shape).astype(x.dtype), aux_loss, exp_counts
 
+    def _sorted(self, tokens):
+        """Whether this call's rows reach the experts sorted by expert
+        (``moe.experts_grouped``: a chunk dispatch's many rows) or as they
+        lie (``moe.experts_gmm``: a decode step's few) — the dropless
+        layer's one decision, both routers', from the row count.  The
+        sorted kernel is the gated experts'."""
+        return self.gated and not self.is_initializing() \
+            and tokens.shape[0] >= dropless.GROUPED_MIN_ROWS
+
     def _scored(self, tokens, gate_w, experts, first, held, live):
         """``shared(x) + sum over the chosen experts that are HELD of
         gate_e * E_e(x)`` (+ the chosen zero experts' gates times ``x``);
@@ -204,11 +217,11 @@ class MoE(nn.Module):
             sum_eps=self.gate_sum_eps, scoring=self.scoring)
         local, counts, elsewhere = dropless.held_load(choice, first, held,
                                                       real)
-        if self.is_initializing() or tokens.shape[0] < dropless.GROUPED_MIN_ROWS:
+        if self._sorted(tokens):
+            y = experts(tokens, grouped=(local, gate))
+        else:
             y = experts(tokens, routed=(
                 dropless.combine_of(local, gate, held), counts))
-        else:
-            y = experts(tokens, grouped=(local, gate))
         if F:
             dense = lambda n, name: nn.Dense(n, use_bias=False,
                                              dtype=tokens.dtype, name=name)

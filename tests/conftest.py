@@ -69,6 +69,33 @@ def _reset_topology():
     topology.reset_topology()
 
 
+# a quarter of Linux's default ``vm.max_map_count`` (65,530): one module of
+# the serving tests has been seen to add 23,000 maps before its teardown
+_MAP_BUDGET = 16384
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    """A worker keeps every XLA:CPU executable it ever compiled — three
+    memory maps each, eager ``jnp`` operations included: ~19,000 of them
+    five sixths of the way through the suite — and once the process passes
+    ``vm.max_map_count`` the NEXT compile dies of a segmentation fault
+    inside XLA, in whatever test happens to run (the worker is lost, and
+    its tests with it).  Between modules, past a quarter of that limit, drop
+    JAX's caches: the maps go with them (57,000 → under 1,000, measured),
+    and what a later module needs compiles again."""
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            maps = sum(1 for _ in f)
+    except OSError:          # no procfs: nothing to count, nothing to do
+        return
+    if maps > _MAP_BUDGET:
+        import gc
+        jax.clear_caches()
+        gc.collect()
+
+
 @pytest.fixture
 def eight_devices():
     import jax

@@ -7,6 +7,7 @@ family's own — normal draws from a seed, bf16-exact — so program and
 reference share nothing but the seed."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -357,6 +358,96 @@ def test_kernels_match_plain_einsums(tokens, width, renormalize, gated):
     assert not np.asarray(combine)[tokens:].any()
     np.testing.assert_array_equal(np.asarray(counts), want_n)
     np.testing.assert_allclose(np.asarray(out), want_out, atol=1e-5)
+
+
+def _dispatch_layer(rows, dtype=jnp.float32):
+    """The softmax layer over ``rows`` chunk rows of 128 tokens — what a
+    chunk dispatch hands it — with a padded tail in row 1 and the last row
+    DEAD: ``(apply(x, live) -> (y, counts), x, live, params)``."""
+    layer = _layer(None).clone(dtype=dtype)
+    ks = jax.random.split(jax.random.key(rows), 2)
+    x = jax.random.normal(ks[0], (rows, 128, 64), dtype)
+    last = np.full((rows,), 127)
+    last[1], last[-1] = 40, -1
+    live = jnp.arange(128)[None, :] <= jnp.asarray(last)[:, None]
+    params = layer.init(ks[1], x[:1, :8], train=False)
+    apply = lambda x, live: layer.apply(params, x, train=False,
+                                        live=live)[::2]
+    return apply, x, live, params["params"]
+
+
+def _expert_kernels(fn, *args):
+    return set(re.findall(r"name=(moe\.experts[a-z_]*)",
+                          str(jax.make_jaxpr(fn)(*args))))
+
+
+def test_a_dispatch_of_512_rows_takes_the_sorted_form():
+    """Four rows of 128 are one 512-token call of the softmax layer:
+    ``moe.experts_grouped`` (interpreted) over the router's own picks —
+    against the float32 oracle, and against the gmm form the same tokens
+    take three rows at a time; dead tokens (a padded tail, a whole dead
+    row) get zeros from both and are counted by neither."""
+    apply, x, live, p = _dispatch_layer(4)
+    assert _expert_kernels(apply, x, live) == {"moe.experts_grouped"}
+    assert _expert_kernels(apply, x[:3], live[:3]) == {"moe.experts_gmm"}
+    y, counts = apply(x, live)
+    w = p["ExpertsMLP_0"]
+    _, want_n, want = _oracle(
+        x.reshape(-1, 64), p["gate_kernel"], TOP_K, False,
+        live.reshape(-1), w["experts_wg"], w["experts_wi"], w["experts_wo"])
+    np.testing.assert_array_equal(np.asarray(counts), want_n)
+    assert int(counts.sum()) == (128 + 41 + 128) * TOP_K
+    np.testing.assert_allclose(np.asarray(y).reshape(-1, 64), want,
+                               atol=1e-5)
+    assert not np.asarray(y)[~np.asarray(live)].any()
+    gmm = jnp.concatenate([apply(x[:3], live[:3])[0],
+                           apply(x[3:], live[3:])[0]])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(gmm), atol=1e-5)
+
+
+def test_the_sorted_form_in_bfloat16_is_as_close_as_the_gmm_form():
+    """The configuration's precision: both forms of the 512-token call
+    against the float32 oracle on the same bfloat16-exact inputs — the
+    sorted form rounds each pair's output before the gates weigh it, the
+    gmm form each gated hidden row before the down projection; neither is
+    the closer by more than a factor of two."""
+    apply, x, live, p = _dispatch_layer(4, jnp.bfloat16)
+    w = jax.tree.map(lambda a: a.astype(jnp.bfloat16).astype(jnp.float32),
+                     p["ExpertsMLP_0"])
+    _, _, want = _oracle(
+        x.reshape(-1, 64).astype(jnp.float32), p["gate_kernel"], TOP_K,
+        False, live.reshape(-1), w["experts_wg"], w["experts_wi"],
+        w["experts_wo"])
+    err = lambda y: float(jnp.abs(
+        y.reshape(-1, 64).astype(jnp.float32) - want).mean())
+    grouped = err(apply(x, live)[0])
+    gmm = err(jnp.concatenate([apply(x[:2], live[:2])[0],
+                               apply(x[2:], live[2:])[0]]))
+    assert 0 < grouped < 2 * gmm and gmm < 2 * grouped
+
+
+def test_picks_of_names_no_expert_for_a_dead_token():
+    combine = jnp.zeros((4, EXPERTS)).at[0, 5].set(0.7).at[0, 2].set(0.1) \
+        .at[2, 7].set(0.4).at[2, 0].set(0.4)
+    local, gate = dropless.picks_of(combine, TOP_K)
+    np.testing.assert_array_equal(
+        np.asarray(local), [[5, 2], [EXPERTS] * 2, [0, 7], [EXPERTS] * 2])
+    np.testing.assert_allclose(
+        np.asarray(gate), [[0.7, 0.1], [0, 0], [0.4, 0.4], [0, 0]])
+
+
+def test_the_sorted_layout_gathers_from_no_expert_sized_table():
+    """What the layout needs of an expert (its first row, its first place
+    in the sorted order) is looked up as a one-hot sum: a ``gather`` of
+    T x k indices into a ``[experts]`` table cost XLA's TPU compiler ~0.4 s
+    apiece, two a layer — 9 s of ``setup_s`` in an eight-layer chunk
+    program (PERF.md section 6, PR 59)."""
+    jaxpr = jax.make_jaxpr(lambda local: dropless.grouped_layout(
+        local, 64, 128))(jnp.zeros((512, TOP_K), jnp.int32))
+    tables = [eqn.invars[0].aval.shape for eqn in jaxpr.jaxpr.eqns
+              if eqn.primitive.name == "gather"]
+    assert tables and all(shape[0] >= 512 * TOP_K for shape in tables), \
+        tables
 
 
 def test_no_live_token_gives_zeros():

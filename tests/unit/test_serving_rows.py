@@ -274,14 +274,14 @@ def test_cancel_of_the_pending_prompt_spares_those_admitted_beside_it(
 
 @pytest.mark.parametrize("case,want", [
     ("dense_chunk_128", 4), ("dense_chunk_8", 64), ("dense_chunk_256", 2),
-    ("chunk_512", 1), ("speculation", 1), ("experts", 1),
+    ("chunk_512", 1), ("speculation", 1), ("experts", 4),
     ("state_kinds", 1), ("own_chunk_path", 1), ("chunk_cap", 4),
     ("chunk_fault", 4), ("row_scatter_form", 1),
 ])
 def test_who_takes_rows_is_observed(case, want):
     """``chunk_rows``: what the chunk kernel's bound holds of the chunk
-    the user set — and ONE row for a contract with dropless experts, with
-    per-slot state or a chunk geometry of its own (``own_chunk_path``,
+    the user set, dropless experts or none — and ONE row for a contract
+    with per-slot state or a chunk geometry of its own (``own_chunk_path``,
     SAID: a contract that merely declares a cap or a fault function, the
     defaults' own values, gets the rows a silent one gets), under
     speculation, where the chunk is the bound already, and where the write
@@ -332,3 +332,82 @@ def test_one_row_servers_keep_the_scalar_start_program(rows_engine,
         assert out.shape == (603,)
     finally:
         srv.close()
+
+
+# --------------------------------------------------------------------- #
+# An expert model takes rows too: an expert layer flattens a dispatch's
+# rows to tokens, so 4 rows of 128 are ONE call of it — 512 tokens, where
+# gated experts take the sorted form (``moe.experts_grouped``) — and the
+# dispatch has one load vector, whoever's rows it carried.
+# --------------------------------------------------------------------- #
+TOP_K, EXPERT_LAYERS = 2, 1              # moe_every 2 of 2 layers
+EXPERT_FORMS = {"sorted": dict(gated_mlp=True, activation="silu"),
+                "gmm": {}}                # un-gated experts: never sorted
+
+
+@pytest.mark.parametrize("form", list(EXPERT_FORMS))
+def test_expert_rows_of_two_prompts_and_a_dead_row(form, tmp_path):
+    """Prompts of 2 and 1 chunks in one iteration of an idle server (three
+    live rows and a dead one), then one of 4 chunks, traced.  ONE test a
+    form — one engine built, in one worker, whatever ``--dist`` says:
+
+    * greedy tokens of every request are its solo ``generate()`` run's
+      (one row of 128 a call there: ``moe.experts_gmm`` in either form),
+      and the 7 chunks took ``ceil(7 / 4)`` dispatches;
+    * every live token — the prompts' 800, and each generated token but a
+      request's last — chose ``top_k`` experts an expert layer; the dead
+      row's 128 tokens and the tails' 96 chose none;
+    * the dispatch's one load vector rides with its FIRST row's admission
+      and is summed once: the admit waits carry ``moe_calls`` = expert
+      layers for requests 0 and 2 and nothing for request 1, and the spans'
+      assignments add up to the statistics'."""
+    import json
+    from deepspeed_tpu.monitor import trace as span_trace
+    model = Transformer(tiny_cfg(
+        max_seq_len=1024, moe_num_experts=4, moe_top_k=TOP_K,
+        moe_capacity_factor=None, scan_layers=False, **EXPERT_FORMS[form]))
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, 97, (2, 12)),
+                      jnp.int32)
+    eng = deepspeed_tpu.init_inference(
+        model, config={"dtype": "float32", "prefill_chunk_size": 128,
+                       "serving": ROWS})
+    eng.set_params(model.init(jax.random.key(0), {"input_ids": ids}))
+    srv, patch = eng.serve(tracing=True), pytest.MonkeyPatch()
+    try:
+        assert (srv.chunk, srv.chunk_rows) == (128, 4)
+        log, _, _ = _record_dispatches(srv, patch)
+        prompts = _long_prompts(np.random.default_rng(31), (200, 100, 500))
+        rids = [srv.submit(p, max_new_tokens=5) for p in prompts[:2]]
+        srv.step()
+        rids.append(srv.submit(prompts[2], max_new_tokens=5))
+        a, b, c = rids
+        outs = srv.drain()
+        path = srv.dump_trace(str(tmp_path / "trace.json"))
+        with srv._lock:
+            stats, tokens = dict(srv.stats), srv.moe_expert_tokens.copy()
+    finally:
+        patch.undo()
+        srv.close()
+        span_trace.disable()
+    assert log == [[(a, 0), (a, 1), (b, 0)],
+                   [(c, 0), (c, 1), (c, 2), (c, 3)]]
+    assert stats["prefill_dispatches"] == 2 and stats["prefill_rows"] == 7
+    _assert_solo(eng, outs, rids, prompts, 5)
+
+    prompt_tokens = sum(len(p) for p in prompts)
+    live = prompt_tokens + 3 * (5 - 1)
+    assert stats["moe_assignments"] == live * TOP_K * EXPERT_LAYERS \
+        == tokens.sum()
+    assert tokens.shape == (EXPERT_LAYERS, 4)
+
+    with open(path) as f:
+        events = json.load(f)["traceEvents"]
+    loads = [e["args"] for e in events
+             if e["name"] in ("dstpu.sched.wait_device", "dstpu.sched.commit")
+             and "moe_calls" in e["args"]]
+    admits = [x for x in loads if x.get("event") == "admit"]
+    assert [x["moe_calls"] for x in admits] == [EXPERT_LAYERS] * 2
+    assert sum(x["moe_assignments"] for x in admits) \
+        == prompt_tokens * TOP_K * EXPERT_LAYERS
+    assert sum(x["moe_assignments"] for x in loads) \
+        == stats["moe_assignments"]

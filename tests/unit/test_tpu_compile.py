@@ -154,13 +154,21 @@ def _mono_decode(batch=16, cache_len=1024):
                 ((batch, H, D), BF16), ((batch, H, D), BF16)]
 
 
-def _moe_experts(tokens, hidden=2048, experts=64, width=1024, top_k=8):
-    """OLMoE-1B-7B's expert layer (``moe.route`` + ``moe.experts_gmm``) at
-    a serving program's token count: 64 decode lanes, or one chunk."""
+def _moe_experts(tokens, sorted_form=False, hidden=2048, experts=64,
+                 width=1024, top_k=8):
+    """OLMoE-1B-7B's expert layer at a serving program's token count:
+    ``moe.route`` + ``moe.experts_gmm`` (64 decode lanes, or one chunk), or
+    — ``sorted_form``, as ``MoE`` runs a chunk DISPATCH's 4 rows of 128 —
+    the picks read back off the combine matrix and
+    ``moe.experts_grouped``."""
     up = ((experts, hidden, width), BF16)
 
     def fn(x, gate_w, live, wg, wu, wd):
         combine, counts = moe_mod.route(x, gate_w, top_k, live=live)
+        if sorted_form:
+            return moe_mod.experts_grouped(
+                x, *moe_mod.picks_of(combine[:tokens], top_k), wg, wu, wd,
+                jax.nn.silu), counts
         return moe_mod.experts(x, combine, counts, wg, wu, wd,
                                jax.nn.silu), counts
     return fn, [((tokens, hidden), BF16), ((hidden, experts), BF16),
@@ -387,6 +395,7 @@ CASES = {
     "moe_experts_olmoe_t64": lambda: _moe_experts(64),
     "moe_experts_olmoe_t128": lambda: _moe_experts(128),
     "moe_experts_olmoe_t512": lambda: _moe_experts(512),
+    "moe_sorted_olmoe_r4c128": lambda: _moe_experts(512, sorted_form=True),
     "flash_fwd_bwd_s2048": _flash_fwd_bwd,
     # opt67b-zero3-4chip's shard: micro-batch 4, 32 heads of 128
     "flash_fwd_bwd_s2048_d128": lambda: _flash_fwd_bwd(4, head_dim=128),
@@ -665,6 +674,42 @@ def test_evabyte_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 12.4e9 < total < 14e9, f"{total / 1e9:.2f} GB"
+
+
+def test_olmoe_chunk_step_compiles_at_four_rows(one_chip, mosaic):
+    """``olmoe-serve-gen-batch``'s chunk dispatch as ``serving/slots.py``
+    builds it at the cell's own settings — ``chunk_rows`` = 4 rows of 128,
+    a start and a last position a row, the load vector beside the logits:
+    every layer's 512-token expert call is the sorted form
+    (``moe.experts_grouped``; no ``moe.experts_gmm`` in the program), the
+    pools are aliased input -> output, and weights, pools and temporaries
+    are the 11.7 GB the cell's sizing reckons — the [4, 128] dispatch adds
+    its sorted rows (8 x 4,096 pairs padded to tiles: 20 MB a layer) and no
+    pool-sized value."""
+    from deepspeed_tpu.inference.serving import slots
+    c = _slot_programs_of("olmoe-serve-gen-batch", "olmoe", one_chip)
+    rows = slots.chunk_rows(c.declared, c.chunk, c.serving["page_size"])
+    assert (c.chunk, rows, c.declared.routes_experts) == (128, 4, True)
+    compiled = slots.make_chunk_fn(c.module, c.declared, None).lower(
+        c.params, c.pool, c.ints(rows, c.pages.table_width),
+        c.ints(rows, c.chunk), c.ints(rows), c.ints(rows)).compile()
+    text = compiled.as_text()
+    layers = c.declared.expert_layers
+    assert layers == 8
+    calls = lambda name: len(re.findall(
+        rf"%{re.escape(name)}[.\d]* = ", text))
+    assert (calls("moe.experts_grouped"), calls("moe.route"),
+            calls("moe.experts_gmm")) == (layers, layers, 0)
+    logits, _, load = compiled.out_info
+    assert logits.shape[:2] == (rows, 1)
+    assert load.shape == (layers * c.declared.experts + 2,)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * 2 for x in jax.tree.leaves(c.pool))
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    assert mem.temp_size_in_bytes < 0.3e9, mem.temp_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 11.6e9 < total < 12.1e9, f"{total / 1e9:.2f} GB"
 
 
 def _no_pool_layer_is_sliced_out(text, pages):

@@ -43,7 +43,7 @@ K/V, and at many slots larger than the weights).
 """
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +77,7 @@ class GraniteHybridConfig:
     rms_norm_eps: float = 1e-5
     held_experts: Optional[Tuple[int, int]] = None
     dtype: str = "bfloat16"
+    mamba_groups = 1             # one B and one C for every head
 
     @property
     def jnp_dtype(self):
@@ -167,8 +168,11 @@ def granite_hybrid_model(hf, held_experts=None, **overrides):
 class Mamba2Mixer(nn.Module):
     """The state-space mixer, a state mixer of ``models/hybrid.py`` over
     two pools: ``conv [SSM layers, rows, ...short_conv.rows_shape]`` and
-    ``ssm [SSM layers, rows, ...ssd.state_shape]``."""
-    config: GraniteHybridConfig
+    ``ssm [SSM layers, rows, ...ssd.state_shape]``.  Also
+    ``models/nemotron_h.py``'s, whose config says ``mamba_groups`` 8: ``B``
+    and ``C`` a GROUP of heads, and the gate-norm over each group's
+    channels separately (one group is the whole width)."""
+    config: Any                  # the fields of GraniteHybridConfig read here
 
     @nn.compact
     def __call__(self, u, state=None, start=None, last=None, live=None):
@@ -179,7 +183,7 @@ class Mamba2Mixer(nn.Module):
         cfg = self.config
         H, P, N, K = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state, \
             cfg.conv_size
-        W, CW = cfg.mamba_width, cfg.conv_width
+        W, CW, G = cfg.mamba_width, cfg.conv_width, cfg.mamba_groups
         f32 = jnp.float32
         dense = lambda n, name: nn.Dense(n, use_bias=False,
                                          dtype=cfg.jnp_dtype, name=name)
@@ -203,8 +207,10 @@ class Mamba2Mixer(nn.Module):
             dt_bias, a_log = vector("dt_bias", H), vector("A_log", H)
             skip = vector("D", H, nn.initializers.ones)
             with jax.named_scope("ssd.scan"):
-                x, b, c = jnp.split(xbc, [W, W + N], axis=-1)
+                x, b, c = jnp.split(xbc, [W, W + G * N], axis=-1)
                 x = x.reshape(-1, H, P)
+                if G > 1:
+                    b, c = b.reshape(-1, G, N), c.reshape(-1, G, N)
                 step = jax.nn.softplus(dt.astype(f32) + dt_bias)
                 decay = -jnp.exp(a_log) * step            # log, at most 0
                 if state is None:
@@ -218,8 +224,14 @@ class Mamba2Mixer(nn.Module):
                     .astype(cfg.jnp_dtype).reshape(-1, W)
             gain = vector("norm", W, nn.initializers.ones)
             with jax.named_scope("ssd.gate_norm"):
-                y = _rms((y.astype(f32) * nn.silu(z.astype(f32)))
-                         .astype(cfg.jnp_dtype), gain, cfg.rms_norm_eps)
+                y = (y.astype(f32) * nn.silu(z.astype(f32))) \
+                    .astype(cfg.jnp_dtype)
+                if G > 1:                # the mean of squares a group
+                    y = _rms(y.reshape(-1, G, W // G),
+                             gain.reshape(G, W // G),
+                             cfg.rms_norm_eps).reshape(-1, W)
+                else:
+                    y = _rms(y, gain, cfg.rms_norm_eps)
             # no state given: nothing is kept (the one-row pool was scratch)
             return dense(cfg.hidden_size, "out_proj")(y), (
                 conv_pool, ssm_pool if state is not None else None)

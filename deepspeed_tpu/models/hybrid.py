@@ -1,10 +1,16 @@
 """One skeleton for the page-and-state-row model families
-(``models/lfm2.py``, ``models/solar_open2.py``, ``models/granite_hybrid.py``)
-and the grouped-query attention module they share with ``models/trinity.py``.
+(``models/lfm2.py``, ``models/solar_open2.py``, ``models/granite_hybrid.py``,
+``models/nemotron_h.py``) and the grouped-query attention module they share
+with ``models/trinity.py``.
 
-A model of this kind is an embedding; a list of layers, each ``norm -> mixer
--> residual -> norm -> MLP -> residual``; a final norm and a head.  By the
-layer's index the mixer is either
+A model of this kind is an embedding; a list of layers; a final norm and a
+head.  A layer is ``norm -> mixer -> residual -> norm -> MLP -> residual``
+— or, where the family declares ``expert_blocks`` (Nemotron-H), ONE
+sublayer: ``norm -> sublayer -> residual``, the sublayer a mixer OR the
+expert layer, so that the layers that attend, those that keep a state and
+those that route experts are three counts (:meth:`Hybrid.state_layers`,
+:meth:`Hybrid.expert_layers`), not two.  By the layer's index the mixer is
+either
 
 * grouped-query softmax attention (:class:`GroupedQueryAttention`) whose K/V
   rows lie in LANE pages under the slot's page table, growing with the
@@ -161,19 +167,43 @@ class Hybrid:
     mixer: Tuple[str, Any]       # its name and its class, built on the config
     state: Tuple[StateKind, ...]
     # the family's arguments of ``moe/layer.py::MoE``, as ``moe_mlp``, beside
-    # what every family of this kind has: dropless gated SiLU experts at the
-    # config's ``hidden_size`` and dtype
+    # what every family of this kind has: dropless experts at the config's
+    # ``hidden_size`` and dtype — gated SiLU ones unless it says otherwise
+    # (``gated``, ``activation``)
     moe: dict
     # a dense SwiGLU's name, its width and how many FIRST layers carry it
     dense: Tuple[Optional[str], int, int] = (None, 0, 0)
-    norms: Tuple[str, str] = ("input_layernorm", "post_attention_layernorm")
+    # the norm before each sublayer of a layer (one name: one sublayer)
+    norms: Tuple[str, ...] = ("input_layernorm", "post_attention_layernorm")
     final_norm: str = "norm"
+    # a layer is ONE sublayer where given: these layers are the expert layer
+    # alone (after any ``dense`` ones), ``attention_layers`` attention alone
+    # and the rest the state mixer alone.  None: a mixer AND an MLP a layer
+    expert_blocks: Optional[Tuple[int, ...]] = None
     tied: bool = False           # the head is the embedding; else ``lm_head``
     # the prefix of the family's work counters — ``<p>_scan_rows``,
     # ``<p>_state_rows``, beside ``full_keys`` — where it has a chunk path
     # of its own: up to ``CHUNK_CAP`` positions, one chunk a dispatch (the
     # state is a slot's)
     work: Optional[str] = None
+
+    def sublayers(self, i):
+        """Layer ``i``'s sublayers in order, each by the attribute it is."""
+        mlp = self.dense[0] if i < self.dense[2] else "moe_mlp"
+        if self.expert_blocks is not None and i in self.expert_blocks:
+            return (mlp,)
+        mixer = "self_attn" if i in self.attention_layers else self.mixer[0]
+        return (mixer,) if self.expert_blocks is not None else (mixer, mlp)
+
+    def state_layers(self, num_layers):
+        """The layers whose mixer is the state mixer: the state pools'
+        layers, in order."""
+        return tuple(i for i in range(num_layers)
+                     if self.mixer[0] in self.sublayers(i))
+
+    def expert_layers(self, num_layers):
+        """How many layers route experts: the load vector's rows."""
+        return sum("moe_mlp" in self.sublayers(i) for i in range(num_layers))
 
 
 class HybridLayer(nn.Module):
@@ -184,34 +214,34 @@ class HybridLayer(nn.Module):
     def setup(self):
         cfg, i = self.config, self.layer_idx
         d = self.family.declare(cfg)
-        for name in d.norms:
-            setattr(self, name, _Norm(d.norm_eps))
-        if i in d.attention_layers:
-            self.self_attn = GroupedQueryAttention(d.attention)
-        else:
-            setattr(self, d.mixer[0], d.mixer[1](cfg))
-        if i < d.dense[2]:
-            setattr(self, d.dense[0], _Mlp(d.dense[1], cfg.jnp_dtype))
-        else:
-            self.moe_mlp = MoE(
-                hidden_size=cfg.hidden_size, capacity_factor=None,
-                dtype=cfg.jnp_dtype, gated=True, activation=nn.silu, **d.moe)
+        build = {
+            "self_attn": lambda: GroupedQueryAttention(d.attention),
+            d.mixer[0]: lambda: d.mixer[1](cfg),
+            d.dense[0]: lambda: _Mlp(d.dense[1], cfg.jnp_dtype),
+            "moe_mlp": lambda: MoE(**{
+                "hidden_size": cfg.hidden_size, "capacity_factor": None,
+                "dtype": cfg.jnp_dtype, "gated": True, "activation": nn.silu,
+                **d.moe})}
+        for norm, name in zip(d.norms, d.sublayers(i)):
+            setattr(self, norm, _Norm(d.norm_eps))
+            setattr(self, name, build[name]())
 
     def __call__(self, x, mix, live=None):
         """``mix(mixer, normed x) -> (out, cache)``: the call form the model
-        chose (chunk or step) with this layer's cache."""
-        d, i, cfg = self.family.declare(self.config), self.layer_idx, \
-            self.config
-        add = self.family.residual
-        mixer = self.self_attn if i in d.attention_layers \
-            else getattr(self, d.mixer[0])
-        a, cache = mix(mixer, getattr(self, d.norms[0])(x))
-        x = add(cfg, x, a)
-        m = getattr(self, d.norms[1])(x)
-        if i < d.dense[2]:
-            return add(cfg, x, getattr(self, d.dense[0])(m)), cache
-        y, _, _ = self.moe_mlp(m, train=False, live=live)
-        return add(cfg, x, y), cache
+        chose (chunk or step) with this layer's cache — None from a layer
+        that is its experts alone."""
+        d, cfg = self.family.declare(self.config), self.config
+        cache = None
+        for norm, name in zip(d.norms, d.sublayers(self.layer_idx)):
+            h = getattr(self, norm)(x)
+            if name == "moe_mlp":
+                y, _, _ = self.moe_mlp(h, train=False, live=live)
+            elif name == d.dense[0]:
+                y = getattr(self, name)(h)
+            else:
+                y, cache = mix(getattr(self, name), h)
+            x = self.family.residual(cfg, x, y)
+        return x, cache
 
 
 class HybridModel(nn.Module):
@@ -253,6 +283,7 @@ class HybridModel(nn.Module):
             x = self._embed(ids)
             positions = jnp.arange(ids.shape[0])[None]
             for i, layer in enumerate(self.layers):
+                # (a layer that is its experts alone calls neither)
                 if i in d.attention_layers:
                     mix = lambda op, u: (op(u[None], positions)[0][0], None)
                 else:
@@ -265,8 +296,8 @@ class HybridModel(nn.Module):
     def slot_contract(self):
         """For the slot engine (``models/contract.py``): K/V pages under the
         slot's table for the attention layers; behind its STATE ROW the
-        state layers' kinds; dropless experts after the dense layers, where
-        the family holds a share of them the held ones."""
+        state layers' kinds; dropless experts in the layers that route
+        them, where the family holds a share of them the held ones."""
         cfg, d = self.config, self.declare(self.config)
         held = d.moe.get("held_experts")
         own_path = {} if d.work is None else dict(
@@ -281,7 +312,7 @@ class HybridModel(nn.Module):
             dtype=cfg.dtype, num_layers=cfg.num_layers,
             state_kinds=tuple(kind.name for kind in d.state),
             routes_experts=True, holds_share=held is not None,
-            expert_layers=cfg.num_layers - d.dense[2],
+            expert_layers=d.expert_layers(cfg.num_layers),
             experts=(held or (0, d.moe["num_experts"]))[1], **own_path)
 
     @staticmethod
@@ -311,7 +342,7 @@ class HybridModel(nn.Module):
     def _work(self, scanned, moved, keys):
         d = self.declare(self.config)
         attention = len(d.attention_layers)
-        state = self.config.num_layers - attention
+        state = len(d.state_layers(self.config.num_layers))
         return {d.work + "_scan_rows": state * scanned,
                 d.work + "_state_rows": state * moved,
                 "full_keys": attention * keys}
@@ -333,7 +364,7 @@ class HybridModel(nn.Module):
         z, attention = d.attention, len(d.attention_layers)
         kv = (attention, int(num_pages), int(page_size),
               z.num_kv_heads * z.head_dim)
-        rows = (cfg.num_layers - attention, int(state_rows))
+        rows = (len(d.state_layers(cfg.num_layers)), int(state_rows))
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
                 **{kind.name: jnp.zeros(
                     rows + tuple(kind.row(dtype) if callable(kind.row)
@@ -367,7 +398,8 @@ class HybridModel(nn.Module):
                 else logits_at[0].astype(jnp.int32)
 
         # a layer calls its ``mix`` inside its own call: both read ``kv``,
-        # ``pools``, ``i`` and ``attended`` as the loop below has them then
+        # ``pools``, ``attended`` and ``scanned`` as the loop below has them
+        # then
         def attend(op, u):
             out, new = op(lift(u), positions, {
                 **kv, "pages": table, **marker,
@@ -375,18 +407,22 @@ class HybridModel(nn.Module):
             return (out[:, 0] if per_row else out[0]), new
 
         def scan(op, u):
-            state = (*pools, i - attended, rows)
+            state = (*pools, scanned, rows)
             if per_row:
                 return op(u, state, live=flat_live)
             return op(u, state, start_pos, last)
 
-        x, attended = self._embed(ids), 0      # attention layers so far
+        state_layers = d.state_layers(self.config.num_layers)
+        x, attended, scanned = self._embed(ids), 0, 0      # layers so far
         for i, layer in enumerate(self.layers):
             if i in d.attention_layers:
                 x, new = layer(x, attend, live=flat_live)
                 kv, attended = {"k": new["k"], "v": new["v"]}, attended + 1
-            else:
+            elif i in state_layers:
                 x, pools = layer(x, scan, live=flat_live)
+                scanned += 1
+            else:                        # its experts alone: no cache
+                x, _ = layer(x, None, live=flat_live)
         with jax.named_scope("slots.tables"):
             h = lift(x)
         return self._head(h, logits_at), {

@@ -1,6 +1,9 @@
 """Dropless routed experts — the inference path of a sparse-expert model
 (OLMoE / Mixtral-style: softmax over all experts, top-k, every chosen
-(token, expert) pair computed, whatever the imbalance).
+(token, expert) pair computed, whatever the imbalance).  An expert is GATED
+(three matrices, ``(act(x wg) * (x wu)) wd``: OLMoE, dots3, LFM2, ...) or
+UN-GATED (two, ``act(x wu) wd``: Nemotron-H's ``relu2``) — both kernels
+below, both routers and the layer's row-count decision take either.
 
 ``sharded_moe.py`` is the GShard formulation: ``[tokens, experts,
 capacity]`` dispatch tensors and a capacity past which a token is DROPPED.
@@ -27,7 +30,7 @@ findable in a device trace by its own name:
 
 At a decode step's token count (one a slot) or a lone chunk's (<= 128)
 the layer is bound by streaming the touched experts' weights, not by the
-arithmetic — on a v5e 64 rows through an expert's three matrices take
+arithmetic — on a v5e 64 rows through a gated expert's three matrices take
 about the 15 us their 12.6 MB take to arrive — so the zero-weight rows
 ride for free, and no sort, gather or scatter stands around the matmuls:
 the weighted combine is the kernel's own accumulation.  Rows are
@@ -43,7 +46,7 @@ bias and whose chip holds a SHARE of the experts (``models/dots3.py``):
 * ``moe.experts_grouped`` (:func:`experts_grouped`) — a chunk's rows
   sorted by the HELD expert they chose, each expert's rows padded to a
   whole row tile, and one kernel over the live tiles: a tile reads its
-  expert's matrices and computes its own rows only.  At 8 of 256 experts
+  expert's (three or two) matrices and computes its own rows only.  At 8 of 256 experts
   a token, a 2,048-token chunk hands each of 32 held experts ~64 rows;
   the dense form above would compute 32 x 2,048.  The softmax layer's
   chunk DISPATCH (4 rows of 128: one 512-token call) takes it too, its
@@ -155,10 +158,17 @@ def _fetch_plan(counts, nf):
 
 
 def _width_tile(F):
-    for t in (1024, 512, 256, 128):
+    """The width tile of an expert's matrices: the largest of 1024, 512 and
+    256 that divides ``F``; for an odd number of lane tiles (1920 = 15 x
+    128) the most whole lane tiles that divide it under 1024 (640: at 128
+    the accumulator's update a grid step costs what the step's DMA does);
+    the whole width where 128 does not divide it."""
+    for t in (1024, 512, 256):
         if F % t == 0:
             return t
-    return F
+    if F % 128:
+        return F
+    return max(128 * d for d in range(1, 9) if (F // 128) % d == 0)
 
 
 def _gmm_kernel(on_ref, src_ref, pin_ref, x_ref, cw_ref, *rest, act, gated):
@@ -322,8 +332,11 @@ def picks_of(combine, k):
         gate
 
 
-def _grouped_kernel(tiles_ref, expert_ref, x_ref, wg_ref, wu_ref, wd_ref,
-                    o_ref, acc_ref, *, act):
+def _grouped_kernel(tiles_ref, expert_ref, x_ref, *rest, act, gated):
+    if gated:
+        wg_ref, wu_ref, wd_ref, o_ref, acc_ref = rest
+    else:
+        wu_ref, wd_ref, o_ref, acc_ref = rest
     t, f = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t < tiles_ref[0])
@@ -333,8 +346,13 @@ def _grouped_kernel(tiles_ref, expert_ref, x_ref, wg_ref, wu_ref, wd_ref,
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         x = x_ref[...]
-        h = act(jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)) \
-            * jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        if gated:
+            h = act(jnp.dot(x, wg_ref[0],
+                            preferred_element_type=jnp.float32)) \
+                * jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        else:
+            h = act(jnp.dot(x, wu_ref[0],
+                            preferred_element_type=jnp.float32))
         acc_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[0],
                                 preferred_element_type=jnp.float32)
 
@@ -384,9 +402,12 @@ def experts_grouped(x, local, gate, wg, wu, wd, act, tile=128):
     ``sum_j gate[t, j] * E_{local[t, j]}(x[t])`` over the pairs a held
     expert takes — real rows only, each expert's matrices read once a
     row tile.  ``local``/``gate [T, k]`` from :func:`held_load` /
-    :func:`route_scored`; ``wg``/``wu [E, M, F]``, ``wd [E, F, M]``."""
+    :func:`route_scored`; ``wg``/``wu [E, M, F]``, ``wd [E, F, M]``
+    (``wg`` None: un-gated experts, as :func:`experts`)."""
     T, M = x.shape
     E, _, F = wu.shape
+    gated = wg is not None
+    n_up = 2 if gated else 1
     dest, source, tile_expert, live_tiles = grouped_layout(local, E, tile)
     rows = source.shape[0]
     xs = x[source]
@@ -404,10 +425,10 @@ def experts_grouped(x, local, gate, wg, wu, wd, act, tile=128):
     row_spec = pl.BlockSpec((tile, M),
                             lambda t, f, tiles, ex: (row_of(t, tiles), 0))
     out = pl.pallas_call(
-        functools.partial(_grouped_kernel, act=act),
+        functools.partial(_grouped_kernel, act=act, gated=gated),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(rows // tile, nf),
-            in_specs=[row_spec, up_spec, up_spec, down_spec],
+            in_specs=[row_spec] + [up_spec] * n_up + [down_spec],
             out_specs=row_spec,
             scratch_shapes=[pltpu.VMEM((tile, M), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((rows, M), x.dtype),
@@ -415,11 +436,11 @@ def experts_grouped(x, local, gate, wg, wu, wd, act, tile=128):
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=min(
                 100 * 1024 * 1024,
-                6 * M * tf * itemsize + tile * (8 * M + 16 * tf)
-                + 16 * 1024 * 1024)),
+                2 * (n_up + 1) * M * tf * itemsize
+                + tile * (8 * M + 16 * tf) + 16 * 1024 * 1024)),
         interpret=_interpret(),
         name="moe.experts_grouped",
-    )(live_tiles[None], tile_expert, xs, wg, wu, wd)
+    )(live_tiles[None], tile_expert, xs, *([wg] if gated else []), wu, wd)
     # each token gathers its pairs' rows back, weighted by their gates
     # (a pair no held expert took reads the zero row appended here)
     out = jnp.concatenate([out, jnp.zeros((1, M), out.dtype)])

@@ -7,6 +7,15 @@ on the ``ep`` mesh axis (see ``EXPERT_PARAM_PATTERN`` in
 ``sharded_moe.py`` lower to all-to-alls over ICI and expert-parameter
 gradients reduce only over the expert-data-parallel group — the semantics
 ``utils/groups.py:108`` builds with explicit process groups.
+
+The DROPLESS layer (``capacity_factor=None``, ``moe/dropless.py``: the
+serving path) has two routers — softmax top-k (``dropless.route``) and the
+scored form (sigmoid or softmax scores + a stored selection bias, a held
+share, a shared expert, zero experts) — over experts that are gated (three
+matrices) or not (two); its shared expert is gated where they are; and one
+decision, ``MoE._sorted``: from ``GROUPED_MIN_ROWS`` rows a call the rows
+reach the experts sorted by expert (``moe.experts_grouped``), under it as
+they lie (``moe.experts_gmm``) — whichever the router and the gating.
 """
 
 from typing import Any, Callable, Optional
@@ -24,7 +33,11 @@ class ExpertsMLP(nn.Module):
     (reference wraps arbitrary expert modules; ``Experts`` replicates them —
     here one einsum-batched module computes all local experts on the MXU).
     ``gated``: the three-matrix form ``(act(x wg) * (x wi)) wo`` (SwiGLU
-    with ``activation=silu``; OLMoE, Mixtral).
+    with ``activation=silu``; OLMoE, Mixtral); else ``act(x wi) wo``.
+    ``stored_size``: the width the matrices are STORED at, ``ffn_hidden_size``
+    rounded up (Nemotron-H: 1856 = 14.5 lane tiles as 1920) — the added
+    columns of ``wi`` / ``wg`` and rows of ``wo`` zeros, which is exact
+    where ``act(0) = 0``.
 
     Two call forms over the same parameters: ``experts(x [E, C, M])`` —
     the GShard batch, one capacity-sized queue an expert — and
@@ -38,17 +51,30 @@ class ExpertsMLP(nn.Module):
     dtype: Any = jnp.bfloat16
     use_bias: bool = False
     gated: bool = False
+    stored_size: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, routed=None, grouped=None):
         E, M, F = self.num_experts, self.hidden_size, self.ffn_hidden_size
-        wi = self.param("experts_wi", nn.initializers.lecun_normal(),
-                        (E, M, F), jnp.float32).astype(x.dtype)
-        wo = self.param("experts_wo", nn.initializers.lecun_normal(),
-                        (E, F, M), jnp.float32).astype(x.dtype)
-        wg = self.param("experts_wg", nn.initializers.lecun_normal(),
-                        (E, M, F), jnp.float32).astype(x.dtype) \
-            if self.gated else None
+        S = self.stored_size or F
+        if S != F and (self.use_bias or S < F):
+            raise ValueError("stored_size pads bias-free experts' width")
+        lecun = nn.initializers.lecun_normal()
+
+        def matrix(name, axis):
+            """A ``[E, M, S]`` (``axis`` 2) or ``[E, S, M]`` (1) parameter,
+            drawn at the real width and zero beyond it."""
+            def padded(key, shape, dtype):
+                real = tuple(F if i == axis else n
+                             for i, n in enumerate(shape))
+                return jnp.pad(lecun(key, real, dtype),
+                               [(0, n - r) for n, r in zip(shape, real)])
+            return self.param(name, lecun if S == F else padded,
+                              (E, M, S) if axis == 2 else (E, S, M),
+                              jnp.float32).astype(x.dtype)
+
+        wi, wo = matrix("experts_wi", 2), matrix("experts_wo", 1)
+        wg = matrix("experts_wg", 2) if self.gated else None
         if self.use_bias and (grouped is not None or routed is not None):
             raise ValueError("the dropless expert kernels carry no "
                              "per-expert biases")
@@ -100,16 +126,19 @@ class MoE(nn.Module):
     drop_tokens: bool = True
     use_residual: bool = False
     ffn_hidden_size: Optional[int] = None
+    ffn_stored_size: Optional[int] = None    # ``ExpertsMLP.stored_size``
     expert: Optional[nn.Module] = None
     dtype: Any = jnp.bfloat16
     expert_bias: bool = False
     gated: bool = False
     activation: Callable = nn.gelu
     norm_topk_prob: bool = True
-    # the dropless layer's further forms (config, not a fork):
+    # the dropless layer's further forms (config, not a fork), over gated
+    # experts (three matrices) or un-gated ones (two: ``act(x wi) wo``,
+    # Nemotron-H's ``relu2``) alike:
     # ``scoring="sigmoid"`` with a stored selection bias (``noaux_tc``:
     # the top-k of score + bias, gates from the scores), a shared expert
-    # every token takes, and ``held_experts=(first, count)`` — the chip's
+    # every token takes — gated or not as the routed ones are —, and ``held_experts=(first, count)`` — the chip's
     # share under expert parallelism: the router scores all
     # ``num_experts``, this layer holds and computes ``count`` of them
     # and adds nothing for the absent ones (``moe/dropless.py``);
@@ -142,11 +171,12 @@ class MoE(nn.Module):
         experts = self.expert or ExpertsMLP(
             held, M, self.ffn_hidden_size or 4 * M,
             activation=self.activation, dtype=self.dtype,
-            use_bias=self.expert_bias, gated=self.gated)
+            use_bias=self.expert_bias, gated=self.gated,
+            stored_size=self.ffn_stored_size)
         if self.scoring == "sigmoid" or self.noaux_tc:
-            if self.capacity_factor is not None or not self.gated:
+            if self.capacity_factor is not None:
                 raise ValueError("score + bias routing is the dropless "
-                                 "layer's, over gated experts")
+                                 "layer's (capacity_factor=None)")
             return self._scored(tokens, gate_w, experts, first, held,
                                 live).reshape(orig_shape).astype(x.dtype), \
                 0.0, None
@@ -194,9 +224,8 @@ class MoE(nn.Module):
         """Whether this call's rows reach the experts sorted by expert
         (``moe.experts_grouped``: a chunk dispatch's many rows) or as they
         lie (``moe.experts_gmm``: a decode step's few) — the dropless
-        layer's one decision, both routers', from the row count.  The
-        sorted kernel is the gated experts'."""
-        return self.gated and not self.is_initializing() \
+        layer's one decision, both routers', from the row count."""
+        return not self.is_initializing() \
             and tokens.shape[0] >= dropless.GROUPED_MIN_ROWS
 
     def _scored(self, tokens, gate_w, experts, first, held, live):
@@ -225,9 +254,13 @@ class MoE(nn.Module):
         if F:
             dense = lambda n, name: nn.Dense(n, use_bias=False,
                                              dtype=tokens.dtype, name=name)
-            y = y + dense(M, "shared_down")(
-                self.activation(dense(F, "shared_gate")(tokens))
-                * dense(F, "shared_up")(tokens))
+            if self.gated:
+                y = y + dense(M, "shared_down")(
+                    self.activation(dense(F, "shared_gate")(tokens))
+                    * dense(F, "shared_up")(tokens))
+            else:
+                y = y + dense(M, "shared_down")(
+                    self.activation(dense(F, "shared_up")(tokens)))
         sown = [("expert_tokens", counts), ("elsewhere", elsewhere)]
         if self.zero_experts:
             with jax.named_scope("moe.zero_experts"):

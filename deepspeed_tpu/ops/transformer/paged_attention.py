@@ -688,9 +688,18 @@ def _chunk_loop_vmem_bytes(c, h, d, bk, kvhd, kv_itemsize, q_itemsize):
     64 MiB floor the chunk step's next weights could not be prefetched
     into VMEM while the kernel ran, 0.66 ms of a 4.70 ms chunk
     (PERF.md, PR 36)."""
-    return (4 * bk * kvhd * kv_itemsize + 6 * c * bk * 4
-            + 4 * c * h * d * q_itemsize + _chunk_scratch_bytes(c, h, d)
-            + 16 * 1024 * 1024)
+    ask = (4 * bk * kvhd * kv_itemsize + 6 * c * bk * 4
+           + 4 * c * h * d * q_itemsize + _chunk_scratch_bytes(c, h, d)
+           + 16 * 1024 * 1024)
+    # a KV head is walked with ALL its query heads: their q columns, their
+    # accumulator columns and their outputs before they are joined.  Up to
+    # eight heads a KV head that is inside the headroom; Nemotron-H's
+    # sixteen of 128 at a 512-query block are 14.7 MB and were not (72.9 MB
+    # wanted of 63 asked, once two rows double-buffer q and the output)
+    group = h * d // kvhd
+    if group > 8:
+        ask += c * group * d * (q_itemsize + 12)
+    return ask
 
 
 def paged_chunk_prefill_attention(q, k_pool, v_pool, starts, pages, *,

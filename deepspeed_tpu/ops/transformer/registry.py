@@ -576,7 +576,8 @@ def ssm_state_update(x, dt, a, b, c, state, *, start=None, real=None,
     the state in the pool: ``state = (pool [layers, rows, H, P, N]``
     float32``, layer, rows)``.  A chunk (``start`` a scalar): ``x [T, H,
     P]``, ``dt`` / ``a [T, H]`` (step size, log-decay), ``b`` / ``c [T, N]``
-    consecutive positions of ONE slot, ``rows`` its state row, from zeros
+    (one group: every head's) or ``[T, G, N]`` (a head reads group ``h // (H
+    / G)``'s), consecutive positions of ONE slot, ``rows`` its state row, from zeros
     where ``start == 0`` and through the chunk's ``real`` rows (None: all).
     A step (``start`` None): row ``n`` is lane ``n``'s one token, ``rows
     [N]``, dead lanes (``live [N]``) write nothing.  Returns ``(y, pool)``

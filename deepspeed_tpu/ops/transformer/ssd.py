@@ -4,7 +4,9 @@ duality, arXiv:2405.21060; ``models/granite_hybrid.py``).
 A head keeps ``S [P, N]`` in float32 and no row a position.  With a step
 size ``dt_t`` (a head's, after its softplus), a log-decay ``a_t = dt_t A``
 (ONE scalar a head and position, at most 0) and ``B_t``, ``C_t [N]`` that
-EVERY head shares (one group)::
+the heads of a GROUP share — ``b`` / ``c [T, N]``: one group, every head's
+(Granite 4.0-H); ``[T, G, N]``: ``G`` groups of ``H / G`` consecutive heads
+(Nemotron-H: 8 of 8)::
 
     S_t = exp(a_t) S_{t-1} + dt_t x_t (x) B_t
     y_t = S_t C_t
@@ -31,8 +33,9 @@ blocks and copies nothing else.
 consecutive positions of one slot in row blocks of 128, ``grid = (heads / 8,
 T / 128)``, the eight heads' states carried in VMEM across the blocks.  With
 ``L_i = sum_{j <= i} a_j`` inside the block (a head's) and ``G = C B^T`` —
-formed ONCE a row block, before the kernel, for all heads: a multi-query
-linear attention, every head to the one ``B`` and ``C`` —
+formed ONCE a row block and group, before the kernel, for all its heads (a
+grid step's eight heads lie in one group): a multi-query linear attention,
+every head of a group to its one ``B`` and ``C`` —
 
     Y_i   = sum_{j <= i} G_ij e^{L_i - L_j} dt_j x_j + e^{L_i} S_in C_i
     S_out = e^{L_last} S_in + sum_j e^{L_last - L_j} dt_j x_j (x) B_j
@@ -47,7 +50,8 @@ state exactly as it is, and blocks wholly past it are skipped.
 lane, ``grid = (lanes, heads / 32)``; the lane's state row goes through VMEM
 once — read, decayed, added to, read out against ``C``, written back: 2 x 4
 MiB a lane and layer at 128 heads of 64 x 128, which is what the step costs.
-A DEAD lane (its table row on the trash row) hands its row back as it found
+A grid step's heads are whole groups (or lie in one): it sends each group's
+``B`` and ``C`` down the sublanes once.  A DEAD lane (its table row on the trash row) hands its row back as it found
 it.
 """
 
@@ -104,17 +108,23 @@ def heads_of(tiles, P):
 # --------------------------------------------------------------------- #
 def _step_xla(state, x, dt, a, b, c):
     """One position of every head: ``state [..., H, P, N]``, ``x [..., H,
-    P]``, ``dt`` / ``a [..., H]``, ``b`` / ``c [..., N]`` (float32).
-    Returns ``(state, y [..., H, P])``."""
+    P]``, ``dt`` / ``a [..., H]``, ``b`` / ``c [..., N]`` or ``[..., G,
+    N]`` (float32).  Returns ``(state, y [..., H, P])``."""
+    read = "...hpn,...n->...hp"
+    if b.ndim == x.ndim:                 # in groups: a head reads its own's
+        per = x.shape[-2] // b.shape[-2]
+        b, c = (jnp.repeat(t, per, axis=-2) for t in (b, c))
+        read = "...hpn,...hn->...hp"
+    else:
+        b = b[..., None, :]              # every head's
     state = jnp.exp(a)[..., None, None] * state \
-        + (dt[..., None] * x)[..., None] * b[..., None, None, :]
-    return state, jnp.einsum("...hpn,...n->...hp", state, c,
-                             precision=HIGHEST)
+        + (dt[..., None] * x)[..., None] * b[..., None, :]
+    return state, jnp.einsum(read, state, c, precision=HIGHEST)
 
 
 def _scan_xla(state, x, dt, a, b, c):
     """``T`` positions one after the other: ``x [T, H, P]``, ``dt`` / ``a
-    [T, H]``, ``b`` / ``c [T, N]``, ``state [H, P, N]``."""
+    [T, H]``, ``b`` / ``c [T, N]`` or ``[T, G, N]``, ``state [H, P, N]``."""
     def step(s, row):
         return _step_xla(s, *row)
     return jax.lax.scan(step, state, (x, dt, a, b, c))
@@ -124,13 +134,20 @@ def _f32(*xs):
     return [x.astype(jnp.float32) for x in xs]
 
 
-def chunk_heads(H, P):
-    """The heads a grid step of the chunk kernel takes (whole tiles), or
-    None where the kernel has no form for them (the plain-XLA path then)."""
+def _one_group(b, c):
+    """``[T, 1, N]`` is ``[T, N]``: one group takes the one-group forms."""
+    return (b[:, 0], c[:, 0]) if b.ndim == 3 and b.shape[1] == 1 else (b, c)
+
+
+def chunk_heads(H, P, groups=1):
+    """The heads a grid step of the chunk kernel takes (whole tiles, inside
+    ONE of the ``groups``), or None where the kernel has no form for them
+    (the plain-XLA path then)."""
     per = heads_per_tile(H, P)
-    if H % _CHUNK_HEADS == 0 and _CHUNK_HEADS % per == 0:
+    if H % _CHUNK_HEADS == 0 and _CHUNK_HEADS % per == 0 \
+            and (H // groups) % _CHUNK_HEADS == 0:
         return _CHUNK_HEADS
-    return H if 2 * H <= BLOCK else None
+    return H if 2 * H <= BLOCK and groups == 1 else None
 
 
 def step_tiles(G):
@@ -138,6 +155,17 @@ def step_tiles(G):
     whole rows of ``dt x``, eight at a time or all of them."""
     return next(n for n in _STEP_TILES + (G,)
                 if G % n == 0 and (n % 8 == 0 or n == G))
+
+
+def step_groups(H, P, groups=1):
+    """The groups whose ``B`` and ``C`` a grid step of the decode kernel
+    reads — its heads are that many whole groups, or lie in one (1) —, or
+    None where they are neither (the plain-XLA path then)."""
+    per = heads_per_tile(H, P)
+    heads, each = step_tiles(H // per) * per, H // groups
+    if each % per == 0 and (heads % each == 0 or each % heads == 0):
+        return max(heads // each, 1)
+    return None
 
 
 # --------------------------------------------------------------------- #
@@ -216,21 +244,31 @@ def _chunk_kernel(meta, x_ref, l_ref, dt_ref, g_ref, b_ref, c_ref, s_in,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _chunk_pallas(x, dt, a, b, c, pool, meta, *, interpret):
     """``x [T, H, P]``, ``dt`` / ``a [T, H]`` float32 (zero past the real
-    rows), ``b`` / ``c [T, N]``; ``T`` whole blocks."""
+    rows), ``b`` / ``c [T, N]`` or ``[T, G, N]``; ``T`` whole blocks."""
     T, H, P = x.shape
-    N, Q = b.shape[1], BLOCK
-    heads, per = chunk_heads(H, P), heads_per_tile(H, P)
+    N, Q = b.shape[-1], BLOCK
+    groups = b.shape[1] if b.ndim == 3 else 1
+    heads, per = chunk_heads(H, P, groups), heads_per_tile(H, P)
     tiles, W = heads // per, per * P
     blocks = lambda t: t.reshape((T // Q, Q) + t.shape[1:])
-    # what every head shares, once a row block: the scores C B^T (float32
-    # sums of the stored rows' products) ...
-    G = jnp.einsum("cqn,ckn->cqk", blocks(c), blocks(b),
-                   preferred_element_type=jnp.float32).reshape(T, Q)
+    # what every head (of a group) shares, once a row block: the scores
+    # C B^T (float32 sums of the stored rows' products) ...
+    if b.ndim == 3:
+        G = jnp.einsum("cqgn,ckgn->gcqk", blocks(c), blocks(b),
+                       preferred_element_type=jnp.float32) \
+            .reshape(groups, T, Q)
+        b, c = jnp.swapaxes(b, 0, 1), jnp.swapaxes(c, 0, 1)
+        steps = H // groups // heads         # grid steps a group
+        shared = lambda w: pl.BlockSpec(
+            (None, Q, w), lambda n, i, m: (n // steps, i, 0))
+    else:
+        G = jnp.einsum("cqn,ckn->cqk", blocks(c), blocks(b),
+                       preferred_element_type=jnp.float32).reshape(T, Q)
+        shared = lambda w: pl.BlockSpec((Q, w), lambda n, i, m: (i, 0))
     # ... and a head's log-decay summed down the block
     L = jnp.cumsum(blocks(a), axis=1).reshape(T, H)
     tile = pl.BlockSpec((tiles, Q, W), lambda n, i, m: (n, i, 0))
     row = pl.BlockSpec((heads, Q), lambda n, i, m: (n, i))
-    shared = lambda w: pl.BlockSpec((Q, w), lambda n, i, m: (i, 0))
     state = pl.BlockSpec((None, None, tiles, N, W),
                          lambda n, i, m: (m[0], m[1], n, 0, 0))
     y, pool = pl.pallas_call(
@@ -258,15 +296,16 @@ def chunk_scan(x, dt, a, b, c, pool, layer, row, *, fresh, real,
     """``T`` consecutive positions of ONE slot through layer ``layer`` of
     ``pool [layers, rows, H / per, N, per x P]``: ``x [T, H, P]``, ``dt [T,
     H]`` the step sizes and ``a [T, H]`` the log-decays ``dt A`` (float32),
-    ``b`` / ``c [T, N]``; the state starts from zeros where ``fresh`` (the
+    ``b`` / ``c [T, N]`` or, in groups, ``[T, G, N]``; the state starts from zeros where ``fresh`` (the
     request's first chunk, whatever the row's last occupant left) and else
     from row ``row``, and the row is left holding the state after position
     ``real - 1``.  Returns ``(y [T, H, P]`` float32``, pool)``."""
     T, H, P = x.shape
+    b, c = _one_group(b, c)
     layer, row, real = (jnp.asarray(t, jnp.int32) for t in (layer, row, real))
     live = (jnp.arange(T) < real)[:, None]
     dt, a = (jnp.where(live, t, 0.0) for t in _f32(dt, a))
-    if pallas and chunk_heads(H, P):
+    if pallas and chunk_heads(H, P, b.shape[1] if b.ndim == 3 else 1):
         pad = -T % BLOCK
         padded = lambda t: jnp.pad(t, ((0, pad),) + ((0, 0),) * (t.ndim - 1))
         meta = jnp.stack([layer, row, jnp.asarray(fresh, jnp.int32), real])
@@ -286,11 +325,13 @@ def _step_kernel(layer, rows, live, dx_ref, a_ref, b_ref, c_ref, s_in, o_ref,
                  s_out):
     """One lane's ``R`` tiles: ``dx_ref`` / ``a_ref [R, 128]`` their heads'
     ``dt x`` and decay ``exp(a)`` (a head's, over its ``P`` lanes);
-    ``b_ref`` / ``c_ref [1, N]``; the tiles ``[R, N, 128]`` of the lane's
-    row.  ``B`` and ``C`` go down the sublanes once a step: one transpose,
-    two lane broadcasts."""
+    ``b_ref`` / ``c_ref [groups, N]``, the groups these tiles' heads are, in
+    equal runs; the tiles ``[R, N, 128]`` of the lane's row.  ``B`` and
+    ``C`` go down the sublanes once a step: one transpose, two lane
+    broadcasts a group."""
     n = pl.program_id(0)
     R, N, W = s_in.shape
+    groups = b_ref.shape[0]
 
     @pl.when(live[n] == 0)
     def _():
@@ -300,24 +341,34 @@ def _step_kernel(layer, rows, live, dx_ref, a_ref, b_ref, c_ref, s_in, o_ref,
     @pl.when(live[n] != 0)
     def _():
         cols = jnp.concatenate(
-            [b_ref[...], c_ref[...], jnp.zeros((N - 2, N), jnp.float32)]).T
-        B = jnp.broadcast_to(cols[:, 0:1], (N, W))
-        C = jnp.broadcast_to(cols[:, 1:2], (N, W))
+            [b_ref[...], c_ref[...],
+             jnp.zeros((N - 2 * groups, N), jnp.float32)]).T
+        B, C = ([jnp.broadcast_to(cols[:, g:g + 1], (N, W))
+                 for g in range(first, first + groups)]
+                for first in (0, groups))
         for r in range(R):
-            S = a_ref[r:r + 1, :] * s_in[r] + B * dx_ref[r:r + 1, :]
+            g = r * groups // R
+            S = a_ref[r:r + 1, :] * s_in[r] + B[g] * dx_ref[r:r + 1, :]
             s_out[r] = S
-            o_ref[r:r + 1, :] = jnp.sum(S * C, axis=0, keepdims=True)
+            o_ref[r:r + 1, :] = jnp.sum(S * C[g], axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _step_pallas(dx, decay, b, c, pool, layer, rows, live, *, interpret):
     """``dx`` / ``decay [lanes, tiles, 128]`` float32, ``b`` / ``c [lanes,
-    1, states]`` float32."""
+    1, states]`` float32 — or, in groups, ``[lanes, G / g, g, states]``:
+    ``g`` the groups of a grid step's heads (:func:`step_groups`)."""
     lanes, G, W = dx.shape
     N = pool.shape[3]
     R = step_tiles(G)
     wide = pl.BlockSpec((None, R, W), lambda n, h, *refs: (n, h, 0))
-    shared = pl.BlockSpec((None, 1, N), lambda n, h, *refs: (n, 0, 0))
+    if b.ndim == 3:
+        shared = pl.BlockSpec((None, 1, N), lambda n, h, *refs: (n, 0, 0))
+    else:
+        # a step's heads are ``g`` whole groups, or ``of`` steps share one
+        of = (G // R) // b.shape[1]
+        shared = pl.BlockSpec((None, None, b.shape[2], N),
+                              lambda n, h, *refs: (n, h // of, 0, 0))
     state = pl.BlockSpec(
         (None, None, R, N, W),
         lambda n, h, layer, rows, live: (layer[0], rows[n], h, 0, 0))
@@ -340,23 +391,27 @@ def _step_pallas(dx, decay, b, c, pool, layer, rows, live, *, interpret):
 def decode_step(x, dt, a, b, c, pool, layer, rows, live=None, *,
                 pallas=True):
     """One token a lane through layer ``layer`` of ``pool``: ``x [N, H,
-    P]``, ``dt`` / ``a [N, H]``, ``b`` / ``c [N, states]``, ``rows [N]`` the
-    lanes' state rows, ``live [N]`` (None: all) — a dead lane's row is
+    P]``, ``dt`` / ``a [N, H]``, ``b`` / ``c [N, states]`` or, in groups,
+    ``[N, G, states]``, ``rows [N]`` the lanes' state rows, ``live [N]`` (None: all) — a dead lane's row is
     handed back as it was and its output is zero.  Returns ``(y [N, H, P]``
     float32``, pool)``."""
     N, H, P = x.shape
+    b, c = _one_group(b, c)
     layer = jnp.asarray(layer, jnp.int32)
     rows = rows.astype(jnp.int32)
     live = jnp.ones((N,), bool) if live is None else live.astype(bool)
     xf, dt, a, b, c = _f32(x, dt, a, b, c)
     per = heads_per_tile(H, P)
-    if pallas:
+    each = step_groups(H, P, b.shape[1]) if b.ndim == 3 else 1
+    if pallas and each:
         interpret = _interpret()                # a bool: static by value
         wide = lambda t: t.reshape(N, H // per, per * P)
+        shared = (lambda t: t[:, None]) if b.ndim == 2 \
+            else lambda t: t.reshape(N, -1, each, t.shape[-1])
         out, pool = _step_pallas(
             wide(dt[..., None] * xf),
             wide(jnp.broadcast_to(jnp.exp(a)[..., None], xf.shape)),
-            b[:, None], c[:, None], pool, layer.reshape(1), rows,
+            shared(b), shared(c), pool, layer.reshape(1), rows,
             live.astype(jnp.int32), interpret=interpret)
         return out.reshape(N, H, P), pool
     before = heads_of(pool[layer, rows], P)

@@ -374,6 +374,11 @@ SCOPE_PARTS = (
     (r"attn\.ssd|ssd\.(scan|gate_norm)|\w*ssd\.(chunk_scan|decode_step)\w*",
      "attn.ssd"),
     (r"mamba(\.\w+)?", "attn.ssd"),
+    # a ONE-sublayer block (``models/nemotron_h.py``): its one norm is
+    # called ``norm`` as the checkpoint has it (its sublayer is ``mamba`` /
+    # ``moe_mlp`` / ``self_attn`` by its kind, rows of their own here; the
+    # final ``norm_f`` runs under ``head.logits``)
+    (r"layers_\d+/norm", "norm"),
     # the layer scan's own operations (``scan_layers``): a layer's slice
     # out of the stacked parameters and saved residuals, the saves' and the
     # gradients' writes back into the stacks, the stacks' zeros and copies

@@ -336,13 +336,14 @@ def test_one_row_servers_keep_the_scalar_start_program(rows_engine,
 
 # --------------------------------------------------------------------- #
 # An expert model takes rows too: an expert layer flattens a dispatch's
-# rows to tokens, so 4 rows of 128 are ONE call of it — 512 tokens, where
-# gated experts take the sorted form (``moe.experts_grouped``) — and the
-# dispatch has one load vector, whoever's rows it carried.
+# rows to tokens, so 4 rows of 128 are ONE call of it — 512 tokens, which
+# take the sorted form (``moe.experts_grouped``), gated experts and since PR
+# 60 un-gated ones (its two-matrix body) alike — and the dispatch has one
+# load vector, whoever's rows it carried.
 # --------------------------------------------------------------------- #
 TOP_K, EXPERT_LAYERS = 2, 1              # moe_every 2 of 2 layers
 EXPERT_FORMS = {"sorted": dict(gated_mlp=True, activation="silu"),
-                "gmm": {}}                # un-gated experts: never sorted
+                "gmm": {}}                # un-gated experts (two matrices)
 
 
 @pytest.mark.parametrize("form", list(EXPERT_FORMS))
@@ -352,7 +353,7 @@ def test_expert_rows_of_two_prompts_and_a_dead_row(form, tmp_path):
     form — one engine built, in one worker, whatever ``--dist`` says:
 
     * greedy tokens of every request are its solo ``generate()`` run's
-      (one row of 128 a call there: ``moe.experts_gmm`` in either form),
+      (one row of 128 a call there: ``moe.experts_gmm`` for either kind),
       and the 7 chunks took ``ceil(7 / 4)`` dispatches;
     * every live token — the prompts' 800, and each generated token but a
       request's last — chose ``top_k`` experts an expert layer; the dead

@@ -98,12 +98,16 @@ def test_serving_flow_leaves_the_tabled_spans(ring_off):
     srv = eng.serve()
     report = srv.warmup()
     ready = _since(t)
+    # the lazy imports' spans depend on what the process ran before this
+    # test (an xdist worker's earlier tests): present or absent, each names
+    # its module and lies inside the engine's span
+    probes = _named(ready, "lazy_import")
+    ready = [s for s in ready if s not in probes]
     assert [s[0][len(P):] for s in ready] == [
-        "lazy_import", "engine", "weights", "serve", "compile", "compile",
-        "warmup"]
-    torch_probe, engine, weights, serve, chunk, block, warmup = ready
-    assert torch_probe[4] == {"module": "torch"} \
-        and _inside(torch_probe, engine)
+        "engine", "weights", "serve", "compile", "compile", "warmup"]
+    engine, weights, serve, chunk, block, warmup = ready
+    assert all(set(p[4]) == {"module"} and _inside(p, engine)
+               for p in probes)
     assert engine[4] == {"entry": "init_inference", "chips": 8}
     assert weights[4] == {"bytes": _tree_bytes(eng.params),
                           "leaves": len(jax.tree.leaves(eng.params)),
@@ -127,7 +131,7 @@ def test_serving_flow_leaves_the_tabled_spans(ring_off):
 
     # (d) the same signatures again: a warm-up span, no compile
     assert set(srv.warmup().values()) == {0.0}
-    again = _since(t)[len(ready):]
+    again = _since(t)[len(ready) + len(probes):]
     assert [s[0] for s in again] == [P + "warmup"]
 
     # first requests: the pools are allocated and the admit program
@@ -138,7 +142,7 @@ def test_serving_flow_leaves_the_tabled_spans(ring_off):
     submit()
     while not _named(_since(t), "compile", program="admit"):
         srv.step()
-    first = _since(t)[len(ready) + 1:]
+    first = _since(t)[len(ready) + len(probes) + 1:]
     assert [s[0][len(P):] for s in first] == ["pools", "compile"]
     pools, admit = first
     with srv._lock:

@@ -324,17 +324,21 @@ def _kda_chunk_scan(chunk=2048, heads=64, dim=128, slots=64, layers=3):
     return fn, args
 
 
-def _ssd(chunk, heads=128, dim=64, states=128, slots=176, layers=9):
+def _ssd(chunk, heads=128, dim=64, states=128, slots=176, layers=9,
+         groups=None):
     """One Mamba-2 layer of ``granite-serve-chatgen-batch``: a 512-row chunk
     through ``ssd.chunk_scan``, or one token of each of the 176 lanes
     through ``ssd.decode_step``, over the cell's float32 state pool (177
-    rows x 9 layers x 4 MiB), the state rows in and out."""
+    rows x 9 layers x 4 MiB), the state rows in and out.  ``groups``: ``B``
+    and ``C`` a group of heads (``nemotron3-serve-thinkgen-batch``: 8
+    groups of 8 heads — a chunk step's eight heads one group's, a decode
+    step's 32 heads four —, 193 rows x 6 blocks x 2 MiB)."""
     n = chunk or slots
     pool = ((layers, 1 + slots) + ssd_mod.state_shape(heads, dim, states),
             F32)
+    shared = ((n, states) if groups is None else (n, groups, states), BF16)
     args = [((n, heads, dim), BF16), ((n, heads), F32), ((n, heads), F32),
-            ((n, states), BF16), ((n, states), BF16), pool,
-            ((n,), I32), ((), I32)]
+            shared, shared, pool, ((n,), I32), ((), I32)]
 
     def fn(x, dt, a, b, c, pool, rows, start):
         if chunk:
@@ -344,6 +348,34 @@ def _ssd(chunk, heads=128, dim=64, states=128, slots=176, layers=9):
         return ssd_mod.decode_step(x, dt, a, b, c, pool, layers - 1, rows,
                                    rows > 0)
     return fn, args
+
+
+def _moe_ungated(tokens, hidden=2688, experts=128, held=64, width=1856,
+                 top_k=6):
+    """Nemotron-H's expert layer as ``MoE._scored`` runs it: sigmoid scores
+    over 128 outputs, 64 held experts of TWO matrices (``relu2``, no gate)
+    at the published width 1856 = 14.5 lane tiles STORED as 1920 (zero
+    columns / rows: ``NemotronHConfig.stored_expert_width``) in width tiles
+    of 640 —, the dense form for a decode step's 192 rows, the sorted form
+    for a chunk's."""
+    assert width == 1856 and moe_mod._width_tile(1920) == 640
+    width = 1920
+    from deepspeed_tpu.models.nemotron_h import relu2
+
+    def fn(x, gate_w, bias, live, wu, wd):
+        choice, gate = moe_mod.route_scored(x, gate_w, bias, top_k,
+                                            live=live, scaling=2.5,
+                                            sum_eps=1e-20)
+        local, counts, _ = moe_mod.held_load(choice, 0, held)
+        if tokens < moe_mod.GROUPED_MIN_ROWS:
+            return moe_mod.experts(
+                x, moe_mod.combine_of(local, gate, held), counts, None, wu,
+                wd, relu2), counts
+        return moe_mod.experts_grouped(x, local, gate, None, wu, wd,
+                                       relu2), counts
+    return fn, [((tokens, hidden), BF16), ((hidden, experts), F32),
+                ((experts,), F32), ((tokens,), jnp.bool_),
+                ((held, hidden, width), BF16), ((held, width, hidden), BF16)]
 
 
 def _conv_step(slots, taps, width, layers):
@@ -366,6 +398,13 @@ CASES = {
     "granite_ssd_chunk_scan_c512": lambda: _ssd(512),
     "granite_ssd_decode_step_176": lambda: _ssd(0),
     "granite_conv_step_176": lambda: _conv_step(176, 4, 8448, 9),
+    "nemotron_ssd_chunk_scan_g8_c512": lambda: _ssd(
+        512, heads=64, slots=192, layers=6, groups=8),
+    "nemotron_ssd_decode_step_g8_192": lambda: _ssd(
+        0, heads=64, slots=192, layers=6, groups=8),
+    "nemotron_conv_step_192": lambda: _conv_step(192, 4, 6144, 6),
+    "nemotron_moe_ungated_gmm_t192": lambda: _moe_ungated(192),
+    "nemotron_moe_ungated_grouped_c1024": lambda: _moe_ungated(1024),
     "solar_conv_step_64": lambda: _conv_step(64, 4, 3 * 8192, 3),
     "lfm2_conv_step_256": lambda: _conv_step(256, 3, 2048, 8),
     "solar_kda_chunk_scan_c2048": _kda_chunk_scan,
@@ -1064,6 +1103,80 @@ def test_granite_slot_programs_compile_at_the_cells_sizes(program, one_chip,
     assert 13.4e9 < total < 15.5e9, f"{total / 1e9:.2f} GB"
 
 
+@pytest.mark.parametrize("program", ["chunk_step", "decode_block"])
+def test_nemotron_slot_programs_compile_at_the_cells_sizes(program, one_chip,
+                                                           mosaic):
+    """The two programs ``nemotron3-serve-thinkgen-batch`` runs, whole, at
+    the cell's own settings: 14 ONE-sublayer blocks — six Mamba-2 blocks in
+    8 ``B`` / ``C`` groups over a float32 matrix state and a bfloat16 conv
+    state a slot, six expert blocks of 64 held two-matrix experts under a
+    128-wide sigmoid router, two NoPE softmax blocks of 32 query heads on 2
+    KV heads over K/V lane pages —, an untied 65,536-wide head.  Every pool
+    aliased input -> output, 9.17 GB of weights + pools + the programs'
+    temporaries inside one chip; and every instruction under the model's
+    call lies in a part of the profiler's table — none of them a part
+    called ``mixer``."""
+    from deepspeed_tpu.inference.serving import slots
+    from deepspeed_tpu.inference.serving.paging import SlotPages
+    from deepspeed_tpu.profiling.flops_profiler import profiler
+    c = _slot_programs_of("nemotron3-serve-thinkgen-batch", "nemotron_h",
+                          one_chip)
+    module, s, chunk = c.module, c.serving, c.chunk
+    params, ints, on_chip = c.params, c.ints, c.on_chip
+    pages = SlotPages(module, c.declared, s["num_slots"], s["max_cache_len"],
+                      s["page_size"], s["num_pages"], chunk, False, {})
+    pool = on_chip(jax.eval_shape(lambda: pages.new_pools(BF16)))
+    n = s["num_slots"]
+    assert (pages.pages_per_slot, pages.state_rows, pages.table_width) \
+        == (88, n + 1, 89)
+    assert {k: (v.shape, str(v.dtype)) for k, v in pool.items()} == {
+        "k": ((2, s["num_pages"], 64, 256), "bfloat16"),
+        "v": ((2, s["num_pages"], 64, 256), "bfloat16"),
+        # 3 x 6,144 a row: 144 sublanes, whole bfloat16 tiles
+        "conv": ((6, n + 1, 144, 128), "bfloat16"),
+        "ssm": ((6, n + 1, 32, 128, 128), "float32")}
+    assert pages.state_kind_bytes == {"conv": 6 * 144 * 128 * 2,
+                                      "ssm": 6 * 2 * 2 ** 20}
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    weights = sum(x.size * 2 for x in jax.tree.leaves(params))
+    # the published 4.585 B and the experts' zero padding to 1920
+    assert weights == 2 * (4584903936 + 6 * 64 * 2 * 2688 * 64)
+    if program == "chunk_step":
+        compiled = slots.make_chunk_fn(module, c.declared, None).lower(
+            params, pool, ints(1, pages.table_width), ints(1, chunk), ints(),
+            ints(1)).compile()
+    else:
+        state = on_chip({k: jnp.asarray(v) for k, v in
+                         slots.init_slot_state(n).items()})
+        rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                   sharding=one_chip)
+        compiled = slots.make_decode_block_fn(
+            module, c.declared, lambda logits, rng: jnp.argmax(logits, -1),
+            None, s["decode_block"], pages.cache_len).lower(
+                params, pool, state, ints(n, pages.table_width),
+                rng).compile()
+    text = compiled.as_text()
+    # a paged chunk / paged decode an attention block, a state scan / step
+    # a Mamba block, the experts of six blocks
+    assert text.count("tpu_custom_call") >= 2 + 6 + 6
+    assert ("ssd.chunk_scan" if program == "chunk_step"
+            else "ssd.decode_step") in text
+    assert ("moe.experts_grouped" if chunk >= moe_mod.GROUPED_MIN_ROWS
+            and program == "chunk_step" else "moe.experts_gmm") in text
+    named = set(re.findall(
+        r'op_name="([^"]*NemotronHModel\.decode[^"]*)"', text))
+    parts = {profiler.part_of(n)[0] for n in named}
+    assert named and None not in parts and "mixer" not in parts
+    assert {"attn.ssd", "moe.experts", "moe.route", "attn.core", "mlp",
+            "norm", "head", "conv.short"} <= parts, parts
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(program, "total", total / 1e9, "pool", pool_bytes / 1e9)
+    assert 12.0e9 < total < 15.5e9, f"{total / 1e9:.2f} GB"
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
     fn, shapes = CASES[case]()
@@ -1117,7 +1230,32 @@ def test_kernel_compiles_to_mosaic(case, one_chip, mosaic):
         assert " gather(" not in text and " scatter(" not in text
         rows = shapes[2][0][2:]
         assert rows == {"granite": (208, 128), "solar": (576, 128),
-                        "lfm2": (32, 128)}[case.split("_")[0]]
+                        "lfm2": (32, 128),
+                        "nemotron": (144, 128)}[case.split("_")[0]]
+    if case.startswith("nemotron_ssd_"):
+        # as Granite's: one kernel of the name, the pool aliased in -> out;
+        # a chunk step's eight heads are one of the 8 groups (its C B^T
+        # block [None, 128, 128] of the [8, T, 128] scores), a decode step's
+        # 16 tiles = 32 heads four (B and C blocks [4, 128])
+        call, = _pallas_calls(fn, shapes)
+        name = "ssd.chunk_scan" if "chunk" in case else "ssd.decode_step"
+        assert call.params["name"] == name in text
+        assert call.params["input_output_aliases"] == ((7, 1),)
+        grid = call.params["grid_mapping"].grid
+        assert grid == ((64 // 8, 512 // 128) if "chunk" in case
+                        else (192, 32 // 16))
+    if case.startswith("nemotron_moe_ungated_"):
+        # ONE expert kernel, TWO weight operands (no gate matrix), each in
+        # three width tiles of the stored [64, 2688, 1920] / [64, 1920, 2688]
+        call, = [c for c in _pallas_calls(fn, shapes)
+                 if c.params["name"].startswith("moe.experts")]
+        assert call.params["name"] == (
+            "moe.experts_gmm" if "gmm" in case else "moe.experts_grouped")
+        blocks = [tuple(x.block_size for x in b.block_shape
+                        if hasattr(x, "block_size"))
+                  for b in call.params["grid_mapping"].block_mappings]
+        assert blocks.count((1, 2688, 640)) == 1 \
+            and blocks.count((1, 640, 2688)) == 1, blocks
     if case in _FLASH_TRAINED:
         # the backward of one call is ONE Mosaic kernel beside the
         # forward's: the head's float32 dq sum fits the VMEM a kernel gets

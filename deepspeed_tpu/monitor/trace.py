@@ -310,6 +310,10 @@ _TRACER = None                           # None = the ring is off
 SETUP_SPANS_KEPT = 512
 _SETUP = deque(maxlen=SETUP_SPANS_KEPT)
 _SETUP_LOCK = threading.Lock()
+# the package's import span, once it is recorded: a process that builds
+# many engines pushes it off the list above, and every ``ready`` line
+# begins with it
+_IMPORT = []
 _SETUP_OPEN = threading.local()          # .names: this thread's open ones
 
 
@@ -361,8 +365,9 @@ def ready_line(what):
     spans = setup_spans()
     built = [t0 for name, t0, *_ in spans if name == "dstpu.setup.engine"]
     since = built[-1] if built else float("-inf")
-    spans = sorted((s for s in spans
-                    if s[1] >= since or s[0] == "dstpu.setup.import"),
+    imported = [s for s in spans if s[0] == "dstpu.setup.import"] or _IMPORT
+    spans = sorted(imported + [s for s in spans if s[1] >= since
+                               and s[0] != "dstpu.setup.import"],
                    key=lambda s: (s[1], -s[2]))
     own, programs, open_ = {}, {}, {}    # open_: track -> [(t1, name)]
     for name, t0, t1, track, args in spans:
@@ -453,9 +458,11 @@ class span:
         track = self.track if self.track is not None \
             else threading.current_thread().name
         if self.cat == "setup":
+            kept = (self.name, self.t0, self.t1, track, dict(self.args))
             with _SETUP_LOCK:
-                _SETUP.append((self.name, self.t0, self.t1, track,
-                               dict(self.args)))
+                _SETUP.append(kept)
+                if self.name == "dstpu.setup.import":
+                    _IMPORT[:] = [kept]
         if tr is not None:
             tr.add(self.name, self.cat, self.t0, self.t1, track=track,
                    **self.args)

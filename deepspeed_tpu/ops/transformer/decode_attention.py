@@ -51,6 +51,7 @@ head — and selects a masked score once (``_ChunkState``, ``STAT_FLOOR``).
 """
 
 import functools
+import math
 import os as _os
 from typing import Any, NamedTuple
 
@@ -285,6 +286,69 @@ def _write_stripe(st, length, block_k, load8, ko, vo, kso, vso, *, kvh, d):
             vsm = jnp.where(m, vs_n[hk, 0], vsm)
         kso[...] = ksm.astype(kso.dtype)
         vso[...] = vsm.astype(vso.dtype)
+
+
+# What a decode-side kernel asks of VMEM past the buffers and values it is
+# counted to hold: the compiler's own scratch and what it spills.  (The least
+# limit each call compiles under at the serving cells' shapes is within
+# 1 MiB of its declared buffers, and under its count: PERF.md, PR 61.)
+VMEM_HEADROOM = 4 * 1024 * 1024
+
+
+def vmem_bytes(shape, dtype):
+    """Bytes an array of ``shape`` takes in VMEM: its last two dims in
+    whole tiles of 128 lanes by the dtype's sublanes (8 rows of 32 bits, 16
+    of bfloat16, 32 of int8)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 32 // itemsize
+    *lead, rows, lanes = (1, 1) + tuple(shape)
+    return (math.prod(lead) * -(-rows // sublanes) * sublanes
+            * -(-lanes // 128) * 128 * itemsize)
+
+
+def _decode_vmem_bytes(rows, h, d, bk, kvhd, kv_dtype, q_dtype, *, buffers=2,
+                       quant=False):
+    """The VMEM a single-token decode kernel asks for — ``attn.decode``,
+    ``attn.paged_decode`` in both its forms, ``attn.eva_decode`` — from the
+    buffers the call declares and the values one per-block update
+    (:func:`_block_update`) makes, and no more:
+
+    * ``buffers`` K and as many V blocks of ``[bk, KVH*D]`` — the block
+      loop's and the grid pipeline's two, ``attn.eva_decode``'s ring of
+      three — and, for an int8 cache (``quant``), as many ``[bk, KVH]``
+      float32 scale blocks (whole 128-lane tiles) and a K and a V block
+      cast to the query's dtype for the MXU;
+    * the fused write's 8-row stripes, two a pool — staged by the block
+      loop, output blocks of the grid forms; counted whether or not the
+      call writes (128 bytes a lane);
+    * the q and output blocks ``[rows, H, D]`` and the new K/V rows
+      ``[rows, KVH, D]``, each double-buffered by the pipeline: ``rows`` is
+      1 a grid step, and EVERY lane of the block loop — LFM2's 256 lanes
+      are 12 MiB and Nemotron's 192 are 9, nothing a headroom hides;
+    * the online-softmax scratch: the running max and sum (and the int8
+      MXU form's q scales), the accumulator, the block-diagonal q;
+    * the update's ``[H, bk]`` float32 score-side tiles — the scores, their
+      masked, exponentiated, scaled and cast forms: six — and its ``[H,
+      KVH*D]`` float32 product;
+    * ``VMEM_HEADROOM``.
+
+    What a kernel is granted XLA cannot use across it: under the ``max(96
+    MiB, ...)`` floor this ask had, the decode block's next weights could
+    not be prefetched into VMEM while the kernel ran (PERF.md, PR 61)."""
+    kvh = kvhd // d
+    f32 = jnp.float32
+    ask = 2 * buffers * vmem_bytes((bk, kvhd), kv_dtype)
+    ask += 2 * 2 * vmem_bytes((8, kvhd), kv_dtype)
+    if quant:
+        ask += 2 * buffers * vmem_bytes((bk, kvh), f32)
+        ask += 2 * 2 * vmem_bytes((8, kvh), f32)
+        ask += 2 * vmem_bytes((bk, kvhd), q_dtype)
+    ask += 2 * 2 * (vmem_bytes((rows, h, d), q_dtype)
+                    + vmem_bytes((rows, kvh, d), q_dtype))
+    ask += (3 * vmem_bytes((h, LSE_LANES), f32) + vmem_bytes((h, d), f32)
+            + vmem_bytes((h, kvhd), q_dtype))
+    ask += 6 * vmem_bytes((h, bk), f32) + vmem_bytes((h, kvhd), f32)
+    return ask + VMEM_HEADROOM
 
 
 def _decode_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
@@ -900,14 +964,8 @@ def decode_attention(q, k_cache, v_cache, lengths,
         input_output_aliases=io_aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            # the [block_k, KVH*D] K/V slabs double-buffer; the default
-            # 16 MB scoped-vmem budget is a hair short at the default
-            # block_k, and DSTPU_DECODE_BLOCK_K can grow the slabs further —
-            # size the budget from the actual blocks (4 slab buffers +
-            # write-block outputs + scratch/q/out headroom)
-            vmem_limit_bytes=max(
-                96 * 1024 * 1024,
-                6 * block_k * KVHD * q.dtype.itemsize + 16 * 1024 * 1024)),
+            vmem_limit_bytes=_decode_vmem_bytes(
+                1, H, D, block_k, KVHD, k_cache.dtype, q.dtype, quant=quant)),
         interpret=_interpret(),
         name="attn.decode",
     )(jnp.asarray(lengths, jnp.int32), layer_arr, *operands)

@@ -52,8 +52,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.transformer.decode_attention import (
     _ChunkState, _RowState, _block_update, _chunk_block_update,
-    _chunk_scratch, _finish_chunk, _finish_row, _init_chunk, _init_row,
-    _write_stripe)
+    _chunk_scratch, _decode_vmem_bytes, _finish_chunk, _finish_row,
+    _init_chunk, _init_row, _write_stripe)
 from deepspeed_tpu.ops.transformer.flash_attention import LSE_LANES, _interpret
 from deepspeed_tpu.ops.transformer.paged_attention import (
     _CHUNK_BLOCK_KEYS, _DECODE_PAGE_BUFFERS, _chunk_loop_vmem_bytes)
@@ -200,9 +200,9 @@ def eva_decode_attention(q, k_ring, v_ring, k_sum, v_sum, positions,
         input_output_aliases={5: 1, 6: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
-            vmem_limit_bytes=max(
-                96 * 1024 * 1024,
-                6 * page * HD * q.dtype.itemsize + 16 * 1024 * 1024)),
+            vmem_limit_bytes=_decode_vmem_bytes(
+                1, H, D, page, HD, k_ring.dtype, q.dtype,
+                buffers=_DECODE_PAGE_BUFFERS)),
         interpret=_interpret(),
         name="attn.eva_decode",
     )(positions, jnp.asarray([layer], jnp.int32), ring_pages,

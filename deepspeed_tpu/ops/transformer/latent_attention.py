@@ -77,7 +77,12 @@ NEG = -1e30
 # of 0 and returns zeros
 FLOOR = -1e29
 LANES = 128
-# what this file's grid kernels ask of VMEM, flat (ROADMAP S26)
+# what this file's grid kernels ask of VMEM, flat.  The two lane kernels
+# hold 3 MiB of it, and keep the rest ON PURPOSE: asked for what they hold,
+# XLA keeps ~100 MB of LongCat's dense FFN weights in flight across each
+# ``attn.mla_lane_decode`` call, and the kernel — bound by its page copies'
+# latency, at 53% of its bytes — loses more to that traffic than the
+# weights gain: the decode block read 137.0 -> 140.0 ms (PERF.md, PR 61)
 VMEM_ASK = 64 * 1024 * 1024
 # heads of a flash grid step unrolled into one block of code (the rest of
 # the step's heads loop over such groups)

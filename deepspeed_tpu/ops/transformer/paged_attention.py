@@ -162,9 +162,8 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.transformer.decode_attention import (
     _ChunkState, _RowState, _block_update, _chunk_block_update,
     _chunk_grid_vmem_bytes, _chunk_prefill_kernel, _chunk_scratch,
-    _chunk_scratch_bytes,
-    _decode_kernel, _finish_chunk, _finish_row, _init_chunk, _init_row,
-    _write_stripe)
+    _chunk_scratch_bytes, _decode_kernel, _decode_vmem_bytes, _finish_chunk,
+    _finish_row, _init_chunk, _init_row, _write_stripe)
 from deepspeed_tpu.ops.transformer.flash_attention import LSE_LANES, _interpret
 
 # VMEM ring of a decode loop that folds a page an update (``attn.eva_decode``
@@ -604,7 +603,7 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
             nk=nk, kvh=KVH, g=G, d=D, stacked=True, quant=True,
             window=None, mxu_int8=mxu_int8, fused_write=fused_write)
         loop_scratch = []
-        block_bytes = page * KVHD * q.dtype.itemsize
+        q_rows, block_keys = 1, page        # a row and a page a grid step
     else:
         # the block loop: ONE grid step, every row's q / new rows / output
         # in VMEM, the pools whole in HBM on both sides (the kernel sends
@@ -632,7 +631,7 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
             loop_scratch += [pltpu.VMEM((2, 8, KVHD), k_pool.dtype),
                              pltpu.VMEM((2, 8, KVHD), v_pool.dtype),
                              pltpu.SemaphoreType.DMA((2, 2))]
-        block_bytes = bp * page * KVHD * k_pool.dtype.itemsize
+        q_rows, block_keys = B, bp * page
 
     out_specs = [rows(H, D)]
     out_shape = [jax.ShapeDtypeStruct((B, H, D), q.dtype)]
@@ -670,10 +669,9 @@ def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *, scale=None,
         input_output_aliases=io_aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")[:len(grid)],
-            # blocks are small (<= a monolithic block_k) — the monolithic
-            # slab-sized floor is comfortably enough headroom
-            vmem_limit_bytes=max(96 * 1024 * 1024,
-                                 6 * block_bytes + 16 * 1024 * 1024)),
+            vmem_limit_bytes=_decode_vmem_bytes(
+                q_rows, H, D, block_keys, KVHD, k_pool.dtype, q.dtype,
+                quant=quant)),
         interpret=_interpret(),
         name="attn.paged_decode",
     )(lengths, layer_arr, pages_arr, *operands)

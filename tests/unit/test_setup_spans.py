@@ -315,6 +315,24 @@ def test_setup_spans_are_kept_ring_off_and_bounded(ring_off):
         trace._SETUP.extend(before)
 
 
+def test_ready_line_begins_with_the_import_past_the_bound(ring_off):
+    """A process that has built many engines (an xdist worker late in the
+    suite) has pushed the package's import span off the bounded list: the
+    ``ready`` line still begins with it."""
+    before = trace.setup_spans()
+    try:
+        for i in range(trace.SETUP_SPANS_KEPT):
+            with trace.span(P + "pools", cat="setup", bytes=i):
+                pass
+        assert not _named(trace.setup_spans(), "import")
+        assert trace.ready_line("serving").startswith(
+            "ready[serving]: import ")
+    finally:
+        with trace._SETUP_LOCK:
+            trace._SETUP.clear()
+            trace._SETUP.extend(before)
+
+
 def test_a_ring_turned_on_inside_a_setup_span_still_gets_it(ring_off):
     """``ServingEngine.__init__`` turns the ring on under
     ``dstpu.setup.serve``: the span lands in the ring it finds at exit."""

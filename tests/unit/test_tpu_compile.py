@@ -14,11 +14,13 @@ under xdist exactly one worker — the one handed this file — may do it,
 and only after collection.
 """
 
+import math
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.moe import dropless as moe_mod
@@ -79,7 +81,6 @@ def vmem_asks(monkeypatch):
     """The ``vmem_limit_bytes`` of every Pallas call traced while the test
     runs, read off the calls themselves (the chunk kernels' calls are
     jitted: their own caches are dropped so that each is traced anew)."""
-    from jax.experimental.pallas import tpu as pltpu
     asked, params = [], pltpu.CompilerParams
 
     def recording(*args, **kw):
@@ -102,23 +103,27 @@ def _flash_fwd_bwd(batch=2, seq=2048, heads=H, head_dim=D):
 
 
 def _paged_decode(quant, page=64, slots=8, cache_len=512, layers=L,
-                  kv_heads=H, heads=H, head_dim=D):
+                  kv_heads=H, heads=H, head_dim=D, fused=True):
+    """``fused``: the step's K/V row written by the kernel, as a lane pool's
+    decode step does; a ring's (``registry``'s ``pallas_ring_decode``) reads
+    rows that are written already."""
     pages_per_slot = cache_len // page
     n_pages = slots * pages_per_slot + 1
     pool = ((layers, n_pages, page, kv_heads * head_dim),
             I8 if quant else BF16)
     args = [((slots, heads, head_dim), BF16), pool, pool, ((slots,), I32),
-            ((slots, pages_per_slot), I32),
-            ((slots, kv_heads, head_dim), BF16),
-            ((slots, kv_heads, head_dim), BF16)]
+            ((slots, pages_per_slot), I32)]
+    if fused:
+        args += [((slots, kv_heads, head_dim), BF16)] * 2
     if quant:
         args += [((layers, n_pages, page, kv_heads), F32)] * 2
 
-    def fn(q, k_pool, v_pool, lengths, pages, new_k, new_v, *scales):
-        kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
+    def fn(q, k_pool, v_pool, lengths, pages, *rest):
+        kw = dict(new_k=rest[0], new_v=rest[1]) if fused else {}
+        if quant:
+            kw.update(k_scale=rest[-2], v_scale=rest[-1])
         return paged_mod.paged_decode_attention(
-            q, k_pool, v_pool, lengths, pages, layer=layers - 1,
-            new_k=new_k, new_v=new_v, **kw)
+            q, k_pool, v_pool, lengths, pages, layer=layers - 1, **kw)
     return fn, args
 
 
@@ -540,43 +545,125 @@ def test_chunk_fold_compiles_under_its_old_vmem_ask(case, one_chip, mosaic,
     assert decode_mod._chunk_scratch_bytes(512, 32, 128) == 25165824
 
 
-# the four cells that run ``attn.paged_decode``: slots, query heads, KV
-# heads, head size, pages a slot (pages of 64)
+# the cells' calls of ``attn.paged_decode``: slots, query heads, KV heads,
+# head size, pages a slot (pages of 64), layers of the pool (two stand for
+# OPT's 24, OLMoE's 8 and LFM2's 2 + 8), and whether the call writes the
+# step's row — Trinity's four sliding layers read a RING of 32 pages a slot
+# through the same kernel, unfused; Solar walks 529 pages a slot
 _DECODE_CELLS = {
-    "opt13b_chat": (32, 32, 32, 64, 22),
-    "opt13b_longprompt": (24, 32, 32, 64, 29),
-    "olmoe_gen": (64, 16, 16, 128, 17),
-    "lfm2_widegen": (256, 32, 8, 64, 21),
+    "opt13b_chat": (32, 32, 32, 64, 22, 2, True),
+    "opt13b_longprompt": (24, 32, 32, 64, 29, 2, True),
+    "olmoe_gen": (64, 16, 16, 128, 17, 2, True),
+    "lfm2_widegen": (256, 32, 8, 64, 21, 2, True),
+    "trinity_ring": (128, 32, 4, 128, 32, 4, False),
+    "trinity_lane": (128, 32, 4, 128, 273, 1, True),
+    "solar_longctx": (64, 64, 8, 128, 529, 1, True),
+    "granite_chatgen": (176, 32, 8, 128, 45, 1, True),
+    "nemotron_thinkgen": (192, 32, 2, 128, 88, 2, True),
 }
 
 
 @pytest.mark.parametrize("cell", sorted(_DECODE_CELLS))
-def test_paged_decode_block_loop_at_the_cells_shapes(cell, one_chip, mosaic):
+def test_paged_decode_block_loop_at_the_cells_shapes(cell, one_chip, mosaic,
+                                                     vmem_asks):
     """The decode kernel's block loop — a loop over the live rows, blocks
     of pages double-buffered, the next row's first block handed over,
     the fused write's stripes sent to the pools by hand — lowers through
-    Mosaic at each serving cell's shapes with the fused write: the two
+    Mosaic at each serving cell's shapes: with the fused write the two
     pools stay aliased input -> output (donated, they are updated in
-    place: nothing pool-sized among the temporaries), and the block
-    buffers fit the VMEM the kernel asks for (a kernel over its limit
-    does not compile) with the ask at its floor."""
-    slots, heads, kv_heads, head_dim, slot_pages = _DECODE_CELLS[cell]
-    page, layers = 64, 2
+    place: nothing pool-sized among the temporaries), and the call asks
+    for the VMEM its buffers need and no floor — what
+    ``_decode_vmem_bytes`` counts from the call's shapes, 12.6 to 18.6 MiB
+    here where every call asked 96 — and compiles under it (a kernel over
+    its limit does not compile)."""
+    slots, heads, kv_heads, head_dim, slot_pages, layers, fused = \
+        _DECODE_CELLS[cell]
+    page = 64
     fn, shapes = _paged_decode(False, page=page, slots=slots,
                                cache_len=slot_pages * page, layers=layers,
                                kv_heads=kv_heads, heads=heads,
-                               head_dim=head_dim)
+                               head_dim=head_dim, fused=fused)
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+    compiled = jax.jit(fn, donate_argnums=(1, 2) if fused else ()) \
+        .lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    mem = compiled.memory_analysis()
-    pool_bytes = 2 * layers * (slots * slot_pages + 1) * page \
-        * kv_heads * head_dim * 2
-    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
-    assert mem.temp_size_in_bytes < pool_bytes // 16, mem.temp_size_in_bytes
+    if fused:
+        mem = compiled.memory_analysis()
+        pool_bytes = 2 * layers * (slots * slot_pages + 1) * page \
+            * kv_heads * head_dim * 2
+        assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+        assert mem.temp_size_in_bytes < pool_bytes // 16, \
+            mem.temp_size_in_bytes
     bp = paged_mod._decode_block_pages(page, slot_pages, 2)
-    block_bytes = bp * page * kv_heads * head_dim * 2
-    assert 6 * block_bytes + 16 * 2 ** 20 <= 96 * 2 ** 20, block_bytes
+    asked = decode_mod._decode_vmem_bytes(
+        slots, heads, head_dim, bp * page, kv_heads * head_dim, BF16, BF16)
+    assert vmem_asks == [asked], (vmem_asks, asked)
+    assert 12 * 2 ** 20 < asked < 20 * 2 ** 20, f"{asked / 2 ** 20:.1f} MiB"
+
+
+def _lane_kernel(kind, lanes, rows, heads, slot_pages, width=640, rank=512,
+                 page=64, layers=2):
+    """One layer-step of a latent decode block at a cell's sizes: ``lanes``
+    lanes of ``rows`` query rows (GLM-5's verify window has two) over a pool
+    of 640-wide latent rows (``mla_lane_decode``) or 128-wide index keys
+    (``dsa_lane_index``), the slot's table padded to whole 512-key blocks."""
+    bp = 512 // page
+    table = -(-slot_pages // bp) * bp
+    keys = table * page
+    pool = ((layers, lanes * slot_pages + 1, page,
+             width if kind == "mla_lane_decode" else 128), BF16)
+    tail = [pool, ((lanes, table), I32), ((lanes,), I32)]
+    if kind == "mla_lane_decode":
+        def fn(q, kept, pool, table, ctx):
+            return latent_mod.lane_decode(q, kept, pool, layers - 1, table,
+                                          bp, ctx, rank, 0.07)
+        return fn, [((lanes, rows, heads, width), BF16),
+                    ((lanes, rows, keys), I8)] + tail
+
+    def fn(q, w, pool, table, ctx):
+        return latent_mod.lane_index_scores(q, w, pool, layers - 1, table, bp,
+                                            ctx)
+    return fn, [((lanes, rows, heads, 128), BF16),
+                ((lanes, rows, heads), F32)] + tail
+
+
+# the other decode-side calls at their cells' sizes, and what each asks:
+# EvaByte's 24 lanes (a three-deep ring of 64-row pages of 4,096 lanes) and
+# the monolithic kernel ``generate()`` runs, what ``_decode_vmem_bytes``
+# counts; GLM-5's verify windows (64 lanes x 2 rows x 64 heads over 73
+# pages, 32 index heads) and LongCat's steps (128 lanes x 64 heads over 33
+# pages), the flat ``VMEM_ASK`` they keep — at the 7 MiB they hold
+# LongCat's decode block ran 2% LONGER on the chip (PERF.md, PR 61)
+_DECODE_SIDE_ASKS = {
+    "evabyte_eva_decode": (_eva_decode, lambda: decode_mod._decode_vmem_bytes(
+        1, 32, 128, 64, 4096, BF16, BF16, buffers=3)),
+    "mono_decode": (_mono_decode, lambda: decode_mod._decode_vmem_bytes(
+        1, H, D, 512, HD, BF16, BF16)),
+    "glm5_mla_lane_decode": (
+        lambda: _lane_kernel("mla_lane_decode", 64, 2, 64, 73),
+        lambda: latent_mod.VMEM_ASK),
+    "longcat_mla_lane_decode": (
+        lambda: _lane_kernel("mla_lane_decode", 128, 1, 64, 33),
+        lambda: latent_mod.VMEM_ASK),
+    "glm5_dsa_lane_index": (
+        lambda: _lane_kernel("dsa_lane_index", 64, 2, 32, 73),
+        lambda: latent_mod.VMEM_ASK),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DECODE_SIDE_ASKS))
+def test_decode_side_kernel_compiles_under_its_ask(case, one_chip, mosaic,
+                                                   vmem_asks):
+    """``attn.eva_decode`` and ``attn.decode`` ask for what their call's
+    shapes count to — 8.5 and 13.1 MiB where they asked 96 —, the two lane
+    kernels for their flat 64, and Mosaic accepts each at its cell's sizes
+    under its ask."""
+    build, ask = _DECODE_SIDE_ASKS[case]
+    fn, shapes = build()
+    compiled = _compile(fn, shapes, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert vmem_asks == [ask()], (vmem_asks, ask())
+    assert ask() == latent_mod.VMEM_ASK or ask() < 14 * 2 ** 20, vmem_asks
 
 
 @pytest.mark.parametrize("rows", [1, 4], ids=["one_row", "four_rows"])
@@ -646,9 +733,11 @@ def test_chunk_step_writes_page_runs_in_place(rows, one_chip, mosaic):
     assert len(prefetched) >= L - 2, sorted(prefetched)
 
 
-def _slot_programs_of(cell, family, one_chip):
+def _slot_programs_of(cell, family, one_chip, **overrides):
     """What a serving cell's slot programs are lowered from, at the cell's
-    own settings: the module, the cell's ``serving`` block, its chunk and
+    own settings: the module (``overrides``: the family's
+    ``program_model`` keywords, as ``benchmark/serving.py`` passes
+    ``scan_layers=False``), the cell's ``serving`` block, its chunk and
     ``SlotPages`` — and ``params`` / ``pool`` as shapes on the described
     chip, with ``on_chip`` and ``ints`` to make more of them."""
     import os
@@ -659,7 +748,7 @@ def _slot_programs_of(cell, family, one_chip):
     bench = spec.Benchmark(os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))))
     cell = bench.cell(cell)
-    module = bench.family(family).program_model(cell["config"])
+    module = bench.family(family).program_model(cell["config"], **overrides)
     s = cell["system"]["serving"]
     declared = module.slot_contract()
     chunk = slots.admission_chunk(declared, s["prefill_chunk"])
@@ -675,6 +764,77 @@ def _slot_programs_of(cell, family, one_chip):
         pool=on_chip(jax.eval_shape(lambda: pages.new_pools(BF16))),
         ints=lambda *shape: jax.ShapeDtypeStruct(shape, I32,
                                                  sharding=one_chip))
+
+
+_ITEMSIZE = {"bf16": 2, "f32": 4, "s32": 4, "s8": 1}
+
+
+def _slices_open_across_kernels(text):
+    """``[(slices, bytes)]`` a ``tpu_custom_call`` of a compiled program's
+    text: the ``slice-start``s whose ``slice-done`` comes after the call —
+    operands XLA is copying into VMEM (the second shape of a
+    ``slice-start``'s tuple, in memory space ``S(1)``) while the kernel
+    runs.  The text of a compiled module lists a computation's instructions
+    in the order they are scheduled.  (``docs/performance.md``, "What a
+    kernel is granted, XLA cannot use".)"""
+    calls, started = [], {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*)", line)
+        if not m:
+            started = {}                    # a computation's first or last line
+            continue
+        name, rest = m.groups()
+        if " slice-start(" in rest:
+            dtype, dims = re.findall(r"(\w+)\[([\d,]*)\]", rest)[1]
+            started[name] = _ITEMSIZE[dtype] * math.prod(
+                map(int, dims.split(",")))
+        elif " slice-done(" in rest:
+            started.pop(re.search(r"slice-done\(%([^),]+)", rest).group(1),
+                        None)
+        elif 'custom_call_target="tpu_custom_call"' in rest:
+            calls.append((len(started), sum(started.values())))
+    return calls
+
+
+def test_chat_decode_block_prefetches_weights_across_the_kernel(
+        one_chip, mosaic, monkeypatch):
+    """The mechanism of PR 61, counted without the chip: ``opt13b-serve-chat``'s
+    decode block as the cell serves it (``scan_layers=False``; four layers
+    of the 24) keeps the next weights' copies into VMEM open ACROSS each
+    ``attn.paged_decode`` call — more ``slice-start`` / ``slice-done`` pairs
+    than the same program with the call's ask forced back to the 96 MiB
+    floor, over 30 MB open across a call where the floor leaves under half
+    of that (at 24 layers, PR 53's probe and this PR's: 368 pairs against
+    184, 38.1 MB a call against 2.0).  What a kernel is granted XLA cannot
+    use: if a later change to the ask, the kernel or the block closes the
+    window again, this fails before a chip run has to say so."""
+    from deepspeed_tpu.inference.serving import slots
+
+    def block():
+        c = _slot_programs_of("opt13b-serve-chat", "opt", one_chip,
+                              scan_layers=False, num_layers=4)
+        n = c.serving["num_slots"]
+        state = c.on_chip({k: jnp.asarray(v) for k, v in
+                           slots.init_slot_state(n).items()})
+        rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                   sharding=one_chip)
+        text = slots.make_decode_block_fn(
+            c.module, c.declared, lambda logits, rng: jnp.argmax(logits, -1),
+            None, c.serving["decode_block"], c.pages.cache_len).lower(
+                c.params, c.pool, state, c.ints(n, c.pages.table_width),
+                rng).compile().as_text()
+        return text.count(" slice-start("), _slices_open_across_kernels(text)
+
+    pairs, across = block()
+    monkeypatch.setattr(paged_mod, "_decode_vmem_bytes",
+                        lambda *a, **kw: 96 * 2 ** 20)
+    floor_pairs, floor_across = block()
+    assert len(across) == len(floor_across) == 4
+    mean = lambda calls, i: sum(c[i] for c in calls) / len(calls)
+    assert pairs > floor_pairs, (pairs, floor_pairs)
+    assert mean(across, 0) > mean(floor_across, 0), (across, floor_across)
+    assert mean(across, 1) > 30e6 > 2 * mean(floor_across, 1), \
+        (across, floor_across)
 
 
 @pytest.mark.parametrize("program", ["chunk_step", "decode_block"])
@@ -954,6 +1114,10 @@ def test_trinity_slot_programs_compile_at_the_cells_sizes(program, one_chip,
                 params, pool, state, ints(n, pages.table_width),
                 rng).compile()
         calls = 5 + 4         # paged decode a layer; the experts
+        # ``attn.paged_decode`` over the ring's table and over the lane
+        # pool's: the same ask, from the same blocks of 8 pages of 512 lanes
+        assert vmem_asks.count(decode_mod._decode_vmem_bytes(
+            n, 32, 128, 512, 512, BF16, BF16)) == 5, vmem_asks
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= calls
     named = set(re.findall(r'op_name="([^"]*TrinityModel\.decode[^"]*)"',
